@@ -140,6 +140,11 @@ def main(argv=None) -> int:
                           "signal_families": cfg.used_signal_types()}))
         return 0
 
+    # serving from here on: the one place the compile cache is placed
+    from .runtime.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     if args.command == "serve-extproc":
         import time
 

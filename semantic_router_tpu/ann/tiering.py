@@ -154,7 +154,7 @@ class TierPolicy:
     def run_cycle(self) -> Dict[str, int]:
         """One maintenance pass; returns counts for the metric bumps."""
         ewma = self._roll_ewma()
-        promoted = self._promote(ewma)
+        promoted_ids = self._promote(ewma)
         evicted = self._evict(ewma)
         compacted = 0
         # compact on the ratio, but ALSO whenever delete + promote
@@ -167,29 +167,38 @@ class TierPolicy:
         if self.bank.dirty():
             self.bank.publish()
             published = 1
+        # a promoted entry leaves the host tier only once the published
+        # view holds it: popped before publish() it would be findable
+        # NOWHERE for the length of the publish (a lookup in that window
+        # returned no hits); held in both, lookup() dedups by best score
+        for entry_id in promoted_ids:
+            if entry_id in self.bank:
+                self.host.pop(entry_id)
+        promoted = len(promoted_ids)
         return {"promoted": promoted, "evicted": evicted,
                 "compacted": compacted, "published": published}
 
-    def _promote(self, ewma: Dict[str, float]) -> int:
-        """Hot host entries move into the device bank, hottest first.
-        Entries below ``promote_min_hits`` EWMA stay host-side; a bank
-        at max capacity refuses and the overflow simply stays exact."""
+    def _promote(self, ewma: Dict[str, float]) -> List[str]:
+        """Hot host entries are copied into the device bank, hottest
+        first; returns their ids (run_cycle drops them from the host tier
+        after the publish).  Entries below ``promote_min_hits`` EWMA stay
+        host-side; a bank at max capacity refuses and the overflow simply
+        stays exact."""
         host_ids = set(self.host.ids())
         if not host_ids:
-            return 0
+            return []
         ranked = sorted(
             (i for i in host_ids
              if ewma.get(i, 0.0) >= self.promote_min_hits),
             key=lambda i: ewma.get(i, 0.0), reverse=True)
-        promoted = 0
+        promoted: List[str] = []
         for entry_id in ranked:
             vec = self.host.get(entry_id)
             if vec is None:
                 continue
             if not self.bank.add(entry_id, vec):
                 break  # max tier full — eviction may free room later
-            self.host.pop(entry_id)
-            promoted += 1
+            promoted.append(entry_id)
         return promoted
 
     def _evict(self, ewma: Dict[str, float]) -> int:

@@ -102,8 +102,8 @@ def pick_bucket(seq_len: int, buckets: Sequence[int]) -> int:
 class _DispatchPool:
     """N DAEMON worker threads over a queue — deliberately not
     ThreadPoolExecutor, whose non-daemon workers are joined at
-    interpreter exit: a forward call wedged in PJRT (the tunnel-wedge
-    scenario) would then block process exit forever.  Daemon workers
+    interpreter exit: a forward call wedged in PJRT would then block
+    process exit forever.  Daemon workers
     let a clean self-exit proceed; shutdown() CANCELS still-queued
     batches instead of running them against torn-down model state."""
 

@@ -249,6 +249,11 @@ class TrunkGroup:
     host_refs: Any = None
 
 
+class WarmupError(RuntimeError):
+    """A warmup program failed to compile or run; the message names each
+    (target, bucket) and the compiler's error."""
+
+
 class InferenceEngine:
     """Owner of all TPU-served classifier tasks + the batching shim."""
 
@@ -395,6 +400,8 @@ class InferenceEngine:
         # (group, variant, shape) triples already executed — the step
         # sampler's per-PROGRAM compile detection (_step_fresh)
         self._compiled_steps: set = set()
+        # per-(target, bucket) warmup outcomes (warmup_report())
+        self._warmup_report: List[Dict[str, Any]] = []
         # generative decode mutates per-generator jit/cache state; one
         # generation runs on-device at a time (decode steps saturate the
         # chip anyway — concurrency comes from the classify batcher)
@@ -459,6 +466,19 @@ class InferenceEngine:
             from ..parallel import shard_params
 
             params = shard_params(params, self.mesh)
+        else:
+            # weights live on the device.  A checkpoint load hands over
+            # host (numpy) arrays; left as they are, the whole tree —
+            # 0.57 GB for an mmBERT trunk — would cross the jit boundary,
+            # and PCIe, on EVERY step.  A member of an existing trunk
+            # group takes the group's trunk instead of uploading its own.
+            g = self._trunk_groups.get(tkey) if tkey is not None else None
+            if g is not None:
+                p = dict(params.get("params", params))
+                p["model"] = g.trunk_params
+                params = {**dict(params), "params": p} \
+                    if "params" in params else p
+            params = jax.tree_util.tree_map(jnp.asarray, params)
         with self._lock:
             self._tasks[name] = _Task(name, kind, list(labels), tokenizer,
                                       apply_fn, params, max_len, pad_id,
@@ -901,20 +921,27 @@ class InferenceEngine:
         meta.setdefault("mesh", None)
         act = activation(cfg.classifier_activation)
         use_mean = cfg.classifier_pooling == "mean"
+        srv_mesh = self._serving_mesh if meta["mesh"] is not None \
+            else None
+        trunk_cfg = cfg
+        if srv_mesh is not None and cfg.attention_impl == "flash":
+            # GSPMD cannot partition the Pallas kernel: the trunk must
+            # know its mesh so attention shard_maps the kernel over it
+            trunk_cfg = replace(cfg, mesh=srv_mesh)
         if meta["quant"] == "off":
-            trunk, serving_params = g.trunk_module, g.trunk_params
+            trunk = g.trunk_module if trunk_cfg is cfg \
+                else type(g.trunk_module)(trunk_cfg)
+            serving_params = g.trunk_params
         else:
             from ..models.quant import build_quant_trunk
 
             trunk, serving_params = build_quant_trunk(
-                cfg, g.trunk_params, meta["quant"])
+                trunk_cfg, g.trunk_params, meta["quant"])
         # serving-mesh placement (docs/PARALLEL.md): the SERVING copy of
         # the trunk tree lands on the mesh per the Megatron rules (tp=1
         # degenerates to replication); g.trunk_params keeps the
         # unplaced original, so a mesh teardown restores byte-identical
         # single-device serving from the same source arrays
-        srv_mesh = self._serving_mesh if meta["mesh"] is not None \
-            else None
         if srv_mesh is not None:
             from ..parallel import shard_params
 
@@ -1711,9 +1738,13 @@ class InferenceEngine:
         return futures
 
     def warmup(self, tasks: Optional[Sequence[str]] = None,
-               buckets: Optional[Sequence[int]] = None) -> None:
-        """Pre-trigger jit compilation for the hot (task, bucket, batch=1)
+               buckets: Optional[Sequence[int]] = None,
+               batch_sizes: Sequence[int] = (1,)) -> None:
+        """Pre-trigger jit compilation for the hot (task, bucket, batch)
         shapes (reference warmupRouterRuntime, runtime_bootstrap.go:439).
+        ``batch_sizes``: real-row counts to warm, each padded the way the
+        batcher pads; the default warms batch 1 only, so every other
+        padded batch still compiles on its first use.
 
         EVERY bucket a task can serve warms by default — a cold bucket in
         production is a guaranteed SLO breach (one full XLA compile on the
@@ -1724,15 +1755,48 @@ class InferenceEngine:
         past their timeouts — the exact breach warmup exists to prevent.
         The compile cache is on the jitted function, so live requests of
         the same shape hit it either way."""
+        failures: List[str] = []
+        with self._lock:
+            self._warmup_report = []
+
+        def warm(target: str, bucket: int, rows: int,
+                 fn: Callable[[], None]) -> None:
+            # every (target, bucket, batch) is attempted and timed; a
+            # failure is recorded and raised at the END — startup must
+            # say which program the compiler refused, never serve on
+            # without it
+            t0 = time.perf_counter()
+            row = {"target": target, "bucket": int(bucket),
+                   "rows": int(rows), "error": ""}
+            try:
+                fn()
+            except Exception as exc:
+                row["error"] = f"{type(exc).__name__}: {exc}"
+                failures.append(
+                    f"{target}@{bucket}x{rows}: {row['error'][:400]}")
+            row["seconds"] = round(time.perf_counter() - t0, 3)
+            with self._lock:
+                self._warmup_report.append(row)
+
         for name in tasks or list(self._tasks):
             t = self._tasks.get(name)
             if t is None or t.kind in ("generative", "multimodal"):
                 continue  # their compile caches key on other shapes
-            for b in buckets or self.cfg.seq_len_buckets:
+            if name in self._task_group:
+                # fused members serve through their trunk group's
+                # programs (warmed below); the per-task program only
+                # runs classify_windowed's multi-window batches, whose
+                # batch sizes this batch-1 warmup never covered — a
+                # full-width compile per bucket for nothing
+                continue
+            for b, n in ((b, n)
+                         for b in buckets or self.cfg.seq_len_buckets
+                         for n in batch_sizes):
                 if b > t.max_seq_len:
                     continue
-                try:
-                    padded_n = self._padded_batch(1)
+
+                def warm_task(t=t, b=b, n=n) -> None:
+                    padded_n = self._padded_batch(n)
                     ids = np.full((padded_n, b), t.pad_id, np.int32)
                     ids[:, 0] = 1
                     mask = np.ones((padded_n, b), np.int32)
@@ -1749,8 +1813,8 @@ class InferenceEngine:
                     else:
                         out = t.apply_fn(t.params, ids_dev, mask_dev)
                         jax.block_until_ready(out)
-                except Exception:
-                    pass
+
+                warm(f"task:{name}", b, n, warm_task)
         # fused trunk groups compile their OWN programs (trunk + stacked
         # heads): warm those the same way — one cold fused bucket would
         # stall the whole bank's traffic, not one task's.  Every flavor
@@ -1761,10 +1825,13 @@ class InferenceEngine:
         for g in list(self._groups_by_gid.values()):
             if tasks and not any(m in tasks for m in g.members):
                 continue
-            for b in buckets or self.cfg.seq_len_buckets:
+            for b, n in ((b, n)
+                         for b in buckets or self.cfg.seq_len_buckets
+                         for n in batch_sizes):
                 if b > g.max_seq_len:
                     continue
-                try:
+
+                def warm_group(g=g, b=b, n=n) -> None:
                     fns = g.fns
                     srv_mesh = fns.get("mesh")
                     # banks from the SAME snapshot as the programs —
@@ -1774,7 +1841,7 @@ class InferenceEngine:
                     dmx = fns.get("demux") or g.demux or {}
                     bank = dmx.get("bank")
                     tok_bank = dmx.get("tok_bank")
-                    padded_n = self._padded_batch(1, mesh=srv_mesh)
+                    padded_n = self._padded_batch(n, mesh=srv_mesh)
                     ids = np.full((padded_n, b), g.pad_id, np.int32)
                     ids[:, 0] = 1
                     mask = np.ones((padded_n, b), np.int32)
@@ -1809,9 +1876,21 @@ class InferenceEngine:
                         pooled = trunk_fn(g.trunk_params, ids_dev,
                                           mask_dev)
                         jax.block_until_ready(head_fn(bank, pooled))
-                except Exception:
-                    pass
-                self._warm_packed(g, b)
+                    self._warm_packed(g, b)
+
+                warm(f"trunk:{g.gid}", b, n, warm_group)
+        if failures:
+            raise WarmupError(
+                f"{len(failures)} warmup program(s) failed: "
+                + "; ".join(failures))
+
+    def warmup_report(self) -> List[Dict[str, Any]]:
+        """One row per (target, bucket, rows) the last warmup()
+        attempted: {target: "task:<name>" | "trunk:<gid>", bucket, rows,
+        seconds, error} — seconds is compile + one execution of every
+        program of the target at that shape; error is "" on success."""
+        with self._lock:
+            return [dict(r) for r in self._warmup_report]
 
     def _warm_packed(self, g: TrunkGroup, bucket: int) -> None:
         """Pre-compile the hot packed programs for one (group, bucket):
@@ -1832,7 +1911,9 @@ class InferenceEngine:
         off the dispatch path, then MARK it in the compiled-step
         registry: the first real packed step of this shape is a warm
         execute and must account as one (cold-count stays flat —
-        tests/test_packing.py TestPackedWarmup)."""
+        tests/test_packing.py TestPackedWarmup).  False = packing cannot
+        serve this group right now; a program that fails to compile or
+        run RAISES."""
         if not self._packing["enabled"] or self.mesh is not None \
                 or g.fns is None \
                 or getattr(g.config, "attention_impl",
@@ -1844,98 +1925,96 @@ class InferenceEngine:
         dmx = fns.get("demux") or g.demux or {}
         bank = dmx.get("bank")
         tok_bank = dmx.get("tok_bank")
-        try:
-            class _WarmEnc:
-                """Minimal Encoding shim so warmup builds its packed
-                batch through pack_items — ONE layout implementation,
-                the warm program traces exactly what real packed steps
-                will."""
 
-                def __init__(self, n: int) -> None:
-                    self.ids = np.ones(n, np.int32)
-                    self.attention_mask = np.ones(n, np.int32)
+        class _WarmEnc:
+            """Minimal Encoding shim so warmup builds its packed
+            batch through pack_items — ONE layout implementation,
+            the warm program traces exactly what real packed steps
+            will."""
 
-                def __len__(self) -> int:
-                    return len(self.ids)
+            def __init__(self, n: int) -> None:
+                self.ids = np.ones(n, np.int32)
+                self.attention_mask = np.ones(n, np.int32)
 
-            k_eff = max(2, int(k_pad))
-            half = max(1, bucket // 2)
-            pb = pack_items(
-                [_WarmEnc(half), _WarmEnc(bucket - half)], bucket,
-                g.pad_id, max_rows=1, max_segments_per_row=2,
-                pad_rows_to=padded_rows, pad_segments_to=k_eff)
-            ids_dev, mask_dev = self._to_device(pb.ids, pb.mask,
-                                                mesh=srv_mesh)
-            if srv_mesh is not None:
-                from ..parallel import batch_sharding, replicated
+            def __len__(self) -> int:
+                return len(self.ids)
 
-                row_sh = batch_sharding(srv_mesh)
-                rep = replicated(srv_mesh)
-                pos_dev = jax.device_put(pb.position_ids, row_sh)
-                seg_dev = jax.device_put(pb.segment_ids, row_sh)
-                row_dev = jax.device_put(pb.seg_row, rep)
-                start_dev = jax.device_put(pb.seg_start, rep)
-            else:
-                pos_dev = jnp.asarray(pb.position_ids)
-                seg_dev = jnp.asarray(pb.segment_ids)
-                row_dev = jnp.asarray(pb.seg_row)
-                start_dev = jnp.asarray(pb.seg_start)
-            tp = fns["trunk_params"]
-            if fns["meta"]["bgmv"]:
-                pp = int(pair_pad) or 2
-                pair = (jnp.zeros(pp, jnp.int32),
-                        jnp.zeros(pp, jnp.int32))
-                sfx = f":p{pp}"
-            else:
-                pair, sfx = (), ""
-            want = set(flavors or ("seq", "tok", "both"))
-            meta = fns["meta"]
-            measured = "packed_mesh" if srv_mesh is not None else "packed"
-            if bank is not None and "seq" in want:
-                jax.block_until_ready(fns["packed_seq"](
-                    tp, bank, ids_dev, mask_dev,
-                    pos_dev, seg_dev, row_dev, start_dev, *pair))
-                if self._step_fresh(f"trunk:{g.gid}",
-                                    f"packed:seq:{k_eff}{sfx}{msfx}",
-                                    (padded_rows, bucket)):
-                    self._capture_program(
-                        f"trunk:{g.gid}", bucket,
-                        f"packed:seq:{k_eff}{sfx}{msfx}",
-                        (padded_rows, bucket), fns["packed_seq"],
-                        (tp, bank, ids_dev, mask_dev, pos_dev, seg_dev,
-                         row_dev, start_dev, *pair), measured, meta)
-            if tok_bank is not None and "tok" in want:
-                jax.block_until_ready(fns["packed_tok"](
-                    tp, tok_bank, ids_dev, mask_dev,
-                    pos_dev, seg_dev))
-                if self._step_fresh(f"trunk:{g.gid}",
-                                    f"packed:tok:{k_eff}{msfx}",
-                                    (padded_rows, bucket)):
-                    self._capture_program(
-                        f"trunk:{g.gid}", bucket,
-                        f"packed:tok:{k_eff}{msfx}",
-                        (padded_rows, bucket), fns["packed_tok"],
-                        (tp, tok_bank, ids_dev, mask_dev, pos_dev,
-                         seg_dev), measured, meta)
-            if bank is not None and tok_bank is not None \
-                    and "both" in want:
-                out = fns["packed_both"](
-                    tp, bank, tok_bank, ids_dev, mask_dev,
-                    pos_dev, seg_dev, row_dev, start_dev, *pair)
-                jax.block_until_ready(out)
-                if self._step_fresh(f"trunk:{g.gid}",
-                                    f"packed:both:{k_eff}{sfx}{msfx}",
-                                    (padded_rows, bucket)):
-                    self._capture_program(
-                        f"trunk:{g.gid}", bucket,
-                        f"packed:both:{k_eff}{sfx}{msfx}",
-                        (padded_rows, bucket), fns["packed_both"],
-                        (tp, bank, tok_bank, ids_dev, mask_dev, pos_dev,
-                         seg_dev, row_dev, start_dev, *pair),
-                        measured, meta)
-            return True
-        except Exception:
-            return False
+        k_eff = max(2, int(k_pad))
+        half = max(1, bucket // 2)
+        pb = pack_items(
+            [_WarmEnc(half), _WarmEnc(bucket - half)], bucket,
+            g.pad_id, max_rows=1, max_segments_per_row=2,
+            pad_rows_to=padded_rows, pad_segments_to=k_eff)
+        ids_dev, mask_dev = self._to_device(pb.ids, pb.mask,
+                                            mesh=srv_mesh)
+        if srv_mesh is not None:
+            from ..parallel import batch_sharding, replicated
+
+            row_sh = batch_sharding(srv_mesh)
+            rep = replicated(srv_mesh)
+            pos_dev = jax.device_put(pb.position_ids, row_sh)
+            seg_dev = jax.device_put(pb.segment_ids, row_sh)
+            row_dev = jax.device_put(pb.seg_row, rep)
+            start_dev = jax.device_put(pb.seg_start, rep)
+        else:
+            pos_dev = jnp.asarray(pb.position_ids)
+            seg_dev = jnp.asarray(pb.segment_ids)
+            row_dev = jnp.asarray(pb.seg_row)
+            start_dev = jnp.asarray(pb.seg_start)
+        tp = fns["trunk_params"]
+        if fns["meta"]["bgmv"]:
+            pp = int(pair_pad) or 2
+            pair = (jnp.zeros(pp, jnp.int32),
+                    jnp.zeros(pp, jnp.int32))
+            sfx = f":p{pp}"
+        else:
+            pair, sfx = (), ""
+        want = set(flavors or ("seq", "tok", "both"))
+        meta = fns["meta"]
+        measured = "packed_mesh" if srv_mesh is not None else "packed"
+        if bank is not None and "seq" in want:
+            jax.block_until_ready(fns["packed_seq"](
+                tp, bank, ids_dev, mask_dev,
+                pos_dev, seg_dev, row_dev, start_dev, *pair))
+            if self._step_fresh(f"trunk:{g.gid}",
+                                f"packed:seq:{k_eff}{sfx}{msfx}",
+                                (padded_rows, bucket)):
+                self._capture_program(
+                    f"trunk:{g.gid}", bucket,
+                    f"packed:seq:{k_eff}{sfx}{msfx}",
+                    (padded_rows, bucket), fns["packed_seq"],
+                    (tp, bank, ids_dev, mask_dev, pos_dev, seg_dev,
+                     row_dev, start_dev, *pair), measured, meta)
+        if tok_bank is not None and "tok" in want:
+            jax.block_until_ready(fns["packed_tok"](
+                tp, tok_bank, ids_dev, mask_dev,
+                pos_dev, seg_dev))
+            if self._step_fresh(f"trunk:{g.gid}",
+                                f"packed:tok:{k_eff}{msfx}",
+                                (padded_rows, bucket)):
+                self._capture_program(
+                    f"trunk:{g.gid}", bucket,
+                    f"packed:tok:{k_eff}{msfx}",
+                    (padded_rows, bucket), fns["packed_tok"],
+                    (tp, tok_bank, ids_dev, mask_dev, pos_dev,
+                     seg_dev), measured, meta)
+        if bank is not None and tok_bank is not None \
+                and "both" in want:
+            out = fns["packed_both"](
+                tp, bank, tok_bank, ids_dev, mask_dev,
+                pos_dev, seg_dev, row_dev, start_dev, *pair)
+            jax.block_until_ready(out)
+            if self._step_fresh(f"trunk:{g.gid}",
+                                f"packed:both:{k_eff}{sfx}{msfx}",
+                                (padded_rows, bucket)):
+                self._capture_program(
+                    f"trunk:{g.gid}", bucket,
+                    f"packed:both:{k_eff}{sfx}{msfx}",
+                    (padded_rows, bucket), fns["packed_both"],
+                    (tp, bank, tok_bank, ids_dev, mask_dev, pos_dev,
+                     seg_dev, row_dev, start_dev, *pair),
+                    measured, meta)
+        return True
 
     def _packed_census_rows(self, gid: str) -> list:
         """Packed program shapes this engine has executed for one
@@ -1994,10 +2073,22 @@ class InferenceEngine:
             remaining = set()
             for row in sorted(rows):
                 bucket, k_pad, padded_rows, flavor, pair_pad = row
-                if self._warm_packed_shape(g, bucket, k_pad,
-                                           padded_rows,
-                                           pair_pad=pair_pad,
-                                           flavors=(flavor,)):
+                try:
+                    warmed = self._warm_packed_shape(
+                        g, bucket, k_pad, padded_rows,
+                        pair_pad=pair_pad, flavors=(flavor,))
+                except Exception as exc:
+                    # knob-apply time (boot + hot reload) stays
+                    # fail-open like every apply_* path, but says what
+                    # the compiler refused
+                    warmed = False
+                    from ..observability.logging import component_event
+
+                    component_event(
+                        "engine", "packed_warmup_failed",
+                        level="warning", group=gid, row=list(row),
+                        error=f"{type(exc).__name__}: {exc}"[:400])
+                if warmed:
                     n += 1
                 else:
                     remaining.add(row)

@@ -44,6 +44,7 @@ from ..ops.attention import (
     sdpa,
     sliding_window_bias,
 )
+from ..ops.epilogue import gelu_exact
 from ..ops.rope import RopeSpec, apply_rotary
 
 
@@ -74,7 +75,9 @@ class ModernBertConfig:
     # exact attention over mesh[ring_seq_axis] — ops.ring_attention)
     attention_impl: str = "dense"
     chunk_block_size: int = 512
-    mesh: Any = None  # required for attention_impl="ring"
+    # required for attention_impl="ring"; for "flash" the serving mesh
+    # (engine.mesh) whose shards each run the kernel
+    mesh: Any = None
     ring_seq_axis: str = "sp"
     ring_batch_axis: str = "dp"
     ring_head_axis: Optional[str] = "tp"
@@ -118,7 +121,7 @@ class ModernBertConfig:
 
 def _act(name: str):
     if name in ("gelu", "gelu_python"):
-        return lambda x: jax.nn.gelu(x, approximate=False)
+        return gelu_exact
     if name in ("gelu_new", "gelu_pytorch_tanh"):
         return lambda x: jax.nn.gelu(x, approximate=True)
     if name == "relu":
@@ -246,8 +249,12 @@ class ModernBertAttention(nn.Module):
         if cfg.attention_impl == "flash":
             from ..ops.flash_attention import flash_attention
 
+            # cfg.mesh is set when the engine serves this trunk under
+            # a dp×tp mesh: the kernel then runs per shard
             out = flash_attention(q, k, v, key_padding_mask=attention_mask,
-                                  window=window)
+                                  window=window, mesh=cfg.mesh,
+                                  batch_axis=cfg.ring_batch_axis,
+                                  head_axis=cfg.ring_head_axis)
         elif cfg.attention_impl == "chunked":
             out = chunked_sdpa(q, k, v, key_padding_mask=attention_mask,
                                window=window,

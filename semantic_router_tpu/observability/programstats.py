@@ -44,12 +44,14 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 # -- per-device peak table ----------------------------------------------------
 #
-# (substring-of-device_kind, tier) — first match wins, CPU placeholder
-# is the fallback.  TPU numbers are the public datasheet peaks (dense
-# bf16 MXU FLOP/s, HBM bandwidth, HBM capacity); the CPU tier exists so
-# roofline math stays total on dev rigs, but it is an order-of-magnitude
-# guess about an unknown host — rows carry ``peak_note`` saying exactly
-# that, and CPU fractions must never be compared across machines.
+# (substring-of-device_kind, tier) — first match wins.  TPU numbers are
+# the public datasheet peaks (dense bf16 MXU FLOP/s, HBM bandwidth, HBM
+# capacity).  The CPU tier exists so roofline math stays total on dev
+# rigs, but it is an order-of-magnitude guess about an unknown host —
+# rows carry ``peak_note`` saying exactly that, and CPU fractions must
+# never be compared across machines.  An ACCELERATOR that is not in the
+# table gets the "unknown" tier: no peaks, so no roofline fractions —
+# never a guess dressed as a measurement.
 _PEAK_TIERS: Tuple[Tuple[Tuple[str, ...], Dict[str, Any]], ...] = (
     (("v6e", "trillium"), {
         "tier": "tpu-v6e", "flops_per_s": 918e12,
@@ -84,16 +86,26 @@ _CPU_TIER: Dict[str, Any] = {
 }
 
 
+_UNKNOWN_TIER: Dict[str, Any] = {
+    "tier": "unknown", "flops_per_s": 0.0,
+    "hbm_bytes_per_s": 0.0, "hbm_bytes": 0,
+    "peak_note": "device not in the peak table: achieved rates are "
+                 "reported, roofline fractions are not",
+}
+
+
 def peak_for(device_kind: str, platform: str = "") -> Dict[str, Any]:
-    """Peak-throughput tier for a jax ``device_kind`` string (substring
-    match against the datasheet table; anything unrecognized — including
-    every CPU — gets the flagged placeholder tier)."""
+    """Peak-throughput tier for a jax ``device_kind`` string: the CPU
+    platform gets the flagged placeholder tier, an accelerator matches
+    the datasheet table by substring or gets the peak-less "unknown"
+    tier."""
+    if platform.lower() == "cpu":
+        return dict(_CPU_TIER)
     kind = (device_kind or "").lower()
-    if platform.lower() != "cpu":
-        for needles, tier in _PEAK_TIERS:
-            if any(n in kind for n in needles):
-                return dict(tier)
-    return dict(_CPU_TIER)
+    for needles, tier in _PEAK_TIERS:
+        if any(n in kind for n in needles):
+            return dict(tier)
+    return dict(_UNKNOWN_TIER)
 
 
 def _local_device_tier() -> Dict[str, Any]:
@@ -101,16 +113,16 @@ def _local_device_tier() -> Dict[str, Any]:
         import jax
 
         d = jax.devices()[0]
-        tier = peak_for(getattr(d, "device_kind", ""),
-                        getattr(d, "platform", ""))
-        tier["device_kind"] = getattr(d, "device_kind", "")
-        tier["platform"] = getattr(d, "platform", "")
-        tier["device_count"] = len(jax.devices())
+        n = len(jax.devices())
+    except Exception as exc:
+        tier = dict(_UNKNOWN_TIER)
+        tier.update({"device_kind": "", "platform": "", "device_count": 0,
+                     "error": f"{type(exc).__name__}: {exc}"[:200]})
         return tier
-    except Exception:
-        tier = dict(_CPU_TIER)
-        tier.update({"device_kind": "", "platform": "", "device_count": 0})
-        return tier
+    tier = peak_for(d.device_kind, d.platform)
+    tier.update({"device_kind": d.device_kind, "platform": d.platform,
+                 "device_count": n})
+    return tier
 
 
 # -- cost rows ----------------------------------------------------------------

@@ -118,12 +118,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``head_axis`` when it divides H (no collectives cross it).  Callable
     under jit; safe with n=1 meshes (degenerates to one local block).
     """
-    try:
-        from jax import shard_map  # jax >= 0.8 (no check_rep kwarg)
-        smap_kwargs = {}
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-        smap_kwargs = {"check_rep": False}
+    from jax import shard_map
 
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -143,5 +138,5 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         partial(_ring_block, axis_name=seq_axis, axis_size=n,
                 window=window, scale=scale),
         mesh=mesh, in_specs=(qspec, qspec, qspec, mspec),
-        out_specs=qspec, **smap_kwargs)
+        out_specs=qspec)
     return fn(q, k, v, key_padding_mask)

@@ -50,7 +50,10 @@ class StartupTracker:
         if phase not in PHASES:
             raise ValueError(f"unknown phase {phase!r}")
         with self._lock:
-            self.status.phase = phase
+            # failed is terminal: a background failure (warmup) must not
+            # be papered over by the main thread's later "ready"
+            if self.status.phase != "failed":
+                self.status.phase = phase
             self.status.updated_t = time.time()
             if note:
                 self.status.notes.append(f"{phase}: {note}")

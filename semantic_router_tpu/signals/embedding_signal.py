@@ -60,13 +60,18 @@ class _PrototypeBank:
                     ctx: Optional[RequestContext] = None) -> np.ndarray:
         """Embed the query, memoized per request so the embedding /
         preference / complexity families share one forward pass."""
+        if ctx is None:
+            return self.engine.embed(self.task, [text])[0]
         key = ("query_emb", self.task, text)
-        if ctx is not None and key in ctx.ext:
+        # the families evaluate on concurrent threads: without the lock
+        # all of them miss the memo at once and each pays a forward (at
+        # 32K tokens, three of them, batched into a shape no warmup
+        # compiled).  dict.setdefault is atomic, so one lock per request.
+        with ctx.ext.setdefault(("query_emb_lock", self.task),
+                                threading.Lock()):
+            if key not in ctx.ext:
+                ctx.ext[key] = self.engine.embed(self.task, [text])[0]
             return ctx.ext[key]
-        emb = self.engine.embed(self.task, [text])[0]
-        if ctx is not None:
-            ctx.ext[key] = emb
-        return emb
 
 
 def _aggregate(sims: np.ndarray, method: str, threshold: float

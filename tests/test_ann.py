@@ -5,9 +5,10 @@ What this file proves, on the forced 8-device CPU mesh (conftest):
 
 - device top-k parity against the numpy brute-force reference, and the
   host-tier scan against the same oracle;
-- sharded (dp=4 x tp=2) top-k **bit-identical** to single-device —
-  slot indices AND float scores, not merely close (the embedding axis
-  stays unsharded, so every score's reduction is local to one device);
+- sharded (dp=4 x tp=2) top-k equal to single-device — the same slot
+  indices, float scores within 1e-6 (the embedding axis stays unsharded,
+  so every score's reduction is local to one device, but a sharded
+  matmul may tile that reduction differently);
 - quantized banks (int8/bf16) clear the calibrated recall@10 gate at
   >= 0.99, and a bank whose geometry quantizes badly falls back to f32
   and stamps it — never silently serves bad recall;
@@ -274,9 +275,12 @@ class TestShardedBitIdentical:
         s1, i1 = programs.run(v_single, queries, k=8)
         s2, i2 = programs.run(v_sharded, queries, k=8)
         assert np.array_equal(i1, i2)
-        # bit-identical floats: D stays unsharded so each score's f32
-        # reduction is local to one device — same order, same bits
-        assert np.array_equal(s1, s2)
+        # scores to a tolerance, not bit for bit: D stays unsharded so
+        # each score's f32 reduction is local to one device, but a
+        # sharded matmul may tile that reduction differently from the
+        # single-device program (it does on the CPU backend: last-bit
+        # differences).  1e-6 is ~8 ulp of a cosine score in [-1, 1].
+        np.testing.assert_allclose(s1, s2, rtol=0, atol=1e-6)
 
     def test_uneven_tier_replicates_instead_of_erroring(self):
         from semantic_router_tpu.engine.mesh import (
@@ -350,6 +354,30 @@ class TestTiering:
         assert "hot" in bank and "warm" in bank
         assert "cold" in host and "cold" not in bank
         assert counts["published"] == 1
+
+    def test_promoted_entry_stays_findable_through_the_publish(self,
+                                                             monkeypatch):
+        """The cause of test_ingest_search_delete_through_ann's empty
+        hits: a promoted entry left the host tier BEFORE the bank
+        published, so a lookup during publish() found it nowhere."""
+        bank = DeviceBank(min_capacity=16, max_capacity=64)
+        host = HostTier()
+        policy = TierPolicy(bank, host, promote_min_hits=0.0)
+        host.add("hot", _corpus(1, seed=47)[0])
+        policy.mark_hits(["hot"])
+        seen = []
+        publish = bank.publish
+
+        def publishing():
+            view = bank.view()
+            seen.append("hot" in host
+                        or (view is not None and "hot" in view.ids))
+            return publish()
+
+        monkeypatch.setattr(bank, "publish", publishing)
+        assert policy.run_cycle()["promoted"] == 1
+        assert seen == [True]
+        assert "hot" in bank and "hot" not in host
 
     def test_eviction_past_watermark_at_max_tier(self):
         bank = DeviceBank(min_capacity=16, max_capacity=16)

@@ -706,7 +706,7 @@ class TestBenchCascadeArm:
             raise bench.subprocess.TimeoutExpired(cmd="bench", timeout=1)
 
         monkeypatch.setattr(bench.subprocess, "run", fake_run)
-        monkeypatch.setattr(bench, "CLAIM_MAX_ATTEMPTS", 2)
+        monkeypatch.setattr(bench, "CASCADE_CHILD_ATTEMPTS", 2)
         row = bench._measure_cascade("cpu")
         assert "error" in row
         assert len(calls) == 2  # attempts hard-capped, never unbounded
@@ -718,6 +718,6 @@ class TestBenchCascadeArm:
                                    stderr="boom\n")
 
         monkeypatch.setattr(bench.subprocess, "run", fake_run)
-        monkeypatch.setattr(bench, "CLAIM_MAX_ATTEMPTS", 1)
+        monkeypatch.setattr(bench, "CASCADE_CHILD_ATTEMPTS", 1)
         row = bench._measure_cascade("cpu")
         assert "error" in row and "rc=4" in row["error"]

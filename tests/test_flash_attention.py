@@ -82,3 +82,48 @@ class TestFlashKernel:
         np.testing.assert_allclose(
             np.asarray(out, dtype=np.float32), np.asarray(ref),
             atol=2e-2, rtol=2e-2)
+
+
+class TestDispatcher:
+    def test_traces_under_jit(self):
+        """The served programs call the dispatcher under jit, where q is
+        a tracer: reading its devices() raised on the first chip run."""
+        import jax
+
+        from semantic_router_tpu.ops.flash_attention import flash_attention
+
+        q, k, v = (rand(1, 2, 64, 16, seed=s) for s in (22, 23, 24))
+        out = jax.jit(lambda q, k, v: flash_attention(q, k, v, window=16))(
+            q, k, v)
+        ref = sdpa(q, k, v, bias=sliding_window_bias(64, 16))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
+
+    def test_sharded_over_dp_tp_matches_unsharded(self):
+        """engine.mesh serving: the kernel shard_mapped over (dp, tp) —
+        rows over dp, heads over tp — equals the kernel on the whole
+        batch (conftest gives 8 CPU devices; interpret mode)."""
+        import jax
+
+        from semantic_router_tpu.ops.flash_attention import (
+            flash_attention_sharded,
+        )
+        from semantic_router_tpu.parallel import create_mesh
+
+        mesh = create_mesh({"dp": 2, "tp": 2},
+                           devices=jax.devices()[:4])
+        q, k, v = (rand(4, 4, 64, 16, seed=s) for s in (25, 26, 27))
+        mask = jnp.asarray(np.concatenate(
+            [np.ones((4, 50)), np.zeros((4, 14))], 1), jnp.int32)
+        kw = dict(window=16, block_q=16, block_k=16, interpret=True)
+        got = jax.jit(lambda q, k, v, m: flash_attention_sharded(
+            q, k, v, m, mesh, **kw))(q, k, v, mask)
+        ref = flash_attention_pallas(q, k, v, mask, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=1e-6, rtol=1e-6)
+        # heads that tp does not divide stay whole on every tensor rank
+        q3, k3, v3 = (rand(4, 3, 64, 16, seed=s) for s in (28, 29, 30))
+        got = flash_attention_sharded(q3, k3, v3, mask, mesh, **kw)
+        ref = flash_attention_pallas(q3, k3, v3, mask, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=1e-6, rtol=1e-6)

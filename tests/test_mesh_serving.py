@@ -468,8 +468,8 @@ class TestMeshWiring:
     def test_head_bank_actually_sharded_on_task_axis(self):
         """tp shards the stacked bank on the TASK axis when the member
         count divides evenly — the PR 1 head_bank_specs follow-on,
-        measured on the CPU mesh (on-chip numbers ride the bench mesh
-        arm the first time a TPU claim grants)."""
+        measured on the CPU mesh (the four-chip path is
+        ``chip_smoke.py --multichip``)."""
         eng = make_engine(mesh={"enabled": True, "dp": 4, "tp": 2},
                           token=False, max_batch=4)
         try:

@@ -95,10 +95,14 @@ class TestPeakTable:
         assert tier["placeholder"] is True
         assert "placeholder" in tier["peak_note"]
 
-    def test_unknown_kind_falls_back_flagged(self):
+    def test_unknown_accelerator_has_no_peaks(self):
+        # an accelerator missing from the table is "unknown", never the
+        # CPU guess: zero peaks mean the catalog emits no fractions
         tier = peak_for("H100 SXM", "gpu")
-        assert tier["placeholder"] is True
-        assert tier["flops_per_s"] > 0 and tier["hbm_bytes_per_s"] > 0
+        assert tier["tier"] == "unknown"
+        assert "placeholder" not in tier
+        assert tier["flops_per_s"] == 0 and tier["hbm_bytes_per_s"] == 0
+        assert peak_for("TPU v9 future", "tpu")["tier"] == "unknown"
 
     def test_datasheet_notes_carry_provenance(self):
         for kind in ("v4", "v5e", "v5p", "v6e"):

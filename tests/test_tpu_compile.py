@@ -1,0 +1,263 @@
+"""The kernels and one whole served program, handed to the TPU compiler
+for a DESCRIBED v5e:2x2 (no chip attached; on-chip-measurement guide §2).
+
+Interpret-mode parity says nothing about what Mosaic accepts: before PR 21
+every kernel here passed its interpret tests and was refused by the chip's
+compiler at served shapes (block tiling, scoped VMEM).  These compiles keep
+that from coming back, at no chip time.
+
+The topology is described inside a module-scoped fixture — never at
+import, in a skipif or in parametrize arguments — because only one process
+may load the TPU library and every xdist worker imports every test file.
+All of these tests live in this one file for the same reason.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+HEADS, HEAD_DIM, HIDDEN = 12, 64, 768  # mmBERT-32K / ModernBERT-base
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compilation_cache():
+    """A compile for a described device can be written to the persistent
+    cache but not read back without a chip: keep it out of the way."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def compile_for(sharding, fn, *shapes):
+    """Lower + compile ``fn`` for the described chip; returns the
+    compiled executable (raises what the chip's compiler would raise)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+# padded batches 1..max_batch_size at the short buckets; the batches that
+# fit one chip's HBM at the long ones (32 x 32768 does not: see below)
+FLASH_SHAPES = [(b, s) for s in (128, 512) for b in (1, 2, 4, 8, 16, 32)] \
+    + [(1, 2048), (8, 2048), (32, 2048), (1, 8192), (4, 8192), (32, 8192),
+       (1, 32768), (2, 32768)]
+
+
+class TestFlashCompilesForV5e:
+    @pytest.mark.parametrize("batch,seq", FLASH_SHAPES)
+    def test_served_shape(self, one_chip, batch, seq):
+        """Every (bucket, padded batch) — including B=8 x S=128/512 (the
+        [B, Sp] key-bias block the compiler refused) and B=1 x S=32768
+        (whole-sequence K/V blocks: 32 MiB of a 16 MiB scoped VMEM)."""
+        from semantic_router_tpu.ops.flash_attention import (
+            flash_attention_pallas,
+        )
+
+        for dtype in (jnp.float32, jnp.bfloat16):
+            for window in (0, 128):
+                qkv = ((batch, HEADS, seq, HEAD_DIM), dtype)
+                compiled = compile_for(
+                    one_chip,
+                    functools.partial(flash_attention_pallas,
+                                      window=window, interpret=False),
+                    qkv, qkv, qkv, ((batch, seq), jnp.int32))
+                assert "tpu_custom_call" in compiled.as_text()
+
+    def test_full_batch_at_32k_exceeds_hbm_not_the_kernel(self, one_chip):
+        """max_batch_size 32 x bucket 32768 is a batch the default config
+        can form and one chip cannot hold: q/k/v alone need 24 GB.  The
+        refusal is HBM capacity — the batcher's to avoid — not tiling."""
+        from semantic_router_tpu.ops.flash_attention import (
+            flash_attention_pallas,
+        )
+
+        qkv = ((32, HEADS, 32768, HEAD_DIM), jnp.float32)
+        with pytest.raises(Exception, match="(?i)hbm"):
+            compile_for(one_chip,
+                        functools.partial(flash_attention_pallas,
+                                          interpret=False),
+                        qkv, qkv, qkv, ((32, 32768), jnp.int32))
+
+    def test_sharded_over_dp_tp_mesh(self, topo):
+        """Under engine.mesh GSPMD refuses to partition a Mosaic kernel;
+        flash_attention_sharded shard_maps it over (dp, tp)."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from semantic_router_tpu.ops.flash_attention import (
+            flash_attention_sharded,
+        )
+
+        mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+        qkv = jax.ShapeDtypeStruct(
+            (4, HEADS, 512, HEAD_DIM), jnp.float32,
+            sharding=NamedSharding(mesh, P("dp", "tp", None, None)))
+        mask = jax.ShapeDtypeStruct(
+            (4, 512), jnp.int32, sharding=NamedSharding(mesh, P("dp", None)))
+        compiled = jax.jit(functools.partial(
+            flash_attention_sharded, mesh=mesh, window=128,
+            interpret=False)).lower(qkv, qkv, qkv, mask).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+class TestEpilogueCompilesForV5e:
+    @pytest.mark.parametrize("tasks", [6, 1])
+    @pytest.mark.parametrize("rows", [1, 8, 32, 256, 300])
+    def test_served_shape(self, one_chip, tasks, rows):
+        """T=6 was refused: (1, H) bias blocks of [T, H] and (br, 1, H)
+        delta/out blocks of [rows, T, H]."""
+        from semantic_router_tpu.models.modernbert import activation
+        from semantic_router_tpu.ops.epilogue import head_epilogue_pallas
+
+        act = activation("gelu")  # what every ModernBERT head serves
+        x = ((rows, HIDDEN), jnp.float32)
+        w = ((tasks, HIDDEN, HIDDEN), jnp.float32)
+        b = ((tasks, HIDDEN), jnp.float32)
+        d = ((rows, tasks, HIDDEN), jnp.float32)
+        for with_bias, with_delta in ((False, False), (True, False),
+                                      (False, True), (True, True)):
+            shapes = [x, w] + ([b] if with_bias else []) \
+                + ([d] if with_delta else [])
+
+            def fn(x, w, *rest):
+                rest = list(rest)
+                bias = rest.pop(0) if with_bias else None
+                delta = rest.pop(0) if with_delta else None
+                return head_epilogue_pallas(x, w, bias, delta, act,
+                                            interpret=False)
+
+            compiled = compile_for(one_chip, fn, *shapes)
+            assert "tpu_custom_call" in compiled.as_text()
+
+    def test_unlowerable_activation_is_refused_loudly(self, one_chip):
+        """Mosaic lowers no erf/erfc: an ad-hoc exact GELU raises the
+        compiler's error instead of serving the reference under the
+        kernel's name (gelu_exact itself is swapped for an in-kernel
+        form)."""
+        from semantic_router_tpu.ops.epilogue import head_epilogue_pallas
+
+        with pytest.raises(NotImplementedError, match="erf"):
+            compile_for(
+                one_chip,
+                lambda x, w: head_epilogue_pallas(
+                    x, w, None, None,
+                    lambda h: jax.nn.gelu(h, approximate=False),
+                    interpret=False),
+                ((8, HIDDEN), jnp.float32),
+                ((6, HIDDEN, HIDDEN), jnp.float32))
+
+
+class TestBgmvCompilesForV5e:
+    @pytest.mark.parametrize("tasks", [8, 64])
+    @pytest.mark.parametrize("pairs", [1, 8, 16, 256])
+    def test_served_shape(self, one_chip, tasks, pairs):
+        """P > 1 was refused: (1, D) blocks of [P, D].  Both matmuls of
+        apply_head_bank_bgmv: the head dense and the label projection."""
+        from semantic_router_tpu.ops.bgmv import bgmv_pallas
+
+        for out_width in (HIDDEN, 16):
+            compiled = compile_for(
+                one_chip,
+                functools.partial(bgmv_pallas, interpret=False),
+                ((pairs, HIDDEN), jnp.float32),
+                ((tasks, HIDDEN, out_width), jnp.float32),
+                ((pairs,), jnp.int32))
+            assert "tpu_custom_call" in compiled.as_text()
+
+
+class TestWholeFusedStepCompilesForV5e:
+    def test_engine_program_b8_s512(self, one_chip, monkeypatch):
+        """The engine's own fused seq program (trunk + head bank) at the
+        published widths, B=8 x S=512, with the flash kernel in all 22
+        layers — and its memory, so a program that cannot fit 16 GB is
+        known before a chip call."""
+        import semantic_router_tpu.ops.flash_attention as fa
+        from semantic_router_tpu.config.schema import InferenceEngineConfig
+        from semantic_router_tpu.engine.classify import InferenceEngine
+        from semantic_router_tpu.models.modernbert import (
+            ModernBertConfig,
+            ModernBertForSequenceClassification,
+        )
+        from semantic_router_tpu.utils.tokenization import HashTokenizer
+
+        # steer the dispatcher's two platform reads to the described chip
+        # (jax.default_backend() is "cpu" here): in the test, not through
+        # an option of the program
+        monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+        monkeypatch.setattr(
+            fa, "flash_attention_pallas",
+            functools.partial(fa.flash_attention_pallas, interpret=False))
+
+        engine = InferenceEngine(InferenceEngineConfig())
+        try:
+            trunk = None
+            for name, n_labels in (("intent", 14), ("jailbreak", 2)):
+                module = ModernBertForSequenceClassification(
+                    ModernBertConfig(
+                        num_labels=n_labels,
+                        max_position_embeddings=32768,
+                        rope_scaling={
+                            "rope_type": "yarn", "factor": 4.0,
+                            "original_max_position_embeddings": 8192},
+                        attention_impl="flash"))
+                shapes = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                                        jnp.ones((1, 8), jnp.int32))
+                params = {"params": dict(jax.tree_util.tree_map(
+                    lambda s: np.zeros(s.shape, s.dtype),
+                    shapes)["params"])}
+                if trunk is None:
+                    trunk = params["params"]["model"]
+                params["params"]["model"] = trunk
+                engine.register_task(
+                    name, "sequence", module, params,
+                    HashTokenizer(vocab_size=50368),
+                    [str(i) for i in range(n_labels)])
+            (g,) = engine._groups_by_gid.values()
+            assert g.members == ["intent", "jailbreak"]
+
+            def abstract(tree):
+                return jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        np.shape(a), a.dtype, sharding=one_chip), tree)
+
+            ids = jax.ShapeDtypeStruct((8, 512), jnp.int32,
+                                       sharding=one_chip)
+            compiled = g.fns["seq"].lower(
+                abstract(g.fns["trunk_params"]),
+                abstract(g.demux["bank"]), ids, ids).compile()
+        finally:
+            engine.shutdown()
+        assert compiled.as_text().count("tpu_custom_call") == 22
+        mem = compiled.memory_analysis()
+        total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            + mem.output_size_in_bytes
+        assert 0.5 * 2**30 < total < 2 * 2**30  # ~0.57 GiB args + temps
