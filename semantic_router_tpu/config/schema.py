@@ -1260,12 +1260,14 @@ class RouterConfig:
     #   observability:
     #     tracing:
     #       otlp_endpoint: http://collector:4318   # OTLP/HTTP JSON export
-    #       sample_rate: 0.1       # fraction of traces with DETAILED
-    #                              # batch tracing (fenced per-stage
-    #                              # device timing); continuity spans
-    #                              # (batch.wait/ride + step links) are
-    #                              # never sampled away.  1.0 = every
-    #                              # trace pays the fences, 0 = none
+    #       sample_rate: 0.1       # fraction of traces that keep the
+    #                              # per-stage children of batch.ride
+    #                              # (stack, h2d, dispatch, readback,
+    #                              # demux: host timers around the one
+    #                              # program every step runs); continuity
+    #                              # spans (batch.wait/ride + step links)
+    #                              # are never sampled away.  1.0 = every
+    #                              # trace keeps them, 0 = none
     #     metrics:
     #       exemplars: true        # OpenMetrics trace-id exemplars on
     #                              # histogram buckets (opt-in)

@@ -236,16 +236,24 @@ class DynamicBatcher:
 
         return M.default_series
 
-    def _observe_batch(self, batch: List[BatchItem]) -> None:
+    def _observe_batch(self, key: Hashable,
+                       batch: List[BatchItem]) -> None:
         """Queue-wait + occupancy series per dispatched batch: the fused
         path's coalescing win must be *visible* (p99 wait vs fill ratio),
-        not inferred from end-to-end latency.  Runs on the single picker
-        thread, so it fails open — an observability error (e.g. a custom
-        metrics object missing these series) must never kill the loop
-        that all serving depends on."""
+        not inferred from end-to-end latency.  Each item's wait is also
+        an ``engine.queue_wait`` profiler annotation carrying its
+        request's trace id (observability.batchtrace.queue_wait), so a
+        profile joins an item to its ``router.route``.  Runs on the
+        single picker thread, so it fails open — an observability error
+        (e.g. a custom metrics object missing these series) must never
+        kill the loop that all serving depends on."""
         try:
+            from ..observability.batchtrace import queue_wait
+
             s = self._series()
             now = time.perf_counter()
+            group = ":".join(map(str, key)) if isinstance(key, tuple) \
+                else str(key)
             for item in batch:
                 # exemplar: the waiting request's trace id, so a slow
                 # queue-wait bucket links straight to the trace that
@@ -254,6 +262,7 @@ class DynamicBatcher:
                 s.batcher_queue_wait.observe(now - item.enqueue_t,
                                              exemplar=tid,
                                              batcher=self.name)
+                queue_wait(tid or "", group, now - item.enqueue_t)
             s.batcher_fill_ratio.observe(len(batch) / self.max_batch_size,
                                          batcher=self.name)
         except Exception:
@@ -401,7 +410,7 @@ class DynamicBatcher:
                 self._stats["max_inflight"] = max(
                     self._stats["max_inflight"],
                     sum(1 for v in self._inflight.values() if v > 0))
-            self._observe_batch(batch)
+            self._observe_batch(key, batch)
             try:
                 self._pool.submit(self._dispatch, self._cancel_batch,
                                   key, batch)

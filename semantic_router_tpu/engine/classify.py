@@ -235,12 +235,6 @@ class TrunkGroup:
     # reads ONE consistent view, so a concurrent re-registration can
     # never pair new row indices with old logits ordering
     demux: Any = None
-    # (trunk+pool fn, head-bank fn): the SAME math as apply_fn split in
-    # two jit programs so sampled batch traces can time the trunk forward
-    # and the head matmul separately (batchtrace stage fencing); compiles
-    # lazily on the first sampled batch of a shape — the untraced hot
-    # path never runs them
-    traced_fns: Any = None
     # the HOST trunk leaves whose id()s form this group's fingerprint:
     # retained so those ids can never be freed and recycled by a later
     # checkpoint load (a stale id-match would silently serve the wrong
@@ -959,28 +953,36 @@ class InferenceEngine:
             return trunk.apply({"params": trunk_params}, ids, mask,
                                position_ids=pos, segment_ids=seg)
 
+        # pool / heads / token_heads: named scopes beside the trunk's
+        # own (models/modernbert.py), so a device profile tells the head
+        # banks' ops from the trunk's — metadata only, same arithmetic
         def pool(hidden, mask):
-            return mean_pool(hidden, mask) if use_mean \
-                else cls_pool(hidden)
+            with jax.named_scope("pool"):
+                return mean_pool(hidden, mask) if use_mean \
+                    else cls_pool(hidden)
 
         def ppool(hidden, seg, seg_row, seg_start):
-            return packed_mean_pool(hidden, seg, seg_row.shape[0]) \
-                if use_mean else packed_cls_pool(hidden, seg_row,
-                                                 seg_start)
+            with jax.named_scope("pool"):
+                return packed_mean_pool(hidden, seg, seg_row.shape[0]) \
+                    if use_mean else packed_cls_pool(hidden, seg_row,
+                                                     seg_start)
 
         def seq_heads(bank, pooled, pair_rows=None, pair_tasks=None):
-            if bgmv:
-                return apply_head_bank_bgmv(bank, pooled, pair_rows,
-                                            pair_tasks, act,
-                                            cfg.norm_eps)
-            return apply_head_bank(bank, pooled, act, cfg.norm_eps,
-                                   epilogue=epilogue)
+            with jax.named_scope("heads"):
+                if bgmv:
+                    return apply_head_bank_bgmv(bank, pooled, pair_rows,
+                                                pair_tasks, act,
+                                                cfg.norm_eps)
+                return apply_head_bank(bank, pooled, act, cfg.norm_eps,
+                                       epilogue=epilogue)
 
         def tok_heads(tok_bank, hidden):
-            B, S, H = hidden.shape
-            flat = apply_head_bank(tok_bank, hidden.reshape(B * S, H),
-                                   act, cfg.norm_eps, epilogue=epilogue)
-            return flat.reshape(B, S, flat.shape[-2], flat.shape[-1])
+            with jax.named_scope("token_heads"):
+                B, S, H = hidden.shape
+                flat = apply_head_bank(tok_bank,
+                                       hidden.reshape(B * S, H), act,
+                                       cfg.norm_eps, epilogue=epilogue)
+                return flat.reshape(B, S, flat.shape[-2], flat.shape[-1])
 
         if bgmv:
             def seq_fn(trunk_params, bank, ids, mask, pr, pt):
@@ -1037,25 +1039,6 @@ class InferenceEngine:
                              hidden_fn(trunk_params, ids, mask, pos,
                                        seg))
 
-        if g.traced_fns is None:
-            # the fenced batch-trace split programs stay STOCK math
-            # (unquantized trunk, einsum heads): they only serve
-            # detailed sampled batches, which the runner gates on the
-            # stock meta so traced numbers describe what actually runs
-            stock_trunk = g.trunk_module
-
-            def trunk_pool(trunk_params, ids, mask):
-                h = stock_trunk.apply({"params": trunk_params}, ids,
-                                      mask)
-                return pool(h, mask)
-
-            def heads(bank, pooled):
-                return apply_head_bank(bank, pooled, act, cfg.norm_eps)
-
-            # jit() is free until called: sampled batch traces pay the
-            # split programs' compiles, untraced traffic never touches
-            # them
-            g.traced_fns = (jax.jit(trunk_pool), jax.jit(heads))
         return {
             "seq": jax.jit(seq_fn),
             "tok": jax.jit(tok_fn),
@@ -1864,18 +1847,6 @@ class InferenceEngine:
                             out = fns["both"](tp, bank, tok_bank,
                                               ids_dev, mask_dev, *pair)
                             jax.block_until_ready(out)
-                    if g.traced_fns is not None and bank is not None \
-                            and srv_mesh is None:
-                        # the split batch-trace programs (batchtrace
-                        # stage fencing) compile on the first SAMPLED
-                        # batch of a shape — warm them here too, or that
-                        # compile lands inline on the batcher's worker
-                        # thread (the exact SLO breach this warmup
-                        # exists to prevent)
-                        trunk_fn, head_fn = g.traced_fns
-                        pooled = trunk_fn(g.trunk_params, ids_dev,
-                                          mask_dev)
-                        jax.block_until_ready(head_fn(bank, pooled))
                     self._warm_packed(g, b)
 
                 warm(f"trunk:{g.gid}", b, n, warm_group)
@@ -2166,11 +2137,11 @@ class InferenceEngine:
 
     def _step_fresh(self, group: str, variant: str, shape: tuple) -> bool:
         """Compile detection for the step sampler, keyed per (group,
-        VARIANT, shape): the fused, fenced-split, and per-task paths are
-        distinct XLA programs, so a shape first seen by a sampled
-        detailed batch must still count the later fused first-execution
-        as a compile (shape_census stays variant-free — it budgets
-        device shapes, not programs)."""
+        VARIANT, shape): the fused flavours, the packed and the per-task
+        paths are distinct XLA programs, so a shape first seen by one
+        must still count another's first execution as a compile
+        (shape_census stays variant-free — it budgets device shapes,
+        not programs)."""
         key = (group, variant, *shape)
         with self._lock:
             fresh = key not in self._compiled_steps
@@ -2375,35 +2346,32 @@ class InferenceEngine:
         n = len(items)
         padded_n = self._padded_batch(n)
 
-        # named profiler regions: the XLA timeline lines up with router
-        # semantics when a trace is being captured (observability.profiler)
         from ..observability import batchtrace
-        from ..observability.profiler import trace_span
 
-        # request-trace continuity across the batching boundary: one
-        # batch.execute step span when any item carries a trace, else
-        # None and the hot path pays a single list scan.  Opened BEFORE
+        # one step instrument (observability.batchtrace): the engine.step
+        # profiler annotation around the five stage annotations, and —
+        # when an item carries a request trace — the batch.execute step
+        # span with each request's batch.wait/ride spans.  Opened BEFORE
         # host stacking so the per-request batch.wait span ends where
         # queue wait actually ends — stacking/H2D time belongs to the
         # step, not to phantom queue congestion.
         step = batchtrace.start_step(
             items, group=f"task:{task_name}", bucket=bucket,
             max_batch=self.cfg.max_batch_size, padded_rows=padded_n,
-            kind=t.kind)
+            kind=t.kind,
+            flavour="embed" if t.kind == "embedding" else task_name,
+            tokens_real=_tokens_real(items, bucket))
         try:
-            # batchtrace.stage() no-ops unless the step's trace is
-            # sampled — non-detailed traced batches still get the step +
-            # ride continuity spans from finish()
-            with batchtrace.stage(step, "stack"):
+            with step.stage("stack"):
                 ids, mask, clipped = self._stack_items(
                     items, bucket, padded_n, t.pad_id, task_name)
+            with step.stage("h2d"):
                 ids_dev, mask_dev = self._to_device(ids, mask)
             # fresh (group, variant, shape) == one XLA compile: the
             # runtime-stats sampler accounts the cold step separately
             self._note_shape(f"task:{task_name}", (padded_n, bucket))
             fresh = self._step_fresh(f"task:{task_name}", "split",
                                      (padded_n, bucket))
-            fwd_cm = batchtrace.stage(step, "trunk_forward")
 
             if t.kind == "embedding":
                 p = items[0].payload
@@ -2415,17 +2383,19 @@ class InferenceEngine:
                         kwargs={"exit_layer": p.exit_layer,
                                 "output_dim": p.output_dim})
                 fwd_t0 = time.perf_counter()
-                with trace_span(f"engine.embed.{t.name}"), fwd_cm:
+                with step.stage("dispatch"):
                     emb = t.apply_fn(t.params, ids_dev, mask_dev,
                                      exit_layer=p.exit_layer,
                                      output_dim=p.output_dim)
+                with step.stage("readback"):
                     emb = np.asarray(jax.device_get(emb), dtype=np.float32)
                 self._record_step(f"task:{task_name}", bucket, "split",
                                   n, padded_n,
                                   time.perf_counter() - fwd_t0, fresh)
                 self._series().trunk_forwards.inc(group=task_name,
                                                   path="traditional")
-                return [emb[i] for i in range(n)]
+                with step.stage("demux"):
+                    return [emb[i] for i in range(n)]
 
             if fresh:
                 self._capture_program(
@@ -2433,8 +2403,9 @@ class InferenceEngine:
                     (padded_n, bucket), t.apply_fn,
                     (t.params, ids_dev, mask_dev), "split")
             fwd_t0 = time.perf_counter()
-            with trace_span(f"engine.classify.{t.name}"), fwd_cm:
+            with step.stage("dispatch"):
                 logits = t.apply_fn(t.params, ids_dev, mask_dev)
+            with step.stage("readback"):
                 logits = np.asarray(jax.device_get(logits),
                                     dtype=np.float32)
             self._record_step(f"task:{task_name}", bucket, "split",
@@ -2443,7 +2414,7 @@ class InferenceEngine:
             self._series().trunk_forwards.inc(group=task_name,
                                               path="traditional")
 
-            demux_cm = batchtrace.stage(step, "demux")
+            demux_cm = step.stage("demux")
             now = time.perf_counter()
             if t.kind == "sequence":
                 with demux_cm:
@@ -2490,8 +2461,7 @@ class InferenceEngine:
         finally:
             # failing batches are exactly the ones traces must explain:
             # the step + ride spans emit even when the forward raised
-            if step is not None:
-                step.finish()
+            step.finish()
 
     def _run_fused_batch(self, gid: str, bucket: int,
                          items: List[BatchItem]) -> Sequence[Any]:
@@ -2693,6 +2663,24 @@ class InferenceEngine:
         return per_task[item.payload.tasks[0]] \
             if len(item.payload.tasks) == 1 else per_task
 
+    @staticmethod
+    def _run_fused_program(step, fn, args: tuple, flavor: str):
+        """One fused program's ``dispatch`` and ``readback`` stages:
+        (sequence logits, token logits) on the host as float32, None for
+        the bank the flavour does not run."""
+        with step.stage("dispatch"):
+            res = fn(*args)
+        seq_logits, tok_logits = (res, None) if flavor == "seq" else \
+            (None, res) if flavor == "tok" else res
+        with step.stage("readback"):
+            if seq_logits is not None:
+                seq_logits = np.asarray(jax.device_get(seq_logits),
+                                        dtype=np.float32)
+            if tok_logits is not None:
+                tok_logits = np.asarray(jax.device_get(tok_logits),
+                                        dtype=np.float32)
+        return seq_logits, tok_logits
+
     def _run_fused_unpacked(self, g: TrunkGroup, gid: str, bucket: int,
                             items: List[BatchItem], urow: List[int],
                             uniq_items: List[BatchItem], demux: dict,
@@ -2711,38 +2699,30 @@ class InferenceEngine:
         # step time reads straight off /debug/runtime)
         msfx = mesh_suffix(meta.get("mesh"))
         use_bgmv = meta["bgmv"] and flavor in ("seq", "both")
-        pr_dev = pt_dev = pair_index = None
+        pr = pt = pr_dev = pt_dev = pair_index = None
         pair_sfx = ""
         if use_bgmv:
             pr, pt, pair_index = self._bgmv_pairs(items, urow, demux)
-            pr_dev, pt_dev = jnp.asarray(pr), jnp.asarray(pt)
             # the padded pair count is its own static program dimension
             pair_sfx = f":p{pr.shape[0]}"
+        tokens_real = _tokens_real(uniq_items, bucket)
 
         from ..observability import batchtrace
-        from ..observability.profiler import trace_span
 
-        # cross-batch trace propagation (observability.batchtrace): a
-        # traced batch gets one batch.execute step span and each
+        # one step instrument (observability.batchtrace): engine.step
+        # around the five stage annotations on the profiler's clock; a
+        # traced batch also gets one batch.execute step span and each
         # originating request's trace receives batch.wait/tokenize/ride
-        # spans linked to it; a SAMPLED batch additionally runs the same
-        # math as two fenced jit programs so trunk forward vs head
-        # matmul time attribute separately.  Untraced batches take the
-        # single fused call unchanged.  Opened BEFORE host stacking so
-        # batch.wait measures only queue time, not stacking/H2D.
+        # spans linked to it (a sampled one, the stages as children).
+        # Every batch runs the same program.  Opened BEFORE host stacking
+        # so batch.wait measures only queue time, not stacking/H2D.
         step = batchtrace.start_step(
             items, group=f"trunk:{gid}", bucket=bucket,
             max_batch=self.cfg.max_batch_size, padded_rows=padded_n,
-            kind="fused")
+            kind="fused", flavour=flavor, rows=n_rows,
+            tokens_real=tokens_real)
         try:
-            # detailed (fenced-split) sampling only describes the STOCK
-            # programs: with a kernel/quant/mesh knob live, the split
-            # programs would time math the serving path no longer runs
-            detailed = step is not None and step.detailed \
-                and g.traced_fns is not None and flavor == "seq" \
-                and meta["quant"] == "off" and not meta["epilogue"] \
-                and not use_bgmv and srv_mesh is None
-            with batchtrace.stage(step, "stack"):
+            with step.stage("stack"):
                 ids, mask, clipped = self._stack_items(uniq_items,
                                                        bucket,
                                                        padded_n, g.pad_id)
@@ -2750,79 +2730,36 @@ class InferenceEngine:
                     if clipped[urow[i]]:
                         for task in item.payload.tasks:
                             self._series().bucket_overflows.inc(task=task)
+            with step.stage("h2d"):
                 ids_dev, mask_dev = self._to_device(ids, mask,
                                                     mesh=srv_mesh)
+                if use_bgmv:
+                    pr_dev, pt_dev = jnp.asarray(pr), jnp.asarray(pt)
             self._note_shape(f"trunk:{gid}", (padded_n, bucket))
-            variant = "fused_detailed" if detailed else \
-                ("fused_mesh" if srv_mesh is not None else "fused")
+            variant = "fused_mesh" if srv_mesh is not None else "fused"
             fresh = self._step_fresh(f"trunk:{gid}",
                                      f"{variant}:{flavor}{pair_sfx}"
                                      f"{msfx}",
                                      (padded_n, bucket))
-            if fresh and not detailed:
-                # fenced-split detailed programs are a sampling artifact,
-                # not a serving program — the cost catalog only carries
-                # what the hot path runs
-                if flavor == "seq":
-                    cap_fn = fns["seq"]
-                    cap_args = (tparams, bank, ids_dev, mask_dev)
-                    if use_bgmv:
-                        cap_args += (pr_dev, pt_dev)
-                elif flavor == "tok":
-                    cap_fn = fns["tok"]
-                    cap_args = (tparams, tok_bank, ids_dev, mask_dev)
-                else:
-                    cap_fn = fns["both"]
-                    cap_args = (tparams, bank, tok_bank, ids_dev,
-                                mask_dev)
-                    if use_bgmv:
-                        cap_args += (pr_dev, pt_dev)
+            if flavor == "seq":
+                fn = fns["seq"]
+                args = (tparams, bank, ids_dev, mask_dev)
+            elif flavor == "tok":
+                fn = fns["tok"]
+                args = (tparams, tok_bank, ids_dev, mask_dev)
+            else:
+                fn = fns["both"]
+                args = (tparams, bank, tok_bank, ids_dev, mask_dev)
+            if use_bgmv:
+                args += (pr_dev, pt_dev)
+            if fresh:
                 self._capture_program(
                     f"trunk:{gid}", bucket,
                     f"{variant}:{flavor}{pair_sfx}{msfx}",
-                    (padded_n, bucket), cap_fn, cap_args, variant, meta)
-            tokens_real = sum(min(len(it.payload.encoding), bucket)
-                              for it in uniq_items)
-            seq_logits = tok_logits = None
+                    (padded_n, bucket), fn, args, variant, meta)
             fwd_t0 = time.perf_counter()
-            with trace_span(f"engine.classify.fused.{gid}"):
-                if detailed:
-                    # sampled: the SAME math split in two fenced programs
-                    # so trunk vs head time attribute separately
-                    trunk_fn, head_fn = g.traced_fns
-                    with step.stage("trunk_forward"):
-                        pooled = trunk_fn(g.trunk_params, ids_dev,
-                                          mask_dev)
-                        step.fence(pooled)
-                    with step.stage("head_matmul"):
-                        seq_logits = head_fn(bank, pooled)
-                        step.fence(seq_logits)
-                elif flavor == "seq":
-                    # the default hot path: one fused program, no fences
-                    # (non-detailed traced batches still get step + ride
-                    # continuity spans from finish())
-                    args = (tparams, bank, ids_dev, mask_dev)
-                    if use_bgmv:
-                        args += (pr_dev, pt_dev)
-                    seq_logits = fns["seq"](*args)
-                elif flavor == "tok":
-                    tok_logits = fns["tok"](tparams, tok_bank,
-                                            ids_dev, mask_dev)
-                else:
-                    args = (tparams, bank, tok_bank, ids_dev, mask_dev)
-                    if use_bgmv:
-                        args += (pr_dev, pt_dev)
-                    seq_logits, tok_logits = fns["both"](*args)
-                if seq_logits is not None:
-                    seq_logits = np.asarray(jax.device_get(seq_logits),
-                                            dtype=np.float32)
-                if tok_logits is not None:
-                    tok_logits = np.asarray(jax.device_get(tok_logits),
-                                            dtype=np.float32)
-            # detailed (sampled-trace) batches ran the fenced split
-            # programs — slower by construction — so they get their own
-            # variant key instead of polluting the warm-execute EWMA the
-            # dashboards (and the path-chooser cost model) read
+            seq_logits, tok_logits = self._run_fused_program(
+                step, fn, args, flavor)
             self._record_step(f"trunk:{gid}", bucket, variant,
                               n_rows, padded_n,
                               time.perf_counter() - fwd_t0, fresh,
@@ -2834,10 +2771,9 @@ class InferenceEngine:
                 self._series().mesh_steps.inc(group=gid)
             self._count_kernel_step(gid, meta, use_bgmv)
 
-            demux_cm = batchtrace.stage(step, "demux")
             now = time.perf_counter()
             out: List[Any] = []
-            with demux_cm:
+            with step.stage("demux"):
                 for i, item in enumerate(items):
                     enc = item.payload.encoding
                     L = min(len(enc), bucket)
@@ -2871,8 +2807,7 @@ class InferenceEngine:
                     out.append(self._fused_result(item, per_task))
             return out
         finally:
-            if step is not None:
-                step.finish()
+            step.finish()
 
     def _run_fused_packed(self, g: TrunkGroup, gid: str, bucket: int,
                           items: List[BatchItem], urow: List[int],
@@ -2896,24 +2831,23 @@ class InferenceEngine:
         tparams = fns["trunk_params"]
         msfx = mesh_suffix(meta.get("mesh"))
         use_bgmv = meta["bgmv"] and flavor in ("seq", "both")
-        pr_dev = pt_dev = pair_index = None
+        pr = pt = pr_dev = pt_dev = pair_index = None
         pair_sfx = ""
         if use_bgmv:
             # packed pairs index SEGMENTS: the packed pool emits one
             # pooled row per segment, and urow is the segment index
             pr, pt, pair_index = self._bgmv_pairs(items, urow, demux)
-            pr_dev, pt_dev = jnp.asarray(pr), jnp.asarray(pt)
             pair_sfx = f":p{pr.shape[0]}"
 
         from ..observability import batchtrace
-        from ..observability.profiler import trace_span
 
         step = batchtrace.start_step(
             items, group=f"trunk:{gid}", bucket=bucket,
             max_batch=self.cfg.max_batch_size, padded_rows=padded_rows,
-            kind="fused")
+            kind="fused", flavour=flavor, rows=plan_rows,
+            tokens_real=_tokens_real(uniq_items, bucket))
         try:
-            with batchtrace.stage(step, "stack"):
+            with step.stage("stack"):
                 dp = int(srv_mesh.shape.get("dp", 1)) \
                     if srv_mesh is not None else 1
                 pb = pack_items(
@@ -2926,6 +2860,7 @@ class InferenceEngine:
                     if clipped[urow[i]]:
                         for task in item.payload.tasks:
                             self._series().bucket_overflows.inc(task=task)
+            with step.stage("h2d"):
                 ids_dev, mask_dev = self._to_device(pb.ids, pb.mask,
                                                     mesh=srv_mesh)
                 if srv_mesh is not None:
@@ -2946,7 +2881,9 @@ class InferenceEngine:
                     seg_dev = jnp.asarray(pb.segment_ids)
                     seg_row = jnp.asarray(pb.seg_row)
                     seg_start = jnp.asarray(pb.seg_start)
-            if step is not None:
+                if use_bgmv:
+                    pr_dev, pt_dev = jnp.asarray(pr), jnp.asarray(pt)
+            if step.traced:
                 # packed-step span attributes: the trace shows HOW
                 # packed this step ran, next to the existing batch
                 # size/fill attributes
@@ -2963,56 +2900,30 @@ class InferenceEngine:
                                      f"packed:{flavor}:{k_pad}"
                                      f"{pair_sfx}{msfx}",
                                      (padded_rows, bucket))
+            if flavor == "seq":
+                fn = fns["packed_seq"]
+                args = (tparams, bank, ids_dev, mask_dev,
+                        pos_dev, seg_dev, seg_row, seg_start)
+            elif flavor == "tok":
+                fn = fns["packed_tok"]
+                args = (tparams, tok_bank, ids_dev, mask_dev,
+                        pos_dev, seg_dev)
+            else:
+                fn = fns["packed_both"]
+                args = (tparams, bank, tok_bank, ids_dev, mask_dev,
+                        pos_dev, seg_dev, seg_row, seg_start)
+            if use_bgmv:
+                args += (pr_dev, pt_dev)
             if fresh:
-                if flavor == "seq":
-                    cap_fn = fns["packed_seq"]
-                    cap_args = (tparams, bank, ids_dev, mask_dev,
-                                pos_dev, seg_dev, seg_row, seg_start)
-                    if use_bgmv:
-                        cap_args += (pr_dev, pt_dev)
-                elif flavor == "tok":
-                    cap_fn = fns["packed_tok"]
-                    cap_args = (tparams, tok_bank, ids_dev, mask_dev,
-                                pos_dev, seg_dev)
-                else:
-                    cap_fn = fns["packed_both"]
-                    cap_args = (tparams, bank, tok_bank, ids_dev,
-                                mask_dev, pos_dev, seg_dev, seg_row,
-                                seg_start)
-                    if use_bgmv:
-                        cap_args += (pr_dev, pt_dev)
                 self._capture_program(
                     f"trunk:{gid}", bucket,
                     f"packed:{flavor}:{k_pad}{pair_sfx}{msfx}",
-                    (padded_rows, bucket), cap_fn, cap_args,
+                    (padded_rows, bucket), fn, args,
                     "packed_mesh" if srv_mesh is not None else "packed",
                     meta)
-            seq_logits = tok_logits = None
             fwd_t0 = time.perf_counter()
-            with trace_span(f"engine.classify.packed.{gid}"):
-                if flavor == "seq":
-                    args = (tparams, bank, ids_dev, mask_dev,
-                            pos_dev, seg_dev, seg_row, seg_start)
-                    if use_bgmv:
-                        args += (pr_dev, pt_dev)
-                    seq_logits = fns["packed_seq"](*args)
-                elif flavor == "tok":
-                    tok_logits = fns["packed_tok"](
-                        tparams, tok_bank, ids_dev, mask_dev,
-                        pos_dev, seg_dev)
-                else:
-                    args = (tparams, bank, tok_bank, ids_dev,
-                            mask_dev, pos_dev, seg_dev, seg_row,
-                            seg_start)
-                    if use_bgmv:
-                        args += (pr_dev, pt_dev)
-                    seq_logits, tok_logits = fns["packed_both"](*args)
-                if seq_logits is not None:
-                    seq_logits = np.asarray(jax.device_get(seq_logits),
-                                            dtype=np.float32)
-                if tok_logits is not None:
-                    tok_logits = np.asarray(jax.device_get(tok_logits),
-                                            dtype=np.float32)
+            seq_logits, tok_logits = self._run_fused_program(
+                step, fn, args, flavor)
             self._record_step(f"trunk:{gid}", bucket,
                               "packed_mesh" if srv_mesh is not None
                               else "packed",
@@ -3032,10 +2943,9 @@ class InferenceEngine:
                 self._series().mesh_steps.inc(group=gid)
             self._count_kernel_step(gid, meta, use_bgmv)
 
-            demux_cm = batchtrace.stage(step, "demux")
             now = time.perf_counter()
             out: List[Any] = []
-            with demux_cm:
+            with step.stage("demux"):
                 for i, item in enumerate(items):
                     enc = item.payload.encoding
                     seg = pb.segments[urow[i]]
@@ -3066,8 +2976,13 @@ class InferenceEngine:
                     out.append(self._fused_result(item, per_task))
             return out
         finally:
-            if step is not None:
-                step.finish()
+            step.finish()
+
+
+def _tokens_real(items: Sequence[BatchItem], bucket: int) -> int:
+    """Unpadded tokens a step carries: each item's encoding, clipped to
+    the bucket."""
+    return sum(min(len(it.payload.encoding), bucket) for it in items)
 
 
 def _softmax(x: np.ndarray) -> np.ndarray:

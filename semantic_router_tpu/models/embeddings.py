@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from ..ops.attention import cls_pool, mean_pool
@@ -40,9 +41,11 @@ class MmBertEmbeddingModel(nn.Module):
             attention_mask = jnp.ones_like(input_ids)
         hidden = ModernBertModel(cfg, name="model")(
             input_ids, attention_mask, exit_layer=exit_layer)
-        pooled = (cls_pool(hidden) if self.pooling == "cls"
-                  else mean_pool(hidden, attention_mask))
-        for i, dim in enumerate(self.bottleneck_dims):
-            pooled = nn.Dense(dim, use_bias=False, name=f"dense_{i}",
-                              dtype=cfg.dtype)(pooled)
-        return truncate_normalize(pooled, output_dim).astype(cfg.dtype)
+        with jax.named_scope("pool"):
+            pooled = (cls_pool(hidden) if self.pooling == "cls"
+                      else mean_pool(hidden, attention_mask))
+            for i, dim in enumerate(self.bottleneck_dims):
+                pooled = nn.Dense(dim, use_bias=False, name=f"dense_{i}",
+                                  dtype=cfg.dtype)(pooled)
+        with jax.named_scope("matryoshka"):
+            return truncate_normalize(pooled, output_dim).astype(cfg.dtype)

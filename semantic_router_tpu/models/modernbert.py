@@ -341,17 +341,26 @@ class ModernBertModel(nn.Module):
         cfg = self.config
         if attention_mask is None:
             attention_mask = jnp.ones_like(input_ids)
-        x = ModernBertEmbeddings(cfg, name="embeddings")(input_ids)
+        # named scopes are metadata on the HLO (every output is bit-
+        # identical): a device profile's ops read embed_tokens/… or
+        # trunk/layers_<i>/{attn,mlp}/… (the inner names are the flax
+        # modules' own), which is how trunk time is told from head time
+        with jax.named_scope("embed_tokens"):
+            x = ModernBertEmbeddings(cfg, name="embeddings")(input_ids)
         n_layers = cfg.num_hidden_layers if exit_layer is None \
             else min(exit_layer, cfg.num_hidden_layers)
-        for i in range(cfg.num_hidden_layers):
-            if i >= n_layers:
-                break  # Matryoshka layer early-exit (static under jit)
-            x = ModernBertEncoderLayer(cfg, i, name=f"layers_{i}",
-                                       dense_factory=self.dense_factory)(
-                x, attention_mask, task_index, position_ids, segment_ids)
-        return nn.LayerNorm(epsilon=cfg.norm_eps, use_bias=cfg.norm_bias,
-                            name="final_norm", dtype=cfg.dtype)(x)
+        with jax.named_scope("trunk"):
+            for i in range(cfg.num_hidden_layers):
+                if i >= n_layers:
+                    break  # Matryoshka layer early-exit (static under jit)
+                x = ModernBertEncoderLayer(
+                    cfg, i, name=f"layers_{i}",
+                    dense_factory=self.dense_factory)(
+                    x, attention_mask, task_index, position_ids,
+                    segment_ids)
+            return nn.LayerNorm(epsilon=cfg.norm_eps,
+                                use_bias=cfg.norm_bias, name="final_norm",
+                                dtype=cfg.dtype)(x)
 
 
 class ModernBertPredictionHead(nn.Module):
@@ -381,13 +390,15 @@ class ModernBertForSequenceClassification(nn.Module):
         if attention_mask is None:
             attention_mask = jnp.ones_like(input_ids)
         hidden = ModernBertModel(cfg, name="model")(input_ids, attention_mask)
-        if cfg.classifier_pooling == "mean":
-            pooled = mean_pool(hidden, attention_mask)
-        else:
-            pooled = cls_pool(hidden)
-        pooled = ModernBertPredictionHead(cfg, name="head")(pooled)
-        return nn.Dense(cfg.num_labels, use_bias=True, name="classifier",
-                        dtype=cfg.dtype)(pooled)
+        with jax.named_scope("pool"):
+            if cfg.classifier_pooling == "mean":
+                pooled = mean_pool(hidden, attention_mask)
+            else:
+                pooled = cls_pool(hidden)
+        with jax.named_scope("heads"):
+            pooled = ModernBertPredictionHead(cfg, name="head")(pooled)
+            return nn.Dense(cfg.num_labels, use_bias=True,
+                            name="classifier", dtype=cfg.dtype)(pooled)
 
 
 class ModernBertForTokenClassification(nn.Module):
@@ -404,6 +415,8 @@ class ModernBertForTokenClassification(nn.Module):
         if attention_mask is None:
             attention_mask = jnp.ones_like(input_ids)
         hidden = ModernBertModel(cfg, name="model")(input_ids, attention_mask)
-        hidden = ModernBertPredictionHead(cfg, name="head")(hidden)
-        return nn.Dense(cfg.num_labels, use_bias=True, name="classifier",
-                        dtype=cfg.dtype)(hidden)  # [B, S, num_labels]
+        with jax.named_scope("token_heads"):
+            hidden = ModernBertPredictionHead(cfg, name="head")(hidden)
+            return nn.Dense(cfg.num_labels, use_bias=True,
+                            name="classifier",
+                            dtype=cfg.dtype)(hidden)  # [B, S, num_labels]

@@ -21,24 +21,37 @@ the batch iteration it rode in:
    ``batch.wait`` (enqueue → dispatch), ``batch.tokenize`` (host encode
    or EncodingCache hit), and ``batch.ride`` (dispatch → results)
    children, the ride span carrying an OTLP span *link* to the shared
-   step span, plus per-stage child spans (trunk forward, head matmul,
-   demux) so tail latency decomposes per request.
+   step span, plus per-stage child spans (stack, h2d, dispatch,
+   readback, demux) so tail latency decomposes per request.
 
-4. **Two-tier cost model** — a batch with no traced item skips the step
-   entirely (one list scan, no spans).  Traced items always get the
-   continuity spans above (cheap host-side bookkeeping), but the
-   *detailed* per-stage attribution — the fenced two-call (trunk, heads)
-   execution with ``jax.block_until_ready`` between stages — only runs
-   when a trace is SAMPLED (``Tracer.sample_rate``, default 10%), so the
-   expensive device syncs never become the default hot path.
+4. **One program, two clocks** — every step, traced or not, sampled or
+   not, runs the SAME jitted program with no device sync but the
+   readback it needs anyway.  ``BatchStep.stage()`` times the five host
+   stages of a step (``stack``, ``h2d``, ``dispatch``, ``readback``,
+   ``demux``) in two places at once:
 
-Known tradeoff: the sampled split execution is the same math as the
-fused program but a different XLA compilation, so its logits can differ
-at float-epsilon order (different fusion/accumulation order).  An
-argmax on an exact near-tie could in principle flip with sampling; the
-engine's warmup pre-compiles the split programs so the cost difference
-is fences only, and the parity tests hold both paths to the same 1e-4
-tolerance.
+   * as ``jax.profiler.TraceAnnotation`` spans (``engine.step`` around
+     ``engine.step.<stage>``, constant names, the step's facts as
+     keyword arguments), which land on the profiler's host plane beside
+     the device's op timeline when an operator has a profiler session
+     running (``/debug/profiler/start``) and cost an object and a flag
+     test when none is — nothing is encoded;
+   * as ``batch.<stage>`` child spans under ``batch.ride`` in a request's
+     own trace when that trace is SAMPLED (``Tracer.sample_rate``,
+     default 10%).  Unsampled traces keep the continuity spans above.
+
+   A batch with no traced item emits no request span at all.  The batcher
+   adds ``engine.queue_wait`` (one per dispatched item: ``trace_id``,
+   ``group``, ``wait_us``) and the router ``router.route`` around a route
+   and ``router.route.done`` at its end (``trace_id``, ``route_us``), so
+   a profile joins an item to its route by id.
+
+Known tradeoff: the host stages see the device only through the readback
+wait — ``dispatch`` is the enqueue, ``readback`` holds the program's whole
+device time plus the transfer.  Trunk against heads is not a host stage:
+it is read from the device timeline, where ``jax.named_scope`` names the
+ops (``trunk``, ``pool``, ``heads``, ``token_heads``: models/modernbert.py,
+engine/classify.py).
 """
 
 from __future__ import annotations
@@ -48,6 +61,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
+from .profiler import trace_span
 from .tracing import Span, Tracer, active_span, new_span_id, new_trace_id
 
 STEP_SPAN = "batch.execute"
@@ -55,6 +69,13 @@ RIDE_SPAN = "batch.ride"
 WAIT_SPAN = "batch.wait"
 TOKENIZE_SPAN = "batch.tokenize"
 STAGE_PREFIX = "batch."
+# the profiler-clock names (constant: facts ride as keyword arguments)
+STEP_ANNOTATION = "engine.step"
+STAGES = ("stack", "h2d", "dispatch", "readback", "demux")
+STAGE_ANNOTATIONS = {n: f"{STEP_ANNOTATION}.{n}" for n in STAGES}
+QUEUE_WAIT_ANNOTATION = "engine.queue_wait"
+ROUTE_ANNOTATION = "router.route"
+ROUTE_DONE_ANNOTATION = "router.route.done"
 
 
 @dataclass
@@ -121,18 +142,41 @@ def _mk_span(name: str, trace_id: str, parent_id: str,
     return s
 
 
-class BatchStep:
-    """One device step's tracing state: stage timers + the traced items.
+class _Stage:
+    """One stage of a step, on both clocks: the profiler annotation
+    always, the ``(name, t0, t1)`` pair only for a sampled step."""
 
-    Created by ``start_step`` only when ≥1 item carries a trace context;
-    ``detailed`` is True when any of those traces is sampled — the
-    runner gates the fenced split-program stage timing on it.  The
-    runner times stages through ``stage()``/``fence()`` and ``finish()``
-    emits the step span plus every per-request wait/tokenize/ride span
-    tree (call it in a ``finally`` so failing batches still trace)."""
+    __slots__ = ("_step", "_name", "_ann", "_t0")
+
+    def __init__(self, step: "BatchStep", name: str) -> None:
+        self._step, self._name = step, name
+        self._ann = trace_span(STAGE_ANNOTATIONS[name])
+
+    def __enter__(self) -> None:
+        self._ann.__enter__()
+        if self._step.detailed:
+            self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        if self._step.detailed:
+            self._step.stages.append(
+                (self._name, self._t0, time.perf_counter()))
+        self._ann.__exit__(*exc)
+
+
+class BatchStep:
+    """One device step's tracing state, from ``start_step`` to
+    ``finish()``: the ``engine.step`` profiler annotation (every step),
+    and, when ≥1 item carries a trace context, the step span plus every
+    per-request wait/tokenize/ride span tree that ``finish()`` emits
+    (call it in a ``finally`` so failing batches still trace).
+    ``detailed`` is True when any of those traces is sampled: then
+    ``stage()`` also keeps the host stage pairs that become
+    ``batch.<stage>`` children of the sampled items' ride spans."""
 
     def __init__(self, name: str, traced: List[Tuple[Any, TraceContext]],
-                 attrs: Dict[str, Any], detailed: bool = True) -> None:
+                 attrs: Dict[str, Any], detailed: bool = True,
+                 facts: Optional[Dict[str, Any]] = None) -> None:
         self.trace_id = new_trace_id()
         self.span_id = new_span_id()
         self.name = name
@@ -142,30 +186,20 @@ class BatchStep:
         self.start_pc = time.perf_counter()
         self.stages: List[Tuple[str, float, float]] = []
         self._finished = False
+        self._ann = trace_span(STEP_ANNOTATION, **(facts or {}))
+        self._ann.__enter__()
 
-    @contextlib.contextmanager
-    def stage(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.stages.append((name, t0, time.perf_counter()))
-
-    def fence(self, value) -> None:
-        """Block until the device finishes ``value`` so the enclosing
-        stage's wall-clock is device time, not dispatch time.  Only ever
-        called on the sampled path — the untraced path never syncs."""
-        try:
-            import jax
-
-            jax.block_until_ready(value)
-        except Exception:
-            pass
+    def stage(self, name: str) -> _Stage:
+        """``with step.stage("h2d"): ...`` — one of ``STAGES``."""
+        return _Stage(self, name)
 
     def finish(self) -> None:
         if self._finished:  # idempotent: callers run it in a finally
             return
         self._finished = True
+        self._ann.__exit__(None, None, None)
+        if not self.traced:
+            return
         end_pc = time.perf_counter()
         offset = time.time() - time.perf_counter()
         stage_attrs = {f"stage.{n}_ms": round((t1 - t0) * 1e3, 3)
@@ -199,33 +233,33 @@ class BatchStep:
             ride = _mk_span(RIDE_SPAN, ctx.trace_id, ctx.span_id,
                             self.start_pc, end_pc, offset, **self.attrs)
             ride.add_link(self.trace_id, self.span_id)
-            for n, t0, t1 in self.stages:
-                ctx.tracer.record(_mk_span(
-                    STAGE_PREFIX + n, ctx.trace_id, ride.span_id,
-                    t0, t1, offset))
+            if ctx.sampled:
+                for n, t0, t1 in self.stages:
+                    ctx.tracer.record(_mk_span(
+                        STAGE_PREFIX + n, ctx.trace_id, ride.span_id,
+                        t0, t1, offset))
             ctx.tracer.record(ride)
 
 
-def stage(step: Optional[BatchStep], name: str):
-    """Stage guard for the batch runners: records a timed stage only
-    when the step exists AND its trace is sampled (detailed) — one
-    helper instead of the same conditional at every call site."""
-    if step is None or not step.detailed:
-        return contextlib.nullcontext()
-    return step.stage(name)
-
-
 def start_step(items, *, group: str, bucket: int, max_batch: int,
-               padded_rows: int, kind: str = "fused",
-               name: str = STEP_SPAN) -> Optional[BatchStep]:
-    """Open per-step tracing iff any batch item carries a trace context;
-    the common untraced case is one list scan and a None.  The step is
-    ``detailed`` (fenced per-stage timing) only when some traced item's
+               padded_rows: int, flavour: str, kind: str = "fused",
+               rows: Optional[int] = None, tokens_real: int = 0,
+               name: str = STEP_SPAN) -> BatchStep:
+    """Open one step: always the ``engine.step`` profiler annotation
+    (``group``, ``flavour``, ``bucket``, ``rows``, ``padded_rows``,
+    ``tokens_real``), and request tracing iff any batch item carries a
+    trace context — the common untraced case is one list scan.  The step
+    is ``detailed`` (host stage pairs kept) only when some traced item's
     trace is sampled."""
     traced = [(it, it.trace) for it in items
               if getattr(it, "trace", None) is not None]
+    facts = {"group": group, "flavour": flavour,
+             "bucket": int(bucket),
+             "rows": len(items) if rows is None else int(rows),
+             "padded_rows": int(padded_rows),
+             "tokens_real": int(tokens_real)}
     if not traced:
-        return None
+        return BatchStep(name, traced, {}, detailed=False, facts=facts)
     detailed = any(ctx.sampled for _, ctx in traced)
     mix: Dict[str, int] = {}
     for it in items:
@@ -243,4 +277,31 @@ def start_step(items, *, group: str, bucket: int, max_batch: int,
     if mix:
         attrs["task_mix"] = ",".join(
             f"{t}:{n}" for t, n in sorted(mix.items()))
-    return BatchStep(name, traced, attrs, detailed=detailed)
+    return BatchStep(name, traced, attrs, detailed=detailed, facts=facts)
+
+
+def queue_wait(trace_id: str, group: str, wait_s: float) -> None:
+    """``engine.queue_wait``: one item left the batcher's queue now,
+    after ``wait_s``.  The interval is in the past, where an annotation
+    cannot start: the event marks its END and carries its length."""
+    with trace_span(QUEUE_WAIT_ANNOTATION, trace_id=trace_id, group=group,
+                    wait_us=int(wait_s * 1e6)):
+        pass
+
+
+def route_span(trace_id: str):
+    """``router.route``: a route on the profiler's clock, beside the
+    tracer's span of the same name, carrying the id that its items'
+    ``engine.queue_wait`` events carry."""
+    return trace_span(ROUTE_ANNOTATION, trace_id=trace_id)
+
+
+def route_done(trace_id: str, route_s: float) -> None:
+    """``router.route.done``: a route ended now, after ``route_s``.  The
+    ``router.route`` annotation around a route is lost when the profiler
+    session began after the route did (a long route, a short session);
+    this marker, like ``engine.queue_wait``, marks the END and carries
+    the length, so every route that completes in a session is seen."""
+    with trace_span(ROUTE_DONE_ANNOTATION, trace_id=trace_id,
+                    route_us=int(route_s * 1e6)):
+        pass

@@ -123,13 +123,16 @@ def configure_xla_dump(dump_dir: str) -> Dict[str, Any]:
             "effective": "now" if live else "next process start"}
 
 
-def trace_span(name: str):
-    """Named region in the profiler timeline: engine hot paths annotate
-    with ``with trace_span('classify.intent'): ...`` so the XLA trace
-    lines up with router semantics."""
+def trace_span(name: str, **facts):
+    """Named region in the profiler timeline, on the same clock as the
+    device's ops: ``with trace_span("engine.step", rows=4): ...``.  The
+    name is a constant; what varies rides in ``facts`` and becomes the
+    event's stats.  The profiler session (``ProfilerControl.start``, or
+    any ``jax.profiler.start_trace``) is the switch: with none running
+    this is an object and a flag test, and nothing is encoded."""
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **facts)
 
 
 default_profiler = ProfilerControl()

@@ -142,12 +142,12 @@ class Tracer:
                  sample_rate: float = 0.1,
                  force_capacity: int = 1024) -> None:
         self.capacity = capacity
-        # fraction of traces that get DETAILED batch tracing — the fenced
-        # split-program per-stage timing (observability.batchtrace).
+        # fraction of traces that keep DETAILED batch tracing — the
+        # per-stage children of batch.ride (observability.batchtrace).
         # Trace CONTINUITY (batch.wait/ride spans + step links) is never
-        # sampled away; only the device-syncing detail is, so the default
-        # hot path pays no extra fences.  Deterministic per trace_id, so
-        # a trace is all-or-nothing.
+        # sampled away, and no step syncs the device or runs another
+        # program for a trace.  Deterministic per trace_id, so a trace
+        # is all-or-nothing.
         self.sample_rate = sample_rate
         # tail-based keep set: trace ids the flight recorder retained
         # (threshold breach / slowest-N) are force-sampled from then on —

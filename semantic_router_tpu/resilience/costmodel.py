@@ -27,10 +27,10 @@ import time
 from typing import Any, Dict, List, Optional
 
 # variant → path mapping (engine/classify.py _record_step callers):
-# "stacked" is the multi-task LoRA bank pass; "fused"/"fused_detailed"
-# (trunk groups) and "split" (per-task) together are the traditional path
+# "stacked" is the multi-task LoRA bank pass; "fused" (trunk groups) and
+# "split" (per-task) together are the traditional path
 _STACKED_VARIANTS = ("stacked",)
-_TRADITIONAL_VARIANTS = ("fused", "fused_detailed", "split")
+_TRADITIONAL_VARIANTS = ("fused", "split")
 
 DEFAULT_REQUEST_COST_S = 0.005  # pre-telemetry guess: 5ms of device time
 

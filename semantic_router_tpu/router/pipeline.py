@@ -34,6 +34,7 @@ from ..config.schema import Decision, ModelRef, RouterConfig
 from ..decision.engine import DecisionEngine, DecisionResult, SignalMatches
 from ..engine.classify import InferenceEngine
 from ..observability import metrics as M
+from ..observability.batchtrace import route_done, route_span
 from ..observability.logging import component_event
 from ..observability.tracing import default_tracer
 from ..selection import Feedback, SelectionContext, registry as selectors
@@ -461,9 +462,13 @@ class Router:
                 rec = self.explain.begin(trace_id, request_id)
             except Exception:
                 rec = None
+        # beside the tracer's span, the same route on the profiler's
+        # clock with its trace id: an operator's profile joins every
+        # engine.queue_wait to its route by id
         with self.tracer.span("router.route", trace_id=trace_id,
                               parent_id=parent_span,
-                              request_id=request_id) as root:
+                              request_id=request_id) as root, \
+                route_span(trace_id):
             if pending_trace is not None:
                 # adopt the pre-minted root span id BEFORE any child
                 # opens (children read the parent id at creation time)
@@ -473,6 +478,7 @@ class Router:
             result.trace_id = trace_id
             result.root_span_id = root.span_id
             root.set(kind=result.kind, model=result.model)
+        route_done(trace_id, time.perf_counter() - start)
         # degradation echo: while the ladder is above L0 every response
         # carries the level, so clients and LBs see brownouts explicitly
         if self.resilience is not None:

@@ -167,9 +167,8 @@ class TestFusedBatchTracing:
                                   ["trace this request end to end"])
             tid = root.trace_id
         names = {s.name for s in t.trace(tid)}
-        assert {"batch.wait", "batch.tokenize", "batch.ride",
-                "batch.trunk_forward", "batch.head_matmul",
-                "batch.demux"} <= names
+        assert {"batch.wait", "batch.tokenize", "batch.ride"} | {
+            f"batch.{n}" for n in batchtrace.STAGES} <= names
         (ride,) = [s for s in t.trace(tid) if s.name == "batch.ride"]
         (step,) = [s for s in t.spans("batch.execute")
                    if {"trace_id": s.trace_id, "span_id": s.span_id}
@@ -181,7 +180,7 @@ class TestFusedBatchTracing:
             assert f"{task}:1" in mix
         assert step.attributes["batch_size"] >= 1
         assert 0 < step.attributes["fill_ratio"] <= 1
-        for stage in ("trunk_forward", "head_matmul", "demux"):
+        for stage in batchtrace.STAGES:
             assert step.attributes[f"stage.{stage}_ms"] >= 0
 
     def test_stage_spans_parent_under_ride(self, engine):
@@ -191,12 +190,19 @@ class TestFusedBatchTracing:
             tid = root.trace_id
         spans = {s.name: s for s in t.trace(tid)}
         ride = spans["batch.ride"]
-        assert spans["batch.trunk_forward"].parent_id == ride.span_id
+        stages = [spans[f"batch.{n}"] for n in batchtrace.STAGES]
+        assert all(st.parent_id == ride.span_id for st in stages)
+        # the host stages in the order the runner goes through them,
+        # one after the other inside the ride
+        for a, b in zip(stages, stages[1:]):
+            assert a.end_pc <= b.start_pc
+        assert ride.start_pc <= stages[0].start_pc
+        assert stages[-1].end_pc <= ride.end_pc
         assert spans["batch.wait"].parent_id == root.span_id
 
     def test_unsampled_trace_keeps_continuity_drops_detail(self, engine):
         """sample_rate=0: continuity spans (wait/ride + step link) still
-        emit — only the fenced per-stage detail is sampled away."""
+        emit — only the per-stage children are sampled away."""
         t = Tracer(sample_rate=0.0)
         with t.span("router.route") as root:
             engine.classify_multi(self.TASKS, ["unsampled request"])
@@ -205,9 +211,8 @@ class TestFusedBatchTracing:
         assert {"batch.wait", "batch.ride"} <= names
         (ride,) = [s for s in t.trace(tid) if s.name == "batch.ride"]
         assert ride.links  # still linked to its batch.execute step
-        # no detailed stage spans, and the step carries no stage attrs
-        assert not {"batch.trunk_forward", "batch.head_matmul",
-                    "batch.demux"} & names
+        # no stage spans, and the step carries no stage attrs
+        assert not {f"batch.{n}" for n in batchtrace.STAGES} & names
         step = next(s for s in t.spans("batch.execute")
                     if s.trace_id == ride.links[0]["trace_id"])
         assert not any(k.startswith("stage.") for k in step.attributes)
@@ -238,8 +243,8 @@ class TestFusedBatchTracing:
                 eng.classify("intent", "per-task path rides too")
                 tid = root.trace_id
             names = {s.name for s in t.trace(tid)}
-            assert {"batch.wait", "batch.ride", "batch.trunk_forward",
-                    "batch.demux"} <= names
+            assert {"batch.wait", "batch.ride"} | {
+                f"batch.{n}" for n in batchtrace.STAGES} <= names
         finally:
             eng.shutdown()
 

@@ -2,8 +2,8 @@
 a fake shared-trunk engine, push 50 mixed-signal requests through it, and
 assert every request's trace survived the fused batcher — a batch.ride
 span linked to a batch.execute step span, with the per-stage spans the
-acceptance criteria name (queue wait, tokenization/cache-hit, trunk
-forward, head matmul, demux)."""
+acceptance criteria name (queue wait, tokenization/cache-hit, and the
+step's host stages: stack, h2d, dispatch, readback, demux)."""
 
 import pytest
 
@@ -51,8 +51,8 @@ def stack():
                             NamedRule(name="negative")],
         ),
     )
-    # full detail: every trace gets the fenced per-stage attribution,
-    # not just the default 10% sample
+    # full detail: every trace keeps its per-stage children, not just
+    # the default 10% sample
     tracer = Tracer(capacity=N_REQUESTS * 40, sample_rate=1.0)
     router = Router(cfg, engine=engine,
                     metrics=MetricSeries(MetricsRegistry()),
@@ -84,8 +84,9 @@ class TestTraceSmoke:
             names = {s.name for s in spans}
             # the acceptance stage set, per request trace
             assert {"router.route", "signals.evaluate", "batch.wait",
-                    "batch.tokenize", "batch.ride", "batch.trunk_forward",
-                    "batch.head_matmul", "batch.demux"} <= names, \
+                    "batch.tokenize", "batch.ride", "batch.stack",
+                    "batch.h2d", "batch.dispatch", "batch.readback",
+                    "batch.demux"} <= names, \
                 f"trace {tid} missing stages: {sorted(names)}"
             rides = [s for s in spans if s.name == "batch.ride"]
             assert rides, f"trace {tid} has no batch.ride span"
