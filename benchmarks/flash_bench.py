@@ -8,18 +8,22 @@ paper/sections/evaluation.tex:83-121):
      geometry, B=3 H=12 D=64, the reference's "3 concurrent classifiers"
      scenario), expecting dense to OOM/regress at long seq like the
      reference's SDPA did at >=8K (evaluation.tex:92-95).
-  3. block-size tuning at 8K (the kernel's fixed 128s were never tuned).
+  3. block sweep: ms per call by (block_q, block_k) at the served
+     geometry, f32 and bf16, global and windowed — the table behind
+     ``ops/flash_attention.py``'s block rule (PERF.md section 6).
   4. end-to-end classifier sweep: mmBERT-32K-geometry ModernBERT b=1 at
      512..32768 tok vs the MI300X FP16 numbers (evaluation.tex:50-57).
 
-Results stream into --out (default benchmarks/results/flash_tpu_latest.json)
-after every section so an interrupted run still leaves partial evidence.
-Diagnostics on stderr; the file is the artifact.
+Results stream into --out (default chiprun_out/flash_bench.json, which the
+chip tool brings back) after every section so an interrupted run still
+leaves partial evidence.  Diagnostics on stderr; the file is the artifact,
+and nothing in the program reads it.
 """
 
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
 import sys
@@ -176,38 +180,90 @@ def run_kernel_sweep(report, out_path, seqs):
         _flush(report, out_path)
 
 
-def run_block_tuning(report, out_path, S=8192):
+# the served geometry (mmBERT-32K: 12 heads x 64) at every default bucket
+# from 512 up, with the padded batch of 8 at the benchmark's bucket
+SWEEP_SHAPES = ((1, 512), (1, 2048), (1, 8192), (8, 8192), (1, 32768))
+SWEEP_BLOCKS_Q = (128, 256, 512, 1024)
+SWEEP_BLOCKS_K = (128, 256, 512, 1024, 2048)
+
+
+def _time_block_pair(q, k, v, window, bq, bk):
+    """{compile_s, ms, calls} of the kernel at one block pair, or {ms:
+    None, error}.  A call's time is a ``fori_loop`` of n dependent calls
+    inside one program over n, so no host dispatch is in it."""
     import jax
     import jax.numpy as jnp
 
     from semantic_router_tpu.ops.flash_attention import flash_attention_pallas
 
-    B, H, D = 3, 12, 64
-    rng = np.random.default_rng(7)
-    q, k, v = (jnp.asarray(
-        rng.standard_normal((B, H, S, D)).astype(np.float32),
-        jnp.bfloat16) for _ in range(3))
+    def loop(n, q, k, v):
+        return jax.lax.fori_loop(
+            0, n, lambda _, x: flash_attention_pallas(
+                x, k, v, window=window, block_q=bq, block_k=bk), q)
+
+    def run(fn, n):
+        t0 = time.perf_counter()
+        fn(jnp.int32(n), q, k, v).block_until_ready()
+        return time.perf_counter() - t0
+
+    try:
+        t0 = time.perf_counter()
+        fn = jax.jit(loop).lower(jnp.int32(1), q, k, v).compile()
+        compile_s = time.perf_counter() - t0
+        run(fn, 1)
+        n = int(min(200, max(3, 0.25 / (run(fn, 2) / 2))))
+        return {"compile_s": round(compile_s, 2), "calls": n,
+                "ms": round(min(run(fn, n), run(fn, n)) / n * 1e3, 4)}
+    except Exception as exc:
+        return {"ms": None,
+                "error": f"{type(exc).__name__}: {exc}"[:160]}
+
+
+def run_block_sweep(report, out_path, shapes=SWEEP_SHAPES):
+    """Device milliseconds per kernel call for every (block_q, block_k)
+    pair: the table ``ops/flash_attention.py``'s block rule was read
+    from (PERF.md section 6, PR 26).  The program reads nothing this
+    writes."""
+    import jax.numpy as jnp
+
+    from semantic_router_tpu.ops.flash_attention import blocks_for
+
+    H, D = 12, 64
     rows = []
-    for bq in (128, 256, 512):
-        for bk in (128, 256, 512):
-            fn = jax.jit(lambda q, k, v, bq=bq, bk=bk:
-                         flash_attention_pallas(q, k, v, block_q=bq,
-                                                block_k=bk).sum())
-            try:
-                dt = _sync_time(fn, q, k, v, warmup=1, iters=3)
-                rows.append({"block_q": bq, "block_k": bk,
-                             "ms": round(dt * 1e3, 2)})
-            except Exception as exc:
-                rows.append({"block_q": bq, "block_k": bk, "ms": None,
-                             "error": f"{type(exc).__name__}"[:80]})
-            sys.stderr.write(f"block tuning {rows[-1]}\n")
-            report["block_tuning"] = {"seq": S, "rows": rows}
-            _flush(report, out_path)
-    ok = [r for r in rows if r.get("ms")]
-    if ok:
-        best = min(ok, key=lambda r: r["ms"])
-        report["block_tuning"]["best"] = best
-        _flush(report, out_path)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        for window in (0, 128):
+            for B, S in shapes:
+                rng = np.random.default_rng(S + B)
+                q, k, v = (jnp.asarray(rng.standard_normal(
+                    (B, H, S, D)).astype(np.float32), dtype)
+                    for _ in range(3))
+                for bq, bk in itertools.product(SWEEP_BLOCKS_Q,
+                                                SWEEP_BLOCKS_K):
+                    if max(bq, bk) > S:
+                        continue
+                    row = {"dtype": jnp.dtype(dtype).name, "window": window,
+                           "batch": B, "seq": S, "block_q": bq,
+                           "block_k": bk,
+                           "rule": (bq, bk) == blocks_for(S, window),
+                           **_time_block_pair(q, k, v, window, bq, bk)}
+                    sys.stderr.write(f"block sweep {row}\n")
+                    rows.append(row)
+                report["block_sweep"] = {
+                    "geometry": {"heads": H, "head_dim": D}, "rows": rows}
+                _flush(report, out_path)
+
+
+def print_block_table(rows):
+    """One stdout line per swept shape, pairs in sweep order, the block
+    rule's own pair starred."""
+    for key in sorted({(r["dtype"], r["window"], r["batch"], r["seq"])
+                       for r in rows}):
+        cells = [f"{r['block_q']}x{r['block_k']}="
+                 f"{r['ms'] if r['ms'] is not None else 'FAIL'}"
+                 f"{'*' if r['rule'] else ''}"
+                 for r in rows if (r["dtype"], r["window"], r["batch"],
+                                   r["seq"]) == key]
+        print("SWEEP", *key, " ".join(cells))
 
 
 def run_classifier_sweep(report, out_path, seqs,
@@ -280,7 +336,7 @@ def run_classifier_sweep(report, out_path, seqs,
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--out", default="benchmarks/results/flash_tpu_latest.json")
+    ap.add_argument("--out", default="chiprun_out/flash_bench.json")
     ap.add_argument("--seqs", default="512,2048,4096,8192,16384,32768")
     ap.add_argument("--cls-seqs", default="512,1024,2048,4096,8192,16384,32768")
     ap.add_argument("--skip", default="",
@@ -321,10 +377,13 @@ def main() -> int:
     if "kernel" not in skip:
         run_kernel_sweep(report, args.out, seqs)
     if "blocks" not in skip:
-        run_block_tuning(report, args.out)
+        run_block_sweep(report, args.out)
     if "classifier" not in skip:
         run_classifier_sweep(report, args.out, cls_seqs)
+    sweep = report.pop("block_sweep", None)
     print(json.dumps(report, indent=2))
+    if sweep:
+        print_block_table(sweep["rows"])
     return 0
 
 

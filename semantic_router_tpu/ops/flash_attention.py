@@ -11,13 +11,17 @@ TPU-native kernel (SURVEY.md N8/N12):
   bounds the grid's K axis: a query block only ever visits the K/V blocks
   its window can touch, partial blocks are masked in-register.
 
-Layout: q/k/v reshaped to [B*H, S, D]; grid = (B*H, Sq/BLOCK_Q, n_kv)
+Layout: q/k/v reshaped to [B*H, S, D]; grid = (B*H, Sq/block_q, n_kv)
 with the K axis innermost.  K and V stream through VMEM one
-(BLOCK_K, D) block per grid step (never a whole sequence — 32K tokens of
+(block_k, D) block per grid step (never a whole sequence — 32K tokens of
 K+V would not fit v5e's 16 MiB scoped VMEM); the online-softmax state
 (m/l/acc, fp32) lives in VMEM scratch across the K steps of one query
 block.  Padding arrives as a per-(B) additive key bias [B, 1, Sp],
 indexed by bh // H.
+
+Blocks: ``blocks_for`` picks (block_q, block_k) from the call's padded
+sequence length and its window — constants of this module, each from a
+v5e measurement; no file, environment variable or config key has a say.
 
 ``flash_attention`` is the public entry: the Pallas kernel on TPU, the
 chunked JAX path on CPU (same semantics; interpret mode on CPU is the
@@ -27,6 +31,7 @@ numerics oracle in tests).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -37,8 +42,44 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import NEG_INF, chunked_sdpa, padding_bias, sdpa, \
     sliding_window_bias
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
+# Blocks the rule may pick, largest first.  Every time below is one call
+# on a TPU v5e at the served geometry, [B*12, S, 64] float32, B = 1 unless
+# said, by benchmarks/flash_bench.py's block sweep (PERF.md section 6,
+# PR 26).  bfloat16 operands are widened on arrival and rank the pairs
+# alike: the pair chosen here is within 8% of their best at every shape.
+LISTED_BLOCKS = (1024, 512, 256, 128)
+# window == 0 and causal calls.  The kernel pays per grid step, not per
+# operation: S = 8192 takes 25.97 ms at 128x128, 5.43 at 512x512, 3.08 at
+# 512x1024, 2.90 at 1024x1024 (B = 8: 227.9 / 46.7 / 27.1 / 25.1; S = 32768:
+# 449 / 89.9 / 50.7 / 47.1; S = 2048: 1.52 / 0.357 / 0.213 / 0.201).
+# 1024x2048 is no faster (3.15) and is refused inside larger programs: it
+# needs 14 MiB of the 16 MiB of scoped VMEM, 1024x1024 needs 10.
+GLOBAL_BLOCKS = (1024, 1024)
+# window > 0.  The band already bounds the K axis and a wide block_k
+# visits keys the band excludes, yet two wide steps a query block beat six
+# narrow ones: S = 8192 takes 1.50 ms at 128x128, 1.96 at 512x128, 1.10 at
+# 512x512, 0.863 at 256x512, 0.862 at 512x1024, 1.18 at 1024x1024 (B = 8:
+# 14.2 / 17.5 / 10.4 / 9.08 / 8.50 / 11.1; S = 32768: 7.06 / 8.75 / 5.18 /
+# 4.55 / 4.25 / 5.59).  256x512 over 512x1024: 1% of a trunk forward
+# slower, and a 22-layer program compiles 2 s sooner (9.7 s against 11.6).
+WINDOW_BLOCKS = (256, 512)
+
+
+def blocks_for(seq_len: int, window: int = 0) -> tuple:
+    """(block_q, block_k) of a call, from its shape alone.  The sequence
+    is padded to the next multiple of 128 and never further: one no longer
+    than the table's block is ONE block of that padded length, a longer
+    one takes the largest listed block that divides it."""
+    padded = -(-seq_len // 128) * 128
+
+    def fit(want: int) -> int:
+        if padded <= want:
+            return padded
+        return next(b for b in LISTED_BLOCKS
+                    if b <= want and padded % b == 0)
+
+    want_q, want_k = WINDOW_BLOCKS if window > 0 else GLOBAL_BLOCKS
+    return fit(want_q), fit(want_k)
 
 
 def _kv_start(qi, *, block_q: int, block_k: int, window: int):
@@ -115,12 +156,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            key_padding_mask: Optional[jnp.ndarray] = None,
                            window: int = 0, causal: bool = False,
-                           block_q: int = DEFAULT_BLOCK_Q,
-                           block_k: int = DEFAULT_BLOCK_K,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None) -> jnp.ndarray:
     """q/k/v: [B, H, S, D]; key_padding_mask: [B, S] (1 = real token).
     ``window``: ModernBERT-style full window width (0 = global).
+    ``block_q`` / ``block_k``: None = the shape's own (``blocks_for``).
     ``interpret``: None = the Pallas interpreter on a CPU platform (so the
     same call site runs in tests), the compiled kernel everywhere else."""
     if interpret is None:
@@ -128,7 +170,10 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     B, H, S, D = q.shape
     if scale is None:
         scale = D ** -0.5
-    pad = (-S) % max(block_q, block_k)
+    if block_q is None or block_k is None:
+        rule_q, rule_k = blocks_for(S, window)
+        block_q, block_k = block_q or rule_q, block_k or rule_k
+    pad = (-S) % math.lcm(block_q, block_k)
     Sp = S + pad
     if pad:
         zq = ((0, 0), (0, 0), (0, pad), (0, 0))
@@ -196,42 +241,6 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.reshape(B, H, Sp, D)[:, :, :S, :]
 
 
-_TUNED_BLOCKS: "Optional[tuple]" = None
-
-
-def tuned_blocks() -> tuple:
-    """(block_q, block_k) for the Pallas kernel: explicit env override
-    (SRT_FLASH_BLOCK_Q/K) > the best row of a recorded on-chip
-    block-tuning sweep (benchmarks/results/flash_tpu_latest.json,
-    written by benchmarks/flash_bench.py; path overridable via
-    SRT_FLASH_TUNING_PATH) > the defaults.  Read once per process.
-    No sweep has been recorded, so the defaults serve; a pair the
-    compiler refuses fails warmup, and with it startup."""
-    global _TUNED_BLOCKS
-    if _TUNED_BLOCKS is None:
-        import json
-        import os
-
-        bq = int(os.environ.get("SRT_FLASH_BLOCK_Q", "0") or 0)
-        bk = int(os.environ.get("SRT_FLASH_BLOCK_K", "0") or 0)
-        if not (bq and bk):
-            path = os.environ.get("SRT_FLASH_TUNING_PATH") or os.path.join(
-                os.path.dirname(os.path.dirname(os.path.dirname(
-                    os.path.abspath(__file__)))),
-                "benchmarks", "results", "flash_tpu_latest.json")
-            try:
-                with open(path) as f:
-                    rows = json.load(f)["block_tuning"]["rows"]
-                best = min((r for r in rows if r.get("ms")),
-                           key=lambda r: r["ms"])
-                bq = bq or int(best["block_q"])
-                bk = bk or int(best["block_k"])
-            except (OSError, KeyError, ValueError, TypeError):
-                pass
-        _TUNED_BLOCKS = (bq or DEFAULT_BLOCK_Q, bk or DEFAULT_BLOCK_K)
-    return _TUNED_BLOCKS
-
-
 def _platform_of(x) -> str:
     """Platform the call will run on.  Under jit ``x`` is a tracer (whose
     ``devices()`` raises), and jit compiles for the default backend."""
@@ -275,9 +284,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """Dispatch: the Pallas kernel on a TPU platform (per shard when the
     model serves under ``mesh``); the chunked JAX path on CPU."""
     if _platform_of(q) == "tpu":
-        bq, bk = tuned_blocks()
-        kw = dict(window=window, causal=causal, block_q=bq, block_k=bk,
-                  scale=scale)
+        kw = dict(window=window, causal=causal, scale=scale)
         if mesh is None:
             return flash_attention_pallas(q, k, v, key_padding_mask, **kw)
         if key_padding_mask is None:

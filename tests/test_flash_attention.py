@@ -84,6 +84,61 @@ class TestFlashKernel:
             atol=2e-2, rtol=2e-2)
 
 
+def dense_oracle(q, k, v, window=0, causal=False, mask=None):
+    S = q.shape[2]
+    bias = jnp.zeros((1, 1, S, S), jnp.float32)
+    if causal:
+        bias = bias + jnp.triu(jnp.full((S, S), NEG_INF, jnp.float32),
+                               k=1)[None, None]
+    if window > 0:
+        bias = bias + sliding_window_bias(S, window)
+    if mask is not None:
+        bias = bias + padding_bias(mask)
+    return sdpa(q, k, v, bias=bias)
+
+
+# (seq, block_q, block_k, window, causal, real tokens or None)
+GEOMETRIES = [
+    pytest.param(96, 32, 16, 0, False, None, id="global-bq>bk"),
+    pytest.param(96, 16, 48, 0, False, None, id="global-bq<bk"),
+    pytest.param(128, 64, 16, 16, False, None, id="bq=4x-window"),
+    pytest.param(128, 8, 16, 32, False, None, id="window-over-3-k-blocks"),
+    pytest.param(128, 16, 64, 16, False, None, id="window-bk>bq"),
+    pytest.param(100, 32, 16, 0, False, 70, id="padding-ends-in-block"),
+    pytest.param(100, 32, 64, 24, False, 70, id="window-padding-bq<bk"),
+    pytest.param(96, 32, 16, 0, True, None, id="causal-bq>bk"),
+    pytest.param(96, 16, 32, 0, True, None, id="causal-bq<bk"),
+    pytest.param(96, 32, 16, 16, True, None, id="causal-window-bq>bk"),
+    # no blocks passed: the shape's own (blocks_for), one q block of 384
+    pytest.param(300, None, None, 0, False, 260, id="rule-global"),
+    pytest.param(300, None, None, 128, False, 260, id="rule-window"),
+]
+
+
+class TestBlockGeometries:
+    """Blocks the 16x16 tests above never met: unequal, wider than the
+    window, a band over several K blocks, padding that ends inside a
+    block.  Same oracle, same atol."""
+
+    @pytest.mark.parametrize("seq,bq,bk,window,causal,real", GEOMETRIES)
+    def test_matches_dense(self, seq, bq, bk, window, causal, real):
+        q, k, v = (rand(2, 2, seq, 16, seed=s) for s in (31, 32, 33))
+        mask = None
+        if real is not None:
+            lens = np.array([real, seq])[:, None]
+            mask = jnp.asarray(np.arange(seq)[None, :] < lens, jnp.float32)
+        out = flash_attention_pallas(
+            q, k, v, key_padding_mask=mask, window=window, causal=causal,
+            block_q=bq, block_k=bk, interpret=True)
+        ref = dense_oracle(q, k, v, window, causal, mask)
+        keep = slice(None) if real is None else slice(0, real)
+        np.testing.assert_allclose(np.asarray(out)[0, :, keep],
+                                   np.asarray(ref)[0, :, keep],
+                                   atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(out)[1], np.asarray(ref)[1],
+                                   atol=1e-5, rtol=1e-5)
+
+
 class TestDispatcher:
     def test_traces_under_jit(self):
         """The served programs call the dispatcher under jit, where q is

@@ -198,47 +198,51 @@ class TestBuildEngineWiring:
             engine.shutdown()
 
 
-class TestTunedBlocks:
-    """The measure→record→serve loop: a recorded on-chip block-tuning
-    sweep drives the serving kernel's block sizes."""
+# (seq, window, pair): the served buckets as PERF.md section 6's v5e sweep
+# chose them, then lengths no bucket has — 5 and 9 tiles of 128 divide by
+# no larger listed block, 12 of them by 512 and 256
+BLOCK_RULE_PAIRS = [
+    (100, 0, (128, 128)), (128, 128, (128, 128)),
+    (512, 0, (512, 512)), (512, 128, (256, 512)),
+    (2048, 0, (1024, 1024)), (2048, 128, (256, 512)),
+    (8192, 0, (1024, 1024)), (8192, 128, (256, 512)),
+    (32768, 0, (1024, 1024)), (32768, 128, (256, 512)),
+    (640, 0, (640, 640)), (1152, 0, (128, 128)),
+    (1536, 0, (512, 512)), (1536, 128, (256, 512)),
+]
 
-    def _reset(self):
+
+class TestBlockRule:
+    """The kernel's blocks are a function of the call's shape
+    (`ops.flash_attention.blocks_for`): no file, no environment."""
+
+    @pytest.mark.parametrize("window", [0, 128])
+    @pytest.mark.parametrize(
+        "seq", [100, 128, 300, 512, 640, 1536, 2048, 5000, 8192, 32768])
+    def test_blocks_fit_the_shape(self, seq, window):
+        import math
+
         import semantic_router_tpu.ops.flash_attention as fa
 
-        fa._TUNED_BLOCKS = None
-        return fa
+        bq, bk = fa.blocks_for(seq, window)
+        # the next multiple of the 128-lane tile: all a call may pad to
+        padded = -(-seq // 128) * 128
+        assert padded % bq == 0 and padded % bk == 0
+        # the kernel pads to a common multiple of its blocks: no further
+        assert seq + (-seq) % math.lcm(bq, bk) == padded
+        want = fa.WINDOW_BLOCKS if window else fa.GLOBAL_BLOCKS
+        for got, table in zip((bq, bk), want):
+            if padded <= table:
+                # a short sequence is ONE block of its own padded length
+                assert got == padded
+            else:
+                assert got in fa.LISTED_BLOCKS and got <= table
+                # the largest listed block that divides it
+                assert not any(b <= table and padded % b == 0
+                               for b in fa.LISTED_BLOCKS if b > got)
 
-    def test_best_recorded_row_wins(self, tmp_path, monkeypatch):
-        import json
+    @pytest.mark.parametrize("seq,window,want", BLOCK_RULE_PAIRS)
+    def test_buckets_get_the_tables_pair(self, seq, window, want):
+        from semantic_router_tpu.ops.flash_attention import blocks_for
 
-        fa = self._reset()
-        rec = {"block_tuning": {"seq": 8192, "rows": [
-            {"block_q": 128, "block_k": 128, "ms": 9.0},
-            {"block_q": 256, "block_k": 512, "ms": 4.5},
-            {"block_q": 512, "block_k": 512, "ms": None,
-             "error": "RESOURCE_EXHAUSTED"},
-        ]}}
-        p = tmp_path / "flash_tpu_latest.json"
-        p.write_text(json.dumps(rec))
-        monkeypatch.setenv("SRT_FLASH_TUNING_PATH", str(p))
-        monkeypatch.delenv("SRT_FLASH_BLOCK_Q", raising=False)
-        monkeypatch.delenv("SRT_FLASH_BLOCK_K", raising=False)
-        assert fa.tuned_blocks() == (256, 512)
-        self._reset()
-
-    def test_env_override_beats_recording(self, tmp_path, monkeypatch):
-        fa = self._reset()
-        monkeypatch.setenv("SRT_FLASH_BLOCK_Q", "512")
-        monkeypatch.setenv("SRT_FLASH_BLOCK_K", "128")
-        assert fa.tuned_blocks() == (512, 128)
-        self._reset()
-
-    def test_defaults_without_recording(self, tmp_path, monkeypatch):
-        fa = self._reset()
-        monkeypatch.setenv("SRT_FLASH_TUNING_PATH",
-                           str(tmp_path / "missing.json"))
-        monkeypatch.delenv("SRT_FLASH_BLOCK_Q", raising=False)
-        monkeypatch.delenv("SRT_FLASH_BLOCK_K", raising=False)
-        assert fa.tuned_blocks() == (fa.DEFAULT_BLOCK_Q,
-                                     fa.DEFAULT_BLOCK_K)
-        self._reset()
+        assert blocks_for(seq, window) == want
