@@ -13,12 +13,6 @@ ROOT = os.path.dirname(HERE)
 # everything a run writes besides the compile cache; git-ignored, fixed
 WORK_DIR = os.path.join(ROOT, ".chipbench_work")
 
-MODEL_KEYS = ("model_type", "vocab_size", "hidden_size", "intermediate_size",
-              "num_hidden_layers", "num_attention_heads",
-              "max_position_embeddings", "rope_scaling",
-              "global_attn_every_n_layers", "local_attention",
-              "classifier_pooling")
-
 
 def _load_json(path: str) -> Dict[str, Any]:
     with open(path) as f:
@@ -39,14 +33,24 @@ def find_cell(bench: Dict[str, Any], name: str) -> Dict[str, Any]:
 
 def load_config(bench: Dict[str, Any], name: str) -> Dict[str, Any]:
     """The configuration's JSON file plus ``model`` (its published
-    numbers) and ``dir`` (where its router_config.yaml lies)."""
+    numbers, the keys its family names) and ``dir`` (where its
+    router_config.yaml lies)."""
     (entry,) = [c for c in bench["configs"] if c["name"] == name]
     path = os.path.join(ROOT, entry["file"])
     cfg = _load_json(path)
-    cfg["model"] = {k: cfg[k] for k in MODEL_KEYS}
+    if not cfg.get("family"):
+        raise SystemExit(f"chipbench: {entry['file']} names no \"family\" "
+                         f"(a file of chipbench/families/): no default")
+    cfg["model"] = {k: cfg[k] for k in load_family(cfg).MODEL_KEYS}
     cfg["dir"] = os.path.dirname(path)
     cfg["name"] = name
     return cfg
+
+
+def load_family(config: Dict[str, Any]):
+    """The one module that knows the configuration's model family:
+    ``families/<family>.py`` (``families/__init__.py`` has the contract)."""
+    return load_module("families", config["family"])
 
 
 def load_workload(traffic: str) -> Dict[str, Any]:

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import bisect
 import functools
+import re
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
@@ -62,7 +63,8 @@ class ProgramSpans:
     steps: List[Step]
     waits: List[Tuple[str, float, float]]  # (trace_id, start, end)
     routes: Dict[str, Interval]  # completed: trace_id -> (start, end)
-    ops: List[Tuple[float, float, str]]  # device 0: (start, end, scope)
+    # device 0, by start: (start, end, scope, the op's name = its HLO text)
+    ops: List[Tuple[float, float, str, str]]
 
 
 def load(run) -> Optional[ProgramSpans]:
@@ -82,7 +84,7 @@ def _load(path: str) -> Optional[ProgramSpans]:
     lines: List[Dict[str, list]] = []
     consumers: Dict[Tuple[int, int], Tuple[int, float, float]] = {}
     modules: Dict[int, Interval] = {}
-    ops: List[Tuple[float, float, str]] = []
+    ops: List[Tuple[float, float, str, str]] = []
     device_seen = False
     for plane in data.planes:
         if plane.name == HOST_PLANE:
@@ -121,7 +123,7 @@ def _load(path: str) -> Optional[ProgramSpans]:
                         s = e.start_ns * NS
                         t = (e.start_ns + e.duration_ns) * NS
                         lo, hi = min(lo, s), max(hi, t)
-                        ops.append((s, t, scope_of.get(e.name, "")))
+                        ops.append((s, t, scope_of.get(e.name, ""), e.name))
     steps = [step for i in range(len(lines))
              for step in _steps_of(i, lines, consumers, modules)]
     if not steps:
@@ -215,7 +217,7 @@ def idle_by_cause(ps: ProgramSpans) -> Dict[str, float]:
     it: ``step_head`` until that step's program begins on the device,
     ``step_tail`` from then on; a piece under no step is
     ``between_steps``."""
-    busy = [(s, t) for s, t, _ in ps.ops]
+    busy = [(s, t) for s, t, _, _ in ps.ops]
     out = {"step_head": 0.0, "step_tail": 0.0, "between_steps": 0.0}
     cuts = sorted({x for st in ps.steps for x in (
         st.start, st.end, st.device[0] if st.device else st.end)})
@@ -257,13 +259,37 @@ def idle_share(run, cause: str) -> Optional[float]:
     return max(0.0, line_idle - sum(in_steps.values()))
 
 
+def kernel_in_steps(ps: ProgramSpans, pattern: str
+                    ) -> List[Tuple[Step, float, int]]:
+    """(step, seconds, calls) of the device ops whose name matches
+    ``pattern`` inside the program run that each traced ``engine.step``
+    span launched: a kernel's time beside the step's own facts (``rows``,
+    ``tokens_real``), so that time and work come from ONE set of steps.  A
+    step whose program run was not found on the device, or ran no such op,
+    is left out on both sides."""
+    rx = re.compile(pattern)
+    starts = [op[0] for op in ps.ops]
+    out = []
+    for st in ps.steps:
+        if st.device is None:
+            continue
+        a, b = st.device
+        mine = [t - s for s, t, _, name in
+                ps.ops[bisect.bisect_left(starts, a):
+                       bisect.bisect_right(starts, b)]
+                if t <= b and rx.search(name)]
+        if mine:
+            out.append((st, sum(mine), len(mine)))
+    return out
+
+
 def scope_ms_per_route(run, scopes) -> Optional[float]:
     ps = load(run)
     if ps is None or not run["trace"]["completed"]:
         return None
-    if not any(scope for _, _, scope in ps.ops):
+    if not any(scope for _, _, scope, _ in ps.ops):
         return None  # a program without named scopes
-    secs = sum(t - s for s, t, scope in ps.ops if scope in scopes)
+    secs = sum(t - s for s, t, scope, _ in ps.ops if scope in scopes)
     return secs / len(run["trace"]["completed"]) * 1e3
 
 

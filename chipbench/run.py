@@ -30,7 +30,7 @@ if __package__ in (None, ""):  # python3 chipbench/run.py
         os.path.abspath(__file__))))
     __package__ = "chipbench"
 
-from . import cells, checkpoints, correctness, loadgen  # noqa: E402
+from . import cells, correctness, loadgen  # noqa: E402
 from . import system as system_mod  # noqa: E402
 from .compile_watch import CompileWatch  # noqa: E402
 
@@ -97,14 +97,13 @@ def _cache_entries(path: str) -> int:
 
 class Tracer:
     """Profiles ``[start_after, start_after + seconds]`` of the window from
-    a helper thread; reads the program's step counters at both ends."""
+    a helper thread."""
 
-    def __init__(self, system, log_dir: str, t0: float, start_after: float,
+    def __init__(self, log_dir: str, t0: float, start_after: float,
                  seconds: float) -> None:
-        self.system, self.log_dir, self.t0 = system, log_dir, t0
+        self.log_dir, self.t0 = log_dir, t0
         self.start_after, self.seconds = start_after, seconds
         self.window: Optional[tuple] = None
-        self.steps: Optional[tuple] = None
         self.error: Optional[str] = None
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="chipbench-tracer")
@@ -122,13 +121,11 @@ class Tracer:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 2
-            before = self.system.step_counters()
             jax.profiler.start_trace(self.log_dir, profiler_options=opts)
             a = time.perf_counter() - self.t0
             time.sleep(self.seconds)
             b = time.perf_counter() - self.t0
             jax.profiler.stop_trace()
-            self.steps = (before, self.system.step_counters())
             self.window = (a, b)
         except Exception as exc:  # reported; the run goes on without a trace
             self.error = f"{type(exc).__name__}: {exc}"
@@ -167,8 +164,9 @@ def run_cell(bench: Dict[str, Any], cell_name: str, seed: int,
 def _run_in(work, bench, cell, config, wl, seed, seconds, trace, device,
             peaks, watch, cache_dir) -> Dict[str, Any]:
     generator = cells.load_module("traffic", wl["generator"])
+    family = cells.load_family(config)
     t = time.perf_counter()
-    ckpt_dirs = checkpoints.write_checkpoints(
+    ckpt_dirs = family.write_checkpoints(
         os.path.join(work, "ckpt"), config, seed)
     print(f"setup checkpoints from seed {seed}: "
           f"{time.perf_counter() - t:.2f} s", flush=True)
@@ -203,7 +201,7 @@ def _run_in(work, bench, cell, config, wl, seed, seconds, trace, device,
         setup_s = t0 - PROCESS_START
         tracer = None
         if trace:
-            tracer = Tracer(sut, os.path.join(work, "trace"), t0,
+            tracer = Tracer(os.path.join(work, "trace"), t0,
                             float(wl.get("trace_after_s", 2.0)),
                             min(float(wl.get("trace_seconds", 10.0)),
                                 max(seconds - 3.0, 1.0))).start()
@@ -222,6 +220,7 @@ def _run_in(work, bench, cell, config, wl, seed, seconds, trace, device,
         wait_after = sut.queue_wait_totals()
         peak = memory_peak_bytes()
         in_window_compiles = watch.since(t0)
+        after_build = watch.since(sut.engine_built)
         spans = list(sut.spans.rows)
         answers = dict(sut.spans.answers)
     finally:
@@ -248,7 +247,15 @@ def _run_in(work, bench, cell, config, wl, seed, seconds, trace, device,
           f"the last reply; attempted {n_attempted}, completed "
           f"{len(completed)}, failed {n_failed}", flush=True)
     print(f"compiles inside the window: {len(in_window_compiles)} "
-          f"({sum(d for _, d in in_window_compiles):.1f} s)", flush=True)
+          f"({sum(c.seconds for c in in_window_compiles):.1f} s)",
+          flush=True)
+    # which program, and when: a run refused for a compile inside the
+    # window then names the program that set-up did not warm
+    for c in after_build:
+        print(f"program after build_engine: {c.name} at "
+              f"{c.when - PROCESS_START:.1f} s of the process "
+              f"({c.when - t0:+.1f} s of the window), {c.seconds:.1f} s",
+              flush=True)
 
     # -- correct ------------------------------------------------------------
     by_index = {r.index: r for r in traffic.requests}
@@ -257,18 +264,17 @@ def _run_in(work, bench, cell, config, wl, seed, seconds, trace, device,
         int(wl.get("correctness_sample", 3)))
     t = time.perf_counter()
     parts: Dict[str, Any] = {}
-    buckets = wl["shapes"]["buckets"]
-    ref = correctness.Reference.from_checkpoints(config, ckpt_dirs)
+    ref = family.Reference.from_checkpoints(config, ckpt_dirs)
     served_tokens = 0
     for req in sample:
         got = answers.get(req.text, {})
-        raw = ref.outputs(req.ids, correctness.pick_bucket(req.n_tokens,
-                                                           buckets))
-        correctness.merge(parts, correctness.compare(config, req.ids, got,
-                                                     raw))
+        raw = ref.outputs(req, wl["shapes"], got)
+        correctness.merge(parts, family.compare(config, req, got, raw))
         served_tokens += req.n_tokens
-    numbers = correctness.finish(parts)
-    ok, lines = correctness.judge(config, numbers, correctness.load_limits())
+    numbers = family.finish(parts)
+    expected = family.expected_numbers(config)
+    limits = correctness.load_limits(config)
+    ok, lines = correctness.judge(expected, numbers, limits)
     print(f"reference: {len(sample)} requests of "
           f"{[r.n_tokens for r in sample]} tokens ({served_tokens} in all) "
           f"in {time.perf_counter() - t:.2f} s; numbers {numbers}",
@@ -283,6 +289,15 @@ def _run_in(work, bench, cell, config, wl, seed, seconds, trace, device,
     if in_window_compiles:
         print("NOT CORRECT: a program compiled inside the window",
               flush=True)
+    # every number compared beside its limit, last in the result's line
+    compared = {name: {"value": numbers.get(name),
+                       "limit": float(limits[name]["limit"])}
+                for name in expected}
+    compared["compiles_in_window"] = {
+        "value": len(in_window_compiles), "limit": 0,
+        "programs": [f"{c.name} at {c.when - t0:+.1f} s"
+                     for c in in_window_compiles]}
+    compared["failed_routes"] = {"value": len(failed), "limit": 0}
 
     # -- metrics ------------------------------------------------------------
     run: Dict[str, Any] = {
@@ -302,7 +317,7 @@ def _run_in(work, bench, cell, config, wl, seed, seconds, trace, device,
             os.path.join(work, "trace")))
         a, b = tracer.window
         run["trace"] = dict(
-            reduced, window=(a, b), steps=tracer.steps,
+            reduced, window=(a, b),
             completed=[r for r in completed if a <= r.end <= b],
             peaks=peaks)
         result_device.update(busy_s=reduced["busy_s"], window_s=b - a)
@@ -325,6 +340,7 @@ def _run_in(work, bench, cell, config, wl, seed, seconds, trace, device,
         # under a metric's name
         result["cpu_rehearsal_values"] = result.pop("metrics")
         result["metrics"] = {}
+    result["compared"] = compared
     return result
 
 
@@ -388,6 +404,10 @@ def main(argv: Optional[List[str]] = None) -> int:
               f"no other mode", file=sys.stderr)
         return EXIT_NO_CHIP
     print(json.dumps(result), flush=True)
+    for name, c in result["compared"].items():
+        print(f"compare {name}: {c['value']!r} limit {c['limit']!r}"
+              + (f" {c['programs']}" if c.get("programs") else ""),
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -14,11 +14,10 @@ import contextlib
 import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-# the engine's public calls that signals, cache and router reach it by
-ENGINE_CALLS = ("classify", "classify_batch", "classify_multi",
-                "token_classify", "embed")
+from . import cells
 
 
 class Spans:
@@ -59,41 +58,32 @@ class Spans:
                 ann.__exit__(None, None, None)
 
 
-def _wrap_engine(engine, spans: Spans) -> None:
-    """Instance-level wrappers around the engine's public calls: a span
-    per call and the call's answers kept by text."""
-    for name in ENGINE_CALLS:
+def _wrap_engine(engine, spans: Spans, calls: Dict[str, Any]) -> None:
+    """Instance-level wrappers around the engine's public calls that the
+    configuration's family names: a span per call and the call's answers
+    kept by text."""
+    for name, (arguments, answers) in calls.items():
         inner = getattr(engine, name)
 
-        def wrapped(*args, _inner=inner, _name=name, **kwargs):
-            task, payload = args[0], args[1]
-            if _name == "classify_multi":
-                tasks, texts = list(task), list(payload)
-            elif _name in ("classify", "token_classify"):
-                tasks, texts = [task], [payload]
-            else:
-                tasks, texts = [task], list(payload)
+        def wrapped(*args, _inner=inner, _name=name, _arguments=arguments,
+                    _answers=answers, **kwargs):
+            tasks, texts = _arguments(*args, **kwargs)
             with spans.span(f"engine.{_name}", texts[0] if texts else ""):
                 out = _inner(*args, **kwargs)
-            if _name == "classify_multi":
-                for t in tasks:
-                    for text, res in zip(texts, out.get(t, [])):
-                        spans.answer(text, t, res)
-            elif _name in ("classify", "token_classify"):
-                spans.answer(texts[0], tasks[0], out)
-            else:
-                for text, res in zip(texts, out):
-                    spans.answer(text, tasks[0], res)
+            for text, task, value in _answers(tasks, texts, out):
+                spans.answer(text, task, value)
             return out
 
         setattr(engine, name, wrapped)
 
 
 class System:
-    def __init__(self, engine, router, spans: Spans, config: Dict[str, Any]
-                 ) -> None:
+    def __init__(self, engine, router, spans: Spans, config: Dict[str, Any],
+                 engine_built: float) -> None:
         self.engine, self.router, self.spans = engine, router, spans
         self.config = config
+        # ``time.perf_counter()`` when ``build_engine`` had returned
+        self.engine_built = engine_built
 
     # -- the entry point under test ---------------------------------------
 
@@ -148,6 +138,19 @@ class System:
         self.engine.shutdown()
 
 
+def _on_a_thread_of_its_own(fn, *args) -> None:
+    """``fn(*args)`` on a new thread, waited for; what it raises is raised
+    here.  JAX records the Python stack with every operation it traces,
+    and on the chip's host a trace costs more the deeper its caller stands:
+    one frame more between ``run.main`` and ``engine.warmup`` made each of
+    16 warm-up programs 1.4 s slower, ten frames made the kernels' lowering
+    three times as long (PERF.md section 6, PR 27).  A thread starts with an
+    empty stack, so the warm-up's seconds do not depend on how deep in the
+    harness this is called from."""
+    with ThreadPoolExecutor(1, thread_name_prefix="chipbench-warm") as pool:
+        pool.submit(fn, *args).result()
+
+
 def write_router_config(config: Dict[str, Any], ckpt_dirs: Dict[str, str],
                         work_dir: str) -> str:
     """The configuration's static router_config.yaml with the run's
@@ -163,15 +166,15 @@ def write_router_config(config: Dict[str, Any], ckpt_dirs: Dict[str, str],
 
 def build(config: Dict[str, Any], config_path: str,
           shapes: Dict[str, Sequence[int]]) -> System:
-    """Engine + router from the config file, warmed on ``shapes``
-    (``buckets`` x ``rows``) and on nothing else.  A failed warm-up
-    program raises (the program's own WarmupError)."""
+    """Engine + router from the config file, warmed by the configuration's
+    family on ``shapes`` (the workload's shape set) and on nothing else."""
     from semantic_router_tpu.config import load_config
     from semantic_router_tpu.runtime.bootstrap import (
         build_engine,
         build_router,
     )
 
+    family = cells.load_family(config)
     cfg = load_config(config_path)
     t0 = time.perf_counter()
     engine = build_engine(cfg)
@@ -180,16 +183,10 @@ def build(config: Dict[str, Any], config_path: str,
     print(f"setup build_engine: {time.perf_counter() - t0:.2f} s; tasks "
           f"{sorted(engine.tasks())}; trunk groups "
           f"{engine.trunk_group_info()}", flush=True)
-    t0 = time.perf_counter()
-    engine.warmup(tasks=list(config["tasks"]), buckets=list(shapes["buckets"]),
-                  batch_sizes=list(shapes["rows"]))
-    for row in engine.warmup_report():
-        print(f"warmup {row['target']} bucket={row['bucket']} "
-              f"rows={row['rows']} {row['seconds']:.2f} s"
-              + (f" ERROR {row['error']}" if row["error"] else ""),
-              flush=True)
+    engine_built = t0 = time.perf_counter()
+    _on_a_thread_of_its_own(family.warm, engine, config, shapes)
     print(f"setup warmup: {time.perf_counter() - t0:.2f} s", flush=True)
     router = build_router(cfg, engine=engine)
     spans = Spans()
-    _wrap_engine(engine, spans)
-    return System(engine, router, spans, config)
+    _wrap_engine(engine, spans, family.ENGINE_CALLS)
+    return System(engine, router, spans, config, engine_built)

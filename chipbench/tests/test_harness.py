@@ -77,7 +77,8 @@ def test_words_are_token_ids():
     t = _gen().generate(LONG, 5, 2, 256000)
     r = t.requests[0]
     assert [int(w[1:]) for w in r.text.split()] == r.ids.tolist()
-    offs = correctness.word_offsets(r.ids[:3])
+    offs = cells.load_module("families", "encoder_bank").word_offsets(
+        r.ids[:3])
     assert [r.text[s:e] for s, e in offs] == r.text.split()[:3]
 
 
@@ -338,7 +339,9 @@ def test_lower_precision_control_fails_the_limits():
     config = cells.load_config(bench, "mmbert32k-bank")
     numbers = control_numbers(config, 7, [120, 300], [128, 512],
                               vocab_size=2048)
-    ok, lines = correctness.judge(config, numbers, correctness.load_limits())
+    ok, lines = correctness.judge(
+        cells.load_family(config).expected_numbers(config), numbers,
+        correctness.load_limits(config))
     assert not ok, lines
 
 

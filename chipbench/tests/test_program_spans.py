@@ -38,6 +38,7 @@ NEW = ["route_queue_wait_ms", "step_stack_ms", "step_h2d_ms",
        "head_bank_device_ms_per_route"]
 FUSED = ("trunk:trunk0", 8192, "fused")
 EMBED = ("task:embedding", 8192, "split")
+PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
 def read(name, run):
@@ -93,8 +94,8 @@ def make_run(path, busy_s, window_s, n_completed, steps):
     completed = [object()] * n_completed
     return {"completed": completed, "steps": steps,
             "trace": {"path": path, "busy_s": busy_s,
-                      "window": (2.0, 2.0 + window_s), "steps": steps,
-                      "completed": completed}}
+                      "window": (2.0, 2.0 + window_s),
+                      "completed": completed, "peaks": PEAKS}}
 
 
 @pytest.fixture(scope="module")
@@ -211,6 +212,38 @@ def test_trunk_against_heads_by_named_scope(step_run):
     assert _program_spans._scope("jit(<lambda>)/dot_general:") == ""
 
 
+def test_flash_roofline_takes_time_and_rows_from_the_same_steps(
+        step_run, monkeypatch):
+    """The recorded ``both`` step: 2 real rows, 10030 real tokens, PR 25's
+    128x128 blocks.  The cut also holds 21 kernel calls of the embedding
+    program that ran before it, whose ``engine.step`` span is not in the
+    cut: neither its time nor its rows may count."""
+    run, ops, _ = step_run
+    oc = cells.load_module("opcount", "flash_attention")
+    # the cut shortened the op names: `attn f32[24,8192,64] #8504`
+    monkeypatch.setattr(oc, "EVENT_PATTERN", r"^attn ")
+    (mine,) = raw(STEP)["/device:TPU:0"]["XLA Modules"]
+    attn = [(s, e) for n, s, e, _ in ops if n.startswith("attn ")]
+    own = [e - s for s, e in attn if mine[1] <= s and e <= mine[2]]
+    assert len(attn) == 43 and len(own) == 22
+    run = dict(run, config={"model": {
+        "num_attention_heads": 12, "hidden_size": 768,
+        "num_hidden_layers": 22, "global_attn_every_n_layers": 3,
+        "local_attention": 128}})
+    # by hand, two rows at the step's mean of 10030 / 2 = 5015 tokens: 8 global layers of 4 * 5015^2 * 64
+    # * 12 = 6.1809e11, 14 windowed of 4 * (5015 * 129 - 64 * 65) * 64 * 12
+    # = 2.7644e10; two rows 1.29147e12 operations = 6.5557 ms at the peak
+    least = 2 * (8 * 4 * 5015 ** 2 + 14 * 4 * (5015 * 129 - 64 * 65)) \
+        * 64 * 12 / 197e12
+    assert least == pytest.approx(6.5557e-3, rel=1e-4)
+    got = read("flash_attention_roofline", run)
+    assert got == pytest.approx(least / sum(own) * 100, rel=1e-9)
+    assert got == pytest.approx(1.3657, rel=1e-3)  # 0.4800 s of kernel
+    # the table of peaks has no such device: nothing to read
+    run["trace"] = dict(run["trace"], peaks=None)
+    assert read("flash_attention_roofline", run) is None
+
+
 def test_rows_by_flavour_sum_to_the_step_counters_figure(step_run):
     run, _, _ = step_run
     whole = read("trunk_rows_per_route", run)
@@ -268,7 +301,7 @@ def test_route_queue_wait_joins_items_to_routes_by_trace_id():
     assert 1500 < waited[3] * 1e3 < 1900
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + ["flash_attention_roofline"])
 def test_a_tree_without_the_spans_reads_none(name):
     """The driver runs these files against the parent too, whose trace has
     none of the annotations: no value, and no exception."""
