@@ -183,8 +183,17 @@ class DynamicBatcher:
 
     def __init__(self, runner: BatchRunner, max_batch_size: int = 32,
                  max_wait_ms: float = 2.0, name: str = "batcher",
-                 dispatch_workers: int = 4, metrics=None) -> None:
+                 dispatch_workers: int = 4, metrics=None,
+                 patient: Optional[Callable[[Hashable], bool]] = None
+                 ) -> None:
         self.runner = runner
+        # groups whose step lasts far longer than max_wait (a whole
+        # generation): such a group takes no low-QPS fast path and counts
+        # its wait from its last step's end, so that the callers a step
+        # released ride the NEXT step together instead of one firing alone
+        # and the rest queueing behind it for a second step
+        self._patient = patient or (lambda key: False)
+        self._released: Dict[Hashable, float] = {}
         self.name = name
         self.max_batch_size = max(1, max_batch_size)
         self.max_wait_s = max_wait_ms / 1000.0
@@ -362,7 +371,7 @@ class DynamicBatcher:
             if self._group_full(key, items) \
                     or self._ready_immediately(key, items):
                 return key
-            age = now - items[0].enqueue_t
+            age = now - self._waiting_since(key, items)
             if age > oldest_age:
                 oldest_key, oldest_age = key, age
         if oldest_key is None:
@@ -371,16 +380,22 @@ class DynamicBatcher:
             return oldest_key
         # single pending group and small queue: fire immediately — waiting
         # cannot grow the batch if no concurrent traffic exists
-        if total == len(self._queues.get(oldest_key, ())) and total <= 1:
+        if total == len(self._queues.get(oldest_key, ())) and total <= 1 \
+                and not self._patient(oldest_key):
             return oldest_key
         return None
+
+    def _waiting_since(self, key: Hashable, items: List[BatchItem]) -> float:
+        """When the group's max_wait began: its oldest item's enqueue, or
+        for a patient group its last step's end if that came later."""
+        return max(items[0].enqueue_t, self._released.get(key, 0.0))
 
     def _next_deadline(self) -> Optional[float]:
         deadline = None
         for key, items in self._queues.items():
             if items and self._inflight.get(key, 0) \
                     < self._inflight_cap(key):
-                d = items[0].enqueue_t + self.max_wait_s
+                d = self._waiting_since(key, items) + self.max_wait_s
                 deadline = d if deadline is None else min(deadline, d)
         return deadline
 
@@ -418,6 +433,8 @@ class DynamicBatcher:
                 self._cancel_batch(key, batch)
 
     def _release_inflight(self, key: Hashable) -> None:
+        if self._patient(key):
+            self._released[key] = time.perf_counter()
         n = self._inflight.get(key, 0)
         if n <= 1:
             self._inflight.pop(key, None)

@@ -64,6 +64,9 @@ from .packing import (
 # batch-group key prefix for fused trunk groups — the group id, not the
 # task name, is the batching unit (see module docstring)
 TRUNK_KEY = "__trunk__"
+# batch-group key of generative tasks: (GEN_KEY, task, prompt bucket,
+# max_new_tokens, adapter row, stop strings)
+GEN_KEY = "__generate__"
 
 # content digests of trunk parameter leaves, memoized by object id with
 # a weakref guard (id() values recycle after GC; the guard makes a
@@ -186,6 +189,88 @@ class _Payload:
     # request-level EncodingCache already held the encoding
     tok_s: float = 0.0
     tok_cached: bool = False
+
+
+def _cut_to_bucket(enc: Encoding, bucket: int, keep_tail: int) -> Encoding:
+    """A prompt longer than the largest bucket, cut to it: its first tokens
+    and its last ``keep_tail`` (a template's closing words, which tell a
+    generative model what to write) stay, the end of what lies between
+    goes.  Marked ``truncated`` with the whole count, as a tokenizer's own
+    clipping is; the generative runner flags the result and counts it."""
+    tail = max(0, min(keep_tail, bucket - 1))
+    head = bucket - tail
+    n = len(enc)
+
+    def cut(seq):
+        return list(seq[:head]) + list(seq[n - tail:])
+
+    return Encoding(ids=cut(enc.ids), attention_mask=cut(enc.attention_mask),
+                    offsets=cut(enc.offsets), truncated=True,
+                    total_tokens=enc.n_total)
+
+
+class _GenerationObserver:
+    """What a generator's ``generate`` reports to, forward by
+    forward (models.generate.NullObserver has the protocol): each forward
+    is one ``engine.step`` annotation with its stages (flavour
+    ``gen.prefill`` | ``gen.denoise`` | ``gen.commit`` | ``gen.decode``;
+    facts ``rows``, ``padded_rows``, ``tokens_real``, ``block``,
+    ``masks_left``), one ``record_step`` sample under group
+    ``gen:<task>`` with the flavour as its variant, and one
+    ``record_generation`` count (the counters of /metrics).  The prefill
+    step carries the batch items, so a traced request's queue wait ends
+    where its generation begins."""
+
+    def __init__(self, engine, task: str, bucket: int, items, padded_rows: int
+                 ) -> None:
+        self.engine, self.task, self.bucket = engine, task, bucket
+        self.items, self.padded_rows = items, padded_rows
+
+    def forward(self, flavour: str, tokens_real: int = 0, **facts):
+        from ..observability import batchtrace
+
+        step = batchtrace.start_step(
+            self.items if flavour == "gen.prefill" else (),
+            group=f"gen:{self.task}", bucket=self.bucket,
+            max_batch=self.engine.cfg.max_batch_size,
+            padded_rows=self.padded_rows, flavour=flavour,
+            kind="generative", rows=len(self.items),
+            tokens_real=tokens_real, **facts)
+        return _GenerationForward(self, step, flavour, tokens_real)
+
+
+class _GenerationForward:
+    def __init__(self, obs: _GenerationObserver, step, flavour: str,
+                 tokens_real: int) -> None:
+        self.obs, self.step, self.flavour = obs, step, flavour
+        self.tokens_real = tokens_real
+        self.t0 = time.perf_counter()
+        self.stage = step.stage
+
+    def done(self, load=None, committed_blocks: int = 0,
+             committed_tokens: int = 0) -> None:
+        """``load [layers, 4]`` of an expert model (models.sdar_moe.moe);
+        a dense generator gives none."""
+        from ..observability import batchtrace
+
+        obs, eng = self.obs, self.obs.engine
+        seconds = time.perf_counter() - self.t0
+        self.step.finish()
+        group = f"gen:{obs.task}"
+        if load is not None:
+            batchtrace.gen_forward(group, self.flavour, load)
+        shape = (obs.padded_rows, obs.bucket)
+        eng._record_step(
+            group, obs.bucket, self.flavour, len(obs.items),
+            obs.padded_rows, seconds,
+            eng._step_fresh(group, self.flavour, shape),
+            tokens_real=self.tokens_real)
+        try:
+            eng._runtime_stats.record_generation(
+                obs.task, self.flavour, committed_blocks=committed_blocks,
+                committed_tokens=committed_tokens)
+        except Exception:
+            pass  # observability never fails a generation
 
 
 @dataclass
@@ -321,6 +406,7 @@ class InferenceEngine:
             max_items_per_step=self._packing["max_items_per_step"],
             max_inflight_steps=self._packing["max_inflight_steps"],
             starvation_steps=self._packing["starvation_steps"],
+            patient=lambda key: isinstance(key, tuple) and key[0] == GEN_KEY,
         )
         # the online shape auto-tuner exists per engine (cheap state);
         # its POLLING THREAD is bootstrap's to start (apply_packing_knobs
@@ -396,10 +482,6 @@ class InferenceEngine:
         self._compiled_steps: set = set()
         # per-(target, bucket) warmup outcomes (warmup_report())
         self._warmup_report: List[Dict[str, Any]] = []
-        # generative decode mutates per-generator jit/cache state; one
-        # generation runs on-device at a time (decode steps saturate the
-        # chip anyway — concurrency comes from the classify batcher)
-        self._generative_lock = threading.Lock()
 
     # -- registration ------------------------------------------------------
 
@@ -1545,9 +1627,13 @@ class InferenceEngine:
 
     def generate(self, task: str, prompts: Sequence[str],
                  max_new_tokens: int = 64, adapter: str = "",
-                 stop_strings: Sequence[str] = ()) -> List[Any]:
+                 stop_strings: Sequence[str] = (),
+                 keep_tail: int = 0) -> List[Any]:
         """Greedy generation on a generative task; ``adapter`` selects the
-        LoRA row by name (generative multi-LoRA per-request selection)."""
+        LoRA row by name (generative multi-LoRA per-request selection).
+        A prompt longer than the largest bucket keeps its first tokens and
+        its last ``keep_tail``; its result says ``truncated`` and
+        llm_batcher_bucket_overflow_total counts it (never silent)."""
         t = self._require(task, kind="generative")
         if adapter:
             if adapter not in t.adapter_index:
@@ -1559,23 +1645,52 @@ class InferenceEngine:
             task_index = t.adapter_index[adapter]
         else:
             task_index = 0
-        with self._generative_lock:
-            return t.generator.generate(list(prompts),
-                                        max_new_tokens=max_new_tokens,
-                                        task_index=task_index,
-                                        stop_strings=stop_strings)
+        # a batch group per (task, prompt bucket, generation settings):
+        # concurrent callers ride one generation in lock step
+        # (_run_generative), at most one in flight per group
+        largest = self.cfg.seq_len_buckets[-1]
+        payloads = []
+        for p in prompts:
+            enc = t.tokenizer.encode(p)
+            # a generator of prompts only tokenizes for itself: no cut here
+            if len(enc) > largest and getattr(t.generator, "batched", False):
+                enc = _cut_to_bucket(enc, largest, keep_tail)
+            payloads.append(_Payload(p, enc))
+        futures = [self.batcher.submit(
+            (GEN_KEY, task,
+             pick_bucket(len(p.encoding), self.cfg.seq_len_buckets),
+             int(max_new_tokens), task_index, tuple(stop_strings)), p)
+            for p in payloads]
+        return [f.result() for f in futures]
 
     def guard_classify(self, task: str, text: str, role: str = "user",
-                       adapter: str = "", max_new_tokens: int = 32):
+                       adapter: str = "",
+                       max_new_tokens: Optional[int] = None):
         """Qwen3Guard-style safety classification: structured-output
         generation + regex parse (qwen3_guard.rs:513). Returns a
-        GuardVerdict; parse failures fail closed to Controversial."""
-        from ..models.generate import build_guard_prompt, parse_guard_output
+        GuardVerdict; parse failures fail closed to Controversial.  The
+        verdict's length is the generator's own ``gen_length`` (the
+        task's ``generation.gen_length``; what ``warmup`` compiled for).
+        Of a prompt longer than the largest bucket the END OF THE TEXT is
+        cut, not the template's closing words; the verdict then says
+        ``truncated``."""
+        from ..models.generate import (
+            GEN_LENGTH,
+            GUARD_PROMPT_TAIL,
+            build_guard_prompt,
+            parse_guard_output,
+        )
 
-        prompt = build_guard_prompt(text, role=role)
-        out = self.generate(task, [prompt], max_new_tokens=max_new_tokens,
-                            adapter=adapter)
-        return parse_guard_output(out[0].text)
+        t = self._require(task, kind="generative")
+        out = self.generate(
+            task, [build_guard_prompt(text, role=role)],
+            max_new_tokens=max_new_tokens
+            or getattr(t.generator, "gen_length", GEN_LENGTH),
+            adapter=adapter,
+            keep_tail=len(t.tokenizer.encode(GUARD_PROMPT_TAIL)))
+        verdict = parse_guard_output(out[0].text)
+        verdict.truncated = getattr(out[0], "truncated", False)
+        return verdict
 
     def has_task(self, name: str) -> bool:
         return name in self._tasks
@@ -1763,8 +1878,20 @@ class InferenceEngine:
 
         for name in tasks or list(self._tasks):
             t = self._tasks.get(name)
-            if t is None or t.kind in ("generative", "multimodal"):
-                continue  # their compile caches key on other shapes
+            if t is None or t.kind == "multimodal":
+                continue  # its compile cache keys on other shapes
+            if t.kind == "generative":
+                # the programs of (rows, prompt bucket) at the generator's
+                # own length of generation: what guard_classify will run
+                if not hasattr(t.generator, "warm"):
+                    continue
+                for b, n in ((b, n)
+                             for b in buckets or self.cfg.seq_len_buckets
+                             for n in batch_sizes):
+                    warm(f"gen:{name}", b, n,
+                         lambda t=t, b=b, n=n: t.generator.warm(
+                             self._padded_batch(n), b))
+                continue
             if name in self._task_group:
                 # fused members serve through their trunk group's
                 # programs (warmed below); the per-task program only
@@ -2341,6 +2468,8 @@ class InferenceEngine:
                    items: List[BatchItem]) -> Sequence[Any]:
         if group_key[0] == TRUNK_KEY:
             return self._run_fused_batch(group_key[1], group_key[2], items)
+        if group_key[0] == GEN_KEY:
+            return self._run_generative(*group_key[1:], items)
         task_name, bucket = group_key[0], group_key[1]
         t = self._require(task_name)
         n = len(items)
@@ -2462,6 +2591,37 @@ class InferenceEngine:
             # failing batches are exactly the ones traces must explain:
             # the step + ride spans emit even when the forward raised
             step.finish()
+
+    def _run_generative(self, task_name: str, bucket: int,
+                        max_new_tokens: int, task_index: int,
+                        stop_strings: tuple,
+                        items: List[BatchItem]) -> Sequence[Any]:
+        """The fourth runner: a batch of prompts of one generative task
+        and bucket through the generator in lock step, from prefill to the
+        last token (request-level batching).  Every device forward of it is
+        one ``engine.step`` and one ``record_step`` sample
+        (``_GenerationObserver``)."""
+        gen = self._require(task_name, kind="generative").generator
+        texts = [it.payload.text for it in items]
+        settings = dict(max_new_tokens=max_new_tokens,
+                        task_index=task_index, stop_strings=stop_strings)
+        if not getattr(gen, "batched", False):  # a generator of prompts only
+            return gen.generate(texts, **settings)
+        padded_n = self._padded_batch(len(items))
+        self._note_shape(f"gen:{task_name}", (padded_n, bucket))
+        encodings = [it.payload.encoding for it in items]
+        results = gen.generate(
+            texts, **settings, encodings=encodings, bucket=bucket,
+            padded_rows=padded_n,
+            observer=_GenerationObserver(self, task_name, bucket, items,
+                                         padded_n))
+        # prompts that generate() cut to the largest bucket
+        n_cut = sum(enc.truncated for enc in encodings)
+        if n_cut:
+            self._series().bucket_overflows.inc(n_cut, task=task_name)
+            for res, enc in zip(results, encodings):
+                res.truncated = enc.truncated
+        return results
 
     def _run_fused_batch(self, gid: str, bucket: int,
                          items: List[BatchItem]) -> Sequence[Any]:

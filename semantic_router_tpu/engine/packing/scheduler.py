@@ -51,7 +51,9 @@ class PackingBatcher(DynamicBatcher):
                  max_inflight_steps: int = 2,
                  starvation_steps: int = 4,
                  segment_cap_of: Optional[Callable[[Hashable],
-                                                   int]] = None) -> None:
+                                                   int]] = None,
+                 patient: Optional[Callable[[Hashable], bool]] = None
+                 ) -> None:
         # knobs must exist BEFORE the base class starts the picker
         # thread (it may call the hooks immediately)
         self.enabled = bool(enabled)
@@ -71,7 +73,7 @@ class PackingBatcher(DynamicBatcher):
         super().__init__(runner, max_batch_size=max_batch_size,
                          max_wait_ms=max_wait_ms, name=name,
                          dispatch_workers=dispatch_workers,
-                         metrics=metrics)
+                         metrics=metrics, patient=patient)
 
     # -- knob application --------------------------------------------------
 

@@ -21,6 +21,7 @@ Design notes (XLA-native, no torch-style dynamic shapes):
 
 from __future__ import annotations
 
+import contextlib
 import re
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -35,6 +36,9 @@ from .lora import LoRAConfig, LoRADense
 from .qwen3 import Qwen3Config, RMSNorm
 
 NEG_INF = -1e30
+# tokens a guard's verdict takes: a generator's ``gen_length`` unless its
+# task's ``generation.gen_length`` says otherwise
+GEN_LENGTH = 32
 
 
 def _rotary_at(x: jnp.ndarray, cos: jnp.ndarray,
@@ -231,10 +235,74 @@ class GenerationResult:
     finished: bool  # hit EOS (vs ran out of budget)
     prompt_tokens: int = 0
     completion_tokens: int = 0
+    # the engine cut the prompt to its largest bucket (engine.generate)
+    truncated: bool = False
+    # block generators: one entry per forward that touched this request
+    # (BlockDiffusionGenerator.generate says what an entry holds)
+    trajectory: Optional[List[Dict[str, Any]]] = None
 
 
 def _round_up(n: int, mult: int) -> int:
     return ((n + mult - 1) // mult) * mult
+
+
+def _one_token(token: int):
+    """A one-token prompt (warm-up: no tokenizer has a say)."""
+    from ..utils.tokenization import Encoding
+
+    return Encoding(ids=[token], attention_mask=[1], offsets=[(0, 0)])
+
+
+def _as_batch(tokenizer, prompts, encodings, bucket, padded_rows):
+    """The engine's batch as given, or the prompts tokenized here and
+    padded to their own longest."""
+    encs = encodings if encodings is not None else \
+        [tokenizer.encode(p) for p in prompts]
+    longest = max(len(e) for e in encs)
+    bucket = bucket or _round_up(longest, 32)
+    if longest > bucket:
+        # the engine cuts such a prompt, flags and counts it
+        # (engine.generate); nothing is dropped silently here
+        raise ValueError(f"a prompt of {longest} tokens does not fit the "
+                         f"bucket of {bucket}")
+    return encs, bucket, padded_rows or len(encs)
+
+
+class _NullForward:
+    """One forward nobody watches: the shape of what an observer's
+    ``forward()`` returns (the engine's opens an ``engine.step``)."""
+
+    def stage(self, name: str):
+        return contextlib.nullcontext()
+
+    def done(self, **after) -> None:
+        pass
+
+
+class NullObserver:
+    """``forward(flavour, **facts)`` before every device forward of a
+    generation; its ``stage(name)`` brackets the five host stages and
+    ``done(**after)`` closes it with what only the readback knows."""
+
+    def forward(self, flavour: str, **facts) -> _NullForward:
+        return _NullForward()
+
+
+def _finish_tokens(tokenizer, tokens: List[int], eos_ids, stop_strings,
+                   prompt_tokens: int, trajectory=None) -> GenerationResult:
+    """Cut at the first end-of-sequence token and at a stop string."""
+    cut = next((i for i, t in enumerate(tokens) if t in eos_ids), None)
+    kept = tokens if cut is None else tokens[:cut]
+    text = tokenizer.decode(kept)
+    for stop in stop_strings:
+        idx = text.find(stop)
+        if idx >= 0:
+            text = text[:idx]
+    return GenerationResult(
+        text=text, token_ids=kept, finished=cut is not None,
+        prompt_tokens=prompt_tokens,
+        completion_tokens=len(kept) + (cut is not None),
+        trajectory=trajectory)
 
 
 class GreedyGenerator:
@@ -244,8 +312,10 @@ class GreedyGenerator:
     def __init__(self, config: Qwen3Config, params,
                  tokenizer, lora: Optional[LoRAConfig] = None,
                  eos_token_ids: Sequence[int] = (),
-                 pad_id: int = 0, cache_dtype=None) -> None:
+                 pad_id: int = 0, cache_dtype=None,
+                 gen_length: int = GEN_LENGTH) -> None:
         self.config = config
+        self.gen_length = int(gen_length)
         self.module = Qwen3Decoder(config, lora)
         self.params = params
         self.tokenizer = tokenizer
@@ -280,72 +350,346 @@ class GreedyGenerator:
                 fn, static_argnames=())
         return self._step_cache[key]
 
+    batched = True  # generate() takes the engine's batch (encodings=...)
+
+    def warm(self, rows: int, bucket: int) -> None:
+        """Compile and run the two programs of ``(rows, bucket)`` at the
+        cache length of ``gen_length`` tokens: two tokens of them."""
+        self.generate([], self.gen_length,
+                      encodings=[_one_token(self.pad_id)], bucket=bucket,
+                      padded_rows=rows, _steps=2)
+
     def generate(self, prompts: Sequence[str], max_new_tokens: int = 64,
-                 task_index: int = 0,
-                 stop_strings: Sequence[str] = ()) -> List[GenerationResult]:
-        encs = [self.tokenizer.encode(p) for p in prompts]
-        B = len(encs)
-        lengths = np.asarray([len(e) for e in encs], np.int32)
-        S = _round_up(int(lengths.max()), 32)
+                 task_index: int = 0, stop_strings: Sequence[str] = (), *,
+                 encodings=None, bucket: Optional[int] = None,
+                 padded_rows: Optional[int] = None, observer=None,
+                 _steps: Optional[int] = None) -> List[GenerationResult]:
+        """``prompts`` as one batch in lock step: one prefill
+        (``gen.prefill``), then one token a row a step (``gen.decode``)
+        until every row hit an end-of-sequence token or
+        ``max_new_tokens``.  The engine's batch runner passes the batch it
+        composed: ``encodings`` (the prompts, tokenized by the callers),
+        the prompt ``bucket`` they are padded to, ``padded_rows`` (a
+        padding row is a one-token prompt that counts as finished) and an
+        ``observer`` of the forwards.  Without them the prompts are
+        tokenized here and padded to their own longest."""
+        encs, bucket, padded_rows = _as_batch(
+            self.tokenizer, prompts, encodings, bucket, padded_rows)
+        obs = observer or NullObserver()
+        n, B, S = len(encs), padded_rows, bucket
+        lengths = np.ones(B, np.int32)
+        lengths[:n] = [len(e) for e in encs]
         M = _round_up(S + max_new_tokens + 1, 64)
+        max_new_tokens = _steps or max_new_tokens
 
-        ids = np.full((B, S), self.pad_id, np.int32)
-        mask = np.zeros((B, M), bool)
-        for i, e in enumerate(encs):
-            ids[i, :len(e)] = e.ids
-            mask[i, :len(e)] = True
-        positions = np.broadcast_to(np.arange(S, dtype=np.int32), (B, S))
-
-        caches = self._init_caches(B, M)
-        prefill = self._prefill_fn((B, S, M))
-        task_arr = jnp.asarray(task_index)
-        logits, caches = prefill(self.params, jnp.asarray(ids), caches,
-                                 jnp.asarray(mask), jnp.asarray(positions),
-                                 task_arr)
-        # next token comes from each row's LAST REAL position
-        last = np.asarray(jax.device_get(
-            jnp.take_along_axis(
-                logits, jnp.asarray(lengths - 1)[:, None, None], axis=1)
-            [:, 0]), np.float32)
-        next_tok = last.argmax(-1).astype(np.int32)
+        fwd = obs.forward("gen.prefill", tokens_real=int(lengths[:n].sum()))
+        with fwd.stage("stack"):
+            ids = np.full((B, S), self.pad_id, np.int32)
+            mask = np.zeros((B, M), bool)
+            for i, e in enumerate(encs):
+                ids[i, :lengths[i]] = e.ids[:lengths[i]]
+            mask[:, :S] = np.arange(S)[None, :] < lengths[:, None]
+            positions = np.broadcast_to(np.arange(S, dtype=np.int32), (B, S))
+        with fwd.stage("h2d"):
+            caches = self._init_caches(B, M)
+            args = (jnp.asarray(ids), caches, jnp.asarray(mask),
+                    jnp.asarray(positions), jnp.asarray(task_index))
+        with fwd.stage("dispatch"):
+            logits, caches = self._prefill_fn((B, S, M))(self.params, *args)
+            # next token comes from each row's LAST REAL position
+            last = jnp.take_along_axis(
+                logits, jnp.asarray(lengths - 1)[:, None, None], axis=1)[:, 0]
+        with fwd.stage("readback"):
+            next_tok = np.asarray(jax.device_get(last), np.float32) \
+                .argmax(-1).astype(np.int32)
+        fwd.done()
 
         out_tokens: List[List[int]] = [[] for _ in range(B)]
         finished = np.zeros(B, bool)
+        finished[n:] = True
         step = self._step_fn((B, 1, M))
-        np_mask = mask
+        task_arr = jnp.asarray(task_index)
         for t in range(max_new_tokens):
-            for i in range(B):
+            for i in range(n):
                 if not finished[i]:
                     out_tokens[i].append(int(next_tok[i]))
                     if int(next_tok[i]) in self.eos_token_ids:
                         finished[i] = True
-            if finished.all():
+            if finished.all() or t == max_new_tokens - 1:
                 break
-            write_index = S + t
-            np_mask = np_mask.copy()
-            np_mask[:, write_index] = True
-            pos = (lengths + t)[:, None].astype(np.int32)
-            logits, caches = step(self.params, jnp.asarray(
-                next_tok[:, None]), caches, jnp.asarray(np_mask),
-                jnp.asarray(pos), write_index, task_arr)
-            next_tok = np.asarray(
-                jax.device_get(logits[:, 0]), np.float32
-            ).argmax(-1).astype(np.int32)
+            fwd = obs.forward("gen.decode", tokens_real=n, block=t)
+            with fwd.stage("stack"):
+                write_index = S + t
+                mask = mask.copy()
+                mask[:, write_index] = True
+                pos = (lengths + t)[:, None].astype(np.int32)
+            with fwd.stage("h2d"):
+                args = (jnp.asarray(next_tok[:, None]), caches,
+                        jnp.asarray(mask), jnp.asarray(pos))
+            with fwd.stage("dispatch"):
+                logits, caches = step(self.params, *args, write_index,
+                                      task_arr)
+            with fwd.stage("readback"):
+                next_tok = np.asarray(
+                    jax.device_get(logits[:, 0]), np.float32
+                ).argmax(-1).astype(np.int32)
+            fwd.done()
+        return [_finish_tokens(self.tokenizer, out_tokens[i],
+                               self.eos_token_ids, stop_strings,
+                               int(lengths[i])) for i in range(n)]
 
-        results = []
-        for i in range(B):
-            toks = [tk for tk in out_tokens[i]
-                    if tk not in self.eos_token_ids]
-            text = self.tokenizer.decode(toks)
-            for stop in stop_strings:
-                idx = text.find(stop)
-                if idx >= 0:
-                    text = text[:idx]
-            results.append(GenerationResult(
-                text=text, token_ids=toks, finished=bool(finished[i]),
-                prompt_tokens=int(lengths[i]),
-                completion_tokens=len(out_tokens[i])))
-        return results
+
+# ---------------------------------------------------------------------------
+# block diffusion: a block of tokens a step
+# ---------------------------------------------------------------------------
+
+
+def _top_k_by_argmax(x: jnp.ndarray, k: int):
+    """The ``k`` largest of the last axis, largest first, ties to the lower
+    index: ``k`` argmax passes (a sort of 150k entries a row is the
+    alternative)."""
+    vals, ids = [], []
+    for _ in range(k):
+        i = jnp.argmax(x, axis=-1)
+        vals.append(jnp.take_along_axis(x, i[..., None], -1)[..., 0])
+        ids.append(i)
+        x = jnp.where(jnp.arange(x.shape[-1]) == i[..., None], -jnp.inf, x)
+    return jnp.stack(vals, -1), jnp.stack(ids, -1)
+
+
+def transfer_by_confidence(logits, tokens, masked, threshold, at_least,
+                           top_logits: int):
+    """One denoising step on ``logits [B, L, V]`` (float32): ``x0 =
+    argmax``, confidence its softmax probability; a row's masked positions
+    whose confidence exceeds ``threshold`` are filled, and never fewer than
+    ``at_least`` of them (the most confident; ``low_confidence_dynamic``).
+    Returns the new tokens and mask and ``report [B, L, 4 + 2 top]``
+    (float32: token after, filled, confidence, log-sum-exp, the ids of the
+    ``top`` largest logits, their values)."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    top_v, top_i = _top_k_by_argmax(logits, top_logits)
+    x0, conf = top_i[..., 0], jnp.exp(top_v[..., 0] - lse)
+    conf_m = jnp.where(masked, conf, -jnp.inf)
+    high = masked & (conf_m > threshold)
+    need = jnp.minimum(at_least, masked.sum(-1))[:, None]
+    # rank by confidence, ties to the lower position
+    before = (conf_m[:, None, :] > conf_m[:, :, None]) | (
+        (conf_m[:, None, :] == conf_m[:, :, None])
+        & (jnp.arange(conf_m.shape[-1])[None, None, :]
+           < jnp.arange(conf_m.shape[-1])[None, :, None]))
+    forced = masked & (before.sum(-1) < need)
+    filled = jnp.where(high.sum(-1, keepdims=True) >= need, high, forced)
+    new_tokens = jnp.where(filled, x0, tokens).astype(tokens.dtype)
+    report = jnp.concatenate(
+        [jnp.stack([new_tokens.astype(jnp.float32),
+                    filled.astype(jnp.float32), conf, lse], -1),
+         top_i.astype(jnp.float32), top_v], -1)
+    return new_tokens, masked & ~filled, report
+
+
+class BlockDiffusionGenerator:
+    """Generation by diffusion over blocks (the ``sdar_moe`` family): the
+    prompt's whole blocks are prefilled under the block-causal mask and
+    committed to the cache; then, a block of ``block_length`` positions at
+    a time, the block (the prompt's trailing partial block, then ``[MASK]``)
+    is denoised by up to ``denoising_steps`` forwards against the committed
+    cache, none of which writes it, each filling the masked positions it is
+    confident of; when no mask is left in any row, one more forward of the
+    final tokens writes the block's K and V (commit).
+
+    Rows run in lock step; programs are keyed by ``(rows, prompt bucket,
+    cache length)``: ``prefill``, ``denoise`` (reads the cache; returns the
+    block's new state on the device and one small report for the host),
+    ``commit``.  One cache layout, ``[rows, kv_heads, M, head_dim]`` a
+    layer in the model's dtype, ``M`` = bucket + generated + block - 1
+    rounded up to 64.  The loop is the host's: it reads one report a
+    forward (which is what tells it when a block is done) and so can open
+    one ``engine.step`` a forward."""
+
+    def __init__(self, config, params, tokenizer, *, mask_token_id: int,
+                 block_length: int = 4, denoising_steps: int = 4,
+                 confidence_threshold: float = 0.9,
+                 gen_length: int = GEN_LENGTH,
+                 eos_token_ids: Sequence[int] = (), pad_id: int = 0,
+                 top_logits: int = 8) -> None:
+        self.config = config
+        self.params = params
+        self.tokenizer = tokenizer
+        self.mask_token_id = int(mask_token_id)
+        self.block_length = int(block_length)
+        self.denoising_steps = int(denoising_steps)
+        self.confidence_threshold = float(confidence_threshold)
+        self.gen_length = int(gen_length)
+        self.eos_token_ids = set(int(t) for t in eos_token_ids)
+        self.pad_id = pad_id
+        self.top_logits = top_logits
+        self._programs: Dict[Tuple[int, int, int], Tuple] = {}
+
+    def cache_len(self, bucket: int, new_tokens: int) -> int:
+        return _round_up(bucket + new_tokens + self.block_length - 1, 64)
+
+    def programs(self, rows: int, bucket: int, cache_len: int):
+        key = (rows, bucket, cache_len)
+        if key not in self._programs:
+            from . import sdar_moe as M
+
+            cfg, L = self.config, self.block_length
+
+            def prefill(params, ids, committed):
+                return M.prefill(cfg, params, ids, committed, cache_len, L)
+
+            def denoise(params, caches, tokens, masked, start, rows_valid,
+                        at_least):
+                logits, _, experts, load = M.block_forward(
+                    cfg, params, caches, tokens, start, rows_valid,
+                    write=False, head=True)
+                with jax.named_scope("transfer"):
+                    tokens, masked, report = transfer_by_confidence(
+                        logits, tokens, masked, self.confidence_threshold,
+                        at_least, self.top_logits)
+                return tokens, masked, report, experts, load
+
+            def commit(params, caches, tokens, start, rows_valid):
+                _, caches, experts, load = M.block_forward(
+                    cfg, params, caches, tokens, start, rows_valid,
+                    write=True, head=False)
+                return caches, experts, load
+
+            self._programs[key] = (jax.jit(prefill), jax.jit(denoise),
+                                   jax.jit(commit, donate_argnums=(1,)))
+        return self._programs[key]
+
+    def transfer_schedule(self) -> List[int]:
+        """Positions to fill at least, by denoising step: the block's
+        length spread evenly, the remainder to the first steps."""
+        base, extra = divmod(self.block_length, self.denoising_steps)
+        return [base + (i < extra) for i in range(self.denoising_steps)]
+
+    batched = True  # generate() takes the engine's batch (encodings=...)
+
+    def warm(self, rows: int, bucket: int) -> None:
+        """Compile and run the three programs of ``(rows, bucket)`` at the
+        cache length of ``gen_length`` tokens: one block of a one-token
+        prompt."""
+        self.generate([], encodings=[_one_token(self.pad_id)], bucket=bucket,
+                      padded_rows=rows, _blocks=1)
+
+    def generate(self, prompts: Sequence[str],
+                 max_new_tokens: Optional[int] = None, task_index: int = 0,
+                 stop_strings: Sequence[str] = (), *, encodings=None,
+                 bucket: Optional[int] = None,
+                 padded_rows: Optional[int] = None, observer=None,
+                 _blocks: Optional[int] = None) -> List[GenerationResult]:
+        """``prompts`` as one batch in lock step (the engine's batch runner
+        passes ``encodings``, ``bucket``, ``padded_rows`` and ``observer``
+        as to ``GreedyGenerator.generate``).  A result's ``trajectory`` has
+        one entry per forward while the request still generated: ``kind``
+        (``denoise`` | ``commit``), ``block``, ``tokens`` (the block's
+        state that went in), ``masked`` (which of them were ``[MASK]``),
+        ``experts [layers, L, k]`` (the router's choice), and for a denoise
+        ``filled``, ``tokens_after``, ``confidence``, ``lse`` and
+        ``top_ids`` / ``top_logits [L, top]`` (float32) — at a masked
+        position the row of logits that scored it.  ``task_index`` is
+        accepted for the engine's one runner (no adapters here)."""
+        encs, bucket, padded_rows = _as_batch(
+            self.tokenizer, prompts, encodings, bucket, padded_rows)
+        obs = observer or NullObserver()
+        L, n, B = self.block_length, len(encs), padded_rows
+        new_tokens = max_new_tokens or self.gen_length
+        M = self.cache_len(bucket, new_tokens)
+        prefill, denoise, commit = self.programs(B, bucket, M)
+        lengths = np.zeros(B, np.int32)
+        lengths[:n] = [len(e) for e in encs]
+        base = lengths // L * L
+        tail = lengths - base
+        blocks_of = -(-(tail + new_tokens) // L)  # a row's own count
+        n_blocks = _blocks or int(blocks_of[:n].max())
+        rows_valid = np.arange(B) < n
+        schedule = self.transfer_schedule()
+
+        fwd = obs.forward("gen.prefill", tokens_real=int(base.sum()))
+        with fwd.stage("stack"):
+            ids = np.full((B, bucket), self.pad_id, np.int32)
+            for i, e in enumerate(encs):
+                ids[i, :lengths[i]] = e.ids[:lengths[i]]
+        with fwd.stage("h2d"):
+            ids_dev, base_dev = jnp.asarray(ids), jnp.asarray(base)
+            valid_dev = jnp.asarray(rows_valid)
+        with fwd.stage("dispatch"):
+            caches, load = prefill(self.params, ids_dev, base_dev)
+        with fwd.stage("readback"):
+            load = np.asarray(jax.device_get(load))
+        fwd.done(load=load)
+
+        generated: List[List[int]] = [[] for _ in range(n)]
+        trajectory: List[List[Dict[str, Any]]] = [[] for _ in range(n)]
+        for b in range(n_blocks):
+            tokens = np.full((B, L), self.mask_token_id, np.int32)
+            masked = np.broadcast_to(rows_valid[:, None], (B, L)).copy()
+            if b == 0:
+                for i in range(n):
+                    tokens[i, :tail[i]] = ids[i, base[i]:lengths[i]]
+                    masked[i, :tail[i]] = False
+            live = [i for i in range(n) if b < blocks_of[i]]
+            start_dev = jnp.asarray(base + b * L)
+            tokens_dev, masked_dev = jnp.asarray(tokens), jnp.asarray(masked)
+            for step in range(self.denoising_steps):
+                if not masked.any():
+                    break
+                fwd = obs.forward("gen.denoise", tokens_real=n * L, block=b,
+                                  masks_left=int(masked.sum()))
+                with fwd.stage("dispatch"):
+                    tokens_dev, masked_dev, report, experts, load = denoise(
+                        self.params, caches, tokens_dev, masked_dev,
+                        start_dev, valid_dev, schedule[step])
+                with fwd.stage("readback"):
+                    report, experts, load = (np.asarray(a) for a in
+                                             jax.device_get(
+                                                 (report, experts, load)))
+                with fwd.stage("demux"):
+                    k = self.top_logits
+                    after = report[..., 0].astype(np.int32)
+                    filled = report[..., 1] > 0.5
+                    for i in live:
+                        if masked[i].any():
+                            trajectory[i].append({
+                                "kind": "denoise", "block": b,
+                                "tokens": tokens[i].copy(),
+                                "masked": masked[i].copy(),
+                                "filled": filled[i],
+                                "tokens_after": after[i],
+                                "confidence": report[i, :, 2],
+                                "lse": report[i, :, 3],
+                                "top_ids": report[i, :, 4:4 + k]
+                                .astype(np.int32),
+                                "top_logits": report[i, :, 4 + k:],
+                                "experts": experts[:, i]})
+                    tokens, masked = after, masked & ~filled
+                fwd.done(load=load)
+            fwd = obs.forward("gen.commit", tokens_real=n * L, block=b,
+                              masks_left=0)
+            with fwd.stage("dispatch"):
+                caches, experts, load = commit(
+                    self.params, caches, tokens_dev, start_dev, valid_dev)
+            with fwd.stage("readback"):
+                experts, load = (np.asarray(a) for a in
+                                 jax.device_get((experts, load)))
+            with fwd.stage("demux"):
+                for i in live:
+                    trajectory[i].append({
+                        "kind": "commit", "block": b,
+                        "tokens": tokens[i].copy(),
+                        "masked": np.zeros(L, bool),
+                        "experts": experts[:, i]})
+                    generated[i].extend(
+                        int(t) for t in tokens[i, tail[i] if b == 0 else 0:])
+            fwd.done(load=load, committed_blocks=len(live),
+                     committed_tokens=len(live) * L)
+        del caches
+        return [_finish_tokens(self.tokenizer, generated[i][:new_tokens],
+                               self.eos_token_ids, stop_strings,
+                               int(lengths[i]), trajectory[i])
+                for i in range(n)]
 
 
 def with_lora_leaves(config: Qwen3Config, lora: LoRAConfig, base_params,
@@ -395,10 +739,17 @@ class GuardVerdict:
     categories: List[str] = field(default_factory=list)
     refusal: Optional[bool] = None
     raw: str = ""
+    # the guard read a prompt cut to its largest bucket: the text's end
+    # went unread (engine.guard_classify)
+    truncated: bool = False
 
     @property
     def is_safe(self) -> bool:
         return self.safety == "Safe"
+
+
+# the template's closing words: what a cut of an over-long prompt keeps
+GUARD_PROMPT_TAIL = "\n\nClassification:\n"
 
 
 def build_guard_prompt(text: str, role: str = "user") -> str:
@@ -410,7 +761,7 @@ def build_guard_prompt(text: str, role: str = "user") -> str:
         f"Safety: Safe, Unsafe, or Controversial\n"
         f"Categories: comma-separated categories, or None\n"
         + (f"Refusal: Yes or No\n" if role == "assistant" else "")
-        + f"\n{role} message:\n{text}\n\nClassification:\n")
+        + f"\n{role} message:\n{text}" + GUARD_PROMPT_TAIL)
 
 
 def parse_guard_output(text: str) -> GuardVerdict:

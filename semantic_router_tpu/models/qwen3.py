@@ -33,6 +33,13 @@ from ..ops.attention import NEG_INF, chunked_sdpa, sdpa
 from ..ops.rope import RopeSpec, apply_rotary
 
 
+def torch_dtype_of(name) -> Any:
+    """A checkpoint's ``torch_dtype`` (``"bfloat16"``, ``torch.bfloat16``)
+    as the dtype a model computes in; anything unknown is float32."""
+    return {"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(
+        str(name).replace("torch.", ""), jnp.float32)
+
+
 @dataclasses.dataclass(frozen=True)
 class Qwen3Config:
     vocab_size: int = 151936
@@ -70,6 +77,7 @@ class Qwen3Config:
             attention_bias=g("attention_bias", False),
             tie_word_embeddings=g("tie_word_embeddings", True),
             rope_scaling=g("rope_scaling", None),
+            dtype=torch_dtype_of(g("torch_dtype", "float32")),
         )
 
 

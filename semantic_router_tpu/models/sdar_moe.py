@@ -1,0 +1,379 @@
+"""The ``sdar_moe`` decoder: a Qwen3-shaped pre-norm block whose MLP is a
+bank of sparse SwiGLU experts, decoded a BLOCK of tokens at a time
+(block diffusion: block-causal attention against a causal cache, the block
+itself bidirectional).
+
+Layer equations (``chipbench/reference/sdar_moe.py`` is the plain form):
+``x <- x + Attn(RMSNorm(x))``, ``x <- x + MoE(RMSNorm(x))``; GQA with
+per-head RMSNorm on q and k, RoPE at absolute positions, no bias;
+``MoE(x) = sum over the top-k experts of w_e * down_e(silu(gate_e x) *
+up_e x)`` with ``w`` the router's softmax over ALL experts, renormalised
+over the k chosen.
+
+The expert layer is told which experts it holds (``experts_held = (first,
+count)``): the router keeps its published width and its experts per token,
+the routed (token, expert) pairs are sorted by expert and only the pairs of
+experts held here go through the grouped matmul; what an expert that lives
+elsewhere would add is left out (on one chip there is no exchange, and no
+stand-in for one).  No token is dropped and nothing is padded to a
+capacity.
+
+Functions over a plain parameter tree, not flax modules: the experts are
+stacked ``[experts held, H, 2I]`` / ``[experts held, I, H]`` arrays that a
+grouped matmul indexes by group.  Scopes on the device timeline:
+``embed_tokens``, ``layers_<i>/attn``, ``layers_<i>/moe`` (``moe/router``,
+``moe/gmm``), ``lm_head``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+from typing import Any, Callable, Dict, Mapping, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.flash_attention import flash_attention
+from ..ops.rope import RopeSpec, apply_rotary
+from .qwen3 import torch_dtype_of
+
+NEG_INF = -1e30
+
+
+@dataclasses.dataclass(frozen=True)
+class SdarMoeConfig:
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1000000.0
+    max_position_embeddings: int = 32768
+    attention_bias: bool = False
+    tie_word_embeddings: bool = False
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 768
+    norm_topk_prob: bool = True
+    dtype: Any = jnp.bfloat16
+    # (first, count) of the experts this chip holds; None = all of them
+    experts_held: Optional[Tuple[int, int]] = None
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.num_experts)
+
+    @classmethod
+    def from_hf(cls, hf: Mapping[str, Any], **overrides) -> "SdarMoeConfig":
+        """From a checkpoint's ``config.json`` (``model_type: sdar_moe``).
+        What the architecture cannot express is refused, not ignored."""
+        if hf.get("attention_bias", False):
+            raise ValueError("sdar_moe: attention_bias is not supported")
+        if hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1:
+            raise ValueError("sdar_moe: every layer must be sparse "
+                             "(mlp_only_layers [], decoder_sparse_step 1)")
+        if hf.get("rope_scaling"):
+            raise ValueError("sdar_moe: rope_scaling is not supported")
+        if hf.get("use_sliding_window", False):
+            raise ValueError("sdar_moe: sliding window is not supported")
+        fields = {f.name for f in dataclasses.fields(cls)}
+        kw = {k: v for k, v in hf.items() if k in fields}
+        kw.setdefault("head_dim", hf["hidden_size"]
+                      // hf["num_attention_heads"])
+        kw["dtype"] = torch_dtype_of(hf.get("torch_dtype", "bfloat16"))
+        kw["rope_theta"] = float(kw.get("rope_theta", 1e6))
+        kw.update(overrides)
+        return cls(**kw)
+
+
+# -- parameters ------------------------------------------------------------------
+
+
+def params_from_checkpoint(path: str, cfg: SdarMoeConfig) -> Dict[str, Any]:
+    """A checkpoint directory (``model.safetensors``, or sharded files
+    with ``model.safetensors.index.json``) as this module's tree."""
+    from safetensors import safe_open
+
+    index = os.path.join(path, "model.safetensors.index.json")
+    if os.path.exists(index):
+        with open(index) as f:
+            where = json.load(f)["weight_map"]
+    else:
+        with safe_open(os.path.join(path, "model.safetensors"), "np") as f:
+            where = {k: "model.safetensors" for k in f.keys()}
+    handles: Dict[str, Any] = {}
+
+    def get(name: str) -> np.ndarray:
+        fname = where[name]
+        if fname not in handles:
+            handles[fname] = safe_open(os.path.join(path, fname), "np")
+        return handles[fname].get_tensor(name)
+
+    try:
+        return params_from_state(get, cfg)
+    finally:
+        handles.clear()
+
+
+def params_from_state(get: Callable[[str], np.ndarray], cfg: SdarMoeConfig
+                      ) -> Dict[str, Any]:
+    """The published tensor names (one ``[I, H]`` matrix per expert and
+    projection; ``get(name)`` loads one) as this module's tree, in
+    ``cfg.dtype`` on the default device.  Only the experts held are read.
+    A layer's expert matrices are stacked on the host and transposed on
+    the device."""
+
+    def dev(a: np.ndarray, transpose: bool = False) -> jnp.ndarray:
+        x = jnp.asarray(a).astype(cfg.dtype)
+        return jnp.swapaxes(x, -1, -2) if transpose else x
+
+    first, count = cfg.held
+    layers = []
+    for i in range(cfg.num_hidden_layers):
+        p = f"model.layers.{i}."
+        experts = {k: np.stack([get(f"{p}mlp.experts.{e}.{k}_proj.weight")
+                                for e in range(first, first + count)])
+                   for k in ("gate", "up", "down")}
+        layers.append({
+            "norm1": dev(get(p + "input_layernorm.weight")),
+            "norm2": dev(get(p + "post_attention_layernorm.weight")),
+            "q_proj": dev(get(p + "self_attn.q_proj.weight"), True),
+            "k_proj": dev(get(p + "self_attn.k_proj.weight"), True),
+            "v_proj": dev(get(p + "self_attn.v_proj.weight"), True),
+            "o_proj": dev(get(p + "self_attn.o_proj.weight"), True),
+            "q_norm": dev(get(p + "self_attn.q_norm.weight")),
+            "k_norm": dev(get(p + "self_attn.k_norm.weight")),
+            "router": dev(get(p + "mlp.gate.weight"), True),
+            "gate_up": jnp.concatenate(
+                [dev(experts["gate"], True), dev(experts["up"], True)], -1),
+            "down": dev(experts["down"], True)})
+        del experts
+    embed = dev(get("model.embed_tokens.weight"))
+    params = {"embed": embed, "layers": layers,
+              "norm": dev(get("model.norm.weight")),
+              "lm_head": embed.T if cfg.tie_word_embeddings
+              else dev(get("lm_head.weight"), True)}
+    return params
+
+
+# -- layers ----------------------------------------------------------------------
+
+
+def rms_norm(x, w, eps: float, dtype):
+    xf = x.astype(jnp.float32)
+    out = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (out * w.astype(jnp.float32)).astype(dtype)
+
+
+def qkv(cfg: SdarMoeConfig, p, x, positions, table_len: int):
+    """``x [B, S, H]`` -> q ``[B, S, heads, D]``, k and v ``[B, S, kv, D]``,
+    q and k normalised per head and rotated at ``positions [B, S]``, all
+    below ``table_len``."""
+    B, S, _ = x.shape
+    nh, nkv, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    q = (x @ p["q_proj"]).reshape(B, S, nh, D)
+    k = (x @ p["k_proj"]).reshape(B, S, nkv, D)
+    v = (x @ p["v_proj"]).reshape(B, S, nkv, D)
+    q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps, cfg.dtype)
+    k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps, cfg.dtype)
+    cos_t, sin_t = RopeSpec(D, cfg.rope_theta).tables(table_len)
+    cos = jnp.take(cos_t, positions, axis=0)[:, :, None, :]
+    sin = jnp.take(sin_t, positions, axis=0)[:, :, None, :]
+    q, k = apply_rotary(q, k, cos, sin)  # float32 inside
+    return q, k, v
+
+
+def _on_cpu() -> bool:
+    return jax.default_backend() == "cpu"
+
+
+def _megablox(lhs, rhs, group_sizes):
+    """The Pallas megablox kernel (interpreted on the CPU, for tests).
+    Rows a tile: 128 reads a touched expert's matrices once for its handful
+    of rows (256: 1.55 ms a layer of a block forward, 512: 3.06); from
+    8192 rows on, 256 (a prefill: 11.2 against 11.7 ms)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    tiling = (min(256 if m >= 8192 else 128, m), min(1024, k), min(768, n))
+    return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+               tiling=tiling, interpret=_on_cpu())
+
+
+def _grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs [m, k]`` rows sorted by group times ``rhs [groups, k, n]``.
+    On a TPU the megablox kernel: at the published widths on a v5e
+    (benchmarks/moe_gmm_bench.py, PERF.md section 6, PR 28) a block
+    forward's 512 pairs over 90 touched experts take 1.30 ms a layer
+    against 2.89 for ``jax.lax.ragged_dot`` (the experts' matrices alone
+    are 1.04 ms of HBM), a 16 x 512 prefill 11.2 against 12.9.  On the CPU
+    ``ragged_dot``, the same sums."""
+    if _on_cpu():
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    return _megablox(lhs, rhs, group_sizes)
+
+
+def moe(cfg: SdarMoeConfig, p, x, valid):
+    """``x [T, H]``, ``valid [T]`` (a padding token routes nowhere).
+    Returns ``(y [T, H], top_e [T, k], load [4])`` with ``load`` = the
+    busiest held expert's pairs, the pairs computed here, the number of
+    held experts that got any, and the busiest's pairs over the mean."""
+    T, H = x.shape
+    k, I = cfg.num_experts_per_tok, cfg.moe_intermediate_size
+    first, count = cfg.held
+    with jax.named_scope("router"):
+        logits = jnp.dot(x, p["router"], preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = jax.lax.top_k(probs, k)
+        if cfg.norm_topk_prob:
+            top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    with jax.named_scope("sort"):
+        local = top_e.reshape(-1) - first
+        here = (local >= 0) & (local < count) & jnp.repeat(valid, k)
+        group = jnp.where(here, local, count)  # elsewhere: after the last
+        order = jnp.argsort(group, stable=True)
+        group_sizes = jnp.bincount(group, length=count + 1)[:count] \
+            .astype(jnp.int32)
+        xs = jnp.take(x, order // k, axis=0)
+    with jax.named_scope("gmm"):
+        gu = _grouped_matmul(xs, p["gate_up"], group_sizes)
+        h = (jax.nn.silu(gu[:, :I].astype(jnp.float32))
+             * gu[:, I:].astype(jnp.float32)).astype(cfg.dtype)
+        ys = _grouped_matmul(h, p["down"], group_sizes)
+    with jax.named_scope("combine"):
+        w = jnp.where(here, top_p.reshape(-1), 0.0)
+        # rows past the held groups were not computed: whatever stands
+        # there is dropped, not weighted by zero
+        ys = jnp.where(jnp.take(here, order)[:, None],
+                       ys.astype(jnp.float32), 0.0)
+        back = jnp.argsort(order)
+        y = (jnp.take(ys, back, axis=0).reshape(T, k, H)
+             * w.reshape(T, k, 1)).sum(1)
+    busiest, pairs = group_sizes.max(), group_sizes.sum()
+    load = jnp.stack([busiest, pairs, (group_sizes > 0).sum(),
+                      busiest * count / jnp.maximum(pairs, 1)]
+                     ).astype(jnp.float32)
+    return y.astype(cfg.dtype), top_e, load
+
+
+def _moe_block(cfg, p, x, valid):
+    """The second half of a layer on ``x [B, S, H]``."""
+    B, S, H = x.shape
+    with jax.named_scope("moe"):
+        h = rms_norm(x, p["norm2"], cfg.rms_norm_eps, cfg.dtype)
+        y, top_e, load = moe(cfg, p, h.reshape(B * S, H), valid.reshape(-1))
+    return x + y.reshape(B, S, H), top_e.reshape(B, S, -1), load
+
+
+# -- prefill: whole prompt blocks under the block-causal mask --------------------
+
+
+def prefill(cfg: SdarMoeConfig, params, ids, committed, cache_len: int,
+            block_length: int):
+    """``ids [B, S]`` right-padded prompts, ``committed [B]`` the number of
+    leading tokens in whole blocks (``len // L * L``): their K and V go to
+    the cache's columns ``[0, committed)``.  Whatever lies beyond is
+    computed and never seen (a key is visible iff it is committed and
+    ``key // L <= query // L``); the first generated block overwrites it.
+    Returns ``(caches [(k, v) [B, kv, M, D]] per layer, load [layers, 4])``;
+    no head: the first block's forward scores the first tokens."""
+    B, S = ids.shape
+    nkv, D = cfg.num_key_value_heads, cfg.head_dim
+    rep = cfg.num_attention_heads // nkv
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    valid = positions < committed[:, None]
+    with jax.named_scope("embed_tokens"):
+        x = jnp.take(params["embed"], ids, axis=0)
+    caches, loads = [], []
+    for i, p in enumerate(params["layers"]):
+        with jax.named_scope(f"layers_{i}"):
+            with jax.named_scope("attn"):
+                h = rms_norm(x, p["norm1"], cfg.rms_norm_eps, cfg.dtype)
+                q, k, v = qkv(cfg, p, h, positions, S)
+                kc, vc = (jnp.moveaxis(t, 2, 1) for t in (k, v))
+                out = flash_attention(
+                    jnp.moveaxis(q, 2, 1), jnp.repeat(kc, rep, axis=1),
+                    jnp.repeat(vc, rep, axis=1),
+                    key_padding_mask=valid.astype(jnp.int32), causal=True,
+                    causal_block=block_length)
+                out = jnp.moveaxis(out, 1, 2).reshape(B, S, -1)
+                x = x + out.astype(cfg.dtype) @ p["o_proj"]
+                pad = ((0, 0), (0, 0), (0, cache_len - S), (0, 0))
+                caches.append((jnp.pad(kc, pad), jnp.pad(vc, pad)))
+            x, _, load = _moe_block(cfg, p, x, valid)
+            loads.append(load)
+    return caches, jnp.stack(loads)
+
+
+# -- one block against the committed cache ---------------------------------------
+
+
+def block_forward(cfg: SdarMoeConfig, params, caches, tokens, start,
+                  rows_valid, *, write: bool, head: bool):
+    """``tokens [B, L]`` at absolute positions ``start[b] .. start[b] + L``,
+    seeing the cache's columns ``[0, start[b])`` and one another
+    (bidirectional inside the block).  ``write=False`` (denoise) leaves the
+    cache alone; ``write=True`` (commit) returns it with the block's K and
+    V at its columns.  ``head=False`` skips the final norm and the head (a
+    commit's logits are read by nobody).  Returns ``(logits [B, L, V]
+    float32 | None, caches | None, top_e [layers, B, L, k], load
+    [layers, 3])``."""
+    B, L = tokens.shape
+    nh, nkv, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    rep = nh // nkv
+    M = caches[0][0].shape[2]
+    positions = start[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
+    valid = jnp.broadcast_to(rows_valid[:, None], (B, L))
+    seen = (jnp.arange(M)[None, :] < start[:, None])  # [B, M]
+    cache_bias = jnp.where(seen, 0.0, NEG_INF)[:, None, None, None, :]
+    scale = 1.0 / np.sqrt(float(D))
+    with jax.named_scope("embed_tokens"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+    new_caches, experts, loads = [], [], []
+    for i, p in enumerate(params["layers"]):
+        with jax.named_scope(f"layers_{i}"):
+            with jax.named_scope("attn"):
+                h = rms_norm(x, p["norm1"], cfg.rms_norm_eps, cfg.dtype)
+                q, k, v = qkv(cfg, p, h, positions, M)
+                k_cache, v_cache = caches[i]
+                qg = jnp.moveaxis(q, 2, 1).reshape(B, nkv, rep, L, D)
+                kb, vb = (jnp.moveaxis(t, 2, 1) for t in (k, v))
+                s_cache = jnp.einsum(
+                    "bgrqd,bgmd->bgrqm", qg, k_cache,
+                    preferred_element_type=jnp.float32) * scale + cache_bias
+                s_block = jnp.einsum(
+                    "bgrqd,bgkd->bgrqk", qg, kb,
+                    preferred_element_type=jnp.float32) * scale
+                probs = jax.nn.softmax(
+                    jnp.concatenate([s_cache, s_block], -1), axis=-1)
+                out = jnp.einsum("bgrqm,bgmd->bgrqd",
+                                 probs[..., :M].astype(cfg.dtype), v_cache,
+                                 preferred_element_type=jnp.float32) \
+                    + jnp.einsum("bgrqk,bgkd->bgrqd",
+                                 probs[..., M:].astype(cfg.dtype), vb,
+                                 preferred_element_type=jnp.float32)
+                out = jnp.moveaxis(out.reshape(B, nh, L, D), 1, 2)
+                x = x + out.reshape(B, L, nh * D).astype(cfg.dtype) \
+                    @ p["o_proj"]
+                if write:
+                    put = jax.vmap(lambda c, new, at: jax.lax.
+                                   dynamic_update_slice(c, new, (0, at, 0)))
+                    new_caches.append((put(k_cache, kb, start),
+                                       put(v_cache, vb, start)))
+            x, top_e, load = _moe_block(cfg, p, x, valid)
+            experts.append(top_e)
+            loads.append(load)
+    logits = None
+    if head:
+        with jax.named_scope("lm_head"):
+            h = rms_norm(x, params["norm"], cfg.rms_norm_eps, cfg.dtype)
+            logits = jnp.dot(h, params["lm_head"],
+                             preferred_element_type=jnp.float32)
+    return (logits, new_caches if write else None, jnp.stack(experts),
+            jnp.stack(loads))

@@ -74,6 +74,7 @@ STEP_ANNOTATION = "engine.step"
 STAGES = ("stack", "h2d", "dispatch", "readback", "demux")
 STAGE_ANNOTATIONS = {n: f"{STEP_ANNOTATION}.{n}" for n in STAGES}
 QUEUE_WAIT_ANNOTATION = "engine.queue_wait"
+GEN_FORWARD_ANNOTATION = "engine.gen.forward"
 ROUTE_ANNOTATION = "router.route"
 ROUTE_DONE_ANNOTATION = "router.route.done"
 
@@ -244,7 +245,7 @@ class BatchStep:
 def start_step(items, *, group: str, bucket: int, max_batch: int,
                padded_rows: int, flavour: str, kind: str = "fused",
                rows: Optional[int] = None, tokens_real: int = 0,
-               name: str = STEP_SPAN) -> BatchStep:
+               name: str = STEP_SPAN, **more_facts: int) -> BatchStep:
     """Open one step: always the ``engine.step`` profiler annotation
     (``group``, ``flavour``, ``bucket``, ``rows``, ``padded_rows``,
     ``tokens_real``), and request tracing iff any batch item carries a
@@ -257,7 +258,8 @@ def start_step(items, *, group: str, bucket: int, max_batch: int,
              "bucket": int(bucket),
              "rows": len(items) if rows is None else int(rows),
              "padded_rows": int(padded_rows),
-             "tokens_real": int(tokens_real)}
+             "tokens_real": int(tokens_real),
+             **{k: int(v) for k, v in more_facts.items()}}
     if not traced:
         return BatchStep(name, traced, {}, detailed=False, facts=facts)
     detailed = any(ctx.sampled for _, ctx in traced)
@@ -286,6 +288,20 @@ def queue_wait(trace_id: str, group: str, wait_s: float) -> None:
     cannot start: the event marks its END and carries its length."""
     with trace_span(QUEUE_WAIT_ANNOTATION, trace_id=trace_id, group=group,
                     wait_us=int(wait_s * 1e6)):
+        pass
+
+
+def gen_forward(group: str, flavour: str, load) -> None:
+    """``engine.gen.forward``: a forward of a generation ended now, and
+    this is what only its readback knew.  ``load [layers, 4]`` per expert
+    layer: the busiest expert's routed pairs, the pairs computed, the
+    experts that got any, the busiest's pairs over the mean; the facts are
+    ``layers``, ``pairs`` and ``experts_touched`` (sums over the layers)
+    and ``load_milli`` (the ratio's mean over the layers, in thousandths)."""
+    with trace_span(GEN_FORWARD_ANNOTATION, group=group, flavour=flavour,
+                    layers=len(load), pairs=int(load[:, 1].sum()),
+                    experts_touched=int(load[:, 2].sum()),
+                    load_milli=int(load[:, 3].mean() * 1000)):
         pass
 
 

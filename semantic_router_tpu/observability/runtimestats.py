@@ -178,6 +178,17 @@ class RuntimeStats:
             "Device batch TOKENS by kind on packing-accounted steps: "
             "real tokens carried prompts, padding tokens were row waste "
             "(engine.packing's fill surface)")
+        self.gen_forwards = registry.counter(
+            "llm_runtime_gen_forwards_total",
+            "Device forwards of generative tasks by flavour (prefill, "
+            "denoise, commit, decode)")
+        self.gen_blocks = registry.counter(
+            "llm_runtime_gen_blocks_committed_total",
+            "Blocks committed to the cache by block-diffusion tasks, "
+            "one per row that still generated")
+        self.gen_tokens = registry.counter(
+            "llm_runtime_gen_tokens_committed_total",
+            "Tokens committed to the cache by generative tasks")
         self.rss_bytes = registry.gauge(
             "llm_process_rss_bytes", "Router process resident set size")
         self.threads = registry.gauge(
@@ -219,6 +230,25 @@ class RuntimeStats:
                               int(padded_rows), float(seconds),
                               bool(compiled), int(tokens_real),
                               int(tokens_padded), int(segments)))
+
+    def record_generation(self, task: str, flavour: str,
+                          committed_blocks: int = 0,
+                          committed_tokens: int = 0) -> None:
+        """One forward of a generation (the engine's generative runner):
+        llm_runtime_gen_forwards_total by flavour,
+        llm_runtime_gen_blocks_committed_total and
+        llm_runtime_gen_tokens_committed_total.  Everything else about a
+        forward is its ``record_step`` sample (group ``gen:<task>``, the
+        flavour as variant) and, under a profiler session, its
+        ``engine.step`` and ``engine.gen.forward`` annotations (expert
+        load among them)."""
+        if not self.enabled:
+            return
+        self.gen_forwards.inc(task=task, flavour=flavour)
+        if committed_blocks:
+            self.gen_blocks.inc(committed_blocks, task=task)
+        if committed_tokens:
+            self.gen_tokens.inc(committed_tokens, task=task)
 
     # -- aggregation -------------------------------------------------------
 

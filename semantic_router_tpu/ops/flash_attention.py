@@ -91,7 +91,9 @@ def _kv_start(qi, *, block_q: int, block_k: int, window: int):
 
 def _kv_stop(qi, *, block_q: int, block_k: int, n_kb: int, window: int,
              causal: bool):
-    """One past the last K block a query block attends to."""
+    """One past the last K block a query block attends to.  (Block-causal
+    calls need no rule of their own: ``block_q`` is a multiple of
+    ``causal_block``, so a query block's last position ends its group.)"""
     last_q = qi * block_q + block_q - 1
     if window > 0:
         return jnp.minimum((last_q + window // 2) // block_k + 1, n_kb)
@@ -102,7 +104,7 @@ def _kv_stop(qi, *, block_q: int, block_k: int, n_kb: int, window: int,
 
 def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
                   acc_ref, *, scale: float, block_q: int, block_k: int,
-                  n_kb: int, window: int, causal: bool):
+                  n_kb: int, window: int, causal: bool, causal_block: int):
     """One (bh, q-block, kv-step) program: fold one K/V block into the
     query block's online-softmax state."""
     qi = pl.program_id(1)
@@ -136,7 +138,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
             s = jnp.where(jnp.abs(q_pos - k_pos) <= window // 2, s,
                           NEG_INF)
         if causal:
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            # causal_block > 1: causal across groups of that many
+            # positions, bidirectional inside one (block diffusion)
+            s = jnp.where(q_pos // causal_block >= k_pos // causal_block,
+                          s, NEG_INF)
         m = m_ref[...]                                     # [Bq, 1]
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -156,12 +161,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            key_padding_mask: Optional[jnp.ndarray] = None,
                            window: int = 0, causal: bool = False,
+                           causal_block: int = 1,
                            block_q: Optional[int] = None,
                            block_k: Optional[int] = None,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None) -> jnp.ndarray:
     """q/k/v: [B, H, S, D]; key_padding_mask: [B, S] (1 = real token).
     ``window``: ModernBERT-style full window width (0 = global).
+    ``causal_block``: with ``causal``, key j is visible to query i iff
+    ``j // causal_block <= i // causal_block`` (1 = plain causal).
     ``block_q`` / ``block_k``: None = the shape's own (``blocks_for``).
     ``interpret``: None = the Pallas interpreter on a CPU platform (so the
     same call site runs in tests), the compiled kernel everywhere else."""
@@ -173,6 +181,9 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if block_q is None or block_k is None:
         rule_q, rule_k = blocks_for(S, window)
         block_q, block_k = block_q or rule_q, block_k or rule_k
+    if block_q % causal_block:
+        raise ValueError(f"block_q {block_q} is no multiple of "
+                         f"causal_block {causal_block}")
     pad = (-S) % math.lcm(block_q, block_k)
     Sp = S + pad
     if pad:
@@ -212,7 +223,8 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return jnp.minimum(_kv_start(qi, **geom) + j, last)
 
     kernel = functools.partial(
-        _flash_kernel, scale=scale, n_kb=n_kb, causal=causal, **geom)
+        _flash_kernel, scale=scale, n_kb=n_kb, causal=causal,
+        causal_block=causal_block, **geom)
 
     out = pl.pallas_call(
         kernel,
@@ -278,13 +290,15 @@ def flash_attention_sharded(q: jnp.ndarray, k: jnp.ndarray,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     key_padding_mask: Optional[jnp.ndarray] = None,
                     window: int = 0, causal: bool = False,
+                    causal_block: int = 1,
                     scale: Optional[float] = None, mesh=None,
                     batch_axis: str = "dp",
                     head_axis: Optional[str] = "tp") -> jnp.ndarray:
     """Dispatch: the Pallas kernel on a TPU platform (per shard when the
     model serves under ``mesh``); the chunked JAX path on CPU."""
     if _platform_of(q) == "tpu":
-        kw = dict(window=window, causal=causal, scale=scale)
+        kw = dict(window=window, causal=causal, causal_block=causal_block,
+                  scale=scale)
         if mesh is None:
             return flash_attention_pallas(q, k, v, key_padding_mask, **kw)
         if key_padding_mask is None:
@@ -294,7 +308,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                                        batch_axis, head_axis, **kw)
     if causal:
         S = q.shape[2]
-        bias = jnp.triu(jnp.full((S, S), NEG_INF, jnp.float32), k=1)[None, None]
+        group = jnp.arange(S) // causal_block
+        bias = jnp.where(group[None, :] <= group[:, None], 0.0,
+                         NEG_INF)[None, None]
         if key_padding_mask is not None:
             bias = bias + padding_bias(key_padding_mask)
         if window > 0:

@@ -63,6 +63,68 @@ def select_attention_impl(engine_cfg, max_seq_len: int,
     return "dense"
 
 
+def build_generator(spec: dict, hf_cfg: dict, path: str, tokenizer,
+                    load_state):
+    """The generator of a ``kind: generative`` task, by the checkpoint's
+    ``model_type``; every model number comes from the checkpoint's
+    ``config.json`` through the architecture's own ``from_hf`` (dtype from
+    ``torch_dtype``), the generation settings from the task's
+    ``generation:`` block.  Returns ``(generator, adapter index)``.
+
+    ``sdar_moe``: sparse experts, generation by diffusion over blocks
+    (``generation: {block_length, denoising_steps, confidence_threshold,
+    mask_token_id, gen_length}``; ``experts_held: [first, count]`` for a
+    chip's share of an expert-parallel layer).  Anything else: the dense
+    Qwen3 causal LM with KV-cached greedy decoding and per-request LoRA
+    adapters (``adapters:``, ``lora: {rank, alpha}``; ``generation:
+    {gen_length}``).  ``gen_length`` is the length of a guard's verdict:
+    what ``engine.guard_classify`` asks for and ``engine.warmup`` compiles."""
+    from types import SimpleNamespace
+
+    eos_raw = spec.get("eos_token_ids") or hf_cfg.get("eos_token_id", 0)
+    # HF configs carry int OR list (Qwen family uses a list)
+    eos = list(eos_raw) if isinstance(eos_raw, (list, tuple)) else [eos_raw]
+    generation = dict(spec.get("generation") or {})
+    if hf_cfg.get("model_type") == "sdar_moe":
+        from ..models.generate import BlockDiffusionGenerator
+        from ..models.sdar_moe import SdarMoeConfig, params_from_checkpoint
+
+        held = spec.get("experts_held")
+        mcfg = SdarMoeConfig.from_hf(
+            hf_cfg, experts_held=tuple(held) if held else None)
+        unknown = set(generation) - {
+            "block_length", "denoising_steps", "confidence_threshold",
+            "mask_token_id", "gen_length"}
+        if unknown or "mask_token_id" not in generation:
+            raise ValueError(
+                f"generation settings of an sdar_moe task: mask_token_id is "
+                f"required, unknown keys {sorted(unknown)}")
+        return BlockDiffusionGenerator(
+            mcfg, params_from_checkpoint(path, mcfg), tokenizer,
+            eos_token_ids=eos, **generation), {}
+
+    from ..models.generate import GreedyGenerator, with_lora_leaves
+    from ..models.lora import LoRAConfig
+    from ..models.qwen3 import Qwen3Config, qwen3_params_from_state_dict
+
+    qcfg = Qwen3Config.from_hf(SimpleNamespace(**hf_cfg))
+    adapters = {name: i for i, name in
+                enumerate(spec.get("adapters", []) or [])}
+    lora_spec = spec.get("lora") or {}
+    lora = LoRAConfig(
+        rank=int(lora_spec.get("rank", 8)),
+        alpha=float(lora_spec.get("alpha", 16.0)),
+        num_tasks=max(1, len(adapters))) if adapters else None
+    qparams = qwen3_params_from_state_dict(load_state(path), wrap="model")
+    if lora is not None:
+        qparams = with_lora_leaves(qcfg, lora, qparams)
+    if set(generation) - {"gen_length"}:
+        raise ValueError(f"generation settings of a dense generative task: "
+                         f"gen_length only, not {sorted(generation)}")
+    return GreedyGenerator(qcfg, qparams, tokenizer, lora=lora,
+                           eos_token_ids=eos, **generation), adapters
+
+
 def build_engine(cfg: RouterConfig, mock: bool = False, registry=None):
     """Engine from config (or the mock seam). Returns None when no
     classifier models are configured — the router then runs heuristics-only
@@ -256,57 +318,16 @@ def build_engine(cfg: RouterConfig, mock: bool = False, registry=None):
                             kind=kind, architecture="deberta-v3")
             continue
         if kind == "generative":
-            # Qwen3 generative classifier / guard (KV-cached greedy decode,
-            # multi-LoRA adapter selection per request)
-            from ..models.generate import GreedyGenerator
-            from ..models.lora import LoRAConfig
-            from ..models.qwen3 import (
-                Qwen3Config,
-                qwen3_params_from_state_dict,
-            )
-
-            qcfg = Qwen3Config(
-                vocab_size=hf_cfg["vocab_size"],
-                hidden_size=hf_cfg["hidden_size"],
-                intermediate_size=hf_cfg["intermediate_size"],
-                num_hidden_layers=hf_cfg["num_hidden_layers"],
-                num_attention_heads=hf_cfg["num_attention_heads"],
-                num_key_value_heads=hf_cfg.get(
-                    "num_key_value_heads", hf_cfg["num_attention_heads"]),
-                head_dim=hf_cfg.get(
-                    "head_dim", hf_cfg["hidden_size"]
-                    // hf_cfg["num_attention_heads"]),
-                rope_theta=hf_cfg.get("rope_theta", 1e6),
-                tie_word_embeddings=hf_cfg.get("tie_word_embeddings", True),
-                rope_scaling=hf_cfg.get("rope_scaling"),
-            )
-            adapters = {name: i for i, name in
-                        enumerate(spec.get("adapters", []) or [])}
-            lora_spec = spec.get("lora") or {}
-            lora = LoRAConfig(
-                rank=int(lora_spec.get("rank", 8)),
-                alpha=float(lora_spec.get("alpha", 16.0)),
-                num_tasks=max(1, len(adapters))) if adapters else None
-            qparams = qwen3_params_from_state_dict(load_state(path),
-                                                   wrap="model")
-            if lora is not None:
-                from ..models.generate import with_lora_leaves
-
-                qparams = with_lora_leaves(qcfg, lora, qparams)
             tok = tokenizer_for(
                 spec.get("tokenizer", path if os.path.isdir(path) else
                          os.path.dirname(path)))
-            eos_raw = spec.get("eos_token_ids") or \
-                hf_cfg.get("eos_token_id", 0)
-            # HF configs carry int OR list (Qwen family uses a list)
-            eos = list(eos_raw) if isinstance(eos_raw, (list, tuple)) \
-                else [eos_raw]
-            engine.register_generative(
-                task, GreedyGenerator(qcfg, qparams, tok, lora=lora,
-                                      eos_token_ids=eos),
-                labels=labels, adapter_index=adapters)
+            generator, adapters = build_generator(spec, hf_cfg, path, tok,
+                                                  load_state)
+            engine.register_generative(task, generator, labels=labels,
+                                       adapter_index=adapters)
             component_event("bootstrap", "model_loaded", task=task,
-                            kind=kind)
+                            kind=kind,
+                            architecture=hf_cfg.get("model_type", "qwen3"))
             continue
         if kind == "embedding":
             module = MmBertEmbeddingModel(mcfg)

@@ -11,7 +11,10 @@ import threading
 import time
 from concurrent.futures import wait
 
+import pytest
+
 from semantic_router_tpu.engine.batcher import DynamicBatcher
+from semantic_router_tpu.engine.packing.scheduler import PackingBatcher
 
 
 class _Recorder:
@@ -122,3 +125,83 @@ class TestConcurrentDispatch:
             assert s["max_inflight"] >= 1
         finally:
             b.shutdown()
+
+
+def _closed_loop(batcher, key, callers, rounds):
+    """``callers`` threads, each submitting again as soon as it is
+    answered: what a step releases comes back within a millisecond."""
+    def caller(i):
+        for _ in range(rounds):
+            batcher.submit(key, i).result(timeout=10.0)
+
+    threads = [threading.Thread(target=caller, args=(i,))
+               for i in range(callers)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30.0)
+
+
+def _packing_batcher(runner, **kw):
+    """The engine's batcher; no group here is packable."""
+    return PackingBatcher(runner, bucket_of=lambda key: None, **kw)
+
+
+@pytest.mark.parametrize("cls", [DynamicBatcher, _packing_batcher],
+                         ids=["DynamicBatcher", "PackingBatcher"])
+class TestPatientGroups:
+    """A group whose step lasts far longer than max_wait (a whole
+    generation; the engine marks its generative groups so): the callers a
+    step released ride the NEXT step together.  Without it the first to
+    come back fires alone (the low-QPS fast path) and the rest queue
+    behind it for a second step: steps of 1 and n-1 rows in turn."""
+
+    def test_released_callers_ride_the_next_step_together(self, cls):
+        rec = _Recorder(stall_group=None)
+        slow = lambda key, batch: (time.sleep(0.15), rec(key, batch))[1]
+        b = cls(slow, max_batch_size=8, max_wait_ms=100.0,
+                patient=lambda key: key == "gen")
+        try:
+            _closed_loop(b, "gen", callers=4, rounds=3)
+        finally:
+            b.shutdown()
+        assert rec.calls == [("gen", 4)] * 3
+
+    def test_a_patient_group_waits_max_wait_from_its_last_release(self, cls):
+        rec = _Recorder()
+        b = cls(rec, max_batch_size=8, max_wait_ms=150.0,
+                patient=lambda key: key == "gen")
+        try:
+            b.submit("gen", 0).result(timeout=5.0)  # releases at about now
+            t0 = time.perf_counter()
+            first = b.submit("gen", 1)
+            time.sleep(0.05)
+            second = b.submit("gen", 2)
+            assert (first.result(timeout=5.0), second.result(timeout=5.0)) \
+                == (2, 4)
+            waited = time.perf_counter() - t0
+        finally:
+            b.shutdown()
+        # one step for both, max_wait after the release and not after the
+        # second item's enqueue
+        assert rec.calls[-1] == ("gen", 2) and 0.05 < waited < 2.0
+
+    def test_an_ordinary_group_still_takes_the_fast_path(self, cls):
+        rec = _Recorder()
+        b = cls(rec, max_batch_size=8, max_wait_ms=2000.0,
+                patient=lambda key: key == "gen")
+        try:
+            t0 = time.perf_counter()
+            assert b.submit("cls", 3).result(timeout=5.0) == 6
+            fast = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            lone = b.submit("gen", 4)
+            time.sleep(0.3)
+            assert not lone.done()  # a lone patient item waits for company
+            for i in range(7):
+                b.submit("gen", i)  # a full group fires at once
+            assert lone.result(timeout=1.0) == 8
+        finally:
+            b.shutdown()
+        assert fast < 0.5, f"a lone ordinary item waited {fast:.2f} s"
+        assert rec.calls == [("cls", 1), ("gen", 8)]

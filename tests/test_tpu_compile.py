@@ -285,3 +285,44 @@ class TestWholeFusedStepCompilesForV5e:
             + mem.output_size_in_bytes
         # ~0.57 GiB of arguments + the temporaries
         assert gib_lo * 2**30 < total < gib_hi * 2**30
+
+
+class TestBlockDiffusionGuardCompilesForV5e:
+    """The sdar_moe guard's kernels at the published widths (hidden 2048,
+    32 x 128 heads over 4 k/v heads, 128 experts of width 768)."""
+
+    @pytest.mark.parametrize("rows", [1, 16])
+    def test_block_causal_prefill_attention(self, one_chip, rows):
+        """Head size 128, bfloat16, the prompt bucket as ONE 512 x 512
+        block, the block-causal mask (groups of 4)."""
+        from semantic_router_tpu.ops.flash_attention import (
+            flash_attention_pallas,
+        )
+
+        qkv = ((rows, 32, 512, 128), jnp.bfloat16)
+        compiled = compile_for(
+            one_chip,
+            functools.partial(flash_attention_pallas, causal=True,
+                              causal_block=4, interpret=False),
+            qkv, qkv, qkv, ((rows, 512), jnp.int32))
+        assert "tpu_custom_call" in compiled.as_text()
+
+    @pytest.mark.parametrize("tokens", [4, 64, 512, 8192])
+    def test_expert_layer_grouped_matmul(self, one_chip, monkeypatch, tokens):
+        """The megablox kernel under ``moe``'s tiling rule: a block forward
+        of 1 and of 16 rows, a prefill of 1 and of 16 rows."""
+        from semantic_router_tpu.models import sdar_moe as M
+
+        monkeypatch.setattr(M, "_on_cpu", lambda: False)
+        cfg = M.SdarMoeConfig(num_hidden_layers=1)
+        H, I, E = cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts
+        bf = jnp.bfloat16
+
+        def layer(router, gate_up, down, x, valid):
+            p = {"router": router, "gate_up": gate_up, "down": down}
+            return M.moe(cfg, p, x, valid)
+
+        compiled = compile_for(
+            one_chip, layer, ((H, E), bf), ((E, H, 2 * I), bf),
+            ((E, I, H), bf), ((tokens, H), bf), ((tokens,), jnp.bool_))
+        assert compiled.as_text().count("tpu_custom_call") >= 2
