@@ -1256,10 +1256,11 @@ class InferenceEngine:
 
     def fused_covers(self, tasks: Sequence[str]) -> bool:
         """True when one fused execution will actually serve every listed
-        sequence task — the dispatcher's prefetch gate.  A trunk group
-        always qualifies (classify_multi routes it fused); the stacked
-        bank only qualifies when the dual-path chooser would pick it RIGHT
-        NOW — claiming coverage while the chooser serves traditional would
+        task — the dispatcher's prefetch gate.  A trunk group always
+        qualifies, for its sequence AND its token members
+        (classify_multi routes them as one item a text); the stacked
+        bank serves sequence tasks only, and only qualifies when the
+        dual-path chooser would pick it RIGHT NOW — claiming coverage while the chooser serves traditional would
         turn the prefetch into K *serial* per-task forwards, the exact
         serialization it exists to avoid.  Best-effort gate: a concurrent
         history record can still flip classify_multi's own choice between
@@ -1269,16 +1270,16 @@ class InferenceEngine:
         tasks = list(tasks)
         if not tasks:
             return False
-        # the prefetch fan-out is classify_multi, which is sequence-only;
-        # token trunk-group members coalesce through their own
-        # token_classify submits instead
-        if any(self.task_kind(t) != "sequence" for t in tasks):
+        kinds = {self.task_kind(t) for t in tasks}
+        if not kinds <= {"sequence", "token"}:
             return False
         if self._common_trunk_group(tasks) is not None:
             return True
+        # off a trunk group a token task has no fused execution: it
+        # keeps its own token_classify submit
         stacked = getattr(self, "_stacked", None)
-        if stacked is None or any(t not in stacked["tasks"]
-                                  for t in tasks):
+        if "token" in kinds or stacked is None \
+                or any(t not in stacked["tasks"] for t in tasks):
             return False
         from .pathing import STACKED, ProcessingRequirements
 
@@ -1358,14 +1359,18 @@ class InferenceEngine:
     def classify_multi(self, tasks: Sequence[str], texts: Sequence[str],
                        timeout: float = 30.0,
                        requirements=None,
-                       enc_cache=None) -> Dict[str, List[ClassResult]]:
-        """Classify the same texts under several sequence tasks — the
-        signal fan-out shape. With a stacked bank registered, the
-        dual-path chooser decides between one fused pass and per-task
-        batcher submits, learning from its own outcome records; without
-        one, tasks sharing a fused trunk group ride ONE batched submit
-        (tokenize once, trunk forward once, heads demuxed), and only
-        unrelated tasks fall back to per-task classify_batch."""
+                       enc_cache=None,
+                       threshold: float = 0.5) -> Dict[str, List[Any]]:
+        """Classify the same texts under several tasks — the signal
+        fan-out shape: ``{task: [result per text]}``, a ClassResult for a
+        sequence task and a TokenClassResult (entity spans scored at
+        ``threshold``, token_classify's argument) for a token task.
+        With a stacked bank registered, the dual-path chooser decides
+        between one fused pass and per-task batcher submits, learning
+        from its own outcome records; without one, tasks sharing a fused trunk group — sequence and token
+        members alike — ride ONE batched submit (tokenize once, trunk
+        forward once, heads demuxed), and only unrelated tasks fall back
+        to per-task classify_batch / token_classify."""
         from .pathing import (
             STACKED,
             TRADITIONAL,
@@ -1375,8 +1380,12 @@ class InferenceEngine:
         )
 
         tasks = list(tasks)
+        token_tasks = set()
         for t in tasks:
-            self._require(t, kind="sequence")
+            if self._require(t).kind == "token":
+                token_tasks.add(t)
+            else:
+                self._require(t, kind="sequence")
         stacked = getattr(self, "_stacked", None)
         eligible = stacked is not None and len(tasks) > 0 and \
             all(t in stacked["tasks"] for t in tasks)
@@ -1447,10 +1456,15 @@ class InferenceEngine:
         if group is not None:
             out = self._fused_multi(group, tasks, texts,
                                     timeout=remaining(),
-                                    enc_cache=enc_cache)
+                                    enc_cache=enc_cache,
+                                    threshold=threshold)
         else:
-            out = {t: self.classify_batch(t, texts, timeout=remaining(),
-                                          enc_cache=enc_cache)
+            out = {t: [self.token_classify(t, text, threshold=threshold,
+                                           timeout=remaining(),
+                                           enc_cache=enc_cache)
+                       for text in texts] if t in token_tasks
+                   else self.classify_batch(t, texts, timeout=remaining(),
+                                            enc_cache=enc_cache)
                    for t in tasks}
         if eligible:
             conf = float(np.mean([r.confidence for rs in out.values()
@@ -1461,11 +1475,14 @@ class InferenceEngine:
 
     def _fused_multi(self, g: TrunkGroup, tasks: Sequence[str],
                      texts: Sequence[str], timeout: float = 30.0,
-                     enc_cache=None) -> Dict[str, List[ClassResult]]:
+                     enc_cache=None, threshold: float = 0.5
+                     ) -> Dict[str, List[Any]]:
         """The trunk-group fan-out: each text is ONE batch item carrying
-        every requested task — tokenized once, submitted as one
-        submit_many per bucket (guaranteed coalescing), trunk forward
-        shared, per-task logits demuxed by the fused runner."""
+        every requested task, token members (spans scored at
+        ``threshold``) beside sequence members — tokenized once,
+        submitted as one submit_many per bucket (guaranteed coalescing),
+        trunk forward shared, per-task logits demuxed by the fused
+        runner."""
         deadline = time.perf_counter() + timeout
         tasks = list(tasks)
         by_bucket: Dict[int, List[tuple]] = {}
@@ -1474,14 +1491,14 @@ class InferenceEngine:
                                                          enc_cache)
             bucket = pick_bucket(len(enc), self.cfg.seq_len_buckets)
             by_bucket.setdefault(bucket, []).append(
-                (ti, _Payload(text, enc, tasks=tuple(tasks),
+                (ti, _Payload(text, enc, threshold, tasks=tuple(tasks),
                               tok_s=tok_s, tok_cached=cached)))
         futs: List[tuple] = []
         for bucket, entries in by_bucket.items():
             fs = self.batcher.submit_many(
                 (TRUNK_KEY, g.gid, bucket), [p for _, p in entries])
             futs.extend(zip((ti for ti, _ in entries), fs))
-        results: List[Optional[Dict[str, ClassResult]]] = [None] * len(texts)
+        results: List[Optional[Dict[str, Any]]] = [None] * len(texts)
         for ti, f in futs:
             res = f.result(timeout=max(0.05,
                                        deadline - time.perf_counter()))

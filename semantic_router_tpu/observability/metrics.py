@@ -564,6 +564,11 @@ class MetricSeries:
             "llm_signal_errors_total",
             "Signal evaluations that failed open, by family — the "
             "numerator of the signal error-rate SLO")
+        self.signal_results = registry.counter(
+            "llm_signal_results_total",
+            "Signal evaluations by family and source (heuristic | engine "
+            "| fused_bank: answered from the request's one fused item, "
+            "which paid the trunk forward for every family on it)")
         self.decision_matches = registry.counter(
             "llm_decision_matches_total", "Decision matches by name")
         self.decision_latency = registry.histogram(
@@ -680,6 +685,7 @@ hallucination_latency = default_series.hallucination_latency
 cache_lookups = default_series.cache_lookups
 signal_latency = default_series.signal_latency
 signal_errors = default_series.signal_errors
+signal_results = default_series.signal_results
 decision_matches = default_series.decision_matches
 decision_latency = default_series.decision_latency
 decision_fallbacks = default_series.decision_fallbacks

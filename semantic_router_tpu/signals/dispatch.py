@@ -13,7 +13,7 @@ from __future__ import annotations
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 from ..config.schema import RouterConfig, SIGNAL_PROJECTION
 from ..decision.engine import SignalMatches
@@ -21,7 +21,7 @@ from ..decision.projections import ProjectionEvaluator, ProjectionTrace
 from .base import RequestContext, SignalEvaluator, SignalResult
 
 
-# serial prefetch budget: a cold fused compile past this falls back to
+# the fused item's budget: a cold fused compile past this falls back to
 # the parallel per-evaluator path instead of stalling the whole request
 PREFETCH_TIMEOUT_S = 10.0
 
@@ -73,18 +73,56 @@ def apply_complexity_composers(signals: SignalMatches,
             signals.add("complexity", hard, max(conf, 0.5))
 
 
+class _Rider(NamedTuple):
+    """A family on a fused item: its task there, the task's kind, and the
+    memo key its evaluator will look the result up by."""
+
+    evaluator: SignalEvaluator
+    task: str
+    kind: str
+    key: tuple
+
+
+@dataclass
+class _FusedItem:
+    """One request's ``classify_multi`` call on one engine: the families
+    of ``active`` whose task reads ``ctx.user_text`` on a common trunk
+    group (or the stacked bank)."""
+
+    engine: Any
+    riders: List[_Rider] = field(default_factory=list)
+    # the token members' span threshold (an item carries one); None
+    # while no token member rides
+    threshold: Optional[float] = None
+
+    def tasks(self) -> List[str]:
+        return sorted({r.task for r in self.riders})
+
+    def evaluators(self) -> List[SignalEvaluator]:
+        return [r.evaluator for r in self.riders]
+
+
 class SignalDispatcher:
     def __init__(self, evaluators: List[SignalEvaluator],
                  projections: Optional[ProjectionEvaluator] = None,
                  used_types: Optional[List[str]] = None,
                  complexity_rules: Optional[list] = None,
-                 max_workers: int = 24) -> None:
+                 max_workers: int = 24,
+                 metrics=None) -> None:
         self.evaluators = {e.signal_type: e for e in evaluators}
+        self._metrics = metrics  # None: the process's default series
         self.projections = projections
         self.used_types = set(used_types) if used_types is not None else None
         self.complexity_rules = list(complexity_rules or [])
         self.pool = ThreadPoolExecutor(max_workers=max_workers,
                                        thread_name_prefix="signal")
+
+    def _series(self):
+        if self._metrics is not None:
+            return self._metrics
+        from ..observability import metrics as M
+
+        return M.default_series
 
     def active_evaluators(self) -> List[SignalEvaluator]:
         if self.used_types is None:
@@ -114,11 +152,10 @@ class SignalDispatcher:
         active = [e for e in self.active_evaluators() if e.signal_type not in skip]
 
         run = self._runner(ctx)
-        self._prefetch_fused(ctx, active)
         if len(active) <= 1:
             results = [run(e) for e in active]
         else:
-            results = list(self.pool.map(run, active))
+            results = self._fan_out(ctx, run, active)
 
         signals = SignalMatches()
         kb_metrics: dict = {}
@@ -167,11 +204,36 @@ class SignalDispatcher:
 
         return run
 
-    @staticmethod
-    def _fold_result(r: SignalResult, signals: SignalMatches,
+    def _fan_out(self, ctx: RequestContext, run, active: list
+                 ) -> List[SignalResult]:
+        """Every active family's result, in ``active``'s order, with the
+        request's fused item in flight BESIDE the rest of the fan-out.
+
+        The families no item serves (embedding, preference, complexity,
+        the heuristics) start on the pool first, so the embedding item
+        reaches its batcher group while the fused item rides.  The
+        blocking classify_multi then runs on THIS thread — never queued
+        behind pool tasks — and the families it served evaluate here
+        too, where they are memo lookups.  An item that failed or timed
+        out hands its families to the pool: each classifies by itself,
+        in parallel, as without an item."""
+        items = self._gather_fused(ctx, active)
+        riding = {id(e) for item in items for e in item.evaluators()}
+        pooled = {id(e): self.pool.submit(run, e)
+                  for e in active if id(e) not in riding}
+        for item in items:
+            if not self._seed_memo(ctx, item):
+                for e in item.evaluators():
+                    pooled[id(e)] = self.pool.submit(run, e)
+        return [pooled[id(e)].result() if id(e) in pooled else run(e)
+                for e in active]
+
+    def _fold_result(self, r: SignalResult, signals: SignalMatches,
                      report: DispatchReport, kb_metrics: dict) -> None:
         """Fold one family's result into the running match set."""
         report.results[r.signal_type] = r
+        self._series().signal_results.inc(family=r.signal_type,
+                                          source=r.source or "unknown")
         for h in r.hits:
             signals.add(r.signal_type, h.rule, h.confidence)
             if h.detail:
@@ -202,54 +264,95 @@ class SignalDispatcher:
             report.projection_trace = self.projections.evaluate(
                 signals, kb_metrics=kb_metrics)
 
-    def _prefetch_fused(self, ctx: RequestContext, active: list) -> None:
-        """Tokenize-once + trunk-once for the learned fan-out.
+    def _gather_fused(self, ctx: RequestContext, active: list
+                      ) -> List[_FusedItem]:
+        """Tokenize-once + trunk-once for the learned fan-out: ONE item
+        a text.
 
-        When ≥2 active engine-backed sequence evaluators target tasks one
-        fused execution can serve (a shared TrunkGroup or the stacked
-        bank), classify the user text for ALL of them in one
-        classify_multi call BEFORE the thread fan-out and seed the
-        request's memo — the per-evaluator classify calls become lookups,
-        so a request activating K learned signals pays exactly one
-        tokenization and one trunk forward.  Unfusable mixes skip this
-        (sequential prefetch would serialize what the fan-out runs in
-        parallel); prefetch errors fall open to per-evaluator calls."""
+        Every active engine-backed family whose task reads
+        ``ctx.user_text`` (its ``prefetch_task``; a token family also
+        names its rule's ``prefetch_threshold``) and whose tasks one
+        fused execution can serve — a shared TrunkGroup, sequence and
+        token members alike, or the stacked bank for sequence tasks —
+        is gathered into one classify_multi call per engine, so a request
+        activating K learned signals pays exactly one tokenization and
+        one trunk forward.  What the item cannot carry keeps its own
+        call: another text (include_history), a second token threshold,
+        a task on no trunk group, a generative task.  Fewer than two
+        tasks, or an unfusable mix, gather nothing (a serial call would
+        serialize what the fan-out runs in parallel)."""
         text = ctx.user_text
         memo = getattr(ctx, "class_memo", None)
         if not text or memo is None:
-            return
-        by_engine: Dict[int, tuple] = {}
+            return []
+        by_engine: Dict[int, _FusedItem] = {}
         for e in active:
             task = getattr(e, "prefetch_task", "")
             engine = getattr(e, "engine", None)
-            if not task or engine is None:
+            if not task or engine is None or not engine.has_task(task):
                 continue
-            if (id(engine), task, text) in memo:
+            kind = engine.task_kind(task)
+            threshold = getattr(e, "prefetch_threshold", None)
+            if kind == "sequence":
+                key = (id(engine), task, text)
+            elif kind == "token" and threshold is not None:
+                key = (id(engine), task, text, threshold)
+            else:
                 continue
-            if not engine.has_task(task) or \
-                    engine.task_kind(task) != "sequence":
+            if key in memo:
                 continue
-            by_engine.setdefault(id(engine), (engine, []))[1].append(task)
-        for engine, tasks in by_engine.values():
-            tasks = sorted(set(tasks))
-            fused_covers = getattr(engine, "fused_covers", None)
-            if len(tasks) < 2 or fused_covers is None \
-                    or not fused_covers(tasks):
+            item = by_engine.setdefault(id(engine), _FusedItem(engine))
+            if kind == "token":
+                if item.threshold is None:
+                    item.threshold = threshold
+                elif item.threshold != threshold:
+                    continue
+            item.riders.append(_Rider(e, task, kind, key))
+        items = []
+        for item in by_engine.values():
+            fused_covers = getattr(item.engine, "fused_covers", None)
+            if fused_covers is None:
                 continue
-            try:
-                # bounded: the prefetch runs serially BEFORE the fan-out,
-                # so a cold compile must not stall the request for the
-                # engine's full default — on timeout the evaluators fall
-                # back to their own (parallel) classify calls while the
-                # abandoned batch keeps warming the jit cache
-                out = engine.classify_multi(
-                    tasks, [text], timeout=PREFETCH_TIMEOUT_S,
-                    enc_cache=getattr(ctx, "enc_cache", None))
-            except Exception:
-                continue  # evaluators classify individually (fail open)
-            for task, results in out.items():
-                if results:
-                    memo[(id(engine), task, text)] = results[0]
+            if item.threshold is not None \
+                    and not fused_covers(item.tasks()):
+                # the token member sits on no trunk group with the
+                # rest: it keeps its own call, the sequence members
+                # still share theirs
+                item.riders = [r for r in item.riders
+                               if r.kind == "sequence"]
+                item.threshold = None
+            tasks = item.tasks()
+            if len(tasks) >= 2 and fused_covers(tasks):
+                items.append(item)
+        return items
+
+    def _seed_memo(self, ctx: RequestContext, item: _FusedItem) -> bool:
+        """Run the item (blocking) and seed the request's memo with each
+        task's result — the per-evaluator classify calls become lookups.
+        False when the item raised or timed out: its families fall open
+        to their own calls."""
+        # bounded: a cold compile must not stall the request for the
+        # engine's full default — on timeout the evaluators fall back
+        # to their own (parallel) classify calls while the abandoned
+        # batch keeps warming the jit cache
+        kwargs = {} if item.threshold is None \
+            else {"threshold": item.threshold}
+        try:
+            out = item.engine.classify_multi(
+                item.tasks(), [ctx.user_text], timeout=PREFETCH_TIMEOUT_S,
+                enc_cache=getattr(ctx, "enc_cache", None), **kwargs)
+            for rider in item.riders:
+                ctx.class_memo[rider.key] = out[rider.task][0]
+        except Exception:
+            return False  # evaluators classify individually (fail open)
+        return True
+
+    def _prefetch_fused(self, ctx: RequestContext, active: list) -> None:
+        """The blocking form, for a caller that fans out by itself
+        (engine/cascade, one wave at a time): run the items of ``active``
+        and return with the memo seeded."""
+        for item in self._gather_fused(ctx, active):
+            self._seed_memo(ctx, item)
 
     def shutdown(self) -> None:
         self.pool.shutdown(wait=False, cancel_futures=True)

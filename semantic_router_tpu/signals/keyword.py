@@ -19,6 +19,8 @@ import unicodedata
 from dataclasses import dataclass
 from typing import Dict, List, Sequence, Tuple
 
+import numpy as np
+
 from ..config.schema import KeywordRule
 from .base import RequestContext, SignalHit, SignalResult
 
@@ -81,9 +83,13 @@ def fuzzy_ratio(a: str, b: str) -> float:
     return _lcs_ratio_py(a, b)
 
 
-def fuzzy_partial_ratio(needle: str, haystack: str) -> float:
+def fuzzy_partial_ratio(needle: str, haystack: str,
+                        floor: float = 0.0) -> float:
     """Best fuzzy match of *needle* against any equal-length window of
-    *haystack* (cheap partial-ratio: slide by whole tokens)."""
+    *haystack* (cheap partial-ratio: slide by whole tokens).  A caller
+    that only acts on scores of at least ``floor`` says so: such a score
+    is returned as it is, a lower one may come back lower still (windows
+    that cannot reach ``floor`` are not scored)."""
     if not needle or not haystack:
         return 0.0
     if needle in haystack:
@@ -98,14 +104,42 @@ def fuzzy_partial_ratio(needle: str, haystack: str) -> float:
     for m in re.finditer(r"\S+", haystack):
         starts.add(m.start())
     starts.update(range(0, len(haystack) - n + 1, max(1, n // 2)))
+    order = np.asarray(sorted(i for i in starts if i + 1 < len(haystack)),
+                       dtype=np.int64)
+    bounds = _ratio_bounds(needle, haystack, order)
     best = 0.0
-    for i in sorted(starts):
-        if i + 1 >= len(haystack):
-            break
+    for i, bound in zip(order.tolist(), bounds.tolist()):
+        # a window whose bound passes neither the floor nor the best so
+        # far cannot change the answer: only windows that share most of
+        # the needle's letters pay the quadratic ratio (in a 5,000-word
+        # prompt, a handful of seven thousand)
+        if bound < floor or bound <= best:
+            continue
         best = max(best, fuzzy_ratio(needle, haystack[i:i + n]))
         if best >= 99.9:
             break
     return best
+
+
+def _ratio_bounds(needle: str, haystack: str, starts: np.ndarray
+                  ) -> np.ndarray:
+    """For each window ``haystack[i:i + len(needle)]``, a value its
+    fuzzy_ratio cannot pass: a common subsequence holds each character at
+    most as often as either string does, so LCS <= sum over the needle's
+    characters of min(count in needle, count in window).  Counts of all
+    windows at once from running sums over the haystack's code points."""
+    n = len(needle)
+    text = np.frombuffer(haystack.encode("utf-32-le", "surrogatepass"),
+                         dtype=np.uint32)
+    ends = np.minimum(starts + n, len(text))
+    common = np.zeros(len(starts), np.int64)
+    for ch in set(needle):
+        running = np.concatenate(([0], np.cumsum(text == ord(ch))))
+        common += np.minimum(running[ends] - running[starts],
+                             needle.count(ch))
+    # fuzzy_ratio's own expression, so that a window whose subsequence
+    # reaches the bound reads the same float
+    return 200.0 * common / (n + ends - starts)
 
 
 class BM25Scorer:
@@ -268,7 +302,7 @@ class KeywordSignal:
             matched, scores = [], []
             for kw in r.keywords:
                 kn = _norm(kw, r.case_sensitive)
-                s = fuzzy_partial_ratio(kn, tn)
+                s = fuzzy_partial_ratio(kn, tn, floor=r.fuzzy_threshold)
                 if s >= r.fuzzy_threshold:
                     matched.append(kw)
                     scores.append(s)

@@ -43,11 +43,13 @@ class _EngineSignal:
     def __init__(self, engine: InferenceEngine, task: str) -> None:
         self.engine = engine
         self.task = task
-        # sequence classification of ctx.user_text the dispatcher may
-        # batch AHEAD of the thread fan-out (one fused trunk forward for
-        # every learned family on a shared trunk); evaluators with other
-        # input shapes (token tasks) blank this out
+        # classification of ctx.user_text the dispatcher may carry on
+        # the request's ONE fused item (a trunk forward shared by every
+        # learned family on that trunk group) and seed into the memo;
+        # a token task rides it at ``prefetch_threshold`` (None: a
+        # sequence task).  Blank the task to opt out.
         self.prefetch_task = task
+        self.prefetch_threshold: Optional[float] = None
 
     def _classify(self, ctx: RequestContext, text: str):
         """Engine classify through the request's shared state: the
@@ -217,18 +219,40 @@ class PIISignal(_EngineSignal):
                  task: str = "pii") -> None:
         super().__init__(engine, task)
         self.rules = rules
-        self.prefetch_task = ""  # token task: not a sequence prefetch
+        # an item carries ONE threshold: the first rule's that reads
+        # ctx.user_text.  A second threshold and include_history rules
+        # (another text) keep their own token_classify call.
+        own = [r.threshold for r in rules if not r.include_history]
+        if own:
+            self.prefetch_threshold = own[0]
+        else:
+            self.prefetch_task = ""
+
+    def _token_classify(self, ctx: RequestContext, text: str,
+                        threshold: float):
+        """The dispatcher-seeded memo first (the request's fused item
+        already paid the forward; keyed by the threshold too, which
+        decides the spans), else a token_classify call of its own."""
+        memo = getattr(ctx, "class_memo", None)
+        source = ("signal_source", id(self))
+        if memo is not None:
+            hit = memo.get((id(self.engine), self.task, text, threshold))
+            if hit is not None:
+                ctx.ext[source] = "fused_bank"
+                return hit
+        ctx.ext.setdefault(source, "engine")
+        return self.engine.token_classify(
+            self.task, text, threshold=threshold,
+            enc_cache=getattr(ctx, "enc_cache", None))
 
     def _evaluate(self, ctx: RequestContext, res: SignalResult) -> None:
         cache: Dict[tuple, list] = {}
         for rule in self.rules:
             key = (rule.include_history, rule.threshold)
             if key not in cache:
-                text = ctx.text_for(rule.include_history)
-                out = self.engine.token_classify(
-                    self.task, text, threshold=rule.threshold,
-                    enc_cache=getattr(ctx, "enc_cache", None))
-                cache[key] = out.entities
+                cache[key] = self._token_classify(
+                    ctx, ctx.text_for(rule.include_history),
+                    rule.threshold).entities
             entities = cache[key]
             allowed = {t.upper() for t in rule.pii_types_allowed}
             denied = [e for e in entities if e.type.upper() not in allowed]

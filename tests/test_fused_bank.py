@@ -327,6 +327,430 @@ class TestFanoutCounters:
         assert cache.hits == 1
 
 
+SEQ_PAIR = [("intent", ["business", "law", "health", "computer science",
+                        "other"]),
+            ("jailbreak", ["benign", "jailbreak"])]
+PII_TASK = ("pii", ["O", "B-EMAIL_ADDRESS", "I-EMAIL_ADDRESS",
+                    "B-PHONE_NUMBER", "I-PHONE_NUMBER",
+                    "B-PERSON", "I-PERSON"])
+# under 1/7: with seven labels every token's best probability passes, so
+# a random head reports spans to compare
+LOW = 0.1
+SHORT_TEXT = "mail alice at example dot com or call 555 0100 about the contract"
+LONG_TEXT = "please forward the invoice to bob " * 120  # past max_seq_len
+
+
+def _bank_engine(series=None, pii_on_trunk=True):
+    """intent + jailbreak (sequence) and pii (token): one trunk group, or
+    pii on a trunk of its own draw (a token task on no trunk group)."""
+    import jax
+    import jax.numpy as jnp
+
+    from semantic_router_tpu.engine.testing import tiny_config
+    from semantic_router_tpu.models.modernbert import (
+        ModernBertForTokenClassification,
+    )
+
+    eng = make_shared_trunk_engine(
+        tasks=SEQ_PAIR, token_tasks=[PII_TASK] if pii_on_trunk else None,
+        metrics=series or fresh_series())
+    if not pii_on_trunk:
+        module = ModernBertForTokenClassification(
+            tiny_config(len(PII_TASK[1])))
+        params = module.init(jax.random.PRNGKey(77),
+                             jnp.ones((1, 8), jnp.int32))
+        eng.register_task("pii", "token", module, params,
+                          HashTokenizer(vocab_size=1024), PII_TASK[1],
+                          max_seq_len=512)
+    return eng
+
+
+def _bank_dispatcher(eng, pii_rules=None, jailbreak_rules=None,
+                     extra=(), **kw):
+    from semantic_router_tpu.config.schema import JailbreakRule, PIIRule
+    from semantic_router_tpu.signals.dispatch import SignalDispatcher
+    from semantic_router_tpu.signals.learned import (
+        DomainSignal,
+        JailbreakSignal,
+        PIISignal,
+    )
+
+    return SignalDispatcher([
+        DomainSignal(eng, [DomainRule(name=n)
+                           for n in eng.task_labels("intent")]),
+        JailbreakSignal(eng, jailbreak_rules or [
+            JailbreakRule(name="jb", method="classifier", threshold=0.0)]),
+        PIISignal(eng, pii_rules or [PIIRule(name="restricted",
+                                             threshold=LOW)]),
+        *extra], **kw)
+
+
+def _ctx(text, history=()):
+    from semantic_router_tpu.signals.base import Message, RequestContext
+
+    return RequestContext(messages=[Message("user", h) for h in history]
+                          + [Message("user", text)])
+
+
+def _spans(result):
+    return [(e.type, e.start, e.end, e.text) for e in result.entities]
+
+
+def _assert_same_entities(got, want):
+    assert _spans(got) == _spans(want)
+    assert [e.score for e in got.entities] == pytest.approx(
+        [e.score for e in want.entities], abs=1e-5)
+    assert got.truncated == want.truncated
+
+
+def _hit_spans(report, rule):
+    (hit,) = [h for h in report.results["pii"].hits if h.rule == rule]
+    return [(e["type"], e["start"], e["end"]) for e in
+            hit.detail["entities"]]
+
+
+class TestOneItemAText:
+    """The PII token task rides the sequence tasks' forward: a request
+    activating domain + jailbreak + pii on one trunk group is ONE fused
+    item, in flight beside the rest of the fan-out."""
+
+    @pytest.mark.parametrize("text", [SHORT_TEXT, LONG_TEXT],
+                             ids=["short", "truncated"])
+    def test_three_families_one_forward_one_tokenization(self, text):
+        series = fresh_series()
+        eng = _bank_engine(series)
+        disp = _bank_dispatcher(eng)
+        try:
+            ctx = _ctx(text)
+            _, report = disp.evaluate(ctx)
+            assert not any(r.error for r in report.results.values())
+            assert series.trunk_forwards.total() == 1
+            assert series.tokenizations.total() == 1
+            assert {f: r.source for f, r in report.results.items()} == {
+                "domain": "fused_bank", "jailbreak": "fused_bank",
+                "pii": "fused_bank"}
+            # the token member's answer is token_classify's alone
+            rode = ctx.class_memo[(id(eng), "pii", text, LOW)]
+            alone = eng.token_classify("pii", text, threshold=LOW)
+            assert alone.entities
+            assert alone.truncated == (text is LONG_TEXT)
+            _assert_same_entities(rode, alone)
+            assert _hit_spans(report, "restricted") == [
+                s[:3] for s in _spans(alone)]
+            # the sequence members' answers are classify_multi's alone
+            seq = eng.classify_multi(["intent", "jailbreak"], [text])
+            for task in ("intent", "jailbreak"):
+                got = ctx.class_memo[(id(eng), task, text)]
+                want = seq[task][0]
+                assert (got.label, got.index, got.truncated) == \
+                    (want.label, want.index, want.truncated)
+                for k in want.probs:
+                    assert got.probs[k] == pytest.approx(want.probs[k],
+                                                         abs=1e-5)
+        finally:
+            disp.shutdown()
+            eng.shutdown()
+
+    def test_classify_multi_takes_token_members(self):
+        """The engine entry: {task: [result per text]}, a
+        TokenClassResult for the token task at the call's threshold, one
+        trunk forward for texts of one bucket."""
+        series = fresh_series()
+        eng = _bank_engine(series)
+        try:
+            texts = [SHORT_TEXT, "write to carol about the audit"]
+            out = eng.classify_multi(["intent", "jailbreak", "pii"], texts,
+                                     threshold=LOW)
+            assert series.trunk_forwards.total() == 1
+            assert series.tokenizations.total() == len(texts)
+            assert set(out) == {"intent", "jailbreak", "pii"}
+            for i, text in enumerate(texts):
+                _assert_same_entities(
+                    out["pii"][i],
+                    eng.token_classify("pii", text, threshold=LOW))
+                assert out["intent"][i].label == \
+                    eng.classify("intent", text).label
+            # the threshold decides the spans: none pass 0.99
+            strict = eng.classify_multi(["jailbreak", "pii"], texts,
+                                        threshold=0.99)
+            assert all(not r.entities for r in strict["pii"])
+        finally:
+            eng.shutdown()
+
+    def test_fused_covers_token_members_of_the_group_only(self):
+        on, off = _bank_engine(), _bank_engine(pii_on_trunk=False)
+        try:
+            assert on.fused_covers(["intent", "jailbreak", "pii"])
+            assert on.fused_covers(["jailbreak", "pii"])
+            assert not off.fused_covers(["intent", "jailbreak", "pii"])
+            assert off.fused_covers(["intent", "jailbreak"])
+            # off the group the call still answers, task by task
+            out = off.classify_multi(["intent", "pii"], [SHORT_TEXT],
+                                     threshold=LOW)
+            _assert_same_entities(
+                out["pii"][0],
+                off.token_classify("pii", SHORT_TEXT, threshold=LOW))
+            assert out["intent"][0].label == \
+                off.classify("intent", SHORT_TEXT).label
+        finally:
+            on.shutdown()
+            off.shutdown()
+
+    def test_classify_multi_refuses_other_kinds(self):
+        from semantic_router_tpu.engine.testing import make_embedding_engine
+
+        eng = make_embedding_engine()
+        try:
+            with pytest.raises(TypeError, match="embed"):
+                eng.classify_multi(["intent", "embedding"], ["x"])
+            assert not eng.fused_covers(["intent", "embedding"])
+        finally:
+            eng.shutdown()
+
+    # what the item cannot carry keeps its own call; every family still
+    # answers.  ``forwards``: the fewest and the most steps the case
+    # allows — calls in flight together on one trunk group may share a
+    # step, a call made after the item came back cannot
+    FALLBACKS = {
+        "include_history_rule": dict(
+            thresholds=[(LOW, True)], forwards=(1, 2),
+            sources={"domain": "fused_bank", "jailbreak": "fused_bank",
+                     "pii": "engine"}),
+        "two_pii_thresholds": dict(
+            thresholds=[(LOW, False), (0.3, False)], forwards=(2, 2),
+            sources={"domain": "fused_bank", "jailbreak": "fused_bank",
+                     "pii": "fused_bank"}),
+        "token_task_on_no_trunk_group": dict(
+            thresholds=[(LOW, False)], pii_on_trunk=False,
+            forwards=(2, 2),
+            sources={"domain": "fused_bank", "jailbreak": "fused_bank",
+                     "pii": "engine"}),
+        "item_raises": dict(
+            thresholds=[(LOW, False)], item_error=RuntimeError("boom"),
+            forwards=(1, 3),
+            sources={"domain": "engine", "jailbreak": "engine",
+                     "pii": "engine"}),
+        "item_times_out": dict(
+            thresholds=[(LOW, False)], item_error=TimeoutError(),
+            forwards=(1, 3),
+            sources={"domain": "engine", "jailbreak": "engine",
+                     "pii": "engine"}),
+    }
+
+    @pytest.mark.parametrize("case", sorted(FALLBACKS))
+    def test_fallbacks_each_family_still_answers(self, case):
+        from semantic_router_tpu.config.schema import PIIRule
+
+        spec = self.FALLBACKS[case]
+        series = fresh_series()
+        eng = _bank_engine(series, spec.get("pii_on_trunk", True))
+        rules = [PIIRule(name=f"r{i}", threshold=th, include_history=hist)
+                 for i, (th, hist) in enumerate(spec["thresholds"])]
+        disp = _bank_dispatcher(eng, pii_rules=rules)
+        if "item_error" in spec:
+            def failing(*a, **kw):
+                raise spec["item_error"]
+
+            eng.classify_multi = failing
+        try:
+            ctx = _ctx(SHORT_TEXT, history=["earlier turn from dave"])
+            _, report = disp.evaluate(ctx)
+            assert set(report.results) == {"domain", "jailbreak", "pii"}
+            assert not any(r.error for r in report.results.values())
+            assert {f: r.source for f, r in report.results.items()} \
+                == spec["sources"]
+            fewest, most = spec["forwards"]
+            assert fewest <= series.trunk_forwards.total() <= most
+            for rule in rules:
+                text = ctx.text_for(rule.include_history)
+                alone = eng.token_classify("pii", text,
+                                           threshold=rule.threshold)
+                assert _hit_spans(report, rule.name) == [
+                    s[:3] for s in _spans(alone)]
+        finally:
+            disp.shutdown()
+            eng.shutdown()
+
+    def test_dead_pii_head_does_not_take_jailbreak_with_it(self):
+        """One item failing falls back per family: it does not fail
+        three families."""
+        eng = _bank_engine()
+        disp = _bank_dispatcher(eng)
+
+        def dead(*a, **kw):
+            raise RuntimeError("pii head is gone")
+
+        eng.classify_multi = dead
+        eng.token_classify = dead
+        try:
+            _, report = disp.evaluate(_ctx(SHORT_TEXT))
+            assert "pii head is gone" in report.results["pii"].error
+            for fam in ("domain", "jailbreak"):
+                assert not report.results[fam].error
+                assert report.results[fam].source == "engine"
+                assert report.results[fam].hits
+        finally:
+            disp.shutdown()
+            eng.shutdown()
+
+    def test_embedding_item_is_submitted_while_the_fused_item_rides(self):
+        """The families the item does not serve start first: the fused
+        item's return is gated on the embedding call having begun."""
+        import jax
+        import jax.numpy as jnp
+
+        from semantic_router_tpu.config.schema import EmbeddingRule
+        from semantic_router_tpu.engine.testing import tiny_config
+        from semantic_router_tpu.models.embeddings import (
+            MmBertEmbeddingModel,
+        )
+        from semantic_router_tpu.signals.embedding_signal import (
+            EmbeddingSignal,
+        )
+
+        eng = _bank_engine()
+        module = MmBertEmbeddingModel(tiny_config(0))
+        eng.register_task(
+            "embedding", "embedding", module,
+            module.init(jax.random.PRNGKey(5), jnp.ones((1, 8), jnp.int32)),
+            HashTokenizer(vocab_size=1024), [], max_seq_len=512)
+        disp = _bank_dispatcher(eng, extra=[EmbeddingSignal(eng, [
+            EmbeddingRule(name="support", threshold=0.0,
+                          candidates=["how to configure the system"])])])
+        embed_begun = threading.Event()
+        seen = {}
+        inner_embed, inner_multi = eng.embed, eng.classify_multi
+
+        def embed(task, texts, *a, **kw):
+            if SHORT_TEXT in texts:
+                embed_begun.set()
+            return inner_embed(task, texts, *a, **kw)
+
+        def classify_multi(tasks, texts, **kw):
+            out = inner_multi(tasks, texts, **kw)
+            # the gate: a dispatcher that waits for the item before it
+            # fans out never calls embed, and fails here
+            seen["embed_begun"] = embed_begun.wait(timeout=60)
+            return out
+
+        eng.embed, eng.classify_multi = embed, classify_multi
+        try:
+            _, report = disp.evaluate(_ctx(SHORT_TEXT))
+            assert seen == {"embed_begun": True}
+            assert not any(r.error for r in report.results.values())
+            assert report.results["embedding"].hits
+            assert report.results["pii"].source == "fused_bank"
+        finally:
+            disp.shutdown()
+            eng.shutdown()
+
+    def test_generative_jailbreak_takes_none_of_the_new_path(self):
+        """A jailbreak task answered by generation sits on no trunk
+        group: its family goes to guard_classify, and with one other
+        task left there is no item."""
+        from types import SimpleNamespace
+
+        from semantic_router_tpu.utils.tokenization import Encoding
+
+        eng = make_shared_trunk_engine(tasks=SEQ_PAIR[:1],
+                                       token_tasks=[PII_TASK],
+                                       metrics=fresh_series())
+
+        class Guard:
+            tokenizer = SimpleNamespace(encode=lambda text, **kw: Encoding(
+                ids=[1, 2, 3], attention_mask=[1, 1, 1],
+                offsets=[(0, 0)] * 3))
+
+            def generate(self, prompts, **kw):
+                return [SimpleNamespace(
+                    text="Safety: Safe\nCategories: None\n",
+                    token_ids=[], truncated=False) for _ in prompts]
+
+        eng.register_generative("jailbreak", Guard())
+        calls = []
+        eng.classify_multi = lambda *a, **kw: calls.append(a)
+        disp = _bank_dispatcher(eng)
+        try:
+            only_guard = [disp.evaluators["jailbreak"]]
+            assert disp._gather_fused(_ctx(SHORT_TEXT), only_guard) == []
+            ctx = _ctx(SHORT_TEXT)
+            (item,) = disp._gather_fused(ctx, disp.active_evaluators())
+            assert item.tasks() == ["intent", "pii"]
+            # the guard cell's shape: one engine family, no item, inline
+            guard_only = _bank_dispatcher(eng)
+            guard_only.used_types = {"jailbreak"}
+            _, report = guard_only.evaluate(_ctx(SHORT_TEXT))
+            guard_only.shutdown()
+            assert not report.results["jailbreak"].error
+            assert report.results["jailbreak"].source == "engine"
+            assert calls == []
+        finally:
+            disp.shutdown()
+            eng.shutdown()
+
+    def test_cascade_results_unchanged(self):
+        """engine/cascade keeps the blocking prefetch: a wave's families
+        answer what the plain fan-out answers, PII from the memo."""
+        from semantic_router_tpu.config.schema import (
+            Decision,
+            ModelRef,
+            RuleNode,
+        )
+        from semantic_router_tpu.decision.engine import DecisionEngine
+        from semantic_router_tpu.engine.cascade import (
+            CascadeEvaluator,
+            normalize_cascade,
+        )
+
+        def leaf(styp, name):
+            return RuleNode(signal_type=styp, name=name)
+
+        eng = _bank_engine()
+        disp = _bank_dispatcher(eng)
+        decisions = DecisionEngine([
+            Decision(name="block", priority=9, rules=leaf("jailbreak", "jb"),
+                     model_refs=[ModelRef(model="m")]),
+            Decision(name="pii", priority=5,
+                     rules=leaf("pii", "restricted"),
+                     model_refs=[ModelRef(model="m")]),
+            Decision(name="law", priority=1, rules=leaf("domain", "law"),
+                     model_refs=[ModelRef(model="m")])], "priority")
+        casc = CascadeEvaluator(metrics=fresh_series())
+        casc.configure(normalize_cascade({"enabled": True}))
+        try:
+            plain_signals, plain = disp.evaluate(_ctx(SHORT_TEXT))
+            signals, report = casc.evaluate(_ctx(SHORT_TEXT), disp,
+                                            decisions)
+            assert signals.matches == plain_signals.matches
+            for fam, want in plain.results.items():
+                got = report.results[fam]
+                assert (got.error, got.source) == (want.error, want.source)
+                assert [(h.rule, round(h.confidence, 5))
+                        for h in got.hits] == \
+                    [(h.rule, round(h.confidence, 5)) for h in want.hits]
+            assert report.results["pii"].source == "fused_bank"
+        finally:
+            disp.shutdown()
+            eng.shutdown()
+
+    def test_results_counted_by_family_and_source(self):
+        registry = MetricsRegistry()
+        series = MetricSeries(registry)
+        eng = _bank_engine()
+        disp = _bank_dispatcher(eng, metrics=series)
+        try:
+            disp.evaluate(_ctx(SHORT_TEXT))
+            for fam in ("domain", "jailbreak", "pii"):
+                assert series.signal_results.get(
+                    family=fam, source="fused_bank") == 1
+            assert series.signal_results.total() == 3
+            assert ('llm_signal_results_total{family="pii",'
+                    'source="fused_bank"} 1') in registry.expose()
+        finally:
+            disp.shutdown()
+            eng.shutdown()
+
+
 class TestJitCacheBudget:
     def test_shapes_per_trunk_within_budget(self):
         """The fused bank's compiled-shape count stays ≤

@@ -2,6 +2,8 @@
 structure_classifier.go, context_classifier.go, language_classifier.go,
 authz_classifier.go, reask_classifier.go, nlp-binding scorers)."""
 
+import pytest
+
 from semantic_router_tpu.config import load_config
 from semantic_router_tpu.decision import DecisionEngine
 from semantic_router_tpu.signals import (
@@ -47,6 +49,92 @@ class TestKeyword:
         sig = KeywordSignal(router_config.signals.keywords)
         res = sig.evaluate(ctx_from_text("my credit-card number is 4111"))
         assert "fuzzy_sensitive" in hits(res)
+
+    @staticmethod
+    def _every_window(needle, haystack):
+        """fuzzy_partial_ratio as it was: every candidate window scored.
+        Kept here as the reference the pruned scan is held to."""
+        import re
+
+        from semantic_router_tpu.signals.keyword import fuzzy_ratio
+
+        if not needle or not haystack:
+            return 0.0
+        if needle in haystack:
+            return 100.0
+        n = len(needle)
+        if len(haystack) <= n:
+            return fuzzy_ratio(needle, haystack)
+        starts = {0}
+        for m in re.finditer(r"\S+", haystack):
+            starts.add(m.start())
+        starts.update(range(0, len(haystack) - n + 1, max(1, n // 2)))
+        best = 0.0
+        for i in sorted(starts):
+            if i + 1 >= len(haystack):
+                break
+            best = max(best, fuzzy_ratio(needle, haystack[i:i + n]))
+            if best >= 99.9:
+                break
+        return best
+
+    @pytest.mark.parametrize("library", ["native", "pure_python"])
+    @pytest.mark.parametrize("floor", [0.0, 50.0, 82.0])
+    def test_fuzzy_window_bound_changes_no_answer(self, floor, library,
+                                                  monkeypatch):
+        """Windows whose character counts cannot reach the floor (or the
+        best so far) are not scored: a score of at least the floor is
+        exactly what scoring every window gives, a lower one is never
+        reported higher; with no floor every score is exact."""
+        import random
+
+        from semantic_router_tpu import native
+        from semantic_router_tpu.signals.keyword import fuzzy_partial_ratio
+
+        if library == "pure_python":
+            monkeypatch.setattr(native, "_LIB", None)
+            monkeypatch.setattr(native, "_LOAD_FAILED", True)
+        elif not native.available():
+            pytest.skip("native lexical library not built")
+        rng = random.Random(int(floor) + len(library))
+        alphabet = "abcde\u00e9 s-"
+        reached = 0
+        for _ in range(1500):
+            needle = "".join(rng.choice(alphabet)
+                             for _ in range(rng.randint(1, 12)))
+            hay = "".join(rng.choice(alphabet)
+                          for _ in range(rng.randint(0, 80)))
+            got = fuzzy_partial_ratio(needle, hay, floor=floor)
+            want = self._every_window(needle, hay)
+            if want >= floor:
+                assert got == want, (needle, hay)
+                reached += 1
+            else:
+                assert got <= want, (needle, hay)
+        assert reached > 20
+
+    def test_fuzzy_rule_on_a_long_prompt_scores_few_windows(
+            self, router_config, monkeypatch):
+        """A 5,000-word prompt with one near miss in it: the rule's hit
+        and confidence are what every window gives, from a handful of
+        ratio computations, not seven thousand a keyword."""
+        from semantic_router_tpu.signals import KeywordSignal, keyword
+
+        words = " ".join(f"w{(i * 7919) % 250000}" for i in range(5000))
+        text = words + " here is my pasword and my credit-card " + words
+        calls = []
+        inner = keyword.fuzzy_ratio
+        monkeypatch.setattr(keyword, "fuzzy_ratio",
+                            lambda a, b: calls.append(1) or inner(a, b))
+        sig = KeywordSignal(router_config.signals.keywords)
+        (hit,) = [h for h in sig.evaluate(ctx_from_text(text)).hits
+                  if h.rule == "fuzzy_sensitive"]
+        assert sorted(hit.detail["keywords"]) == ["credit card", "password"]
+        scored = len(calls)
+        want = [self._every_window(kw, text.lower())
+                for kw in ("credit card", "password")]
+        assert hit.confidence == pytest.approx(sum(want) / 200.0)
+        assert scored < 50 < len(calls) - scored
 
     def test_exact_and_operator(self, router_config):
         from semantic_router_tpu.signals import KeywordSignal
