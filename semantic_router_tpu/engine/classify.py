@@ -60,6 +60,7 @@ from .packing import (
     normalize_packing,
     pack_items,
 )
+from .step import EngineStep
 
 # batch-group key prefix for fused trunk groups — the group id, not the
 # task name, is the batching unit (see module docstring)
@@ -191,6 +192,38 @@ class _Payload:
     tok_cached: bool = False
 
 
+@dataclass
+class _Layout:
+    """A fused step's host batch as its composition stacked it."""
+
+    ids: np.ndarray
+    mask: np.ndarray
+    # a packed batch's position and segment planes, [rows, bucket] ...
+    row_planes: tuple = ()
+    # ... and its per-segment pooling maps (seg_row, seg_start), [K]
+    seg_maps: tuple = ()
+    # per unique encoding: (device row, slice of its tokens in that row,
+    # clipped at the bucket edge)
+    spans: List[tuple] = field(default_factory=list)
+
+
+@dataclass
+class _Composition:
+    """How a fused step lays its unique encodings out on the device —
+    fixed rows or packed rows — as far as the one fused runner needs to
+    know it: the measured variant, the program's census key (before the
+    pair and mesh suffixes), the row facts, a traced step's extra span
+    attributes, and ``stack()``, which makes the host batch."""
+
+    packed: bool
+    variant: str
+    census: str
+    rows: int
+    padded_rows: int
+    stack: Callable[[], _Layout]
+    span_attrs: Dict[str, Any] = field(default_factory=dict)
+
+
 def _cut_to_bucket(enc: Encoding, bucket: int, keep_tail: int) -> Encoding:
     """A prompt longer than the largest bucket, cut to it: its first tokens
     and its last ``keep_tail`` (a template's closing words, which tell a
@@ -212,40 +245,35 @@ def _cut_to_bucket(enc: Encoding, bucket: int, keep_tail: int) -> Encoding:
 class _GenerationObserver:
     """What a generator's ``generate`` reports to, forward by
     forward (models.generate.NullObserver has the protocol): each forward
-    is one ``engine.step`` annotation with its stages (flavour
-    ``gen.prefill`` | ``gen.denoise`` | ``gen.commit`` | ``gen.decode``;
-    facts ``rows``, ``padded_rows``, ``tokens_real``, ``block``,
-    ``masks_left``), one ``record_step`` sample under group
-    ``gen:<task>`` with the flavour as its variant, and one
-    ``record_generation`` count (the counters of /metrics).  The prefill
-    step carries the batch items, so a traced request's queue wait ends
-    where its generation begins."""
+    is one EngineStep — one ``engine.step`` annotation with its stages
+    (flavour ``gen.prefill`` | ``gen.denoise`` | ``gen.commit`` |
+    ``gen.decode``; facts ``rows``, ``padded_rows``, ``tokens_real``,
+    ``block``, ``masks_left``) and one ``record_step`` sample under group
+    ``gen:<task>`` with the flavour as its variant, whose clock runs from
+    ``forward`` to ``done`` — and one ``record_generation`` count (the
+    counters of /metrics).  The prefill step carries the batch items, so
+    a traced request's queue wait ends where its generation begins.  A
+    generation has one forward open at a time: the observer is its
+    handle."""
 
     def __init__(self, engine, task: str, bucket: int, items, padded_rows: int
                  ) -> None:
         self.engine, self.task, self.bucket = engine, task, bucket
         self.items, self.padded_rows = items, padded_rows
+        self.step: Optional[EngineStep] = None
 
     def forward(self, flavour: str, tokens_real: int = 0, **facts):
-        from ..observability import batchtrace
-
-        step = batchtrace.start_step(
-            self.items if flavour == "gen.prefill" else (),
-            group=f"gen:{self.task}", bucket=self.bucket,
-            max_batch=self.engine.cfg.max_batch_size,
-            padded_rows=self.padded_rows, flavour=flavour,
-            kind="generative", rows=len(self.items),
+        self.step = EngineStep(
+            self.engine, self.items if flavour == "gen.prefill" else (),
+            scope="gen", name=self.task, bucket=self.bucket,
+            padded_rows=self.padded_rows, kind="generative",
+            flavour=flavour, variant=flavour, rows=len(self.items),
             tokens_real=tokens_real, **facts)
-        return _GenerationForward(self, step, flavour, tokens_real)
+        self.step.program()
+        return self
 
-
-class _GenerationForward:
-    def __init__(self, obs: _GenerationObserver, step, flavour: str,
-                 tokens_real: int) -> None:
-        self.obs, self.step, self.flavour = obs, step, flavour
-        self.tokens_real = tokens_real
-        self.t0 = time.perf_counter()
-        self.stage = step.stage
+    def stage(self, name: str):
+        return self.step.stage(name)
 
     def done(self, load=None, committed_blocks: int = 0,
              committed_tokens: int = 0) -> None:
@@ -253,24 +281,24 @@ class _GenerationForward:
         a dense generator gives none."""
         from ..observability import batchtrace
 
-        obs, eng = self.obs, self.obs.engine
-        seconds = time.perf_counter() - self.t0
-        self.step.finish()
-        group = f"gen:{obs.task}"
+        step = self.step
+        step.ran()
+        self.close()
         if load is not None:
-            batchtrace.gen_forward(group, self.flavour, load)
-        shape = (obs.padded_rows, obs.bucket)
-        eng._record_step(
-            group, obs.bucket, self.flavour, len(obs.items),
-            obs.padded_rows, seconds,
-            eng._step_fresh(group, self.flavour, shape),
-            tokens_real=self.tokens_real)
+            batchtrace.gen_forward(step.group, step.variant, load)
         try:
-            eng._runtime_stats.record_generation(
-                obs.task, self.flavour, committed_blocks=committed_blocks,
+            self.engine._runtime_stats.record_generation(
+                self.task, step.variant, committed_blocks=committed_blocks,
                 committed_tokens=committed_tokens)
         except Exception:
             pass  # observability never fails a generation
+
+    def close(self) -> None:
+        """End the open forward's step: after ``done``, or when the
+        forward raised before it."""
+        if self.step is not None:
+            self.step.finish()
+            self.step = None
 
 
 @dataclass
@@ -301,7 +329,6 @@ class TrunkGroup:
     tok_bank: Any = None
     tok_widths: List[int] = field(default_factory=list)
     tok_row_of: Dict[str, int] = field(default_factory=dict)
-    apply_fn: Any = None
     # the fused jit program set keyed by flavor: seq / tok / both plus
     # their packed_* siblings (engine.packing) — all share the ONE trunk
     # forward; the runner picks by batch contents, so a batch with no
@@ -485,11 +512,6 @@ class InferenceEngine:
 
     # -- registration ------------------------------------------------------
 
-    @staticmethod
-    def _is_ring(module) -> bool:
-        cfg = getattr(module, "config", None)
-        return getattr(cfg, "attention_impl", "") == "ring"
-
     def register_task(self, name: str, kind: str, module, params,
                       tokenizer: Tokenizer, labels: List[str],
                       max_seq_len: int = 0, pad_id: int = 0,
@@ -502,7 +524,8 @@ class InferenceEngine:
         if kind not in ("sequence", "token", "embedding"):
             raise ValueError(f"unknown task kind {kind!r}")
         if self.mesh is not None and self.mesh.shape.get("sp", 1) > 1 \
-                and not self._is_ring(module):
+                and getattr(getattr(module, "config", None),
+                            "attention_impl", "") != "ring":
             # a non-ring model under an sp mesh would replicate its
             # whole sequence computation across the sp devices — half
             # the slice doing duplicate work looks healthy and is pure
@@ -770,7 +793,6 @@ class InferenceEngine:
                     if cur is not None and cur.get("meta") == meta \
                             and cur.get("demux") is not g.demux:
                         g.fns = {**cur, "demux": g.demux}
-                        g.apply_fn = g.fns["seq"]
 
                 if locked:
                     refresh()
@@ -792,7 +814,6 @@ class InferenceEngine:
                 return False
             fns["demux"] = g.demux   # capture under the lock: pairs
             g.fns = fns              # with the LIVE banks
-            g.apply_fn = fns["seq"]
             return True
 
         if locked:
@@ -1249,136 +1270,30 @@ class InferenceEngine:
         if not tasks:
             return None
         g = self._task_group.get(tasks[0])
-        if g is None:
-            return None
         return g if all(self._task_group.get(t) is g for t in tasks) \
             else None
 
     def fused_covers(self, tasks: Sequence[str]) -> bool:
-        """True when one fused execution will actually serve every listed
-        task — the dispatcher's prefetch gate.  A trunk group always
-        qualifies, for its sequence AND its token members
-        (classify_multi routes them as one item a text); the stacked
-        bank serves sequence tasks only, and only qualifies when the
-        dual-path chooser would pick it RIGHT NOW — claiming coverage while the chooser serves traditional would
-        turn the prefetch into K *serial* per-task forwards, the exact
-        serialization it exists to avoid.  Best-effort gate: a concurrent
-        history record can still flip classify_multi's own choice between
-        this check and the call — that rare window is bounded by the
-        dispatcher's PREFETCH_TIMEOUT_S and the results are still
-        consumed from the memo, so it degrades, never breaks."""
+        """True when one fused execution serves every listed task: the
+        sequence and token tasks of ONE trunk group (classify_multi sends
+        them as one item a text) — the dispatcher's gather gate."""
         tasks = list(tasks)
-        if not tasks:
-            return False
-        kinds = {self.task_kind(t) for t in tasks}
-        if not kinds <= {"sequence", "token"}:
-            return False
-        if self._common_trunk_group(tasks) is not None:
-            return True
-        # off a trunk group a token task has no fused execution: it
-        # keeps its own token_classify submit
-        stacked = getattr(self, "_stacked", None)
-        if "token" in kinds or stacked is None \
-                or any(t not in stacked["tasks"] for t in tasks):
-            return False
-        from .pathing import STACKED, ProcessingRequirements
-
-        sel = self.path_chooser.choose(
-            ProcessingRequirements(tasks=tasks, batch_size=1))
-        return sel.selected_path == STACKED
-
-    def register_stacked_bank(self, module, params, tokenizer: Tokenizer,
-                              max_seq_len: int = 0, pad_id: int = 0,
-                              strategy: str = "adaptive") -> None:
-        """Register the fused multi-task LoRA bank
-        (models.lora.MultiTaskLoRAClassifier) as the SECOND execution
-        path for its sequence tasks: one trunk pass serves every task.
-        Each covered task must also be registered as a traditional task
-        (register_task) — that pairing is the dual-path premise
-        (routing.rs:14-90): both paths can serve, the chooser picks.
-        ``strategy``: adaptive | latency | confidence | traditional |
-        stacked (the last two pin the path — operator override)."""
-        from .pathing import DualPathChooser
-
-        if self.mesh is not None and self.mesh.shape.get("sp", 1) > 1 \
-                and not self._is_ring(module):
-            # same rule as register_task: sp devices must shard the
-            # sequence, not replicate it
-            raise ValueError(
-                "stacked bank: serving mesh has sp>1 but the bank "
-                "model's attention_impl is not 'ring'")
-        seq_tasks = [t for t in module.task_names
-                     if module.task_kinds.get(t, "sequence") == "sequence"]
-        for t in seq_tasks:
-            if not self.has_task(t):
-                raise ValueError(
-                    f"stacked bank task {t!r} has no traditional "
-                    "registration — register_task it first (dual-path "
-                    "needs both)")
-        if self.mesh is not None:
-            from ..parallel import shard_params
-
-            params = shard_params(params, self.mesh)
-        self._stacked = {
-            "apply_fn": jax.jit(module.apply),
-            "params": params,
-            "tokenizer": tokenizer,
-            "tasks": seq_tasks,
-            "max_seq_len": max_seq_len or self.cfg.seq_len_buckets[-1],
-            "pad_id": pad_id,
-        }
-        # one worker: classify_multi waits on it WITH the caller's
-        # timeout; an abandoned (cold-compiling) run keeps going and
-        # warms the jit cache for the next attempt. Re-registration
-        # (bank hot-reload) retires the old pool instead of leaking its
-        # worker thread.
-        from concurrent.futures import ThreadPoolExecutor
-
-        old_pool = getattr(self, "_stacked_pool", None)
-        if old_pool is not None:
-            old_pool.shutdown(wait=False)
-        self._stacked_pool = ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="stacked-bank")
-        # live cost prior (resilience.costmodel): the runtime-stats
-        # warm-execute EWMAs break the chooser's cold start — the step
-        # sampler has per-variant timing for this engine's programs long
-        # before the chooser accumulates min_history of its own records
-        cost_prior = None
-        if self._runtime_stats is not None:
-            from ..resilience.costmodel import (
-                CostModel,
-                make_path_cost_prior,
-            )
-
-            cost_prior = make_path_cost_prior(
-                CostModel(self._runtime_stats))
-        self.path_chooser = DualPathChooser(strategy=strategy,
-                                            cost_prior=cost_prior)
-        self.last_path_selection = None
+        return all(self.task_kind(t) in ("sequence", "token")
+                   for t in tasks) \
+            and self._common_trunk_group(tasks) is not None
 
     def classify_multi(self, tasks: Sequence[str], texts: Sequence[str],
                        timeout: float = 30.0,
-                       requirements=None,
                        enc_cache=None,
                        threshold: float = 0.5) -> Dict[str, List[Any]]:
         """Classify the same texts under several tasks — the signal
         fan-out shape: ``{task: [result per text]}``, a ClassResult for a
         sequence task and a TokenClassResult (entity spans scored at
         ``threshold``, token_classify's argument) for a token task.
-        With a stacked bank registered, the dual-path chooser decides
-        between one fused pass and per-task batcher submits, learning
-        from its own outcome records; without one, tasks sharing a fused trunk group — sequence and token
-        members alike — ride ONE batched submit (tokenize once, trunk
-        forward once, heads demuxed), and only unrelated tasks fall back
-        to per-task classify_batch / token_classify."""
-        from .pathing import (
-            STACKED,
-            TRADITIONAL,
-            PathMetrics,
-            PathSelection,
-            ProcessingRequirements,
-        )
-
+        Tasks sharing a fused trunk group — sequence and token members
+        alike — ride ONE batched submit (tokenize once, trunk forward
+        once, heads demuxed); tasks of different trunks fall back to
+        per-task classify_batch / token_classify under one deadline."""
         tasks = list(tasks)
         token_tasks = set()
         for t in tasks:
@@ -1386,92 +1301,23 @@ class InferenceEngine:
                 token_tasks.add(t)
             else:
                 self._require(t, kind="sequence")
-        stacked = getattr(self, "_stacked", None)
-        eligible = stacked is not None and len(tasks) > 0 and \
-            all(t in stacked["tasks"] for t in tasks)
-        req = requirements or ProcessingRequirements(
-            tasks=tasks, batch_size=len(texts))
-        if eligible:
-            sel = self.path_chooser.choose(req)
-        else:
-            sel = PathSelection(TRADITIONAL, 1.0,
-                                "no stacked bank covers these tasks",
-                                PathMetrics())
-        self.last_path_selection = sel
-
-        # one deadline covers the WHOLE call: a stacked attempt that
-        # burns budget leaves only the remainder for the traditional
-        # fallback — never (1 + n_tasks) stacked timeouts
+        group = self._common_trunk_group(tasks)
+        if group is not None:
+            return self._fused_multi(group, tasks, texts, timeout=timeout,
+                                     enc_cache=enc_cache,
+                                     threshold=threshold)
         deadline = time.perf_counter() + timeout
 
         def remaining() -> float:
             return max(0.05, deadline - time.perf_counter())
 
-        if sel.selected_path == STACKED:
-            from concurrent.futures import TimeoutError as FutTimeout
-
-            t0 = time.perf_counter()
-            # the fused jit has no internal deadline; waiting on the
-            # dedicated worker honors the caller's timeout (a cold
-            # compile keeps going and warms the cache for later).
-            # When a traditional fallback is in play it needs room, so
-            # the stacked attempt gets half the budget — but a PINNED
-            # stacked strategy is an operator override with no fallback
-            # intent and keeps the whole budget.
-            pinned = self.path_chooser.strategy == STACKED
-            stacked_budget = timeout if pinned else timeout / 2
-            try:
-                out = self._stacked_pool.submit(
-                    self._stacked_run, tasks, texts,
-                    enc_cache).result(stacked_budget)
-            except FutTimeout:
-                self.path_chooser.record(
-                    STACKED, tasks, len(texts), stacked_budget, 0.0,
-                    ok=True)
-                sel = PathSelection(TRADITIONAL, 1.0,
-                                    f"stacked pass exceeded "
-                                    f"{stacked_budget:g}s "
-                                    "budget — serving traditional",
-                                    PathMetrics())
-                self.last_path_selection = sel
-            except Exception:
-                self.path_chooser.record(
-                    STACKED, tasks, len(texts),
-                    time.perf_counter() - t0, 0.0, ok=False)
-                sel = PathSelection(TRADITIONAL, 1.0,
-                                    "stacked pass failed — fail-open to "
-                                    "traditional", PathMetrics())
-                self.last_path_selection = sel
-            else:
-                conf = float(np.mean([r.confidence
-                                      for rs in out.values()
-                                      for r in rs])) if texts else 0.0
-                self.path_chooser.record(
-                    STACKED, tasks, len(texts),
-                    time.perf_counter() - t0, conf)
-                return out
-
-        t0 = time.perf_counter()
-        group = self._common_trunk_group(tasks)
-        if group is not None:
-            out = self._fused_multi(group, tasks, texts,
-                                    timeout=remaining(),
-                                    enc_cache=enc_cache,
-                                    threshold=threshold)
-        else:
-            out = {t: [self.token_classify(t, text, threshold=threshold,
-                                           timeout=remaining(),
-                                           enc_cache=enc_cache)
-                       for text in texts] if t in token_tasks
-                   else self.classify_batch(t, texts, timeout=remaining(),
-                                            enc_cache=enc_cache)
-                   for t in tasks}
-        if eligible:
-            conf = float(np.mean([r.confidence for rs in out.values()
-                                  for r in rs])) if texts else 0.0
-            self.path_chooser.record(TRADITIONAL, tasks, len(texts),
-                                     time.perf_counter() - t0, conf)
-        return out
+        return {t: [self.token_classify(t, text, threshold=threshold,
+                                        timeout=remaining(),
+                                        enc_cache=enc_cache)
+                    for text in texts] if t in token_tasks
+                else self.classify_batch(t, texts, timeout=remaining(),
+                                         enc_cache=enc_cache)
+                for t in tasks}
 
     def _fused_multi(self, g: TrunkGroup, tasks: Sequence[str],
                      texts: Sequence[str], timeout: float = 30.0,
@@ -1507,74 +1353,6 @@ class InferenceEngine:
             results[ti] = res
         return {t: [results[i][t] for i in range(len(texts))]
                 for t in tasks}
-
-    def _stacked_run(self, tasks: Sequence[str], texts: Sequence[str],
-                     enc_cache=None) -> Dict[str, List[ClassResult]]:
-        """One fused pass: tokenize once, pad to (pow2 batch, bucket),
-        run the bank, decode each requested task with ITS registered
-        label set — identical decode semantics to the traditional path."""
-        st = self._stacked
-        n = len(texts)
-        if enc_cache is None:
-            encs = [st["tokenizer"].encode(t, max_length=st["max_seq_len"])
-                    for t in texts]
-            for _ in texts:
-                self._count_tokenization("stacked")
-        else:
-            encs = [enc_cache.get_or_encode(
-                st["tokenizer"], t, st["max_seq_len"],
-                on_miss=lambda: self._count_tokenization("stacked"))
-                for t in texts]
-        for enc in encs:
-            self._note_truncation("stacked", enc)
-        bucket = pick_bucket(max((len(e) for e in encs), default=1),
-                             self.cfg.seq_len_buckets)
-        padded_n = self._padded_batch(n)
-        ids = np.full((padded_n, bucket), st["pad_id"], dtype=np.int32)
-        mask = np.zeros((padded_n, bucket), dtype=np.int32)
-        for i, enc in enumerate(encs):
-            L = min(len(enc), bucket)
-            ids[i, :L] = enc.ids[:L]
-            mask[i, :L] = enc.attention_mask[:L]
-        ids_dev, mask_dev = self._to_device(ids, mask)
-        from ..observability.profiler import trace_span
-
-        self._note_shape("stacked", (padded_n, bucket))
-        fresh = self._step_fresh("stacked", "stacked", (padded_n, bucket))
-        if fresh:
-            self._capture_program(
-                "stacked", bucket, "stacked", (padded_n, bucket),
-                st["apply_fn"], (st["params"], ids_dev, mask_dev),
-                "stacked")
-        fwd_t0 = time.perf_counter()
-        with trace_span("engine.classify_multi.stacked"):
-            logits_by_task = st["apply_fn"](st["params"], ids_dev,
-                                            mask_dev)
-            logits_by_task = {k: np.asarray(jax.device_get(v), np.float32)
-                              for k, v in logits_by_task.items()}
-        self._record_step("stacked", bucket, "stacked", n, padded_n,
-                          time.perf_counter() - fwd_t0, fresh)
-        self._series().trunk_forwards.inc(group="stacked", path="stacked")
-        out: Dict[str, List[ClassResult]] = {}
-        for task in tasks:
-            labels = self._tasks[task].labels
-            probs = _softmax(logits_by_task[task][:n])
-            results = []
-            for i in range(n):
-                idx = int(np.argmax(probs[i]))
-                # width-tolerant decode like the traditional path: a
-                # labels/head-width mismatch names classes positionally
-                # instead of raising (which would silently disable the
-                # stacked path via the fail-open record)
-                results.append(ClassResult(
-                    label=labels[idx] if idx < len(labels) else str(idx),
-                    index=idx, confidence=float(probs[i, idx]),
-                    probs={(labels[j] if j < len(labels) else str(j)):
-                           float(probs[i, j])
-                           for j in range(probs.shape[-1])},
-                    truncated=encs[i].truncated))
-            out[task] = results
-        return out
 
     def _emit_registered(self, name: str, kind: str) -> None:
         """Model-runtime lifecycle event (pkg/modelruntime role)."""
@@ -1841,7 +1619,7 @@ class InferenceEngine:
         t = self._require(task, kind="embedding")
         futures = []
         for text in texts:
-            enc = self._encode(t, text)
+            enc = self._encode_info(t, text)[0]
             bucket = pick_bucket(len(enc), self.cfg.seq_len_buckets)
             # exit/dim participate in the group key: different variants are
             # different XLA programs and must not share a device batch
@@ -2232,9 +2010,6 @@ class InferenceEngine:
         if self._autotuner is not None:
             self._autotuner.stop()
         self.batcher.shutdown()
-        pool = getattr(self, "_stacked_pool", None)
-        if pool is not None:
-            pool.shutdown(wait=False)
 
     # -- internals ---------------------------------------------------------
 
@@ -2259,25 +2034,13 @@ class InferenceEngine:
 
         return M.default_series
 
-    def _note_truncation(self, task: str, enc: Encoding) -> None:
-        """Count every clipped input (llm_tokenizer_truncated_inputs_total)
-        so tail-drop is an operator-visible rate, not a silent default."""
-        if enc.truncated:
-            self._series().truncated_inputs.inc(task=task)
-
     def _count_tokenization(self, task: str) -> None:
         self._series().tokenizations.inc(task=task)
 
-    def _note_shape(self, group: str, shape: tuple) -> bool:
-        """Record a device shape; returns True the FIRST time this group
-        executes it — a fresh shape is one XLA compilation, which is how
-        the runtime-stats sampler tells cold steps from warm ones."""
-        shape = tuple(shape)
+    def _note_shape(self, group: str, shape: tuple) -> None:
+        """Record a device shape this group executed (shape_census)."""
         with self._lock:
-            seen = self._shapes.setdefault(group, set())
-            fresh = shape not in seen
-            seen.add(shape)
-        return fresh
+            self._shapes.setdefault(group, set()).add(tuple(shape))
 
     def _step_fresh(self, group: str, variant: str, shape: tuple) -> bool:
         """Compile detection for the step sampler, keyed per (group,
@@ -2291,22 +2054,6 @@ class InferenceEngine:
             fresh = key not in self._compiled_steps
             self._compiled_steps.add(key)
         return fresh
-
-    def _record_step(self, group: str, bucket: int, variant: str,
-                     rows: int, padded_rows: int, seconds: float,
-                     compiled: bool, tokens_real: int = 0,
-                     tokens_padded: int = 0, segments: int = 0) -> None:
-        """One always-on step sample (observability.runtimestats): a
-        bounded deque append on the hot path; never raises.  Fused and
-        packed steps additionally carry token-level fill + segment
-        counts — the series the packing auto-tuner consumes."""
-        try:
-            self._runtime_stats.record_step(
-                group, bucket, variant, rows, padded_rows, seconds,
-                compiled=compiled, tokens_real=tokens_real,
-                tokens_padded=tokens_padded, segments=segments)
-        except Exception:
-            pass
 
     def _capture_program(self, group: str, bucket: int, variant: str,
                          shape: tuple, fn, args,
@@ -2385,9 +2132,6 @@ class InferenceEngine:
             for tag in trunc_tags:
                 s.truncated_inputs.inc(task=tag)
         return enc, tok_s, not missed
-
-    def _encode(self, t: _Task, text: str, enc_cache=None) -> Encoding:
-        return self._encode_info(t, text, enc_cache)[0]
 
     def _encode_info(self, t: _Task, text: str, enc_cache=None
                      ) -> tuple[Encoding, float, bool]:
@@ -2491,123 +2235,47 @@ class InferenceEngine:
         t = self._require(task_name)
         n = len(items)
         padded_n = self._padded_batch(n)
-
-        from ..observability import batchtrace
-
-        # one step instrument (observability.batchtrace): the engine.step
-        # profiler annotation around the five stage annotations, and —
-        # when an item carries a request trace — the batch.execute step
-        # span with each request's batch.wait/ride spans.  Opened BEFORE
-        # host stacking so the per-request batch.wait span ends where
-        # queue wait actually ends — stacking/H2D time belongs to the
-        # step, not to phantom queue congestion.
-        step = batchtrace.start_step(
-            items, group=f"task:{task_name}", bucket=bucket,
-            max_batch=self.cfg.max_batch_size, padded_rows=padded_n,
-            kind=t.kind,
-            flavour="embed" if t.kind == "embedding" else task_name,
-            tokens_real=_tokens_real(items, bucket))
-        try:
+        embedding = t.kind == "embedding"
+        with EngineStep(
+                self, items, scope="task", name=task_name, bucket=bucket,
+                padded_rows=padded_n, kind=t.kind,
+                flavour="embed" if embedding else task_name,
+                variant="split",
+                tokens_real=_tokens_real(items, bucket)) as step:
             with step.stage("stack"):
                 ids, mask, clipped = self._stack_items(
                     items, bucket, padded_n, t.pad_id, task_name)
             with step.stage("h2d"):
                 ids_dev, mask_dev = self._to_device(ids, mask)
-            # fresh (group, variant, shape) == one XLA compile: the
-            # runtime-stats sampler accounts the cold step separately
-            self._note_shape(f"task:{task_name}", (padded_n, bucket))
-            fresh = self._step_fresh(f"task:{task_name}", "split",
-                                     (padded_n, bucket))
-
-            if t.kind == "embedding":
-                p = items[0].payload
-                if fresh:
-                    self._capture_program(
-                        f"task:{task_name}", bucket, "split",
-                        (padded_n, bucket), t.apply_fn,
-                        (t.params, ids_dev, mask_dev), "split",
-                        kwargs={"exit_layer": p.exit_layer,
-                                "output_dim": p.output_dim})
-                fwd_t0 = time.perf_counter()
-                with step.stage("dispatch"):
-                    emb = t.apply_fn(t.params, ids_dev, mask_dev,
-                                     exit_layer=p.exit_layer,
-                                     output_dim=p.output_dim)
-                with step.stage("readback"):
-                    emb = np.asarray(jax.device_get(emb), dtype=np.float32)
-                self._record_step(f"task:{task_name}", bucket, "split",
-                                  n, padded_n,
-                                  time.perf_counter() - fwd_t0, fresh)
-                self._series().trunk_forwards.inc(group=task_name,
-                                                  path="traditional")
-                with step.stage("demux"):
-                    return [emb[i] for i in range(n)]
-
-            if fresh:
-                self._capture_program(
-                    f"task:{task_name}", bucket, "split",
-                    (padded_n, bucket), t.apply_fn,
-                    (t.params, ids_dev, mask_dev), "split")
-            fwd_t0 = time.perf_counter()
+            # a batch group of an embedding task holds ONE Matryoshka
+            # variant (exit/dim are in its key): static arguments
+            p = items[0].payload
+            kwargs = {"exit_layer": p.exit_layer,
+                      "output_dim": p.output_dim} if embedding else {}
+            step.program(t.apply_fn, (t.params, ids_dev, mask_dev), kwargs)
             with step.stage("dispatch"):
-                logits = t.apply_fn(t.params, ids_dev, mask_dev)
+                out = t.apply_fn(t.params, ids_dev, mask_dev, **kwargs)
             with step.stage("readback"):
-                logits = np.asarray(jax.device_get(logits),
-                                    dtype=np.float32)
-            self._record_step(f"task:{task_name}", bucket, "split",
-                              n, padded_n,
-                              time.perf_counter() - fwd_t0, fresh)
-            self._series().trunk_forwards.inc(group=task_name,
-                                              path="traditional")
-
-            demux_cm = step.stage("demux")
+                out = np.asarray(jax.device_get(out), dtype=np.float32)
+            step.ran()
             now = time.perf_counter()
-            if t.kind == "sequence":
-                with demux_cm:
-                    probs = _softmax(logits[:n])
-                    out = []
-                    for i, item in enumerate(items):
-                        p = probs[i]
-                        idx = int(p.argmax())
-                        out.append(ClassResult(
-                            label=t.labels[idx] if idx < len(t.labels)
-                            else str(idx),
-                            index=idx,
-                            confidence=float(p[idx]),
-                            probs={t.labels[j] if j < len(t.labels)
-                                   else str(j):
-                                   float(p[j]) for j in range(p.shape[-1])},
-                            latency_s=now - item.payload.submit_t,
-                            truncated=item.payload.encoding.truncated
-                            or clipped[i],
-                        ))
-                return out
-            # token classification
-            with demux_cm:
-                probs = _softmax(logits[:n])  # [n, S, L]
-                out = []
+            with step.stage("demux"):
+                if embedding:
+                    return [out[i] for i in range(n)]
+                results = []
                 for i, item in enumerate(items):
                     enc = item.payload.encoding
-                    L = min(len(enc), bucket)
-                    tok_probs = probs[i, :L]
-                    pred = tok_probs.argmax(-1)
-                    labels = [t.labels[j] if j < len(t.labels) else str(j)
-                              for j in pred]
-                    scores = [float(tok_probs[k, j])
-                              for k, j in enumerate(pred)]
-                    spans = decode_entity_spans(
-                        item.payload.text, enc.offsets[:L], labels, scores,
-                        threshold=item.payload.threshold)
-                    out.append(TokenClassResult(
-                        entities=[EntitySpan(**s) for s in spans],
-                        latency_s=now - item.payload.submit_t,
-                        truncated=enc.truncated or clipped[i],
-                    ))
-            return out
-        finally:
-            # failing batches are exactly the ones traces must explain:
-            # the step + ride spans emit even when the forward raised
-            step.finish()
+                    latency = now - item.payload.submit_t
+                    trunc = enc.truncated or clipped[i]
+                    if t.kind == "sequence":
+                        results.append(self._demux_seq(
+                            task_name, _softmax(out[i]), latency, trunc))
+                    else:
+                        L = min(len(enc), bucket)
+                        results.append(self._demux_tok(
+                            task_name, _softmax(out[i, :L]), item, enc, L,
+                            latency, trunc))
+                return results
 
     def _run_generative(self, task_name: str, bucket: int,
                         max_new_tokens: int, task_index: int,
@@ -2625,13 +2293,15 @@ class InferenceEngine:
         if not getattr(gen, "batched", False):  # a generator of prompts only
             return gen.generate(texts, **settings)
         padded_n = self._padded_batch(len(items))
-        self._note_shape(f"gen:{task_name}", (padded_n, bucket))
         encodings = [it.payload.encoding for it in items]
-        results = gen.generate(
-            texts, **settings, encodings=encodings, bucket=bucket,
-            padded_rows=padded_n,
-            observer=_GenerationObserver(self, task_name, bucket, items,
-                                         padded_n))
+        observer = _GenerationObserver(self, task_name, bucket, items,
+                                       padded_n)
+        try:
+            results = gen.generate(
+                texts, **settings, encodings=encodings, bucket=bucket,
+                padded_rows=padded_n, observer=observer)
+        finally:
+            observer.close()
         # prompts that generate() cut to the largest bucket
         n_cut = sum(enc.truncated for enc in encodings)
         if n_cut:
@@ -2749,12 +2419,12 @@ class InferenceEngine:
             return (list(self._run_fused_batch(gid, bucket, items[:mid]))
                     + list(self._run_fused_batch(gid, bucket,
                                                  items[mid:])))
-        if use_packed:
-            return self._run_fused_packed(g, gid, bucket, items, urow,
-                                          uniq_items, demux, fns,
-                                          flavor, max_segs, plan_rows)
-        return self._run_fused_unpacked(g, gid, bucket, items, urow,
-                                        uniq_items, demux, fns, flavor)
+        comp = self._packed_rows(g, bucket, uniq_items, flavor, srv_mesh,
+                                 row_cap, max_segs, plan_rows) \
+            if use_packed \
+            else self._fixed_rows(g, bucket, uniq_items, flavor, srv_mesh)
+        return self._run_fused(gid, bucket, items, urow, uniq_items,
+                               demux, fns, flavor, comp)
 
     def _bgmv_pairs(self, items: List[BatchItem], urow: List[int],
                     demux: dict):
@@ -2782,23 +2452,20 @@ class InferenceEngine:
             pt[p] = row
         return pr, pt, pair_index
 
-    def _count_kernel_step(self, gid: str, meta: dict,
-                           used_bgmv: bool) -> None:
-        """llm_engine_kernel_steps_total: device steps served through
-        each tuned-kernel path — the operator's proof the knobs are
-        actually on the hot path, not just accepted by config."""
-        if not (meta["quant"] != "off" or meta["epilogue"] or used_bgmv):
-            return
-        m = self._series()
+    @staticmethod
+    def _kernel_labels(meta: dict, used_bgmv: bool) -> List[str]:
+        """The tuned-kernel paths a fused step ran through (the
+        ``kernel`` labels of llm_engine_kernel_steps_total)."""
+        labels = []
         if meta["quant"] != "off":
-            m.kernel_steps.inc(group=gid,
-                               kernel=f"quant_{meta['quant']}")
+            labels.append(f"quant_{meta['quant']}")
         if meta["epilogue"]:
-            m.kernel_steps.inc(group=gid, kernel="epilogue")
+            labels.append("epilogue")
         if used_bgmv:
-            m.kernel_steps.inc(group=gid, kernel="bgmv")
+            labels.append("bgmv")
+        return labels
 
-    # -- fused demux helpers -----------------------------------------------
+    # -- decoding an item's answer -----------------------------------------
 
     def _demux_seq(self, task: str, p: np.ndarray, latency_s: float,
                    truncated: bool) -> ClassResult:
@@ -2836,324 +2503,202 @@ class InferenceEngine:
             truncated=truncated,
         )
 
-    def _fused_result(self, item, per_task: Dict[str, Any]):
-        return per_task[item.payload.tasks[0]] \
-            if len(item.payload.tasks) == 1 else per_task
+    # -- the fused runner and its two compositions --------------------------
 
-    @staticmethod
-    def _run_fused_program(step, fn, args: tuple, flavor: str):
-        """One fused program's ``dispatch`` and ``readback`` stages:
-        (sequence logits, token logits) on the host as float32, None for
-        the bank the flavour does not run."""
-        with step.stage("dispatch"):
-            res = fn(*args)
-        seq_logits, tok_logits = (res, None) if flavor == "seq" else \
-            (None, res) if flavor == "tok" else res
-        with step.stage("readback"):
-            if seq_logits is not None:
-                seq_logits = np.asarray(jax.device_get(seq_logits),
-                                        dtype=np.float32)
-            if tok_logits is not None:
-                tok_logits = np.asarray(jax.device_get(tok_logits),
-                                        dtype=np.float32)
-        return seq_logits, tok_logits
-
-    def _run_fused_unpacked(self, g: TrunkGroup, gid: str, bucket: int,
-                            items: List[BatchItem], urow: List[int],
-                            uniq_items: List[BatchItem], demux: dict,
-                            fns: dict, flavor: str) -> Sequence[Any]:
-        """The fixed-row fused path: one trunk row per unique encoding,
-        padded to the bucket edge — exactly the pre-packing behavior."""
-        n_rows = len(uniq_items)
-        srv_mesh = fns.get("mesh")
-        padded_n = self._padded_batch(n_rows, mesh=srv_mesh)
-        bank, tok_bank = demux["bank"], demux["tok_bank"]
-        meta = fns["meta"]
-        tparams = fns["trunk_params"]
+    def _fixed_rows(self, g: TrunkGroup, bucket: int,
+                    uniq_items: List[BatchItem], flavor: str, srv_mesh
+                    ) -> _Composition:
+        """One trunk row per unique encoding, padded to the bucket edge."""
+        padded_rows = self._padded_batch(len(uniq_items), mesh=srv_mesh)
         # sharding-aware compile variants key on the mesh shape: the
         # sharded and single-device programs are distinct XLA programs
-        # with their own compile/EWMA accounting (sharded-vs-unsharded
-        # step time reads straight off /debug/runtime)
-        msfx = mesh_suffix(meta.get("mesh"))
-        use_bgmv = meta["bgmv"] and flavor in ("seq", "both")
-        pr = pt = pr_dev = pt_dev = pair_index = None
-        pair_sfx = ""
-        if use_bgmv:
-            pr, pt, pair_index = self._bgmv_pairs(items, urow, demux)
-            # the padded pair count is its own static program dimension
-            pair_sfx = f":p{pr.shape[0]}"
-        tokens_real = _tokens_real(uniq_items, bucket)
+        # with their own compile/EWMA accounting
+        variant = "fused_mesh" if srv_mesh is not None else "fused"
 
-        from ..observability import batchtrace
+        def stack() -> _Layout:
+            ids, mask, clipped = self._stack_items(uniq_items, bucket,
+                                                   padded_rows, g.pad_id)
+            return _Layout(ids, mask, spans=[
+                (u, slice(0, min(len(it.payload.encoding), bucket)),
+                 clipped[u]) for u, it in enumerate(uniq_items)])
 
-        # one step instrument (observability.batchtrace): engine.step
-        # around the five stage annotations on the profiler's clock; a
-        # traced batch also gets one batch.execute step span and each
-        # originating request's trace receives batch.wait/tokenize/ride
-        # spans linked to it (a sampled one, the stages as children).
-        # Every batch runs the same program.  Opened BEFORE host stacking
-        # so batch.wait measures only queue time, not stacking/H2D.
-        step = batchtrace.start_step(
-            items, group=f"trunk:{gid}", bucket=bucket,
-            max_batch=self.cfg.max_batch_size, padded_rows=padded_n,
-            kind="fused", flavour=flavor, rows=n_rows,
-            tokens_real=tokens_real)
-        try:
-            with step.stage("stack"):
-                ids, mask, clipped = self._stack_items(uniq_items,
-                                                       bucket,
-                                                       padded_n, g.pad_id)
-                for i, item in enumerate(items):
-                    if clipped[urow[i]]:
-                        for task in item.payload.tasks:
-                            self._series().bucket_overflows.inc(task=task)
-            with step.stage("h2d"):
-                ids_dev, mask_dev = self._to_device(ids, mask,
-                                                    mesh=srv_mesh)
-                if use_bgmv:
-                    pr_dev, pt_dev = jnp.asarray(pr), jnp.asarray(pt)
-            self._note_shape(f"trunk:{gid}", (padded_n, bucket))
-            variant = "fused_mesh" if srv_mesh is not None else "fused"
-            fresh = self._step_fresh(f"trunk:{gid}",
-                                     f"{variant}:{flavor}{pair_sfx}"
-                                     f"{msfx}",
-                                     (padded_n, bucket))
-            if flavor == "seq":
-                fn = fns["seq"]
-                args = (tparams, bank, ids_dev, mask_dev)
-            elif flavor == "tok":
-                fn = fns["tok"]
-                args = (tparams, tok_bank, ids_dev, mask_dev)
-            else:
-                fn = fns["both"]
-                args = (tparams, bank, tok_bank, ids_dev, mask_dev)
-            if use_bgmv:
-                args += (pr_dev, pt_dev)
-            if fresh:
-                self._capture_program(
-                    f"trunk:{gid}", bucket,
-                    f"{variant}:{flavor}{pair_sfx}{msfx}",
-                    (padded_n, bucket), fn, args, variant, meta)
-            fwd_t0 = time.perf_counter()
-            seq_logits, tok_logits = self._run_fused_program(
-                step, fn, args, flavor)
-            self._record_step(f"trunk:{gid}", bucket, variant,
-                              n_rows, padded_n,
-                              time.perf_counter() - fwd_t0, fresh,
-                              tokens_real=tokens_real,
-                              tokens_padded=padded_n * bucket,
-                              segments=n_rows)
-            self._series().trunk_forwards.inc(group=gid, path="fused")
-            if srv_mesh is not None:
-                self._series().mesh_steps.inc(group=gid)
-            self._count_kernel_step(gid, meta, use_bgmv)
+        return _Composition(False, variant, f"{variant}:{flavor}",
+                            len(uniq_items), padded_rows, stack)
 
-            now = time.perf_counter()
-            out: List[Any] = []
-            with step.stage("demux"):
-                for i, item in enumerate(items):
-                    enc = item.payload.encoding
-                    L = min(len(enc), bucket)
-                    latency = now - item.payload.submit_t
-                    trunc = enc.truncated or clipped[urow[i]]
-                    per_task: Dict[str, Any] = {}
-                    for task in item.payload.tasks:
-                        if self._tasks[task].kind == "token":
-                            row = demux["tok_row_of"][task]
-                            width = demux["tok_widths"][row]
-                            probs = _softmax(
-                                tok_logits[urow[i], :L, row, :width])
-                            per_task[task] = self._demux_tok(
-                                task, probs, item, enc, L, latency,
-                                trunc)
-                        else:
-                            row = demux["row_of"][task]
-                            width = demux["widths"][row]
-                            # fan the shared trunk row's logits out to
-                            # every duplicate item at demux; the BGMV
-                            # path demuxes by PAIR instead of (row,
-                            # task) — same logits, gathered on device
-                            if use_bgmv:
-                                src = seq_logits[
-                                    pair_index[(urow[i], row)], :width]
-                            else:
-                                src = seq_logits[urow[i], row, :width]
-                            p = _softmax(src[None, :])[0]
-                            per_task[task] = self._demux_seq(
-                                task, p, latency, trunc)
-                    out.append(self._fused_result(item, per_task))
-            return out
-        finally:
-            step.finish()
-
-    def _run_fused_packed(self, g: TrunkGroup, gid: str, bucket: int,
-                          items: List[BatchItem], urow: List[int],
-                          uniq_items: List[BatchItem], demux: dict,
-                          fns: dict, flavor: str, max_segs: int,
-                          plan_rows: int) -> Sequence[Any]:
-        """The sequence-packed fused path (docs/PACKING.md): unique
+    def _packed_rows(self, g: TrunkGroup, bucket: int,
+                     uniq_items: List[BatchItem], flavor: str, srv_mesh,
+                     row_cap: int, max_segs: int, plan_rows: int
+                     ) -> _Composition:
+        """The sequence-packed composition (docs/PACKING.md): unique
         encodings bin-pack into shared rows under a block-diagonal
         attention mask with per-segment RoPE positions; sequence heads
-        pool PER SEGMENT, token heads demux each segment's span of the
-        per-token logits.  Logit parity with the unpacked path is the
-        golden gate (tests/test_packing.py, ≤1e-4)."""
-        n_rows = len(uniq_items)
-        srv_mesh = fns.get("mesh")
+        pool PER SEGMENT, token heads read each segment's span of the
+        per-token logits.  Logit parity with fixed rows is the golden
+        gate (tests/test_packing.py, ≤1e-4)."""
+        n_segs = len(uniq_items)
         padded_rows = self._padded_batch(plan_rows, mesh=srv_mesh)
         # the segment axis pads to a power of two — K_pad joins the
-        # closed static-shape set like the row axis does
-        k_pad = 1 << max(0, n_rows - 1).bit_length()
-        bank, tok_bank = demux["bank"], demux["tok_bank"]
+        # closed static-shape set like the row axis does, and is part of
+        # the census key: a fresh K over a warm row shape is a compile
+        k_pad = 1 << max(0, n_segs - 1).bit_length()
+
+        def stack() -> _Layout:
+            pb = pack_items(
+                [it.payload.encoding for it in uniq_items], bucket,
+                g.pad_id, max_rows=row_cap,
+                max_segments_per_row=max_segs,
+                pad_rows_to=padded_rows, pad_segments_to=k_pad)
+            return _Layout(
+                pb.ids, pb.mask,
+                row_planes=(pb.position_ids, pb.segment_ids),
+                seg_maps=(pb.seg_row, pb.seg_start),
+                spans=[(sg.row, slice(sg.start, sg.start + sg.length),
+                        sg.clipped) for sg in pb.segments])
+
+        # "packed_mesh" when dp-sharded: the auto-tuner reads only the
+        # single-device series by design
+        return _Composition(
+            True, "packed_mesh" if srv_mesh is not None else "packed",
+            f"packed:{flavor}:{k_pad}", plan_rows, padded_rows, stack,
+            # HOW packed this step ran, beside a traced step's batch
+            # size and fill attributes
+            span_attrs={
+                "packing.packed": True, "packing.segments": n_segs,
+                "packing.rows": plan_rows,
+                "packing.token_fill": round(
+                    _tokens_real(uniq_items, bucket)
+                    / max(1, padded_rows * bucket), 4)})
+
+    @staticmethod
+    def _fused_program(fns: dict, demux: dict, flavor: str, packed: bool,
+                       ids_dev, mask_dev, row_planes: tuple,
+                       seg_maps: tuple, pairs: tuple):
+        """``(fn, args)`` of a fused step: the program of the flavour and
+        composition out of the batch's snapshot, the banks it applies, the
+        batch, a packed batch's planes, and — for the sequence heads only
+        — its per-segment pooling maps and the BGMV pairs."""
+        banks = {"seq": ("bank",), "tok": ("tok_bank",),
+                 "both": ("bank", "tok_bank")}[flavor]
+        args = (fns["trunk_params"], *(demux[b] for b in banks),
+                ids_dev, mask_dev, *row_planes)
+        if flavor != "tok":
+            args += (*seg_maps, *pairs)
+        return fns["packed_" + flavor if packed else flavor], args
+
+    def _run_fused(self, gid: str, bucket: int, items: List[BatchItem],
+                   urow: List[int], uniq_items: List[BatchItem],
+                   demux: dict, fns: dict, flavor: str,
+                   comp: _Composition) -> Sequence[Any]:
+        """One fused step: the composition's batch through the flavour's
+        program, then each item's (row or segment, task) logits against
+        the task's own label set."""
+        srv_mesh = fns.get("mesh")
         meta = fns["meta"]
-        tparams = fns["trunk_params"]
-        msfx = mesh_suffix(meta.get("mesh"))
         use_bgmv = meta["bgmv"] and flavor in ("seq", "both")
-        pr = pt = pr_dev = pt_dev = pair_index = None
+        pr = pt = pair_index = None
         pair_sfx = ""
         if use_bgmv:
-            # packed pairs index SEGMENTS: the packed pool emits one
-            # pooled row per segment, and urow is the segment index
+            # deduped items share pairs as they share trunk rows; packed
+            # pairs index SEGMENTS (the packed pool emits one pooled row
+            # a segment, and urow is the segment index).  The padded pair
+            # count is its own static program dimension
             pr, pt, pair_index = self._bgmv_pairs(items, urow, demux)
             pair_sfx = f":p{pr.shape[0]}"
-
-        from ..observability import batchtrace
-
-        step = batchtrace.start_step(
-            items, group=f"trunk:{gid}", bucket=bucket,
-            max_batch=self.cfg.max_batch_size, padded_rows=padded_rows,
-            kind="fused", flavour=flavor, rows=plan_rows,
-            tokens_real=_tokens_real(uniq_items, bucket))
-        try:
+        with EngineStep(
+                self, items, scope="trunk", name=gid, bucket=bucket,
+                padded_rows=comp.padded_rows, kind="fused",
+                flavour=flavor, variant=comp.variant,
+                census=f"{comp.census}{pair_sfx}"
+                       f"{mesh_suffix(meta.get('mesh'))}",
+                rows=comp.rows,
+                tokens_real=_tokens_real(uniq_items, bucket),
+                tokens_padded=comp.padded_rows * bucket,
+                segments=len(uniq_items), meta=meta, packed=comp.packed,
+                mesh=srv_mesh is not None,
+                kernels=self._kernel_labels(meta, use_bgmv),
+                span_attrs=comp.span_attrs) as step:
             with step.stage("stack"):
-                dp = int(srv_mesh.shape.get("dp", 1)) \
-                    if srv_mesh is not None else 1
-                pb = pack_items(
-                    [it.payload.encoding for it in uniq_items], bucket,
-                    g.pad_id, max_rows=self.cfg.max_batch_size * dp,
-                    max_segments_per_row=max_segs,
-                    pad_rows_to=padded_rows, pad_segments_to=k_pad)
-                clipped = [s.clipped for s in pb.segments]
+                lay = comp.stack()
                 for i, item in enumerate(items):
-                    if clipped[urow[i]]:
+                    if lay.spans[urow[i]][2]:
                         for task in item.payload.tasks:
                             self._series().bucket_overflows.inc(task=task)
             with step.stage("h2d"):
-                ids_dev, mask_dev = self._to_device(pb.ids, pb.mask,
+                ids_dev, mask_dev = self._to_device(lay.ids, lay.mask,
                                                     mesh=srv_mesh)
-                if srv_mesh is not None:
+                if srv_mesh is not None and comp.packed:
                     # position/segment planes shard with their rows so
                     # each dp shard masks/pools ITS row slice; the
-                    # per-segment demux maps ([K] gathers) replicate —
-                    # XLA inserts the gather collectives
+                    # per-segment maps ([K] gathers) replicate — XLA
+                    # inserts the gather collectives
                     from ..parallel import batch_sharding, replicated
 
                     row_sh = batch_sharding(srv_mesh)
                     rep = replicated(srv_mesh)
-                    pos_dev = jax.device_put(pb.position_ids, row_sh)
-                    seg_dev = jax.device_put(pb.segment_ids, row_sh)
-                    seg_row = jax.device_put(pb.seg_row, rep)
-                    seg_start = jax.device_put(pb.seg_start, rep)
+                    row_planes = tuple(jax.device_put(x, row_sh)
+                                       for x in lay.row_planes)
+                    seg_maps = tuple(jax.device_put(x, rep)
+                                     for x in lay.seg_maps)
                 else:
-                    pos_dev = jnp.asarray(pb.position_ids)
-                    seg_dev = jnp.asarray(pb.segment_ids)
-                    seg_row = jnp.asarray(pb.seg_row)
-                    seg_start = jnp.asarray(pb.seg_start)
-                if use_bgmv:
-                    pr_dev, pt_dev = jnp.asarray(pr), jnp.asarray(pt)
-            if step.traced:
-                # packed-step span attributes: the trace shows HOW
-                # packed this step ran, next to the existing batch
-                # size/fill attributes
-                step.attrs["packing.packed"] = True
-                step.attrs["packing.segments"] = n_rows
-                step.attrs["packing.rows"] = pb.rows_used
-                step.attrs["packing.token_fill"] = round(
-                    pb.tokens_real / max(1, padded_rows * bucket), 4)
-            self._note_shape(f"trunk:{gid}", (padded_rows, bucket))
-            # the K (segment) axis is its own static program dimension:
-            # compile detection keys on it so a fresh K over a warm row
-            # shape still counts as the compile it is
-            fresh = self._step_fresh(f"trunk:{gid}",
-                                     f"packed:{flavor}:{k_pad}"
-                                     f"{pair_sfx}{msfx}",
-                                     (padded_rows, bucket))
-            if flavor == "seq":
-                fn = fns["packed_seq"]
-                args = (tparams, bank, ids_dev, mask_dev,
-                        pos_dev, seg_dev, seg_row, seg_start)
-            elif flavor == "tok":
-                fn = fns["packed_tok"]
-                args = (tparams, tok_bank, ids_dev, mask_dev,
-                        pos_dev, seg_dev)
-            else:
-                fn = fns["packed_both"]
-                args = (tparams, bank, tok_bank, ids_dev, mask_dev,
-                        pos_dev, seg_dev, seg_row, seg_start)
-            if use_bgmv:
-                args += (pr_dev, pt_dev)
-            if fresh:
-                self._capture_program(
-                    f"trunk:{gid}", bucket,
-                    f"packed:{flavor}:{k_pad}{pair_sfx}{msfx}",
-                    (padded_rows, bucket), fn, args,
-                    "packed_mesh" if srv_mesh is not None else "packed",
-                    meta)
-            fwd_t0 = time.perf_counter()
-            seq_logits, tok_logits = self._run_fused_program(
-                step, fn, args, flavor)
-            self._record_step(f"trunk:{gid}", bucket,
-                              "packed_mesh" if srv_mesh is not None
-                              else "packed",
-                              pb.rows_used, padded_rows,
-                              time.perf_counter() - fwd_t0, fresh,
-                              tokens_real=pb.tokens_real,
-                              tokens_padded=padded_rows * bucket,
-                              segments=n_rows)
-            # a packed step IS a fused trunk forward (dashboards sum
-            # path="fused" for bank coalescing); packing visibility has
-            # its own counter + the runtimestats "packed" variant
-            # ("packed_mesh" when dp-sharded — the auto-tuner reads
-            # only the single-device series by design)
-            self._series().trunk_forwards.inc(group=gid, path="fused")
-            self._series().packed_steps.inc(group=gid)
-            if srv_mesh is not None:
-                self._series().mesh_steps.inc(group=gid)
-            self._count_kernel_step(gid, meta, use_bgmv)
+                    row_planes = tuple(map(jnp.asarray, lay.row_planes))
+                    seg_maps = tuple(map(jnp.asarray, lay.seg_maps))
+                pairs = (jnp.asarray(pr), jnp.asarray(pt)) if use_bgmv \
+                    else ()
+            fn, args = self._fused_program(
+                fns, demux, flavor, comp.packed, ids_dev, mask_dev,
+                row_planes, seg_maps, pairs)
+            step.program(fn, args)
+            with step.stage("dispatch"):
+                res = fn(*args)
+            # (sequence logits, token logits), None for the bank the
+            # flavour does not run
+            seq_logits, tok_logits = (res, None) if flavor == "seq" else \
+                (None, res) if flavor == "tok" else res
+            with step.stage("readback"):
+                if seq_logits is not None:
+                    seq_logits = np.asarray(jax.device_get(seq_logits),
+                                            dtype=np.float32)
+                if tok_logits is not None:
+                    tok_logits = np.asarray(jax.device_get(tok_logits),
+                                            dtype=np.float32)
+            step.ran()
 
             now = time.perf_counter()
             out: List[Any] = []
             with step.stage("demux"):
                 for i, item in enumerate(items):
                     enc = item.payload.encoding
-                    seg = pb.segments[urow[i]]
+                    u = urow[i]
+                    row, span, clipped = lay.spans[u]
                     latency = now - item.payload.submit_t
-                    trunc = enc.truncated or seg.clipped
+                    trunc = enc.truncated or clipped
                     per_task: Dict[str, Any] = {}
                     for task in item.payload.tasks:
                         if self._tasks[task].kind == "token":
-                            row = demux["tok_row_of"][task]
-                            width = demux["tok_widths"][row]
-                            sl = slice(seg.start, seg.start + seg.length)
-                            probs = _softmax(
-                                tok_logits[seg.row, sl, row, :width])
+                            b = demux["tok_row_of"][task]
+                            width = demux["tok_widths"][b]
                             per_task[task] = self._demux_tok(
-                                task, probs, item, enc, seg.length,
+                                task,
+                                _softmax(tok_logits[row, span, b, :width]),
+                                item, enc, span.stop - span.start,
                                 latency, trunc)
                         else:
-                            row = demux["row_of"][task]
-                            width = demux["widths"][row]
-                            if use_bgmv:
-                                src = seq_logits[
-                                    pair_index[(urow[i], row)], :width]
-                            else:
-                                src = seq_logits[urow[i], row, :width]
-                            p = _softmax(src[None, :])[0]
+                            b = demux["row_of"][task]
+                            width = demux["widths"][b]
+                            # a shared trunk row's logits fan out to
+                            # every duplicate item here; the BGMV path
+                            # demuxes by PAIR instead of (row, task) —
+                            # same logits, gathered on device
+                            src = seq_logits[pair_index[(u, b)], :width] \
+                                if use_bgmv else seq_logits[u, b, :width]
                             per_task[task] = self._demux_seq(
-                                task, p, latency, trunc)
-                    out.append(self._fused_result(item, per_task))
+                                task, _softmax(src), latency, trunc)
+                    # one task: its result; several (classify_multi's
+                    # one item a text): {task: result}
+                    tasks = item.payload.tasks
+                    out.append(per_task[tasks[0]] if len(tasks) == 1
+                               else per_task)
             return out
-        finally:
-            step.finish()
 
 
 def _tokens_real(items: Sequence[BatchItem], bucket: int) -> int:

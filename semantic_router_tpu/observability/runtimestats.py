@@ -16,8 +16,9 @@ per device step — one bounded ``deque.append`` on the untraced hot path
 thread (or any scrape/report call) drains the deque and aggregates into:
 
 - a **per-jit-program registry** keyed by ``(group, bucket, variant)``
-  (variant: ``fused`` trunk-group batches / ``split`` per-task batches /
-  ``stacked`` bank passes) recording compile count + cold-step time,
+  (variant: ``fused`` / ``packed`` trunk-group batches, ``split``
+  per-task batches, ``gen.*`` forwards of a generation) recording
+  compile count + cold-step time,
   warm execute EWMA + histogram, and padding-waste / fill-ratio
   accounting — the jit-cache budget and MXU utilization surfaces;
 - **process gauges**: host RSS, device memory via

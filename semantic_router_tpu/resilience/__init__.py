@@ -19,7 +19,7 @@ from .controller import (
     default_degradation_controller,
     level_name,
 )
-from .costmodel import CostModel, make_path_cost_prior
+from .costmodel import CostModel
 from .upstream import (
     DEADLINE_HEADER,
     UpstreamHealth,
@@ -36,7 +36,7 @@ from .priority import (
 __all__ = [
     "DegradationController", "Disposition", "TokenBucket", "CostModel",
     "PriorityResolver", "PRIORITY_CLASSES", "PRIORITY_HEADER",
-    "default_degradation_controller", "make_path_cost_prior", "rank_of",
+    "default_degradation_controller", "rank_of",
     "level_name", "LEVEL_NAMES",
     "L0_NORMAL", "L1_SHED_OPTIONAL", "L2_BROWNOUT", "L3_ADMISSION",
     "L4_FAIL_STATIC",

@@ -3,16 +3,12 @@
 PR 3's device-step sampler (observability/runtimestats.py) keeps a warm
 execute EWMA per compiled program ``(group, bucket, variant)`` — the
 engine's own measurement of what one device step costs *right now*.
-This module turns those EWMAs into the two cost questions the
-resilience subsystem and the dual-path chooser ask:
+This module turns those EWMAs into the cost question the resilience
+subsystem asks:
 
 - **per-request device cost** (``request_cost_s``): device-seconds one
   request's learned-signal fan-out will consume — the unit the L3
-  admission token buckets spend and refill in;
-- **per-path prior** (``path_priors``): expected step cost of the
-  ``stacked`` bank pass vs the ``traditional`` (fused/split) path — the
-  DualPathChooser's cold-start tiebreaker, closing the PR 3 ROADMAP
-  item ("feed llm_runtime_step_seconds EWMAs back into pathing.py").
+  admission token buckets spend and refill in.
 
 Reads are snapshot-cached (``ttl_s``) so the admission hot path never
 pays a program-registry walk per request; with no telemetry yet (cold
@@ -25,12 +21,6 @@ from __future__ import annotations
 import threading
 import time
 from typing import Any, Dict, List, Optional
-
-# variant → path mapping (engine/classify.py _record_step callers):
-# "stacked" is the multi-task LoRA bank pass; "fused" (trunk groups) and
-# "split" (per-task) together are the traditional path
-_STACKED_VARIANTS = ("stacked",)
-_TRADITIONAL_VARIANTS = ("fused", "split")
 
 DEFAULT_REQUEST_COST_S = 0.005  # pre-telemetry guess: 5ms of device time
 
@@ -133,55 +123,12 @@ class CostModel:
             return cost
         return cost / self.value_weight(key)
 
-    def variant_ewma_s(self, variants) -> Optional[float]:
-        """Execute-weighted mean of warm EWMAs across the given variants;
-        None when none of them has executed warm yet."""
-        weighted = weight = 0.0
-        for p in self._snapshot():
-            if p.get("variant") in variants and p.get("executes", 0):
-                w = float(p["executes"])
-                weighted += float(p["execute_ewma_s"]) * w
-                weight += w
-        if weight <= 0:
-            return None
-        return weighted / weight
-
-    def path_priors(self) -> Dict[str, float]:
-        """{'stacked': s, 'traditional': s} — only the paths with live
-        telemetry appear, so a chooser can require both before trusting
-        the prior."""
-        out: Dict[str, float] = {}
-        stacked = self.variant_ewma_s(_STACKED_VARIANTS)
-        trad = self.variant_ewma_s(_TRADITIONAL_VARIANTS)
-        if stacked is not None:
-            out["stacked"] = stacked
-        if trad is not None:
-            out["traditional"] = trad
-        return out
-
     def report(self) -> Dict[str, Any]:
         per_row = self.cost_per_row_s()
         return {
             "cost_per_row_s": round(per_row, 9) if per_row else None,
             "request_cost_s": round(self.request_cost_s(), 9),
             "default_request_cost_s": self.default_request_cost_s,
-            "path_priors": {k: round(v, 9)
-                            for k, v in self.path_priors().items()},
             "value_weights": dict(self.value_weights),
             "programs_seen": len(self._snapshot()),
         }
-
-
-def make_path_cost_prior(cost_model: CostModel):
-    """A ``cost_prior`` callable for engine.pathing.DualPathChooser:
-    returns the live {'stacked','traditional'} step-cost estimates (may
-    be partial/empty — the chooser only trusts it when both sides have
-    telemetry).  Never raises into the chooser."""
-
-    def prior() -> Dict[str, float]:
-        try:
-            return cost_model.path_priors()
-        except Exception:
-            return {}
-
-    return prior

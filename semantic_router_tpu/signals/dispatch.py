@@ -87,7 +87,7 @@ class _Rider(NamedTuple):
 class _FusedItem:
     """One request's ``classify_multi`` call on one engine: the families
     of ``active`` whose task reads ``ctx.user_text`` on a common trunk
-    group (or the stacked bank)."""
+    group."""
 
     engine: Any
     riders: List[_Rider] = field(default_factory=list)
@@ -273,10 +273,9 @@ class SignalDispatcher:
         ``ctx.user_text`` (its ``prefetch_task``; a token family also
         names its rule's ``prefetch_threshold``) and whose tasks one
         fused execution can serve — a shared TrunkGroup, sequence and
-        token members alike, or the stacked bank for sequence tasks —
-        is gathered into one classify_multi call per engine, so a request
-        activating K learned signals pays exactly one tokenization and
-        one trunk forward.  What the item cannot carry keeps its own
+        token members alike — is gathered into one classify_multi call
+        per engine, so a request activating K learned signals pays
+        exactly one tokenization and one trunk forward.  What the item cannot carry keeps its own
         call: another text (include_history), a second token threshold,
         a task on no trunk group, a generative task.  Fewer than two
         tasks, or an unfusable mix, gather nothing (a serial call would

@@ -142,6 +142,34 @@ class TestEngine:
             # spans must be exact substrings (offset mapping contract)
             assert e.text == "contact john at j@x.com now"[e.start:e.end]
 
+    def test_multi_single_task_matches_batch(self, engine):
+        texts = ["alpha beta", "gamma delta"]
+        out = engine.classify_multi(["intent"], texts)
+        assert set(out) == {"intent"}
+        for got, want in zip(out["intent"],
+                             engine.classify_batch("intent", texts)):
+            assert got.label == want.label
+            assert got.confidence == pytest.approx(want.confidence,
+                                                   abs=1e-5)
+
+    def test_multi_different_trunks_served_per_task(self, engine):
+        """Tasks of different trunks share no fused item: classify_multi
+        serves each through its own call, and answers what those calls
+        answer."""
+        tasks = ["intent", "jailbreak", "pii"]
+        assert not engine.fused_covers(tasks)
+        text = "contact john at j@x.com now"
+        out = engine.classify_multi(tasks, [text], threshold=0.0)
+        assert set(out) == set(tasks)
+        for task in ("intent", "jailbreak"):
+            want = engine.classify_batch(task, [text])[0]
+            assert out[task][0].label == want.label
+            assert out[task][0].probs == pytest.approx(want.probs,
+                                                       abs=1e-5)
+        want = engine.token_classify("pii", text, threshold=0.0)
+        assert [(e.type, e.start, e.end) for e in out["pii"][0].entities] \
+            == [(e.type, e.start, e.end) for e in want.entities]
+
     def test_unknown_task_raises(self, engine):
         with pytest.raises(KeyError, match="not registered"):
             engine.classify("nope", "x")

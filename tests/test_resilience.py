@@ -14,7 +14,6 @@ from semantic_router_tpu.resilience import (
     DegradationController,
     PriorityResolver,
     TokenBucket,
-    make_path_cost_prior,
     rank_of,
 )
 from semantic_router_tpu.runtime.events import (
@@ -71,10 +70,10 @@ class TestCostModel:
     def _stats_with_steps(self):
         rs = RuntimeStats(MetricsRegistry())
         # warm the program registry: compile step + warm executes
-        rs.record_step("stacked", 128, "stacked", 4, 4, 0.5,
+        rs.record_step("task:t0", 128, "split", 4, 4, 0.5,
                        compiled=True)
         for _ in range(10):
-            rs.record_step("stacked", 128, "stacked", 4, 4, 0.004)
+            rs.record_step("task:t0", 128, "split", 4, 4, 0.004)
             rs.record_step("trunk:g0", 128, "fused", 4, 4, 0.010)
         rs.flush()
         return rs
@@ -90,47 +89,6 @@ class TestCostModel:
     def test_default_before_telemetry(self):
         cm = CostModel(None, default_request_cost_s=0.007)
         assert cm.request_cost_s() == 0.007
-        assert cm.path_priors() == {}
-
-    def test_path_priors_and_chooser_integration(self):
-        from semantic_router_tpu.engine.pathing import (
-            DualPathChooser,
-            ProcessingRequirements,
-        )
-
-        cm = CostModel(self._stats_with_steps(), ttl_s=0.0)
-        priors = cm.path_priors()
-        assert priors["stacked"] == pytest.approx(0.004, rel=0.3)
-        assert priors["traditional"] == pytest.approx(0.010, rel=0.3)
-        # cold-start chooser consults the live prior: stacked is
-        # measured cheaper, so it wins even before min_history
-        ch = DualPathChooser(cost_prior=make_path_cost_prior(cm))
-        sel = ch.choose(ProcessingRequirements(
-            tasks=["a", "b"], batch_size=1))
-        assert sel.selected_path == "stacked"
-        assert "prior" in sel.reasoning
-
-    def test_chooser_single_task_never_stacks_on_prior(self):
-        from semantic_router_tpu.engine.pathing import (
-            DualPathChooser,
-            ProcessingRequirements,
-        )
-
-        cm = CostModel(self._stats_with_steps(), ttl_s=0.0)
-        ch = DualPathChooser(cost_prior=make_path_cost_prior(cm))
-        sel = ch.choose(ProcessingRequirements(tasks=["a"], batch_size=1))
-        assert sel.selected_path == "traditional"
-
-    def test_chooser_ignores_one_sided_prior(self):
-        from semantic_router_tpu.engine.pathing import (
-            DualPathChooser,
-            ProcessingRequirements,
-        )
-
-        ch = DualPathChooser(cost_prior=lambda: {"stacked": 0.001})
-        sel = ch.choose(ProcessingRequirements(
-            tasks=["a", "b"], batch_size=1))
-        assert "cold start (" in sel.reasoning  # static rule, not prior
 
 
 class TestTokenBucket:

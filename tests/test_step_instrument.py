@@ -326,3 +326,226 @@ def test_warmup_compiles_only_programs_the_runners_reach():
     finally:
         logger.removeHandler(handler)
         eng.shutdown()
+
+
+# -- one step, written once (engine/step.py), under every runner --------------
+
+
+class _Samples:
+    """Stands where the engine's RuntimeStats stands and keeps what the
+    runners hand it."""
+
+    def __init__(self):
+        self.steps, self.generations = [], []
+
+    def record_step(self, group, bucket, variant, rows, padded_rows,
+                    seconds, **kw):
+        self.steps.append(dict(group=group, bucket=bucket, variant=variant,
+                               rows=rows, padded_rows=padded_rows,
+                               seconds=seconds, **kw))
+
+    def record_generation(self, task, flavour, **kw):
+        self.generations.append((task, flavour))
+
+
+def _toy_generator():
+    from semantic_router_tpu.models.generate import GreedyGenerator
+    from semantic_router_tpu.models.qwen3 import Qwen3Config, Qwen3ForCausalLM
+    from semantic_router_tpu.utils.tokenization import Encoding
+
+    class Rows:
+        def encode(self, text, max_length=0):
+            ids = [3 + ord(c) % 200 for c in text[:12]]
+            return Encoding(ids=ids, attention_mask=[1] * len(ids),
+                            offsets=[(0, 0)] * len(ids))
+
+        def decode(self, ids):
+            return " ".join(str(int(i)) for i in ids)
+
+    cfg = Qwen3Config(vocab_size=256, hidden_size=64, intermediate_size=128,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      num_key_value_heads=2, head_dim=16,
+                      tie_word_embeddings=True)
+    params = Qwen3ForCausalLM(cfg).init(
+        jax.random.PRNGKey(0), jax.numpy.zeros((1, 8), jax.numpy.int32))
+    return GreedyGenerator(cfg, params, Rows())
+
+
+def _generative_engine():
+    from semantic_router_tpu.config.schema import InferenceEngineConfig
+    from semantic_router_tpu.engine.classify import InferenceEngine
+
+    eng = InferenceEngine(InferenceEngineConfig(
+        max_batch_size=4, max_wait_ms=1.0, seq_len_buckets=[32]))
+    eng.register_generative("guard", _toy_generator())
+    return eng
+
+
+TWO = ["two short prompts", "share one packed row"]
+
+# name -> (engine, the call, group, [(variant, rows, padded_rows) a program])
+RUNNERS = {
+    "task_seq": lambda: (
+        make_shared_trunk_engine(fuse=False),
+        lambda e: e.classify("intent", TEXT),
+        "task:intent", [("split", 1, 1)]),
+    "task_tok": lambda: (
+        make_shared_trunk_engine(token_tasks=[PII], fuse=False),
+        lambda e: e.token_classify("pii", TEXT),
+        "task:pii", [("split", 1, 1)]),
+    "embed": lambda: (
+        make_embedding_engine(),
+        lambda e: e.embed("embedding", [TEXT, "a second row", "a third"]),
+        "task:embedding", [("split", 3, 4)]),
+    "fused_seq": lambda: (
+        make_shared_trunk_engine(token_tasks=[PII]),
+        lambda e: e.classify_multi(SEQ_TASKS, [TEXT]),
+        "trunk:trunk0", [("fused", 1, 1)]),
+    "fused_tok": lambda: (
+        make_shared_trunk_engine(token_tasks=[PII]),
+        lambda e: e.token_classify("pii", TEXT),
+        "trunk:trunk0", [("fused", 1, 1)]),
+    "fused_both": lambda: (
+        make_shared_trunk_engine(token_tasks=[PII]),
+        lambda e: e.classify_multi(SEQ_TASKS + ["pii"], [TEXT]),
+        "trunk:trunk0", [("fused", 1, 1)]),
+    # the tiny trunk's attention is dense, so two short rows pack into one
+    "packed_seq": lambda: (
+        make_shared_trunk_engine(token_tasks=[PII]),
+        lambda e: e.classify_multi(SEQ_TASKS, TWO),
+        "trunk:trunk0", [("packed", 1, 1)]),
+    "packed_both": lambda: (
+        make_shared_trunk_engine(token_tasks=[PII]),
+        lambda e: e.classify_multi(SEQ_TASKS + ["pii"], TWO),
+        "trunk:trunk0", [("packed", 1, 1)]),
+    "generator": lambda: (
+        _generative_engine(),
+        lambda e: e.generate("guard", ["a prompt", "another"],
+                             max_new_tokens=3),
+        "gen:guard", [("gen.prefill", 2, 2), ("gen.decode", 2, 2),
+                      ("gen.decode", 2, 2)]),
+}
+
+
+@pytest.fixture
+def opened(monkeypatch):
+    """Every step a runner opens: (its ``engine.step`` facts, its
+    BatchStep)."""
+    seen, real = [], batchtrace.start_step
+
+    def spy(items, **facts):
+        step = real(items, **facts)
+        seen.append((facts, step))
+        return step
+
+    monkeypatch.setattr(batchtrace, "start_step", spy)
+    return seen
+
+
+@pytest.mark.parametrize("runner", sorted(RUNNERS))
+def test_every_runner_leaves_one_sample_a_program(runner, opened):
+    eng, call, group, programs = RUNNERS[runner]()
+    samples = eng._runtime_stats = _Samples()
+    try:
+        call(eng)
+    finally:
+        eng.shutdown()
+    assert [(s["variant"], s["rows"], s["padded_rows"])
+            for s in samples.steps] == programs
+    assert len(opened) == len(programs)
+    variants = set()
+    for (facts, step), sample in zip(opened, samples.steps):
+        assert sample["group"] == facts["group"] == group
+        assert (sample["rows"], sample["padded_rows"], sample["bucket"]) \
+            == (facts["rows"], facts["padded_rows"], facts["bucket"])
+        # a program's first run is the one accounted as its compile
+        assert sample["compiled"] == (sample["variant"] not in variants)
+        variants.add(sample["variant"])
+        assert sample["seconds"] > 0 and step._finished
+    if runner == "generator":
+        assert [s["variant"] for s in samples.steps] == \
+            [facts["flavour"] for facts, _ in opened] == \
+            [flavour for _, flavour in samples.generations]
+    elif runner.startswith(("fused", "packed")):
+        assert opened[0][0]["flavour"] == runner.split("_")[1]
+        assert samples.steps[0]["tokens_padded"] == 32
+        assert samples.steps[0]["segments"] == (
+            2 if runner.startswith("packed") else 1)
+
+
+def _break_program(eng, runner):
+    """Make the runner's device program raise; returns the undo."""
+    def boom(*a, **kw):
+        raise RuntimeError("program down")
+
+    if runner == "generator":
+        gen = eng._tasks["guard"].generator
+        real = gen._prefill_fn
+        gen._prefill_fn = lambda shape: boom
+        return lambda: setattr(gen, "_prefill_fn", real)
+    if runner.startswith("task") or runner == "embed":
+        t = eng._tasks[{"task_seq": "intent", "task_tok": "pii",
+                        "embed": "embedding"}[runner]]
+        real = t.apply_fn
+        t.apply_fn = boom
+        return lambda: setattr(t, "apply_fn", real)
+    g = next(iter(eng._groups_by_gid.values()))
+    real = g.fns
+    g.fns = {k: boom if callable(v) else v for k, v in real.items()}
+    return lambda: setattr(g, "fns", real)
+
+
+@pytest.mark.parametrize("runner", sorted(RUNNERS))
+def test_a_raising_program_finishes_its_step_and_fails_its_batch_only(
+        runner, opened):
+    eng, call, group, programs = RUNNERS[runner]()
+    samples = eng._runtime_stats = _Samples()
+    tracer = Tracer(sample_rate=1.0)
+    try:
+        undo = _break_program(eng, runner)
+        with tracer.span("router.route"):
+            with pytest.raises(RuntimeError, match="program down"):
+                call(eng)
+        # the step ended on both clocks and left no sample of a program
+        # that did not run
+        assert len(opened) == 1 and opened[0][1]._finished
+        assert samples.steps == []
+        (step,) = tracer.spans(batchtrace.STEP_SPAN)
+        assert step.attributes["group"] == group
+        assert tracer.spans(batchtrace.RIDE_SPAN)
+        # ... and the next batch is served
+        undo()
+        call(eng)
+        assert [s["variant"] for s in samples.steps] == \
+            [p[0] for p in programs]
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("via", ["classify", "classify_multi"])
+def test_per_task_and_fused_decode_alike_with_fewer_labels_than_width(via):
+    """One width-tolerant decode: a label list shorter than the head names
+    the classes beyond it by position, on either path."""
+    split = make_shared_trunk_engine(fuse=False)
+    fused = make_shared_trunk_engine()
+    try:
+        answers = []
+        for eng in (split, fused):
+            eng._tasks["intent"].labels = ["business", "law", "health"]
+            if via == "classify":
+                answers.append(eng.classify("intent", "word " * 600))
+            else:
+                answers.append(eng.classify_multi(
+                    ["intent"], ["word " * 600])["intent"][0])
+        a, b = answers
+        assert list(a.probs) == list(b.probs) == \
+            ["business", "law", "health", "3", "4"]
+        assert (a.label, a.index, a.truncated) == \
+            (b.label, b.index, b.truncated)
+        assert a.truncated  # 600 words against a max_seq_len of 512
+        assert a.confidence == pytest.approx(b.confidence, abs=1e-5)
+        assert a.probs == pytest.approx(b.probs, abs=1e-5)
+        assert a.latency_s > 0 and b.latency_s > 0
+    finally:
+        split.shutdown()
+        fused.shutdown()
