@@ -247,11 +247,13 @@ class _GenerationObserver:
     forward (models.generate.NullObserver has the protocol): each forward
     is one EngineStep — one ``engine.step`` annotation with its stages
     (flavour ``gen.prefill`` | ``gen.denoise`` | ``gen.commit`` |
-    ``gen.decode``; facts ``rows``, ``padded_rows``, ``tokens_real``,
-    ``block``, ``masks_left``) and one ``record_step`` sample under group
-    ``gen:<task>`` with the flavour as its variant, whose clock runs from
-    ``forward`` to ``done`` — and one ``record_generation`` count (the
-    counters of /metrics).  The prefill step carries the batch items, so
+    ``gen.decode``; ``gen.commit`` is the forward that commits block
+    ``b`` and begins block ``b + 1``, two blocks of tokens a row; facts
+    ``rows``, ``padded_rows``, ``tokens_real``, ``block`` (the block begun
+    or gone on with), ``masks_left``) and one ``record_step`` sample under
+    group ``gen:<task>`` with the flavour as its variant, whose clock runs
+    from ``forward`` to ``done`` — and one ``record_generation`` count
+    (the counters of /metrics).  The prefill step carries the batch items, so
     a traced request's queue wait ends where its generation begins.  A
     generation has one forward open at a time: the observer is its
     handle."""
@@ -278,7 +280,9 @@ class _GenerationObserver:
     def done(self, load=None, committed_blocks: int = 0,
              committed_tokens: int = 0) -> None:
         """``load [layers, 4]`` of an expert model (models.sdar_moe.moe);
-        a dense generator gives none."""
+        a dense generator gives none.  ``committed_blocks`` /
+        ``committed_tokens``: what this forward FINISHED (a block's last
+        forward says so, whichever forward writes its K and V later)."""
         from ..observability import batchtrace
 
         step = self.step

@@ -493,18 +493,27 @@ class BlockDiffusionGenerator:
     committed to the cache; then, a block of ``block_length`` positions at
     a time, the block (the prompt's trailing partial block, then ``[MASK]``)
     is denoised by up to ``denoising_steps`` forwards against the committed
-    cache, none of which writes it, each filling the masked positions it is
-    confident of; when no mask is left in any row, one more forward of the
-    final tokens writes the block's K and V (commit).
+    cache, each filling the masked positions it is confident of, until no
+    mask is left in any row.  A finished block's K and V are written by the
+    forward that begins the NEXT block, which carries the finished block's
+    final tokens beside the new block's state (``2L`` positions a row): up
+    to ``denoising_steps`` forwards a block, and none whose only product is
+    the cache.  After the last block nothing is committed, because nothing
+    reads the cache again; a generator that keeps the cache for the next
+    turn (prefix reuse, ROADMAP M5) has to commit that block then.
 
-    Rows run in lock step; programs are keyed by ``(rows, prompt bucket,
-    cache length)``: ``prefill``, ``denoise`` (reads the cache; returns the
-    block's new state on the device and one small report for the host),
-    ``commit``.  One cache layout, ``[rows, kv_heads, M, head_dim]`` a
-    layer in the model's dtype, ``M`` = bucket + generated + block - 1
-    rounded up to 64.  The loop is the host's: it reads one report a
-    forward (which is what tells it when a block is done) and so can open
-    one ``engine.step`` a forward."""
+    Rows run in lock step; three programs keyed by ``(rows, prompt bucket,
+    cache length)``: ``prefill``; ``denoise`` (``L`` positions, reads the
+    cache and writes nothing: the first block's first forward and every
+    block's later forwards; returns the block's new state on the device
+    and one small report for the host); ``commit`` (``2L`` positions: the
+    same for the new block, and the block before goes into the donated
+    cache — the first forward of a block that has a finished block before
+    it).  One cache layout, ``[rows, kv_heads, M, head_dim]`` a layer in
+    the model's dtype, ``M`` = bucket + generated + block - 1 rounded up
+    to 64.  The loop is the host's: it reads one report a forward (which
+    is what tells it when a block is done) and so can open one
+    ``engine.step`` a forward."""
 
     def __init__(self, config, params, tokenizer, *, mask_token_id: int,
                  block_length: int = 4, denoising_steps: int = 4,
@@ -538,22 +547,18 @@ class BlockDiffusionGenerator:
             def prefill(params, ids, committed):
                 return M.prefill(cfg, params, ids, committed, cache_len, L)
 
-            def denoise(params, caches, tokens, masked, start, rows_valid,
-                        at_least):
-                logits, _, experts, load = M.block_forward(
-                    cfg, params, caches, tokens, start, rows_valid,
-                    write=False, head=True)
+            def commit(params, caches, previous, tokens, masked, start,
+                       rows_valid, at_least):
+                logits, caches, experts, load = M.block_forward(
+                    cfg, params, caches, tokens, start, rows_valid, previous)
                 with jax.named_scope("transfer"):
                     tokens, masked, report = transfer_by_confidence(
                         logits, tokens, masked, self.confidence_threshold,
                         at_least, self.top_logits)
-                return tokens, masked, report, experts, load
+                return caches, tokens, masked, report, experts, load
 
-            def commit(params, caches, tokens, start, rows_valid):
-                _, caches, experts, load = M.block_forward(
-                    cfg, params, caches, tokens, start, rows_valid,
-                    write=True, head=False)
-                return caches, experts, load
+            def denoise(params, caches, *block):
+                return commit(params, caches, None, *block)[1:]
 
             self._programs[key] = (jax.jit(prefill), jax.jit(denoise),
                                    jax.jit(commit, donate_argnums=(1,)))
@@ -569,10 +574,11 @@ class BlockDiffusionGenerator:
 
     def warm(self, rows: int, bucket: int) -> None:
         """Compile and run the three programs of ``(rows, bucket)`` at the
-        cache length of ``gen_length`` tokens: one block of a one-token
-        prompt."""
+        cache length of ``gen_length`` tokens: two blocks of a one-token
+        prompt (the second block's first forward is the one that
+        commits)."""
         self.generate([], encodings=[_one_token(self.pad_id)], bucket=bucket,
-                      padded_rows=rows, _blocks=1)
+                      padded_rows=rows, _blocks=2)
 
     def generate(self, prompts: Sequence[str],
                  max_new_tokens: Optional[int] = None, task_index: int = 0,
@@ -582,15 +588,23 @@ class BlockDiffusionGenerator:
                  _blocks: Optional[int] = None) -> List[GenerationResult]:
         """``prompts`` as one batch in lock step (the engine's batch runner
         passes ``encodings``, ``bucket``, ``padded_rows`` and ``observer``
-        as to ``GreedyGenerator.generate``).  A result's ``trajectory`` has
-        one entry per forward while the request still generated: ``kind``
-        (``denoise`` | ``commit``), ``block``, ``tokens`` (the block's
-        state that went in), ``masked`` (which of them were ``[MASK]``),
-        ``experts [layers, L, k]`` (the router's choice), and for a denoise
-        ``filled``, ``tokens_after``, ``confidence``, ``lse`` and
-        ``top_ids`` / ``top_logits [L, top]`` (float32) — at a masked
-        position the row of logits that scored it.  ``task_index`` is
-        accepted for the engine's one runner (no adapters here)."""
+        as to ``GreedyGenerator.generate``).  The observer sees flavour
+        ``gen.denoise`` for a forward of ``L`` positions a row and
+        ``gen.commit`` for one of ``2L`` (it commits block ``block - 1``
+        and begins ``block``); a block's last forward reports it finished
+        (``committed_blocks``, ``committed_tokens``).
+
+        A result's ``trajectory`` has one entry per block that a forward
+        carried while the request still generated: ``kind`` (``denoise`` |
+        ``commit``), ``block``, ``tokens`` (the block's state that went
+        in), ``masked`` (which of them were ``[MASK]``), ``experts [layers,
+        L, k]`` (the router's choice), and for a denoise ``filled``,
+        ``tokens_after``, ``confidence``, ``lse`` and ``top_ids`` /
+        ``top_logits [L, top]`` (float32) — at a masked position the row of
+        logits that scored it.  A forward of two blocks leaves two entries,
+        the commit of the one before the denoise of the other; a request's
+        last block has no commit.  ``task_index`` is accepted for the
+        engine's one runner (no adapters here)."""
         encs, bucket, padded_rows = _as_batch(
             self.tokenizer, prompts, encodings, bucket, padded_rows)
         obs = observer or NullObserver()
@@ -623,6 +637,9 @@ class BlockDiffusionGenerator:
 
         generated: List[List[int]] = [[] for _ in range(n)]
         trajectory: List[List[Dict[str, Any]]] = [[] for _ in range(n)]
+        # the finished block the cache does not hold yet: its final tokens
+        # on the device and on the host
+        finished_dev = finished = None
         for b in range(n_blocks):
             tokens = np.full((B, L), self.mask_token_id, np.int32)
             masked = np.broadcast_to(rows_valid[:, None], (B, L)).copy()
@@ -633,15 +650,24 @@ class BlockDiffusionGenerator:
             live = [i for i in range(n) if b < blocks_of[i]]
             start_dev = jnp.asarray(base + b * L)
             tokens_dev, masked_dev = jnp.asarray(tokens), jnp.asarray(masked)
+            # a real row's block begins with a mask (a prompt's tail is
+            # shorter than a block), so every block has a first forward
             for step in range(self.denoising_steps):
-                if not masked.any():
-                    break
-                fwd = obs.forward("gen.denoise", tokens_real=n * L, block=b,
-                                  masks_left=int(masked.sum()))
+                block = (tokens_dev, masked_dev, start_dev, valid_dev,
+                         schedule[step])
+                commits = finished_dev is not None
+                fwd = obs.forward(
+                    "gen.commit" if commits else "gen.denoise",
+                    tokens_real=n * L * (1 + commits), block=b,
+                    masks_left=int(masked.sum()))
                 with fwd.stage("dispatch"):
-                    tokens_dev, masked_dev, report, experts, load = denoise(
-                        self.params, caches, tokens_dev, masked_dev,
-                        start_dev, valid_dev, schedule[step])
+                    if commits:
+                        caches, *out = commit(self.params, caches,
+                                              finished_dev, *block)
+                        finished_dev = None
+                    else:
+                        out = denoise(self.params, caches, *block)
+                    tokens_dev, masked_dev, report, experts, load = out
                 with fwd.stage("readback"):
                     report, experts, load = (np.asarray(a) for a in
                                              jax.device_get(
@@ -651,6 +677,12 @@ class BlockDiffusionGenerator:
                     after = report[..., 0].astype(np.int32)
                     filled = report[..., 1] > 0.5
                     for i in live:
+                        if commits:
+                            trajectory[i].append({
+                                "kind": "commit", "block": b - 1,
+                                "tokens": finished[i],
+                                "masked": np.zeros(L, bool),
+                                "experts": experts[:, i, :L]})
                         if masked[i].any():
                             trajectory[i].append({
                                 "kind": "denoise", "block": b,
@@ -663,28 +695,17 @@ class BlockDiffusionGenerator:
                                 "top_ids": report[i, :, 4:4 + k]
                                 .astype(np.int32),
                                 "top_logits": report[i, :, 4 + k:],
-                                "experts": experts[:, i]})
+                                "experts": experts[:, i, -L:]})
                     tokens, masked = after, masked & ~filled
-                fwd.done(load=load)
-            fwd = obs.forward("gen.commit", tokens_real=n * L, block=b,
-                              masks_left=0)
-            with fwd.stage("dispatch"):
-                caches, experts, load = commit(
-                    self.params, caches, tokens_dev, start_dev, valid_dev)
-            with fwd.stage("readback"):
-                experts, load = (np.asarray(a) for a in
-                                 jax.device_get((experts, load)))
-            with fwd.stage("demux"):
-                for i in live:
-                    trajectory[i].append({
-                        "kind": "commit", "block": b,
-                        "tokens": tokens[i].copy(),
-                        "masked": np.zeros(L, bool),
-                        "experts": experts[:, i]})
-                    generated[i].extend(
-                        int(t) for t in tokens[i, tail[i] if b == 0 else 0:])
-            fwd.done(load=load, committed_blocks=len(live),
-                     committed_tokens=len(live) * L)
+                ended = step + 1 == self.denoising_steps or not masked.any()
+                fwd.done(load=load, committed_blocks=len(live) * ended,
+                         committed_tokens=len(live) * L * ended)
+                if ended:
+                    break
+            for i in live:
+                generated[i].extend(
+                    int(t) for t in tokens[i, tail[i] if b == 0 else 0:])
+            finished_dev, finished = tokens_dev, tokens
         del caches
         return [_finish_tokens(self.tokenizer, generated[i][:new_tokens],
                                self.eos_token_ids, stop_strings,

@@ -315,23 +315,42 @@ def prefill(cfg: SdarMoeConfig, params, ids, committed, cache_len: int,
 
 
 def block_forward(cfg: SdarMoeConfig, params, caches, tokens, start,
-                  rows_valid, *, write: bool, head: bool):
+                  rows_valid, previous=None):
     """``tokens [B, L]`` at absolute positions ``start[b] .. start[b] + L``,
-    seeing the cache's columns ``[0, start[b])`` and one another
-    (bidirectional inside the block).  ``write=False`` (denoise) leaves the
-    cache alone; ``write=True`` (commit) returns it with the block's K and
-    V at its columns.  ``head=False`` skips the final norm and the head (a
-    commit's logits are read by nobody).  Returns ``(logits [B, L, V]
-    float32 | None, caches | None, top_e [layers, B, L, k], load
-    [layers, 3])``."""
+    seeing the committed cache and one another (bidirectional inside the
+    block), scored by the head.  Without ``previous`` the cache holds the
+    columns ``[0, start[b])`` and is left alone (a denoise forward).
+
+    ``previous [B, L]`` is the final tokens of the block BEFORE, at
+    ``start[b] - L .. start[b]``, whose K and V the cache does not hold
+    yet: the forward then carries both blocks, ``2L`` positions a row,
+    under the block-causal rule.  The previous block's rows see the cache's
+    columns ``[0, start[b] - L)`` and one another, not the new block; the
+    new block's rows see the cache, the previous block's K and V of this
+    same forward (in the model's dtype: what the cache will hold) and one
+    another.  The cache comes back with the previous block's K and V at its
+    columns (commit).  The final norm and the head run on the new block's
+    ``L`` positions only.
+
+    Returns ``(logits [B, L, V] float32, caches | None, top_e [layers, B,
+    S, k], load [layers, 4])`` with ``S = L``, or ``2L`` with the previous
+    block's tokens first."""
     B, L = tokens.shape
+    first = start
+    if previous is not None:
+        tokens, first = jnp.concatenate([previous, tokens], 1), start - L
+    S = tokens.shape[1]
     nh, nkv, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     rep = nh // nkv
     M = caches[0][0].shape[2]
-    positions = start[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
-    valid = jnp.broadcast_to(rows_valid[:, None], (B, L))
-    seen = (jnp.arange(M)[None, :] < start[:, None])  # [B, M]
+    positions = first[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    valid = jnp.broadcast_to(rows_valid[:, None], (B, S))
+    seen = (jnp.arange(M)[None, :] < first[:, None])  # [B, M]
     cache_bias = jnp.where(seen, 0.0, NEG_INF)[:, None, None, None, :]
+    # inside the forward a key is seen from its own block on
+    blk = np.arange(S) // L
+    block_bias = np.where(blk[None, :] <= blk[:, None], 0.0, NEG_INF) \
+        .astype(np.float32)
     scale = 1.0 / np.sqrt(float(D))
     with jax.named_scope("embed_tokens"):
         x = jnp.take(params["embed"], tokens, axis=0)
@@ -342,14 +361,14 @@ def block_forward(cfg: SdarMoeConfig, params, caches, tokens, start,
                 h = rms_norm(x, p["norm1"], cfg.rms_norm_eps, cfg.dtype)
                 q, k, v = qkv(cfg, p, h, positions, M)
                 k_cache, v_cache = caches[i]
-                qg = jnp.moveaxis(q, 2, 1).reshape(B, nkv, rep, L, D)
+                qg = jnp.moveaxis(q, 2, 1).reshape(B, nkv, rep, S, D)
                 kb, vb = (jnp.moveaxis(t, 2, 1) for t in (k, v))
                 s_cache = jnp.einsum(
                     "bgrqd,bgmd->bgrqm", qg, k_cache,
                     preferred_element_type=jnp.float32) * scale + cache_bias
                 s_block = jnp.einsum(
                     "bgrqd,bgkd->bgrqk", qg, kb,
-                    preferred_element_type=jnp.float32) * scale
+                    preferred_element_type=jnp.float32) * scale + block_bias
                 probs = jax.nn.softmax(
                     jnp.concatenate([s_cache, s_block], -1), axis=-1)
                 out = jnp.einsum("bgrqm,bgmd->bgrqd",
@@ -358,22 +377,21 @@ def block_forward(cfg: SdarMoeConfig, params, caches, tokens, start,
                     + jnp.einsum("bgrqk,bgkd->bgrqd",
                                  probs[..., M:].astype(cfg.dtype), vb,
                                  preferred_element_type=jnp.float32)
-                out = jnp.moveaxis(out.reshape(B, nh, L, D), 1, 2)
-                x = x + out.reshape(B, L, nh * D).astype(cfg.dtype) \
+                out = jnp.moveaxis(out.reshape(B, nh, S, D), 1, 2)
+                x = x + out.reshape(B, S, nh * D).astype(cfg.dtype) \
                     @ p["o_proj"]
-                if write:
+                if previous is not None:
                     put = jax.vmap(lambda c, new, at: jax.lax.
                                    dynamic_update_slice(c, new, (0, at, 0)))
-                    new_caches.append((put(k_cache, kb, start),
-                                       put(v_cache, vb, start)))
+                    new_caches.append((put(k_cache, kb[:, :, :L], first),
+                                       put(v_cache, vb[:, :, :L], first)))
             x, top_e, load = _moe_block(cfg, p, x, valid)
             experts.append(top_e)
             loads.append(load)
-    logits = None
-    if head:
-        with jax.named_scope("lm_head"):
-            h = rms_norm(x, params["norm"], cfg.rms_norm_eps, cfg.dtype)
-            logits = jnp.dot(h, params["lm_head"],
-                             preferred_element_type=jnp.float32)
-    return (logits, new_caches if write else None, jnp.stack(experts),
-            jnp.stack(loads))
+    with jax.named_scope("lm_head"):
+        h = rms_norm(x[:, S - L:], params["norm"], cfg.rms_norm_eps,
+                     cfg.dtype)
+        logits = jnp.dot(h, params["lm_head"],
+                         preferred_element_type=jnp.float32)
+    return (logits, new_caches if previous is not None else None,
+            jnp.stack(experts), jnp.stack(loads))

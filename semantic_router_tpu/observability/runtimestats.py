@@ -236,13 +236,16 @@ class RuntimeStats:
                           committed_blocks: int = 0,
                           committed_tokens: int = 0) -> None:
         """One forward of a generation (the engine's generative runner):
-        llm_runtime_gen_forwards_total by flavour,
-        llm_runtime_gen_blocks_committed_total and
-        llm_runtime_gen_tokens_committed_total.  Everything else about a
-        forward is its ``record_step`` sample (group ``gen:<task>``, the
-        flavour as variant) and, under a profiler session, its
-        ``engine.step`` and ``engine.gen.forward`` annotations (expert
-        load among them)."""
+        llm_runtime_gen_forwards_total by flavour, and what the forward
+        FINISHED in llm_runtime_gen_blocks_committed_total and
+        llm_runtime_gen_tokens_committed_total (a generation's last block
+        among them; a block generator's ``gen.commit`` forward commits
+        one block and begins the next, so a generation of B blocks has
+        B - 1 of those: count blocks here, not there).  Everything else
+        about a forward is its ``record_step`` sample (group
+        ``gen:<task>``, the flavour as variant) and, under a profiler
+        session, its ``engine.step`` and ``engine.gen.forward``
+        annotations (expert load among them)."""
         if not self.enabled:
             return
         self.gen_forwards.inc(task=task, flavour=flavour)

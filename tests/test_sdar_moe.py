@@ -183,35 +183,90 @@ def test_params_hold_only_the_experts_held(toy):
 
 
 def test_prefill_then_blocks_equal_the_full_forward(toy):
-    """Twelve tokens prefilled and committed, a block scored, committed,
-    and the next block scored: the logits are the reference's over the
-    whole sequence under the block-causal mask."""
+    """Twelve tokens prefilled and committed, a block scored, and the next
+    block scored by the forward that commits the first: the logits are the
+    reference's over the whole sequence under the block-causal mask, and
+    the cache holds what a prefill of sixteen tokens leaves."""
     state, cfg, params = toy
     ids = np.random.default_rng(3).integers(2, 250, (2, 20)).astype(np.int32)
-    want = [ref.forward(MODEL, state, row, np.arange(20),
-                        ref.block_causal(np.arange(20), L))["logits"]
-            for row in ids]
+    full = [ref.forward(MODEL, state, row, np.arange(20),
+                        ref.block_causal(np.arange(20), L)) for row in ids]
     caches, _ = M.prefill(cfg, params, jnp.asarray(ids[:, :16]),
                           jnp.asarray([12, 12]), 64, L)
     start, rows = jnp.asarray([12, 12]), jnp.ones(2, bool)
-    logits, none, _, _ = M.block_forward(
-        cfg, params, caches, jnp.asarray(ids[:, 12:16]), start, rows,
-        write=False, head=True)
-    assert none is None
+    logits, none, top_e, _ = M.block_forward(
+        cfg, params, caches, jnp.asarray(ids[:, 12:16]), start, rows)
+    assert none is None and top_e.shape == (2, 2, L, 2)
     for b in range(2):
-        np.testing.assert_allclose(np.asarray(logits[b]), want[b][12:16],
-                                   atol=ATOL)
-    no_logits, caches, _, _ = M.block_forward(
-        cfg, params, caches, jnp.asarray(ids[:, 12:16]), start, rows,
-        write=True, head=False)
-    assert no_logits is None
-    logits, _, top_e, _ = M.block_forward(
+        np.testing.assert_allclose(np.asarray(logits[b]),
+                                   full[b]["logits"][12:16], atol=ATOL)
+    logits, caches, top_e, load = M.block_forward(
         cfg, params, caches, jnp.asarray(ids[:, 16:20]), start + L, rows,
-        write=False, head=True)
+        previous=jnp.asarray(ids[:, 12:16]))
+    assert logits.shape == (2, L, 256) and top_e.shape == (2, 2, 2 * L, 2)
+    assert np.asarray(load)[:, 1].tolist() == [2 * 2 * L * 2] * 2  # pairs
+    sixteen, _ = M.prefill(cfg, params, jnp.asarray(ids[:, :16]),
+                           jnp.asarray([16, 16]), 64, L)
     for b in range(2):
-        np.testing.assert_allclose(np.asarray(logits[b]), want[b][16:20],
-                                   atol=ATOL)
-    assert top_e.shape == (2, 2, L, 2)
+        np.testing.assert_allclose(np.asarray(logits[b]),
+                                   full[b]["logits"][16:20], atol=ATOL)
+        assert (np.sort(np.asarray(top_e[:, b]))
+                == np.sort(full[b]["top_e"][:, 12:20])).all()
+    for got, want in zip(caches, sixteen):
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g[:, :, :16]),
+                                       np.asarray(w[:, :, :16]), atol=ATOL)
+
+
+def test_the_committing_forward_equals_commit_then_denoise(toy):
+    """The generator's ``commit`` program — the block before beside the
+    new block, 2L positions a row — against a commit and a denoise apart:
+    the cache it leaves is a prefill's with that block committed, the new
+    block's state and report are ``denoise``'s against that cache, and the
+    experts of all 2L tokens are those two forwards'.  Rows at different
+    starts, and a padding row."""
+    _, cfg, params = toy
+    gen = generator(toy)
+    prefill, denoise, commit = gen.programs(3, 16, 64)
+    rng = np.random.default_rng(6)
+    ids = jnp.asarray(rng.integers(2, 250, (3, 16)), jnp.int32)
+    committed = jnp.asarray([4, 8, 0])
+    valid = jnp.asarray([True, True, False])
+    previous = jnp.stack([ids[0, 4:8], ids[1, 8:12], ids[2, :4]])
+    tokens = np.full((3, L), MASK, np.int32)
+    tokens[:2, 1] = rng.integers(2, 250, 2)
+    masked = np.asarray([[True, False, True, True]] * 2 + [[False] * L])
+    block = (jnp.asarray(tokens), jnp.asarray(masked), committed + L, valid, 2)
+
+    before, _ = prefill(params, ids, committed)
+    after, _ = prefill(params, ids, committed + L)
+    nothing = jnp.zeros((3, L), bool)
+    experts_before = denoise(params, before, previous, nothing, committed,
+                             valid, 0)[3]
+    want = denoise(params, after, *block)
+    got_cache, *got = commit(params, before, previous, *block)
+
+    for b, n in enumerate((8, 12)):  # the real rows' committed columns
+        for layer, layer_after in zip(got_cache, after):
+            for g, w in zip(layer, layer_after):
+                np.testing.assert_allclose(np.asarray(g[b, :, :n]),
+                                           np.asarray(w[b, :, :n]), atol=ATOL)
+    (tok, still, report, experts, load), (w_tok, w_still, w_report,
+                                          w_experts, w_load) = got, want
+    assert np.asarray(tok)[:2].tolist() == np.asarray(w_tok)[:2].tolist()
+    assert np.asarray(still).tolist() == np.asarray(w_still).tolist()
+    assert np.asarray(still).sum() == 2  # two of a row's three masks filled
+    np.testing.assert_allclose(np.asarray(report[:2]),
+                               np.asarray(w_report[:2]), atol=ATOL)
+    assert report.shape == (3, L, 4 + 2 * 4)  # the head saw L positions
+    assert experts.shape == (2, 3, 2 * L, 2)
+    assert (np.asarray(experts[:, :2, :L])
+            == np.asarray(experts_before[:, :2])).all()
+    assert (np.asarray(experts[:, :2, L:])
+            == np.asarray(w_experts[:, :2])).all()
+    # the padding row routes nowhere: two rows x 2L tokens x top-2 a layer
+    assert np.asarray(load)[:, 1].tolist() == [2 * 2 * L * 2] * 2
+    assert np.asarray(w_load)[:, 1].tolist() == [2 * L * 2] * 2
 
 
 def test_rows_of_different_lengths_keep_their_own_positions(toy):
@@ -225,7 +280,7 @@ def test_rows_of_different_lengths_keep_their_own_positions(toy):
     block = np.stack([ids[0, 8:12], ids[1, 12:16]])
     logits, _, _, _ = M.block_forward(
         cfg, params, caches, jnp.asarray(block), jnp.asarray(committed),
-        jnp.ones(2, bool), write=False, head=True)
+        jnp.ones(2, bool))
     for b, n in enumerate((12, 16)):
         want = ref.forward(MODEL, state, ids[b, :n], np.arange(n),
                            ref.block_causal(np.arange(n), L))["logits"]
@@ -270,13 +325,40 @@ def test_every_forward_of_a_generation_equals_the_reference(
         kinds = [e["kind"] for e in res.trajectory]
         # seeded weights are never confident: one position a forward
         blocks = -(-(n % L + 8) // L)
-        assert kinds.count("commit") == blocks
+        # the last block is never committed: nothing reads the cache again
+        assert kinds.count("commit") == blocks - 1
+        assert [e["block"] for e in res.trajectory
+                if e["kind"] == "commit"] == list(range(blocks - 1))
         assert kinds.count("denoise") == blocks * 4 - n % L
     numbers = _compare({"state": toy[0], "out": out}, texts)
     assert numbers["gen_logit_rel_sq_err"] < 1e-9
     assert numbers["gen_transfer_gap_max"] < 1e-3
     assert numbers["moe_route_disagreement_share"] == 0.0
     assert numbers["moe_route_pairs_counted"] > 50 * len(lengths)
+
+
+# what the tree before the commit rode the next block's first forward
+# generated (five forwards a block, 1875b88) for these seeds: folding changes
+# when K and V are written, not what they are
+TOKENS_BEFORE = {
+    (13, (11,)): [[243, 243, 165, 165, 243, 243, 165, 243]],
+    (12, (10,)): [[42, 165, 165, 165, 165, 165, 165, 165]],
+    (11, (5, 14, 8)): [[165] * 8, [42, 165, 165, 165, 165, 165, 42, 42],
+                       [165] * 8],
+    (31, (5, 14, 8)): [[165, 165, 165, 42, 165, 165, 165, 165], [165] * 8,
+                       [165, 165, 165, 42, 42, 165, 165, 165]],
+}
+CONFIDENT_TOKENS_BEFORE = [
+    [165] * 16,
+    [42, 165, 165, 165, 165, 42, 42, 165, 42, 165, 165, 42, 42, 42, 42, 165],
+    [165, 165, 165, 42, 42, 42, 165, 165, 165, 42, 42, 42, 42, 42, 42, 42],
+    [165] * 16]
+
+
+@pytest.mark.parametrize("seed,lengths", sorted(TOKENS_BEFORE))
+def test_a_seeded_generation_gives_the_tokens_it_gave(toy, seed, lengths):
+    out = generator(toy).generate([words(p) for p in prompts(seed, lengths)])
+    assert [r.token_ids for r in out] == TOKENS_BEFORE[seed, lengths]
 
 
 def test_an_altered_token_is_seen(toy, monkeypatch):
@@ -325,6 +407,7 @@ def test_rows_finish_a_block_at_different_forwards(confident, monkeypatch):
     rows = prompts(21, (8, 8, 12, 16))
     texts = [words(p) for p in rows]
     together = gen.generate(texts)
+    assert [r.token_ids for r in together] == CONFIDENT_TOKENS_BEFORE
     per_block = []
     for res in together:
         kinds = [(e["kind"], e["block"]) for e in res.trajectory]
@@ -436,11 +519,13 @@ def test_every_forward_is_a_step_and_a_count(engine):
     engine.generate("guard", texts, max_new_tokens=8)
     steps1, rows1, g1, f1 = _gen_counts(engine)
     # the 10-token row has a partial block, so the batch runs 3 blocks; the
-    # 8-token row starts every block with 4 masks: 4 forwards a block
-    want = {"gen.prefill": 1, "gen.denoise": 12, "gen.commit": 3}
+    # 8-token row starts every block with 4 masks: 4 forwards a block, the
+    # first of the second and third blocks committing the block before
+    want = {"gen.prefill": 1, "gen.denoise": 10, "gen.commit": 2}
     assert {v: steps1[v] - steps0.get(v, 0) for v in steps1} == want
     assert {v: f1[v] - f0[v] for v in f1} == want
-    assert rows1 - rows0 == 6
+    assert rows1 - rows0 == 4
+    # blocks and tokens FINISHED, the last block among them
     assert (g1[0] - g0[0], g1[1] - g0[1]) == (2 + 3, 20)
 
 
@@ -457,9 +542,13 @@ def test_warmup_compiles_the_generative_programs(engine):
     assert gen.gen_length == 8
     assert sorted(warmed) == [(1, 32, 64), (4, 32, 64)]
     sizes = [[f._cache_size() for f in fns] for fns in warmed.values()]
+    assert all(min(row) == 1 for row in sizes)  # prefill, denoise, commit
     engine.guard_classify("guard", words(prompts(33, (9,))[0]))
-    engine.generate("guard", [words(p) for p in prompts(34, (6, 7, 8))],
-                    max_new_tokens=gen.gen_length)
+    out = engine.generate("guard", [words(p) for p in prompts(34, (6, 7, 8))],
+                          max_new_tokens=gen.gen_length)
+    # three blocks: every program of the loop ran, the committing one twice
+    assert [e["block"] for e in out[0].trajectory
+            if e["kind"] == "commit"] == [0, 1]
     assert dict(gen._programs) == warmed
     assert [[f._cache_size() for f in fns]
             for fns in warmed.values()] == sizes
@@ -477,16 +566,23 @@ def test_step_facts_on_the_profilers_clock(engine, monkeypatch):
 
     monkeypatch.setattr(batchtrace, "trace_span", spy)
     engine.generate("guard", [words(prompts(35, (8,))[0])],
-                    max_new_tokens=4)
+                    max_new_tokens=8)
     steps = [f for n, f in seen if n == "engine.step"]
+    # the second block's first forward commits the first; the second block
+    # is the last, and nothing commits it
     assert [s["flavour"] for s in steps] == \
-        ["gen.prefill"] + ["gen.denoise"] * 4 + ["gen.commit"]
+        ["gen.prefill"] + ["gen.denoise"] * 4 + ["gen.commit"] \
+        + ["gen.denoise"] * 3
     assert steps[0]["group"] == "gen:guard" and steps[0]["tokens_real"] == 8
-    assert [s["masks_left"] for s in steps[1:]] == [4, 3, 2, 1, 0]
-    assert all(s["block"] == 0 and s["rows"] == 1 for s in steps[1:])
+    assert [s["masks_left"] for s in steps[1:]] == [4, 3, 2, 1] * 2
+    assert [s["block"] for s in steps[1:]] == [0] * 4 + [1] * 4
+    assert [s["tokens_real"] for s in steps[1:]] == [L] * 4 + [2 * L] + [L] * 3
+    assert all(s["rows"] == 1 for s in steps[1:])
     marks = [f for n, f in seen if n == "engine.gen.forward"]
-    assert len(marks) == 6 and marks[1]["layers"] == 2
+    assert len(marks) == 9 and marks[1]["layers"] == 2
+    assert [m["flavour"] for m in marks] == [s["flavour"] for s in steps]
     assert marks[1]["pairs"] == 2 * L * 2  # layers x tokens x top-2
+    assert marks[5]["pairs"] == 2 * 2 * L * 2  # ... of two blocks
     assert marks[1]["load_milli"] >= 1000
 
 

@@ -307,10 +307,11 @@ class TestBlockDiffusionGuardCompilesForV5e:
             qkv, qkv, qkv, ((rows, 512), jnp.int32))
         assert "tpu_custom_call" in compiled.as_text()
 
-    @pytest.mark.parametrize("tokens", [4, 64, 512, 8192])
+    @pytest.mark.parametrize("tokens", [4, 8, 64, 128, 512, 8192])
     def test_expert_layer_grouped_matmul(self, one_chip, monkeypatch, tokens):
         """The megablox kernel under ``moe``'s tiling rule: a block forward
-        of 1 and of 16 rows, a prefill of 1 and of 16 rows."""
+        of 1 and of 16 rows, of one block and of two (the forward that
+        commits the block before), a prefill of 1 and of 16 rows."""
         from semantic_router_tpu.models import sdar_moe as M
 
         monkeypatch.setattr(M, "_on_cpu", lambda: False)
@@ -326,3 +327,49 @@ class TestBlockDiffusionGuardCompilesForV5e:
             one_chip, layer, ((H, E), bf), ((E, H, 2 * I), bf),
             ((E, I, H), bf), ((tokens, H), bf), ((tokens,), jnp.bool_))
         assert compiled.as_text().count("tpu_custom_call") >= 2
+
+    @pytest.mark.parametrize("rows", [1, 16])
+    def test_the_committing_forward_writes_the_cache_in_place(
+            self, one_chip, monkeypatch, rows):
+        """The generator's ``commit`` program (two blocks a row, one layer
+        of the published widths, bucket 512): the donated cache comes back
+        as the same buffers, and the head scores one block."""
+        from semantic_router_tpu.models import sdar_moe as M
+        from semantic_router_tpu.models.generate import (
+            BlockDiffusionGenerator,
+        )
+
+        monkeypatch.setattr(M, "_on_cpu", lambda: False)
+        cfg = M.SdarMoeConfig(num_hidden_layers=1)
+        H, I, E, V = (cfg.hidden_size, cfg.moe_intermediate_size,
+                      cfg.num_experts, cfg.vocab_size)
+        bf, L = jnp.bfloat16, 4
+
+        def shape(dims, dtype=bf):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+        layer = {"norm1": shape((H,)), "norm2": shape((H,)),
+                 "q_proj": shape((H, 4096)), "k_proj": shape((H, 512)),
+                 "v_proj": shape((H, 512)), "o_proj": shape((4096, H)),
+                 "q_norm": shape((128,)), "k_norm": shape((128,)),
+                 "router": shape((H, E)), "gate_up": shape((E, H, 2 * I)),
+                 "down": shape((E, I, H))}
+        params = {"embed": shape((V, H)), "layers": [layer],
+                  "norm": shape((H,)), "lm_head": shape((H, V))}
+        gen = BlockDiffusionGenerator(cfg, None, None, mask_token_id=151669,
+                                      block_length=L)
+        cache_len = gen.cache_len(512, gen.gen_length)
+        cache = shape((rows, cfg.num_key_value_heads, cache_len,
+                       cfg.head_dim))
+        block = shape((rows, L), jnp.int32)
+        commit = gen.programs(rows, 512, cache_len)[2]
+        args = (params, [(cache, cache)], block, block,
+                shape((rows, L), bool), shape((rows,), jnp.int32),
+                shape((rows,), bool), shape((), jnp.int32))
+        compiled = commit.lower(*args).compile()
+        assert compiled.as_text().count("tpu_custom_call") >= 2
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == 2 * int(np.prod(cache.shape)) * 2
+        _, _, _, report, experts, _ = jax.eval_shape(commit, *args)
+        assert report.shape == (rows, L, 4 + 2 * gen.top_logits)
+        assert experts.shape == (1, rows, 2 * L, cfg.num_experts_per_tok)
