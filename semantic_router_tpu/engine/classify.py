@@ -249,8 +249,10 @@ class _GenerationObserver:
     (flavour ``gen.prefill`` | ``gen.denoise`` | ``gen.commit`` |
     ``gen.decode``; ``gen.commit`` is the forward that commits block
     ``b`` and begins block ``b + 1``, two blocks of tokens a row; facts
-    ``rows``, ``padded_rows``, ``tokens_real``, ``block`` (the block begun
-    or gone on with), ``masks_left``) and one ``record_step`` sample under
+    ``rows``, ``padded_rows``, ``tokens_real`` — a prefill's padded
+    positions are ``padded_rows`` x ``bucket`` — ``block`` (the block begun
+    or gone on with, or the decode step), ``masks_left``) and one
+    ``record_step`` sample (a prefill's with its token fill) under
     group ``gen:<task>`` with the flavour as its variant, whose clock runs
     from ``forward`` to ``done`` — and one ``record_generation`` count
     (the counters of /metrics).  The prefill step carries the batch items, so
@@ -264,13 +266,14 @@ class _GenerationObserver:
         self.items, self.padded_rows = items, padded_rows
         self.step: Optional[EngineStep] = None
 
-    def forward(self, flavour: str, tokens_real: int = 0, **facts):
+    def forward(self, flavour: str, tokens_real: int = 0,
+                tokens_padded: int = 0, **facts):
         self.step = EngineStep(
             self.engine, self.items if flavour == "gen.prefill" else (),
             scope="gen", name=self.task, bucket=self.bucket,
             padded_rows=self.padded_rows, kind="generative",
             flavour=flavour, variant=flavour, rows=len(self.items),
-            tokens_real=tokens_real, **facts)
+            tokens_real=tokens_real, tokens_padded=tokens_padded, **facts)
         self.step.program()
         return self
 
@@ -278,11 +281,14 @@ class _GenerationObserver:
         return self.step.stage(name)
 
     def done(self, load=None, committed_blocks: int = 0,
-             committed_tokens: int = 0) -> None:
-        """``load [layers, 4]`` of an expert model (models.sdar_moe.moe);
-        a dense generator gives none.  ``committed_blocks`` /
-        ``committed_tokens``: what this forward FINISHED (a block's last
-        forward says so, whichever forward writes its K and V later)."""
+             committed_tokens: int = 0, cache_bytes=None) -> None:
+        """``load [layers, 4]`` of an expert model
+        (models.sdar_moe.routed_experts); a dense generator gives none.
+        ``committed_blocks`` / ``committed_tokens``: what this forward
+        FINISHED (a block's last forward says so, whichever forward writes
+        its K and V later; a token-at-a-time forward finishes one token a
+        live row).  ``cache_bytes``: a prefill's cache by kind of state
+        (``{"kv", "conv"}``)."""
         from ..observability import batchtrace
 
         step = self.step
@@ -293,7 +299,7 @@ class _GenerationObserver:
         try:
             self.engine._runtime_stats.record_generation(
                 self.task, step.variant, committed_blocks=committed_blocks,
-                committed_tokens=committed_tokens)
+                committed_tokens=committed_tokens, cache_bytes=cache_bytes)
         except Exception:
             pass  # observability never fails a generation
 

@@ -1,11 +1,15 @@
-"""Generative serving: KV-cached incremental decoding for Qwen3.
+"""Generative serving: one token-at-a-time loop (``GreedyGenerator``) over
+a model that owns its cache — the dense Qwen3 below, the hybrid
+``models/lfm2_moe.py`` — and generation by diffusion over blocks
+(``BlockDiffusionGenerator``, ``models/sdar_moe.py``).
 
 Reference capabilities re-designed TPU-first:
 - qwen3_guard.rs (safety generation: greedy short-generation + structured
   regex parse) and qwen3_multi_lora_classifier.rs:1-60 (multi-LoRA
   generative classification with per-request adapter selection).
 
-Design notes (XLA-native, no torch-style dynamic shapes):
+Design notes of the Qwen3 decoder (XLA-native, no torch-style dynamic
+shapes):
 - The KV cache is an explicit pytree of fixed-shape arrays
   ``[B, KV_heads, M, head_dim]`` updated with ``lax.dynamic_update_slice``
   at a uniform column offset — prompt tokens fill columns ``0..S`` (padding
@@ -305,49 +309,148 @@ def _finish_tokens(tokenizer, tokens: List[int], eos_ids, stop_strings,
         trajectory=trajectory)
 
 
-class GreedyGenerator:
-    """Bucketed greedy decoding: one jitted prefill + one jitted step per
-    (B, prompt_bucket, cache_len) shape; host loop handles EOS."""
+def _top_k_by_argmax(x: jnp.ndarray, k: int):
+    """The ``k`` largest of the last axis, largest first, ties to the lower
+    index: ``k`` argmax passes (a sort of 150k entries a row is the
+    alternative)."""
+    vals, ids = [], []
+    for _ in range(k):
+        i = jnp.argmax(x, axis=-1)
+        vals.append(jnp.take_along_axis(x, i[..., None], -1)[..., 0])
+        ids.append(i)
+        x = jnp.where(jnp.arange(x.shape[-1]) == i[..., None], -jnp.inf, x)
+    return jnp.stack(vals, -1), jnp.stack(ids, -1)
 
-    def __init__(self, config: Qwen3Config, params,
-                 tokenizer, lora: Optional[LoRAConfig] = None,
-                 eos_token_ids: Sequence[int] = (),
-                 pad_id: int = 0, cache_dtype=None,
-                 gen_length: int = GEN_LENGTH) -> None:
+
+def select_greedy(logits, top_logits: int):
+    """The greedy choice on the device: ``logits [B, V]`` (float32) ->
+    ``(tokens [B] int32, report [B, 2 + 2 top])`` with ``report`` = the
+    chosen id, the log-sum-exp, the ids of the ``top`` largest logits and
+    their values (float32: an id below 2^24 is exact).  What a step reads
+    back is this, not the vocabulary's logits."""
+    with jax.named_scope("select"):
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        top_v, top_i = _top_k_by_argmax(logits, top_logits)
+        report = jnp.concatenate(
+            [top_i[:, :1].astype(jnp.float32), lse[:, None],
+             top_i.astype(jnp.float32), top_v], -1)
+        return top_i[:, 0].astype(jnp.int32), report
+
+
+class Qwen3Cached:
+    """The dense Qwen3 (with its LoRA adapters) behind the interface
+    ``GreedyGenerator`` decodes through: a model that owns its cache.
+
+    ``prefill(params, ids [B, S], lengths [B], cache_len, task_index) ->
+    (cache, logits [B, V] at each row's last token, aux)`` and
+    ``decode(params, cache, tokens [B], positions [B], task_index) ->
+    (cache, logits [B, V], aux)``; ``aux`` may hold ``experts`` (the
+    router's choice, ``[layers, B, (S,) k]``) and ``load [layers, 4]`` of
+    an expert model — a dense one gives neither; ``cache_bytes(cache)``
+    the cache's bytes by kind of state.  ``models.lfm2_moe.CachedModel``
+    is the other implementation.
+
+    This cache: K and V ``[B, kv, M, D]`` a layer, prompt tokens in
+    columns ``0..S`` (a padding column is masked forever), decode step
+    ``t`` in column ``S + t`` of every row (``next``), and the mask of the
+    live columns."""
+
+    def __init__(self, config: Qwen3Config, lora: Optional[LoRAConfig],
+                 cache_dtype) -> None:
         self.config = config
-        self.gen_length = int(gen_length)
         self.module = Qwen3Decoder(config, lora)
-        self.params = params
-        self.tokenizer = tokenizer
-        self.eos_token_ids = set(int(t) for t in eos_token_ids)
-        self.pad_id = pad_id
-        self.cache_dtype = cache_dtype or config.dtype
-        self._prefill_cache: Dict[Tuple, Any] = {}
-        self._step_cache: Dict[Tuple, Any] = {}
+        self.cache_dtype = cache_dtype
 
-    def _init_caches(self, B: int, M: int):
+    def init_caches(self, B: int, M: int):
         cfg = self.config
         shape = (B, cfg.num_key_value_heads, M, cfg.head_dim)
         return [(jnp.zeros(shape, self.cache_dtype),
                  jnp.zeros(shape, self.cache_dtype))
                 for _ in range(cfg.num_hidden_layers)]
 
+    def prefill(self, params, ids, lengths, cache_len: int, task_index):
+        B, S = ids.shape
+        lengths = jnp.maximum(lengths, 1)  # a padding row: one pad token
+        mask = jnp.arange(cache_len)[None, :] < lengths[:, None]
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        logits, kv = self.module.apply(
+            params, ids, self.init_caches(B, cache_len), mask, positions, 0,
+            task_index)
+        # the next token comes from each row's LAST REAL position
+        last = jnp.take_along_axis(logits, (lengths - 1)[:, None, None],
+                                   axis=1)[:, 0]
+        cache = {"kv": kv, "mask": mask, "next": jnp.asarray(S, jnp.int32)}
+        return cache, last.astype(jnp.float32), {}
+
+    def decode(self, params, cache, tokens, positions, task_index):
+        at = cache["next"]
+        mask = jax.lax.dynamic_update_slice(
+            cache["mask"], jnp.ones((tokens.shape[0], 1), bool), (0, at))
+        logits, kv = self.module.apply(
+            params, tokens[:, None], cache["kv"], mask, positions[:, None],
+            at, task_index)
+        cache = {"kv": kv, "mask": mask, "next": at + 1}
+        return cache, logits[:, 0].astype(jnp.float32), {}
+
+    @staticmethod
+    def cache_bytes(cache) -> Dict[str, int]:
+        return {"kv": sum(int(a.size) * a.dtype.itemsize
+                          for a in jax.tree_util.tree_leaves(cache["kv"]))}
+
+
+class GreedyGenerator:
+    """Bucketed greedy decoding, a token at a time, over a model that owns
+    its cache (``Qwen3Cached`` unless ``model`` is given): one jitted
+    prefill + one jitted step per (B, prompt_bucket, cache_len) shape; the
+    token is chosen on the device (``select_greedy``) and the host loop,
+    which reads one small report a forward, handles EOS."""
+
+    def __init__(self, config, params,
+                 tokenizer, lora: Optional[LoRAConfig] = None,
+                 eos_token_ids: Sequence[int] = (),
+                 pad_id: int = 0, cache_dtype=None,
+                 gen_length: int = GEN_LENGTH, model=None,
+                 top_logits: int = 8) -> None:
+        self.config = config
+        self.gen_length = int(gen_length)
+        self.model = model or Qwen3Cached(config, lora,
+                                          cache_dtype or config.dtype)
+        self.params = params
+        self.tokenizer = tokenizer
+        self.eos_token_ids = set(int(t) for t in eos_token_ids)
+        self.pad_id = pad_id
+        self.top_logits = top_logits
+        self._prefill_cache: Dict[Tuple, Any] = {}
+        self._step_cache: Dict[Tuple, Any] = {}
+
+    @property
+    def module(self):
+        """The dense model's flax module (its parameter tree's owner)."""
+        return self.model.module
+
+    def _init_caches(self, B: int, M: int):
+        return self.model.init_caches(B, M)
+
     def _prefill_fn(self, key):
         if key not in self._prefill_cache:
-            def fn(params, ids, caches, cache_mask, positions, task_index):
-                return self.module.apply(params, ids, caches, cache_mask,
-                                         positions, 0, task_index)
+            M = key[2]
+
+            def fn(params, ids, lengths, task_index):
+                cache, logits, aux = self.model.prefill(
+                    params, ids, lengths, M, task_index)
+                tokens, report = select_greedy(logits, self.top_logits)
+                return cache, tokens, report, aux
             self._prefill_cache[key] = jax.jit(fn)
         return self._prefill_cache[key]
 
     def _step_fn(self, key):
         if key not in self._step_cache:
-            def fn(params, token, caches, cache_mask, positions,
-                   write_index, task_index):
-                return self.module.apply(params, token, caches, cache_mask,
-                                         positions, write_index, task_index)
-            self._step_cache[key] = jax.jit(
-                fn, static_argnames=())
+            def fn(params, cache, tokens, positions, task_index):
+                cache, logits, aux = self.model.decode(
+                    params, cache, tokens, positions, task_index)
+                tokens, report = select_greedy(logits, self.top_logits)
+                return cache, tokens, positions + 1, report, aux
+            self._step_cache[key] = jax.jit(fn, donate_argnums=(1,))
         return self._step_cache[key]
 
     batched = True  # generate() takes the engine's batch (encodings=...)
@@ -370,46 +473,67 @@ class GreedyGenerator:
         ``max_new_tokens``.  The engine's batch runner passes the batch it
         composed: ``encodings`` (the prompts, tokenized by the callers),
         the prompt ``bucket`` they are padded to, ``padded_rows`` (a
-        padding row is a one-token prompt that counts as finished) and an
+        padding row has length 0 and counts as finished) and an
         ``observer`` of the forwards.  Without them the prompts are
-        tokenized here and padded to their own longest."""
+        tokenized here and padded to their own longest.
+
+        A result's ``trajectory`` has one entry per forward that chose a
+        token for the request: ``kind`` (``prefill`` | ``decode``),
+        ``position`` (of the token whose logits chose), ``token`` (the
+        choice), ``lse``, ``top_ids`` / ``top_logits [top]`` (float32) and,
+        of an expert model, ``experts [layers, n, k]`` (the router's choice
+        at the prompt's ``n`` tokens, or at the one decoded)."""
         encs, bucket, padded_rows = _as_batch(
             self.tokenizer, prompts, encodings, bucket, padded_rows)
         obs = observer or NullObserver()
         n, B, S = len(encs), padded_rows, bucket
-        lengths = np.ones(B, np.int32)
+        lengths = np.zeros(B, np.int32)
         lengths[:n] = [len(e) for e in encs]
         M = _round_up(S + max_new_tokens + 1, 64)
         max_new_tokens = _steps or max_new_tokens
+        k = self.top_logits
 
-        fwd = obs.forward("gen.prefill", tokens_real=int(lengths[:n].sum()))
+        def choices(kind: str, at, report, experts) -> None:
+            """Every live row's entry of one forward."""
+            for i in range(n):
+                if finished[i]:
+                    continue
+                entry = {"kind": kind, "position": int(at[i]),
+                         "token": int(report[i, 0]), "lse": report[i, 1],
+                         "top_ids": report[i, 2:2 + k].astype(np.int32),
+                         "top_logits": report[i, 2 + k:]}
+                if experts is not None:
+                    entry["experts"] = experts[:, i, :lengths[i]] \
+                        if kind == "prefill" else experts[:, i, None]
+                trajectory[i].append(entry)
+
+        fwd = obs.forward("gen.prefill", tokens_real=int(lengths.sum()),
+                          tokens_padded=B * S)
         with fwd.stage("stack"):
             ids = np.full((B, S), self.pad_id, np.int32)
-            mask = np.zeros((B, M), bool)
             for i, e in enumerate(encs):
                 ids[i, :lengths[i]] = e.ids[:lengths[i]]
-            mask[:, :S] = np.arange(S)[None, :] < lengths[:, None]
-            positions = np.broadcast_to(np.arange(S, dtype=np.int32), (B, S))
         with fwd.stage("h2d"):
-            caches = self._init_caches(B, M)
-            args = (jnp.asarray(ids), caches, jnp.asarray(mask),
-                    jnp.asarray(positions), jnp.asarray(task_index))
+            task_arr = jnp.asarray(task_index)
+            args = (jnp.asarray(ids), jnp.asarray(lengths), task_arr)
         with fwd.stage("dispatch"):
-            logits, caches = self._prefill_fn((B, S, M))(self.params, *args)
-            # next token comes from each row's LAST REAL position
-            last = jnp.take_along_axis(
-                logits, jnp.asarray(lengths - 1)[:, None, None], axis=1)[:, 0]
+            cache, tokens_dev, report, aux = self._prefill_fn((B, S, M))(
+                self.params, *args)
+            positions_dev = args[1]
         with fwd.stage("readback"):
-            next_tok = np.asarray(jax.device_get(last), np.float32) \
-                .argmax(-1).astype(np.int32)
-        fwd.done()
-
+            report, aux = jax.device_get((report, aux))
         out_tokens: List[List[int]] = [[] for _ in range(B)]
+        trajectory: List[List[Dict[str, Any]]] = [[] for _ in range(n)]
         finished = np.zeros(B, bool)
         finished[n:] = True
+        with fwd.stage("demux"):
+            choices("prefill", lengths - 1, report, aux.get("experts"))
+        fwd.done(load=aux.get("load"), committed_tokens=n,
+                 cache_bytes=self.model.cache_bytes(cache))
+
         step = self._step_fn((B, 1, M))
-        task_arr = jnp.asarray(task_index)
         for t in range(max_new_tokens):
+            next_tok = report[:, 0].astype(np.int32)
             for i in range(n):
                 if not finished[i]:
                     out_tokens[i].append(int(next_tok[i]))
@@ -417,44 +541,26 @@ class GreedyGenerator:
                         finished[i] = True
             if finished.all() or t == max_new_tokens - 1:
                 break
-            fwd = obs.forward("gen.decode", tokens_real=n, block=t)
-            with fwd.stage("stack"):
-                write_index = S + t
-                mask = mask.copy()
-                mask[:, write_index] = True
-                pos = (lengths + t)[:, None].astype(np.int32)
-            with fwd.stage("h2d"):
-                args = (jnp.asarray(next_tok[:, None]), caches,
-                        jnp.asarray(mask), jnp.asarray(pos))
+            live = int((~finished).sum())
+            fwd = obs.forward("gen.decode", tokens_real=live, block=t)
             with fwd.stage("dispatch"):
-                logits, caches = step(self.params, *args, write_index,
-                                      task_arr)
+                cache, tokens_dev, positions_dev, report, aux = step(
+                    self.params, cache, tokens_dev, positions_dev, task_arr)
             with fwd.stage("readback"):
-                next_tok = np.asarray(
-                    jax.device_get(logits[:, 0]), np.float32
-                ).argmax(-1).astype(np.int32)
-            fwd.done()
+                report, aux = jax.device_get((report, aux))
+            with fwd.stage("demux"):
+                choices("decode", lengths + t, report, aux.get("experts"))
+            fwd.done(load=aux.get("load"), committed_tokens=live)
+        del cache
         return [_finish_tokens(self.tokenizer, out_tokens[i],
                                self.eos_token_ids, stop_strings,
-                               int(lengths[i])) for i in range(n)]
+                               int(lengths[i]), trajectory[i])
+                for i in range(n)]
 
 
 # ---------------------------------------------------------------------------
 # block diffusion: a block of tokens a step
 # ---------------------------------------------------------------------------
-
-
-def _top_k_by_argmax(x: jnp.ndarray, k: int):
-    """The ``k`` largest of the last axis, largest first, ties to the lower
-    index: ``k`` argmax passes (a sort of 150k entries a row is the
-    alternative)."""
-    vals, ids = [], []
-    for _ in range(k):
-        i = jnp.argmax(x, axis=-1)
-        vals.append(jnp.take_along_axis(x, i[..., None], -1)[..., 0])
-        ids.append(i)
-        x = jnp.where(jnp.arange(x.shape[-1]) == i[..., None], -jnp.inf, x)
-    return jnp.stack(vals, -1), jnp.stack(ids, -1)
 
 
 def transfer_by_confidence(logits, tokens, masked, threshold, at_least,

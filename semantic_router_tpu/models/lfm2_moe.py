@@ -1,0 +1,437 @@
+"""The ``lfm2_moe`` decoder (LFM2-24B-A2B): three kinds of layer in one
+stack, decoded a token at a time over ONE hybrid cache.
+
+Layer equations (``chipbench/reference/lfm2_moe.py`` is the plain form):
+``x <- x + Op(RMSNorm_op(x))``, ``x <- x + FF(RMSNorm_ffn(x))``, a final
+RMSNorm (``embedding_norm``), a head tied to the embedding.
+
+- ``Op`` by ``layer_types[i]``.  ``conv``: ``[B, C, u] = split3(W_in h)``,
+  ``z = B * u``, ``c_t = w0 z_{t-2} + w1 z_{t-1} + w2 z_t`` (depthwise,
+  causal, zeros before position 0), ``Op(h)_t = W_out (C_t * c_t)``.
+  ``full_attention``: causal GQA with per-head RMSNorm on q and k, RoPE,
+  no bias.
+- ``FF``: a dense SwiGLU in the first ``num_dense_layers`` layers, else the
+  sparse experts of ``sdar_moe.routed_experts`` behind the SIGMOID router:
+  ``s = sigmoid(W_g h)``, the top k of ``s + b`` (``use_expert_bias``) are
+  chosen, the weights are the UNBIASED ``s`` there, ``/ (sum + 1e-6)``
+  (``norm_topk_prob``), ``* routed_scaling_factor``.
+
+The cache a row carries from token to token is a pytree with two kinds of
+state side by side: per attention layer K and V ``[rows, kv, M, D]``, per
+conv layer the last ``conv_L_cache - 1`` vectors ``z`` ``[rows, L-1, H]``,
+whatever the context; and ``lengths [rows]`` (0 = a padding row, which
+routes nowhere).  A right-padded row's conv state is taken at its true
+last token.  A decode token's K and V go to the column of its position.
+
+Precision: parameters, K/V and conv state in ``cfg.dtype``; norms, RoPE,
+softmax, the gate products of the convolution, the router's logits and
+sigmoid and the head's logits in float32 (the published code computes the
+router's logits in the model's dtype: more precise here, never less).
+
+A prefill is bounded in tokens: rows are mapped INSIDE the program
+(``jax.lax.map``), so the expert layers' temporaries exist for one row at
+a time; decoding runs all rows together.
+
+Scopes: ``embed_tokens``, ``layers_<i>/conv`` (``in_proj``, ``conv1d``,
+``out_proj``), ``layers_<i>/attn``, ``layers_<i>/mlp``, ``layers_<i>/moe``
+(``router``, ``sort``, ``gmm``, ``combine``), ``lm_head``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Dict, Mapping, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.flash_attention import flash_attention
+from .qwen3 import torch_dtype_of
+from .sdar_moe import (
+    NEG_INF,
+    checkpoint_reader,
+    qkv,
+    rms_norm,
+    routed_experts,
+)
+
+LAYER_TYPES = ("conv", "full_attention")
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    vocab_size: int = 65536
+    hidden_size: int = 2048
+    intermediate_size: int = 11776
+    moe_intermediate_size: int = 1536
+    num_hidden_layers: int = 40
+    num_dense_layers: int = 2
+    layer_types: Tuple[str, ...] = ()
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    head_dim: int = 64
+    conv_L_cache: int = 3
+    norm_eps: float = 1e-5
+    rope_theta: float = 1000000.0
+    max_position_embeddings: int = 128000
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    tie_word_embeddings: bool = True
+    dtype: Any = jnp.bfloat16
+    # (first, count) of the experts this chip holds; None = all of them
+    experts_held: Optional[Tuple[int, int]] = None
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def rms_norm_eps(self) -> float:  # the name sdar_moe.qkv reads
+        return self.norm_eps
+
+    @classmethod
+    def from_hf(cls, hf: Mapping[str, Any], **overrides) -> "Lfm2MoeConfig":
+        """From a checkpoint's ``config.json`` (``model_type: lfm2_moe``).
+        What the architecture cannot express is refused, not ignored."""
+        if hf.get("conv_bias", False):
+            raise ValueError("lfm2_moe: conv_bias is not supported")
+        rope = dict(hf.get("rope_parameters") or {})
+        if rope.get("rope_type", "default") != "default" \
+                or hf.get("rope_scaling"):
+            raise ValueError("lfm2_moe: only the default RoPE is supported")
+        types = tuple(hf.get("layer_types") or ())
+        if len(types) != hf["num_hidden_layers"] \
+                or set(types) - set(LAYER_TYPES):
+            raise ValueError(
+                f"lfm2_moe: layer_types must name one of {LAYER_TYPES} for "
+                f"each of the {hf['num_hidden_layers']} layers, not {types}")
+        fields = {f.name for f in dataclasses.fields(cls)}
+        kw = {k: v for k, v in hf.items() if k in fields}
+        kw["layer_types"] = types
+        if kw.get("head_dim") is None:
+            kw["head_dim"] = hf["hidden_size"] // hf["num_attention_heads"]
+        kw["dtype"] = torch_dtype_of(hf.get("torch_dtype", "bfloat16"))
+        kw["rope_theta"] = float(rope.get("rope_theta",
+                                          hf.get("rope_theta", 1e6)))
+        kw.update(overrides)
+        return cls(**kw)
+
+    def is_sparse(self, i: int) -> bool:
+        return i >= self.num_dense_layers
+
+
+# -- parameters ------------------------------------------------------------------
+
+
+def params_from_checkpoint(path: str, cfg: Lfm2MoeConfig) -> Dict[str, Any]:
+    with checkpoint_reader(path) as get:
+        return params_from_state(get, cfg)
+
+
+def params_from_state(get: Callable[[str], np.ndarray], cfg: Lfm2MoeConfig
+                      ) -> Dict[str, Any]:
+    """The published tensor names (``get(name)`` loads one) as this
+    module's tree, in ``cfg.dtype`` on the default device; the router's
+    selection bias stays float32.  Only the experts held are read."""
+
+    def dev(a: np.ndarray, transpose: bool = False) -> jnp.ndarray:
+        x = jnp.asarray(a).astype(cfg.dtype)
+        return jnp.swapaxes(x, -1, -2) if transpose else x
+
+    first, count = cfg.held
+    layers = []
+    for i, kind in enumerate(cfg.layer_types):
+        p = f"model.layers.{i}."
+        layer = {"norm1": dev(get(p + "operator_norm.weight")),
+                 "norm2": dev(get(p + "ffn_norm.weight"))}
+        if kind == "conv":
+            layer.update(
+                in_proj=dev(get(p + "conv.in_proj.weight"), True),
+                # [H, 1, L] as a depthwise Conv1d stores it -> [L, H]
+                conv_w=dev(np.asarray(get(p + "conv.conv.weight"))[:, 0].T),
+                out_proj=dev(get(p + "conv.out_proj.weight"), True))
+        else:
+            a = p + "self_attn."
+            layer.update(
+                q_proj=dev(get(a + "q_proj.weight"), True),
+                k_proj=dev(get(a + "k_proj.weight"), True),
+                v_proj=dev(get(a + "v_proj.weight"), True),
+                o_proj=dev(get(a + "out_proj.weight"), True),
+                q_norm=dev(get(a + "q_layernorm.weight")),
+                k_norm=dev(get(a + "k_layernorm.weight")))
+        f = p + "feed_forward."
+        if cfg.is_sparse(i):
+            experts = {k: np.stack([get(f"{f}experts.{e}.{k}.weight")
+                                    for e in range(first, first + count)])
+                       for k in ("w1", "w3", "w2")}
+            layer.update(
+                router=dev(get(f + "gate.weight"), True),
+                gate_up=jnp.concatenate([dev(experts["w1"], True),
+                                         dev(experts["w3"], True)], -1),
+                down=dev(experts["w2"], True))
+            if cfg.use_expert_bias:
+                layer["expert_bias"] = jnp.asarray(
+                    np.asarray(get(f + "expert_bias"), np.float32))
+            del experts
+        else:
+            layer.update(
+                gate_up=jnp.concatenate([dev(get(f + "w1.weight"), True),
+                                         dev(get(f + "w3.weight"), True)],
+                                        -1),
+                down=dev(get(f + "w2.weight"), True))
+        layers.append(layer)
+    params = {"embed": dev(get("model.embed_tokens.weight")),
+              "layers": layers,
+              "norm": dev(get("model.embedding_norm.weight"))}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = dev(get("lm_head.weight"))
+    return params
+
+
+# -- layers ----------------------------------------------------------------------
+
+
+def route(cfg: Lfm2MoeConfig, p, x):
+    """The sigmoid router on ``x [T, H]``: ``(top_e [T, k], weights [T, k]
+    float32)``.  The bias moves the choice and never the weights."""
+    logits = jnp.dot(x, p["router"], preferred_element_type=jnp.float32)
+    s = jax.nn.sigmoid(logits)
+    pick = s + p["expert_bias"] if cfg.use_expert_bias else s
+    _, top_e = jax.lax.top_k(pick, cfg.num_experts_per_tok)
+    w = jnp.take_along_axis(s, top_e, -1)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-6)
+    return top_e, w * cfg.routed_scaling_factor
+
+
+def moe(cfg: Lfm2MoeConfig, p, x, valid):
+    """``x [T, H]`` through the sigmoid router and the shared expert
+    layer.  Returns ``(y [T, H], top_e [T, k], load [4])``."""
+    with jax.named_scope("router"):
+        top_e, w = route(cfg, p, x)
+    y, load = routed_experts(p, x, valid, top_e, w, cfg.held, cfg.dtype)
+    return y, top_e, load
+
+
+def _swiglu(cfg, p, x):
+    gu = x @ p["gate_up"]
+    I = p["down"].shape[0]
+    h = jax.nn.silu(gu[..., :I].astype(jnp.float32)) \
+        * gu[..., I:].astype(jnp.float32)
+    return h.astype(cfg.dtype) @ p["down"]
+
+
+def _feed_forward(cfg, i, p, x, valid):
+    """The second half of layer ``i`` on ``x [B, S, H]``; a dense layer
+    reports no experts."""
+    B, S, H = x.shape
+    h = rms_norm(x, p["norm2"], cfg.norm_eps, cfg.dtype)
+    if not cfg.is_sparse(i):
+        with jax.named_scope("mlp"):
+            return x + _swiglu(cfg, p, h), None, None
+    with jax.named_scope("moe"):
+        y, top_e, load = moe(cfg, p, h.reshape(B * S, H), valid.reshape(-1))
+    return x + y.reshape(B, S, H), top_e.reshape(B, S, -1), load
+
+
+def _gates(cfg, p, h):
+    """``h [..., H]`` -> the conv operator's ``z = B * u`` (in the model's
+    dtype: what the state holds) and its output gate ``C`` (float32)."""
+    with jax.named_scope("in_proj"):
+        b, c, u = jnp.split((h @ p["in_proj"]).astype(jnp.float32), 3, -1)
+        return (b * u).astype(cfg.dtype), c
+
+
+def _taps(p, z_window):
+    """``sum_k w_k * z_window[k]`` over the leading axis, float32."""
+    w = p["conv_w"].astype(jnp.float32)
+    return sum(w[k] * z_window[k].astype(jnp.float32)
+               for k in range(w.shape[0]))
+
+
+def _expert_ids(cfg, top_e):
+    return top_e.astype(jnp.uint8 if cfg.num_experts <= 256 else jnp.int32)
+
+
+def _head(cfg, params, x):
+    """``x [B, H]`` -> logits ``[B, V]`` float32."""
+    with jax.named_scope("lm_head"):
+        h = rms_norm(x, params["norm"], cfg.norm_eps, cfg.dtype)
+        w = params["embed"] if cfg.tie_word_embeddings else params["lm_head"]
+        return jnp.einsum("bh,vh->bv", h, w,
+                          preferred_element_type=jnp.float32)
+
+
+# -- prefill ---------------------------------------------------------------------
+
+
+def _prefill_rows(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int):
+    """``ids [B, S]`` right-padded, ``lengths [B]`` -> ``(kv, conv, logits
+    [B, V], experts [layers, B, S, k], load [layers, 4])``: the whole
+    prompt under the causal mask, all rows of ``ids`` at once."""
+    B, S = ids.shape
+    nkv = cfg.num_key_value_heads
+    rep = cfg.num_attention_heads // nkv
+    keep = cfg.conv_L_cache - 1
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    valid = positions < lengths[:, None]
+    last = jnp.maximum(lengths - 1, 0)
+    with jax.named_scope("embed_tokens"):
+        x = jnp.take(params["embed"], ids, axis=0)
+    kv, conv, experts, loads = [], [], [], []
+    for i, (kind, p) in enumerate(zip(cfg.layer_types, params["layers"])):
+        with jax.named_scope(f"layers_{i}"):
+            h = rms_norm(x, p["norm1"], cfg.norm_eps, cfg.dtype)
+            if kind == "conv":
+                with jax.named_scope("conv"):
+                    z, c = _gates(cfg, p, h)
+                    with jax.named_scope("conv1d"):
+                        zp = jnp.pad(z, ((0, 0), (keep, 0), (0, 0)))
+                        y = c * _taps(p, [zp[:, k:k + S]
+                                          for k in range(keep + 1)])
+                        # the state at each row's TRUE last token: z at
+                        # len - keep .. len - 1 (zeros before position 0)
+                        at = last[:, None] + jnp.arange(1, keep + 1)
+                        conv.append(jnp.take_along_axis(
+                            zp, at[:, :, None], axis=1)
+                            * (lengths > 0)[:, None, None].astype(z.dtype))
+                    with jax.named_scope("out_proj"):
+                        x = x + y.astype(cfg.dtype) @ p["out_proj"]
+            else:
+                with jax.named_scope("attn"):
+                    q, k, v = qkv(cfg, p, h, positions, S)
+                    kc, vc = (jnp.moveaxis(t, 2, 1) for t in (k, v))
+                    out = flash_attention(
+                        jnp.moveaxis(q, 2, 1), jnp.repeat(kc, rep, axis=1),
+                        jnp.repeat(vc, rep, axis=1),
+                        key_padding_mask=valid.astype(jnp.int32),
+                        causal=True)
+                    out = jnp.moveaxis(out, 1, 2).reshape(B, S, -1)
+                    x = x + out.astype(cfg.dtype) @ p["o_proj"]
+                    pad = ((0, 0), (0, 0), (0, cache_len - S), (0, 0))
+                    kv.append((jnp.pad(kc, pad), jnp.pad(vc, pad)))
+            x, top_e, load = _feed_forward(cfg, i, p, x, valid)
+            if top_e is not None:
+                experts.append(_expert_ids(cfg, top_e))
+                loads.append(load)
+    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+    return (kv, conv, _head(cfg, params, x_last), jnp.stack(experts),
+            jnp.stack(loads))
+
+
+def _sum_loads(loads):
+    """Per-row ``load [rows, layers, 4]`` of a mapped prefill as one
+    ``[layers, 4]``: every row's grouped matmul reads its own touched
+    experts, so pairs and experts touched add up; the busiest is the
+    busiest of any row, the ratio the rows' mean."""
+    return jnp.stack([loads[..., 0].max(0), loads[..., 1].sum(0),
+                      loads[..., 2].sum(0), loads[..., 3].mean(0)], -1)
+
+
+def prefill(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int):
+    """``ids [B, S]`` right-padded prompts of ``lengths [B]`` (0 = a padding
+    row) -> ``(cache, logits [B, V] float32 at each row's last token, aux)``
+    with ``aux = {"experts" [expert layers, B, S, k], "load" [expert
+    layers, 4]}``.  One row at a time inside the program, so a bucket's
+    temporaries are those of ONE row whatever the batch."""
+    def one(row):
+        kv, conv, logits, experts, load = _prefill_rows(
+            cfg, params, row[0][None], row[1][None], cache_len)
+        return kv, conv, logits[0], experts[:, 0], load
+
+    kv, conv, logits, experts, loads = jax.lax.map(one, (ids, lengths))
+    cache = {"kv": [(k[:, 0], v[:, 0]) for k, v in kv],
+             "conv": [c[:, 0] for c in conv],
+             "lengths": lengths.astype(jnp.int32)}
+    return cache, logits, {"experts": jnp.moveaxis(experts, 0, 1),
+                           "load": _sum_loads(loads)}
+
+
+# -- decode: one token a row against the hybrid cache ----------------------------
+
+
+def decode(cfg: Lfm2MoeConfig, params, cache, tokens, positions):
+    """``tokens [B]`` at ``positions [B]`` (a row's count of tokens before
+    this one), all rows together.  Returns ``(cache, logits [B, V], aux)``
+    with ``aux["experts"] [expert layers, B, k]``; the cache comes back
+    with this token's K and V at column ``positions`` of every attention
+    layer and with every conv layer's state moved on by one."""
+    B = tokens.shape[0]
+    nh, nkv, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    rep = nh // nkv
+    live = cache["lengths"] > 0
+    pos = positions[:, None]
+    put = jax.vmap(lambda c, new, at: jax.lax.dynamic_update_slice(
+        c, new, (0, at, 0)))
+    with jax.named_scope("embed_tokens"):
+        x = jnp.take(params["embed"], tokens, axis=0)[:, None]  # [B, 1, H]
+    kv, conv, experts, loads = [], [], [], []
+    kv_in, conv_in = iter(cache["kv"]), iter(cache["conv"])
+    for i, (kind, p) in enumerate(zip(cfg.layer_types, params["layers"])):
+        with jax.named_scope(f"layers_{i}"):
+            h = rms_norm(x, p["norm1"], cfg.norm_eps, cfg.dtype)
+            if kind == "conv":
+                with jax.named_scope("conv"):
+                    state = next(conv_in)  # [B, L-1, H]
+                    z, c = _gates(cfg, p, h)
+                    with jax.named_scope("conv1d"):
+                        window = jnp.concatenate([state, z], axis=1)
+                        y = c[:, 0] * _taps(p, jnp.moveaxis(window, 1, 0))
+                        conv.append(window[:, 1:])
+                    with jax.named_scope("out_proj"):
+                        x = x + (y.astype(cfg.dtype) @ p["out_proj"])[:, None]
+            else:
+                with jax.named_scope("attn"):
+                    k_cache, v_cache = next(kv_in)
+                    M = k_cache.shape[2]
+                    q, k, v = qkv(cfg, p, h, pos, M)
+                    k_cache = put(k_cache, jnp.moveaxis(k, 2, 1), positions)
+                    v_cache = put(v_cache, jnp.moveaxis(v, 2, 1), positions)
+                    kv.append((k_cache, v_cache))
+                    qg = q.reshape(B, nkv, rep, D)
+                    s = jnp.einsum("bgrd,bgmd->bgrm", qg, k_cache,
+                                   preferred_element_type=jnp.float32) \
+                        * (1.0 / np.sqrt(float(D)))
+                    seen = jnp.arange(M)[None, :] <= pos  # [B, M]
+                    s = s + jnp.where(seen, 0.0, NEG_INF)[:, None, None, :]
+                    out = jnp.einsum(
+                        "bgrm,bgmd->bgrd",
+                        jax.nn.softmax(s, axis=-1).astype(cfg.dtype),
+                        v_cache, preferred_element_type=jnp.float32)
+                    x = x + (out.reshape(B, nh * D).astype(cfg.dtype)
+                             @ p["o_proj"])[:, None]
+            x, top_e, load = _feed_forward(cfg, i, p, x, live[:, None])
+            if top_e is not None:
+                experts.append(_expert_ids(cfg, top_e[:, 0]))
+                loads.append(load)
+    cache = {"kv": kv, "conv": conv, "lengths": cache["lengths"]}
+    return cache, _head(cfg, params, x[:, 0]), {
+        "experts": jnp.stack(experts), "load": jnp.stack(loads)}
+
+
+class CachedModel:
+    """This decoder behind the interface ``models.generate.GreedyGenerator``
+    decodes through (``generate.Qwen3Cached`` says what it is); no
+    adapters here, ``task_index`` is accepted and unused."""
+
+    def __init__(self, config: Lfm2MoeConfig) -> None:
+        self.config = config
+
+    def prefill(self, params, ids, lengths, cache_len: int, task_index):
+        return prefill(self.config, params, ids, lengths, cache_len)
+
+    def decode(self, params, cache, tokens, positions, task_index):
+        return decode(self.config, params, cache, tokens, positions)
+
+    @staticmethod
+    def cache_bytes(cache) -> Dict[str, int]:
+        """The cache's bytes by kind of state."""
+        def size(tree):
+            return sum(int(a.size) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(tree))
+
+        return {"kv": size(cache["kv"]), "conv": size(cache["conv"])}

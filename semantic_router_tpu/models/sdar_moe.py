@@ -20,13 +20,17 @@ capacity.
 
 Functions over a plain parameter tree, not flax modules: the experts are
 stacked ``[experts held, H, 2I]`` / ``[experts held, I, H]`` arrays that a
-grouped matmul indexes by group.  Scopes on the device timeline:
+grouped matmul indexes by group.  ``routed_experts`` is the ONE expert layer
+(sort, grouped matmuls, combine, ``load``) of every sparse decoder; only the
+router in front of it differs (softmax here, sigmoid and a selection bias
+in ``models/lfm2_moe.py``).  Scopes on the device timeline:
 ``embed_tokens``, ``layers_<i>/attn``, ``layers_<i>/moe`` (``moe/router``,
 ``moe/gmm``), ``lm_head``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -94,9 +98,11 @@ class SdarMoeConfig:
 # -- parameters ------------------------------------------------------------------
 
 
-def params_from_checkpoint(path: str, cfg: SdarMoeConfig) -> Dict[str, Any]:
-    """A checkpoint directory (``model.safetensors``, or sharded files
-    with ``model.safetensors.index.json``) as this module's tree."""
+@contextlib.contextmanager
+def checkpoint_reader(path: str):
+    """``get(name) -> tensor`` over a checkpoint directory
+    (``model.safetensors``, or sharded files with
+    ``model.safetensors.index.json``), one tensor loaded per call."""
     from safetensors import safe_open
 
     index = os.path.join(path, "model.safetensors.index.json")
@@ -115,9 +121,15 @@ def params_from_checkpoint(path: str, cfg: SdarMoeConfig) -> Dict[str, Any]:
         return handles[fname].get_tensor(name)
 
     try:
-        return params_from_state(get, cfg)
+        yield get
     finally:
         handles.clear()
+
+
+def params_from_checkpoint(path: str, cfg: SdarMoeConfig) -> Dict[str, Any]:
+    """A checkpoint directory as this module's tree."""
+    with checkpoint_reader(path) as get:
+        return params_from_state(get, cfg)
 
 
 def params_from_state(get: Callable[[str], np.ndarray], cfg: SdarMoeConfig
@@ -192,6 +204,13 @@ def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
+# (k, n) of an expert matrix -> (k tile, n tile) where a sweep on the chip
+# beat the rule below: whole-K tiles at the lfm2_moe widths
+# (benchmarks/lfm2_gmm_sweep.py, PERF.md section 6, PR 32: a prefill row's
+# gate+up 3.23 -> 2.51 ms, down 2.30 -> 1.33 ms; a decode forward the same)
+_SWEPT_TILES = {(2048, 3072): (2048, 1024), (1536, 2048): (1536, 1024)}
+
+
 def _megablox(lhs, rhs, group_sizes):
     """The Pallas megablox kernel (interpreted on the CPU, for tests).
     Rows a tile: 128 reads a touched expert's matrices once for its handful
@@ -201,7 +220,8 @@ def _megablox(lhs, rhs, group_sizes):
 
     m, k = lhs.shape
     n = rhs.shape[-1]
-    tiling = (min(256 if m >= 8192 else 128, m), min(1024, k), min(768, n))
+    tk, tn = _SWEPT_TILES.get((k, n), (min(1024, k), min(768, n)))
+    tiling = (min(256 if m >= 8192 else 128, m), tk, tn)
     return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
                tiling=tiling, interpret=_on_cpu())
 
@@ -219,20 +239,20 @@ def _grouped_matmul(lhs, rhs, group_sizes):
     return _megablox(lhs, rhs, group_sizes)
 
 
-def moe(cfg: SdarMoeConfig, p, x, valid):
-    """``x [T, H]``, ``valid [T]`` (a padding token routes nowhere).
-    Returns ``(y [T, H], top_e [T, k], load [4])`` with ``load`` = the
-    busiest held expert's pairs, the pairs computed here, the number of
-    held experts that got any, and the busiest's pairs over the mean."""
+def routed_experts(p, x, valid, top_e, top_w, held: Tuple[int, int], dtype):
+    """The expert layer behind any router: ``x [T, H]``, ``valid [T]`` (a
+    padding token routes nowhere), the router's choice ``top_e [T, k]``
+    with its weights ``top_w [T, k]`` (float32), ``held = (first, count)``
+    the experts whose matrices ``p["gate_up"] [count, H, 2I]`` and
+    ``p["down"] [count, I, H]`` are.  The routed pairs are sorted by
+    expert, the pairs of held experts go through the grouped matmuls, and
+    each token's results come back weighted and summed.  Returns ``(y [T,
+    H], load [4])`` with ``load`` = the busiest held expert's pairs, the
+    pairs computed here, the number of held experts that got any, and the
+    busiest's pairs over the mean."""
     T, H = x.shape
-    k, I = cfg.num_experts_per_tok, cfg.moe_intermediate_size
-    first, count = cfg.held
-    with jax.named_scope("router"):
-        logits = jnp.dot(x, p["router"], preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_e = jax.lax.top_k(probs, k)
-        if cfg.norm_topk_prob:
-            top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    k, I = top_e.shape[-1], p["down"].shape[-2]
+    first, count = held
     with jax.named_scope("sort"):
         local = top_e.reshape(-1) - first
         here = (local >= 0) & (local < count) & jnp.repeat(valid, k)
@@ -244,10 +264,10 @@ def moe(cfg: SdarMoeConfig, p, x, valid):
     with jax.named_scope("gmm"):
         gu = _grouped_matmul(xs, p["gate_up"], group_sizes)
         h = (jax.nn.silu(gu[:, :I].astype(jnp.float32))
-             * gu[:, I:].astype(jnp.float32)).astype(cfg.dtype)
+             * gu[:, I:].astype(jnp.float32)).astype(dtype)
         ys = _grouped_matmul(h, p["down"], group_sizes)
     with jax.named_scope("combine"):
-        w = jnp.where(here, top_p.reshape(-1), 0.0)
+        w = jnp.where(here, top_w.reshape(-1), 0.0)
         # rows past the held groups were not computed: whatever stands
         # there is dropped, not weighted by zero
         ys = jnp.where(jnp.take(here, order)[:, None],
@@ -259,7 +279,20 @@ def moe(cfg: SdarMoeConfig, p, x, valid):
     load = jnp.stack([busiest, pairs, (group_sizes > 0).sum(),
                       busiest * count / jnp.maximum(pairs, 1)]
                      ).astype(jnp.float32)
-    return y.astype(cfg.dtype), top_e, load
+    return y.astype(dtype), load
+
+
+def moe(cfg: SdarMoeConfig, p, x, valid):
+    """``x [T, H]`` through the softmax router and ``routed_experts``.
+    Returns ``(y [T, H], top_e [T, k], load [4])``."""
+    with jax.named_scope("router"):
+        logits = jnp.dot(x, p["router"], preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+        if cfg.norm_topk_prob:
+            top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    y, load = routed_experts(p, x, valid, top_e, top_p, cfg.held, cfg.dtype)
+    return y, top_e, load
 
 
 def _moe_block(cfg, p, x, valid):

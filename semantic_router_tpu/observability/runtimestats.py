@@ -190,6 +190,11 @@ class RuntimeStats:
         self.gen_tokens = registry.counter(
             "llm_runtime_gen_tokens_committed_total",
             "Tokens committed to the cache by generative tasks")
+        self.gen_cache_bytes = registry.gauge(
+            "llm_runtime_gen_cache_bytes",
+            "Bytes of a generative task's newest cache by kind of state "
+            "(kv: keys and values that grow with the context; conv: "
+            "fixed-size recurrent state)")
         self.rss_bytes = registry.gauge(
             "llm_process_rss_bytes", "Router process resident set size")
         self.threads = registry.gauge(
@@ -234,7 +239,8 @@ class RuntimeStats:
 
     def record_generation(self, task: str, flavour: str,
                           committed_blocks: int = 0,
-                          committed_tokens: int = 0) -> None:
+                          committed_tokens: int = 0,
+                          cache_bytes=None) -> None:
         """One forward of a generation (the engine's generative runner):
         llm_runtime_gen_forwards_total by flavour, and what the forward
         FINISHED in llm_runtime_gen_blocks_committed_total and
@@ -245,10 +251,13 @@ class RuntimeStats:
         about a forward is its ``record_step`` sample (group
         ``gen:<task>``, the flavour as variant) and, under a profiler
         session, its ``engine.step`` and ``engine.gen.forward``
-        annotations (expert load among them)."""
+        annotations (expert load among them).  ``cache_bytes`` (a
+        prefill's, by kind) sets llm_runtime_gen_cache_bytes."""
         if not self.enabled:
             return
         self.gen_forwards.inc(task=task, flavour=flavour)
+        for kind, size in (cache_bytes or {}).items():
+            self.gen_cache_bytes.set(size, task=task, kind=kind)
         if committed_blocks:
             self.gen_blocks.inc(committed_blocks, task=task)
         if committed_tokens:

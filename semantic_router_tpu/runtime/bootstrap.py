@@ -63,35 +63,50 @@ def select_attention_impl(engine_cfg, max_seq_len: int,
     return "dense"
 
 
+GENERATIVE_MODEL_TYPES = ("sdar_moe", "lfm2_moe", "qwen3")
+
+
 def build_generator(spec: dict, hf_cfg: dict, path: str, tokenizer,
                     load_state):
     """The generator of a ``kind: generative`` task, by the checkpoint's
     ``model_type``; every model number comes from the checkpoint's
     ``config.json`` through the architecture's own ``from_hf`` (dtype from
     ``torch_dtype``), the generation settings from the task's
-    ``generation:`` block.  Returns ``(generator, adapter index)``.
+    ``generation:`` block.  Returns ``(generator, adapter index)``.  Three
+    types are served, any other is refused by name:
 
     ``sdar_moe``: sparse experts, generation by diffusion over blocks
     (``generation: {block_length, denoising_steps, confidence_threshold,
-    mask_token_id, gen_length}``; ``experts_held: [first, count]`` for a
-    chip's share of an expert-parallel layer).  Anything else: the dense
-    Qwen3 causal LM with KV-cached greedy decoding and per-request LoRA
-    adapters (``adapters:``, ``lora: {rank, alpha}``; ``generation:
-    {gen_length}``).  ``gen_length`` is the length of a guard's verdict:
-    what ``engine.guard_classify`` asks for and ``engine.warmup`` compiles."""
+    mask_token_id, gen_length}``).
+    ``lfm2_moe``: short-convolution and attention layers over one hybrid
+    cache, sigmoid-and-bias expert routing, greedy decoding a token at a
+    time (``generation: {gen_length}``).
+    ``qwen3``: the dense Qwen3 causal LM, the same token-at-a-time loop
+    with per-request LoRA adapters (``adapters:``, ``lora: {rank, alpha}``;
+    ``generation: {gen_length}``).
+
+    Both expert models take ``experts_held: [first, count]`` for a chip's
+    share of an expert-parallel layer.  ``gen_length`` is the length of a
+    guard's verdict: what ``engine.guard_classify`` asks for and
+    ``engine.warmup`` compiles."""
     from types import SimpleNamespace
 
     eos_raw = spec.get("eos_token_ids") or hf_cfg.get("eos_token_id", 0)
     # HF configs carry int OR list (Qwen family uses a list)
     eos = list(eos_raw) if isinstance(eos_raw, (list, tuple)) else [eos_raw]
     generation = dict(spec.get("generation") or {})
-    if hf_cfg.get("model_type") == "sdar_moe":
+    model_type = hf_cfg.get("model_type")
+    if model_type not in GENERATIVE_MODEL_TYPES:
+        raise ValueError(
+            f"a generative task's checkpoint says model_type "
+            f"{model_type!r}; served: {', '.join(GENERATIVE_MODEL_TYPES)}")
+    held = spec.get("experts_held")
+    held = tuple(held) if held else None
+    if model_type == "sdar_moe":
         from ..models.generate import BlockDiffusionGenerator
         from ..models.sdar_moe import SdarMoeConfig, params_from_checkpoint
 
-        held = spec.get("experts_held")
-        mcfg = SdarMoeConfig.from_hf(
-            hf_cfg, experts_held=tuple(held) if held else None)
+        mcfg = SdarMoeConfig.from_hf(hf_cfg, experts_held=held)
         unknown = set(generation) - {
             "block_length", "denoising_steps", "confidence_threshold",
             "mask_token_id", "gen_length"}
@@ -103,7 +118,22 @@ def build_generator(spec: dict, hf_cfg: dict, path: str, tokenizer,
             mcfg, params_from_checkpoint(path, mcfg), tokenizer,
             eos_token_ids=eos, **generation), {}
 
-    from ..models.generate import GreedyGenerator, with_lora_leaves
+    from ..models.generate import GreedyGenerator
+
+    if set(generation) - {"gen_length"}:
+        raise ValueError(f"generation settings of a token-at-a-time "
+                         f"generative task: gen_length only, not "
+                         f"{sorted(generation)}")
+    if model_type == "lfm2_moe":
+        from ..models import lfm2_moe
+
+        mcfg = lfm2_moe.Lfm2MoeConfig.from_hf(hf_cfg, experts_held=held)
+        return GreedyGenerator(
+            mcfg, lfm2_moe.params_from_checkpoint(path, mcfg), tokenizer,
+            eos_token_ids=eos, model=lfm2_moe.CachedModel(mcfg),
+            **generation), {}
+
+    from ..models.generate import with_lora_leaves
     from ..models.lora import LoRAConfig
     from ..models.qwen3 import Qwen3Config, qwen3_params_from_state_dict
 
@@ -118,9 +148,6 @@ def build_generator(spec: dict, hf_cfg: dict, path: str, tokenizer,
     qparams = qwen3_params_from_state_dict(load_state(path), wrap="model")
     if lora is not None:
         qparams = with_lora_leaves(qcfg, lora, qparams)
-    if set(generation) - {"gen_length"}:
-        raise ValueError(f"generation settings of a dense generative task: "
-                         f"gen_length only, not {sorted(generation)}")
     return GreedyGenerator(qcfg, qparams, tokenizer, lora=lora,
                            eos_token_ids=eos, **generation), adapters
 
@@ -327,7 +354,7 @@ def build_engine(cfg: RouterConfig, mock: bool = False, registry=None):
                                        adapter_index=adapters)
             component_event("bootstrap", "model_loaded", task=task,
                             kind=kind,
-                            architecture=hf_cfg.get("model_type", "qwen3"))
+                            architecture=hf_cfg["model_type"])
             continue
         if kind == "embedding":
             module = MmBertEmbeddingModel(mcfg)

@@ -288,3 +288,61 @@ class TestEngineGenerativeKind:
             assert out[0].text  # decoded ids joined
         finally:
             eng.shutdown()
+
+
+class TestOneTokenAtATimeLoop:
+    """``GreedyGenerator`` is one loop over a model that owns its cache;
+    the dense decoder is served through ``Qwen3Cached`` (the hybrid one
+    through ``models.lfm2_moe.CachedModel``: ``tests/test_lfm2_moe.py``)."""
+
+    def test_the_dense_decoder_carries_its_trajectory(self, tiny_params):
+        cfg, full, params = tiny_params
+        rows = [np.random.default_rng(8).integers(3, 256, n) for n in (7, 4)]
+        gen = GreedyGenerator(cfg, params, RowTokenizer(rows), top_logits=4)
+        out = gen.generate(["a", "b"], max_new_tokens=5)
+        for row, res in zip(rows, out):
+            n = len(row)
+            traj = res.trajectory
+            assert [e["kind"] for e in traj] == ["prefill"] + ["decode"] * 4
+            assert [e["position"] for e in traj] == list(range(n - 1, n + 4))
+            assert [e["token"] for e in traj] == res.token_ids
+            assert "experts" not in traj[0]  # a dense model routes nothing
+            ids = jnp.asarray([list(row) + res.token_ids[:-1]], jnp.int32)
+            logits = np.asarray(full.apply(params, ids), np.float32)[0]
+            for e in traj:
+                z = logits[e["position"]]
+                assert e["token"] == z.argmax() == e["top_ids"][0]
+                np.testing.assert_allclose(e["top_logits"], z[e["top_ids"]],
+                                           atol=1e-4)
+                np.testing.assert_allclose(
+                    e["lse"], jax.nn.logsumexp(jnp.asarray(z)), atol=1e-4)
+
+    def test_padding_rows_change_nothing(self, tiny_params):
+        cfg, _, params = tiny_params
+        row = np.random.default_rng(9).integers(3, 256, 6)
+        alone = GreedyGenerator(cfg, params, RowTokenizer([row])).generate(
+            ["x"], max_new_tokens=5)[0]
+        gen = GreedyGenerator(cfg, params, RowTokenizer([row]))
+        padded = gen.generate(["x"], max_new_tokens=5, bucket=32,
+                              encodings=[gen.tokenizer.encode("x")],
+                              padded_rows=4)[0]
+        assert padded.token_ids == alone.token_ids
+        assert padded.prompt_tokens == 6
+
+    def test_a_step_reads_back_a_small_report_not_the_vocabulary(
+            self, tiny_params):
+        cfg, _, params = tiny_params
+        row = np.random.default_rng(10).integers(3, 256, 5)
+        gen = GreedyGenerator(cfg, params, RowTokenizer([row]))
+        gen.generate(["x"], max_new_tokens=3)
+        (prefill,), (step,) = (gen._prefill_cache.values(),
+                               gen._step_cache.values())
+        args = (params, jnp.zeros((1, 32), jnp.int32),
+                jnp.ones(1, jnp.int32), jnp.asarray(0))
+        cache, tokens, report, aux = jax.eval_shape(prefill, *args)
+        assert report.shape == (1, 2 + 2 * 8) and tokens.shape == (1,)
+        assert aux == {}
+        out = jax.eval_shape(step, params, cache, tokens, tokens,
+                             jnp.asarray(0))
+        assert max(int(np.prod(a.shape)) for a in
+                   jax.tree_util.tree_leaves(out[1:])) < cfg.vocab_size

@@ -1,0 +1,500 @@
+"""The lfm2_moe family at a toy size on the CPU (hidden 64, 5 layers: conv
+with a dense MLP, then attention, conv, conv, attention with 8 experts
+top-2 behind the sigmoid router): the program's layers, prefill and decode
+through the hybrid cache against the plain reference
+(``chipbench/reference/lfm2_moe.py``) on seeded float32 weights, the
+router's bias, the expert-parallel share under both routers, and the one
+token-at-a-time loop through the engine's batcher.
+
+Tolerances: both sides compute in float32 here and differ only in the order
+of their sums, so logits of size 1-15 agree to 2e-4; bfloat16 would miss
+that by two orders of magnitude, which ``chipbench``'s limits hold on the
+chip."""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import cells
+from semantic_router_tpu.models import lfm2_moe as M
+from semantic_router_tpu.models import sdar_moe
+from semantic_router_tpu.models.generate import GreedyGenerator
+from semantic_router_tpu.utils.tokenization import Encoding
+
+MODEL = {
+    "model_type": "lfm2_moe", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 96, "moe_intermediate_size": 32,
+    "num_hidden_layers": 5, "num_dense_layers": 1,
+    "layer_types": ["conv", "full_attention", "conv", "conv",
+                    "full_attention"],
+    "num_attention_heads": 4, "num_key_value_heads": 2, "conv_L_cache": 3,
+    "conv_bias": False, "norm_eps": 1e-5,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "max_position_embeddings": 512, "num_experts": 8,
+    "num_experts_per_tok": 2, "norm_topk_prob": True,
+    "use_expert_bias": True, "routed_scaling_factor": 1,
+    "tie_word_embeddings": True, "torch_dtype": "float32"}
+CONFIG = {
+    "family": "hybrid_ar_guard", "model": MODEL,
+    "weights": {"std": 0.08, "embed_std": 0.3, "conv_std": 0.5,
+                "qk_norm": 1.5, "router_std": 0.3,
+                "router_row_log_std": 0.3, "expert_bias_std": 0.1,
+                "writer_threads": 2},
+    "tasks": {"jailbreak": {"kind": "generative"}},
+    "route_margin": 0.01, "route_sample": 8}
+ATOL = 2e-4
+
+family = cells.load_family(CONFIG)
+ref = cells.load_module("reference", "lfm2_moe")
+
+
+class WordTokenizer:
+    """``w<id>`` is token ``id``, any other piece is token 1."""
+
+    def encode(self, text, max_length=0):
+        ids = [int(w[1:]) if w[0] == "w" and w[1:].isdigit() else 1
+               for w in family.base.PIECES.findall(text)]
+        return Encoding(ids=ids, attention_mask=[1] * len(ids),
+                        offsets=[(0, 0)] * len(ids))
+
+    def decode(self, ids):
+        return " ".join(f"w{int(i)}" for i in ids)
+
+
+def words(ids) -> str:
+    return " ".join(f"w{int(i)}" for i in ids)
+
+
+def variant(**changes):
+    """(model numbers, state, config, params) of the toy with ``changes``."""
+    model = dict(MODEL, **changes)
+    state = family.generate_state(dict(CONFIG, model=model), 7)
+    cfg = M.Lfm2MoeConfig.from_hf(model)
+    return model, state, cfg, M.params_from_state(state.__getitem__, cfg)
+
+
+@pytest.fixture(scope="module")
+def toy():
+    return variant()
+
+
+def prompts(seed: int, lengths):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(2, 250, n) for n in lengths]
+
+
+def padded(rows, bucket: int, pad: int = 0):
+    ids = np.full((len(rows), bucket), pad, np.int32)
+    for i, r in enumerate(rows):
+        ids[i, :len(r)] = r
+    return jnp.asarray(ids), jnp.asarray([len(r) for r in rows], jnp.int32)
+
+
+# -- the layers, one kind at a time -----------------------------------------------
+
+
+@pytest.mark.parametrize("kind, dense", [
+    ("conv", 1), ("conv", 0), ("full_attention", 1), ("full_attention", 0)],
+    ids=["conv+mlp", "conv+moe", "attn+mlp", "attn+moe"])
+def test_one_layer_of_each_kind_equals_the_reference(kind, dense):
+    """Two layers, the second always sparse (the reference stacks what the
+    expert layers chose), the first of the kind under test."""
+    model, state, cfg, params = variant(
+        num_hidden_layers=2, num_dense_layers=dense,
+        layer_types=[kind, "conv"])
+    (row,) = prompts(3, (11,))
+    ids, lengths = padded([row], 11)
+    _, logits, aux = M.prefill(cfg, params, ids, lengths, 16)
+    want = ref.forward(model, state, row, [10])
+    np.testing.assert_allclose(np.asarray(logits), want["logits"], atol=ATOL)
+    assert (np.sort(np.asarray(aux["experts"])[:, 0], -1)
+            == np.sort(want["top_e"], -1)).all()
+    assert aux["experts"].shape[0] == 2 - dense
+
+
+def test_the_full_forward_equals_the_reference_at_every_position(toy):
+    """Every prefix of one prompt: the prefill's last-position logits are
+    the reference's full forward at that position."""
+    model, state, cfg, params = toy
+    (row,) = prompts(4, (12,))
+    want = ref.forward(model, state, row)
+    fn = jax.jit(lambda ids, n: M.prefill(cfg, params, ids, n, 16)[1])
+    ids, _ = padded([row], 12)
+    for n in range(1, 13):
+        got = fn(ids, jnp.asarray([n], jnp.int32))
+        np.testing.assert_allclose(np.asarray(got)[0], want["logits"][n - 1],
+                                   atol=ATOL)
+
+
+# -- prefill, then decoding through the hybrid cache --------------------------------
+
+
+def _decode_greedily(cfg, params, rows, bucket, steps, pad=0, extra_rows=0):
+    """Prefill + ``steps`` decode forwards on rows of their own lengths
+    (+ ``extra_rows`` padding rows): per row the tokens chosen and the
+    logits that chose them, and the prefill's aux."""
+    rows = list(rows) + [[]] * extra_rows
+    ids, lengths = padded(rows, bucket, pad)
+    cache, logits, aux = M.prefill(cfg, params, ids, lengths,
+                                   bucket + steps + 1)
+    step = jax.jit(lambda c, t, p: M.decode(cfg, params, c, t, p))
+    all_logits, tokens, positions = [np.asarray(logits)], [], lengths
+    for _ in range(steps):
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        tokens.append(np.asarray(tok))
+        cache, logits, _ = step(cache, tok, positions)
+        all_logits.append(np.asarray(logits))
+        positions = positions + 1
+    return np.stack(tokens, 1), np.stack(all_logits, 1), aux, cache
+
+
+def test_prefill_then_decode_equal_the_full_forward(toy):
+    """Rows of DIFFERENT lengths and a padding row in one batch: every
+    row's logits, step by step, are the reference's one causal forward over
+    its prompt and its served tokens — the conv state was taken at each
+    row's true last token, every K and V stands at its own position."""
+    model, state, cfg, params = toy
+    rows = prompts(5, (5, 14, 9))
+    tokens, logits, aux, cache = _decode_greedily(cfg, params, rows, 16, 6,
+                                                  extra_rows=1)
+    for i, row in enumerate(rows):
+        n = len(row)
+        want = ref.forward(model, state, np.concatenate([row, tokens[i]]),
+                           list(range(n - 1, n + 6)))
+        np.testing.assert_allclose(logits[i], want["logits"], atol=ATOL)
+        assert (np.sort(np.asarray(aux["experts"])[:, i, :n], -1)
+                == np.sort(want["top_e"][:, :n], -1)).all()
+    # two kinds of state side by side: 2 attention layers of K and V,
+    # 3 conv layers of 2 vectors a row
+    assert M.CachedModel.cache_bytes(cache) == {
+        "kv": 2 * 2 * 4 * 2 * 23 * 16 * 4, "conv": 3 * 4 * 2 * 64 * 4}
+
+
+def test_padding_is_never_seen(toy):
+    _, _, cfg, params = toy
+    rows = prompts(6, (4, 13))
+    a = _decode_greedily(cfg, params, rows, 16, 3, pad=0)
+    b = _decode_greedily(cfg, params, rows, 16, 3, pad=77, extra_rows=2)
+    assert (a[0] == b[0][:2]).all()
+    np.testing.assert_allclose(a[1], b[1][:2], atol=1e-5)
+    # a padding row routes nowhere: the pairs are the real tokens' alone
+    assert float(np.asarray(b[2]["load"])[0, 1]) == (4 + 13) * 2
+
+
+def test_a_batch_equals_its_rows_one_at_a_time(toy):
+    _, _, cfg, params = toy
+    rows = prompts(7, (6, 15, 10))
+    together = _decode_greedily(cfg, params, rows, 16, 4)
+    for i, row in enumerate(rows):
+        alone = _decode_greedily(cfg, params, [row], 16, 4)
+        assert (alone[0][0] == together[0][i]).all()
+        np.testing.assert_allclose(alone[1][0], together[1][i], atol=ATOL)
+
+
+# -- the router --------------------------------------------------------------------
+
+
+def _route_inputs(toy, n=64):
+    _, state, cfg, params = toy
+    x = jnp.asarray(np.random.default_rng(8).standard_normal((n, 64)),
+                    jnp.float32)
+    return state, cfg, params["layers"][1], x
+
+
+def test_the_router_equals_the_reference(toy):
+    state, cfg, p, x = _route_inputs(toy)
+    top_e, w = M.route(cfg, p, x)
+    _, want_e, want_w = ref.route(
+        MODEL, ref.layer_weights(MODEL, state, 1, "highest")["ff"], x)
+    assert (np.asarray(top_e) == np.asarray(want_e)).all()
+    np.testing.assert_allclose(np.asarray(w), np.asarray(want_w), atol=1e-6)
+
+
+def test_the_bias_moves_the_choice_and_not_the_weights(toy):
+    _, cfg, p, x = _route_inputs(toy)
+    top_e, w = M.route(cfg, p, x)
+    bare_e, _ = M.route(dataclasses.replace(cfg, use_expert_bias=False),
+                        p, x)
+    assert (np.sort(np.asarray(top_e)) != np.sort(np.asarray(bare_e))).any()
+    # a huge bias on one expert: always chosen, and its weight is still
+    # its own UNBIASED sigmoid over the chosen two's sum
+    forced = dict(p, expert_bias=p["expert_bias"].at[3].add(100.0))
+    e3, w3 = M.route(cfg, forced, x)
+    assert (np.asarray(e3)[:, 0] == 3).all()
+    s = np.asarray(jax.nn.sigmoid(x @ p["router"]))
+    chosen = np.take_along_axis(s, np.asarray(e3), -1)
+    np.testing.assert_allclose(
+        np.asarray(w3), chosen / (chosen.sum(-1, keepdims=True) + 1e-6),
+        atol=1e-6)
+
+
+def test_norm_topk_prob_and_its_epsilon_and_the_scaling_factor(toy):
+    _, cfg, p, x = _route_inputs(toy)
+    e, w = M.route(cfg, p, x)
+    s = np.take_along_axis(np.asarray(jax.nn.sigmoid(x @ p["router"])),
+                           np.asarray(e), -1)
+    total = np.asarray(w).sum(-1)
+    np.testing.assert_allclose(total, s.sum(-1) / (s.sum(-1) + 1e-6),
+                               rtol=1e-6)
+    assert (total < 1.0).all()  # the 1e-6 is there
+    _, raw = M.route(dataclasses.replace(cfg, norm_topk_prob=False,
+                                         routed_scaling_factor=2.5), p, x)
+    np.testing.assert_allclose(np.asarray(raw), 2.5 * s, rtol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "megablox"])
+@pytest.mark.parametrize("router", ["softmax", "sigmoid_and_bias"])
+def test_the_shares_add_up_to_the_uncut_layer(toy, router, impl, monkeypatch):
+    """Four chips of two experts each, through the ONE expert layer both
+    decoders call: every share routes over all eight experts (by either
+    router) and computes its own; the four partial results add up to the
+    uncut layer's, in the program and in the reference alike."""
+    if impl == "megablox":
+        monkeypatch.setattr(sdar_moe, "_grouped_matmul", sdar_moe._megablox)
+    x = jnp.asarray(np.random.default_rng(2).standard_normal((128, 64)),
+                    jnp.float32)
+    valid = jnp.ones(128, bool)
+    if router == "softmax":
+        from tests import test_sdar_moe as T
+
+        state = T.family.generate_state(T.CONFIG, 7)
+        cfg = sdar_moe.SdarMoeConfig.from_hf(T.MODEL)
+        p = sdar_moe.params_from_state(state.__getitem__, cfg)["layers"][1]
+        moe = sdar_moe.moe
+        w = T.ref.layer_weights(T.MODEL, state, 1, "highest")["moe"]
+        ref_moe = lambda held: T.ref.moe(T.MODEL, w, x, experts_held=held)
+    else:
+        _, state, cfg, params = toy
+        p, moe = params["layers"][1], M.moe
+        w = ref.layer_weights(MODEL, state, 1, "highest")["ff"]
+        ref_moe = lambda held: ref.moe(MODEL, w, x, experts_held=held)
+    whole, _, _ = moe(cfg, p, x, valid)
+    total, ref_total = 0.0, 0.0
+    for first in (0, 2, 4, 6):
+        held = dataclasses.replace(cfg, experts_held=(first, 2))
+        share = dict(p, gate_up=p["gate_up"][first:first + 2],
+                     down=p["down"][first:first + 2])
+        part, top_e, load = moe(held, share, x, valid)
+        ref_part = ref_moe((first, 2))[0]
+        np.testing.assert_allclose(np.asarray(part), np.asarray(ref_part),
+                                   atol=ATOL)
+        assert float(load[1]) == ((np.asarray(top_e) >= first)
+                                  & (np.asarray(top_e) < first + 2)).sum()
+        total, ref_total = total + part, ref_total + ref_part
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               atol=ATOL)
+    np.testing.assert_allclose(np.asarray(ref_total),
+                               np.asarray(ref_moe(None)[0]), atol=ATOL)
+
+
+def test_params_hold_only_the_experts_held(toy):
+    _, state, cfg, _ = toy
+    held = dataclasses.replace(cfg, experts_held=(4, 2))
+    read = []
+
+    def get(name):
+        read.append(name)
+        return state[name]
+
+    params = M.params_from_state(get, held)
+    assert params["layers"][1]["gate_up"].shape == (2, 64, 64)
+    assert params["layers"][0]["gate_up"].shape == (64, 192)  # the dense MLP
+    experts = {n.split("experts.")[1].split(".")[0] for n in read
+               if "experts." in n}
+    assert experts == {"4", "5"}
+
+
+# -- the checkpoint's config.json ----------------------------------------------------
+
+
+def test_every_model_number_comes_from_the_checkpoints_config():
+    cfg = M.Lfm2MoeConfig.from_hf(dict(
+        MODEL, norm_eps=3e-4, routed_scaling_factor=1.5,
+        norm_topk_prob=False, use_expert_bias=False, conv_L_cache=4,
+        num_dense_layers=2, torch_dtype="bfloat16",
+        rope_parameters={"rope_theta": 5e5, "rope_type": "default"}),
+        experts_held=(2, 4))
+    assert (cfg.norm_eps, cfg.routed_scaling_factor, cfg.norm_topk_prob,
+            cfg.use_expert_bias, cfg.conv_L_cache, cfg.num_dense_layers,
+            cfg.rope_theta, cfg.head_dim, cfg.held) == \
+        (3e-4, 1.5, False, False, 4, 2, 5e5, 16, (2, 4))
+    assert cfg.dtype == jnp.bfloat16
+    assert cfg.layer_types == tuple(MODEL["layer_types"])
+
+
+@pytest.mark.parametrize("changes, says", [
+    ({"conv_bias": True}, "conv_bias"),
+    ({"rope_parameters": {"rope_type": "yarn", "rope_theta": 1e6}}, "RoPE"),
+    ({"layer_types": ["conv"] * 4}, "layer_types"),
+    ({"layer_types": ["conv", "sliding_attention"] + ["conv"] * 3},
+     "layer_types")])
+def test_what_the_architecture_cannot_express_is_refused(changes, says):
+    with pytest.raises(ValueError, match=says):
+        M.Lfm2MoeConfig.from_hf(dict(MODEL, **changes))
+
+
+def test_an_unknown_model_type_is_refused_by_name():
+    from semantic_router_tpu.runtime.bootstrap import build_generator
+
+    with pytest.raises(ValueError, match=r"'mamba2'.*sdar_moe, lfm2_moe, "
+                                         r"qwen3"):
+        build_generator({}, dict(MODEL, model_type="mamba2"), "", None,
+                        lambda path: {})
+
+
+# -- the one token-at-a-time loop ---------------------------------------------------
+
+
+def generator(toy, **kw) -> GreedyGenerator:
+    _, _, cfg, params = toy
+    return GreedyGenerator(cfg, params, WordTokenizer(),
+                           model=M.CachedModel(cfg), gen_length=6,
+                           top_logits=4, **kw)
+
+
+def test_the_loop_serves_the_hybrid_decoder_with_its_trajectory(toy):
+    model, state, _, _ = toy
+    rows = prompts(9, (7, 12))
+    out = generator(toy).generate([words(r) for r in rows], max_new_tokens=6)
+    for row, res in zip(rows, out):
+        n = len(row)
+        assert res.prompt_tokens == n and len(res.token_ids) == 6
+        traj = res.trajectory
+        assert [e["kind"] for e in traj] == ["prefill"] + ["decode"] * 5
+        assert [e["position"] for e in traj] == list(range(n - 1, n + 5))
+        assert [e["token"] for e in traj] == res.token_ids
+        assert traj[0]["experts"].shape == (4, n, 2)
+        assert traj[1]["experts"].shape == (4, 1, 2)
+        want = ref.forward(model, state,
+                           np.concatenate([row, res.token_ids[:-1]]),
+                           [e["position"] for e in traj])
+        for e, z in zip(traj, want["logits"]):
+            assert e["token"] == z.argmax() == e["top_ids"][0]
+            np.testing.assert_allclose(e["top_logits"],
+                                       z[e["top_ids"]], atol=ATOL)
+            np.testing.assert_allclose(
+                e["lse"], jax.nn.logsumexp(jnp.asarray(z)), atol=ATOL)
+
+
+def test_a_step_reads_back_a_small_report_not_the_vocabulary(toy):
+    gen = generator(toy)
+    gen.generate([words(prompts(10, (5,))[0])], max_new_tokens=3)
+    (step,) = gen._step_cache.values()
+    cfg = gen.config
+    cache = jax.eval_shape(
+        lambda: M.prefill(cfg, gen.params, jnp.zeros((1, 32), jnp.int32),
+                          jnp.ones(1, jnp.int32), 64)[0])
+    out = jax.eval_shape(step, gen.params, cache, jnp.zeros(1, jnp.int32),
+                         jnp.zeros(1, jnp.int32), jnp.asarray(0))
+    small = jax.tree_util.tree_leaves(out[1:])
+    assert max(int(np.prod(a.shape)) for a in small) < cfg.vocab_size
+
+
+# -- through the engine and the batcher ----------------------------------------------
+
+
+@pytest.fixture()
+def engine(tmp_path):
+    """A toy ``lfm2_moe`` checkpoint on disk, loaded the way
+    ``build_engine`` loads a ``kind: generative`` task."""
+    from semantic_router_tpu.config.schema import InferenceEngineConfig
+    from semantic_router_tpu.engine.classify import InferenceEngine
+    from semantic_router_tpu.runtime.bootstrap import build_generator
+
+    dirs = family.write_checkpoints(str(tmp_path), CONFIG, 11)
+    with open(os.path.join(dirs["jailbreak"], "config.json")) as f:
+        hf = json.load(f)
+    gen, adapters = build_generator(
+        {"generation": {"gen_length": 6}}, hf, dirs["jailbreak"],
+        WordTokenizer(), None)
+    assert isinstance(gen, GreedyGenerator) and adapters == {}
+    assert gen.config.layer_types == tuple(MODEL["layer_types"])
+    eng = InferenceEngine(InferenceEngineConfig(
+        max_batch_size=4, max_wait_ms=50.0, seq_len_buckets=[64]))
+    eng.register_generative("guard", gen)
+    yield eng
+    eng.shutdown()
+
+
+def test_guard_classify_goes_through_the_batcher_a_step_a_forward(
+        engine, monkeypatch):
+    from semantic_router_tpu.observability import batchtrace
+
+    seen = []
+    real = batchtrace.trace_span
+
+    def spy(name, **facts):
+        seen.append((name, facts))
+        return real(name, **facts)
+
+    monkeypatch.setattr(batchtrace, "trace_span", spy)
+    rs = engine._runtime_stats
+    before = {v: rs.gen_forwards.get(task="guard", flavour=v)
+              for v in ("gen.prefill", "gen.decode")}
+    tokens0 = rs.gen_tokens.get(task="guard")
+    verdict = engine.guard_classify("guard", words(prompts(12, (9,))[0]))
+    assert verdict.safety == "Controversial"  # seeded weights say nothing
+    steps = [f for n, f in seen if n == "engine.step"]
+    assert [s["flavour"] for s in steps] == ["gen.prefill"] \
+        + ["gen.decode"] * 5
+    n_prompt = steps[0]["tokens_real"]
+    assert n_prompt > 9 and steps[0]["group"] == "gen:guard"
+    assert steps[0]["bucket"] * steps[0]["padded_rows"] == 64
+    assert [s["block"] for s in steps[1:]] == [0, 1, 2, 3, 4]
+    assert all(s["tokens_real"] == 1 for s in steps[1:])
+    marks = [f for n, f in seen if n == "engine.gen.forward"]
+    assert [m["flavour"] for m in marks] == [s["flavour"] for s in steps]
+    assert marks[0]["layers"] == 4 and marks[0]["pairs"] == 4 * n_prompt * 2
+    assert marks[1]["pairs"] == 4 * 2 and marks[1]["experts_touched"] == 8
+    assert {v: rs.gen_forwards.get(task="guard", flavour=v) - before[v]
+            for v in before} == {"gen.prefill": 1, "gen.decode": 5}
+    assert rs.gen_tokens.get(task="guard") - tokens0 == 6
+    # the first count of two kinds of state in one cache
+    M_len = 128  # 64 + 6 + 1 rounded up to 64
+    assert rs.gen_cache_bytes.get(task="guard", kind="kv") \
+        == 2 * 2 * 1 * 2 * M_len * 16 * 4
+    assert rs.gen_cache_bytes.get(task="guard", kind="conv") \
+        == 3 * 1 * 2 * 64 * 4
+    rs.flush()
+    fill = [p for p in rs.programs() if p["group"] == "gen:guard"
+            and p["variant"] == "gen.prefill"]
+    assert fill[0]["tokens_padded"] == 64
+
+
+def test_three_rows_through_the_batcher_equal_one_at_a_time(engine):
+    texts = [words(p) for p in prompts(13, (5, 14, 8))]
+    together = engine.generate("guard", texts, max_new_tokens=6)
+    stats = engine.batcher.stats()
+    assert stats["batches"] == 1 and stats["max_batch"] == 3
+    gen = engine._tasks["guard"].generator
+    for text, res in zip(texts, together):
+        alone = gen.generate([text], max_new_tokens=6)[0]
+        assert alone.token_ids == res.token_ids
+        for a, b in zip(alone.trajectory, res.trajectory):
+            np.testing.assert_allclose(a["top_logits"], b["top_logits"],
+                                       atol=ATOL)
+
+
+def test_warmup_compiles_both_programs_of_every_row_count(engine):
+    engine.warmup(batch_sizes=(1, 3))
+    assert [(r["target"], r["bucket"], r["rows"], r["error"])
+            for r in engine.warmup_report()] == [
+        ("gen:guard", 64, 1, ""), ("gen:guard", 64, 3, "")]
+    gen = engine._tasks["guard"].generator
+    keys = (sorted(gen._prefill_cache), sorted(gen._step_cache))
+    assert keys == ([(1, 64, 128), (4, 64, 128)],
+                    [(1, 1, 128), (4, 1, 128)])
+    engine.guard_classify("guard", words(prompts(14, (9,))[0]))
+    engine.generate("guard", [words(p) for p in prompts(15, (6, 7, 8))],
+                    max_new_tokens=gen.gen_length)
+    assert (sorted(gen._prefill_cache), sorted(gen._step_cache)) == keys
+    assert all(f._cache_size() == 1 for f in
+               list(gen._prefill_cache.values())
+               + list(gen._step_cache.values()))

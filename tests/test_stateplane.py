@@ -428,7 +428,14 @@ class TestSharedVectorStore:
             time.sleep(0.05)
         keys = set(b.backend.scan(chunk_prefix))
         assert keys and not (keys & set(stranded))  # reaped + replayed
-        hits = sc.search("contract law agreements", top_k=5)
+        # the replay runs on the breaker's recovery thread: its chunk
+        # rows are on the plane before its doc row and version bump, so
+        # wait for the replica's mirror to see the document
+        while time.time() < deadline:
+            hits = sc.search("contract law agreements", top_k=5)
+            if hits:
+                break
+            time.sleep(0.05)
         assert sum("contract" in h.chunk.text.lower()
                    for h in hits) == 1  # replayed once, no duplicates
         a.close(), b.close()

@@ -373,3 +373,112 @@ class TestBlockDiffusionGuardCompilesForV5e:
         _, _, _, report, experts, _ = jax.eval_shape(commit, *args)
         assert report.shape == (rows, L, 4 + 2 * gen.top_logits)
         assert experts.shape == (1, rows, 2 * L, cfg.num_experts_per_tok)
+
+
+class TestHybridGuardCompilesForV5e:
+    """The lfm2_moe guard at the published widths (hidden 2048, 32 x 64
+    heads over 8 k/v heads, 64 experts of width 1536, dense 11776,
+    vocabulary 65536), bucket 8192."""
+
+    def test_causal_prefill_attention(self, one_chip):
+        """Head size 64, bfloat16, one row's 32 heads over 8192 columns
+        under the plain causal mask: what a mapped prefill row runs."""
+        from semantic_router_tpu.ops.flash_attention import (
+            flash_attention_pallas,
+        )
+
+        qkv = ((1, 32, 8192, 64), jnp.bfloat16)
+        compiled = compile_for(
+            one_chip,
+            functools.partial(flash_attention_pallas, causal=True,
+                              interpret=False),
+            qkv, qkv, qkv, ((1, 8192), jnp.int32))
+        assert "tpu_custom_call" in compiled.as_text()
+
+    @pytest.mark.parametrize("tokens", [8, 8192])
+    def test_expert_layer_grouped_matmul(self, one_chip, monkeypatch, tokens):
+        """The shared expert layer behind the sigmoid router, at THIS
+        model's matrices ([2048, 3072] and [1536, 2048], whole-K tiles): a
+        decode forward of 8 rows (32 pairs), a prefill row (32 k pairs)."""
+        from semantic_router_tpu.models import lfm2_moe, sdar_moe
+
+        monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
+        cfg = lfm2_moe.Lfm2MoeConfig(layer_types=("conv",),
+                                     num_hidden_layers=1, num_dense_layers=0)
+        H, I, E = cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts
+        bf = jnp.bfloat16
+
+        def layer(router, bias, gate_up, down, x, valid):
+            p = {"router": router, "expert_bias": bias, "gate_up": gate_up,
+                 "down": down}
+            return lfm2_moe.moe(cfg, p, x, valid)
+
+        compiled = compile_for(
+            one_chip, layer, ((H, E), bf), ((E,), jnp.float32),
+            ((E, H, 2 * I), bf), ((E, I, H), bf), ((tokens, H), bf),
+            ((tokens,), jnp.bool_))
+        assert compiled.as_text().count("tpu_custom_call") >= 2
+
+    def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch):
+        """The generator's prefill (8 rows mapped inside it) and decode
+        programs over two layers that hold every kind of part (attention
+        with the dense MLP, then a convolution with experts): the
+        prefill's temporaries are one row's, the decode step writes the
+        donated hybrid cache in place and returns a small report."""
+        from semantic_router_tpu.models import lfm2_moe, sdar_moe
+        from semantic_router_tpu.models.generate import GreedyGenerator
+        from semantic_router_tpu.ops import flash_attention as fa
+
+        monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
+        monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+        monkeypatch.setattr(
+            fa, "flash_attention_pallas",
+            functools.partial(fa.flash_attention_pallas, interpret=False))
+        cfg = lfm2_moe.Lfm2MoeConfig(
+            layer_types=("full_attention", "conv"),
+            num_hidden_layers=2, num_dense_layers=1)
+        H, I, E, V, W = (cfg.hidden_size, cfg.moe_intermediate_size,
+                         cfg.num_experts, cfg.vocab_size,
+                         cfg.intermediate_size)
+        rows, S, M = 8, 8192, 8256
+
+        def shape(dims, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+        experts = {"router": shape((H, E)),
+                   "expert_bias": shape((E,), jnp.float32),
+                   "gate_up": shape((E, H, 2 * I)), "down": shape((E, I, H))}
+        norms = {"norm1": shape((H,)), "norm2": shape((H,))}
+        conv = {"in_proj": shape((H, 3 * H)), "conv_w": shape((3, H)),
+                "out_proj": shape((H, H))}
+        attn = {"q_proj": shape((H, 2048)), "k_proj": shape((H, 512)),
+                "v_proj": shape((H, 512)), "o_proj": shape((2048, H)),
+                "q_norm": shape((64,)), "k_norm": shape((64,))}
+        params = {"embed": shape((V, H)), "norm": shape((H,)), "layers": [
+            {**norms, **attn, "gate_up": shape((H, 2 * W)),
+             "down": shape((W, H))},
+            {**norms, **conv, **experts}]}
+        gen = GreedyGenerator(cfg, None, None,
+                              model=lfm2_moe.CachedModel(cfg))
+        args = (params, shape((rows, S), jnp.int32),
+                shape((rows,), jnp.int32), shape((), jnp.int32))
+        prefill = gen._prefill_fn((rows, S, M))
+        compiled = prefill.lower(*args).compile()
+        # one causal flash call and an expert layer's two grouped matmuls
+        assert compiled.as_text().count("tpu_custom_call") >= 3
+        # a row's 32 k pairs and its dense MLP, not eight rows' of them
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2**30
+        cache, _, report, aux = jax.eval_shape(prefill, *args)
+        assert report.shape == (rows, 2 + 2 * gen.top_logits)
+        assert aux["experts"].shape == (1, rows, S, 4)
+        cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
+                                       cache)
+        step = gen._step_fn((rows, 1, M)).lower(
+            params, cache, shape((rows,), jnp.int32),
+            shape((rows,), jnp.int32), shape((), jnp.int32)).compile()
+        mem = step.memory_analysis()
+        sizes = lfm2_moe.CachedModel.cache_bytes(cache)
+        assert sizes == {"kv": 2 * rows * 8 * M * 64 * 2,
+                         "conv": rows * 2 * H * 2}
+        assert mem.alias_size_in_bytes >= sizes["kv"] + sizes["conv"]
+        assert mem.temp_size_in_bytes < 0.1 * 2**30
