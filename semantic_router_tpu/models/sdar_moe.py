@@ -25,7 +25,7 @@ grouped matmul indexes by group.  ``routed_experts`` is the ONE expert layer
 router in front of it differs (softmax here, sigmoid and a selection bias
 in ``models/lfm2_moe.py``).  Scopes on the device timeline:
 ``embed_tokens``, ``layers_<i>/attn``, ``layers_<i>/moe`` (``moe/router``,
-``moe/gmm``), ``lm_head``.
+``moe/sort``, ``moe/gmm``, ``moe/combine``), ``lm_head``.
 """
 
 from __future__ import annotations
@@ -246,35 +246,48 @@ def routed_experts(p, x, valid, top_e, top_w, held: Tuple[int, int], dtype):
     the experts whose matrices ``p["gate_up"] [count, H, 2I]`` and
     ``p["down"] [count, I, H]`` are.  The routed pairs are sorted by
     expert, the pairs of held experts go through the grouped matmuls, and
-    each token's results come back weighted and summed.  Returns ``(y [T,
-    H], load [4])`` with ``load`` = the busiest held expert's pairs, the
-    pairs computed here, the number of held experts that got any, and the
-    busiest's pairs over the mean."""
+    each token's results come back weighted and summed.
+
+    Each pair is moved once each way, in ``dtype``: ``x``'s rows are
+    gathered into sorted order, and the grouped matmuls' rows are gathered
+    back with the k-th choices of all tokens together (``[k, T, H]``: k
+    on the major axis is a view; on a tiled axis it would be a copy), so
+    that ONE fusion reads them and writes ``y``: drop, convert to float32,
+    times the float32 weight, summed over k in index order, one rounding
+    to ``dtype``.  A value converts the same before or after it is moved,
+    so no float32 array of ``pairs`` rows is ever written.  Both gathers'
+    indices are in range by construction (``mode="clip"``: no select
+    against a fill value).  The rows past the held groups are DROPPED by a
+    ``where``, not weighted by zero: the grouped matmul never wrote them,
+    and whatever stands there may be NaN.
+
+    Returns ``(y [T, H], load [4])`` with ``load`` = the busiest held
+    expert's pairs, the pairs computed here, the number of held experts
+    that got any, and the busiest's pairs over the mean."""
     T, H = x.shape
     k, I = top_e.shape[-1], p["down"].shape[-2]
     first, count = held
     with jax.named_scope("sort"):
-        local = top_e.reshape(-1) - first
-        here = (local >= 0) & (local < count) & jnp.repeat(valid, k)
-        group = jnp.where(here, local, count)  # elsewhere: after the last
+        local = top_e - first
+        here = (local >= 0) & (local < count) & valid[:, None]  # [T, k]
+        group = jnp.where(here, local, count).reshape(-1)  # elsewhere: last
         order = jnp.argsort(group, stable=True)
         group_sizes = jnp.bincount(group, length=count + 1)[:count] \
             .astype(jnp.int32)
-        xs = jnp.take(x, order // k, axis=0)
+        xs = jnp.take(x, order // k, axis=0, mode="clip")
     with jax.named_scope("gmm"):
         gu = _grouped_matmul(xs, p["gate_up"], group_sizes)
         h = (jax.nn.silu(gu[:, :I].astype(jnp.float32))
              * gu[:, I:].astype(jnp.float32)).astype(dtype)
         ys = _grouped_matmul(h, p["down"], group_sizes)
     with jax.named_scope("combine"):
-        w = jnp.where(here, top_w.reshape(-1), 0.0)
-        # rows past the held groups were not computed: whatever stands
-        # there is dropped, not weighted by zero
-        ys = jnp.where(jnp.take(here, order)[:, None],
-                       ys.astype(jnp.float32), 0.0)
-        back = jnp.argsort(order)
-        y = (jnp.take(ys, back, axis=0).reshape(T, k, H)
-             * w.reshape(T, k, 1)).sum(1)
+        # where pair (t, j) stands among the sorted rows, j-major
+        back = jnp.argsort(order).reshape(T, k).T
+        ys = jnp.take(ys, back.reshape(-1), axis=0, mode="clip") \
+            .reshape(k, T, H)
+        w = jnp.where(here, top_w, 0.0)
+        y = sum(jnp.where(here[:, j, None], ys[j].astype(jnp.float32), 0.0)
+                * w[:, j, None] for j in range(k))
     busiest, pairs = group_sizes.max(), group_sizes.sum()
     load = jnp.stack([busiest, pairs, (group_sizes > 0).sum(),
                       busiest * count / jnp.maximum(pairs, 1)]

@@ -168,6 +168,105 @@ def test_the_shares_add_up_to_the_uncut_layer(toy, impl, monkeypatch):
                                atol=ATOL)
 
 
+def _routed_experts_before(p, x, valid, top_e, top_w, held, dtype):
+    """``routed_experts``' combine as it was until PR 33, kept here as the
+    statement of the arithmetic: every sorted row to float32 under the
+    mask, a float32 gather back, ``[T, k, H]`` times the weights, summed
+    over k."""
+    T, H = x.shape
+    k, I = top_e.shape[-1], p["down"].shape[-2]
+    first, count = held
+    local = top_e.reshape(-1) - first
+    here = (local >= 0) & (local < count) & jnp.repeat(valid, k)
+    group = jnp.where(here, local, count)
+    order = jnp.argsort(group, stable=True)
+    group_sizes = jnp.bincount(group, length=count + 1)[:count] \
+        .astype(jnp.int32)
+    xs = jnp.take(x, order // k, axis=0)
+    gu = M._grouped_matmul(xs, p["gate_up"], group_sizes)
+    h = (jax.nn.silu(gu[:, :I].astype(jnp.float32))
+         * gu[:, I:].astype(jnp.float32)).astype(dtype)
+    ys = M._grouped_matmul(h, p["down"], group_sizes)
+    w = jnp.where(here, top_w.reshape(-1), 0.0)
+    ys = jnp.where(jnp.take(here, order)[:, None], ys.astype(jnp.float32),
+                   0.0)
+    back = jnp.argsort(order)
+    y = (jnp.take(ys, back, axis=0).reshape(T, k, H)
+         * w.reshape(T, k, 1)).sum(1)
+    return y.astype(dtype)
+
+
+def _a_routed_layer(k, tokens, held, real, dtype, experts=16, H=64, I=32):
+    """Seeded arguments of ``routed_experts``: ``experts`` routed over, the
+    ``held`` of them here, the first ``real`` tokens valid."""
+    keys = jax.random.split(jax.random.PRNGKey(31 * k + tokens), 4)
+    p = {"gate_up": jax.random.normal(keys[0], (held[1], H, 2 * I), dtype),
+         "down": jax.random.normal(keys[1], (held[1], I, H), dtype)}
+    x = jax.random.normal(keys[2], (tokens, H), dtype)
+    top_w, top_e = jax.lax.top_k(jax.nn.softmax(
+        jax.random.normal(keys[3], (tokens, experts))), k)
+    return p, x, jnp.arange(tokens) < real, top_e, top_w, held, dtype
+
+
+# (tokens, held, real tokens): everything here; a held sub-range; padded
+# tokens; a token count that is no multiple of 8 with both
+ROUTED_CASES = [(24, (0, 16), 24), (24, (4, 8), 24), (24, (0, 16), 17),
+                (13, (2, 9), 10)]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("tokens,held,real", ROUTED_CASES)
+@pytest.mark.parametrize("k", [2, 4, 8])
+def test_the_combine_is_the_arithmetic_it_was(k, tokens, held, real, dtype):
+    """Each pair moved once each way in the model's dtype gives, bit for
+    bit, what the float32 copies and the ``[T, k, H]`` sum gave: a value
+    converts the same before or after it is moved, and the sum over k
+    runs in the same order.  (Op by op, as here; inside one compiled
+    program the CPU's compiler may contract a multiply and an add of
+    EITHER form into one rounding.)"""
+    args = _a_routed_layer(k, tokens, held, real, jnp.dtype(dtype))
+    y, load = M.routed_experts(*args)
+    want = _routed_experts_before(*args)
+    assert y.dtype == want.dtype and y.shape == (tokens, 64)
+    assert np.array_equal(np.asarray(y, np.float32),
+                          np.asarray(want, np.float32))
+    top_e = np.asarray(args[3])[:real]
+    counts = np.bincount(top_e.ravel(), minlength=16)[held[0]:sum(held)]
+    assert tuple(np.asarray(load)[:3]) == (counts.max(), counts.sum(),
+                                           (counts > 0).sum())
+    # compiled as one program: to one unit in bfloat16's last place
+    jitted, _ = jax.jit(M.routed_experts, static_argnums=(5, 6))(*args)
+    np.testing.assert_allclose(np.asarray(jitted, np.float32),
+                               np.asarray(want, np.float32), rtol=2 ** -7,
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("tokens,held,real", ROUTED_CASES[1:])
+def test_rows_the_grouped_matmul_never_wrote_cannot_reach_y(
+        tokens, held, real, monkeypatch):
+    """The chip's kernel leaves the rows past the held groups unwritten:
+    with NaN standing there, ``y`` is what it was."""
+    args = _a_routed_layer(4, tokens, held, real, jnp.bfloat16)
+    want, _ = M.routed_experts(*args)
+    grouped = M._grouped_matmul
+    unwritten = []
+
+    def leaves_the_rest_unwritten(lhs, rhs, group_sizes):
+        rows = jnp.arange(lhs.shape[0])[:, None]
+        unwritten.append(lhs.shape[0] - int(group_sizes.sum()))
+        return jnp.where(rows < group_sizes.sum(),
+                         grouped(lhs, rhs, group_sizes), jnp.nan)
+
+    monkeypatch.setattr(M, "_grouped_matmul", leaves_the_rest_unwritten)
+    y, _ = M.routed_experts(*args)
+    assert unwritten[0] > 0 and unwritten[0] == unwritten[1]
+    assert np.isfinite(np.asarray(y, np.float32)).all()
+    assert np.array_equal(np.asarray(y, np.float32),
+                          np.asarray(want, np.float32))
+    if real < tokens:
+        assert np.asarray(y, np.float32)[real:].max() == 0.0
+
+
 def test_params_hold_only_the_experts_held(toy):
     state, cfg, _ = toy
     held = dataclasses.replace(cfg, experts_held=(2, 4))

@@ -66,6 +66,20 @@ def compile_for(sharding, fn, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
+def assert_each_pair_moves_once_each_way(compiled, tokens, k, hidden,
+                                         temp_before):
+    """The expert layer's glue at a prefill row's shape (PR 33): no
+    float32 array of ``pairs`` rows, no ``[T, k, H]`` array (k on a tiled
+    axis is a physical copy; the combine reads ``[k, T, H]``), and
+    temporaries under three quarters of what the float32 copies took."""
+    text = compiled.as_text()
+    assert f"f32[{tokens * k},{hidden}]" not in text
+    assert f"[{tokens},{k},{hidden}]" not in text
+    assert f"bf16[{k},{tokens},{hidden}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.75 * temp_before
+
+
 # padded batches 1..max_batch_size at the short buckets; the batches that
 # fit one chip's HBM at the long ones (32 x 32768 does not: see below)
 FLASH_SHAPES = [(b, s) for s in (128, 512) for b in (1, 2, 4, 8, 16, 32)] \
@@ -327,6 +341,9 @@ class TestBlockDiffusionGuardCompilesForV5e:
             one_chip, layer, ((H, E), bf), ((E, H, 2 * I), bf),
             ((E, I, H), bf), ((tokens, H), bf), ((tokens,), jnp.bool_))
         assert compiled.as_text().count("tpu_custom_call") >= 2
+        if tokens == 8192:  # 65,536 pairs: before PR 33, 1.08 GB
+            assert_each_pair_moves_once_each_way(
+                compiled, tokens, cfg.num_experts_per_tok, H, 1_075_564_544)
 
     @pytest.mark.parametrize("rows", [1, 16])
     def test_the_committing_forward_writes_the_cache_in_place(
@@ -418,6 +435,9 @@ class TestHybridGuardCompilesForV5e:
             ((E, H, 2 * I), bf), ((E, I, H), bf), ((tokens, H), bf),
             ((tokens,), jnp.bool_))
         assert compiled.as_text().count("tpu_custom_call") >= 2
+        if tokens == 8192:  # 32,768 pairs: before PR 33, 0.54 GB
+            assert_each_pair_moves_once_each_way(
+                compiled, tokens, cfg.num_experts_per_tok, H, 538_391_040)
 
     def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch):
         """The generator's prefill (8 rows mapped inside it) and decode
