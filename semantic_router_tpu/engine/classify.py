@@ -281,21 +281,24 @@ class _GenerationObserver:
         return self.step.stage(name)
 
     def done(self, load=None, committed_blocks: int = 0,
-             committed_tokens: int = 0, cache_bytes=None) -> None:
+             committed_tokens: int = 0, cache_bytes=None, keys=None) -> None:
         """``load [layers, 4]`` of an expert model
         (models.sdar_moe.routed_experts); a dense generator gives none.
         ``committed_blocks`` / ``committed_tokens``: what this forward
         FINISHED (a block's last forward says so, whichever forward writes
         its K and V later; a token-at-a-time forward finishes one token a
         live row).  ``cache_bytes``: a prefill's cache by kind of state
-        (``{"kv", "conv"}``)."""
+        (``{"kv", "conv"}``, or a latent cache's ``{"latent", "index",
+        "window"}``).  ``keys [rows, 2]``: of a model with a learned
+        selection, the keys its queries selected and those visible to
+        them, summed on the device over the full layers."""
         from ..observability import batchtrace
 
         step = self.step
         step.ran()
         self.close()
         if load is not None:
-            batchtrace.gen_forward(step.group, step.variant, load)
+            batchtrace.gen_forward(step.group, step.variant, load, keys)
         try:
             self.engine._runtime_stats.record_generation(
                 self.task, step.variant, committed_blocks=committed_blocks,

@@ -482,7 +482,10 @@ class GreedyGenerator:
         ``position`` (of the token whose logits chose), ``token`` (the
         choice), ``lse``, ``top_ids`` / ``top_logits [top]`` (float32) and,
         of an expert model, ``experts [layers, n, k]`` (the router's choice
-        at the prompt's ``n`` tokens, or at the one decoded)."""
+        at the prompt's ``n`` tokens, or at the one decoded); of a model
+        with a learned selection of keys, ``selected`` (bits over the key
+        positions, a row a full layer: of a decode at the token decoded, of
+        a prefill at the prompt positions ``selected_at``)."""
         encs, bucket, padded_rows = _as_batch(
             self.tokenizer, prompts, encodings, bucket, padded_rows)
         obs = observer or NullObserver()
@@ -493,8 +496,9 @@ class GreedyGenerator:
         max_new_tokens = _steps or max_new_tokens
         k = self.top_logits
 
-        def choices(kind: str, at, report, experts) -> None:
+        def choices(kind: str, at, report, aux) -> None:
             """Every live row's entry of one forward."""
+            experts, selected = aux.get("experts"), aux.get("selected")
             for i in range(n):
                 if finished[i]:
                     continue
@@ -505,6 +509,10 @@ class GreedyGenerator:
                 if experts is not None:
                     entry["experts"] = experts[:, i, :lengths[i]] \
                         if kind == "prefill" else experts[:, i, None]
+                if selected is not None:
+                    entry["selected"] = selected[:, i]
+                    if kind == "prefill":
+                        entry["selected_at"] = aux["selected_at"][i]
                 trajectory[i].append(entry)
 
         fwd = obs.forward("gen.prefill", tokens_real=int(lengths.sum()),
@@ -527,9 +535,10 @@ class GreedyGenerator:
         finished = np.zeros(B, bool)
         finished[n:] = True
         with fwd.stage("demux"):
-            choices("prefill", lengths - 1, report, aux.get("experts"))
+            choices("prefill", lengths - 1, report, aux)
         fwd.done(load=aux.get("load"), committed_tokens=n,
-                 cache_bytes=self.model.cache_bytes(cache))
+                 cache_bytes=self.model.cache_bytes(cache),
+                 keys=aux.get("keys"))
 
         step = self._step_fn((B, 1, M))
         for t in range(max_new_tokens):
@@ -549,8 +558,9 @@ class GreedyGenerator:
             with fwd.stage("readback"):
                 report, aux = jax.device_get((report, aux))
             with fwd.stage("demux"):
-                choices("decode", lengths + t, report, aux.get("experts"))
-            fwd.done(load=aux.get("load"), committed_tokens=live)
+                choices("decode", lengths + t, report, aux)
+            fwd.done(load=aux.get("load"), committed_tokens=live,
+                     keys=aux.get("keys"))
         del cache
         return [_finish_tokens(self.tokenizer, out_tokens[i],
                                self.eos_token_ids, stop_strings,

@@ -195,17 +195,30 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: Lfm2MoeConfig
 # -- layers ----------------------------------------------------------------------
 
 
-def route(cfg: Lfm2MoeConfig, p, x):
-    """The sigmoid router on ``x [T, H]``: ``(top_e [T, k], weights [T, k]
-    float32)``.  The bias moves the choice and never the weights."""
-    logits = jnp.dot(x, p["router"], preferred_element_type=jnp.float32)
+def sigmoid_route(x, router, bias, k: int, norm: bool, scaling: float,
+                  eps: float):
+    """The sigmoid-and-bias choice of every decoder that has one (this one
+    and ``models/dots3_note.py``): ``x [T, H]`` -> ``(top_e [T, k], weights
+    [T, k] float32)``.  ``s = sigmoid(x W)`` in float32; the ``k`` largest
+    of ``s + bias`` are chosen (``bias`` None: of ``s``); the weights are
+    the UNBIASED ``s`` there, over ``their sum + eps`` if ``norm``, times
+    ``scaling``.  The bias moves the choice and never the weights."""
+    logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
     s = jax.nn.sigmoid(logits)
-    pick = s + p["expert_bias"] if cfg.use_expert_bias else s
-    _, top_e = jax.lax.top_k(pick, cfg.num_experts_per_tok)
+    pick = s if bias is None else s + bias
+    _, top_e = jax.lax.top_k(pick, k)
     w = jnp.take_along_axis(s, top_e, -1)
-    if cfg.norm_topk_prob:
-        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-6)
-    return top_e, w * cfg.routed_scaling_factor
+    if norm:
+        w = w / (jnp.sum(w, -1, keepdims=True) + eps)
+    return top_e, w * scaling
+
+
+def route(cfg: Lfm2MoeConfig, p, x):
+    """The sigmoid router on ``x [T, H]``."""
+    return sigmoid_route(
+        x, p["router"], p["expert_bias"] if cfg.use_expert_bias else None,
+        cfg.num_experts_per_tok, cfg.norm_topk_prob,
+        cfg.routed_scaling_factor, 1e-6)
 
 
 def moe(cfg: Lfm2MoeConfig, p, x, valid):

@@ -102,7 +102,8 @@ class SdarMoeConfig:
 def checkpoint_reader(path: str):
     """``get(name) -> tensor`` over a checkpoint directory
     (``model.safetensors``, or sharded files with
-    ``model.safetensors.index.json``), one tensor loaded per call."""
+    ``model.safetensors.index.json``), one tensor loaded per call;
+    ``get.rows(name, first, count)`` reads those rows of it alone."""
     from safetensors import safe_open
 
     index = os.path.join(path, "model.safetensors.index.json")
@@ -114,11 +115,17 @@ def checkpoint_reader(path: str):
             where = {k: "model.safetensors" for k in f.keys()}
     handles: Dict[str, Any] = {}
 
-    def get(name: str) -> np.ndarray:
+    def handle(name: str):
         fname = where[name]
         if fname not in handles:
             handles[fname] = safe_open(os.path.join(path, fname), "np")
-        return handles[fname].get_tensor(name)
+        return handles[fname]
+
+    def get(name: str) -> np.ndarray:
+        return handle(name).get_tensor(name)
+
+    get.rows = lambda name, first, count: \
+        handle(name).get_slice(name)[first:first + count]
 
     try:
         yield get

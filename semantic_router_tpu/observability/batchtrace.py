@@ -291,17 +291,23 @@ def queue_wait(trace_id: str, group: str, wait_s: float) -> None:
         pass
 
 
-def gen_forward(group: str, flavour: str, load) -> None:
+def gen_forward(group: str, flavour: str, load, keys=None) -> None:
     """``engine.gen.forward``: a forward of a generation ended now, and
     this is what only its readback knew.  ``load [layers, 4]`` per expert
     layer: the busiest expert's routed pairs, the pairs computed, the
     experts that got any, the busiest's pairs over the mean; the facts are
     ``layers``, ``pairs`` and ``experts_touched`` (sums over the layers)
-    and ``load_milli`` (the ratio's mean over the layers, in thousandths)."""
+    and ``load_milli`` (the ratio's mean over the layers, in thousandths).
+    ``keys [rows, 2]`` of a model with a learned selection of keys adds
+    ``keys_selected`` and ``keys_visible``: what the forward's queries
+    selected and what they could see, over its rows and full layers."""
+    facts = {} if keys is None else {
+        "keys_selected": int(keys[:, 0].sum(dtype="int64")),
+        "keys_visible": int(keys[:, 1].sum(dtype="int64"))}
     with trace_span(GEN_FORWARD_ANNOTATION, group=group, flavour=flavour,
                     layers=len(load), pairs=int(load[:, 1].sum()),
                     experts_touched=int(load[:, 2].sum()),
-                    load_milli=int(load[:, 3].mean() * 1000)):
+                    load_milli=int(load[:, 3].mean() * 1000), **facts):
         pass
 
 

@@ -194,7 +194,9 @@ class RuntimeStats:
             "llm_runtime_gen_cache_bytes",
             "Bytes of a generative task's newest cache by kind of state "
             "(kv: keys and values that grow with the context; conv: "
-            "fixed-size recurrent state)")
+            "fixed-size recurrent state; latent, index: a latent-attention "
+            "layer's compressed keys and its indexer's keys, both growing "
+            "with the context; window: a sliding layer's ring of latents)")
         self.rss_bytes = registry.gauge(
             "llm_process_rss_bytes", "Router process resident set size")
         self.threads = registry.gauge(

@@ -93,20 +93,25 @@ def _kv_stop(qi, *, block_q: int, block_k: int, n_kb: int, window: int,
              causal: bool):
     """One past the last K block a query block attends to.  (Block-causal
     calls need no rule of their own: ``block_q`` is a multiple of
-    ``causal_block``, so a query block's last position ends its group.)"""
+    ``causal_block``, so a query block's last position ends its group.)
+    A causal call's band ends at the diagonal, windowed or not."""
     last_q = qi * block_q + block_q - 1
-    if window > 0:
-        return jnp.minimum((last_q + window // 2) // block_k + 1, n_kb)
     if causal:
         return last_q // block_k + 1
+    if window > 0:
+        return jnp.minimum((last_q + window // 2) // block_k + 1, n_kb)
     return n_kb
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
-                  acc_ref, *, scale: float, block_q: int, block_k: int,
-                  n_kb: int, window: int, causal: bool, causal_block: int):
+def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, scale: float,
+                  block_q: int, block_k: int, n_kb: int, window: int,
+                  causal: bool, causal_block: int, selected: bool):
     """One (bh, q-block, kv-step) program: fold one K/V block into the
-    query block's online-softmax state."""
+    query block's online-softmax state.  ``selected``: a
+    ``(block_q, block_k)`` block of the per-query selection comes before
+    the output (0 = this query does not see this key)."""
+    sel_ref = rest[0] if selected else None
+    o_ref, m_ref, l_ref, acc_ref = rest[-4:]
     qi = pl.program_id(1)
     j = pl.program_id(2)
     kb = _kv_start(qi, block_q=block_q, block_k=block_k,
@@ -129,6 +134,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [Bq, Bk]
         s = s + bias_ref[0]                                # [1, Bk]
+        if selected:
+            s = jnp.where(sel_ref[0] != 0, s, NEG_INF)
         shape = (block_q, block_k)
         q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape,
                                                         0)
@@ -165,9 +172,18 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            block_q: Optional[int] = None,
                            block_k: Optional[int] = None,
                            scale: Optional[float] = None,
-                           interpret: Optional[bool] = None) -> jnp.ndarray:
-    """q/k/v: [B, H, S, D]; key_padding_mask: [B, S] (1 = real token).
-    ``window``: ModernBERT-style full window width (0 = global).
+                           interpret: Optional[bool] = None,
+                           select: Optional[jnp.ndarray] = None
+                           ) -> jnp.ndarray:
+    """q/k: [B, H, S, D], v: [B, H, S, Dv] (the output's head size; a
+    latent-attention layer's differs from D); key_padding_mask: [B, S]
+    (1 = real token).
+    ``window``: ModernBERT-style full window width (0 = global); with
+    ``causal`` the band's upper half is gone, so a query sees the
+    ``window // 2 + 1`` latest keys, itself among them.
+    ``select``: [B, S, S] int8, shared by the heads: query i sees key j
+    only where ``select[b, i, j]`` is not 0 (a learned sparse selection),
+    on top of every other rule.
     ``causal_block``: with ``causal``, key j is visible to query i iff
     ``j // causal_block <= i // causal_block`` (1 = plain causal).
     ``block_q`` / ``block_k``: None = the shape's own (``blocks_for``).
@@ -176,6 +192,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     B, H, S, D = q.shape
+    Dv = v.shape[-1]
     if scale is None:
         scale = D ** -0.5
     if block_q is None or block_k is None:
@@ -191,6 +208,8 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         q = jnp.pad(q, zq)
         k = jnp.pad(k, zq)
         v = jnp.pad(v, zq)
+        if select is not None:
+            select = jnp.pad(select, ((0, 0), (0, pad), (0, pad)))
     if key_padding_mask is None:
         bias = jnp.zeros((B, Sp), jnp.float32)
         if pad:
@@ -204,7 +223,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     BH = B * H
     qf = q.reshape(BH, Sp, D)
     kf = k.reshape(BH, Sp, D)
-    vf = v.reshape(BH, Sp, D)
+    vf = v.reshape(BH, Sp, Dv)
     # [B, 1, Sp]: a (1, 1, BLOCK_K) block then satisfies the TPU tiling
     # rule (second-to-last block dim equals the array's) at any batch
     bias = bias[:, None, :]
@@ -213,7 +232,8 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # K steps per query block: every K block when global/causal, only the
     # blocks one window can straddle when windowed
     n_kv = n_kb if window <= 0 else min(
-        n_kb, (block_q - 1 + 2 * (window // 2)) // block_k + 2)
+        n_kb, (block_q - 1 + (1 if causal else 2) * (window // 2))
+        // block_k + 2)
     geom = dict(block_q=block_q, block_k=block_k, window=window)
 
     def kv_block(qi, j):
@@ -224,33 +244,41 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, n_kb=n_kb, causal=causal,
-        causal_block=causal_block, **geom)
+        causal_block=causal_block, selected=select is not None, **geom)
+
+    operands = [qf, kf, vf, bias]
+    in_specs = [
+        pl.BlockSpec((1, block_q, D), lambda bh, qi, j: (bh, qi, 0)),
+        pl.BlockSpec((1, block_k, D),
+                     lambda bh, qi, j: (bh, kv_block(qi, j), 0)),
+        pl.BlockSpec((1, block_k, Dv),
+                     lambda bh, qi, j: (bh, kv_block(qi, j), 0)),
+        pl.BlockSpec((1, 1, block_k),
+                     lambda bh, qi, j: (bh // H, 0, kv_block(qi, j))),
+    ]
+    if select is not None:
+        operands.append(select.astype(jnp.int8))
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda bh, qi, j: (bh // H, qi, kv_block(qi, j))))
 
     out = pl.pallas_call(
         kernel,
         grid=(BH, Sp // block_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, j: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, qi, j: (bh, kv_block(qi, j), 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, qi, j: (bh, kv_block(qi, j), 0)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda bh, qi, j: (bh // H, 0, kv_block(qi, j))),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, block_q, Dv),
                                lambda bh, qi, j: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Sp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((BH, Sp, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qf, kf, vf, bias)
-    return out.reshape(B, H, Sp, D)[:, :, :S, :]
+    )(*operands)
+    return out.reshape(B, H, Sp, Dv)[:, :, :S, :]
 
 
 def _platform_of(x) -> str:
@@ -293,13 +321,21 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal_block: int = 1,
                     scale: Optional[float] = None, mesh=None,
                     batch_axis: str = "dp",
-                    head_axis: Optional[str] = "tp") -> jnp.ndarray:
+                    head_axis: Optional[str] = "tp",
+                    select: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Dispatch: the Pallas kernel on a TPU platform (per shard when the
-    model serves under ``mesh``); the chunked JAX path on CPU."""
+    model serves under ``mesh``); the chunked JAX path on CPU.  ``select``
+    (``flash_attention_pallas`` says what it is) goes with ``causal`` and
+    no mesh."""
+    if select is not None and (mesh is not None or not causal):
+        raise ValueError("a per-query selection is served for causal "
+                         "calls without a mesh")
     if _platform_of(q) == "tpu":
         kw = dict(window=window, causal=causal, causal_block=causal_block,
                   scale=scale)
         if mesh is None:
+            if select is not None:
+                kw["select"] = select
             return flash_attention_pallas(q, k, v, key_padding_mask, **kw)
         if key_padding_mask is None:
             key_padding_mask = jnp.ones((q.shape[0], q.shape[2]),
@@ -315,6 +351,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             bias = bias + padding_bias(key_padding_mask)
         if window > 0:
             bias = bias + sliding_window_bias(S, window)
+        if select is not None:
+            bias = bias + jnp.where(select != 0, 0.0, NEG_INF)[:, None]
         return sdpa(q, k, v, bias=bias, scale=scale)
     return chunked_sdpa(q, k, v, key_padding_mask=key_padding_mask,
                         window=window, scale=scale)

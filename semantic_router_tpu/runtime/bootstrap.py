@@ -63,80 +63,66 @@ def select_attention_impl(engine_cfg, max_seq_len: int,
     return "dense"
 
 
-GENERATIVE_MODEL_TYPES = ("sdar_moe", "lfm2_moe", "qwen3")
+def _held(spec: dict, key: str):
+    """A task's ``<key>: [first, count]`` (a chip's share), or None."""
+    return tuple(spec[key]) if spec.get(key) else None
 
 
-def build_generator(spec: dict, hf_cfg: dict, path: str, tokenizer,
-                    load_state):
-    """The generator of a ``kind: generative`` task, by the checkpoint's
-    ``model_type``; every model number comes from the checkpoint's
-    ``config.json`` through the architecture's own ``from_hf`` (dtype from
-    ``torch_dtype``), the generation settings from the task's
-    ``generation:`` block.  Returns ``(generator, adapter index)``.  Three
-    types are served, any other is refused by name:
-
-    ``sdar_moe``: sparse experts, generation by diffusion over blocks
-    (``generation: {block_length, denoising_steps, confidence_threshold,
-    mask_token_id, gen_length}``).
-    ``lfm2_moe``: short-convolution and attention layers over one hybrid
-    cache, sigmoid-and-bias expert routing, greedy decoding a token at a
-    time (``generation: {gen_length}``).
-    ``qwen3``: the dense Qwen3 causal LM, the same token-at-a-time loop
-    with per-request LoRA adapters (``adapters:``, ``lora: {rank, alpha}``;
-    ``generation: {gen_length}``).
-
-    Both expert models take ``experts_held: [first, count]`` for a chip's
-    share of an expert-parallel layer.  ``gen_length`` is the length of a
-    guard's verdict: what ``engine.guard_classify`` asks for and
-    ``engine.warmup`` compiles."""
-    from types import SimpleNamespace
-
-    eos_raw = spec.get("eos_token_ids") or hf_cfg.get("eos_token_id", 0)
-    # HF configs carry int OR list (Qwen family uses a list)
-    eos = list(eos_raw) if isinstance(eos_raw, (list, tuple)) else [eos_raw]
-    generation = dict(spec.get("generation") or {})
-    model_type = hf_cfg.get("model_type")
-    if model_type not in GENERATIVE_MODEL_TYPES:
-        raise ValueError(
-            f"a generative task's checkpoint says model_type "
-            f"{model_type!r}; served: {', '.join(GENERATIVE_MODEL_TYPES)}")
-    held = spec.get("experts_held")
-    held = tuple(held) if held else None
-    if model_type == "sdar_moe":
-        from ..models.generate import BlockDiffusionGenerator
-        from ..models.sdar_moe import SdarMoeConfig, params_from_checkpoint
-
-        mcfg = SdarMoeConfig.from_hf(hf_cfg, experts_held=held)
-        unknown = set(generation) - {
-            "block_length", "denoising_steps", "confidence_threshold",
-            "mask_token_id", "gen_length"}
-        if unknown or "mask_token_id" not in generation:
-            raise ValueError(
-                f"generation settings of an sdar_moe task: mask_token_id is "
-                f"required, unknown keys {sorted(unknown)}")
-        return BlockDiffusionGenerator(
-            mcfg, params_from_checkpoint(path, mcfg), tokenizer,
-            eos_token_ids=eos, **generation), {}
-
-    from ..models.generate import GreedyGenerator
-
+def _token_at_a_time(generation: dict) -> dict:
     if set(generation) - {"gen_length"}:
         raise ValueError(f"generation settings of a token-at-a-time "
                          f"generative task: gen_length only, not "
                          f"{sorted(generation)}")
-    if model_type == "lfm2_moe":
-        from ..models import lfm2_moe
+    return generation
 
-        mcfg = lfm2_moe.Lfm2MoeConfig.from_hf(hf_cfg, experts_held=held)
+
+def _serve_sdar_moe(spec, hf_cfg, path, tokenizer, load_state, eos,
+                    generation):
+    from ..models.generate import BlockDiffusionGenerator
+    from ..models.sdar_moe import SdarMoeConfig, params_from_checkpoint
+
+    mcfg = SdarMoeConfig.from_hf(hf_cfg,
+                                 experts_held=_held(spec, "experts_held"))
+    unknown = set(generation) - {
+        "block_length", "denoising_steps", "confidence_threshold",
+        "mask_token_id", "gen_length"}
+    if unknown or "mask_token_id" not in generation:
+        raise ValueError(
+            f"generation settings of an sdar_moe task: mask_token_id is "
+            f"required, unknown keys {sorted(unknown)}")
+    return BlockDiffusionGenerator(
+        mcfg, params_from_checkpoint(path, mcfg), tokenizer,
+        eos_token_ids=eos, **generation), {}
+
+
+def _serve_cached(module_name: str, config_name: str, shares=("experts_held",)):
+    """A decoder that owns its cache (``<module>.CachedModel``) under the
+    token-at-a-time loop, with the task's shares of a layer."""
+    def serve(spec, hf_cfg, path, tokenizer, load_state, eos, generation):
+        import importlib
+
+        from ..models.generate import GreedyGenerator
+
+        module = importlib.import_module(f"..models.{module_name}",
+                                         __package__)
+        generation = _token_at_a_time(generation)
+        mcfg = getattr(module, config_name).from_hf(
+            hf_cfg, **{key: _held(spec, key) for key in shares})
         return GreedyGenerator(
-            mcfg, lfm2_moe.params_from_checkpoint(path, mcfg), tokenizer,
-            eos_token_ids=eos, model=lfm2_moe.CachedModel(mcfg),
+            mcfg, module.params_from_checkpoint(path, mcfg), tokenizer,
+            eos_token_ids=eos, model=module.CachedModel(mcfg),
             **generation), {}
+    return serve
 
-    from ..models.generate import with_lora_leaves
+
+def _serve_qwen3(spec, hf_cfg, path, tokenizer, load_state, eos, generation):
+    from types import SimpleNamespace
+
+    from ..models.generate import GreedyGenerator, with_lora_leaves
     from ..models.lora import LoRAConfig
     from ..models.qwen3 import Qwen3Config, qwen3_params_from_state_dict
 
+    generation = _token_at_a_time(generation)
     qcfg = Qwen3Config.from_hf(SimpleNamespace(**hf_cfg))
     adapters = {name: i for i, name in
                 enumerate(spec.get("adapters", []) or [])}
@@ -150,6 +136,67 @@ def build_generator(spec: dict, hf_cfg: dict, path: str, tokenizer,
         qparams = with_lora_leaves(qcfg, lora, qparams)
     return GreedyGenerator(qcfg, qparams, tokenizer, lora=lora,
                            eos_token_ids=eos, **generation), adapters
+
+
+# the served generative model types: ``model_type`` of the checkpoint's
+# ``config.json`` -> (what it is, how it is built); a new type is a row
+GENERATORS = {
+    "sdar_moe": (
+        "sparse experts, generation by diffusion over blocks "
+        "(``generation: {block_length, denoising_steps, "
+        "confidence_threshold, mask_token_id, gen_length}``; "
+        "``experts_held``).", _serve_sdar_moe),
+    "lfm2_moe": (
+        "short-convolution and attention layers over one hybrid cache, "
+        "sigmoid-and-bias expert routing, greedy decoding a token at a "
+        "time (``generation: {gen_length}``; ``experts_held``).",
+        _serve_cached("lfm2_moe", "Lfm2MoeConfig")),
+    "qwen3": (
+        "the dense Qwen3 causal LM, the token-at-a-time loop with "
+        "per-request LoRA adapters (``adapters:``, ``lora: {rank, alpha}``; "
+        "``generation: {gen_length}``).", _serve_qwen3),
+    "dots3_note": (
+        "latent attention over one latent cache (full layers under a "
+        "learned top-k selection of keys, sliding layers over a ring), "
+        "sigmoid-and-bias expert routing beside a shared expert, the same "
+        "loop (``generation: {gen_length}``; ``experts_held``, "
+        "``vocab_held``: ids and logits are then over that slice).",
+        _serve_cached("dots3_note", "Dots3NoteConfig",
+                      ("experts_held", "vocab_held"))),
+}
+GENERATIVE_MODEL_TYPES = tuple(GENERATORS)
+
+
+def build_generator(spec: dict, hf_cfg: dict, path: str, tokenizer,
+                    load_state):
+    """The generator of a ``kind: generative`` task, by the checkpoint's
+    ``model_type`` (``GENERATORS`` is the table of the served types; any
+    other is refused by name before anything is built); every model number
+    comes from the checkpoint's ``config.json`` through the architecture's
+    own ``from_hf`` (dtype from ``torch_dtype``), the generation settings
+    from the task's ``generation:`` block.  Returns ``(generator, adapter
+    index)``.
+
+    ``experts_held: [first, count]`` (and, where the type takes it,
+    ``vocab_held``) is a chip's share of a layer that several chips divide.
+    ``gen_length`` is the length of a guard's verdict: what
+    ``engine.guard_classify`` asks for and ``engine.warmup`` compiles."""
+    eos_raw = spec.get("eos_token_ids") or hf_cfg.get("eos_token_id", 0)
+    # HF configs carry int OR list (Qwen family uses a list)
+    eos = list(eos_raw) if isinstance(eos_raw, (list, tuple)) else [eos_raw]
+    model_type = hf_cfg.get("model_type")
+    if model_type not in GENERATORS:
+        raise ValueError(
+            f"a generative task's checkpoint says model_type "
+            f"{model_type!r}; served: {', '.join(GENERATORS)}")
+    _, serve = GENERATORS[model_type]
+    return serve(spec, hf_cfg, path, tokenizer, load_state, eos,
+                 dict(spec.get("generation") or {}))
+
+
+build_generator.__doc__ += "\n\n    Served:\n" + "".join(
+    f"\n    ``{name}``: {doc}" for name, (doc, _)
+    in GENERATORS.items())
 
 
 def build_engine(cfg: RouterConfig, mock: bool = False, registry=None):

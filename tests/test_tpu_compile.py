@@ -502,3 +502,151 @@ class TestHybridGuardCompilesForV5e:
                          "conv": rows * 2 * H * 2}
         assert mem.alias_size_in_bytes >= sizes["kv"] + sizes["conv"]
         assert mem.temp_size_in_bytes < 0.1 * 2**30
+
+
+class TestSparseLatentGuardCompilesForV5e:
+    """The dots3_note guard at the published widths (hidden 5120; full
+    layers of 128 latent heads, q/k 192 and v 128, under a per-query
+    selection; sliding layers of 64 heads, q/k 256, over 513 keys; 32 of
+    256 experts of width 1536 held beside a shared one; 19,008 vocabulary
+    rows), bucket 8192."""
+
+    @pytest.mark.parametrize("kind", ["full", "window"])
+    def test_prefill_attention_cores(self, one_chip, kind):
+        """One row's heads over 8192 columns, bfloat16, v's head size
+        apart from q/k's: causal under the int8 selection ``[S, S]``, and
+        causal with the window on the Pallas path."""
+        from semantic_router_tpu.ops.flash_attention import (
+            flash_attention_pallas,
+        )
+
+        heads, d = (128, 192) if kind == "full" else (64, 256)
+        qk, v = ((1, heads, 8192, d), jnp.bfloat16), \
+            ((1, heads, 8192, 128), jnp.bfloat16)
+        mask = ((1, 8192), jnp.int32)
+        if kind == "full":
+            compiled = compile_for(
+                one_chip,
+                lambda q, k, v, m, s: flash_attention_pallas(
+                    q, k, v, m, causal=True, select=s, interpret=False),
+                qk, qk, v, mask, ((1, 8192, 8192), jnp.int8))
+        else:
+            compiled = compile_for(
+                one_chip,
+                functools.partial(flash_attention_pallas, causal=True,
+                                  window=2 * 512, interpret=False),
+                qk, qk, v, mask)
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        assert f"bf16[{heads},8192,128]" in text  # the output is v's size
+
+    @pytest.mark.parametrize("tokens", [8, 8192])
+    def test_expert_layer_beside_the_shared_expert(self, one_chip,
+                                                   monkeypatch, tokens):
+        """``routed_experts`` at THIS model's matrices ([5120, 3072] by the
+        rule's tiles, [1536, 5120]) with 32 of 256 experts held, behind
+        the sigmoid router of 256 outputs, plus the shared expert."""
+        from semantic_router_tpu.models import dots3_note, sdar_moe
+
+        monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
+        cfg = dots3_note.Dots3NoteConfig(experts_held=(0, 32))
+        H, I, E = (cfg.hidden_size, cfg.moe_intermediate_size,
+                   cfg.n_routed_experts)
+        bf = jnp.bfloat16
+
+        def layer(router, bias, gate_up, down, s_gate_up, s_down, x, valid):
+            p = {"router": router, "expert_bias": bias, "gate_up": gate_up,
+                 "down": down,
+                 "shared": {"gate_up": s_gate_up, "down": s_down}}
+            return dots3_note.moe(cfg, p, x, valid)
+
+        compiled = compile_for(
+            one_chip, layer, ((H, E), bf), ((E,), jnp.float32),
+            ((32, H, 2 * I), bf), ((32, I, H), bf), ((H, 2 * I), bf),
+            ((I, H), bf), ((tokens, H), bf), ((tokens,), jnp.bool_))
+        assert compiled.as_text().count("tpu_custom_call") >= 2
+
+    def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch):
+        """The generator's prefill (8 rows mapped inside it) and decode
+        programs over two layers that hold every kind of part (a full
+        layer with the dense MLP, a sliding layer with experts): the
+        prefill's temporaries are one row's, the decode step writes the
+        donated latent cache in place and returns a small report."""
+        from semantic_router_tpu.models import dots3_note, sdar_moe
+        from semantic_router_tpu.models.generate import GreedyGenerator
+        from semantic_router_tpu.ops import flash_attention as fa
+
+        monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
+        monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+        monkeypatch.setattr(
+            fa, "flash_attention_pallas",
+            functools.partial(fa.flash_attention_pallas, interpret=False))
+        cfg = dots3_note.Dots3NoteConfig(
+            layer_types=("full_attention", "sliding_attention"),
+            num_hidden_layers=2, experts_held=(0, 32),
+            vocab_held=(0, 19008))
+        H, I, W = (cfg.hidden_size, cfg.moe_intermediate_size,
+                   cfg.intermediate_size)
+        rows, S, M = 8, 8192, 8256
+
+        def shape(dims, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+        def attention(kind):
+            g = cfg.geometry(kind)
+            return {"norm1": shape((H,)), "norm2": shape((H,)),
+                    "q_a": shape((H, g.r_q)), "q_a_norm": shape((g.r_q,)),
+                    "q_b": shape((g.r_q, g.heads * (g.nope + g.rope))),
+                    "kv_a": shape((H, g.r_kv + g.rope)),
+                    "kv_a_norm": shape((g.r_kv,)),
+                    "kv_b": shape((g.r_kv, g.heads * (g.nope + g.v))),
+                    "o_proj": shape((g.heads * g.v, H)),
+                    "gate_proj": shape((H, g.heads))}
+
+        indexer = {"index_q": shape((1024, 64 * 128)),
+                   "index_k": shape((H, 128)), "index_k_norm": shape((128,)),
+                   "index_k_bias": shape((128,)), "index_w": shape((H, 64))}
+        experts = {"router": shape((H, 256)),
+                   "expert_bias": shape((256,), jnp.float32),
+                   "gate_up": shape((32, H, 2 * I)),
+                   "down": shape((32, I, H)),
+                   "shared": {"gate_up": shape((H, 2 * I)),
+                              "down": shape((I, H))}}
+        params = {"embed": shape((19008, H)), "norm": shape((H,)),
+                  "lm_head": shape((19008, H)), "layers": [
+            {**attention("full_attention"), **indexer,
+             "gate_up": shape((H, 2 * W)), "down": shape((W, H))},
+            {**attention("sliding_attention"), **experts}]}
+        gen = GreedyGenerator(cfg, None, None,
+                              model=dots3_note.CachedModel(cfg))
+        args = (params, shape((rows, S), jnp.int32),
+                shape((rows,), jnp.int32), shape((), jnp.int32))
+        prefill = gen._prefill_fn((rows, S, M))
+        compiled = prefill.lower(*args).compile()
+        # both cores and an expert layer's two grouped matmuls
+        assert compiled.as_text().count("tpu_custom_call") >= 4
+        # a row's heads, scores of one block of queries, a row's pairs
+        assert compiled.memory_analysis().temp_size_in_bytes < 4.5 * 2**30
+        cache, _, report, aux = jax.eval_shape(prefill, *args)
+        assert report.shape == (rows, 2 + 2 * gen.top_logits)
+        assert aux["experts"].shape == (1, rows, S, 8)
+        assert aux["keys"].shape == (rows, 2)
+        assert aux["selected"].shape == (1, rows, dots3_note.SELECT_SAMPLE,
+                                         S // 8)
+        cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
+                                       cache)
+        step = gen._step_fn((rows, 1, M)).lower(
+            params, cache, shape((rows,), jnp.int32),
+            shape((rows,), jnp.int32), shape((), jnp.int32)).compile()
+        mem = step.memory_analysis()
+        sizes = dots3_note.CachedModel.cache_bytes(cache)
+        assert sizes == {"latent": rows * M * (512 + 64) * 2,
+                         "index": rows * M * 128 * 2,
+                         "window": rows * 513 * (1024 + 64) * 2}
+        assert mem.alias_size_in_bytes >= sum(sizes.values())
+        assert mem.temp_size_in_bytes < 0.2 * 2**30
+        _, _, _, report, aux = jax.eval_shape(
+            gen._step_fn((rows, 1, M)), params, cache,
+            shape((rows,), jnp.int32), shape((rows,), jnp.int32),
+            shape((), jnp.int32))
+        assert aux["selected"].shape == (1, rows, M // 8)
