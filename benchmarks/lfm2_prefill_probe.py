@@ -1,12 +1,20 @@
-"""On-device probe of the lfm2_moe guard's two ways to bound a prefill in
-tokens, at the cell's own widths (``chipbench/configs/lfm2-24b-a2b-guard``:
-9 layers, 10.36 GB of bfloat16): rows mapped INSIDE one program
-(``models/lfm2_moe.py`` ``prefill``: ``jax.lax.map``) against a program a
-row (the one-row prefill called once a row, its cache written into the
-batch's).  Also one decode step of all rows.  The table behind the choice
-in ``models/lfm2_moe.py`` (PERF.md section 6, PR 32).
+"""On-device probe of the lfm2_moe guard's prefill at the cell's own widths
+(``chipbench/configs/lfm2-24b-a2b-guard``: 9 layers, 10.36 GB of bfloat16).
 
-    python benchmarks/lfm2_prefill_probe.py [--out chiprun_out/lfm2_prefill_probe.json]
+``--groups 1,2,4,8``: rows mapped inside one program a GROUP at a time
+(``models/lfm2_moe.py`` ``_prefill_groups``), for each group size the
+whole prefill's ms, the ``moe/gmm`` scope's device ms a row-layer (one
+traced call, read as the benchmark's ``ar_moe_gmm_roofline`` reads it),
+the compiler's temporaries and the allocator's peak: the table behind
+``lfm2_moe.rows_per_group`` (PERF.md section 6, PR 37;
+``benchmarks/results/lfm2_prefill_groups.json``).  A group that does not
+fit the device is recorded as such.  Then the two ways to bound a prefill
+in tokens that PR 32 chose between: rows mapped INSIDE one program
+(``prefill``, at the rule's group) against a program a row (the one-row
+prefill called once a row, its cache written into the batch's), and one
+decode step of all rows.
+
+    python benchmarks/lfm2_prefill_probe.py [--groups 1,2,4,8] [--out FILE]
 
 Needs one TPU chip; weights are drawn on the device (N(0, 0.02); the
 router's spread is not the cell's: experts touched a decode step are
@@ -26,28 +34,88 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--out", default="chiprun_out/lfm2_prefill_probe.json")
-    ap.add_argument("--rows", type=int, default=8)
-    ap.add_argument("--iters", type=int, default=3)
-    args = ap.parse_args()
+SCOPES = ("embed_tokens", "conv", "attn", "mlp", "moe/router", "moe/sort",
+          "moe/gmm", "moe/combine", "lm_head")
 
+
+def device_ms_by_scope(log_dir: str):
+    """One traced call's device ms under each of ``SCOPES`` (an op's scope
+    is the ``tf_op`` path the benchmark's readers take from the trace
+    file), everything else under ``other``, and the ten longest ops."""
+    from chipbench import reduce_trace
+    from chipbench.layer_metrics import _gen_spans
+
+    path = reduce_trace.find_xplane(log_dir)
+    reduced = reduce_trace.reduce(path)
+    tf_ops = _gen_spans._tf_ops(path)
+    # a mapped prefill's ``while`` op covers its body's ops: not work of
+    # its own (busy time is the union)
+    ms = dict.fromkeys(SCOPES + ("other",), 0.0)
+    for name, secs in reduced["op_seconds"].items():
+        if reduce_trace.short_name(name).startswith("while"):
+            continue
+        tf_op = f"/{tf_ops.get(name, '')}/"
+        scope = next((s for s in SCOPES if f"/{s}/" in tf_op), "other")
+        ms[scope] += secs * 1e3
+    return reduced["busy_s"] * 1e3, ms, [
+        [n, s * 1e3] for n, s in reduce_trace.top_ops(reduced, 12)]
+
+
+def measure_group(G, cfg, params, ids, lengths, cache_len, layers, timed,
+                  dev):
+    """One group size's row of the table."""
+    import tempfile
+
+    import jax
+
+    from semantic_router_tpu.models import lfm2_moe as M
+
+    row = {"rows_per_group": G}
+    fn = jax.jit(lambda p, i, n: M._prefill_groups(cfg, p, i, n, cache_len,
+                                                   G))
+    try:
+        compiled = fn.lower(params, ids, lengths).compile()
+        row["temp_bytes"] = compiled.memory_analysis().temp_size_in_bytes
+        times, _ = timed(lambda: compiled(params, ids, lengths),
+                         lambda o: o[1])
+        with tempfile.TemporaryDirectory() as log_dir:
+            jax.profiler.start_trace(log_dir)
+            try:
+                jax.block_until_ready(compiled(params, ids, lengths)[1])
+            finally:
+                jax.profiler.stop_trace()
+            busy_ms, by_scope, top = device_ms_by_scope(log_dir)
+    except Exception as exc:  # a group the device cannot hold
+        if "RESOURCE_EXHAUSTED" not in str(exc):
+            raise
+        row["does_not_fit"] = str(exc).splitlines()[0][:200]
+        return row
+    row["prefill_ms"] = [t * 1e3 for t in times]
+    row["device_busy_ms"] = busy_ms
+    row["device_ms_by_scope"] = by_scope
+    row["gmm_ms_per_row_layer"] = by_scope["moe/gmm"] \
+        / (ids.shape[0] * layers)
+    row["top_ops_ms"] = top
+    # the allocator's peak so far: it is this group's while the groups
+    # are measured in ascending order
+    row["peak_bytes_in_use"] = int(
+        (dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
+    return row
+
+
+def cell_inputs(B: int, S: int = 8192):
+    """The cell's configuration, its parameters drawn on the device and
+    ``B`` rows of 2090-8041 tokens: ``(cfg, params, ids [B, S], lengths
+    [B] on the device, lengths)``."""
     import jax
     import jax.numpy as jnp
 
     from semantic_router_tpu.models import lfm2_moe as M
 
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print(f"lfm2_prefill_probe: needs a TPU, found {dev.platform}",
-              file=sys.stderr)
-        return 2
     with open(os.path.join(os.path.dirname(__file__), "..", "chipbench",
                            "configs", "lfm2-24b-a2b-guard",
                            "model.json")) as f:
         cfg = M.Lfm2MoeConfig.from_hf(json.load(f))
-    S, cache_len, B = 8192, 8256, args.rows
 
     def draw(key, shape, std=0.02, dtype=jnp.bfloat16):
         return (jax.random.normal(key, shape, jnp.float32) * std) \
@@ -83,14 +151,37 @@ def main() -> int:
         layers.append(p)
     params = {"embed": draw(next(keys), (V, H), 0.07), "layers": layers,
               "norm": jnp.ones(H, jnp.bfloat16)}
-    n_params = sum(int(a.size) for a in jax.tree_util.tree_leaves(params))
-    print(f"parameters on the device: {n_params / 1e6:.1f} M", flush=True)
-
     rng = np.random.default_rng(0)
     lengths = np.exp(rng.uniform(np.log(2090), np.log(8041), B)) \
         .astype(np.int32)
     ids = rng.integers(2, V, (B, S)).astype(np.int32)
-    ids_dev, len_dev = jnp.asarray(ids), jnp.asarray(lengths)
+    return cfg, params, jnp.asarray(ids), jnp.asarray(lengths), lengths
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--out", default="chiprun_out/lfm2_prefill_probe.json")
+    ap.add_argument("--rows", type=int, default=8)
+    ap.add_argument("--iters", type=int, default=3)
+    ap.add_argument("--groups", default="1,2,4,8",
+                    help="rows a group to measure, ascending")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+
+    from semantic_router_tpu.models import lfm2_moe as M
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"lfm2_prefill_probe: needs a TPU, found {dev.platform}",
+              file=sys.stderr)
+        return 2
+    cfg, params, ids_dev, len_dev, lengths = cell_inputs(args.rows)
+    S, cache_len, B, V = 8192, 8256, args.rows, cfg.vocab_size
+    n_params = sum(int(a.size) for a in jax.tree_util.tree_leaves(params))
+    print(f"parameters on the device: {n_params / 1e6:.1f} M", flush=True)
+    rng = np.random.default_rng(1)
 
     mapped = jax.jit(lambda p, i, n: M.prefill(cfg, p, i, n, cache_len))
 
@@ -128,7 +219,20 @@ def main() -> int:
         return times, out
 
     results = {"rows": B, "lengths": lengths.tolist(),
-               "parameters": n_params}
+               "parameters": n_params, "device_kind": dev.device_kind,
+               "bytes_limit": (dev.memory_stats() or {}).get("bytes_limit"),
+               "rule_rows_per_group": M.prefill_group(cfg, params, B, S,
+                                                      cache_len),
+               "groups": []}
+    print(f"{dev.device_kind}: bytes_limit {results['bytes_limit']}, the "
+          f"rule gives {results['rule_rows_per_group']} rows a group",
+          flush=True)
+    layers = sum(cfg.is_sparse(i) for i in range(cfg.num_hidden_layers))
+    for G in [int(g) for g in args.groups.split(",")]:
+        results["groups"].append(
+            measure_group(G, cfg, params, ids_dev, len_dev, cache_len,
+                          layers, timed, dev))
+        print(results["groups"][-1], flush=True)
     t_map, out = timed(lambda: mapped(params, ids_dev, len_dev),
                        lambda o: o[1])
     cache, _, aux = out

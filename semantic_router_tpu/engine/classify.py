@@ -281,7 +281,8 @@ class _GenerationObserver:
         return self.step.stage(name)
 
     def done(self, load=None, committed_blocks: int = 0,
-             committed_tokens: int = 0, cache_bytes=None, keys=None) -> None:
+             committed_tokens: int = 0, cache_bytes=None, keys=None,
+             rows_per_group=None) -> None:
         """``load [layers, 4]`` of an expert model
         (models.sdar_moe.routed_experts); a dense generator gives none.
         ``committed_blocks`` / ``committed_tokens``: what this forward
@@ -291,18 +292,22 @@ class _GenerationObserver:
         (``{"kv", "conv"}``, or a latent cache's ``{"latent", "index",
         "window"}``).  ``keys [rows, 2]``: of a model with a learned
         selection, the keys its queries selected and those visible to
-        them, summed on the device over the full layers."""
+        them, summed on the device over the full layers.
+        ``rows_per_group``: of a prefill whose rows are mapped inside the
+        program, how many of them one grouped matmul served."""
         from ..observability import batchtrace
 
         step = self.step
         step.ran()
         self.close()
         if load is not None:
-            batchtrace.gen_forward(step.group, step.variant, load, keys)
+            batchtrace.gen_forward(step.group, step.variant, load, keys,
+                                   rows_per_group)
         try:
             self.engine._runtime_stats.record_generation(
                 self.task, step.variant, committed_blocks=committed_blocks,
-                committed_tokens=committed_tokens, cache_bytes=cache_bytes)
+                committed_tokens=committed_tokens, cache_bytes=cache_bytes,
+                rows_per_group=rows_per_group)
         except Exception:
             pass  # observability never fails a generation
 

@@ -52,7 +52,8 @@ vocabulary is a smaller vocabulary: id 0 is row ``first``).
 
 Precision: parameters and cache in ``cfg.dtype``; norms, RoPE, softmax, the
 indexer's scores, the gates, the router and the head's logits in float32.
-A prefill maps its rows INSIDE the program (``jax.lax.map``).
+A prefill maps its rows INSIDE the program, a group at a time
+(``lfm2_moe.map_row_groups``).
 
 Scopes: ``embed_tokens``; ``layers_<i>/attn_full`` (``q``, ``kv``,
 ``indexer/scores``, ``indexer/select``, ``core``, ``gate_out``);
@@ -72,7 +73,14 @@ import numpy as np
 
 from ..ops.flash_attention import flash_attention
 from ..ops.rope import RopeSpec, apply_rotary
-from .lfm2_moe import _sum_loads, _swiglu, sigmoid_route
+from .lfm2_moe import (
+    _sum_loads,
+    _swiglu,
+    map_row_groups,
+    rows_per_group,
+    sigmoid_route,
+    tree_bytes,
+)
 from .qwen3 import torch_dtype_of
 from .sdar_moe import NEG_INF, checkpoint_reader, rms_norm, routed_experts
 
@@ -384,17 +392,39 @@ def moe(cfg: Dots3NoteConfig, p, x, valid):
     return y, top_e, load
 
 
-def _feed_forward(cfg, i, p, x, valid):
-    """The second half of layer ``i`` on ``x [B, S, H]``; a dense layer
-    reports no experts."""
-    B, S, H = x.shape
-    h = rms_norm(x, p["norm2"], cfg.rms_norm_eps, cfg.dtype)
+def _ffn(cfg, i, p, h, valid):
+    """What the second half of layer ``i`` adds to the residual stream,
+    from its normed input ``h [B, S, H]``, and the experts' ``(top_e [B *
+    S, k], load)``; a dense layer reports no experts."""
+    B, S, H = h.shape
     if not cfg.is_sparse(i):
         with jax.named_scope("mlp"):
-            return x + _swiglu(cfg, p, h), None, None
+            return _swiglu(cfg, p, h), None, None
     with jax.named_scope("moe"):
         y, top_e, load = moe(cfg, p, h.reshape(B * S, H), valid.reshape(-1))
-    return x + y.reshape(B, S, H), top_e.reshape(B, S, -1), load
+    return y.reshape(B, S, H), top_e, load
+
+
+def _feed_forward(cfg, i, p, x, valid):
+    """The second half of layer ``i`` on ``x [B, S, H]``; ``top_e`` comes
+    back ``[B, S, k]``."""
+    B, S, _ = x.shape
+    y, top_e, load = _ffn(
+        cfg, i, p, rms_norm(x, p["norm2"], cfg.rms_norm_eps, cfg.dtype), valid)
+    x = x + y
+    return x, None if top_e is None else top_e.reshape(B, S, -1), load
+
+
+def _feed_forward_as_one_row(cfg, i, p, x, valid):
+    """``_feed_forward`` of a prefill's group ``x [G, S, H]`` with the
+    group's tokens as ONE row of ``G * S`` from the norm on
+    (``lfm2_moe._feed_forward_as_one_row`` says why)."""
+    G, S, H = x.shape
+    h = rms_norm(x, p["norm2"], cfg.rms_norm_eps, cfg.dtype)
+    y, top_e, load = _ffn(cfg, i, p, h.reshape(1, G * S, H),
+                          valid.reshape(1, G * S))
+    return (x.reshape(1, G * S, H) + y).reshape(G, S, H), \
+        None if top_e is None else top_e.reshape(G, S, -1), load
 
 
 def _expert_ids(cfg, top_e):
@@ -569,7 +599,7 @@ def _prefill_rows(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
                             lat, jnp.clip(src, 0, S - 1)[:, :, None], axis=1)
                         window.append(ring * held[:, :, None]
                                       .astype(ring.dtype))
-            x, top_e, load = _feed_forward(cfg, i, p, x, valid)
+            x, top_e, load = _feed_forward_as_one_row(cfg, i, p, x, valid)
             if top_e is not None:
                 experts.append(_expert_ids(cfg, top_e))
                 loads.append(load)
@@ -579,13 +609,69 @@ def _prefill_rows(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
             jnp.stack(selected), sample_at)
 
 
-def prefill(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
-    """``ids [B, S]`` right-padded prompts of ``lengths [B]`` (0 = a padding
-    row) -> ``(cache, logits [B, V] float32 at each row's last token, aux)``
-    with ``aux = {"experts" [expert layers, B, S, k], "load" [expert
-    layers, 4], "keys" [B, 2], "selected" [full layers, B, n, S / 8],
-    "selected_at" [B, n]}``.  One row at a time inside the program, so a
-    bucket's temporaries are those of ONE row whatever the batch."""
+def _row_bytes(cfg: Dots3NoteConfig, S: int) -> int:
+    """A prefill row's temporaries, reckoned from above: a full layer's
+    arrays a head (q and k ``nope + rope``, ``W_kvb``'s output ``nope +
+    v``, v and the core's output ``v``), one block of the indexer's
+    float32 scores of all its heads, the indexer's queries, the selection
+    as bool and int8 — and every array of ``S * k`` rows the expert layer
+    writes (``3 (H + I)`` a pair) as if live beside them.  At the guard's
+    widths (S 8192, 128 heads, k 8, bfloat16) 3.2 + 2.6 = 5.8 GB; the
+    compiler's count for a described v5e is 4.2 GB at one row a group of
+    the cell's six layers (2.5 of two layers) and 1.7-2.5 more a row."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    g = cfg.geometry("full_attention")
+    heads = g.heads * S * (2 * (g.nope + g.rope) + (g.nope + g.v)
+                           + 2 * g.v) * item
+    indexer = cfg.index_n_heads * S * (4 * min(INDEX_BLOCK, S)
+                                       + cfg.index_head_dim * item)
+    experts = 3 * S * cfg.num_experts_per_tok * item \
+        * (cfg.hidden_size + cfg.moe_intermediate_size)
+    return heads + indexer + 2 * S * S + experts
+
+
+def _cache_bytes(cfg: Dots3NoteConfig, rows: int, cache_len: int) -> int:
+    """The bytes of the cache a prefill of ``rows`` returns."""
+    full, sliding = (cfg.geometry(k) for k in LAYER_TYPES)
+    n_full = len(cfg.full_layers)
+    return rows * jnp.dtype(cfg.dtype).itemsize * (
+        n_full * cache_len * (full.r_kv + full.rope + cfg.index_head_dim)
+        + (len(cfg.layer_types) - n_full) * cfg.sliding_window_size
+        * (sliding.r_kv + sliding.rope))
+
+
+def _prefill_groups(cfg: Dots3NoteConfig, params, ids, lengths,
+                    cache_len: int, group: int):
+    """``prefill`` at ``group`` rows a call of ``_prefill_rows``."""
+    if group == 1:
+        return _prefill_a_row_at_a_time(cfg, params, ids, lengths, cache_len)
+
+    def rows(ids, lengths):
+        (latent, index, window, logits, experts, load, keys, selected,
+         at) = _prefill_rows(cfg, params, ids, lengths, cache_len)
+        return (latent, index, window, logits, jnp.moveaxis(experts, 1, 0),
+                keys, jnp.moveaxis(selected, 1, 0), at), load
+
+    (latent, index, window, logits, experts, keys, selected,
+     at), loads = map_row_groups(rows, group, ids, lengths)
+    cache = {"latent": latent, "index": index, "window": window,
+             "lengths": lengths.astype(jnp.int32)}
+    return cache, logits, {
+        "experts": jnp.moveaxis(experts, 0, 1), "load": _sum_loads(loads),
+        "keys": keys, "selected": jnp.moveaxis(selected, 0, 1),
+        "selected_at": at}
+
+
+def _prefill_a_row_at_a_time(cfg: Dots3NoteConfig, params, ids, lengths,
+                             cache_len: int):
+    """``_prefill_groups`` at one row a group, written as it was before
+    there were groups: the row cut out of ``[B, S]`` inside the map and
+    every output but the cache leaves squeezed there.  ``map_row_groups``
+    at one row is the same arithmetic under other reshapes, and the
+    compiler schedules THAT program's weight prefetches differently: on a
+    v5e the guard's cell read 2.2519 routes/s against 2.3212 for this form
+    (PERF.md section 6, PR 37).  This lowers to the program of before
+    groups line for line."""
     def one(row):
         (latent, index, window, logits, experts, load, keys, selected,
          at) = _prefill_rows(cfg, params, row[0][None], row[1][None],
@@ -603,6 +689,30 @@ def prefill(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
         "experts": jnp.moveaxis(experts, 0, 1), "load": _sum_loads(loads),
         "keys": keys, "selected": jnp.moveaxis(selected, 0, 1),
         "selected_at": at}
+
+
+def prefill_group(cfg: Dots3NoteConfig, params, rows: int, S: int,
+                  cache_len: int) -> int:
+    """The rows a group of a prefill of ``rows`` x ``S``
+    (``lfm2_moe.rows_per_group`` at this model's sizes: at the guard's
+    widths a row's attention arrays leave room for no second one)."""
+    return rows_per_group(
+        rows, _row_bytes(cfg, S),
+        tree_bytes(params) + _cache_bytes(cfg, rows, cache_len),
+        S * cfg.hidden_size * jnp.dtype(cfg.dtype).itemsize)
+
+
+def prefill(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
+    """``ids [B, S]`` right-padded prompts of ``lengths [B]`` (0 = a padding
+    row) -> ``(cache, logits [B, V] float32 at each row's last token, aux)``
+    with ``aux = {"experts" [expert layers, B, S, k], "load" [expert
+    layers, 4], "keys" [B, 2], "selected" [full layers, B, n, S / 8],
+    "selected_at" [B, n]}``.  ``prefill_group`` rows at a time inside the
+    program (``lfm2_moe.map_row_groups``), so a bucket's temporaries are
+    those of ONE group whatever the batch."""
+    return _prefill_groups(
+        cfg, params, ids, lengths, cache_len,
+        prefill_group(cfg, params, *ids.shape, cache_len))
 
 
 # -- decode: one token a row against the latent cache ----------------------------
@@ -723,11 +833,14 @@ class CachedModel:
     def decode(self, params, cache, tokens, positions, task_index):
         return decode(self.config, params, cache, tokens, positions)
 
+    def rows_per_group(self, params, rows: int, bucket: int,
+                       cache_len: int) -> int:
+        """How many rows of such a prefill go through the layers
+        together."""
+        return prefill_group(self.config, params, rows, bucket, cache_len)
+
     @staticmethod
     def cache_bytes(cache) -> Dict[str, int]:
         """The cache's bytes by kind of state."""
-        def size(tree):
-            return sum(int(a.size) * a.dtype.itemsize
-                       for a in jax.tree_util.tree_leaves(tree))
-
-        return {k: size(cache[k]) for k in ("latent", "index", "window")}
+        return {k: tree_bytes(cache[k])
+                for k in ("latent", "index", "window")}

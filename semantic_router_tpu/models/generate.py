@@ -347,7 +347,9 @@ class Qwen3Cached:
     (cache, logits [B, V], aux)``; ``aux`` may hold ``experts`` (the
     router's choice, ``[layers, B, (S,) k]``) and ``load [layers, 4]`` of
     an expert model — a dense one gives neither; ``cache_bytes(cache)``
-    the cache's bytes by kind of state.  ``models.lfm2_moe.CachedModel``
+    the cache's bytes by kind of state; ``rows_per_group(params, rows,
+    bucket, cache_len)`` the rows of such a prefill that go through the
+    layers together (None: the rows are not mapped).  ``models.lfm2_moe.CachedModel``
     is the other implementation.
 
     This cache: K and V ``[B, kv, M, D]`` a layer, prompt tokens in
@@ -391,6 +393,12 @@ class Qwen3Cached:
             at, task_index)
         cache = {"kv": kv, "mask": mask, "next": at + 1}
         return cache, logits[:, 0].astype(jnp.float32), {}
+
+    @staticmethod
+    def rows_per_group(params, rows: int, bucket: int, cache_len: int):
+        """A model that maps a prefill's rows inside the program says how
+        many go through its layers together; this one maps none."""
+        return None
 
     @staticmethod
     def cache_bytes(cache) -> Dict[str, int]:
@@ -538,7 +546,9 @@ class GreedyGenerator:
             choices("prefill", lengths - 1, report, aux)
         fwd.done(load=aux.get("load"), committed_tokens=n,
                  cache_bytes=self.model.cache_bytes(cache),
-                 keys=aux.get("keys"))
+                 keys=aux.get("keys"),
+                 rows_per_group=self.model.rows_per_group(
+                     self.params, B, S, M))
 
         step = self._step_fn((B, 1, M))
         for t in range(max_new_tokens):
@@ -749,7 +759,7 @@ class BlockDiffusionGenerator:
             caches, load = prefill(self.params, ids_dev, base_dev)
         with fwd.stage("readback"):
             load = np.asarray(jax.device_get(load))
-        fwd.done(load=load)
+        fwd.done(load=load, rows_per_group=B)  # every row in one call
 
         generated: List[List[int]] = [[] for _ in range(n)]
         trajectory: List[List[Dict[str, Any]]] = [[] for _ in range(n)]

@@ -29,8 +29,10 @@ sigmoid and the head's logits in float32 (the published code computes the
 router's logits in the model's dtype: more precise here, never less).
 
 A prefill is bounded in tokens: rows are mapped INSIDE the program
-(``jax.lax.map``), so the expert layers' temporaries exist for one row at
-a time; decoding runs all rows together.
+(``map_row_groups``) a GROUP at a time, so the layers' temporaries exist
+for one group and an expert layer's grouped matmuls read each touched
+expert once a group (``rows_per_group`` has the rule); decoding runs all
+rows together.
 
 Scopes: ``embed_tokens``, ``layers_<i>/conv`` (``in_proj``, ``conv1d``,
 ``out_proj``), ``layers_<i>/attn``, ``layers_<i>/mlp``, ``layers_<i>/moe``
@@ -238,17 +240,45 @@ def _swiglu(cfg, p, x):
     return h.astype(cfg.dtype) @ p["down"]
 
 
-def _feed_forward(cfg, i, p, x, valid):
-    """The second half of layer ``i`` on ``x [B, S, H]``; a dense layer
-    reports no experts."""
-    B, S, H = x.shape
-    h = rms_norm(x, p["norm2"], cfg.norm_eps, cfg.dtype)
+def _ffn(cfg, i, p, h, valid):
+    """What the second half of layer ``i`` adds to the residual stream,
+    from its normed input ``h [B, S, H]``, and the experts' ``(top_e [B *
+    S, k], load)``; a dense layer reports no experts."""
+    B, S, H = h.shape
     if not cfg.is_sparse(i):
         with jax.named_scope("mlp"):
-            return x + _swiglu(cfg, p, h), None, None
+            return _swiglu(cfg, p, h), None, None
     with jax.named_scope("moe"):
         y, top_e, load = moe(cfg, p, h.reshape(B * S, H), valid.reshape(-1))
-    return x + y.reshape(B, S, H), top_e.reshape(B, S, -1), load
+    return y.reshape(B, S, H), top_e, load
+
+
+def _feed_forward(cfg, i, p, x, valid):
+    """The second half of layer ``i`` on ``x [B, S, H]``; ``top_e`` comes
+    back ``[B, S, k]``."""
+    B, S, _ = x.shape
+    y, top_e, load = _ffn(
+        cfg, i, p, rms_norm(x, p["norm2"], cfg.norm_eps, cfg.dtype), valid)
+    x = x + y
+    return x, None if top_e is None else top_e.reshape(B, S, -1), load
+
+
+def _feed_forward_as_one_row(cfg, i, p, x, valid):
+    """``_feed_forward`` of a prefill's group ``x [G, S, H]``: the norm in
+    the rows' shape (it rides the epilogue of the matmul before it), then
+    the group's tokens as ONE row of ``G * S`` through the layer and the
+    residual sum — a token's feed-forward does not know its row.  With
+    the sum in the rows' shape the compiler cuts the expert layer's
+    combine at the reshape between them and writes the float32 copies of
+    all ``k`` gathered slices (1.07 GB a layer at four rows; 54 ms of a
+    757 ms prefill on a v5e, PERF.md section 6, PR 37).  One row is its
+    own shape: nothing is reshaped."""
+    G, S, H = x.shape
+    h = rms_norm(x, p["norm2"], cfg.norm_eps, cfg.dtype)
+    y, top_e, load = _ffn(cfg, i, p, h.reshape(1, G * S, H),
+                          valid.reshape(1, G * S))
+    return (x.reshape(1, G * S, H) + y).reshape(G, S, H), \
+        None if top_e is None else top_e.reshape(G, S, -1), load
 
 
 def _gates(cfg, p, h):
@@ -327,7 +357,7 @@ def _prefill_rows(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int):
                     x = x + out.astype(cfg.dtype) @ p["o_proj"]
                     pad = ((0, 0), (0, 0), (0, cache_len - S), (0, 0))
                     kv.append((jnp.pad(kc, pad), jnp.pad(vc, pad)))
-            x, top_e, load = _feed_forward(cfg, i, p, x, valid)
+            x, top_e, load = _feed_forward_as_one_row(cfg, i, p, x, valid)
             if top_e is not None:
                 experts.append(_expert_ids(cfg, top_e))
                 loads.append(load)
@@ -337,31 +367,146 @@ def _prefill_rows(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int):
 
 
 def _sum_loads(loads):
-    """Per-row ``load [rows, layers, 4]`` of a mapped prefill as one
-    ``[layers, 4]``: every row's grouped matmul reads its own touched
-    experts, so pairs and experts touched add up; the busiest is the
-    busiest of any row, the ratio the rows' mean."""
+    """Per-group ``load [groups, layers, 4]`` of a mapped prefill as one
+    ``[layers, 4]``: every group's grouped matmul reads its own touched
+    experts once, so pairs and experts touched add up over the groups; the
+    busiest is the busiest of any group, the ratio the groups' mean."""
     return jnp.stack([loads[..., 0].max(0), loads[..., 1].sum(0),
                       loads[..., 2].sum(0), loads[..., 3].mean(0)], -1)
+
+
+def device_bytes() -> Optional[int]:
+    """What the device a program is traced for may allocate; None where
+    the backend reports no limit (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+def tree_bytes(tree) -> int:
+    """The bytes of a tree's arrays (or of their shapes, under a trace)."""
+    return sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+SPARE_BYTES = 1_500_000_000
+# half a v5e core's own memory (VMEM, 128 MiB): what a group's normed
+# activations may take for the compiler to keep them there
+GATHER_SOURCE_BYTES = 64 * 2**20
+
+
+def rows_per_group(rows: int, row_bytes: int, resident_bytes: int,
+                   source_bytes: int) -> int:
+    """How many of a prefill's ``rows`` run through the layers together
+    (``map_row_groups``).  More rows a group feed each touched expert's
+    matrices more pairs a read.  Two things bound a group: its
+    temporaries, ``row_bytes`` a row beside ``resident_bytes`` of weights
+    and cache on a device of ``device_bytes()``, must leave ``SPARE_BYTES``
+    free (the allocator's fragments, the decode program's temporaries);
+    and its normed activations ``[G * S, H]``, ``source_bytes`` a row —
+    which the expert layer's first gather reads k times a token — must
+    stay within ``GATHER_SOURCE_BYTES``, where the compiler keeps them in
+    the core's own memory and the gather costs 13 ns a row, not 33.  The
+    rule: the largest divisor of ``rows`` within both; 1 where not even
+    one row is; where the backend reports no limit (the CPU), every row.
+
+    Set from ``benchmarks/results/lfm2_prefill_groups.json`` (one v5e, the
+    lfm2_moe guard's 8 x 8192 prefill, PERF.md section 6, PR 37), rows a
+    group -> whole prefill ms / grouped matmuls ms a row-layer / the
+    gathers' scope ms / the compiler's temporaries GB: 1 -> 752 / 4.05 /
+    83 / 0.75; 2 -> 642 / 3.27 / 34 / 1.32 (activations 67 MB, in the
+    core's memory); 4 -> 685 / 2.77 / 91 / 2.60 (134 MB: not); 8 -> 670 /
+    2.51 / 91 / 5.13 (leaves 1.1 GB of a 16.9 GB device beside 10.6).  So
+    the guard's cell runs 2 rows a group, the dots3_note cell (a row's
+    temporaries 5.8 GB reckoned, 4.2 by the compiler; activations 84 MB a
+    row) 1."""
+    limit = device_bytes()
+    if limit is None:
+        return rows
+    fit = min((limit - resident_bytes - SPARE_BYTES) // row_bytes,
+              GATHER_SOURCE_BYTES // source_bytes)
+    return max(g for g in range(1, rows + 1)
+               if rows % g == 0 and g <= max(fit, 1))
+
+
+def map_row_groups(rows_fn, group: int, ids, lengths):
+    """``rows_fn(ids [G, S], lengths [G]) -> (per_row, per_group)`` over
+    the batch ``ids [B, S]``, ``lengths [B]``, ``group`` rows a call, one
+    call at a time INSIDE the program (``jax.lax.map``: the temporaries
+    are one group's whatever the batch).  Every leaf of ``per_row`` has
+    the group's rows on its leading axis and comes back ``[B, ...]`` in
+    the batch's order; ``per_group`` comes back stacked ``[B / group,
+    ...]``.  ``group`` divides ``B``.  Not ``jax.lax.map(batch_size=)``:
+    that ``vmap``s a one-row body, and a ``vmap`` of the grouped matmul is
+    one grouped matmul a row under one more grid axis, each reading every
+    expert; here a group's rows are ONE call's tokens."""
+    B = ids.shape[0]
+
+    def split(a):
+        return a.reshape((B // group, group) + a.shape[1:])
+
+    per_row, per_group = jax.lax.map(lambda g: rows_fn(*g),
+                                     (split(ids), split(lengths)))
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((B,) + a.shape[2:]), per_row), per_group
+
+
+def _row_bytes(cfg: Lfm2MoeConfig, S: int) -> int:
+    """A prefill row's temporaries, reckoned from above: every array of
+    ``S * k`` rows an expert layer writes (the sorted rows, gate+up, the
+    activation, down, the rows gathered back: ``3 (H + I)`` a pair) as if
+    all were live at once, beside four ``[S, H]`` of residual stream.  At
+    the guard's widths (S 8192, k 4, H 2048, I 1536, bfloat16) 0.84 GB;
+    the compiler's count for a described v5e is 0.75 GB a row at 1, 2, 4
+    and 8 rows a group (the dense MLP's ``3 W`` a token is 0.58)."""
+    H, I = cfg.hidden_size, cfg.moe_intermediate_size
+    return S * jnp.dtype(cfg.dtype).itemsize \
+        * (3 * cfg.num_experts_per_tok * (H + I) + 4 * H)
+
+
+def _cache_bytes(cfg: Lfm2MoeConfig, rows: int, cache_len: int) -> int:
+    """The bytes of the cache a prefill of ``rows`` returns."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    attn = sum(k == "full_attention" for k in cfg.layer_types)
+    kv = 2 * cfg.num_key_value_heads * cache_len * cfg.head_dim
+    conv = (cfg.conv_L_cache - 1) * cfg.hidden_size
+    return rows * item * (attn * kv + (len(cfg.layer_types) - attn) * conv)
+
+
+def _prefill_groups(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int,
+                    group: int):
+    """``prefill`` at ``group`` rows a call of ``_prefill_rows``."""
+    def rows(ids, lengths):
+        kv, conv, logits, experts, load = _prefill_rows(
+            cfg, params, ids, lengths, cache_len)
+        return (kv, conv, logits, jnp.moveaxis(experts, 1, 0)), load
+
+    (kv, conv, logits, experts), loads = map_row_groups(
+        rows, group, ids, lengths)
+    cache = {"kv": kv, "conv": conv, "lengths": lengths.astype(jnp.int32)}
+    return cache, logits, {"experts": jnp.moveaxis(experts, 0, 1),
+                           "load": _sum_loads(loads)}
+
+
+def prefill_group(cfg: Lfm2MoeConfig, params, rows: int, S: int,
+                  cache_len: int) -> int:
+    """The rows a group of a prefill of ``rows`` x ``S``
+    (``rows_per_group`` at this model's sizes)."""
+    return rows_per_group(
+        rows, _row_bytes(cfg, S),
+        tree_bytes(params) + _cache_bytes(cfg, rows, cache_len),
+        S * cfg.hidden_size * jnp.dtype(cfg.dtype).itemsize)
 
 
 def prefill(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int):
     """``ids [B, S]`` right-padded prompts of ``lengths [B]`` (0 = a padding
     row) -> ``(cache, logits [B, V] float32 at each row's last token, aux)``
     with ``aux = {"experts" [expert layers, B, S, k], "load" [expert
-    layers, 4]}``.  One row at a time inside the program, so a bucket's
-    temporaries are those of ONE row whatever the batch."""
-    def one(row):
-        kv, conv, logits, experts, load = _prefill_rows(
-            cfg, params, row[0][None], row[1][None], cache_len)
-        return kv, conv, logits[0], experts[:, 0], load
-
-    kv, conv, logits, experts, loads = jax.lax.map(one, (ids, lengths))
-    cache = {"kv": [(k[:, 0], v[:, 0]) for k, v in kv],
-             "conv": [c[:, 0] for c in conv],
-             "lengths": lengths.astype(jnp.int32)}
-    return cache, logits, {"experts": jnp.moveaxis(experts, 0, 1),
-                           "load": _sum_loads(loads)}
+    layers, 4]}``.  ``prefill_group`` rows at a time inside the program, so
+    a bucket's temporaries are those of ONE group whatever the batch, and
+    an expert layer's grouped matmuls serve a group's tokens together."""
+    return _prefill_groups(
+        cfg, params, ids, lengths, cache_len,
+        prefill_group(cfg, params, *ids.shape, cache_len))
 
 
 # -- decode: one token a row against the hybrid cache ----------------------------
@@ -440,11 +585,14 @@ class CachedModel:
     def decode(self, params, cache, tokens, positions, task_index):
         return decode(self.config, params, cache, tokens, positions)
 
+    def rows_per_group(self, params, rows: int, bucket: int,
+                       cache_len: int) -> int:
+        """How many rows of such a prefill go through the layers
+        together."""
+        return prefill_group(self.config, params, rows, bucket, cache_len)
+
     @staticmethod
     def cache_bytes(cache) -> Dict[str, int]:
         """The cache's bytes by kind of state."""
-        def size(tree):
-            return sum(int(a.size) * a.dtype.itemsize
-                       for a in jax.tree_util.tree_leaves(tree))
-
-        return {"kv": size(cache["kv"]), "conv": size(cache["conv"])}
+        return {"kv": tree_bytes(cache["kv"]),
+                "conv": tree_bytes(cache["conv"])}

@@ -291,7 +291,8 @@ def queue_wait(trace_id: str, group: str, wait_s: float) -> None:
         pass
 
 
-def gen_forward(group: str, flavour: str, load, keys=None) -> None:
+def gen_forward(group: str, flavour: str, load, keys=None,
+                rows_per_group=None) -> None:
     """``engine.gen.forward``: a forward of a generation ended now, and
     this is what only its readback knew.  ``load [layers, 4]`` per expert
     layer: the busiest expert's routed pairs, the pairs computed, the
@@ -300,10 +301,14 @@ def gen_forward(group: str, flavour: str, load, keys=None) -> None:
     and ``load_milli`` (the ratio's mean over the layers, in thousandths).
     ``keys [rows, 2]`` of a model with a learned selection of keys adds
     ``keys_selected`` and ``keys_visible``: what the forward's queries
-    selected and what they could see, over its rows and full layers."""
+    selected and what they could see, over its rows and full layers.
+    ``rows_per_group`` of a prefill mapped over groups of rows adds the
+    fact of that name: the rows one grouped matmul served."""
     facts = {} if keys is None else {
         "keys_selected": int(keys[:, 0].sum(dtype="int64")),
         "keys_visible": int(keys[:, 1].sum(dtype="int64"))}
+    if rows_per_group is not None:
+        facts["rows_per_group"] = int(rows_per_group)
     with trace_span(GEN_FORWARD_ANNOTATION, group=group, flavour=flavour,
                     layers=len(load), pairs=int(load[:, 1].sum()),
                     experts_touched=int(load[:, 2].sum()),

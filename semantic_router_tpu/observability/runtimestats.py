@@ -197,6 +197,11 @@ class RuntimeStats:
             "fixed-size recurrent state; latent, index: a latent-attention "
             "layer's compressed keys and its indexer's keys, both growing "
             "with the context; window: a sliding layer's ring of latents)")
+        self.gen_rows_per_group = registry.gauge(
+            "llm_runtime_gen_prefill_rows_per_group",
+            "Rows of a generative task's newest prefill that went through "
+            "the layers together (one grouped matmul an expert layer "
+            "served them); the others waited their turn inside the program")
         self.rss_bytes = registry.gauge(
             "llm_process_rss_bytes", "Router process resident set size")
         self.threads = registry.gauge(
@@ -242,7 +247,7 @@ class RuntimeStats:
     def record_generation(self, task: str, flavour: str,
                           committed_blocks: int = 0,
                           committed_tokens: int = 0,
-                          cache_bytes=None) -> None:
+                          cache_bytes=None, rows_per_group=None) -> None:
         """One forward of a generation (the engine's generative runner):
         llm_runtime_gen_forwards_total by flavour, and what the forward
         FINISHED in llm_runtime_gen_blocks_committed_total and
@@ -254,12 +259,16 @@ class RuntimeStats:
         ``gen:<task>``, the flavour as variant) and, under a profiler
         session, its ``engine.step`` and ``engine.gen.forward``
         annotations (expert load among them).  ``cache_bytes`` (a
-        prefill's, by kind) sets llm_runtime_gen_cache_bytes."""
+        prefill's, by kind) sets llm_runtime_gen_cache_bytes,
+        ``rows_per_group`` (a mapped prefill's)
+        llm_runtime_gen_prefill_rows_per_group."""
         if not self.enabled:
             return
         self.gen_forwards.inc(task=task, flavour=flavour)
         for kind, size in (cache_bytes or {}).items():
             self.gen_cache_bytes.set(size, task=task, kind=kind)
+        if rows_per_group is not None:
+            self.gen_rows_per_group.set(int(rows_per_group), task=task)
         if committed_blocks:
             self.gen_blocks.inc(committed_blocks, task=task)
         if committed_tokens:

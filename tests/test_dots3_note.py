@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from chipbench import cells
+from test_lfm2_moe import ROW_GROUPS, touched_by_group
 from semantic_router_tpu.models import dots3_note as M
 from semantic_router_tpu.models import lfm2_moe, sdar_moe
 from semantic_router_tpu.models.generate import GreedyGenerator
@@ -249,6 +250,50 @@ def test_padding_is_never_seen(toy):
     a = M.prefill(cfg, params, *padded([row], 24), 32)[1]
     b = M.prefill(cfg, params, *padded([row], 48, pad=77), 64)[1]
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+@pytest.mark.parametrize("rows, group, real", ROW_GROUPS)
+def test_a_prefill_in_groups_equals_its_rows_one_at_a_time(toy, rows, group,
+                                                           real):
+    """``test_lfm2_moe``'s test of the same name on this decoder: what a
+    leading batch axis could get wrong here is the per-row selection, the
+    ring's gather at each row's own last position and the sampled rows of
+    the selection.  Rows past ``index_topk`` (8) and past the window (5),
+    padding rows inside a group and a whole group of them."""
+    _, _, cfg, params = toy
+    lens = [21, 40, 9, 33, 3, 17, 26, 12][:real] + [0] * (rows - real)
+    ids, lengths = padded(prompts(22, lens), 48)
+    cache, logits, aux = jax.jit(
+        lambda p, i, n: M._prefill_groups(cfg, p, i, n, 64, group))(
+            params, ids, lengths)
+    one = jax.jit(lambda p, i, n: M._prefill_rows(cfg, p, i, n, 64))
+    alone = [one(params, ids[b:b + 1], lengths[b:b + 1])
+             for b in range(rows)]
+
+    def rows_of(leaf, axis=0):
+        return np.concatenate([np.asarray(leaf(a)) for a in alone], axis)
+
+    def close(got, want):
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5)
+
+    close(logits[:real], rows_of(lambda a: a[3])[:real])
+    for kind, at in (("latent", 0), ("index", 1), ("window", 2)):
+        for i, leaf in enumerate(cache[kind]):
+            close(leaf, rows_of(lambda a: a[at][i]))
+    lens = np.asarray(lengths)
+    experts, want = np.asarray(aux["experts"]), rows_of(lambda a: a[4], 1)
+    for b in range(rows):
+        assert (experts[:, b, :lens[b]] == want[:, b, :lens[b]]).all()
+    np.testing.assert_array_equal(np.asarray(aux["keys"]),
+                                  rows_of(lambda a: a[6]))
+    np.testing.assert_array_equal(np.asarray(aux["selected"]),
+                                  rows_of(lambda a: a[7], 1))
+    np.testing.assert_array_equal(np.asarray(aux["selected_at"]),
+                                  rows_of(lambda a: a[8]))
+    np.testing.assert_array_equal(
+        np.asarray(aux["load"])[:, :3],
+        touched_by_group(experts, lens, group, EXPERTS))
 
 
 def test_kth_largest_is_exact():

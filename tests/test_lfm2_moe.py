@@ -14,6 +14,7 @@ chip."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -196,6 +197,126 @@ def test_a_batch_equals_its_rows_one_at_a_time(toy):
         alone = _decode_greedily(cfg, params, [row], 16, 4)
         assert (alone[0][0] == together[0][i]).all()
         np.testing.assert_allclose(alone[1][0], together[1][i], atol=ATOL)
+
+
+# -- a prefill's rows in groups ---------------------------------------------------
+
+V5E_BYTES = 16_909_336_064  # a v5e's memory_stats()["bytes_limit"]
+
+# (rows, rows a group, real rows): the padding rows (length 0) come last, so
+# (8, 2, 5) has one inside a group and a whole group of them, (8, 4, 3) too
+ROW_GROUPS = [(1, 1, 1), (2, 1, 2), (2, 2, 1), (4, 1, 3), (4, 2, 3),
+              (4, 4, 3), (8, 1, 8), (8, 2, 5), (8, 4, 3), (8, 4, 8),
+              (8, 2, 8)]
+
+
+def touched_by_group(experts, lengths, group: int, held=None):
+    """From the router's choices ``experts [layers, B, S, k]``: per expert
+    layer ``(the busiest held expert's pairs in any group, the pairs, the
+    held experts with a pair summed over the GROUPS of rows)`` — what
+    ``load`` must say when each group is one grouped matmul."""
+    layers, B, S, _ = experts.shape
+    out = []
+    for layer in range(layers):
+        busiest = pairs = touched = 0
+        for g in range(0, B, group):
+            chosen = np.concatenate(
+                [experts[layer, b, :lengths[b]].reshape(-1)
+                 for b in range(g, g + group)])
+            if held is not None:
+                chosen = chosen[(chosen >= held[0])
+                                & (chosen < held[0] + held[1])]
+            counts = np.bincount(chosen.astype(np.int64), minlength=1)
+            busiest = max(busiest, int(counts.max()))
+            pairs += int(counts.sum())
+            touched += int((counts > 0).sum())
+        out.append((busiest, pairs, touched))
+    return np.asarray(out, np.float32)
+
+
+@pytest.mark.parametrize("rows, group, real", ROW_GROUPS)
+def test_a_prefill_in_groups_equals_its_rows_one_at_a_time(toy, rows, group,
+                                                           real):
+    """``group`` rows through the layers together (ONE grouped matmul an
+    expert layer) against ``_prefill_rows`` called a row at a time: the
+    router's choices exactly, every float (cache leaves, last-token
+    logits) to 1e-5 (the CPU's matmuls sum a batch of ``group`` x 16 tokens
+    in another order than one of 16: up to 3e-6 at logits of size 5, a few
+    dozen units of float32's last place), ``load`` the sum over the
+    groups."""
+    _, _, cfg, params = toy
+    lens = [5, 14, 9, 16, 3, 11, 7, 12][:real] + [0] * (rows - real)
+    ids, lengths = padded(prompts(21, lens), 16)
+    cache, logits, aux = jax.jit(
+        lambda p, i, n: M._prefill_groups(cfg, p, i, n, 24, group))(
+            params, ids, lengths)
+    one = jax.jit(lambda p, i, n: M._prefill_rows(cfg, p, i, n, 24))
+    alone = [one(params, ids[b:b + 1], lengths[b:b + 1])
+             for b in range(rows)]
+
+    def rows_of(leaf):  # leaf(a row's outputs) [1, ...] -> [rows, ...]
+        return np.concatenate([np.asarray(leaf(a)) for a in alone])
+
+    close = functools.partial(np.testing.assert_allclose, rtol=1e-5,
+                              atol=1e-5)
+    close(np.asarray(logits)[:real], rows_of(lambda a: a[2])[:real])
+    for i, (k, v) in enumerate(cache["kv"]):
+        close(np.asarray(k), rows_of(lambda a: a[0][i][0]))
+        close(np.asarray(v), rows_of(lambda a: a[0][i][1]))
+    for i, c in enumerate(cache["conv"]):
+        close(np.asarray(c), rows_of(lambda a: a[1][i]))
+        assert (np.asarray(c)[real:] == 0).all()
+    experts = np.asarray(aux["experts"])
+    want = np.concatenate([np.asarray(a[3]) for a in alone], 1)
+    lens = np.asarray(lengths)
+    for b in range(rows):
+        assert (experts[:, b, :lens[b]] == want[:, b, :lens[b]]).all()
+    assert experts.shape == (4, rows, 16, 2)
+    np.testing.assert_array_equal(
+        np.asarray(aux["load"])[:, :3],
+        touched_by_group(experts, lens, group))
+
+
+@pytest.mark.parametrize("name, rows, limit, want", [
+    # the lfm2_moe cell: 10.36 GB of weights, 0.84 GB a row reckoned, 34 MB
+    # of activations a row: memory would hold 5 rows, the core's own 2
+    ("lfm2-24b-a2b-guard", 8, V5E_BYTES, 2),
+    ("lfm2-24b-a2b-guard", 4, V5E_BYTES, 2),
+    ("lfm2-24b-a2b-guard", 2, V5E_BYTES, 2),
+    ("lfm2-24b-a2b-guard", 1, V5E_BYTES, 1),
+    # a device that holds the weights and not one more row
+    ("lfm2-24b-a2b-guard", 8, 12e9, 1),
+    # the dots3_note cell: a row's attention arrays leave no room, and
+    # its activations are 84 MB a row
+    ("dots3-note-guard", 8, V5E_BYTES, 1),
+    ("dots3-note-guard", 2, V5E_BYTES, 1),
+    ("dots3-note-guard", 1, V5E_BYTES, 1),
+    ("dots3-note-guard", 8, 4 * V5E_BYTES, 1),
+    # no limit known (the CPU): every row
+    ("lfm2-24b-a2b-guard", 3, None, 3),
+    ("dots3-note-guard", 8, None, 8),
+])
+def test_the_rows_a_group_follow_from_shapes_and_memory(monkeypatch, name,
+                                                        rows, limit, want):
+    """``rows_per_group``'s table at the cells' published widths (bucket
+    8192, cache 8256) on a v5e's memory."""
+    from semantic_router_tpu.models import dots3_note
+
+    with open(os.path.join(os.path.dirname(cells.__file__), "configs", name,
+                           "model.json")) as f:
+        hf = json.load(f)
+    if name.startswith("lfm2"):
+        module, weights = M, 10.356e9
+        cfg = M.Lfm2MoeConfig.from_hf(hf)
+    else:
+        module, weights = dots3_note, 10.022e9
+        cfg = dots3_note.Dots3NoteConfig.from_hf(
+            hf, experts_held=(0, 32), vocab_held=(0, 19008))
+    monkeypatch.setattr(M, "device_bytes", lambda: limit)
+    assert M.rows_per_group(
+        rows, module._row_bytes(cfg, 8192),
+        weights + module._cache_bytes(cfg, rows, 8256),
+        8192 * cfg.hidden_size * 2) == want
 
 
 # -- the router --------------------------------------------------------------------
@@ -452,6 +573,9 @@ def test_guard_classify_goes_through_the_batcher_a_step_a_forward(
     marks = [f for n, f in seen if n == "engine.gen.forward"]
     assert [m["flavour"] for m in marks] == [s["flavour"] for s in steps]
     assert marks[0]["layers"] == 4 and marks[0]["pairs"] == 4 * n_prompt * 2
+    assert marks[0]["rows_per_group"] == 1 \
+        and "rows_per_group" not in marks[1]
+    assert rs.gen_rows_per_group.get(task="guard") == 1
     assert marks[1]["pairs"] == 4 * 2 and marks[1]["experts_touched"] == 8
     assert {v: rs.gen_forwards.get(task="guard", flavour=v) - before[v]
             for v in before} == {"gen.prefill": 1, "gen.decode": 5}

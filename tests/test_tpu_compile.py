@@ -80,6 +80,98 @@ def assert_each_pair_moves_once_each_way(compiled, tokens, k, hidden,
         < 0.75 * temp_before
 
 
+V5E_BYTES = 16_909_336_064  # a v5e's memory_stats()["bytes_limit"]
+
+
+def lfm2_param_shapes(cfg, shape):
+    """The ``lfm2_moe`` parameter tree of ``cfg`` as shapes."""
+    H, I, E, V, W = (cfg.hidden_size, cfg.moe_intermediate_size,
+                     cfg.num_experts, cfg.vocab_size, cfg.intermediate_size)
+    kv = cfg.num_key_value_heads * cfg.head_dim
+    layers = []
+    for i, kind in enumerate(cfg.layer_types):
+        p = {"norm1": shape((H,)), "norm2": shape((H,))}
+        if kind == "conv":
+            p.update(in_proj=shape((H, 3 * H)), conv_w=shape((3, H)),
+                     out_proj=shape((H, H)))
+        else:
+            p.update(q_proj=shape((H, H)), k_proj=shape((H, kv)),
+                     v_proj=shape((H, kv)), o_proj=shape((H, H)),
+                     q_norm=shape((cfg.head_dim,)),
+                     k_norm=shape((cfg.head_dim,)))
+        if cfg.is_sparse(i):
+            p.update(router=shape((H, E)),
+                     expert_bias=shape((E,), jnp.float32),
+                     gate_up=shape((E, H, 2 * I)), down=shape((E, I, H)))
+        else:
+            p.update(gate_up=shape((H, 2 * W)), down=shape((W, H)))
+        layers.append(p)
+    return {"embed": shape((V, H)), "norm": shape((H,)), "layers": layers}
+
+
+def dots3_param_shapes(cfg, shape):
+    """The ``dots3_note`` parameter tree of ``cfg`` as shapes."""
+    H, I, W = cfg.hidden_size, cfg.moe_intermediate_size, \
+        cfg.intermediate_size
+    E, held, V = cfg.n_routed_experts, cfg.held[1], cfg.vocab[1]
+    layers = []
+    for i, kind in enumerate(cfg.layer_types):
+        g = cfg.geometry(kind)
+        p = {"norm1": shape((H,)), "norm2": shape((H,)),
+             "q_a": shape((H, g.r_q)), "q_a_norm": shape((g.r_q,)),
+             "q_b": shape((g.r_q, g.heads * (g.nope + g.rope))),
+             "kv_a": shape((H, g.r_kv + g.rope)),
+             "kv_a_norm": shape((g.r_kv,)),
+             "kv_b": shape((g.r_kv, g.heads * (g.nope + g.v))),
+             "o_proj": shape((g.heads * g.v, H)),
+             "gate_proj": shape((H, g.heads))}
+        if kind == "full_attention":
+            d = cfg.index_head_dim
+            p.update(index_q=shape((g.r_q, cfg.index_n_heads * d)),
+                     index_k=shape((H, d)), index_k_norm=shape((d,)),
+                     index_k_bias=shape((d,)),
+                     index_w=shape((H, cfg.index_n_heads)))
+        if cfg.is_sparse(i):
+            p.update(router=shape((H, E)),
+                     expert_bias=shape((E,), jnp.float32),
+                     gate_up=shape((held, H, 2 * I)),
+                     down=shape((held, I, H)),
+                     shared={"gate_up": shape((H, 2 * I)),
+                             "down": shape((I, H))})
+        else:
+            p.update(gate_up=shape((H, 2 * W)), down=shape((W, H)))
+        layers.append(p)
+    return {"embed": shape((V, H)), "norm": shape((H,)),
+            "lm_head": shape((V, H)), "layers": layers}
+
+
+def on_the_described_chip(monkeypatch):
+    """Steer the programs' platform reads to the described v5e (the test's
+    backend is the CPU): the megablox kernel, the flash kernel compiled
+    and not interpreted, and the device's memory for ``rows_per_group``."""
+    from semantic_router_tpu.models import lfm2_moe, sdar_moe
+    from semantic_router_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    monkeypatch.setattr(
+        fa, "flash_attention_pallas",
+        functools.partial(fa.flash_attention_pallas, interpret=False))
+    monkeypatch.setattr(lfm2_moe, "device_bytes", lambda: V5E_BYTES)
+
+
+def cell_model(name):
+    """``chipbench/configs/<name>/model.json``: a cell's model numbers."""
+    import json
+    import os
+
+    import chipbench
+
+    with open(os.path.join(os.path.dirname(chipbench.__file__), "configs",
+                           name, "model.json")) as f:
+        return json.load(f)
+
+
 # padded batches 1..max_batch_size at the short buckets; the batches that
 # fit one chip's HBM at the long ones (32 x 32768 does not: see below)
 FLASH_SHAPES = [(b, s) for s in (128, 512) for b in (1, 2, 4, 8, 16, 32)] \
@@ -440,44 +532,27 @@ class TestHybridGuardCompilesForV5e:
                 compiled, tokens, cfg.num_experts_per_tok, H, 538_391_040)
 
     def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch):
-        """The generator's prefill (8 rows mapped inside it) and decode
-        programs over two layers that hold every kind of part (attention
-        with the dense MLP, then a convolution with experts): the
-        prefill's temporaries are one row's, the decode step writes the
-        donated hybrid cache in place and returns a small report."""
-        from semantic_router_tpu.models import lfm2_moe, sdar_moe
+        """The generator's prefill (8 rows mapped inside it, two a group)
+        and decode programs over two
+        layers that hold every kind of part (attention with the dense MLP,
+        then a convolution with experts): the prefill's temporaries are
+        one group's and under what ``rows_per_group`` reckoned for it,
+        the decode step writes the donated hybrid cache in place and
+        returns a small report."""
+        from semantic_router_tpu.models import lfm2_moe
         from semantic_router_tpu.models.generate import GreedyGenerator
-        from semantic_router_tpu.ops import flash_attention as fa
 
-        monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
-        monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
-        monkeypatch.setattr(
-            fa, "flash_attention_pallas",
-            functools.partial(fa.flash_attention_pallas, interpret=False))
+        on_the_described_chip(monkeypatch)
         cfg = lfm2_moe.Lfm2MoeConfig(
             layer_types=("full_attention", "conv"),
             num_hidden_layers=2, num_dense_layers=1)
-        H, I, E, V, W = (cfg.hidden_size, cfg.moe_intermediate_size,
-                         cfg.num_experts, cfg.vocab_size,
-                         cfg.intermediate_size)
+        H = cfg.hidden_size
         rows, S, M = 8, 8192, 8256
 
         def shape(dims, dtype=jnp.bfloat16):
             return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-        experts = {"router": shape((H, E)),
-                   "expert_bias": shape((E,), jnp.float32),
-                   "gate_up": shape((E, H, 2 * I)), "down": shape((E, I, H))}
-        norms = {"norm1": shape((H,)), "norm2": shape((H,))}
-        conv = {"in_proj": shape((H, 3 * H)), "conv_w": shape((3, H)),
-                "out_proj": shape((H, H))}
-        attn = {"q_proj": shape((H, 2048)), "k_proj": shape((H, 512)),
-                "v_proj": shape((H, 512)), "o_proj": shape((2048, H)),
-                "q_norm": shape((64,)), "k_norm": shape((64,))}
-        params = {"embed": shape((V, H)), "norm": shape((H,)), "layers": [
-            {**norms, **attn, "gate_up": shape((H, 2 * W)),
-             "down": shape((W, H))},
-            {**norms, **conv, **experts}]}
+        params = lfm2_param_shapes(cfg, shape)
         gen = GreedyGenerator(cfg, None, None,
                               model=lfm2_moe.CachedModel(cfg))
         args = (params, shape((rows, S), jnp.int32),
@@ -486,8 +561,10 @@ class TestHybridGuardCompilesForV5e:
         compiled = prefill.lower(*args).compile()
         # one causal flash call and an expert layer's two grouped matmuls
         assert compiled.as_text().count("tpu_custom_call") >= 3
-        # a row's 32 k pairs and its dense MLP, not eight rows' of them
-        assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2**30
+        assert gen.model.rows_per_group(params, rows, S, M) == 2
+        # two rows' 65 k pairs and their dense MLP, not eight rows'
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 2 * lfm2_moe._row_bytes(cfg, S) < 1.6 * 2**30
         cache, _, report, aux = jax.eval_shape(prefill, *args)
         assert report.shape == (rows, 2 + 2 * gen.top_logits)
         assert aux["experts"].shape == (1, rows, S, 4)
@@ -567,56 +644,27 @@ class TestSparseLatentGuardCompilesForV5e:
         assert compiled.as_text().count("tpu_custom_call") >= 2
 
     def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch):
-        """The generator's prefill (8 rows mapped inside it) and decode
-        programs over two layers that hold every kind of part (a full
-        layer with the dense MLP, a sliding layer with experts): the
-        prefill's temporaries are one row's, the decode step writes the
-        donated latent cache in place and returns a small report."""
-        from semantic_router_tpu.models import dots3_note, sdar_moe
+        """The generator's prefill (8 rows mapped inside it, one a group:
+        a row's activations are 84 MB) and decode programs over two
+        layers that hold every kind of part (a full layer with the dense
+        MLP, a sliding layer with experts): the prefill's temporaries are
+        one row's and under what ``rows_per_group`` reckoned for it,
+        the decode step writes the donated latent cache in place and
+        returns a small report."""
+        from semantic_router_tpu.models import dots3_note
         from semantic_router_tpu.models.generate import GreedyGenerator
-        from semantic_router_tpu.ops import flash_attention as fa
 
-        monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
-        monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
-        monkeypatch.setattr(
-            fa, "flash_attention_pallas",
-            functools.partial(fa.flash_attention_pallas, interpret=False))
+        on_the_described_chip(monkeypatch)
         cfg = dots3_note.Dots3NoteConfig(
             layer_types=("full_attention", "sliding_attention"),
             num_hidden_layers=2, experts_held=(0, 32),
             vocab_held=(0, 19008))
-        H, I, W = (cfg.hidden_size, cfg.moe_intermediate_size,
-                   cfg.intermediate_size)
         rows, S, M = 8, 8192, 8256
 
         def shape(dims, dtype=jnp.bfloat16):
             return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-        def attention(kind):
-            g = cfg.geometry(kind)
-            return {"norm1": shape((H,)), "norm2": shape((H,)),
-                    "q_a": shape((H, g.r_q)), "q_a_norm": shape((g.r_q,)),
-                    "q_b": shape((g.r_q, g.heads * (g.nope + g.rope))),
-                    "kv_a": shape((H, g.r_kv + g.rope)),
-                    "kv_a_norm": shape((g.r_kv,)),
-                    "kv_b": shape((g.r_kv, g.heads * (g.nope + g.v))),
-                    "o_proj": shape((g.heads * g.v, H)),
-                    "gate_proj": shape((H, g.heads))}
-
-        indexer = {"index_q": shape((1024, 64 * 128)),
-                   "index_k": shape((H, 128)), "index_k_norm": shape((128,)),
-                   "index_k_bias": shape((128,)), "index_w": shape((H, 64))}
-        experts = {"router": shape((H, 256)),
-                   "expert_bias": shape((256,), jnp.float32),
-                   "gate_up": shape((32, H, 2 * I)),
-                   "down": shape((32, I, H)),
-                   "shared": {"gate_up": shape((H, 2 * I)),
-                              "down": shape((I, H))}}
-        params = {"embed": shape((19008, H)), "norm": shape((H,)),
-                  "lm_head": shape((19008, H)), "layers": [
-            {**attention("full_attention"), **indexer,
-             "gate_up": shape((H, 2 * W)), "down": shape((W, H))},
-            {**attention("sliding_attention"), **experts}]}
+        params = dots3_param_shapes(cfg, shape)
         gen = GreedyGenerator(cfg, None, None,
                               model=dots3_note.CachedModel(cfg))
         args = (params, shape((rows, S), jnp.int32),
@@ -625,8 +673,10 @@ class TestSparseLatentGuardCompilesForV5e:
         compiled = prefill.lower(*args).compile()
         # both cores and an expert layer's two grouped matmuls
         assert compiled.as_text().count("tpu_custom_call") >= 4
+        assert gen.model.rows_per_group(params, rows, S, M) == 1
         # a row's heads, scores of one block of queries, a row's pairs
-        assert compiled.memory_analysis().temp_size_in_bytes < 4.5 * 2**30
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < dots3_note._row_bytes(cfg, S) < 5.5 * 2**30
         cache, _, report, aux = jax.eval_shape(prefill, *args)
         assert report.shape == (rows, 2 + 2 * gen.top_logits)
         assert aux["experts"].shape == (1, rows, S, 8)
@@ -650,3 +700,44 @@ class TestSparseLatentGuardCompilesForV5e:
             shape((rows,), jnp.int32), shape((rows,), jnp.int32),
             shape((), jnp.int32))
         assert aux["selected"].shape == (1, rows, M // 8)
+
+
+class TestLongPromptGuardsPrefillFitsAtTheRulesGroup:
+    """The 8 x 8192 prefill of both long-prompt guards at their cells'
+    widths and depths (``chipbench/configs/*/model.json``), at the rows a
+    group ``lfm2_moe.rows_per_group`` gives on a v5e's memory."""
+
+    @pytest.mark.parametrize("name, group", [("lfm2-24b-a2b-guard", 2),
+                                             ("dots3-note-guard", 1)])
+    def test_arguments_and_temporaries_fit(self, one_chip, monkeypatch, name,
+                                           group):
+        from semantic_router_tpu.models import dots3_note, lfm2_moe
+
+        on_the_described_chip(monkeypatch)
+        if name.startswith("lfm2"):
+            module, shapes = lfm2_moe, lfm2_param_shapes
+            cfg = lfm2_moe.Lfm2MoeConfig.from_hf(cell_model(name))
+        else:
+            module, shapes = dots3_note, dots3_param_shapes
+            cfg = dots3_note.Dots3NoteConfig.from_hf(
+                cell_model(name), experts_held=(0, 32),
+                vocab_held=(0, 19008))
+        groups = []
+        real = module._prefill_groups
+        monkeypatch.setattr(
+            module, "_prefill_groups",
+            lambda *a: groups.append(a[-1]) or real(*a))
+
+        def shape(dims, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+        compiled = jax.jit(
+            lambda p, i, n: module.prefill(cfg, p, i, n, 8256)).lower(
+                shapes(cfg, shape), shape((8, 8192), jnp.int32),
+                shape((8,), jnp.int32)).compile()
+        assert groups == [group]
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes > 10.0e9  # the cell's weights
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 15.0 * 2**30
+        assert mem.temp_size_in_bytes < group * module._row_bytes(cfg, 8192)
