@@ -258,16 +258,34 @@ class _GenerationObserver:
     (the counters of /metrics).  The prefill step carries the batch items, so
     a traced request's queue wait ends where its generation begins.  A
     generation has one forward open at a time: the observer is its
-    handle."""
+    handle.
+
+    Between the steps the observer keeps the generation's clock
+    (``batchtrace.GenerationClock``): an ``engine.gen.turn`` annotation
+    from each step's close to the next one's open — this class's own
+    marker and counters and the generator's loop top run inside it; after
+    a ``done()`` and before the next ``forward()``, ``stage(name)`` is a
+    stage of that turn — and the one after the last forward until the
+    runner calls ``end()``, which writes ``engine.gen.done`` and the
+    generation's seconds by phase
+    (``runtimestats.record_generation_done``)."""
 
     def __init__(self, engine, task: str, bucket: int, items, padded_rows: int
                  ) -> None:
+        from ..observability import batchtrace
+
         self.engine, self.task, self.bucket = engine, task, bucket
         self.items, self.padded_rows = items, padded_rows
         self.step: Optional[EngineStep] = None
+        self.clock = batchtrace.GenerationClock(
+            f"gen:{task}", rows=len(items), padded_rows=int(padded_rows),
+            bucket=int(bucket))
+        self._block = -1
 
     def forward(self, flavour: str, tokens_real: int = 0,
                 tokens_padded: int = 0, **facts):
+        self.clock.step_opens()
+        self._block = int(facts.get("block", -1))
         self.step = EngineStep(
             self.engine, self.items if flavour == "gen.prefill" else (),
             scope="gen", name=self.task, bucket=self.bucket,
@@ -278,6 +296,8 @@ class _GenerationObserver:
         return self
 
     def stage(self, name: str):
+        if self.step is None:
+            return self.clock.turn_stage(name)
         return self.step.stage(name)
 
     def done(self, load=None, committed_blocks: int = 0,
@@ -300,6 +320,8 @@ class _GenerationObserver:
         step = self.step
         step.ran()
         self.close()
+        self.clock.blocks += committed_blocks
+        self.clock.tokens += committed_tokens
         if load is not None:
             batchtrace.gen_forward(step.group, step.variant, load, keys,
                                    rows_per_group)
@@ -312,11 +334,27 @@ class _GenerationObserver:
             pass  # observability never fails a generation
 
     def close(self) -> None:
-        """End the open forward's step: after ``done``, or when the
-        forward raised before it."""
+        """End the open forward's step, after ``done`` or when the forward
+        raised before it; the turn that follows it begins."""
         if self.step is not None:
             self.step.finish()
+            self.clock.step_closed(self.step.variant, self._block)
             self.step = None
+
+    def end(self, done: bool) -> None:
+        """The runner has its results (``done``) or gave up: the open
+        forward, if one raised, and the last turn end; a generation that
+        came through says what it was (``engine.gen.done``) and where its
+        seconds went."""
+        self.close()
+        seconds = self.clock.end(done)
+        if seconds is None:
+            return
+        try:
+            self.engine._runtime_stats.record_generation_done(
+                self.task, seconds)
+        except Exception:
+            pass  # observability never fails a generation
 
 
 @dataclass
@@ -1463,8 +1501,16 @@ class InferenceEngine:
         # (_run_generative), at most one in flight per group
         largest = self.cfg.seq_len_buckets[-1]
         payloads = []
+        from ..observability import batchtrace
+
         for p in prompts:
+            # beside the tokenize seam (no length to clip at, no cache, and
+            # no counter or span on a path where sixteen callers stand in
+            # line between two generations): the marker alone
+            t0 = time.perf_counter()
             enc = t.tokenizer.encode(p)
+            batchtrace.tokenized(task, time.perf_counter() - t0, len(enc),
+                                 cached=False)
             # a generator of prompts only tokenizes for itself: no cut here
             if len(enc) > largest and getattr(t.generator, "batched", False):
                 enc = _cut_to_bucket(enc, largest, keep_tail)
@@ -2130,7 +2176,10 @@ class InferenceEngine:
         traditional path's per-task attribution so existing dashboards
         keep reading.  Returns (encoding, seconds spent encoding,
         cache-hit) so batch tracing can attribute host tokenization per
-        request."""
+        request; the same seconds go on the profiler's clock here
+        (``engine.tokenize``; a cache hit's are the lookup)."""
+        from ..observability import batchtrace
+
         t0 = time.perf_counter()
         missed = []
         if enc_cache is None:
@@ -2145,6 +2194,7 @@ class InferenceEngine:
             enc = enc_cache.get_or_encode(tokenizer, text, max_seq_len,
                                           on_miss=on_miss)
         tok_s = time.perf_counter() - t0
+        batchtrace.tokenized(tok_tag, tok_s, len(enc), cached=not missed)
         if enc.truncated:
             s = self._series()
             for tag in trunc_tags:
@@ -2314,18 +2364,20 @@ class InferenceEngine:
         encodings = [it.payload.encoding for it in items]
         observer = _GenerationObserver(self, task_name, bucket, items,
                                        padded_n)
+        done = False
         try:
             results = gen.generate(
                 texts, **settings, encodings=encodings, bucket=bucket,
                 padded_rows=padded_n, observer=observer)
+            # prompts that generate() cut to the largest bucket
+            n_cut = sum(enc.truncated for enc in encodings)
+            if n_cut:
+                self._series().bucket_overflows.inc(n_cut, task=task_name)
+                for res, enc in zip(results, encodings):
+                    res.truncated = enc.truncated
+            done = True
         finally:
-            observer.close()
-        # prompts that generate() cut to the largest bucket
-        n_cut = sum(enc.truncated for enc in encodings)
-        if n_cut:
-            self._series().bucket_overflows.inc(n_cut, task=task_name)
-            for res, enc in zip(results, encodings):
-                res.truncated = enc.truncated
+            observer.end(done)
         return results
 
     def _run_fused_batch(self, gid: str, bucket: int,

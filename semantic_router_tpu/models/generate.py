@@ -286,7 +286,8 @@ class _NullForward:
 class NullObserver:
     """``forward(flavour, **facts)`` before every device forward of a
     generation; its ``stage(name)`` brackets the five host stages and
-    ``done(**after)`` closes it with what only the readback knows."""
+    ``done(**after)`` closes it with what only the readback knows.  A
+    ``stage(name)`` after ``done()`` is host work between two forwards."""
 
     def forward(self, flavour: str, **facts) -> _NullForward:
         return _NullForward()
@@ -774,8 +775,10 @@ class BlockDiffusionGenerator:
                     tokens[i, :tail[i]] = ids[i, base[i]:lengths[i]]
                     masked[i, :tail[i]] = False
             live = [i for i in range(n) if b < blocks_of[i]]
-            start_dev = jnp.asarray(base + b * L)
-            tokens_dev, masked_dev = jnp.asarray(tokens), jnp.asarray(masked)
+            with fwd.stage("h2d"):  # of the turn after the last forward
+                start_dev = jnp.asarray(base + b * L)
+                tokens_dev = jnp.asarray(tokens)
+                masked_dev = jnp.asarray(masked)
             # a real row's block begins with a mask (a prompt's tail is
             # shorter than a block), so every block has a first forward
             for step in range(self.denoising_steps):

@@ -46,6 +46,25 @@ the batch iteration it rode in:
    and ``router.route.done`` at its end (``trace_id``, ``route_us``), so
    a profile joins an item to its route by id.
 
+5. **A generation's time** — a generation is many steps on one thread,
+   and the device idles between them.  Where that time goes is written
+   around the steps, never inside them: ``engine.gen.turn`` (``group``,
+   ``after``, ``block``) runs from the moment a forward's step closed to
+   the moment the next one's opens — the ``engine.gen.forward`` marker,
+   the counters and the generator's loop top are inside it, a block
+   generator's copies as its stage ``engine.gen.turn.h2d`` — and the one
+   after a generation's last forward until the runner has its results;
+   ``engine.gen.done`` marks a generation's END and carries its counts
+   (``forwards``, ``blocks``, ``tokens``) and, on the host clock, how
+   its length divides into steps, turns and the finish
+   (``GenerationClock``, which also feeds
+   ``llm_runtime_gen_seconds_total`` for an operator without a
+   session); ``engine.tokenize`` marks the end of a tokenization on the
+   caller's thread (``trace_id``, ``tag``, ``tok_us``, ``tokens``,
+   ``cached``), so what stood between two generations — finish,
+   callers, tokenization, queue wait, the prefill's head — can be read
+   off one clock.
+
 Known tradeoff: the host stages see the device only through the readback
 wait — ``dispatch`` is the enqueue, ``readback`` holds the program's whole
 device time plus the transfer.  Trunk against heads is not a host stage:
@@ -75,6 +94,10 @@ STAGES = ("stack", "h2d", "dispatch", "readback", "demux")
 STAGE_ANNOTATIONS = {n: f"{STEP_ANNOTATION}.{n}" for n in STAGES}
 QUEUE_WAIT_ANNOTATION = "engine.queue_wait"
 GEN_FORWARD_ANNOTATION = "engine.gen.forward"
+GEN_TURN_ANNOTATION = "engine.gen.turn"
+GEN_TURN_STAGE_ANNOTATIONS = {n: f"{GEN_TURN_ANNOTATION}.{n}" for n in STAGES}
+GEN_DONE_ANNOTATION = "engine.gen.done"
+TOKENIZE_ANNOTATION = "engine.tokenize"
 ROUTE_ANNOTATION = "router.route"
 ROUTE_DONE_ANNOTATION = "router.route.done"
 
@@ -332,3 +355,91 @@ def route_done(trace_id: str, route_s: float) -> None:
     with trace_span(ROUTE_DONE_ANNOTATION, trace_id=trace_id,
                     route_us=int(route_s * 1e6)):
         pass
+
+
+def tokenized(tag: str, tok_s: float, tokens: int, cached: bool) -> None:
+    """``engine.tokenize``: a tokenization (or the lookup that spared one)
+    ended now on this thread, after ``tok_s``: the marker at its END with
+    its length and the calling thread's trace id (what
+    ``engine.queue_wait`` and ``router.route.done`` carry; empty when
+    none).  Nothing else happens here — no lock, no span, no system call:
+    between two generations every caller passes this way, one after
+    another (the request's own ``batch.tokenize`` span is written later
+    from the same seconds, by the step its item rides)."""
+    top = active_span()
+    with trace_span(TOKENIZE_ANNOTATION,
+                    trace_id=top[1].trace_id if top is not None else "",
+                    tag=tag, tok_us=int(tok_s * 1e6), tokens=int(tokens),
+                    cached=int(cached)):
+        pass
+
+
+class GenerationClock:
+    """Where a generation's host time went, between its steps: one
+    ``time.perf_counter()`` read where a forward's step opens and one
+    where it closes, so that the steps (open to close: the device wait),
+    the turns between them and the finish after the last tile the
+    generation exactly.  ``step_opens()`` / ``step_closed()`` bracket
+    every forward and keep the ``engine.gen.turn`` annotation open in
+    between; ``end()`` closes the last turn and, for a generation that
+    came to its results, writes ``engine.gen.done`` and returns the
+    seconds by phase."""
+
+    def __init__(self, group: str, **facts: int) -> None:
+        self.group, self.facts = group, facts
+        self.forwards = self.blocks = self.tokens = 0
+        self._began: Optional[float] = None
+        self._mark = 0.0  # the last edge: a step's open or its close
+        self._steps_s = self._turns_s = self._turn_max_s = 0.0
+        self._turn_max_after = -1
+        self._turn = None
+
+    def _close_turn(self, now: float) -> float:
+        self._turn.__exit__(None, None, None)
+        self._turn = None
+        return now - self._mark
+
+    def step_opens(self) -> None:
+        now = time.perf_counter()
+        if self._began is None:
+            self._began = now
+        if self._turn is not None:
+            turn = self._close_turn(now)
+            self._turns_s += turn
+            if turn > self._turn_max_s:
+                self._turn_max_s = turn
+                self._turn_max_after = self.forwards - 1
+        self._mark = now
+
+    def step_closed(self, after: str, block: int) -> None:
+        now = time.perf_counter()
+        self._steps_s += now - self._mark
+        self._mark = now
+        self.forwards += 1
+        self._turn = trace_span(GEN_TURN_ANNOTATION, group=self.group,
+                                after=after, block=int(block))
+        self._turn.__enter__()
+
+    def turn_stage(self, name: str):
+        """A stage of the open turn (``engine.gen.turn.<name>``)."""
+        return trace_span(GEN_TURN_STAGE_ANNOTATIONS[name])
+
+    def end(self, done: bool) -> Optional[Dict[str, float]]:
+        if self._turn is None:
+            return None
+        now = time.perf_counter()
+        finish = self._close_turn(now)
+        if not done:
+            return None
+        us = {k: int(v * 1e6) for k, v in (
+            ("generation_us", now - self._began),
+            ("steps_us", self._steps_s), ("turns_us", self._turns_s),
+            ("finish_us", finish), ("turn_max_us", self._turn_max_s))}
+        with trace_span(GEN_DONE_ANNOTATION, group=self.group,
+                        forwards=self.forwards, blocks=self.blocks,
+                        tokens=self.tokens,
+                        turn_max_after=self._turn_max_after,
+                        **self.facts, **us):
+            pass
+        return {"forward": self._steps_s, "turn": self._turns_s,
+                "finish": finish}

@@ -190,6 +190,17 @@ class RuntimeStats:
         self.gen_tokens = registry.counter(
             "llm_runtime_gen_tokens_committed_total",
             "Tokens committed to the cache by generative tasks")
+        self.gen_seconds = registry.counter(
+            "llm_runtime_gen_seconds_total",
+            "Host-clock seconds of finished generations by phase: forward "
+            "(a step, open to close: the device wait), turn (between two "
+            "forwards: the host alone), finish (after the last forward "
+            "until the runner had its results); (turn + finish) over the "
+            "three is the host's share of a generation")
+        self.gen_generations = registry.counter(
+            "llm_runtime_gen_generations_total",
+            "Generations that came to their results, one per batch of "
+            "rows in lock step")
         self.gen_cache_bytes = registry.gauge(
             "llm_runtime_gen_cache_bytes",
             "Bytes of a generative task's newest cache by kind of state "
@@ -273,6 +284,19 @@ class RuntimeStats:
             self.gen_blocks.inc(committed_blocks, task=task)
         if committed_tokens:
             self.gen_tokens.inc(committed_tokens, task=task)
+
+    def record_generation_done(self, task: str,
+                               seconds: Dict[str, float]) -> None:
+        """One generation came to its results: its host-clock seconds by
+        phase (``forward`` | ``turn`` | ``finish``, the sums its
+        ``engine.gen.done`` marker carries) into
+        llm_runtime_gen_seconds_total, and one
+        llm_runtime_gen_generations_total."""
+        if not self.enabled:
+            return
+        for phase, secs in seconds.items():
+            self.gen_seconds.inc(secs, task=task, phase=phase)
+        self.gen_generations.inc(task=task)
 
     # -- aggregation -------------------------------------------------------
 

@@ -336,7 +336,7 @@ class _Samples:
     runners hand it."""
 
     def __init__(self):
-        self.steps, self.generations = [], []
+        self.steps, self.generations, self.finished = [], [], []
 
     def record_step(self, group, bucket, variant, rows, padded_rows,
                     seconds, **kw):
@@ -347,20 +347,27 @@ class _Samples:
     def record_generation(self, task, flavour, **kw):
         self.generations.append((task, flavour))
 
+    def record_generation_done(self, task, seconds):
+        self.finished.append((task, seconds))
+
+
+class _Rows:
+    """A token a character, twelve at most."""
+
+    def encode(self, text, max_length=0):
+        from semantic_router_tpu.utils.tokenization import Encoding
+
+        ids = [3 + ord(c) % 200 for c in text[:12]]
+        return Encoding(ids=ids, attention_mask=[1] * len(ids),
+                        offsets=[(0, 0)] * len(ids))
+
+    def decode(self, ids):
+        return " ".join(str(int(i)) for i in ids)
+
 
 def _toy_generator():
     from semantic_router_tpu.models.generate import GreedyGenerator
     from semantic_router_tpu.models.qwen3 import Qwen3Config, Qwen3ForCausalLM
-    from semantic_router_tpu.utils.tokenization import Encoding
-
-    class Rows:
-        def encode(self, text, max_length=0):
-            ids = [3 + ord(c) % 200 for c in text[:12]]
-            return Encoding(ids=ids, attention_mask=[1] * len(ids),
-                            offsets=[(0, 0)] * len(ids))
-
-        def decode(self, ids):
-            return " ".join(str(int(i)) for i in ids)
 
     cfg = Qwen3Config(vocab_size=256, hidden_size=64, intermediate_size=128,
                       num_hidden_layers=2, num_attention_heads=4,
@@ -368,7 +375,7 @@ def _toy_generator():
                       tie_word_embeddings=True)
     params = Qwen3ForCausalLM(cfg).init(
         jax.random.PRNGKey(0), jax.numpy.zeros((1, 8), jax.numpy.int32))
-    return GreedyGenerator(cfg, params, Rows())
+    return GreedyGenerator(cfg, params, _Rows())
 
 
 def _generative_engine():
@@ -549,3 +556,301 @@ def test_per_task_and_fused_decode_alike_with_fewer_labels_than_width(via):
     finally:
         split.shutdown()
         fused.shutdown()
+
+
+# -- a generation's host time, between its steps -------------------------------
+
+
+def _toy_block_generator():
+    from semantic_router_tpu.models import sdar_moe
+    from tests import test_sdar_moe as toy
+
+    state = toy.family.generate_state(toy.CONFIG, 7)
+    cfg = sdar_moe.SdarMoeConfig.from_hf(toy.MODEL)
+    gen = toy.generator(
+        (state, cfg, sdar_moe.params_from_state(state.__getitem__, cfg)))
+    gen.tokenizer = _Rows()
+    return gen
+
+
+def _greedy_loop():
+    return _toy_generator(), 4, {"gen.prefill": 1, "gen.decode": 3}
+
+
+def _block_loop():
+    # prompts of 8 and 7 tokens and 8 new ones: three blocks, the second
+    # and third begun by the forward that commits the one before
+    return _toy_block_generator(), 8, {"gen.prefill": 1, "gen.denoise": 10,
+                                       "gen.commit": 2}
+
+
+LOOPS = {"greedy": _greedy_loop, "blockdiff": _block_loop}
+PROMPTS = ["a prompt", "another"]
+
+
+@pytest.fixture(params=sorted(LOOPS))
+def loop(request):
+    """(engine, new tokens, forwards by flavour) of a toy generation
+    through one of the two loops, its programs compiled."""
+    from semantic_router_tpu.config.schema import InferenceEngineConfig
+    from semantic_router_tpu.engine.classify import InferenceEngine
+
+    gen, new_tokens, forwards = LOOPS[request.param]()
+    eng = InferenceEngine(InferenceEngineConfig(
+        max_batch_size=4, max_wait_ms=1.0, seq_len_buckets=[32]))
+    eng.register_generative("guard", gen)
+    eng.generate("guard", PROMPTS, max_new_tokens=new_tokens)
+    yield eng, new_tokens, forwards
+    eng.shutdown()
+
+
+def _named(rows, name):
+    return sorted((r for r in rows if r[1] == name), key=lambda r: r[2])
+
+
+def _gen_counters(eng):
+    rs = eng._runtime_stats
+    return {"forwards": sum(rs.gen_forwards._values.values()),
+            "blocks": rs.gen_blocks.get(task="guard"),
+            "tokens": rs.gen_tokens.get(task="guard"),
+            "generations": rs.gen_generations.get(task="guard"),
+            **{phase: rs.gen_seconds.get(task="guard", phase=phase)
+               for phase in ("forward", "turn", "finish")}}
+
+
+def test_steps_and_turns_tile_a_generation(loop, tmp_path):
+    eng, new_tokens, forwards = loop
+    _, rows = _profiled(tmp_path, lambda: eng.generate(
+        "guard", PROMPTS, max_new_tokens=new_tokens))
+    steps = _named(rows, batchtrace.STEP_ANNOTATION)
+    turns = _named(rows, batchtrace.GEN_TURN_ANNOTATION)
+    (done,) = _named(rows, batchtrace.GEN_DONE_ANNOTATION)
+    assert len(steps) == len(turns) == sum(forwards.values())
+    # one thread: step, turn, step, turn, ..., the last turn, done
+    assert {r[0] for r in steps + turns + [done]} == {steps[0][0]}
+    pieces = sorted(steps + turns, key=lambda r: r[2])
+    assert [r[1] for r in pieces] == [
+        batchtrace.STEP_ANNOTATION, batchtrace.GEN_TURN_ANNOTATION
+    ] * len(steps)
+    # they do not overlap, and what lies under neither (a turn's exit to
+    # the next annotation's enter) is microseconds on a quiet machine, a
+    # thread switch on one that runs other tests
+    for a, b in zip(pieces, pieces[1:] + [done]):
+        assert a[3] <= b[2]
+        assert b[2] - a[3] < 50_000_000, (a[1], b[1], b[2] - a[3])
+    # a turn says which forward it follows
+    for step, turn in zip(steps, turns):
+        assert turn[4]["after"] == step[4]["flavour"]
+        assert turn[4]["group"] == "gen:guard"
+        assert turn[4]["block"] == step[4].get("block", -1)
+    # the forward's marker stays where its readers look for it: after its
+    # step's end and before the next step's start (inside the turn)
+    marks = _named(rows, batchtrace.GEN_FORWARD_ANNOTATION)
+    if "gen.commit" in forwards:  # the expert model reports its load
+        assert len(marks) == len(steps)
+        for step, turn, mark in zip(steps, turns, marks):
+            assert mark[4]["flavour"] == step[4]["flavour"]
+            assert step[3] <= mark[2] and turn[2] <= mark[2] <= turn[3]
+        # a block's copies are a stage of the turn before its first forward
+        copies = _named(rows, batchtrace.GEN_TURN_STAGE_ANNOTATIONS["h2d"])
+        assert len(copies) == 3
+        for c in copies:
+            assert any(t[2] <= c[2] and c[3] <= t[3] for t in turns)
+    else:
+        assert marks == []
+
+
+def test_gen_done_carries_the_counters_deltas_and_a_tiling(loop, tmp_path):
+    eng, new_tokens, forwards = loop
+    before = _gen_counters(eng)
+    _, rows = _profiled(tmp_path, lambda: eng.generate(
+        "guard", PROMPTS, max_new_tokens=new_tokens))
+    after = _gen_counters(eng)
+    (done,) = _named(rows, batchtrace.GEN_DONE_ANNOTATION)
+    facts = done[4]
+    assert (facts["group"], facts["rows"], facts["padded_rows"],
+            facts["bucket"]) == ("gen:guard", 2, 2, 32)
+    for count in ("forwards", "blocks", "tokens"):
+        assert facts[count] == after[count] - before[count], count
+    assert facts["forwards"] == sum(forwards.values())
+    assert after["generations"] - before["generations"] == 1
+    # the three parts tile the whole, on the host clock ...
+    parts = facts["steps_us"] + facts["turns_us"] + facts["finish_us"]
+    assert abs(parts - facts["generation_us"]) < 1000
+    assert 0 < facts["turn_max_us"] <= facts["turns_us"]
+    assert 0 <= facts["turn_max_after"] < facts["forwards"] - 1
+    # ... and are what /metrics got
+    for phase, fact in (("forward", "steps_us"), ("turn", "turns_us"),
+                        ("finish", "finish_us")):
+        assert (after[phase] - before[phase]) * 1e6 == pytest.approx(
+            facts[fact], abs=2)
+    # and the same generation as on the profiler's clock, first step's
+    # open to the marker (two clocks read microseconds apart, on a machine
+    # that may run other tests: loosely)
+    steps = _named(rows, batchtrace.STEP_ANNOTATION)
+    turns = _named(rows, batchtrace.GEN_TURN_ANNOTATION)
+    assert (done[2] - steps[0][2]) / 1e3 == pytest.approx(
+        facts["generation_us"], rel=0.5)
+    assert sum(t[3] - t[2] for t in turns[:-1]) / 1e3 <= \
+        facts["turns_us"] + 1000
+
+
+def _break_a_later_forward(eng):
+    """The generator's second kind of program raises, so that a turn is
+    open when it does."""
+    def boom(*a, **kw):
+        raise RuntimeError("program down")
+
+    gen = eng._tasks["guard"].generator
+    if hasattr(gen, "programs"):
+        real = gen.programs
+        gen.programs = lambda *a: (real(*a)[0], boom, boom)
+        return lambda: setattr(gen, "programs", real)
+    real = gen._step_fn
+    gen._step_fn = lambda key: boom
+    return lambda: setattr(gen, "_step_fn", real)
+
+
+def test_a_raising_forward_ends_its_turn_and_writes_no_done(loop, tmp_path):
+    eng, new_tokens, forwards = loop
+    before = _gen_counters(eng)
+    undo = _break_a_later_forward(eng)
+
+    def go():
+        with pytest.raises(RuntimeError, match="program down"):
+            eng.generate("guard", PROMPTS, max_new_tokens=new_tokens)
+
+    _, rows = _profiled(tmp_path / "down", go)
+    steps = _named(rows, batchtrace.STEP_ANNOTATION)
+    turns = _named(rows, batchtrace.GEN_TURN_ANNOTATION)
+    # the prefill and the forward that raised: both steps ended, and so
+    # did the turn after each (an annotation left open leaves no event)
+    assert [s[4]["flavour"] for s in steps] == [
+        "gen.prefill", "gen.denoise" if "gen.commit" in forwards
+        else "gen.decode"]
+    assert len(turns) == 2
+    for step, turn in zip(steps, turns):
+        assert step[3] <= turn[2] and turn[4]["after"] == step[4]["flavour"]
+    assert _named(rows, batchtrace.GEN_DONE_ANNOTATION) == []
+    after = _gen_counters(eng)
+    assert after["generations"] == before["generations"]
+    assert after["forward"] == before["forward"]
+    # ... and the next generation is whole
+    undo()
+    _, rows = _profiled(tmp_path / "up", lambda: eng.generate(
+        "guard", PROMPTS, max_new_tokens=new_tokens))
+    (done,) = _named(rows, batchtrace.GEN_DONE_ANNOTATION)
+    assert done[4]["forwards"] == sum(forwards.values())
+
+
+def _spy_programs(eng, calls):
+    """Every call of the generator's programs: (which, its small array
+    arguments as bytes) — the parameters and the cache are left out."""
+    import numpy as np
+
+    def small(a):
+        return isinstance(a, (int, float)) or (
+            hasattr(a, "shape") and hasattr(a, "dtype")
+            and np.prod(a.shape) < 4096)
+
+    def spied(name, fn):
+        def call(*args):
+            calls.append((name, tuple(np.asarray(a).tobytes()
+                                      for a in args if small(a))))
+            return fn(*args)
+        return call
+
+    gen = eng._tasks["guard"].generator
+    if hasattr(gen, "programs"):
+        real = gen.programs
+        gen.programs = lambda *a: tuple(
+            spied(n, f) for n, f in zip(("prefill", "denoise", "commit"),
+                                        real(*a)))
+    else:
+        pre, step = gen._prefill_fn, gen._step_fn
+        gen._prefill_fn = lambda key: spied("prefill", pre(key))
+        gen._step_fn = lambda key: spied("decode", step(key))
+
+
+def test_a_session_never_changes_a_generations_programs(loop, tmp_path):
+    eng, new_tokens, forwards = loop
+    calls, got = [], {}
+    _spy_programs(eng, calls)
+
+    def ask(tracer):
+        with _span(tracer):
+            out = eng.generate("guard", PROMPTS, max_new_tokens=new_tokens)
+        return [r.token_ids for r in out], list(calls)
+
+    for label, tracer in (("untraced", None),
+                          ("sampled", Tracer(sample_rate=1.0))):
+        del calls[:]
+        got[label] = ask(tracer)
+    del calls[:]
+    got["session"], _ = _profiled(tmp_path, lambda: ask(None))
+    tokens, programs = got["untraced"]
+    assert len(programs) == sum(forwards.values())
+    assert [name for name, _ in programs].count("prefill") == 1
+    # the same programs with the same arguments, bit for bit, whatever
+    # watches: a request trace, a profiler session, nobody
+    assert got["sampled"] == got["session"] == (tokens, programs)
+
+
+def _through_generate(tracer):
+    eng = _generative_engine()
+    eng.generate("guard", ["warm the programs"], max_new_tokens=2)
+
+    def go():
+        with tracer.span("router.route") as root:
+            eng.generate("guard", ["a traced prompt"], max_new_tokens=2)
+        return root.trace_id
+
+    return eng, go, "guard", [0], 0
+
+
+def _through_the_fused_bank(tracer):
+    from semantic_router_tpu.utils.tokenization import EncodingCache
+
+    eng = make_shared_trunk_engine(token_tasks=[PII])
+    eng.classify_multi(SEQ_TASKS, ["warm the shape first"])
+
+    def go():
+        cache = EncodingCache()
+        with tracer.span("router.route") as root:
+            eng.classify_multi(SEQ_TASKS, [TEXT], enc_cache=cache)
+            eng.classify_multi(SEQ_TASKS, [TEXT], enc_cache=cache)
+        return root.trace_id
+
+    return eng, go, "trunk0", [0, 1], 2
+
+
+@pytest.mark.parametrize("make", [_through_generate,
+                                  _through_the_fused_bank])
+def test_tokenize_marker_carries_the_routes_trace_id(make, tmp_path):
+    tracer = Tracer(sample_rate=0.0)
+    eng, go, tag, cached, spans_written = make(tracer)
+    try:
+        trace_id, rows = _profiled(tmp_path, go)
+    finally:
+        eng.shutdown()
+    toks = _named(rows, batchtrace.TOKENIZE_ANNOTATION)
+    waits = _named(rows, batchtrace.QUEUE_WAIT_ANNOTATION)
+    # one marker a tokenization, a cache hit among them, each with the id
+    # that its item's queue wait carries
+    assert [t[4]["cached"] for t in toks] == cached
+    assert len(waits) == len(toks)
+    for tok, wait in zip(toks, waits):
+        assert tok[4]["trace_id"] == wait[4]["trace_id"] == trace_id
+        assert tok[4]["tag"] == tag and tok[4]["tokens"] > 0
+        assert tok[4]["tok_us"] >= 0
+        assert tok[3] <= wait[3]  # tokenized, then queued
+    # the marker is all a caller writes where it tokenized; the request's
+    # batch.tokenize span comes from the same seconds when its item's step
+    # ends (a generation's prompts have none, as before)
+    spans = tracer.spans(batchtrace.TOKENIZE_SPAN)
+    assert [s.attributes["cache_hit"] for s in spans] == \
+        [bool(c) for c in cached][:spans_written]
+    for span, tok in zip(spans, toks):
+        assert span.trace_id == trace_id
+        assert span.duration_s * 1e6 == pytest.approx(tok[4]["tok_us"],
+                                                      abs=2)
