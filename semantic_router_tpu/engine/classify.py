@@ -243,31 +243,34 @@ def _cut_to_bucket(enc: Encoding, bucket: int, keep_tail: int) -> Encoding:
 
 
 class _GenerationObserver:
-    """What a generator's ``generate`` reports to, forward by
-    forward (models.generate.NullObserver has the protocol): each forward
-    is one EngineStep — one ``engine.step`` annotation with its stages
-    (flavour ``gen.prefill`` | ``gen.denoise`` | ``gen.commit`` |
-    ``gen.decode``; ``gen.commit`` is the forward that commits block
-    ``b`` and begins block ``b + 1``, two blocks of tokens a row; facts
-    ``rows``, ``padded_rows``, ``tokens_real`` — a prefill's padded
-    positions are ``padded_rows`` x ``bucket`` — ``block`` (the block begun
-    or gone on with, or the decode step), ``masks_left``) and one
-    ``record_step`` sample (a prefill's with its token fill) under
-    group ``gen:<task>`` with the flavour as its variant, whose clock runs
-    from ``forward`` to ``done`` — and one ``record_generation`` count
-    (the counters of /metrics).  The prefill step carries the batch items, so
-    a traced request's queue wait ends where its generation begins.  A
-    generation has one forward open at a time: the observer is its
-    handle.
+    """What a generator's ``generate`` reports to, turn by turn of the host
+    (models.generate.NullObserver has the protocol): each stretch of
+    device work the host dispatches before it reads anything back is one
+    EngineStep — one ``engine.step`` annotation with its stages (flavour
+    ``gen.prefill`` | ``gen.denoise`` | ``gen.commit`` | ``gen.decode``;
+    facts ``rows``, ``padded_rows``, ``tokens_real`` — a prefill's padded
+    positions are ``padded_rows`` x ``bucket`` — ``block`` (the block run,
+    or the decode step), ``masks_left``) and one ``record_step`` sample (a
+    prefill's with its token fill) under group ``gen:<task>`` with the
+    flavour as its variant, whose clock runs from ``forward`` to ``done``
+    — and one ``record_generation`` count (the counters of /metrics).  A
+    token-at-a-time generator's step is one forward.  A block generator's
+    is a BLOCK: ``gen.denoise`` is block 0's loop of forwards,
+    ``gen.commit`` a later block's (its first forward commits block ``b -
+    1`` beside block ``b``, two blocks of tokens a row, which is its
+    ``tokens_real``; the loop is queued behind it); ``done(forwards=n)``
+    says how many forwards the device ran in it, known only from the
+    readback, so the fact is the ``engine.gen.forward`` marker's and not
+    the step's.  The prefill step carries the batch items, so a traced
+    request's queue wait ends where its generation begins.  A generation
+    has one step open at a time: the observer is its handle.
 
     Between the steps the observer keeps the generation's clock
     (``batchtrace.GenerationClock``): an ``engine.gen.turn`` annotation
     from each step's close to the next one's open — this class's own
-    marker and counters and the generator's loop top run inside it; after
-    a ``done()`` and before the next ``forward()``, ``stage(name)`` is a
-    stage of that turn — and the one after the last forward until the
-    runner calls ``end()``, which writes ``engine.gen.done`` and the
-    generation's seconds by phase
+    marker and counters and the generator's loop top run inside it — and
+    the one after the last step until the runner calls ``end()``, which
+    writes ``engine.gen.done`` and the generation's seconds by phase
     (``runtimestats.record_generation_done``)."""
 
     def __init__(self, engine, task: str, bucket: int, items, padded_rows: int
@@ -296,18 +299,17 @@ class _GenerationObserver:
         return self
 
     def stage(self, name: str):
-        if self.step is None:
-            return self.clock.turn_stage(name)
         return self.step.stage(name)
 
-    def done(self, load=None, committed_blocks: int = 0,
+    def done(self, load=None, forwards: int = 1, committed_blocks: int = 0,
              committed_tokens: int = 0, cache_bytes=None, keys=None,
              rows_per_group=None) -> None:
         """``load [layers, 4]`` of an expert model
-        (models.sdar_moe.routed_experts); a dense generator gives none.
-        ``committed_blocks`` / ``committed_tokens``: what this forward
-        FINISHED (a block's last forward says so, whichever forward writes
-        its K and V later; a token-at-a-time forward finishes one token a
+        (models.sdar_moe.routed_experts), of a step of several
+        ``forwards`` theirs stacked (``[forwards x layers, 4]``); a dense
+        generator gives none.  ``committed_blocks`` / ``committed_tokens``:
+        what this step FINISHED (a block's step its block, whichever step
+        writes its K and V later; a token-at-a-time forward one token a
         live row).  ``cache_bytes``: a prefill's cache by kind of state
         (``{"kv", "conv"}``, or a latent cache's ``{"latent", "index",
         "window"}``).  ``keys [rows, 2]``: of a model with a learned
@@ -319,26 +321,28 @@ class _GenerationObserver:
 
         step = self.step
         step.ran()
-        self.close()
+        self.close(forwards)
         self.clock.blocks += committed_blocks
         self.clock.tokens += committed_tokens
         if load is not None:
             batchtrace.gen_forward(step.group, step.variant, load, keys,
-                                   rows_per_group)
+                                   rows_per_group, forwards)
         try:
             self.engine._runtime_stats.record_generation(
-                self.task, step.variant, committed_blocks=committed_blocks,
+                self.task, step.variant, forwards=forwards,
+                committed_blocks=committed_blocks,
                 committed_tokens=committed_tokens, cache_bytes=cache_bytes,
                 rows_per_group=rows_per_group)
         except Exception:
             pass  # observability never fails a generation
 
-    def close(self) -> None:
-        """End the open forward's step, after ``done`` or when the forward
-        raised before it; the turn that follows it begins."""
+    def close(self, forwards: int = 1) -> None:
+        """End the open step, after ``done`` or when its program raised
+        before it (it counts as one forward then); the turn that follows it
+        begins."""
         if self.step is not None:
             self.step.finish()
-            self.clock.step_closed(self.step.variant, self._block)
+            self.clock.step_closed(self.step.variant, self._block, forwards)
             self.step = None
 
     def end(self, done: bool) -> None:
@@ -2351,8 +2355,9 @@ class InferenceEngine:
                         items: List[BatchItem]) -> Sequence[Any]:
         """The fourth runner: a batch of prompts of one generative task
         and bucket through the generator in lock step, from prefill to the
-        last token (request-level batching).  Every device forward of it is
-        one ``engine.step`` and one ``record_step`` sample
+        last token (request-level batching).  Every turn of the host in it (a
+        forward, or a block generator's block of forwards) is one
+        ``engine.step`` and one ``record_step`` sample
         (``_GenerationObserver``)."""
         gen = self._require(task_name, kind="generative").generator
         texts = [it.payload.text for it in items]

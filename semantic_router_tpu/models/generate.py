@@ -284,10 +284,11 @@ class _NullForward:
 
 
 class NullObserver:
-    """``forward(flavour, **facts)`` before every device forward of a
-    generation; its ``stage(name)`` brackets the five host stages and
-    ``done(**after)`` closes it with what only the readback knows.  A
-    ``stage(name)`` after ``done()`` is host work between two forwards."""
+    """``forward(flavour, **facts)`` before every stretch of device work
+    of a generation (a forward, or a block generator's block of forwards:
+    whatever the host dispatches before it reads anything back); its
+    ``stage(name)`` brackets the five host stages and ``done(**after)``
+    closes it with what only the readback knows."""
 
     def forward(self, flavour: str, **facts) -> _NullForward:
         return _NullForward()
@@ -630,17 +631,28 @@ class BlockDiffusionGenerator:
     turn (prefix reuse, ROADMAP M5) has to commit that block then.
 
     Rows run in lock step; three programs keyed by ``(rows, prompt bucket,
-    cache length)``: ``prefill``; ``denoise`` (``L`` positions, reads the
-    cache and writes nothing: the first block's first forward and every
-    block's later forwards; returns the block's new state on the device
-    and one small report for the host); ``commit`` (``2L`` positions: the
-    same for the new block, and the block before goes into the donated
-    cache — the first forward of a block that has a finished block before
-    it).  One cache layout, ``[rows, kv_heads, M, head_dim]`` a layer in
-    the model's dtype, ``M`` = bucket + generated + block - 1 rounded up
-    to 64.  The loop is the host's: it reads one report a forward (which
-    is what tells it when a block is done) and so can open one
-    ``engine.step`` a forward."""
+    cache length)``: ``prefill``; ``denoise`` (a block's LOOP: forwards of
+    ``L`` positions that read the cache and write nothing, from a given
+    step until ``step < denoising_steps and masked.any()`` fails, decided
+    on the device — a ``jax.lax.while_loop`` whose every forward's transfer
+    makes the next forward's tokens and mask where they are, and whose
+    cache is an operand, never a carried value); ``commit`` (a later
+    block's first forward, ``2L`` positions: it makes the block's state on
+    the device, ``[MASK]`` at every position of a real row, writes the
+    block before into the donated cache and leaves everything ``denoise``
+    goes on from on the device).  Block 0 is ``denoise`` from step 0; a
+    later block is ``commit`` and ``denoise`` from step 1 queued one
+    behind the other, so a block is one stretch of device work whichever
+    it is.  (One program for both would hold two forward bodies: traced,
+    lowered and loaded for every row count, it cost a sixth more set-up,
+    PERF.md section 6, PR 39.)  Each forward's report, experts and load are
+    written into buffers of ``denoising_steps`` entries, so the host turns
+    once a block: one dispatch, one readback, then the trajectory's
+    entries forward by forward from the stacked reports, and one
+    ``engine.step`` a block whose observer learns how many ``forwards`` it
+    ran.  One cache layout, ``[rows, kv_heads, M, head_dim]`` a layer in
+    the model's dtype, ``M`` = bucket + generated + block - 1 rounded up to
+    64."""
 
     def __init__(self, config, params, tokenizer, *, mask_token_id: int,
                  block_length: int = 4, denoising_steps: int = 4,
@@ -665,31 +677,85 @@ class BlockDiffusionGenerator:
         return _round_up(bucket + new_tokens + self.block_length - 1, 64)
 
     def programs(self, rows: int, bucket: int, cache_len: int):
+        """``(prefill, denoise, commit)`` of one shape.  ``denoise`` is a
+        block's loop from ``step`` on: it returns the block's final tokens
+        (on the device: the next block's ``previous``), ``outs`` with an
+        entry a forward it ran, and the step it ended at = the forwards
+        the block took.  ``outs = (reports [T, rows, L, 4 + 2 top],
+        experts [T, layers, rows, L, k], loads [T, layers, 4])``, ``T =
+        denoising_steps``; what no forward wrote stays as it came.
+        ``commit`` is a later block's first forward: it returns the cache,
+        the new block's state ``(tokens, masked, start)`` and ``outs`` with
+        entry 0 written, all left on the device for ``denoise`` from step
+        1, and the committed block's experts ``[layers, rows, L, k]``."""
         key = (rows, bucket, cache_len)
         if key not in self._programs:
             from . import sdar_moe as M
 
-            cfg, L = self.config, self.block_length
+            cfg, L, T = self.config, self.block_length, self.denoising_steps
+            schedule = np.asarray(self.transfer_schedule(), np.int32)
 
             def prefill(params, ids, committed):
                 return M.prefill(cfg, params, ids, committed, cache_len, L)
 
-            def commit(params, caches, previous, tokens, masked, start,
-                       rows_valid, at_least):
+            def forward(params, caches, previous, tokens, masked, start,
+                        rows_valid, step):
                 logits, caches, experts, load = M.block_forward(
                     cfg, params, caches, tokens, start, rows_valid, previous)
                 with jax.named_scope("transfer"):
                     tokens, masked, report = transfer_by_confidence(
                         logits, tokens, masked, self.confidence_threshold,
-                        at_least, self.top_logits)
-                return caches, tokens, masked, report, experts, load
+                        jnp.asarray(schedule)[step], self.top_logits)
+                return caches, tokens, masked, (report, experts, load)
 
-            def denoise(params, caches, *block):
-                return commit(params, caches, None, *block)[1:]
+            def keep(outs, out, step):
+                return jax.tree.map(
+                    lambda buf, o: jax.lax.dynamic_update_index_in_dim(
+                        buf, o, step, 0), outs, out)
+
+            def denoise(params, caches, tokens, masked, start, rows_valid,
+                        step, outs):
+                # the cache is an operand of the loop, not a carried value:
+                # the body reads it where it lies
+                def more(carry):
+                    step, _, masked, _ = carry
+                    return (step < T) & masked.any()
+
+                def one(carry):
+                    step, tokens, masked, outs = carry
+                    _, tokens, masked, out = forward(
+                        params, caches, None, tokens, masked, start,
+                        rows_valid, step)
+                    return step + 1, tokens, masked, keep(outs, out, step)
+
+                step, tokens, _, outs = jax.lax.while_loop(
+                    more, one, (jnp.int32(step), tokens, masked, outs))
+                return tokens, outs, step
+
+            def commit(params, caches, previous, base, rows_valid, b):
+                start = base + b * L
+                masked = jnp.broadcast_to(rows_valid[:, None], (rows, L))
+                tokens = jnp.full((rows, L), self.mask_token_id,
+                                  previous.dtype)
+                caches, tokens, masked, (report, experts, load) = forward(
+                    params, caches, previous, tokens, masked, start,
+                    rows_valid, 0)
+                outs = keep(self.buffers(rows, jnp),
+                            (report, experts[:, :, L:], load), 0)
+                return (caches, tokens, masked, start, outs,
+                        experts[:, :, :L])
 
             self._programs[key] = (jax.jit(prefill), jax.jit(denoise),
                                    jax.jit(commit, donate_argnums=(1,)))
         return self._programs[key]
+
+    def buffers(self, rows: int, xp=np):
+        """Empty ``outs`` of a block of ``rows`` rows."""
+        cfg, T, L = self.config, self.denoising_steps, self.block_length
+        return (xp.zeros((T, rows, L, 4 + 2 * self.top_logits), xp.float32),
+                xp.zeros((T, cfg.num_hidden_layers, rows, L,
+                          cfg.num_experts_per_tok), xp.int32),
+                xp.zeros((T, cfg.num_hidden_layers, 4), xp.float32))
 
     def transfer_schedule(self) -> List[int]:
         """Positions to fill at least, by denoising step: the block's
@@ -702,8 +768,8 @@ class BlockDiffusionGenerator:
     def warm(self, rows: int, bucket: int) -> None:
         """Compile and run the three programs of ``(rows, bucket)`` at the
         cache length of ``gen_length`` tokens: two blocks of a one-token
-        prompt (the second block's first forward is the one that
-        commits)."""
+        prompt (the second begins with the forward that commits; how many
+        forwards a loop runs is data, not shape)."""
         self.generate([], encodings=[_one_token(self.pad_id)], bucket=bucket,
                       padded_rows=rows, _blocks=2)
 
@@ -715,11 +781,12 @@ class BlockDiffusionGenerator:
                  _blocks: Optional[int] = None) -> List[GenerationResult]:
         """``prompts`` as one batch in lock step (the engine's batch runner
         passes ``encodings``, ``bucket``, ``padded_rows`` and ``observer``
-        as to ``GreedyGenerator.generate``).  The observer sees flavour
-        ``gen.denoise`` for a forward of ``L`` positions a row and
-        ``gen.commit`` for one of ``2L`` (it commits block ``block - 1``
-        and begins ``block``); a block's last forward reports it finished
-        (``committed_blocks``, ``committed_tokens``).
+        as to ``GreedyGenerator.generate``).  The observer sees a BLOCK as
+        one ``forward()``: flavour ``gen.denoise`` for block 0, ``gen.commit``
+        for a later block (it commits block ``block - 1`` and runs
+        ``block``), closed with the ``forwards`` the device ran in it, their
+        loads stacked, and the block it finished (``committed_blocks``,
+        ``committed_tokens``).
 
         A result's ``trajectory`` has one entry per block that a forward
         carried while the request still generated: ``kind`` (``denoise`` |
@@ -746,16 +813,26 @@ class BlockDiffusionGenerator:
         blocks_of = -(-(tail + new_tokens) // L)  # a row's own count
         n_blocks = _blocks or int(blocks_of[:n].max())
         rows_valid = np.arange(B) < n
-        schedule = self.transfer_schedule()
+        k = self.top_logits
+
+        def block_state():
+            """``[MASK]`` everywhere, masked in the real rows."""
+            return (np.full((B, L), self.mask_token_id, np.int32),
+                    np.broadcast_to(rows_valid[:, None], (B, L)).copy())
 
         fwd = obs.forward("gen.prefill", tokens_real=int(base.sum()))
         with fwd.stage("stack"):
             ids = np.full((B, bucket), self.pad_id, np.int32)
+            tokens, masked = block_state()  # block 0 begins with the tails
             for i, e in enumerate(encs):
                 ids[i, :lengths[i]] = e.ids[:lengths[i]]
+                tokens[i, :tail[i]] = ids[i, base[i]:lengths[i]]
+                masked[i, :tail[i]] = False
         with fwd.stage("h2d"):
             ids_dev, base_dev = jnp.asarray(ids), jnp.asarray(base)
             valid_dev = jnp.asarray(rows_valid)
+            block = (jnp.asarray(tokens), jnp.asarray(masked), base_dev)
+            outs = tuple(jnp.asarray(a) for a in self.buffers(B))
         with fwd.stage("dispatch"):
             caches, load = prefill(self.params, ids_dev, base_dev)
         with fwd.stage("readback"):
@@ -768,50 +845,42 @@ class BlockDiffusionGenerator:
         # on the device and on the host
         finished_dev = finished = None
         for b in range(n_blocks):
-            tokens = np.full((B, L), self.mask_token_id, np.int32)
-            masked = np.broadcast_to(rows_valid[:, None], (B, L)).copy()
-            if b == 0:
-                for i in range(n):
-                    tokens[i, :tail[i]] = ids[i, base[i]:lengths[i]]
-                    masked[i, :tail[i]] = False
+            if b:
+                tokens, masked = block_state()
             live = [i for i in range(n) if b < blocks_of[i]]
-            with fwd.stage("h2d"):  # of the turn after the last forward
-                start_dev = jnp.asarray(base + b * L)
-                tokens_dev = jnp.asarray(tokens)
-                masked_dev = jnp.asarray(masked)
-            # a real row's block begins with a mask (a prompt's tail is
-            # shorter than a block), so every block has a first forward
-            for step in range(self.denoising_steps):
-                block = (tokens_dev, masked_dev, start_dev, valid_dev,
-                         schedule[step])
-                commits = finished_dev is not None
-                fwd = obs.forward(
-                    "gen.commit" if commits else "gen.denoise",
-                    tokens_real=n * L * (1 + commits), block=b,
-                    masks_left=int(masked.sum()))
-                with fwd.stage("dispatch"):
-                    if commits:
-                        caches, *out = commit(self.params, caches,
-                                              finished_dev, *block)
-                        finished_dev = None
-                    else:
-                        out = denoise(self.params, caches, *block)
-                    tokens_dev, masked_dev, report, experts, load = out
-                with fwd.stage("readback"):
-                    report, experts, load = (np.asarray(a) for a in
-                                             jax.device_get(
-                                                 (report, experts, load)))
-                with fwd.stage("demux"):
-                    k = self.top_logits
-                    after = report[..., 0].astype(np.int32)
-                    filled = report[..., 1] > 0.5
+            fwd = obs.forward(
+                "gen.commit" if b else "gen.denoise",
+                tokens_real=n * L * (1 + (b > 0)), block=b,
+                masks_left=int(masked.sum()))
+            with fwd.stage("dispatch"):
+                # a later block is two programs queued one behind the
+                # other: nothing of the first is read before the second
+                committed = None
+                if b:
+                    caches, *block, outs, committed = commit(
+                        self.params, caches, finished_dev, base_dev,
+                        valid_dev, b)
+                finished_dev, outs, ran = denoise(
+                    self.params, caches, *block, valid_dev, int(b > 0), outs)
+            with fwd.stage("readback"):
+                (reports, experts, loads), ran, committed = jax.device_get(
+                    (outs, ran, committed))
+                ran = int(ran)
+            with fwd.stage("demux"):
+                # forward by forward from the stacked reports: what went
+                # into a forward is what the one before left
+                afters = reports[..., 0].astype(np.int32)
+                fills = reports[..., 1] > 0.5
+                top_ids = reports[..., 4:4 + k].astype(np.int32)
+                for f in range(ran):
+                    report, after, filled = reports[f], afters[f], fills[f]
                     for i in live:
-                        if commits:
+                        if b and not f:
                             trajectory[i].append({
                                 "kind": "commit", "block": b - 1,
                                 "tokens": finished[i],
                                 "masked": np.zeros(L, bool),
-                                "experts": experts[:, i, :L]})
+                                "experts": committed[:, i]})
                         if masked[i].any():
                             trajectory[i].append({
                                 "kind": "denoise", "block": b,
@@ -821,20 +890,17 @@ class BlockDiffusionGenerator:
                                 "tokens_after": after[i],
                                 "confidence": report[i, :, 2],
                                 "lse": report[i, :, 3],
-                                "top_ids": report[i, :, 4:4 + k]
-                                .astype(np.int32),
+                                "top_ids": top_ids[f, i],
                                 "top_logits": report[i, :, 4 + k:],
-                                "experts": experts[:, i, -L:]})
+                                "experts": experts[f][:, i]})
                     tokens, masked = after, masked & ~filled
-                ended = step + 1 == self.denoising_steps or not masked.any()
-                fwd.done(load=load, committed_blocks=len(live) * ended,
-                         committed_tokens=len(live) * L * ended)
-                if ended:
-                    break
+            fwd.done(load=loads[:ran].reshape(-1, 4), forwards=ran,
+                     committed_blocks=len(live),
+                     committed_tokens=len(live) * L)
             for i in live:
                 generated[i].extend(
                     int(t) for t in tokens[i, tail[i] if b == 0 else 0:])
-            finished_dev, finished = tokens_dev, tokens
+            finished = tokens
         del caches
         return [_finish_tokens(self.tokenizer, generated[i][:new_tokens],
                                self.eos_token_ids, stop_strings,

@@ -51,8 +51,7 @@ the batch iteration it rode in:
    around the steps, never inside them: ``engine.gen.turn`` (``group``,
    ``after``, ``block``) runs from the moment a forward's step closed to
    the moment the next one's opens — the ``engine.gen.forward`` marker,
-   the counters and the generator's loop top are inside it, a block
-   generator's copies as its stage ``engine.gen.turn.h2d`` — and the one
+   the counters and the generator's loop top are inside it — and the one
    after a generation's last forward until the runner has its results;
    ``engine.gen.done`` marks a generation's END and carries its counts
    (``forwards``, ``blocks``, ``tokens``) and, on the host clock, how
@@ -95,7 +94,6 @@ STAGE_ANNOTATIONS = {n: f"{STEP_ANNOTATION}.{n}" for n in STAGES}
 QUEUE_WAIT_ANNOTATION = "engine.queue_wait"
 GEN_FORWARD_ANNOTATION = "engine.gen.forward"
 GEN_TURN_ANNOTATION = "engine.gen.turn"
-GEN_TURN_STAGE_ANNOTATIONS = {n: f"{GEN_TURN_ANNOTATION}.{n}" for n in STAGES}
 GEN_DONE_ANNOTATION = "engine.gen.done"
 TOKENIZE_ANNOTATION = "engine.tokenize"
 ROUTE_ANNOTATION = "router.route"
@@ -315,13 +313,15 @@ def queue_wait(trace_id: str, group: str, wait_s: float) -> None:
 
 
 def gen_forward(group: str, flavour: str, load, keys=None,
-                rows_per_group=None) -> None:
-    """``engine.gen.forward``: a forward of a generation ended now, and
-    this is what only its readback knew.  ``load [layers, 4]`` per expert
-    layer: the busiest expert's routed pairs, the pairs computed, the
-    experts that got any, the busiest's pairs over the mean; the facts are
-    ``layers``, ``pairs`` and ``experts_touched`` (sums over the layers)
-    and ``load_milli`` (the ratio's mean over the layers, in thousandths).
+                rows_per_group=None, forwards: int = 1) -> None:
+    """``engine.gen.forward``: a step of a generation ended now, and
+    this is what only its readback knew: the ``forwards`` the device ran
+    in it (a block generator's step is a block's loop) and ``load
+    [forwards x layers, 4]`` per forward and expert layer: the busiest
+    expert's routed pairs, the pairs computed, the experts that got any,
+    the busiest's pairs over the mean; the facts are ``layers``, ``pairs``
+    and ``experts_touched`` (sums over the forwards' layers) and
+    ``load_milli`` (the ratio's mean over them, in thousandths).
     ``keys [rows, 2]`` of a model with a learned selection of keys adds
     ``keys_selected`` and ``keys_visible``: what the forward's queries
     selected and what they could see, over its rows and full layers.
@@ -333,7 +333,8 @@ def gen_forward(group: str, flavour: str, load, keys=None,
     if rows_per_group is not None:
         facts["rows_per_group"] = int(rows_per_group)
     with trace_span(GEN_FORWARD_ANNOTATION, group=group, flavour=flavour,
-                    layers=len(load), pairs=int(load[:, 1].sum()),
+                    forwards=int(forwards), layers=len(load),
+                    pairs=int(load[:, 1].sum()),
                     experts_touched=int(load[:, 2].sum()),
                     load_milli=int(load[:, 3].mean() * 1000), **facts):
         pass
@@ -376,14 +377,15 @@ def tokenized(tag: str, tok_s: float, tokens: int, cached: bool) -> None:
 
 class GenerationClock:
     """Where a generation's host time went, between its steps: one
-    ``time.perf_counter()`` read where a forward's step opens and one
+    ``time.perf_counter()`` read where a step opens and one
     where it closes, so that the steps (open to close: the device wait),
     the turns between them and the finish after the last tile the
     generation exactly.  ``step_opens()`` / ``step_closed()`` bracket
-    every forward and keep the ``engine.gen.turn`` annotation open in
-    between; ``end()`` closes the last turn and, for a generation that
-    came to its results, writes ``engine.gen.done`` and returns the
-    seconds by phase."""
+    every step (``forwards`` counts the forwards the device ran in
+    them, several in a block generator's) and keep the ``engine.gen.turn``
+    annotation open in between; ``end()`` closes the last turn and, for a
+    generation that came to its results, writes ``engine.gen.done`` and
+    returns the seconds by phase."""
 
     def __init__(self, group: str, **facts: int) -> None:
         self.group, self.facts = group, facts
@@ -411,18 +413,14 @@ class GenerationClock:
                 self._turn_max_after = self.forwards - 1
         self._mark = now
 
-    def step_closed(self, after: str, block: int) -> None:
+    def step_closed(self, after: str, block: int, forwards: int = 1) -> None:
         now = time.perf_counter()
         self._steps_s += now - self._mark
         self._mark = now
-        self.forwards += 1
+        self.forwards += forwards
         self._turn = trace_span(GEN_TURN_ANNOTATION, group=self.group,
                                 after=after, block=int(block))
         self._turn.__enter__()
-
-    def turn_stage(self, name: str):
-        """A stage of the open turn (``engine.gen.turn.<name>``)."""
-        return trace_span(GEN_TURN_STAGE_ANNOTATIONS[name])
 
     def end(self, done: bool) -> Optional[Dict[str, float]]:
         if self._turn is None:

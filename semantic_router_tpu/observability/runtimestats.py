@@ -183,6 +183,12 @@ class RuntimeStats:
             "llm_runtime_gen_forwards_total",
             "Device forwards of generative tasks by flavour (prefill, "
             "denoise, commit, decode)")
+        self.gen_programs = registry.counter(
+            "llm_runtime_gen_programs_total",
+            "Stretches of device work of generative tasks that the host "
+            "dispatched and then read back (a forward; a block generator's "
+            "block), by flavour: one per turn of the host; forwards over "
+            "programs is how many forwards ran without it")
         self.gen_blocks = registry.counter(
             "llm_runtime_gen_blocks_committed_total",
             "Blocks committed to the cache by block-diffusion tasks, "
@@ -194,7 +200,7 @@ class RuntimeStats:
             "llm_runtime_gen_seconds_total",
             "Host-clock seconds of finished generations by phase: forward "
             "(a step, open to close: the device wait), turn (between two "
-            "forwards: the host alone), finish (after the last forward "
+            "programs: the host alone), finish (after the last program "
             "until the runner had its results); (turn + finish) over the "
             "three is the host's share of a generation")
         self.gen_generations = registry.counter(
@@ -255,18 +261,21 @@ class RuntimeStats:
                               bool(compiled), int(tokens_real),
                               int(tokens_padded), int(segments)))
 
-    def record_generation(self, task: str, flavour: str,
+    def record_generation(self, task: str, flavour: str, forwards: int = 1,
                           committed_blocks: int = 0,
                           committed_tokens: int = 0,
                           cache_bytes=None, rows_per_group=None) -> None:
-        """One forward of a generation (the engine's generative runner):
-        llm_runtime_gen_forwards_total by flavour, and what the forward
+        """One step of a generation (the engine's generative runner; a
+        forward, or a block generator's block): one
+        llm_runtime_gen_programs_total and ``forwards``
+        llm_runtime_gen_forwards_total — the first of ``flavour``, a
+        block's later ones ``gen.denoise`` (only a ``gen.commit`` step's
+        first forward carries the block before) — and what the step
         FINISHED in llm_runtime_gen_blocks_committed_total and
         llm_runtime_gen_tokens_committed_total (a generation's last block
-        among them; a block generator's ``gen.commit`` forward commits
-        one block and begins the next, so a generation of B blocks has
-        B - 1 of those: count blocks here, not there).  Everything else
-        about a forward is its ``record_step`` sample (group
+        among them; a generation of B blocks has B - 1 ``gen.commit``
+        steps: count blocks here, not there).  Everything else
+        about a step is its ``record_step`` sample (group
         ``gen:<task>``, the flavour as variant) and, under a profiler
         session, its ``engine.step`` and ``engine.gen.forward``
         annotations (expert load among them).  ``cache_bytes`` (a
@@ -275,7 +284,11 @@ class RuntimeStats:
         llm_runtime_gen_prefill_rows_per_group."""
         if not self.enabled:
             return
+        self.gen_programs.inc(task=task, flavour=flavour)
         self.gen_forwards.inc(task=task, flavour=flavour)
+        if forwards > 1:
+            self.gen_forwards.inc(forwards - 1, task=task,
+                                  flavour="gen.denoise")
         for kind, size in (cache_bytes or {}).items():
             self.gen_cache_bytes.set(size, task=task, kind=kind)
         if rows_per_group is not None:

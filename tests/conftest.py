@@ -145,3 +145,20 @@ def router_config(fixture_config_path):
     from semantic_router_tpu.config import load_config
 
     return load_config(fixture_config_path)
+
+
+@pytest.fixture()
+def seen(monkeypatch):
+    """Every annotation the program writes on the profiler's clock, as
+    (name, facts), whether or not a session runs."""
+    from semantic_router_tpu.observability import batchtrace
+
+    seen = []
+    real = batchtrace.trace_span
+
+    def spy(name, **facts):
+        seen.append((name, facts))
+        return real(name, **facts)
+
+    monkeypatch.setattr(batchtrace, "trace_span", spy)
+    return seen

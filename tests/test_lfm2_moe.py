@@ -545,17 +545,7 @@ def engine(tmp_path):
 
 
 def test_guard_classify_goes_through_the_batcher_a_step_a_forward(
-        engine, monkeypatch):
-    from semantic_router_tpu.observability import batchtrace
-
-    seen = []
-    real = batchtrace.trace_span
-
-    def spy(name, **facts):
-        seen.append((name, facts))
-        return real(name, **facts)
-
-    monkeypatch.setattr(batchtrace, "trace_span", spy)
+        engine, seen):
     rs = engine._runtime_stats
     before = {v: rs.gen_forwards.get(task="guard", flavour=v)
               for v in ("gen.prefill", "gen.decode")}
@@ -590,6 +580,45 @@ def test_guard_classify_goes_through_the_batcher_a_step_a_forward(
     fill = [p for p in rs.programs() if p["group"] == "gen:guard"
             and p["variant"] == "gen.prefill"]
     assert fill[0]["tokens_padded"] == 64
+
+
+def test_a_token_at_a_time_generation_is_a_forward_a_program(engine, seen):
+    """The observer, the marker and the counters are shared with the block
+    generator, whose program is a block of forwards; this loop's steps,
+    markers and counters are what they were: a forward a step, and the new
+    ``forwards`` fact and programs counter say one."""
+    rs = engine._runtime_stats
+    flavours = ("gen.prefill", "gen.decode", "gen.denoise", "gen.commit")
+
+    def counts():
+        return {(c, v): getattr(rs, c).get(task="guard", flavour=v)
+                for c in ("gen_forwards", "gen_programs") for v in flavours}
+
+    before = counts()
+    engine.guard_classify("guard", words(prompts(14, (7,))[0]))
+    after = counts()
+    step_facts = {"group", "flavour", "bucket", "rows", "padded_rows",
+                  "tokens_real"}
+    mark_facts = {"group", "flavour", "forwards", "layers", "pairs",
+                  "experts_touched", "load_milli"}
+    steps = [f for n, f in seen if n == "engine.step"]
+    assert [s["flavour"] for s in steps] == ["gen.prefill"] \
+        + ["gen.decode"] * 5
+    assert set(steps[0]) == step_facts
+    assert all(set(s) == step_facts | {"block"} for s in steps[1:])
+    marks = [f for n, f in seen if n == "engine.gen.forward"]
+    assert [m["flavour"] for m in marks] == [s["flavour"] for s in steps]
+    assert set(marks[0]) == mark_facts | {"rows_per_group"}
+    assert all(set(m) == mark_facts for m in marks[1:])
+    assert all(m["forwards"] == 1 and m["layers"] == 4 for m in marks)
+    assert [f["after"] for n, f in seen if n == "engine.gen.turn"] == \
+        [s["flavour"] for s in steps]
+    (done,) = [f for n, f in seen if n == "engine.gen.done"]
+    assert done["forwards"] == 6
+    for counter in ("gen_forwards", "gen_programs"):
+        assert {v: after[counter, v] - before[counter, v]
+                for v in flavours} == {"gen.prefill": 1, "gen.decode": 5,
+                                       "gen.denoise": 0, "gen.commit": 0}
 
 
 def test_three_rows_through_the_batcher_equal_one_at_a_time(engine):

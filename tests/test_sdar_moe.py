@@ -317,55 +317,84 @@ def test_prefill_then_blocks_equal_the_full_forward(toy):
                                        np.asarray(w[:, :, :16]), atol=ATOL)
 
 
-def test_the_committing_forward_equals_commit_then_denoise(toy):
-    """The generator's ``commit`` program — the block before beside the
-    new block, 2L positions a row — against a commit and a denoise apart:
-    the cache it leaves is a prefill's with that block committed, the new
-    block's state and report are ``denoise``'s against that cache, and the
-    experts of all 2L tokens are those two forwards'.  Rows at different
-    starts, and a padding row."""
+def _one_forward_programs(gen):
+    """The two block programs the generator had while the host ran the
+    loop, one forward each: the reference its block programs are held to,
+    and the loop below is the host's of then."""
+    def commit(params, caches, previous, tokens, masked, start, rows_valid,
+               at_least):
+        logits, caches, experts, load = M.block_forward(
+            gen.config, params, caches, tokens, start, rows_valid, previous)
+        tokens, masked, report = transfer_by_confidence(
+            logits, tokens, masked, gen.confidence_threshold, at_least,
+            gen.top_logits)
+        return caches, tokens, masked, report, experts, load
+
+    def denoise(params, caches, *block):
+        return commit(params, caches, None, *block)[1:]
+
+    return jax.jit(denoise), jax.jit(commit)
+
+
+def test_the_committing_forward_and_the_loop_equal_a_commit_then_a_block(toy):
+    """A later block as the generator runs it — ``commit`` (the block
+    before beside the new block, 2L positions a row, the new block's state
+    made on the device) and ``denoise`` from step 1 on what it left there —
+    against a commit and a whole block apart: the cache it leaves is a
+    prefill's with that block committed, the new block's tokens and every
+    forward's report are ``denoise``'s from step 0 against that cache from
+    ``[MASK]`` everywhere, and the experts of the committed block are a
+    forward's of its own.  Rows at different starts, and a padding row."""
     _, cfg, params = toy
     gen = generator(toy)
     prefill, denoise, commit = gen.programs(3, 16, 64)
+    one_forward, _ = _one_forward_programs(gen)
     rng = np.random.default_rng(6)
     ids = jnp.asarray(rng.integers(2, 250, (3, 16)), jnp.int32)
     committed = jnp.asarray([4, 8, 0])
     valid = jnp.asarray([True, True, False])
     previous = jnp.stack([ids[0, 4:8], ids[1, 8:12], ids[2, :4]])
-    tokens = np.full((3, L), MASK, np.int32)
-    tokens[:2, 1] = rng.integers(2, 250, 2)
-    masked = np.asarray([[True, False, True, True]] * 2 + [[False] * L])
-    block = (jnp.asarray(tokens), jnp.asarray(masked), committed + L, valid, 2)
+    tokens = jnp.full((3, L), MASK, jnp.int32)
+    masked = jnp.asarray([[True] * L] * 2 + [[False] * L])
 
     before, _ = prefill(params, ids, committed)
     after, _ = prefill(params, ids, committed + L)
     nothing = jnp.zeros((3, L), bool)
-    experts_before = denoise(params, before, previous, nothing, committed,
-                             valid, 0)[3]
-    want = denoise(params, after, *block)
-    got_cache, *got = commit(params, before, previous, *block)
+    experts_before = one_forward(params, before, previous, nothing,
+                                 committed, valid, 0)[3]
+    w_tok, (w_reports, w_experts, w_loads), w_ran = denoise(
+        params, after, tokens, masked, committed + L, valid, 0,
+        gen.buffers(3))
+    # block 1 of rows whose whole blocks end at ``committed``
+    got_cache, *block, outs, experts_committed = commit(
+        params, before, previous, committed, valid, 1)
+    assert np.asarray(block[2]).tolist() == [8, 12, 4]  # where it starts
+    assert np.asarray(block[1]).sum() == 2 * (L - 1)  # a position filled
+    tok, (reports, experts, loads), ran = denoise(
+        params, got_cache, *block, valid, 1, outs)
 
     for b, n in enumerate((8, 12)):  # the real rows' committed columns
         for layer, layer_after in zip(got_cache, after):
             for g, w in zip(layer, layer_after):
                 np.testing.assert_allclose(np.asarray(g[b, :, :n]),
                                            np.asarray(w[b, :, :n]), atol=ATOL)
-    (tok, still, report, experts, load), (w_tok, w_still, w_report,
-                                          w_experts, w_load) = got, want
+    assert int(ran) == int(w_ran) == 4  # never confident: a position each
     assert np.asarray(tok)[:2].tolist() == np.asarray(w_tok)[:2].tolist()
-    assert np.asarray(still).tolist() == np.asarray(w_still).tolist()
-    assert np.asarray(still).sum() == 2  # two of a row's three masks filled
-    np.testing.assert_allclose(np.asarray(report[:2]),
-                               np.asarray(w_report[:2]), atol=ATOL)
-    assert report.shape == (3, L, 4 + 2 * 4)  # the head saw L positions
-    assert experts.shape == (2, 3, 2 * L, 2)
-    assert (np.asarray(experts[:, :2, :L])
+    assert not (np.asarray(tok)[:2] == MASK).any()
+    np.testing.assert_allclose(np.asarray(reports[:, :2]),
+                               np.asarray(w_reports[:, :2]), atol=ATOL)
+    assert reports.shape == (4, 3, L, 4 + 2 * 4)  # the head saw L positions
+    assert experts.shape == (4, 2, 3, L, 2)
+    assert (np.asarray(experts[:, :, :2])
+            == np.asarray(w_experts[:, :, :2])).all()
+    assert experts_committed.shape == (2, 3, L, 2)
+    assert (np.asarray(experts_committed[:, :2])
             == np.asarray(experts_before[:, :2])).all()
-    assert (np.asarray(experts[:, :2, L:])
-            == np.asarray(w_experts[:, :2])).all()
     # the padding row routes nowhere: two rows x 2L tokens x top-2 a layer
-    assert np.asarray(load)[:, 1].tolist() == [2 * 2 * L * 2] * 2
-    assert np.asarray(w_load)[:, 1].tolist() == [2 * L * 2] * 2
+    # in the forward that commits, x L in the others
+    assert np.asarray(loads)[:, :, 1].tolist() == \
+        [[2 * 2 * L * 2] * 2] + [[2 * L * 2] * 2] * 3
+    assert np.asarray(w_loads)[:, :, 1].tolist() == [[2 * L * 2] * 2] * 4
 
 
 def test_rows_of_different_lengths_keep_their_own_positions(toy):
@@ -539,6 +568,139 @@ def test_rows_finish_a_block_at_different_forwards(confident, monkeypatch):
         assert alone.token_ids == res.token_ids
 
 
+def _forward_by_forward(gen, params, texts):
+    """The generation as the host ran it while a block's forwards were a
+    program each: one report read a forward, the next forward launched from
+    what it said.  Returns each row's tokens and trajectory and the
+    forwards every block took."""
+    denoise, commit = _one_forward_programs(gen)
+    encs = [gen.tokenizer.encode(t) for t in texts]
+    n = B = len(encs)
+    bucket = -(-max(len(e) for e in encs) // 32) * 32
+    cache_len = gen.cache_len(bucket, gen.gen_length)
+    lengths = np.asarray([len(e) for e in encs], np.int32)
+    base = lengths // L * L
+    tail = lengths - base
+    blocks_of = -(-(tail + gen.gen_length) // L)
+    valid = jnp.ones(B, bool)
+    ids = np.zeros((B, bucket), np.int32)
+    for i, e in enumerate(encs):
+        ids[i, :lengths[i]] = e.ids
+    caches, _ = gen.programs(B, bucket, cache_len)[0](
+        params, jnp.asarray(ids), jnp.asarray(base))
+    generated = [[] for _ in range(n)]
+    trajectory = [[] for _ in range(n)]
+    forwards, finished = [], None
+    k = gen.top_logits
+    for b in range(int(blocks_of.max())):
+        tokens = np.full((B, L), MASK, np.int32)
+        masked = np.ones((B, L), bool)
+        if b == 0:
+            for i in range(n):
+                tokens[i, :tail[i]] = ids[i, base[i]:lengths[i]]
+                masked[i, :tail[i]] = False
+        live = [i for i in range(n) if b < blocks_of[i]]
+        start = jnp.asarray(base + b * L)
+        forwards.append(0)
+        for step in range(gen.denoising_steps):
+            block = (jnp.asarray(tokens), jnp.asarray(masked), start, valid,
+                     gen.transfer_schedule()[step])
+            commits = finished is not None
+            if commits:
+                caches, *out = commit(params, caches, jnp.asarray(finished),
+                                      *block)
+            else:
+                out = denoise(params, caches, *block)
+            report, experts = np.asarray(out[2]), np.asarray(out[3])
+            forwards[-1] += 1
+            after = report[..., 0].astype(np.int32)
+            filled = report[..., 1] > 0.5
+            for i in live:
+                if commits:
+                    trajectory[i].append({
+                        "kind": "commit", "block": b - 1,
+                        "tokens": finished[i], "masked": np.zeros(L, bool),
+                        "experts": experts[:, i, :L]})
+                if masked[i].any():
+                    trajectory[i].append({
+                        "kind": "denoise", "block": b,
+                        "tokens": tokens[i].copy(),
+                        "masked": masked[i].copy(), "filled": filled[i],
+                        "tokens_after": after[i],
+                        "confidence": report[i, :, 2],
+                        "lse": report[i, :, 3],
+                        "top_ids": report[i, :, 4:4 + k].astype(np.int32),
+                        "top_logits": report[i, :, 4 + k:],
+                        "experts": experts[:, i, -L:]})
+            tokens, masked, finished = after, masked & ~filled, None
+            if not masked.any():
+                break
+        for i in live:
+            generated[i].extend(
+                int(t) for t in tokens[i, tail[i] if b == 0 else 0:])
+        finished = tokens
+    return ([g[:gen.gen_length] for g in generated], trajectory, forwards)
+
+
+class _Programs:
+    """An observer that keeps what the generator tells one."""
+
+    def __init__(self):
+        self.opened, self.closed = [], []
+
+    def forward(self, flavour, **facts):
+        self.opened.append((flavour, facts))
+        return self
+
+    def stage(self, name):
+        import contextlib
+
+        return contextlib.nullcontext()
+
+    def done(self, **after):
+        self.closed.append(after)
+
+
+@pytest.mark.parametrize("lengths", [(9,), (12, 5), (5, 14, 8)])
+@pytest.mark.parametrize("weights", ["unconfident", "confident"])
+def test_the_block_programs_give_what_the_hosts_loop_gave(
+        toy, confident, weights, lengths):
+    """Forward for forward: the same tokens, the same trajectory entries
+    in the same order, the same number of forwards a block, whether every
+    block takes all its forwards or some end early."""
+    _, _, params = confident if weights == "confident" else toy
+    gen = generator(toy, params=params, gen_length=12)
+    texts = [words(p) for p in prompts(17, lengths)]
+    want_tokens, want, want_forwards = _forward_by_forward(gen, params, texts)
+    seen = _Programs()
+    out = gen.generate(texts, observer=seen)
+    assert [r.token_ids for r in out] == want_tokens
+    assert [c["forwards"] for c in seen.closed[1:]] == want_forwards
+    if weights == "confident":
+        assert min(want_forwards) < 4, want_forwards
+    else:
+        assert set(want_forwards[1:]) == {4}
+    assert [f for f, _ in seen.opened] == \
+        ["gen.prefill", "gen.denoise"] + ["gen.commit"] * (
+            len(want_forwards) - 1)
+    layers = 2
+    for done, ran in zip(seen.closed[1:], want_forwards):
+        assert done["load"].shape == (ran * layers, 4)
+        assert done["committed_tokens"] == done["committed_blocks"] * L
+    for res, entries in zip(out, want):
+        assert len(res.trajectory) == len(entries)
+        for got, e in zip(res.trajectory, entries):
+            assert sorted(got) == sorted(e)
+            for key, value in e.items():
+                if np.asarray(value).dtype.kind == "f":
+                    np.testing.assert_allclose(got[key], value, atol=1e-5,
+                                               err_msg=key)
+                else:
+                    assert np.array_equal(got[key], value), key
+                    assert np.asarray(got[key]).dtype == \
+                        np.asarray(value).dtype, key
+
+
 @pytest.mark.parametrize("seed", range(4))
 def test_transfer_on_the_device_equals_the_references(seed):
     rng = np.random.default_rng(seed)
@@ -598,34 +760,80 @@ def test_three_rows_through_the_batcher_equal_one_at_a_time(engine, toy):
 
 def _gen_counts(engine):
     """(steps by flavour, rows of the commit steps, committed blocks and
-    tokens, forwards by flavour) so far: the default RuntimeStats is the
-    process's, so tests take differences."""
+    tokens, forwards by flavour, programs by flavour) so far: the default
+    RuntimeStats is the process's, so tests take differences."""
     rs = engine._runtime_stats
     rs.flush()
     rows = {p["variant"]: p for p in rs.programs()
             if p["group"] == "gen:guard"}
+    flavours = ("gen.prefill", "gen.denoise", "gen.commit")
     return ({v: p["executes"] + p["compiles"] for v, p in rows.items()},
             rows.get("gen.commit", {}).get("rows_real", 0),
             (rs.gen_blocks.get(task="guard"),
              rs.gen_tokens.get(task="guard")),
             {v: rs.gen_forwards.get(task="guard", flavour=v)
-             for v in ("gen.prefill", "gen.denoise", "gen.commit")})
+             for v in flavours},
+            {v: rs.gen_programs.get(task="guard", flavour=v)
+             for v in flavours})
 
 
-def test_every_forward_is_a_step_and_a_count(engine):
-    steps0, rows0, g0, f0 = _gen_counts(engine)
+def test_every_block_is_a_step_and_every_forward_a_count(engine):
+    steps0, rows0, g0, f0, p0 = _gen_counts(engine)
     texts = [words(p) for p in prompts(32, (8, 10))]
     engine.generate("guard", texts, max_new_tokens=8)
-    steps1, rows1, g1, f1 = _gen_counts(engine)
+    steps1, rows1, g1, f1, p1 = _gen_counts(engine)
     # the 10-token row has a partial block, so the batch runs 3 blocks; the
     # 8-token row starts every block with 4 masks: 4 forwards a block, the
-    # first of the second and third blocks committing the block before
-    want = {"gen.prefill": 1, "gen.denoise": 10, "gen.commit": 2}
+    # first of the second and third blocks committing the block before.
+    # A block is a program and a step of the host; its forwards are counted
+    want = {"gen.prefill": 1, "gen.denoise": 1, "gen.commit": 2}
     assert {v: steps1[v] - steps0.get(v, 0) for v in steps1} == want
-    assert {v: f1[v] - f0[v] for v in f1} == want
+    assert {v: p1[v] - p0[v] for v in p1} == want
+    assert {v: f1[v] - f0[v] for v in f1} == \
+        {"gen.prefill": 1, "gen.denoise": 10, "gen.commit": 2}
     assert rows1 - rows0 == 4
     # blocks and tokens FINISHED, the last block among them
     assert (g1[0] - g0[0], g1[1] - g0[1]) == (2 + 3, 20)
+
+
+def test_an_early_end_runs_fewer_forwards_and_says_so(toy, confident, seen):
+    """With a confident head some block fills its masks before its fourth
+    forward: the loop ends on the device, and the marker's ``forwards``,
+    the two counters and ``engine.gen.done`` all say how many ran."""
+    from semantic_router_tpu.config.schema import InferenceEngineConfig
+    from semantic_router_tpu.engine.classify import InferenceEngine
+
+    eng = InferenceEngine(InferenceEngineConfig(
+        max_batch_size=4, max_wait_ms=50.0, seq_len_buckets=[32]))
+    try:
+        eng.register_generative(
+            "guard", generator(toy, params=confident[2], gen_length=16))
+        _, _, _, f0, p0 = _gen_counts(eng)
+        texts = [words(p) for p in prompts(21, (8, 8, 12, 16))]
+        out = eng.generate("guard", texts, max_new_tokens=16)
+        _, _, _, f1, p1 = _gen_counts(eng)
+    finally:
+        eng.shutdown()
+    assert [r.token_ids for r in out] == CONFIDENT_TOKENS_BEFORE
+    marks = [f for n, f in seen if n == "engine.gen.forward"]
+    assert [m["flavour"] for m in marks] == \
+        ["gen.prefill", "gen.denoise"] + ["gen.commit"] * 3
+    ran = [m["forwards"] for m in marks]
+    assert ran[0] == 1 and all(1 <= r <= 4 for r in ran) and min(ran[1:]) < 4
+    # a block's forwards are as many as its slowest row's denoise entries
+    for blk, r in enumerate(ran[1:]):
+        assert r == max(sum(1 for e in res.trajectory
+                            if e["kind"] == "denoise" and e["block"] == blk)
+                        for res in out)
+    assert [m["layers"] for m in marks] == [2 * r for r in ran]
+    forwards = {v: f1[v] - f0[v] for v in f1}
+    programs = {v: p1[v] - p0[v] for v in p1}
+    assert programs == {"gen.prefill": 1, "gen.denoise": 1, "gen.commit": 3}
+    assert forwards == {"gen.prefill": 1, "gen.commit": 3,
+                        "gen.denoise": sum(ran) - 4}
+    assert sum(forwards.values()) / sum(programs.values()) < 4
+    (done,) = [f for n, f in seen if n == "engine.gen.done"]
+    assert done["forwards"] == sum(ran) and done["blocks"] == 4 * 4
 
 
 def test_warmup_compiles_the_generative_programs(engine):
@@ -653,36 +861,37 @@ def test_warmup_compiles_the_generative_programs(engine):
             for fns in warmed.values()] == sizes
 
 
-def test_step_facts_on_the_profilers_clock(engine, monkeypatch):
-    from semantic_router_tpu.observability import batchtrace
-
-    seen = []
-    real = batchtrace.trace_span
-
-    def spy(name, **facts):
-        seen.append((name, facts))
-        return real(name, **facts)
-
-    monkeypatch.setattr(batchtrace, "trace_span", spy)
+def test_step_facts_on_the_profilers_clock(engine, seen):
     engine.generate("guard", [words(prompts(35, (8,))[0])],
                     max_new_tokens=8)
     steps = [f for n, f in seen if n == "engine.step"]
-    # the second block's first forward commits the first; the second block
-    # is the last, and nothing commits it
+    # a step a block: the second block's program commits the first; the
+    # second block is the last, and nothing commits it
     assert [s["flavour"] for s in steps] == \
-        ["gen.prefill"] + ["gen.denoise"] * 4 + ["gen.commit"] \
-        + ["gen.denoise"] * 3
+        ["gen.prefill", "gen.denoise", "gen.commit"]
     assert steps[0]["group"] == "gen:guard" and steps[0]["tokens_real"] == 8
-    assert [s["masks_left"] for s in steps[1:]] == [4, 3, 2, 1] * 2
-    assert [s["block"] for s in steps[1:]] == [0] * 4 + [1] * 4
-    assert [s["tokens_real"] for s in steps[1:]] == [L] * 4 + [2 * L] + [L] * 3
+    assert [s["masks_left"] for s in steps[1:]] == [4, 4]
+    assert [s["block"] for s in steps[1:]] == [0, 1]
+    # what a program takes in: a block, or the block before beside it
+    assert [s["tokens_real"] for s in steps[1:]] == [L, 2 * L]
     assert all(s["rows"] == 1 for s in steps[1:])
+    # how many forwards a program ran is known after it: the marker's fact
+    assert all("forwards" not in s for s in steps)
     marks = [f for n, f in seen if n == "engine.gen.forward"]
-    assert len(marks) == 9 and marks[1]["layers"] == 2
     assert [m["flavour"] for m in marks] == [s["flavour"] for s in steps]
-    assert marks[1]["pairs"] == 2 * L * 2  # layers x tokens x top-2
-    assert marks[5]["pairs"] == 2 * 2 * L * 2  # ... of two blocks
+    assert [m["forwards"] for m in marks] == [1, 4, 4]
+    assert [m["layers"] for m in marks] == [2, 4 * 2, 4 * 2]
+    # forwards x layers x tokens x top-2; a commit's first is of two blocks
+    assert marks[1]["pairs"] == 4 * 2 * L * 2
+    assert marks[2]["pairs"] == (4 + 1) * 2 * L * 2
     assert marks[1]["load_milli"] >= 1000
+    # a turn between two steps has no stage of its own: a block's state is
+    # made on the device
+    assert not [n for n, _ in seen if n.startswith("engine.gen.turn.")]
+    assert [f["after"] for n, f in seen if n == "engine.gen.turn"] == \
+        ["gen.prefill", "gen.denoise", "gen.commit"]
+    (done,) = [f for n, f in seen if n == "engine.gen.done"]
+    assert (done["forwards"], done["blocks"], done["tokens"]) == (9, 2, 8)
 
 
 def test_the_dense_generator_through_the_batcher_gives_its_tokens():

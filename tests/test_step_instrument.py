@@ -585,13 +585,16 @@ def _block_loop():
 
 
 LOOPS = {"greedy": _greedy_loop, "blockdiff": _block_loop}
+# the host's steps: a forward a program in the one loop, a BLOCK's forwards
+# a program in the other (a prefill and three blocks)
+STEPS = {"greedy": 4, "blockdiff": 4}
 PROMPTS = ["a prompt", "another"]
 
 
 @pytest.fixture(params=sorted(LOOPS))
 def loop(request):
-    """(engine, new tokens, forwards by flavour) of a toy generation
-    through one of the two loops, its programs compiled."""
+    """(engine, new tokens, forwards by flavour, steps) of a toy
+    generation through one of the two loops, its programs compiled."""
     from semantic_router_tpu.config.schema import InferenceEngineConfig
     from semantic_router_tpu.engine.classify import InferenceEngine
 
@@ -600,7 +603,7 @@ def loop(request):
         max_batch_size=4, max_wait_ms=1.0, seq_len_buckets=[32]))
     eng.register_generative("guard", gen)
     eng.generate("guard", PROMPTS, max_new_tokens=new_tokens)
-    yield eng, new_tokens, forwards
+    yield eng, new_tokens, forwards, STEPS[request.param]
     eng.shutdown()
 
 
@@ -619,13 +622,13 @@ def _gen_counters(eng):
 
 
 def test_steps_and_turns_tile_a_generation(loop, tmp_path):
-    eng, new_tokens, forwards = loop
+    eng, new_tokens, forwards, n_steps = loop
     _, rows = _profiled(tmp_path, lambda: eng.generate(
         "guard", PROMPTS, max_new_tokens=new_tokens))
     steps = _named(rows, batchtrace.STEP_ANNOTATION)
     turns = _named(rows, batchtrace.GEN_TURN_ANNOTATION)
     (done,) = _named(rows, batchtrace.GEN_DONE_ANNOTATION)
-    assert len(steps) == len(turns) == sum(forwards.values())
+    assert len(steps) == len(turns) == n_steps
     # one thread: step, turn, step, turn, ..., the last turn, done
     assert {r[0] for r in steps + turns + [done]} == {steps[0][0]}
     pieces = sorted(steps + turns, key=lambda r: r[2])
@@ -651,17 +654,19 @@ def test_steps_and_turns_tile_a_generation(loop, tmp_path):
         for step, turn, mark in zip(steps, turns, marks):
             assert mark[4]["flavour"] == step[4]["flavour"]
             assert step[3] <= mark[2] and turn[2] <= mark[2] <= turn[3]
-        # a block's copies are a stage of the turn before its first forward
-        copies = _named(rows, batchtrace.GEN_TURN_STAGE_ANNOTATIONS["h2d"])
-        assert len(copies) == 3
-        for c in copies:
-            assert any(t[2] <= c[2] and c[3] <= t[3] for t in turns)
+            assert mark[4]["forwards"] >= 1
+        # a program's forwards are on its marker; a generation's on its end
+        assert sum(m[4]["forwards"] for m in marks) == done[4]["forwards"] \
+            == sum(forwards.values())
+        # a block's state is made on the device: a turn holds no copies
+        assert not [r for r in rows
+                    if r[1].startswith(batchtrace.GEN_TURN_ANNOTATION + ".")]
     else:
         assert marks == []
 
 
 def test_gen_done_carries_the_counters_deltas_and_a_tiling(loop, tmp_path):
-    eng, new_tokens, forwards = loop
+    eng, new_tokens, forwards, n_steps = loop
     before = _gen_counters(eng)
     _, rows = _profiled(tmp_path, lambda: eng.generate(
         "guard", PROMPTS, max_new_tokens=new_tokens))
@@ -712,7 +717,7 @@ def _break_a_later_forward(eng):
 
 
 def test_a_raising_forward_ends_its_turn_and_writes_no_done(loop, tmp_path):
-    eng, new_tokens, forwards = loop
+    eng, new_tokens, forwards, n_steps = loop
     before = _gen_counters(eng)
     undo = _break_a_later_forward(eng)
 
@@ -773,7 +778,7 @@ def _spy_programs(eng, calls):
 
 
 def test_a_session_never_changes_a_generations_programs(loop, tmp_path):
-    eng, new_tokens, forwards = loop
+    eng, new_tokens, forwards, n_steps = loop
     calls, got = [], {}
     _spy_programs(eng, calls)
 
@@ -789,7 +794,9 @@ def test_a_session_never_changes_a_generations_programs(loop, tmp_path):
     del calls[:]
     got["session"], _ = _profiled(tmp_path, lambda: ask(None))
     tokens, programs = got["untraced"]
-    assert len(programs) == sum(forwards.values())
+    # a step is a program, but for a block with a block before it: the
+    # forward that commits, then the loop, queued one behind the other
+    assert len(programs) == n_steps + forwards.get("gen.commit", 0)
     assert [name for name, _ in programs].count("prefill") == 1
     # the same programs with the same arguments, bit for bit, whatever
     # watches: a request trace, a profiler session, nobody

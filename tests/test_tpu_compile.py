@@ -437,12 +437,11 @@ class TestBlockDiffusionGuardCompilesForV5e:
             assert_each_pair_moves_once_each_way(
                 compiled, tokens, cfg.num_experts_per_tok, H, 1_075_564_544)
 
-    @pytest.mark.parametrize("rows", [1, 16])
-    def test_the_committing_forward_writes_the_cache_in_place(
-            self, one_chip, monkeypatch, rows):
-        """The generator's ``commit`` program (two blocks a row, one layer
-        of the published widths, bucket 512): the donated cache comes back
-        as the same buffers, and the head scores one block."""
+    @staticmethod
+    def block_programs(one_chip, monkeypatch, rows):
+        """The generator's two block programs at the cell's shapes (bucket
+        512, blocks of 4, one layer of the published widths) with their
+        arguments as shapes on the described chip, and the cache's."""
         from semantic_router_tpu.models import sdar_moe as M
         from semantic_router_tpu.models.generate import (
             BlockDiffusionGenerator,
@@ -471,17 +470,79 @@ class TestBlockDiffusionGuardCompilesForV5e:
         cache = shape((rows, cfg.num_key_value_heads, cache_len,
                        cfg.head_dim))
         block = shape((rows, L), jnp.int32)
-        commit = gen.programs(rows, 512, cache_len)[2]
-        args = (params, [(cache, cache)], block, block,
-                shape((rows, L), bool), shape((rows,), jnp.int32),
-                shape((rows,), bool), shape((), jnp.int32))
+        base, valid = shape((rows,), jnp.int32), shape((rows,), bool)
+        step = shape((), jnp.int32)
+        outs = tuple(shape(a.shape, a.dtype) for a in gen.buffers(rows))
+        _, denoise, commit = gen.programs(rows, 512, cache_len)
+        return gen, cache, {
+            "denoise": (denoise, (
+                params, [(cache, cache)], block, shape((rows, L), bool),
+                base, valid, step, outs)),
+            "commit": (commit, (
+                params, [(cache, cache)], block, base, valid, step))}
+
+    @pytest.mark.parametrize("rows", [1, 16])
+    def test_the_committing_forward_writes_the_cache_in_place(
+            self, one_chip, monkeypatch, rows):
+        """The generator's ``commit`` program (two blocks a row): the
+        donated cache comes back as the same buffers, the head scores one
+        block, and what the loop goes on from has the loop's shapes."""
+        gen, cache, programs = self.block_programs(one_chip, monkeypatch,
+                                                   rows)
+        commit, args = programs["commit"]
         compiled = commit.lower(*args).compile()
         assert compiled.as_text().count("tpu_custom_call") >= 2
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes == 2 * int(np.prod(cache.shape)) * 2
-        _, _, _, report, experts, _ = jax.eval_shape(commit, *args)
-        assert report.shape == (rows, L, 4 + 2 * gen.top_logits)
-        assert experts.shape == (1, rows, 2 * L, cfg.num_experts_per_tok)
+        L, k = gen.block_length, 8
+        _, tokens, masked, start, outs, committed = jax.eval_shape(
+            commit, *args)
+        assert tokens.shape == masked.shape == (rows, L)
+        assert start.shape == (rows,) and committed.shape == (1, rows, L, k)
+        loop_args = programs["denoise"][1]
+        assert [(o.shape, o.dtype) for o in outs] == \
+            [(a.shape, a.dtype) for a in loop_args[-1]]
+        assert outs[0].shape == (gen.denoising_steps, rows, L,
+                                 4 + 2 * gen.top_logits)
+
+    def test_a_blocks_forwards_are_one_loop_that_copies_no_cache(
+            self, one_chip, monkeypatch):
+        """16 rows, the cell's shape: the forwards of a block are ONE
+        ``while`` of the compiled ``denoise`` program (the one that carries
+        the reports' buffer), the cache is not among what it carries forward
+        changed, and its body holds no copy of a cache-shaped array — a
+        forward inside the loop reads the cache where it lies, as a forward
+        that was a program did."""
+        import re
+
+        gen, cache, programs = self.block_programs(one_chip, monkeypatch, 16)
+        fn, args = programs["denoise"]
+        text = fn.lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text
+        reports = "f32[%d,16,4,%d]" % (gen.denoising_steps,
+                                       4 + 2 * gen.top_logits)
+        loops = [line for line in text.splitlines()
+                 if " while(" in line and reports in line.split(" while(")[0]]
+        assert len(loops) == 1, len(loops)
+        body = re.search(r"body=%?([\w.\-]+)", loops[0]).group(1)
+        start = text.index("\n%" + body + " (")
+        lines = text[start:text.index("\n}\n", start)].splitlines()
+        assert len(lines) > 100  # a whole forward
+        held = "bf16[%s]" % ",".join(str(d) for d in cache.shape)
+        copies = [line for line in lines if re.search(
+            r"= \(?" + re.escape(held) + r"[^=]* copy(-start)?\(", line)]
+        assert copies == [], copies[:2]
+        # what the loop gives back of the cache is what went in: the body's
+        # root hands on its own parameter's element, untouched
+        root = next(line for line in lines if "ROOT" in line)
+        handed_on = 0
+        for operand in re.findall(r"%[\w.\-]+", root.split(" tuple(")[1]):
+            made = next(line for line in lines
+                        if line.lstrip().startswith(operand + " = "))
+            if held in made.split(" = ")[1].split(" ")[0]:
+                assert "get-tuple-element(" in made, made[:200]
+                handed_on += 1
+        assert handed_on >= 2  # K and V of the layer
 
 
 class TestHybridGuardCompilesForV5e:
