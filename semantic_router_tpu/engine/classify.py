@@ -254,7 +254,10 @@ class _GenerationObserver:
     prefill's with its token fill) under group ``gen:<task>`` with the
     flavour as its variant, whose clock runs from ``forward`` to ``done``
     — and one ``record_generation`` count (the counters of /metrics).  A
-    token-at-a-time generator's step is one forward.  A block generator's
+    token-at-a-time generator's step is one forward (of two positions a
+    row where the model drafts for itself: ``done(drafted=, accepted=)``
+    then says how many drafts the step verified and how many were right,
+    and ``committed_tokens`` is one or two a row).  A block generator's
     is a BLOCK: ``gen.denoise`` is block 0's loop of forwards,
     ``gen.commit`` a later block's (its first forward commits block ``b -
     1`` beside block ``b``, two blocks of tokens a row, which is its
@@ -303,7 +306,8 @@ class _GenerationObserver:
 
     def done(self, load=None, forwards: int = 1, committed_blocks: int = 0,
              committed_tokens: int = 0, cache_bytes=None, keys=None,
-             rows_per_group=None) -> None:
+             rows_per_group=None, drafted: Optional[int] = None,
+             accepted: int = 0) -> None:
         """``load [layers, 4]`` of an expert model
         (models.sdar_moe.routed_experts), of a step of several
         ``forwards`` theirs stacked (``[forwards x layers, 4]``); a dense
@@ -316,7 +320,10 @@ class _GenerationObserver:
         selection, the keys its queries selected and those visible to
         them, summed on the device over the full layers.
         ``rows_per_group``: of a prefill whose rows are mapped inside the
-        program, how many of them one grouped matmul served."""
+        program, how many of them one grouped matmul served.  ``drafted``
+        / ``accepted``: of a step of a model that drafts for itself, the
+        drafts it verified (one a live row) and those that were right;
+        its ``committed_tokens`` is then the true count, one or two a row."""
         from ..observability import batchtrace
 
         step = self.step
@@ -325,14 +332,17 @@ class _GenerationObserver:
         self.clock.blocks += committed_blocks
         self.clock.tokens += committed_tokens
         if load is not None:
-            batchtrace.gen_forward(step.group, step.variant, load, keys,
-                                   rows_per_group, forwards)
+            batchtrace.gen_forward(
+                step.group, step.variant, load, keys, rows_per_group,
+                forwards, None if drafted is None else
+                (drafted, accepted, committed_tokens))
         try:
             self.engine._runtime_stats.record_generation(
                 self.task, step.variant, forwards=forwards,
                 committed_blocks=committed_blocks,
                 committed_tokens=committed_tokens, cache_bytes=cache_bytes,
-                rows_per_group=rows_per_group)
+                rows_per_group=rows_per_group, drafted=drafted or 0,
+                accepted=accepted)
         except Exception:
             pass  # observability never fails a generation
 

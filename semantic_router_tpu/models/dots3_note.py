@@ -37,7 +37,11 @@ under a mask.  A decode step masks its ``[rows, M]`` scores the same way.
 Prefill keeps K and V a head (``W_kvb`` applied to every position); decode
 is the absorbed form: ``q_i^nope`` goes through ``W_kvb,i``'s key half into
 the latent space, scores and the weighted sum are taken against the cached
-latents, and the value half comes last.  The same mathematics.
+latents, and the value half comes last.  The same mathematics — and the
+same code as ``models/joyai_llm_flash.py``'s: ``models/latent_attention.py``
+has the projections, the cache's row and both forms; what is this family's
+alone (the rescale and ``rotate_half`` through ``Geometry``, the indexer,
+the window's ring, the gate) is here.
 
 The cache a row carries: per full layer ``latent [rows, M, r_kv + rope]``
 (``c_kv ; k^r``, every token) and ``index [rows, M, index_head_dim]``
@@ -72,7 +76,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.flash_attention import flash_attention
-from ..ops.rope import RopeSpec, apply_rotary
+from ..ops.rope import apply_rotary
+from .latent_attention import (
+    Geometry,
+    absorbed,
+    keys_values,
+    latents,
+    put_latents,
+    queries,
+    tables,
+)
 from .lfm2_moe import (
     _sum_loads,
     _swiglu,
@@ -82,25 +95,13 @@ from .lfm2_moe import (
     tree_bytes,
 )
 from .qwen3 import torch_dtype_of
-from .sdar_moe import NEG_INF, checkpoint_reader, rms_norm, routed_experts
+from .sdar_moe import checkpoint_reader, rms_norm, routed_experts
 
 LAYER_TYPES = ("full_attention", "sliding_attention")
 INDEX_NORM_EPS = 1e-6   # the indexer's LayerNorm
 ROUTE_EPS = 1e-20       # under the chosen scores' sum
 INDEX_BLOCK = 512       # queries a block of a prefill's indexer scores
 SELECT_SAMPLE = 8       # prompt positions a row whose selection is reported
-
-
-@dataclasses.dataclass(frozen=True)
-class Geometry:
-    """The latent attention's numbers of one kind of layer."""
-    heads: int
-    r_q: int
-    r_kv: int
-    nope: int
-    rope: int
-    v: int
-    theta: float
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,10 +157,12 @@ class Dots3NoteConfig:
     def geometry(self, kind: str) -> Geometry:
         p = "swa_" if kind == "sliding_attention" else ""
         g = lambda name: getattr(self, p + name)  # noqa: E731
-        return Geometry(g("num_attention_heads"), g("q_lora_rank"),
-                        g("kv_lora_rank"), g("qk_nope_head_dim"),
-                        g("qk_rope_head_dim"), g("v_head_dim"),
-                        float(g("rope_theta")))
+        return Geometry(
+            g("num_attention_heads"), g("q_lora_rank"), g("kv_lora_rank"),
+            g("qk_nope_head_dim"), g("qk_rope_head_dim"), g("v_head_dim"),
+            float(g("rope_theta")), eps=self.rms_norm_eps,
+            rescale_to=self.hidden_size
+            if self.apply_mla_qkv_lora_rescale else 0, dtype=self.dtype)
 
     def is_sparse(self, i: int) -> bool:
         return i >= self.first_k_dense_replace
@@ -234,16 +237,20 @@ def _rows(get, name: str, first: int, count: int) -> np.ndarray:
     return np.asarray(get(name))[first:first + count]
 
 
-def params_from_state(get: Callable[[str], np.ndarray], cfg: Dots3NoteConfig
-                      ) -> Dict[str, Any]:
-    """The published tensor names (``get(name)`` loads one) as this
-    module's tree, in ``cfg.dtype`` on the default device; the router's
-    selection bias stays float32.  Only the experts held and the
-    vocabulary rows held are read."""
+def _on_device(cfg, a: np.ndarray, transpose: bool = False) -> jnp.ndarray:
+    x = jnp.asarray(a).astype(cfg.dtype)
+    return jnp.swapaxes(x, -1, -2) if transpose else x
 
-    def dev(a: np.ndarray, transpose: bool = False) -> jnp.ndarray:
-        x = jnp.asarray(a).astype(cfg.dtype)
-        return jnp.swapaxes(x, -1, -2) if transpose else x
+
+def layer_params(get: Callable[[str], np.ndarray], cfg, i: int
+                 ) -> Dict[str, Any]:
+    """Layer ``i`` under DeepSeek-V3's tensor names — the two norms, the
+    latent attention's seven tensors, and the dense SwiGLU or the router,
+    its selection bias (float32), the experts HELD (``cfg.held``: an
+    expert is a tensor of its own, so only those are read) and the shared
+    expert — as this family's and ``models/joyai_llm_flash.py``'s tree."""
+    def dev(a, transpose: bool = False):
+        return _on_device(cfg, a, transpose)
 
     def pair(prefix: str) -> Dict[str, Any]:
         """A SwiGLU's three matrices as ``gate_up`` and ``down``."""
@@ -252,21 +259,51 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: Dots3NoteConfig
                      dev(get(prefix + "up_proj.weight"), True)], -1),
                 "down": dev(get(prefix + "down_proj.weight"), True)}
 
+    p = f"model.layers.{i}."
+    a = p + "self_attn."
+    layer = {"norm1": dev(get(p + "input_layernorm.weight")),
+             "norm2": dev(get(p + "post_attention_layernorm.weight")),
+             "q_a": dev(get(a + "q_a_proj.weight"), True),
+             "q_a_norm": dev(get(a + "q_a_layernorm.weight")),
+             "q_b": dev(get(a + "q_b_proj.weight"), True),
+             "kv_a": dev(get(a + "kv_a_proj_with_mqa.weight"), True),
+             "kv_a_norm": dev(get(a + "kv_a_layernorm.weight")),
+             "kv_b": dev(get(a + "kv_b_proj.weight"), True),
+             "o_proj": dev(get(a + "o_proj.weight"), True)}
+    f = p + "mlp."
+    if not cfg.is_sparse(i):
+        layer.update(pair(f))
+        return layer
     first, count = cfg.held
+    experts = {k: np.stack([get(f"{f}experts.{e}.{k}_proj.weight")
+                            for e in range(first, first + count)])
+               for k in ("gate", "up", "down")}
+    layer.update(
+        router=dev(get(f + "gate.weight"), True),
+        expert_bias=jnp.asarray(np.asarray(
+            get(f + "gate.e_score_correction_bias"), np.float32)),
+        gate_up=jnp.concatenate([dev(experts["gate"], True),
+                                 dev(experts["up"], True)], -1),
+        down=dev(experts["down"], True),
+        shared=pair(f + "shared_experts."))
+    return layer
+
+
+def params_from_state(get: Callable[[str], np.ndarray], cfg: Dots3NoteConfig
+                      ) -> Dict[str, Any]:
+    """The published tensor names (``get(name)`` loads one) as this
+    module's tree, in ``cfg.dtype`` on the default device; the router's
+    selection bias stays float32.  Only the experts held and the
+    vocabulary rows held are read."""
+
+    def dev(a, transpose: bool = False):
+        return _on_device(cfg, a, transpose)
+
     layers = []
     for i, kind in enumerate(cfg.layer_types):
-        p = f"model.layers.{i}."
-        a = p + "self_attn."
-        layer = {"norm1": dev(get(p + "input_layernorm.weight")),
-                 "norm2": dev(get(p + "post_attention_layernorm.weight")),
-                 "q_a": dev(get(a + "q_a_proj.weight"), True),
-                 "q_a_norm": dev(get(a + "q_a_layernorm.weight")),
-                 "q_b": dev(get(a + "q_b_proj.weight"), True),
-                 "kv_a": dev(get(a + "kv_a_proj_with_mqa.weight"), True),
-                 "kv_a_norm": dev(get(a + "kv_a_layernorm.weight")),
-                 "kv_b": dev(get(a + "kv_b_proj.weight"), True),
-                 "o_proj": dev(get(a + "o_proj.weight"), True),
-                 "gate_proj": dev(get(a + "gate_proj.weight"), True)}
+        a = f"model.layers.{i}.self_attn."
+        layer = layer_params(get, cfg, i)
+        layer["gate_proj"] = dev(get(a + "gate_proj.weight"), True)
         if kind == "full_attention":
             x = a + "indexer."
             layer.update(
@@ -275,22 +312,6 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: Dots3NoteConfig
                 index_k_norm=dev(get(x + "k_norm.weight")),
                 index_k_bias=dev(get(x + "k_norm.bias")),
                 index_w=dev(get(x + "weights_proj.weight"), True))
-        f = p + "mlp."
-        if cfg.is_sparse(i):
-            experts = {k: np.stack([get(f"{f}experts.{e}.{k}_proj.weight")
-                                    for e in range(first, first + count)])
-                       for k in ("gate", "up", "down")}
-            layer.update(
-                router=dev(get(f + "gate.weight"), True),
-                expert_bias=jnp.asarray(np.asarray(
-                    get(f + "gate.e_score_correction_bias"), np.float32)),
-                gate_up=jnp.concatenate([dev(experts["gate"], True),
-                                         dev(experts["up"], True)], -1),
-                down=dev(experts["down"], True),
-                shared=pair(f + "shared_experts."))
-            del experts
-        else:
-            layer.update(pair(f))
         layers.append(layer)
     v_first, v_count = cfg.vocab
     return {"embed": dev(_rows(get, "model.embed_tokens.weight", v_first,
@@ -301,23 +322,6 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: Dots3NoteConfig
 
 
 # -- what prefill and decode share -----------------------------------------------
-
-
-def _latent_norm(cfg, x, w, rank: int):
-    """``RMSNorm(x) * sqrt(H / rank)`` (the rescale), rounded once."""
-    xf = x.astype(jnp.float32)
-    out = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
-                             + cfg.rms_norm_eps) * w.astype(jnp.float32)
-    if cfg.apply_mla_qkv_lora_rescale:
-        out = out * float(np.sqrt(cfg.hidden_size / rank))
-    return out.astype(cfg.dtype)
-
-
-def _tables(g: Geometry, positions, table_len: int):
-    """cos and sin ``[..., rope]`` at ``positions``."""
-    cos_t, sin_t = RopeSpec(g.rope, g.theta).tables(table_len)
-    return (jnp.take(cos_t, positions, axis=0),
-            jnp.take(sin_t, positions, axis=0))
 
 
 def _rotate_front(x, cos, sin, n: int):
@@ -454,40 +458,6 @@ def _gate_out(cfg, g: Geometry, p, h, out):
 # -- prefill ---------------------------------------------------------------------
 
 
-def _queries(cfg, g: Geometry, p, h, cos, sin):
-    """``(c_q [B, S, r_q], q [B, heads, S, nope + rope])``."""
-    with jax.named_scope("q"):
-        c_q = _latent_norm(cfg, h @ p["q_a"], p["q_a_norm"], g.r_q)
-        q = jnp.einsum("bsr,rhd->bhsd", c_q,
-                       p["q_b"].reshape(g.r_q, g.heads, g.nope + g.rope))
-        return c_q, _rotate_back(q, cos[:, None], sin[:, None], g.nope)
-
-
-def _rotate_back(x, cos, sin, n: int):
-    """RoPE on the dims of ``x``'s last axis from ``n`` on."""
-    back, _ = apply_rotary(x[..., n:], x[..., n:], cos, sin)
-    return jnp.concatenate([x[..., :n], back], -1)
-
-
-def _latents(cfg, g: Geometry, p, h, cos, sin):
-    """What a token leaves in the cache: ``[c_kv ; k^r] [..., r_kv +
-    rope]``."""
-    kv = h @ p["kv_a"]
-    c_kv = _latent_norm(cfg, kv[..., :g.r_kv], p["kv_a_norm"], g.r_kv)
-    return jnp.concatenate(
-        [c_kv, _rotate_back(kv[..., g.r_kv:], cos, sin, 0)], -1)
-
-
-def _keys_values(g: Geometry, p, lat):
-    """``lat [B, S, r_kv + rope]`` -> ``k [B, heads, S, nope + rope]``,
-    ``v [B, heads, S, v]``."""
-    kvb = jnp.einsum("bsr,rhd->bhsd", lat[..., :g.r_kv],
-                     p["kv_b"].reshape(g.r_kv, g.heads, g.nope + g.v))
-    k_r = jnp.broadcast_to(lat[:, None, :, g.r_kv:],
-                           kvb.shape[:3] + (g.rope,))
-    return jnp.concatenate([kvb[..., :g.nope], k_r], -1), kvb[..., g.nope:]
-
-
 def _select_prefill(cfg, q_i, k_i, w, valid):
     """The prompt's selection ``[B, S, S]`` (bool) from ``q^I [B, S, j,
     d]``, ``k^I [B, S, d]``, ``w [B, S, j]``: a block of queries at a time
@@ -544,16 +514,15 @@ def _prefill_rows(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
     keys = jnp.zeros((B, 2), jnp.int32)
     for i, (kind, p) in enumerate(zip(cfg.layer_types, params["layers"])):
         g = cfg.geometry(kind)
-        cos, sin = _tables(g, positions, S)
-        scale = float((g.nope + g.rope) ** -0.5)
+        cos, sin = tables(g, positions, S)
         with jax.named_scope(f"layers_{i}"):
             h = rms_norm(x, p["norm1"], cfg.rms_norm_eps, cfg.dtype)
             if kind == "full_attention":
                 with jax.named_scope("attn_full"):
-                    c_q, q = _queries(cfg, g, p, h, cos, sin)
+                    c_q, q = queries(g, p, h, cos, sin)
                     with jax.named_scope("kv"):
-                        lat = _latents(cfg, g, p, h, cos, sin)
-                        k, v = _keys_values(g, p, lat)
+                        lat = latents(g, p, h, cos, sin)
+                        k, v = keys_values(g, p, lat)
                     with jax.named_scope("indexer"):
                         q_i = _rotate_front(
                             (c_q @ p["index_q"]).reshape(
@@ -574,20 +543,20 @@ def _prefill_rows(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
                     with jax.named_scope("core"):
                         out = flash_attention(
                             q, k, v, key_padding_mask=mask, causal=True,
-                            scale=scale, select=chosen.astype(jnp.int8))
+                            scale=g.scale, select=chosen.astype(jnp.int8))
                     x = x + _gate_out(cfg, g, p, h, out)
                     latent.append(jnp.pad(lat, pad_to))
                     index.append(jnp.pad(k_i, pad_to))
             else:
                 with jax.named_scope("attn_window"):
-                    _, q = _queries(cfg, g, p, h, cos, sin)
+                    _, q = queries(g, p, h, cos, sin)
                     with jax.named_scope("kv"):
-                        lat = _latents(cfg, g, p, h, cos, sin)
-                        k, v = _keys_values(g, p, lat)
+                        lat = latents(g, p, h, cos, sin)
+                        k, v = keys_values(g, p, lat)
                     with jax.named_scope("core"):
                         out = flash_attention(
                             q, k, v, key_padding_mask=mask, causal=True,
-                            window=2 * (W - 1), scale=scale)
+                            window=2 * (W - 1), scale=g.scale)
                     x = x + _gate_out(cfg, g, p, h, out)
                     with jax.named_scope("kv"):
                         # slot j holds the latest position p <= last with
@@ -718,22 +687,6 @@ def prefill(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
 # -- decode: one token a row against the latent cache ----------------------------
 
 
-def _absorbed(cfg, g: Geometry, p, q, lat, seen, scale: float):
-    """One query a row in the latent space: ``q [B, heads, nope + rope]``
-    against ``lat [B, M, r_kv + rope]`` under ``seen [B, M]`` -> ``[B,
-    heads, 1, v]``."""
-    kv_b = p["kv_b"].reshape(g.r_kv, g.heads, g.nope + g.v)
-    q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :g.nope], kv_b[..., :g.nope])
-    qq = jnp.concatenate([q_lat.astype(cfg.dtype), q[..., g.nope:]], -1)
-    s = jnp.einsum("bhc,bmc->bhm", qq, lat,
-                   preferred_element_type=jnp.float32) * scale
-    s = s + jnp.where(seen, 0.0, NEG_INF)[:, None, :]
-    o_lat = jnp.einsum("bhm,bmr->bhr",
-                       jax.nn.softmax(s, axis=-1).astype(cfg.dtype),
-                       lat[..., :g.r_kv])
-    return jnp.einsum("bhr,rhv->bhv", o_lat, kv_b[..., g.nope:])[:, :, None]
-
-
 def decode(cfg: Dots3NoteConfig, params, cache, tokens, positions):
     """``tokens [B]`` at ``positions [B]`` (a row's count of tokens before
     this one), all rows together.  Returns ``(cache, logits [B, V], aux)``
@@ -745,8 +698,7 @@ def decode(cfg: Dots3NoteConfig, params, cache, tokens, positions):
     W = cfg.sliding_window_size
     live = cache["lengths"] > 0
     pos = positions[:, None]
-    put = jax.vmap(lambda c, new, at: jax.lax.dynamic_update_slice(
-        c, new, (at, 0)))
+    put = put_latents
     with jax.named_scope("embed_tokens"):
         x = jnp.take(params["embed"], tokens, axis=0)[:, None]  # [B, 1, H]
     latent, index, window, experts, loads, selected = [], [], [], [], [], []
@@ -756,16 +708,15 @@ def decode(cfg: Dots3NoteConfig, params, cache, tokens, positions):
     M = cache["latent"][0].shape[1]  # every position has a column there
     for i, (kind, p) in enumerate(zip(cfg.layer_types, params["layers"])):
         g = cfg.geometry(kind)
-        scale = float((g.nope + g.rope) ** -0.5)
         with jax.named_scope(f"layers_{i}"):
             h = rms_norm(x, p["norm1"], cfg.rms_norm_eps, cfg.dtype)
             if kind == "full_attention":
                 with jax.named_scope("attn_full"):
                     lat, idx = next(lat_in), next(idx_in)
-                    cos, sin = _tables(g, pos, M)
-                    c_q, q = _queries(cfg, g, p, h, cos, sin)
+                    cos, sin = tables(g, pos, M)
+                    c_q, q = queries(g, p, h, cos, sin)
                     with jax.named_scope("kv"):
-                        lat = put(lat, _latents(cfg, g, p, h, cos, sin),
+                        lat = put(lat, latents(g, p, h, cos, sin),
                                   positions)
                     visible = jnp.arange(M)[None, :] <= pos  # [B, M]
                     with jax.named_scope("indexer"):
@@ -789,23 +740,21 @@ def decode(cfg: Dots3NoteConfig, params, cache, tokens, positions):
                                 -1).astype(jnp.int32) * live[:, None]
                             selected.append(jnp.packbits(chosen, axis=-1))
                     with jax.named_scope("core"):
-                        out = _absorbed(cfg, g, p, q[:, :, 0], lat, chosen,
-                                        scale)
+                        out = absorbed(g, p, q, lat, chosen[:, None])
                     x = x + _gate_out(cfg, g, p, h, out)
                     latent.append(lat)
                     index.append(idx)
             else:
                 with jax.named_scope("attn_window"):
                     ring = next(win_in)
-                    cos, sin = _tables(g, pos, M)
-                    _, q = _queries(cfg, g, p, h, cos, sin)
+                    cos, sin = tables(g, pos, M)
+                    _, q = queries(g, p, h, cos, sin)
                     with jax.named_scope("kv"):
-                        ring = put(ring, _latents(cfg, g, p, h, cos, sin),
+                        ring = put(ring, latents(g, p, h, cos, sin),
                                    positions % W)
                     with jax.named_scope("core"):
                         seen = jnp.arange(W)[None, :] <= pos
-                        out = _absorbed(cfg, g, p, q[:, :, 0], ring, seen,
-                                        scale)
+                        out = absorbed(g, p, q, ring, seen[:, None])
                     x = x + _gate_out(cfg, g, p, h, out)
                     window.append(ring)
             x, top_e, load = _feed_forward(cfg, i, p, x, live[:, None])

@@ -1,6 +1,8 @@
 """Generative serving: one token-at-a-time loop (``GreedyGenerator``) over
 a model that owns its cache — the dense Qwen3 below, the hybrid
-``models/lfm2_moe.py`` — and generation by diffusion over blocks
+``models/lfm2_moe.py``, the latent-attention ``models/dots3_note.py`` — which
+commits one or two tokens a step where the model drafts for itself
+(``models/joyai_llm_flash.py``), and generation by diffusion over blocks
 (``BlockDiffusionGenerator``, ``models/sdar_moe.py``).
 
 Reference capabilities re-designed TPU-first:
@@ -339,6 +341,14 @@ def select_greedy(logits, top_logits: int):
         return top_i[:, 0].astype(jnp.int32), report
 
 
+def advance(positions, accepted, last: int):
+    """Where a self-drafting step leaves its rows: one column on, or two
+    where the draft was accepted.  A rejected draft's column is not
+    counted — the next step starts there and overwrites it.  Never past
+    ``last`` (a finished row goes on stepping until its batch is done)."""
+    return jnp.minimum(positions + 1 + accepted, last)
+
+
 class Qwen3Cached:
     """The dense Qwen3 (with its LoRA adapters) behind the interface
     ``GreedyGenerator`` decodes through: a model that owns its cache.
@@ -413,7 +423,20 @@ class GreedyGenerator:
     its cache (``Qwen3Cached`` unless ``model`` is given): one jitted
     prefill + one jitted step per (B, prompt_bucket, cache_len) shape; the
     token is chosen on the device (``select_greedy``) and the host loop,
-    which reads one small report a forward, handles EOS."""
+    which reads one small report a forward, handles EOS.
+
+    A model that DRAFTS for itself (``model.drafts``: its checkpoint has a
+    multi-token-prediction module; ``models.joyai_llm_flash.CachedModel`` has
+    the protocol) is stepped two positions at a time: a row's state is its
+    last committed token ``x`` at position ``p``, not yet run, and a draft
+    ``d`` for ``p + 1``; ONE program a step runs the layers on ``[x, d]``
+    (``verify``), chooses ``y`` and ``z`` from both positions' logits,
+    accepts iff ``y == d`` (then ``z`` is committed too), runs the drafter on
+    what was chosen (``draft``) and advances the row by 1 or 2, all on the
+    device.  The tokens served are those of the same model decoding a token
+    at a time; drafting changes the number of steps and nothing else.  No
+    knob: a model without a drafter takes the token-at-a-time path as it
+    was."""
 
     def __init__(self, config, params,
                  tokenizer, lora: Optional[LoRAConfig] = None,
@@ -430,6 +453,7 @@ class GreedyGenerator:
         self.eos_token_ids = set(int(t) for t in eos_token_ids)
         self.pad_id = pad_id
         self.top_logits = top_logits
+        self.drafts = bool(getattr(self.model, "drafts", False))
         self._prefill_cache: Dict[Tuple, Any] = {}
         self._step_cache: Dict[Tuple, Any] = {}
 
@@ -450,8 +474,48 @@ class GreedyGenerator:
                     params, ids, lengths, M, task_index)
                 tokens, report = select_greedy(logits, self.top_logits)
                 return cache, tokens, report, aux
-            self._prefill_cache[key] = jax.jit(fn)
+
+            def drafting(params, ids, lengths, task_index):
+                cache, tokens, report, aux = fn(params, ids, lengths,
+                                                task_index)
+                cache, logits, aux = self.model.first_draft(
+                    params, cache, ids, lengths, tokens, aux)
+                draft, drafted = select_greedy(logits, self.top_logits)
+                return cache, (tokens, draft), (report, drafted), aux
+            self._prefill_cache[key] = jax.jit(
+                drafting if self.drafts else fn)
         return self._prefill_cache[key]
+
+    def _verify_fn(self, key):
+        """A drafting model's step (the class's docstring): ``(cache,
+        (tokens, draft), positions)`` -> the same after one or two more
+        tokens a row, ``report = (chosen [B, 2, 2 + 2 top], accepted [B],
+        drafted [B, 2 + 2 top])`` — ``select_greedy``'s report of both
+        positions and of the drafter's logits behind the next draft — and
+        ``aux``.  A row stops advancing two columns short of the cache's
+        end; a live row never gets there (``generate`` sizes the cache)."""
+        if key not in self._step_cache:
+            M = key[2]
+
+            def fn(params, cache, state, positions, task_index):
+                tokens, draft = state
+                B = tokens.shape[0]
+                cache, logits, hidden, aux = self.model.verify(
+                    params, cache, jnp.stack([tokens, draft], 1), positions,
+                    task_index)
+                chosen, report = select_greedy(
+                    logits.reshape(2 * B, -1), self.top_logits)
+                chosen = chosen.reshape(B, 2)
+                accepted = chosen[:, 0] == draft
+                cache, logits, aux = self.model.draft(
+                    params, cache, hidden, chosen, positions, accepted, aux)
+                draft, drafted = select_greedy(logits, self.top_logits)
+                tokens = jnp.where(accepted, chosen[:, 1], chosen[:, 0])
+                positions = advance(positions, accepted, M - 2)
+                return cache, (tokens, draft), positions, (
+                    report.reshape(B, 2, -1), accepted, drafted), aux
+            self._step_cache[key] = jax.jit(fn, donate_argnums=(1,))
+        return self._step_cache[key]
 
     def _step_fn(self, key):
         if key not in self._step_cache:
@@ -544,6 +608,9 @@ class GreedyGenerator:
         trajectory: List[List[Dict[str, Any]]] = [[] for _ in range(n)]
         finished = np.zeros(B, bool)
         finished[n:] = True
+        drafted = None
+        if self.drafts:
+            report, drafted = report
         with fwd.stage("demux"):
             choices("prefill", lengths - 1, report, aux)
         fwd.done(load=aux.get("load"), committed_tokens=n,
@@ -552,6 +619,18 @@ class GreedyGenerator:
                  rows_per_group=self.model.rows_per_group(
                      self.params, B, S, M))
 
+        def results() -> List[GenerationResult]:
+            return [_finish_tokens(self.tokenizer, out_tokens[i],
+                                   self.eos_token_ids, stop_strings,
+                                   int(lengths[i]), trajectory[i])
+                    for i in range(n)]
+
+        if self.drafts:
+            self._verify_steps(
+                obs, (cache, tokens_dev, positions_dev, task_arr),
+                (B, 2, M), report, drafted, lengths, max_new_tokens,
+                out_tokens, trajectory, finished)
+            return results()
         step = self._step_fn((B, 1, M))
         for t in range(max_new_tokens):
             next_tok = report[:, 0].astype(np.int32)
@@ -574,10 +653,69 @@ class GreedyGenerator:
             fwd.done(load=aux.get("load"), committed_tokens=live,
                      keys=aux.get("keys"))
         del cache
-        return [_finish_tokens(self.tokenizer, out_tokens[i],
-                               self.eos_token_ids, stop_strings,
-                               int(lengths[i]), trajectory[i])
-                for i in range(n)]
+        return results()
+
+    def _verify_steps(self, obs, device, key, report, drafted, lengths,
+                      budget: int, out_tokens, trajectory, finished) -> None:
+        """A drafting model's steps after the prefill whose ``report`` and
+        first draft's ``drafted`` are given: one ``gen.decode`` step a turn
+        (``_verify_fn``) until every row has its ``budget`` of tokens or an
+        end-of-sequence token, filling ``out_tokens``, ``trajectory`` and
+        ``finished`` in place.  The host only mirrors what the device
+        decided: a row's position, and which of a pair's tokens count."""
+        cache, state, positions, task = device
+        k, n = self.top_logits, len(trajectory)
+
+        def entry(row) -> Dict[str, Any]:
+            return {"token": int(row[0]), "lse": row[1],
+                    "top_ids": row[2:2 + k].astype(np.int32),
+                    "top_logits": row[2 + k:]}
+
+        def commit(i: int, token: int) -> None:
+            out_tokens[i].append(token)
+            finished[i] = token in self.eos_token_ids \
+                or len(out_tokens[i]) >= budget
+
+        at = lengths.astype(np.int64)  # the committed token not yet run
+        for i in range(n):
+            trajectory[i][-1]["draft"] = dict(entry(drafted[i]),
+                                              position=int(at[i]) - 1)
+            commit(i, int(report[i, 0]))
+        verify = self._verify_fn(key)
+        t = 0
+        while not finished.all():
+            live = np.flatnonzero(~finished)
+            fwd = obs.forward("gen.decode", tokens_real=2 * len(live),
+                              block=t)
+            with fwd.stage("dispatch"):
+                cache, state, positions, report, aux = verify(
+                    self.params, cache, state, positions, task)
+            with fwd.stage("readback"):
+                (report, accepted, after), aux = jax.device_get(
+                    (report, aux))
+            committed = 0
+            with fwd.stage("demux"):
+                for i in live:
+                    for slot in range(1 + int(accepted[i])):
+                        e = dict(entry(report[i, slot]), kind="decode",
+                                 position=int(at[i]) + slot,
+                                 experts=aux["experts"][:, i, slot, None])
+                        if slot == 0:
+                            e.update(
+                                drafted=int(drafted[i, 0]),
+                                accepted=bool(accepted[i]),
+                                draft=dict(entry(after[i]), position=int(
+                                    at[i]) + int(accepted[i])))
+                        trajectory[i].append(e)
+                        commit(i, e["token"])
+                        committed += 1
+                        if finished[i]:
+                            break
+                    at[i] += 1 + int(accepted[i])
+                drafted = after
+            fwd.done(load=aux["load"], committed_tokens=committed,
+                     drafted=len(live), accepted=int(accepted[live].sum()))
+            t += 1
 
 
 # ---------------------------------------------------------------------------

@@ -313,7 +313,7 @@ def queue_wait(trace_id: str, group: str, wait_s: float) -> None:
 
 
 def gen_forward(group: str, flavour: str, load, keys=None,
-                rows_per_group=None, forwards: int = 1) -> None:
+                rows_per_group=None, forwards: int = 1, drafts=None) -> None:
     """``engine.gen.forward``: a step of a generation ended now, and
     this is what only its readback knew: the ``forwards`` the device ran
     in it (a block generator's step is a block's loop) and ``load
@@ -326,12 +326,18 @@ def gen_forward(group: str, flavour: str, load, keys=None,
     ``keys_selected`` and ``keys_visible``: what the forward's queries
     selected and what they could see, over its rows and full layers.
     ``rows_per_group`` of a prefill mapped over groups of rows adds the
-    fact of that name: the rows one grouped matmul served."""
+    fact of that name: the rows one grouped matmul served.  ``drafts =
+    (drafted, accepted, committed_tokens)`` of a step of a model that drafts
+    for itself adds those three: the drafts verified (one a live row), those
+    that were right, and the tokens the step committed (one or two a row)."""
     facts = {} if keys is None else {
         "keys_selected": int(keys[:, 0].sum(dtype="int64")),
         "keys_visible": int(keys[:, 1].sum(dtype="int64"))}
     if rows_per_group is not None:
         facts["rows_per_group"] = int(rows_per_group)
+    if drafts is not None:
+        facts.update(zip(("drafted", "accepted", "committed_tokens"),
+                         map(int, drafts)))
     with trace_span(GEN_FORWARD_ANNOTATION, group=group, flavour=flavour,
                     forwards=int(forwards), layers=len(load),
                     pairs=int(load[:, 1].sum()),

@@ -196,6 +196,16 @@ class RuntimeStats:
         self.gen_tokens = registry.counter(
             "llm_runtime_gen_tokens_committed_total",
             "Tokens committed to the cache by generative tasks")
+        self.gen_drafts = registry.counter(
+            "llm_runtime_gen_draft_tokens_total",
+            "Tokens a generative task's own drafter (its multi-token-"
+            "prediction module) proposed and a decode step verified, one "
+            "per live row and step")
+        self.gen_drafts_accepted = registry.counter(
+            "llm_runtime_gen_draft_accepted_total",
+            "Drafted tokens that were the model's own choice: the step "
+            "committed a second token for that row; over "
+            "llm_runtime_gen_draft_tokens_total it is the acceptance rate")
         self.gen_seconds = registry.counter(
             "llm_runtime_gen_seconds_total",
             "Host-clock seconds of finished generations by phase: forward "
@@ -213,7 +223,8 @@ class RuntimeStats:
             "(kv: keys and values that grow with the context; conv: "
             "fixed-size recurrent state; latent, index: a latent-attention "
             "layer's compressed keys and its indexer's keys, both growing "
-            "with the context; window: a sliding layer's ring of latents)")
+            "with the context; window: a sliding layer's ring of latents; "
+            "draft: the latents of a self-drafting model's drafter)")
         self.gen_rows_per_group = registry.gauge(
             "llm_runtime_gen_prefill_rows_per_group",
             "Rows of a generative task's newest prefill that went through "
@@ -264,7 +275,8 @@ class RuntimeStats:
     def record_generation(self, task: str, flavour: str, forwards: int = 1,
                           committed_blocks: int = 0,
                           committed_tokens: int = 0,
-                          cache_bytes=None, rows_per_group=None) -> None:
+                          cache_bytes=None, rows_per_group=None,
+                          drafted: int = 0, accepted: int = 0) -> None:
         """One step of a generation (the engine's generative runner; a
         forward, or a block generator's block): one
         llm_runtime_gen_programs_total and ``forwards``
@@ -281,7 +293,10 @@ class RuntimeStats:
         annotations (expert load among them).  ``cache_bytes`` (a
         prefill's, by kind) sets llm_runtime_gen_cache_bytes,
         ``rows_per_group`` (a mapped prefill's)
-        llm_runtime_gen_prefill_rows_per_group."""
+        llm_runtime_gen_prefill_rows_per_group; ``drafted`` / ``accepted``
+        (a self-drafting model's step: its ``committed_tokens`` is one or
+        two a row) llm_runtime_gen_draft_tokens_total and
+        llm_runtime_gen_draft_accepted_total."""
         if not self.enabled:
             return
         self.gen_programs.inc(task=task, flavour=flavour)
@@ -297,6 +312,10 @@ class RuntimeStats:
             self.gen_blocks.inc(committed_blocks, task=task)
         if committed_tokens:
             self.gen_tokens.inc(committed_tokens, task=task)
+        if drafted:
+            self.gen_drafts.inc(drafted, task=task)
+        if accepted:
+            self.gen_drafts_accepted.inc(accepted, task=task)
 
     def record_generation_done(self, task: str,
                                seconds: Dict[str, float]) -> None:

@@ -163,6 +163,17 @@ GENERATORS = {
         "``vocab_held``: ids and logits are then over that slice).",
         _serve_cached("dots3_note", "Dots3NoteConfig",
                       ("experts_held", "vocab_held"))),
+    "joyai_llm_flash": (
+        "DeepSeek-V3's layers (latent attention in every layer, "
+        "sigmoid-and-bias expert routing beside a shared expert) with the "
+        "checkpoint's multi-token-prediction module as its own drafter: "
+        "the same loop, whose decode step then runs the last committed "
+        "token and the module's draft of the next and commits one token "
+        "or two a row, the tokens being those of one-at-a-time decoding; "
+        "no setting turns it on or off, a checkpoint without the module "
+        "(``num_nextn_predict_layers`` 0) decodes a token at a time "
+        "(``generation: {gen_length}``; ``experts_held``).",
+        _serve_cached("joyai_llm_flash", "JoyaiLlmFlashConfig")),
 }
 GENERATIVE_MODEL_TYPES = tuple(GENERATORS)
 

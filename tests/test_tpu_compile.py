@@ -145,6 +145,40 @@ def dots3_param_shapes(cfg, shape):
             "lm_head": shape((V, H)), "layers": layers}
 
 
+def joyai_param_shapes(cfg, shape):
+    """The ``joyai_llm_flash`` parameter tree of ``cfg`` as shapes."""
+    H, I, W = cfg.hidden_size, cfg.moe_intermediate_size, \
+        cfg.intermediate_size
+    E, held, V = cfg.n_routed_experts, cfg.held[1], cfg.vocab_size
+    g = cfg.geometry
+
+    def block(i):
+        p = {"norm1": shape((H,)), "norm2": shape((H,)),
+             "q_a": shape((H, g.r_q)), "q_a_norm": shape((g.r_q,)),
+             "q_b": shape((g.r_q, g.heads * (g.nope + g.rope))),
+             "kv_a": shape((H, g.r_kv + g.rope)),
+             "kv_a_norm": shape((g.r_kv,)),
+             "kv_b": shape((g.r_kv, g.heads * (g.nope + g.v))),
+             "o_proj": shape((g.heads * g.v, H))}
+        if cfg.is_sparse(i):
+            p.update(router=shape((H, E)),
+                     expert_bias=shape((E,), jnp.float32),
+                     gate_up=shape((held, H, 2 * I)),
+                     down=shape((held, I, H)),
+                     shared={"gate_up": shape((H, 2 * I)),
+                             "down": shape((I, H))})
+        else:
+            p.update(gate_up=shape((H, 2 * W)), down=shape((W, H)))
+        return p
+
+    n = cfg.num_hidden_layers
+    return {"embed": shape((V, H)), "norm": shape((H,)),
+            "lm_head": shape((V, H)), "layers": [block(i) for i in range(n)],
+            "mtp": {"enorm": shape((H,)), "hnorm": shape((H,)),
+                    "eh_proj": shape((2 * H, H)), "norm": shape((H,)),
+                    "block": block(n)}}
+
+
 def on_the_described_chip(monkeypatch):
     """Steer the programs' platform reads to the described v5e (the test's
     backend is the CPU): the megablox kernel, the flash kernel compiled
@@ -761,6 +795,67 @@ class TestSparseLatentGuardCompilesForV5e:
             shape((rows,), jnp.int32), shape((rows,), jnp.int32),
             shape((), jnp.int32))
         assert aux["selected"].shape == (1, rows, M // 8)
+
+
+class TestSelfDraftingGuardCompilesForV5e:
+    """The joyai_llm_flash guard at the published widths (hidden 2048; 32
+    latent heads, q/k 192 and v 128; 128 of 256 experts of width 768 held
+    beside a shared one; the whole vocabulary of 129,280; the MTP module),
+    bucket 512 x 16 rows: the dense layer, one expert layer and the
+    module, which is every kind of part the cell's seven blocks have."""
+
+    def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch):
+        """The generator's prefill (the layers, the choice, then the
+        module over the prompt) and its step (two positions a row through
+        the layers, the choice, the module, the advance: ONE program that
+        writes both donated latent caches in place and returns a small
+        report)."""
+        from semantic_router_tpu.models import joyai_llm_flash
+        from semantic_router_tpu.models.generate import GreedyGenerator
+
+        on_the_described_chip(monkeypatch)
+        cfg = joyai_llm_flash.JoyaiLlmFlashConfig(
+            num_hidden_layers=2, experts_held=(0, 128))
+        rows, S, M = 16, 512, 576
+
+        def shape(dims, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+        params = joyai_param_shapes(cfg, shape)
+        gen = GreedyGenerator(cfg, None, None,
+                              model=joyai_llm_flash.CachedModel(cfg))
+        assert gen.drafts
+        vec, scalar = shape((rows,), jnp.int32), shape((), jnp.int32)
+        args = (params, shape((rows, S), jnp.int32), vec, scalar)
+        prefill = gen._prefill_fn((rows, S, M))
+        compiled = prefill.lower(*args).compile()
+        # three attention cores, two expert layers' two grouped matmuls
+        assert compiled.as_text().count("tpu_custom_call") >= 7
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * 2**30
+        cache, state, report, aux = jax.eval_shape(prefill, *args)
+        assert "hidden" not in cache  # the prompt's h_i stay in the program
+        assert [a.shape for a in state] == [(rows,), (rows,)]
+        assert [a.shape for a in report] == [(rows, 2 + 2 * gen.top_logits)] * 2
+        assert aux["experts"].shape == (2, rows, S, 8)
+        cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
+                                       cache)
+        sizes = joyai_llm_flash.CachedModel.cache_bytes(cache)
+        assert sizes == {"latent": 2 * rows * M * (512 + 64) * 2,
+                         "draft": rows * M * (512 + 64) * 2}
+        verify = gen._verify_fn((rows, 2, M))
+        step_args = (params, cache, (vec, vec), vec, scalar)
+        step = verify.lower(*step_args).compile()
+        mem = step.memory_analysis()
+        assert mem.alias_size_in_bytes >= sum(sizes.values())
+        assert mem.temp_size_in_bytes < 0.2 * 2**30
+        assert step.as_text().count("tpu_custom_call") >= 4
+        _, state, at, (chosen, accepted, drafted), aux = jax.eval_shape(
+            verify, *step_args)
+        assert chosen.shape == (rows, 2, 2 + 2 * gen.top_logits)
+        assert accepted.shape == at.shape == (rows,)
+        assert drafted.shape == (rows, 2 + 2 * gen.top_logits)
+        assert aux["experts"].shape == (2, rows, 2, 8)
+        assert aux["load"].shape == (2, 4)
 
 
 class TestLongPromptGuardsPrefillFitsAtTheRulesGroup:
