@@ -249,16 +249,17 @@ class _GenerationObserver:
     EngineStep — one ``engine.step`` annotation with its stages (flavour
     ``gen.prefill`` | ``gen.denoise`` | ``gen.commit`` | ``gen.decode``;
     facts ``rows``, ``padded_rows``, ``tokens_real`` — a prefill's padded
-    positions are ``padded_rows`` x ``bucket`` — ``block`` (the block run,
-    or the decode step), ``masks_left``) and one ``record_step`` sample (a
+    positions are ``padded_rows`` x ``bucket`` — ``block`` (the block run),
+    ``masks_left``) and one ``record_step`` sample (a
     prefill's with its token fill) under group ``gen:<task>`` with the
     flavour as its variant, whose clock runs from ``forward`` to ``done``
     — and one ``record_generation`` count (the counters of /metrics).  A
-    token-at-a-time generator's step is one forward (of two positions a
-    row where the model drafts for itself: ``done(drafted=, accepted=)``
-    then says how many drafts the step verified and how many were right,
-    and ``committed_tokens`` is one or two a row).  A block generator's
-    is a BLOCK: ``gen.denoise`` is block 0's loop of forwards,
+    token-at-a-time generator's ``gen.decode`` step is a generation's LOOP
+    of decode steps, one program (a decode step is two positions a row
+    where the model drafts for itself: ``done(drafted=, accepted=)`` then
+    says how many drafts the steps verified and how many were right, and
+    ``committed_tokens`` is one or two a row and step).  A block
+    generator's is a BLOCK: ``gen.denoise`` is block 0's loop of forwards,
     ``gen.commit`` a later block's (its first forward commits block ``b -
     1`` beside block ``b``, two blocks of tokens a row, which is its
     ``tokens_real``; the loop is queued behind it); ``done(forwards=n)``
@@ -313,17 +314,19 @@ class _GenerationObserver:
         ``forwards`` theirs stacked (``[forwards x layers, 4]``); a dense
         generator gives none.  ``committed_blocks`` / ``committed_tokens``:
         what this step FINISHED (a block's step its block, whichever step
-        writes its K and V later; a token-at-a-time forward one token a
-        live row).  ``cache_bytes``: a prefill's cache by kind of state
+        writes its K and V later; a decode loop one token a live row and
+        step).  ``cache_bytes``: a prefill's cache by kind of state
         (``{"kv", "conv"}``, or a latent cache's ``{"latent", "index",
         "window"}``).  ``keys [rows, 2]``: of a model with a learned
         selection, the keys its queries selected and those visible to
         them, summed on the device over the full layers.
         ``rows_per_group``: of a prefill whose rows are mapped inside the
         program, how many of them one grouped matmul served.  ``drafted``
-        / ``accepted``: of a step of a model that drafts for itself, the
-        drafts it verified (one a live row) and those that were right;
-        its ``committed_tokens`` is then the true count, one or two a row."""
+        / ``accepted``: of the decode loop of a model that drafts for
+        itself, the drafts it verified (one a live row and step) and those
+        that were right; its ``committed_tokens`` is then the true count,
+        one or two a row and step.  A loop's ``load`` and ``keys`` are its
+        steps' stacked."""
         from ..observability import batchtrace
 
         step = self.step

@@ -349,6 +349,29 @@ def advance(positions, accepted, last: int):
     return jnp.minimum(positions + 1 + accepted, last)
 
 
+def _keep(outs, out, step):
+    """A loop's buffers ``outs`` (an entry a step, leaf for leaf) with
+    ``out`` written at ``step``."""
+    return jax.tree.map(
+        lambda buf, o: jax.lax.dynamic_update_index_in_dim(buf, o, step, 0),
+        outs, out)
+
+
+def _entries(report, top: int):
+    """``select_greedy``'s ``report [..., 2 + 2 top]`` read back -> ``entry(*
+    index)``, a trajectory entry's ``token``, ``lse``, ``top_ids`` and
+    ``top_logits`` at an index of the leading axes; the ids are converted
+    once for all of them."""
+    tokens = report[..., 0].astype(np.int32)
+    top_ids = report[..., 2:2 + top].astype(np.int32)
+
+    def entry(*at) -> Dict[str, Any]:
+        row = report[at]
+        return {"token": int(tokens[at]), "lse": row[1],
+                "top_ids": top_ids[at], "top_logits": row[2 + top:]}
+    return entry
+
+
 class Qwen3Cached:
     """The dense Qwen3 (with its LoRA adapters) behind the interface
     ``GreedyGenerator`` decodes through: a model that owns its cache.
@@ -420,23 +443,30 @@ class Qwen3Cached:
 
 class GreedyGenerator:
     """Bucketed greedy decoding, a token at a time, over a model that owns
-    its cache (``Qwen3Cached`` unless ``model`` is given): one jitted
-    prefill + one jitted step per (B, prompt_bucket, cache_len) shape; the
-    token is chosen on the device (``select_greedy``) and the host loop,
-    which reads one small report a forward, handles EOS.
+    its cache (``Qwen3Cached`` unless ``model`` is given): two jitted
+    programs per (B, prompt_bucket, cache_len) shape, the prefill and the
+    LOOP of a generation's decode steps (``_loop_fn``: a
+    ``jax.lax.while_loop`` whose body is one step).  The token is chosen on
+    the device (``select_greedy``), and so is when to stop: the loop carries
+    the cache, each row's count of committed tokens against its budget and
+    which rows have finished (budget reached or an end-of-sequence id
+    chosen), and writes each step's small report into buffers of one entry
+    a step.  The host turns twice a generation: after the prefill, and
+    after the loop, when it reads the stacked reports back once and writes
+    the tokens and the trajectories step by step from them.
 
     A model that DRAFTS for itself (``model.drafts``: its checkpoint has a
     multi-token-prediction module; ``models.joyai_llm_flash.CachedModel`` has
     the protocol) is stepped two positions at a time: a row's state is its
     last committed token ``x`` at position ``p``, not yet run, and a draft
-    ``d`` for ``p + 1``; ONE program a step runs the layers on ``[x, d]``
+    ``d`` for ``p + 1``; a step runs the layers on ``[x, d]``
     (``verify``), chooses ``y`` and ``z`` from both positions' logits,
     accepts iff ``y == d`` (then ``z`` is committed too), runs the drafter on
     what was chosen (``draft``) and advances the row by 1 or 2, all on the
     device.  The tokens served are those of the same model decoding a token
     at a time; drafting changes the number of steps and nothing else.  No
-    knob: a model without a drafter takes the token-at-a-time path as it
-    was."""
+    knob: ``model.drafts`` decides which of the two steps is the loop's
+    body."""
 
     def __init__(self, config, params,
                  tokenizer, lora: Optional[LoRAConfig] = None,
@@ -455,7 +485,7 @@ class GreedyGenerator:
         self.top_logits = top_logits
         self.drafts = bool(getattr(self.model, "drafts", False))
         self._prefill_cache: Dict[Tuple, Any] = {}
-        self._step_cache: Dict[Tuple, Any] = {}
+        self._loop_cache: Dict[Tuple, Any] = {}
 
     @property
     def module(self):
@@ -486,52 +516,103 @@ class GreedyGenerator:
                 drafting if self.drafts else fn)
         return self._prefill_cache[key]
 
-    def _verify_fn(self, key):
-        """A drafting model's step (the class's docstring): ``(cache,
-        (tokens, draft), positions)`` -> the same after one or two more
-        tokens a row, ``report = (chosen [B, 2, 2 + 2 top], accepted [B],
-        drafted [B, 2 + 2 top])`` — ``select_greedy``'s report of both
-        positions and of the drafter's logits behind the next draft — and
-        ``aux``.  A row stops advancing two columns short of the cache's
-        end; a live row never gets there (``generate`` sizes the cache)."""
-        if key not in self._step_cache:
-            M = key[2]
+    def step(self, cache_len: int):
+        """One decode step, the loop's body: ``(params, cache, state,
+        positions, task_index)`` -> ``(cache, state, positions, chosen [B,
+        Q], taken [B], out)`` — the tokens the step chose at its ``Q``
+        positions a row, how many of them count (the first ``taken``), and
+        ``out = (report, aux)``, what the host reads of the step.
 
-            def fn(params, cache, state, positions, task_index):
-                tokens, draft = state
-                B = tokens.shape[0]
-                cache, logits, hidden, aux = self.model.verify(
-                    params, cache, jnp.stack([tokens, draft], 1), positions,
-                    task_index)
-                chosen, report = select_greedy(
-                    logits.reshape(2 * B, -1), self.top_logits)
-                chosen = chosen.reshape(B, 2)
-                accepted = chosen[:, 0] == draft
-                cache, logits, aux = self.model.draft(
-                    params, cache, hidden, chosen, positions, accepted, aux)
-                draft, drafted = select_greedy(logits, self.top_logits)
-                tokens = jnp.where(accepted, chosen[:, 1], chosen[:, 0])
-                positions = advance(positions, accepted, M - 2)
-                return cache, (tokens, draft), positions, (
-                    report.reshape(B, 2, -1), accepted, drafted), aux
-            self._step_cache[key] = jax.jit(fn, donate_argnums=(1,))
-        return self._step_cache[key]
+        A model without a drafter: ``state`` the rows' last tokens, ``Q``
+        1, ``report [B, 2 + 2 top]`` (``select_greedy``'s).  A drafting
+        model (the class's docstring): ``state = (tokens, draft)``, ``Q``
+        2, ``taken`` 2 where the draft was accepted, ``report = (chosen [B,
+        2, 2 + 2 top], accepted [B], drafted [B, 2 + 2 top])`` —
+        ``select_greedy``'s report of both positions and of the drafter's
+        logits behind the next draft.  A row stops advancing two columns
+        short of the cache's end; a live row never gets there (``generate``
+        sizes the cache)."""
+        def one(params, cache, tokens, positions, task_index):
+            cache, logits, aux = self.model.decode(
+                params, cache, tokens, positions, task_index)
+            tokens, report = select_greedy(logits, self.top_logits)
+            return (cache, tokens, positions + 1, tokens[:, None],
+                    jnp.ones_like(tokens), (report, aux))
 
-    def _step_fn(self, key):
-        if key not in self._step_cache:
-            def fn(params, cache, tokens, positions, task_index):
-                cache, logits, aux = self.model.decode(
-                    params, cache, tokens, positions, task_index)
-                tokens, report = select_greedy(logits, self.top_logits)
-                return cache, tokens, positions + 1, report, aux
-            self._step_cache[key] = jax.jit(fn, donate_argnums=(1,))
-        return self._step_cache[key]
+        def two(params, cache, state, positions, task_index):
+            tokens, draft = state
+            B = tokens.shape[0]
+            cache, logits, hidden, aux = self.model.verify(
+                params, cache, jnp.stack([tokens, draft], 1), positions,
+                task_index)
+            chosen, report = select_greedy(
+                logits.reshape(2 * B, -1), self.top_logits)
+            chosen = chosen.reshape(B, 2)
+            accepted = chosen[:, 0] == draft
+            cache, logits, aux = self.model.draft(
+                params, cache, hidden, chosen, positions, accepted, aux)
+            draft, drafted = select_greedy(logits, self.top_logits)
+            tokens = jnp.where(accepted, chosen[:, 1], chosen[:, 0])
+            positions = advance(positions, accepted, cache_len - 2)
+            return (cache, (tokens, draft), positions, chosen,
+                    1 + accepted.astype(jnp.int32),
+                    ((report.reshape(B, 2, -1), accepted, drafted), aux))
+        return two if self.drafts else one
+
+    def _loop_fn(self, key):
+        """A generation's decode steps as one program of shape ``(rows,
+        positions a step, cache_len, steps at most)``: ``(params, cache,
+        state, positions, task_index, finished [B], eos [n], budget)`` ->
+        ``(cache, outs, steps run)``.  Steps run until every row is
+        ``finished`` — a padding row comes in so; a live row ends where the
+        tokens it has committed (one, the prefill's, when the loop begins)
+        reach ``budget`` or one of them is among ``eos``.  ``budget`` is an
+        operand: a warm-up's two tokens and a served generation's all run
+        the same compiled program.  ``outs`` is every step's ``out``
+        (``step``) stacked, an entry a step; what no step wrote stays
+        zero.  The donated cache is a carried value of the loop, written in
+        place by every step."""
+        if key not in self._loop_cache:
+            _, Q, M, T = key
+            step = self.step(M)
+
+            def fn(params, cache, state, positions, task_index, finished,
+                   eos, budget):
+                def ends(tokens, count):
+                    return (tokens[:, None] == eos[None, :]).any(-1) \
+                        | (count >= budget)
+
+                def more(carry):
+                    return (carry[0] < T) & ~carry[4].all()
+
+                def one(carry):
+                    t, cache, state, positions, finished, count, outs = carry
+                    cache, state, positions, chosen, taken, out = step(
+                        params, cache, state, positions, task_index)
+                    for q in range(Q):  # the host's commit, token by token
+                        live = ~finished & (q < taken)
+                        count = count + live
+                        finished = finished | live & ends(chosen[:, q], count)
+                    return (t + 1, cache, state, positions, finished, count,
+                            _keep(outs, out, t))
+
+                out = jax.eval_shape(step, params, cache, state, positions,
+                                     task_index)[-1]
+                outs = jax.tree.map(
+                    lambda o: jnp.zeros((T,) + o.shape, o.dtype), out)
+                t, cache, _, _, _, _, outs = jax.lax.while_loop(
+                    more, one, (jnp.int32(0), cache, state, positions,
+                                finished, jnp.ones_like(positions), outs))
+                return cache, outs, t
+            self._loop_cache[key] = jax.jit(fn, donate_argnums=(1,))
+        return self._loop_cache[key]
 
     batched = True  # generate() takes the engine's batch (encodings=...)
 
     def warm(self, rows: int, bucket: int) -> None:
         """Compile and run the two programs of ``(rows, bucket)`` at the
-        cache length of ``gen_length`` tokens: two tokens of them."""
+        cache length of ``gen_length`` tokens: two tokens of them (how many
+        steps the loop runs is data, not shape)."""
         self.generate([], self.gen_length,
                       encodings=[_one_token(self.pad_id)], bucket=bucket,
                       padded_rows=rows, _steps=2)
@@ -542,24 +623,29 @@ class GreedyGenerator:
                  padded_rows: Optional[int] = None, observer=None,
                  _steps: Optional[int] = None) -> List[GenerationResult]:
         """``prompts`` as one batch in lock step: one prefill
-        (``gen.prefill``), then one token a row a step (``gen.decode``)
-        until every row hit an end-of-sequence token or
-        ``max_new_tokens``.  The engine's batch runner passes the batch it
-        composed: ``encodings`` (the prompts, tokenized by the callers),
-        the prompt ``bucket`` they are padded to, ``padded_rows`` (a
-        padding row has length 0 and counts as finished) and an
-        ``observer`` of the forwards.  Without them the prompts are
+        (``gen.prefill``), then the loop of decode steps (``gen.decode``:
+        one program, closed with the ``forwards`` it ran) until every row
+        hit an end-of-sequence token or ``max_new_tokens``; none where the
+        prefill's token ends every row.  The engine's batch runner passes
+        the batch it composed: ``encodings`` (the prompts, tokenized by the
+        callers), the prompt ``bucket`` they are padded to, ``padded_rows``
+        (a padding row has length 0 and counts as finished) and an
+        ``observer`` of the two programs.  Without them the prompts are
         tokenized here and padded to their own longest.
 
-        A result's ``trajectory`` has one entry per forward that chose a
-        token for the request: ``kind`` (``prefill`` | ``decode``),
+        A result's ``trajectory`` has one entry per token chosen for the
+        request: ``kind`` (``prefill`` | ``decode``),
         ``position`` (of the token whose logits chose), ``token`` (the
         choice), ``lse``, ``top_ids`` / ``top_logits [top]`` (float32) and,
         of an expert model, ``experts [layers, n, k]`` (the router's choice
         at the prompt's ``n`` tokens, or at the one decoded); of a model
         with a learned selection of keys, ``selected`` (bits over the key
         positions, a row a full layer: of a decode at the token decoded, of
-        a prefill at the prompt positions ``selected_at``)."""
+        a prefill at the prompt positions ``selected_at``); of a model that
+        drafts, on the entry of a step's first token ``drafted`` (the draft
+        the step verified), ``accepted`` and ``draft`` (the drafter's own
+        ``token`` .. ``top_logits`` and ``position`` behind the NEXT draft;
+        on the prefill's entry the first one's)."""
         encs, bucket, padded_rows = _as_batch(
             self.tokenizer, prompts, encodings, bucket, padded_rows)
         obs = observer or NullObserver()
@@ -567,27 +653,12 @@ class GreedyGenerator:
         lengths = np.zeros(B, np.int32)
         lengths[:n] = [len(e) for e in encs]
         M = _round_up(S + max_new_tokens + 1, 64)
-        max_new_tokens = _steps or max_new_tokens
-        k = self.top_logits
-
-        def choices(kind: str, at, report, aux) -> None:
-            """Every live row's entry of one forward."""
-            experts, selected = aux.get("experts"), aux.get("selected")
-            for i in range(n):
-                if finished[i]:
-                    continue
-                entry = {"kind": kind, "position": int(at[i]),
-                         "token": int(report[i, 0]), "lse": report[i, 1],
-                         "top_ids": report[i, 2:2 + k].astype(np.int32),
-                         "top_logits": report[i, 2 + k:]}
-                if experts is not None:
-                    entry["experts"] = experts[:, i, :lengths[i]] \
-                        if kind == "prefill" else experts[:, i, None]
-                if selected is not None:
-                    entry["selected"] = selected[:, i]
-                    if kind == "prefill":
-                        entry["selected_at"] = aux["selected_at"][i]
-                trajectory[i].append(entry)
+        Q = 1 + self.drafts
+        key = (B, Q, M, max(max_new_tokens - 1, 1))
+        budget = _steps or max_new_tokens
+        eos = np.asarray(sorted(self.eos_token_ids), np.int32)
+        if _steps:  # a warm-up takes its steps whatever it chooses
+            eos = np.full_like(eos, -1)
 
         fwd = obs.forward("gen.prefill", tokens_real=int(lengths.sum()),
                           tokens_padded=B * S)
@@ -599,9 +670,9 @@ class GreedyGenerator:
             task_arr = jnp.asarray(task_index)
             args = (jnp.asarray(ids), jnp.asarray(lengths), task_arr)
         with fwd.stage("dispatch"):
-            cache, tokens_dev, report, aux = self._prefill_fn((B, S, M))(
+            cache, state, report, aux = self._prefill_fn((B, S, M))(
                 self.params, *args)
-            positions_dev = args[1]
+            positions = args[1]
         with fwd.stage("readback"):
             report, aux = jax.device_get((report, aux))
         out_tokens: List[List[int]] = [[] for _ in range(B)]
@@ -611,111 +682,113 @@ class GreedyGenerator:
         drafted = None
         if self.drafts:
             report, drafted = report
+
+        def commit(i: int, token: int) -> None:
+            out_tokens[i].append(token)
+            finished[i] = token in eos or len(out_tokens[i]) >= budget
+
         with fwd.stage("demux"):
-            choices("prefill", lengths - 1, report, aux)
+            experts, selected = aux.get("experts"), aux.get("selected")
+            chosen = _entries(report, self.top_logits)
+            draft = self.drafts and _entries(drafted, self.top_logits)
+            for i in range(n):
+                e = {"kind": "prefill", "position": int(lengths[i]) - 1,
+                     **chosen(i)}
+                if experts is not None:
+                    e["experts"] = experts[:, i, :lengths[i]]
+                if selected is not None:
+                    e["selected"] = selected[:, i]
+                    e["selected_at"] = aux["selected_at"][i]
+                if self.drafts:
+                    e["draft"] = dict(draft(i), position=int(lengths[i]) - 1)
+                trajectory[i].append(e)
+                commit(i, e["token"])
         fwd.done(load=aux.get("load"), committed_tokens=n,
                  cache_bytes=self.model.cache_bytes(cache),
                  keys=aux.get("keys"),
                  rows_per_group=self.model.rows_per_group(
                      self.params, B, S, M))
+        if not finished.all():
+            self._decode(obs, key, (cache, state, positions, task_arr),
+                         drafted, lengths, (eos, budget), commit, trajectory,
+                         finished)
+        return [_finish_tokens(self.tokenizer, out_tokens[i],
+                               self.eos_token_ids, stop_strings,
+                               int(lengths[i]), trajectory[i])
+                for i in range(n)]
 
-        def results() -> List[GenerationResult]:
-            return [_finish_tokens(self.tokenizer, out_tokens[i],
-                                   self.eos_token_ids, stop_strings,
-                                   int(lengths[i]), trajectory[i])
-                    for i in range(n)]
-
+    def _decode(self, obs, key, device, drafted, lengths, ends, commit,
+                trajectory, finished) -> None:
+        """The decode steps after the prefill (whose first draft's report
+        is ``drafted``, of a drafting model): the loop (``_loop_fn``) as
+        one ``gen.decode`` step, then its stacked reports replayed step by
+        step as the host read them when it turned once a step — a step's
+        live rows are those not yet ``finished``, a live row gets an entry
+        a token that counts (``commit`` says when it has finished, by
+        ``ends = (eos ids, budget)``: ``generate``'s, which the loop's
+        carried values mirror) and stands one position on, or two where
+        its draft was accepted."""
+        live = int((~finished).sum())
+        fwd = obs.forward("gen.decode", tokens_real=key[1] * live)
+        with fwd.stage("h2d"):
+            eos, budget = ends
+            # (a 0-d array: a scalar would be converted by a program)
+            operands = (jnp.asarray(finished), jnp.asarray(eos),
+                        jnp.asarray(np.asarray(budget, np.int32)))
+        with fwd.stage("dispatch"):  # the cache it gives back: nobody's
+            _, outs, ran = self._loop_fn(key)(self.params, *device,
+                                              *operands)
+        with fwd.stage("readback"):
+            (report, aux), ran = jax.device_get((outs, ran))
+            ran = int(ran)
+        experts, selected = aux.get("experts"), aux.get("selected")
         if self.drafts:
-            self._verify_steps(
-                obs, (cache, tokens_dev, positions_dev, task_arr),
-                (B, 2, M), report, drafted, lengths, max_new_tokens,
-                out_tokens, trajectory, finished)
-            return results()
-        step = self._step_fn((B, 1, M))
-        for t in range(max_new_tokens):
-            next_tok = report[:, 0].astype(np.int32)
-            for i in range(n):
-                if not finished[i]:
-                    out_tokens[i].append(int(next_tok[i]))
-                    if int(next_tok[i]) in self.eos_token_ids:
-                        finished[i] = True
-            if finished.all() or t == max_new_tokens - 1:
-                break
-            live = int((~finished).sum())
-            fwd = obs.forward("gen.decode", tokens_real=live, block=t)
-            with fwd.stage("dispatch"):
-                cache, tokens_dev, positions_dev, report, aux = step(
-                    self.params, cache, tokens_dev, positions_dev, task_arr)
-            with fwd.stage("readback"):
-                report, aux = jax.device_get((report, aux))
-            with fwd.stage("demux"):
-                choices("decode", lengths + t, report, aux)
-            fwd.done(load=aux.get("load"), committed_tokens=live,
-                     keys=aux.get("keys"))
-        del cache
-        return results()
-
-    def _verify_steps(self, obs, device, key, report, drafted, lengths,
-                      budget: int, out_tokens, trajectory, finished) -> None:
-        """A drafting model's steps after the prefill whose ``report`` and
-        first draft's ``drafted`` are given: one ``gen.decode`` step a turn
-        (``_verify_fn``) until every row has its ``budget`` of tokens or an
-        end-of-sequence token, filling ``out_tokens``, ``trajectory`` and
-        ``finished`` in place.  The host only mirrors what the device
-        decided: a row's position, and which of a pair's tokens count."""
-        cache, state, positions, task = device
-        k, n = self.top_logits, len(trajectory)
-
-        def entry(row) -> Dict[str, Any]:
-            return {"token": int(row[0]), "lse": row[1],
-                    "top_ids": row[2:2 + k].astype(np.int32),
-                    "top_logits": row[2 + k:]}
-
-        def commit(i: int, token: int) -> None:
-            out_tokens[i].append(token)
-            finished[i] = token in self.eos_token_ids \
-                or len(out_tokens[i]) >= budget
-
+            report, accepted, after = report
+            draft = _entries(after, self.top_logits)
+            drafted = np.concatenate([drafted[None], after])[..., 0].astype(
+                np.int32)  # [1 + steps, B]: the draft a step verified
+        else:  # one position a row, never a second
+            report = report[:, :, None]
+            accepted = np.zeros(report.shape[:2], bool)
+            if experts is not None:
+                experts = experts[:, :, :, None]
+        chosen = _entries(report, self.top_logits)
         at = lengths.astype(np.int64)  # the committed token not yet run
-        for i in range(n):
-            trajectory[i][-1]["draft"] = dict(entry(drafted[i]),
-                                              position=int(at[i]) - 1)
-            commit(i, int(report[i, 0]))
-        verify = self._verify_fn(key)
-        t = 0
-        while not finished.all():
-            live = np.flatnonzero(~finished)
-            fwd = obs.forward("gen.decode", tokens_real=2 * len(live),
-                              block=t)
-            with fwd.stage("dispatch"):
-                cache, state, positions, report, aux = verify(
-                    self.params, cache, state, positions, task)
-            with fwd.stage("readback"):
-                (report, accepted, after), aux = jax.device_get(
-                    (report, aux))
-            committed = 0
-            with fwd.stage("demux"):
-                for i in live:
-                    for slot in range(1 + int(accepted[i])):
-                        e = dict(entry(report[i, slot]), kind="decode",
-                                 position=int(at[i]) + slot,
-                                 experts=aux["experts"][:, i, slot, None])
-                        if slot == 0:
-                            e.update(
-                                drafted=int(drafted[i, 0]),
-                                accepted=bool(accepted[i]),
-                                draft=dict(entry(after[i]), position=int(
-                                    at[i]) + int(accepted[i])))
+        committed = n_drafted = n_accepted = 0
+        with fwd.stage("demux"):
+            for t in range(ran):
+                rows = np.flatnonzero(~finished)
+                n_drafted += len(rows)
+                n_accepted += int(accepted[t, rows].sum())
+                for i in rows:
+                    took = int(accepted[t, i])
+                    for slot in range(1 + took):
+                        e = {"kind": "decode", "position": int(at[i]) + slot,
+                             **chosen(t, i, slot)}
+                        if experts is not None:
+                            e["experts"] = experts[t][:, i, slot, None]
+                        if selected is not None:
+                            e["selected"] = selected[t][:, i]
+                        if self.drafts and slot == 0:
+                            e.update(drafted=int(drafted[t, i]),
+                                     accepted=bool(took),
+                                     draft=dict(draft(t, i),
+                                                position=int(at[i]) + took))
                         trajectory[i].append(e)
                         commit(i, e["token"])
                         committed += 1
                         if finished[i]:
                             break
-                    at[i] += 1 + int(accepted[i])
-                drafted = after
-            fwd.done(load=aux["load"], committed_tokens=committed,
-                     drafted=len(live), accepted=int(accepted[live].sum()))
-            t += 1
+                    at[i] += 1 + took
+
+        def stacked(name: str):
+            a = aux.get(name)
+            return None if a is None else a[:ran].reshape(-1, a.shape[-1])
+
+        drafts = dict(drafted=n_drafted, accepted=n_accepted) \
+            if self.drafts else {}
+        fwd.done(load=stacked("load"), forwards=ran,
+                 committed_tokens=committed, keys=stacked("keys"), **drafts)
 
 
 # ---------------------------------------------------------------------------
@@ -846,11 +919,6 @@ class BlockDiffusionGenerator:
                         jnp.asarray(schedule)[step], self.top_logits)
                 return caches, tokens, masked, (report, experts, load)
 
-            def keep(outs, out, step):
-                return jax.tree.map(
-                    lambda buf, o: jax.lax.dynamic_update_index_in_dim(
-                        buf, o, step, 0), outs, out)
-
             def denoise(params, caches, tokens, masked, start, rows_valid,
                         step, outs):
                 # the cache is an operand of the loop, not a carried value:
@@ -864,7 +932,7 @@ class BlockDiffusionGenerator:
                     _, tokens, masked, out = forward(
                         params, caches, None, tokens, masked, start,
                         rows_valid, step)
-                    return step + 1, tokens, masked, keep(outs, out, step)
+                    return step + 1, tokens, masked, _keep(outs, out, step)
 
                 step, tokens, _, outs = jax.lax.while_loop(
                     more, one, (jnp.int32(step), tokens, masked, outs))
@@ -878,7 +946,7 @@ class BlockDiffusionGenerator:
                 caches, tokens, masked, (report, experts, load) = forward(
                     params, caches, previous, tokens, masked, start,
                     rows_valid, 0)
-                outs = keep(self.buffers(rows, jnp),
+                outs = _keep(self.buffers(rows, jnp),
                             (report, experts[:, :, L:], load), 0)
                 return (caches, tokens, masked, start, outs,
                         experts[:, :, :L])

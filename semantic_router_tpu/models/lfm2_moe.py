@@ -17,7 +17,11 @@ RMSNorm (``embedding_norm``), a head tied to the embedding.
   (``norm_topk_prob``), ``* routed_scaling_factor``.
 
 The cache a row carries from token to token is a pytree with two kinds of
-state side by side: per attention layer K and V ``[rows, kv, M, D]``, per
+state side by side: per attention layer K and V ``[rows, kv, D, M]`` (the
+columns last: a head's 64 numbers are half a lane tile, and the layout the
+chip gives ``[.., M, 64]`` where it may choose is this one; written down,
+a loop that carries the cache keeps it, where a free choice inside the loop
+padded every column to 128 and copied the cache in and out), per
 conv layer the last ``conv_L_cache - 1`` vectors ``z`` ``[rows, L-1, H]``,
 whatever the context; and ``lengths [rows]`` (0 = a padding row, which
 routes nowhere).  A right-padded row's conv state is taken at its true
@@ -355,8 +359,9 @@ def _prefill_rows(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int):
                         causal=True)
                     out = jnp.moveaxis(out, 1, 2).reshape(B, S, -1)
                     x = x + out.astype(cfg.dtype) @ p["o_proj"]
-                    pad = ((0, 0), (0, 0), (0, cache_len - S), (0, 0))
-                    kv.append((jnp.pad(kc, pad), jnp.pad(vc, pad)))
+                    pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - S))
+                    kv.append(tuple(jnp.pad(jnp.swapaxes(t, 2, 3), pad)
+                                    for t in (kc, vc)))
             x, top_e, load = _feed_forward_as_one_row(cfg, i, p, x, valid)
             if top_e is not None:
                 experts.append(_expert_ids(cfg, top_e))
@@ -524,7 +529,7 @@ def decode(cfg: Lfm2MoeConfig, params, cache, tokens, positions):
     live = cache["lengths"] > 0
     pos = positions[:, None]
     put = jax.vmap(lambda c, new, at: jax.lax.dynamic_update_slice(
-        c, new, (0, at, 0)))
+        c, new, (0, 0, at)))  # a row's column of [kv, D, M]
     with jax.named_scope("embed_tokens"):
         x = jnp.take(params["embed"], tokens, axis=0)[:, None]  # [B, 1, H]
     kv, conv, experts, loads = [], [], [], []
@@ -545,19 +550,19 @@ def decode(cfg: Lfm2MoeConfig, params, cache, tokens, positions):
             else:
                 with jax.named_scope("attn"):
                     k_cache, v_cache = next(kv_in)
-                    M = k_cache.shape[2]
-                    q, k, v = qkv(cfg, p, h, pos, M)
-                    k_cache = put(k_cache, jnp.moveaxis(k, 2, 1), positions)
-                    v_cache = put(v_cache, jnp.moveaxis(v, 2, 1), positions)
+                    M = k_cache.shape[3]
+                    q, k, v = qkv(cfg, p, h, pos, M)  # k, v [B, 1, kv, D]
+                    k_cache = put(k_cache, jnp.moveaxis(k, 1, 3), positions)
+                    v_cache = put(v_cache, jnp.moveaxis(v, 1, 3), positions)
                     kv.append((k_cache, v_cache))
                     qg = q.reshape(B, nkv, rep, D)
-                    s = jnp.einsum("bgrd,bgmd->bgrm", qg, k_cache,
+                    s = jnp.einsum("bgrd,bgdm->bgrm", qg, k_cache,
                                    preferred_element_type=jnp.float32) \
                         * (1.0 / np.sqrt(float(D)))
                     seen = jnp.arange(M)[None, :] <= pos  # [B, M]
                     s = s + jnp.where(seen, 0.0, NEG_INF)[:, None, None, :]
                     out = jnp.einsum(
-                        "bgrm,bgmd->bgrd",
+                        "bgrm,bgdm->bgrd",
                         jax.nn.softmax(s, axis=-1).astype(cfg.dtype),
                         v_cache, preferred_element_type=jnp.float32)
                     x = x + (out.reshape(B, nh * D).astype(cfg.dtype)

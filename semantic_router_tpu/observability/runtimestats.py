@@ -186,8 +186,9 @@ class RuntimeStats:
         self.gen_programs = registry.counter(
             "llm_runtime_gen_programs_total",
             "Stretches of device work of generative tasks that the host "
-            "dispatched and then read back (a forward; a block generator's "
-            "block), by flavour: one per turn of the host; forwards over "
+            "dispatched and then read back (a prefill; a generation's loop "
+            "of decode steps; a block generator's block), by flavour: one "
+            "per turn of the host; forwards over "
             "programs is how many forwards ran without it")
         self.gen_blocks = registry.counter(
             "llm_runtime_gen_blocks_committed_total",
@@ -277,13 +278,12 @@ class RuntimeStats:
                           committed_tokens: int = 0,
                           cache_bytes=None, rows_per_group=None,
                           drafted: int = 0, accepted: int = 0) -> None:
-        """One step of a generation (the engine's generative runner; a
-        forward, or a block generator's block): one
-        llm_runtime_gen_programs_total and ``forwards``
-        llm_runtime_gen_forwards_total — the first of ``flavour``, a
-        block's later ones ``gen.denoise`` (only a ``gen.commit`` step's
-        first forward carries the block before) — and what the step
-        FINISHED in llm_runtime_gen_blocks_committed_total and
+        """One step of a generation (the engine's generative runner: a
+        prefill, a token-at-a-time generator's loop of decode steps, or a
+        block generator's block): one llm_runtime_gen_programs_total and
+        ``forwards`` llm_runtime_gen_forwards_total of ``flavour`` — but a
+        ``gen.commit`` step's later ones ``gen.denoise`` (only its first
+        forward carries the block before) — and what the step FINISHED in llm_runtime_gen_blocks_committed_total and
         llm_runtime_gen_tokens_committed_total (a generation's last block
         among them; a generation of B blocks has B - 1 ``gen.commit``
         steps: count blocks here, not there).  Everything else
@@ -302,8 +302,9 @@ class RuntimeStats:
         self.gen_programs.inc(task=task, flavour=flavour)
         self.gen_forwards.inc(task=task, flavour=flavour)
         if forwards > 1:
-            self.gen_forwards.inc(forwards - 1, task=task,
-                                  flavour="gen.denoise")
+            self.gen_forwards.inc(
+                forwards - 1, task=task, flavour="gen.denoise"
+                if flavour == "gen.commit" else flavour)
         for kind, size in (cache_bytes or {}).items():
             self.gen_cache_bytes.set(size, task=task, kind=kind)
         if rows_per_group is not None:

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import per_step_loop
 from chipbench import cells
 from test_lfm2_moe import ROW_GROUPS, touched_by_group
 from semantic_router_tpu.models import dots3_note as M
@@ -499,32 +500,23 @@ def test_the_loop_serves_the_decoder_with_its_trajectory(toy):
         assert traj[0]["selected_at"].shape == (M.SELECT_SAMPLE,)
 
 
-def test_a_forward_reports_its_keys_to_the_observer(toy):
-    """``keys [rows, 2]`` reaches ``done`` with every forward; the marker
-    sums them (``batchtrace.gen_forward``)."""
+def test_a_program_reports_its_keys_to_the_observer(toy):
+    """``keys [rows, 2]`` reaches ``done`` with the prefill, and with the
+    loop its steps' stacked (``[steps x rows, 2]``); the marker sums them
+    (``batchtrace.gen_forward``)."""
     from semantic_router_tpu.observability import batchtrace
 
-    seen = []
-
-    class Forward:
-        def stage(self, name):
-            import contextlib
-            return contextlib.nullcontext()
-
-        def done(self, **after):
-            seen.append(after)
-
-    class Observer:
-        def forward(self, flavour, **facts):
-            return Forward()
-
+    seen = per_step_loop.Steps()
     (row,) = prompts(2, (20,))
-    generator(toy).generate([words(row)], 4, observer=Observer())
-    assert len(seen) == 4
-    assert seen[0]["keys"].shape == (1, 2)
-    assert seen[0]["keys"][0, 1] == 3 * 20 * 21 // 2
-    assert set(seen[0]["cache_bytes"]) == {"latent", "index", "window"}
-    assert seen[1]["keys"][0, 1] == 3 * 21
+    generator(toy).generate([words(row)], 4, observer=seen)
+    prefill, loop = seen.closed
+    assert prefill["keys"].shape == (1, 2)
+    assert prefill["keys"][0, 1] == 3 * 20 * 21 // 2
+    assert set(prefill["cache_bytes"]) == {"latent", "index", "window"}
+    # three steps of one row: 21, 22 and 23 keys visible in 3 full layers
+    assert loop["forwards"] == 3 and loop["keys"].shape == (3, 2)
+    assert list(loop["keys"][:, 1]) == [3 * 21, 3 * 22, 3 * 23]
+    assert loop["load"].shape == (3 * 4, 4)
     facts = {}
 
     def span(name, **kw):
@@ -534,14 +526,40 @@ def test_a_forward_reports_its_keys_to_the_observer(toy):
 
     import unittest.mock as mock
     with mock.patch.object(batchtrace, "trace_span", span):
-        batchtrace.gen_forward("gen:t", "gen.decode", seen[1]["load"],
-                               seen[1]["keys"])
-    assert facts["keys_visible"] == 63 and facts["keys_selected"] >= 24
+        batchtrace.gen_forward("gen:t", "gen.decode", loop["load"],
+                               loop["keys"], forwards=loop["forwards"])
+    assert facts["keys_visible"] == 63 + 66 + 69
+    assert facts["keys_selected"] >= 3 * 24
+    assert facts["forwards"] == 3 and facts["layers"] == 12
     assert facts["name"] == batchtrace.GEN_FORWARD_ANNOTATION
     with mock.patch.object(batchtrace, "trace_span", span):
         facts.clear()
-        batchtrace.gen_forward("gen:t", "gen.decode", seen[1]["load"])
+        batchtrace.gen_forward("gen:t", "gen.decode", loop["load"][:4])
     assert "keys_visible" not in facts and facts["layers"] == 4
+
+
+@pytest.fixture(scope="module")
+def looped(toy):
+    return generator(toy)
+
+
+@pytest.mark.parametrize("case", per_step_loop.CASES)
+def test_the_loop_gives_what_the_hosts_loop_gave(looped, case):
+    """The decode loop on the device against a program a step
+    (``tests/per_step_loop.py``) over the latent cache: latents, index
+    keys and the sliding layers' rings carried through the loop, and a
+    step's ``selected`` bits in the loop's buffers."""
+    texts = [words(r) for r in prompts(33, (30, 12, 21))]
+    seen = per_step_loop.check_case(case, looped, texts, 7)
+    for res in seen["out"]:
+        for e in res.trajectory[1:]:
+            assert e["experts"].shape == (4, 1, 2)
+            assert e["selected"].shape[0] == 3 and "selected_at" not in e
+    if seen["done"] is not None:
+        steps = len(seen["steps"])
+        assert seen["done"]["load"].shape == (4 * steps, 4)
+        rows = 3 + (case == "a_padding_row")
+        assert seen["done"]["keys"].shape == (rows * steps, 2)
 
 
 @pytest.fixture

@@ -12,6 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import per_step_loop
 from semantic_router_tpu.models.generate import (
     GreedyGenerator,
     GuardVerdict,
@@ -329,20 +330,56 @@ class TestOneTokenAtATimeLoop:
         assert padded.token_ids == alone.token_ids
         assert padded.prompt_tokens == 6
 
-    def test_a_step_reads_back_a_small_report_not_the_vocabulary(
+    def test_the_loop_reads_back_small_reports_not_the_vocabulary(
             self, tiny_params):
         cfg, _, params = tiny_params
         row = np.random.default_rng(10).integers(3, 256, 5)
         gen = GreedyGenerator(cfg, params, RowTokenizer([row]))
         gen.generate(["x"], max_new_tokens=3)
-        (prefill,), (step,) = (gen._prefill_cache.values(),
-                               gen._step_cache.values())
+        (prefill,), (key,) = gen._prefill_cache.values(), gen._loop_cache
+        assert key == (1, 1, 64, 2)  # rows, positions a step, cache, steps
         args = (params, jnp.zeros((1, 32), jnp.int32),
                 jnp.ones(1, jnp.int32), jnp.asarray(0))
         cache, tokens, report, aux = jax.eval_shape(prefill, *args)
         assert report.shape == (1, 2 + 2 * 8) and tokens.shape == (1,)
         assert aux == {}
-        out = jax.eval_shape(step, params, cache, tokens, tokens,
-                             jnp.asarray(0))
-        assert max(int(np.prod(a.shape)) for a in
-                   jax.tree_util.tree_leaves(out[1:])) < cfg.vocab_size
+        _, (reports, aux), ran = jax.eval_shape(
+            gen._loop_cache[key], params, cache, tokens, tokens,
+            jnp.asarray(0), jnp.zeros(1, bool), jnp.zeros(0, jnp.int32),
+            jnp.asarray(3))
+        # an entry a step, and nothing else comes back
+        assert reports.shape == (2, 1, 2 + 2 * 8) and aux == {}
+        assert ran.shape == ()
+
+    @pytest.fixture(scope="class")
+    def adapters(self, tiny_params):
+        """The dense guard with two LoRA rows, the second one perturbed."""
+        from semantic_router_tpu.models.generate import with_lora_leaves
+
+        cfg, _, base = tiny_params
+        lora = LoRAConfig(rank=2, alpha=4.0, num_tasks=2)
+        params = with_lora_leaves(cfg, lora, base)
+        rng = np.random.default_rng(5)
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, a: a.at[1].set(
+                0.5 * rng.normal(size=a.shape[1:]).astype(a.dtype))
+            if path[-1].key == "lora_B" else a, params)
+        rows = [np.random.default_rng(11).integers(3, 256, n)
+                for n in (9, 5, 13)]
+        return GreedyGenerator(cfg, params, RowTokenizer(rows), lora=lora,
+                               top_logits=4)
+
+    @pytest.mark.parametrize("task_index", [0, 1])
+    @pytest.mark.parametrize("case", per_step_loop.CASES)
+    def test_the_loop_gives_what_the_hosts_loop_gave(self, adapters, case,
+                                                     task_index):
+        """The decode loop on the device against a program a step
+        (``tests/per_step_loop.py``), under either adapter row."""
+        adapters.tokenizer.i = 0
+        seen = per_step_loop.check_case(case, adapters, ["a", "b", "c"], 7,
+                                        task_index=task_index)
+        assert all("experts" not in e and "selected" not in e
+                   for r in seen["out"] for e in r.trajectory)
+        if seen["done"] is not None:  # a dense model routes nothing
+            assert seen["done"]["load"] is None
+            assert "drafted" not in seen["done"]

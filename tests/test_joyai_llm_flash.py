@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import per_step_loop
 from chipbench import cells
 from test_dots3_note import WordTokenizer, prompts, words
 from semantic_router_tpu.models import generate as G
@@ -306,23 +307,6 @@ class Oracle(M.CachedModel):
                                  positions + 1 + accepted), aux
 
 
-class Steps:
-    """An observer that keeps what every forward was told and told back."""
-
-    def __init__(self) -> None:
-        self.opened, self.closed = [], []
-
-    def forward(self, flavour, **facts):
-        self.opened.append(dict(facts, flavour=flavour))
-        return self
-
-    def stage(self, name):
-        return contextlib.nullcontext()
-
-    def done(self, **after):
-        self.closed.append(after)
-
-
 ROWS = (30, 12, 21, 5)
 NEW = 9
 
@@ -330,17 +314,24 @@ NEW = 9
 GENERATORS = {}  # a drafter's compiled programs serve every test of it
 
 
-def serve(toy, model=None, new=NEW, eos=()):
+def generator(toy, model=None) -> GreedyGenerator:
     _, _, cfg, params = toy
     key = (cfg, None if model is None else tuple(np.asarray(model.sign)))
     if key not in GENERATORS:
         GENERATORS[key] = GreedyGenerator(
             cfg, params, WordTokenizer(), model=model or M.CachedModel(cfg),
             gen_length=NEW, top_logits=4)
-    gen, steps = GENERATORS[key], Steps()
+    return GENERATORS[key]
+
+
+def texts():
+    return [words(r) for r in prompts(8, ROWS)]
+
+
+def serve(toy, model=None, new=NEW, eos=()):
+    gen, steps = generator(toy, model), per_step_loop.Steps()
     gen.eos_token_ids = set(eos)
-    out = gen.generate([words(r) for r in prompts(8, ROWS)], new,
-                       observer=steps)
+    out = gen.generate(texts(), new, observer=steps)
     return out, steps
 
 
@@ -352,7 +343,8 @@ def one_at_a_time(toy):
     plain = dataclasses.replace(cfg, num_nextn_predict_layers=0)
     assert not M.CachedModel(plain).drafts
     out, steps = serve((hf, state, plain, params))
-    assert len(steps.opened) == NEW and "drafted" not in steps.closed[1]
+    (loop,) = steps.decodes()  # the prefill's token, then a token a step
+    assert loop["forwards"] == NEW - 1 and "drafted" not in loop
     return out
 
 
@@ -377,32 +369,80 @@ def test_drafting_serves_the_tokens_of_one_at_a_time(toy, one_at_a_time,
         for e, b in zip(res.trajectory, base.trajectory):
             np.testing.assert_allclose(e["top_logits"], b["top_logits"],
                                        atol=ATOL)
-    decodes = [c for o, c in zip(steps.opened, steps.closed)
-               if o["flavour"] == "gen.decode"]
+    (loop,) = steps.decodes()  # one program, and the steps it ran
     accepted = [[e["accepted"] for e in res.trajectory if "accepted" in e]
                 for res in out]
     # a row takes NEW - 1 tokens after the prefill's, one or two a step
     want_steps = {"never_agrees": NEW - 1, "always_agrees": NEW // 2}
     if drafter in want_steps:
-        assert len(decodes) == want_steps[drafter]
+        assert loop["forwards"] == want_steps[drafter]
         assert all(all(a) == (drafter == "always_agrees") and
                    any(a) == (drafter == "always_agrees") for a in accepted)
     elif drafter == "rows_differ":
-        assert len(decodes) == NEW - 1  # the slowest row's
+        assert loop["forwards"] == NEW - 1  # the slowest row's
         assert [len(a) for a in accepted] == [NEW // 2, NEW - 1] * 2
     else:
         rate = np.mean([a for row in accepted for a in row])
         assert 0.2 < rate < 0.9, rate
-        assert NEW // 2 < len(decodes) <= NEW - 1
-    # what the observer learns: one draft a live row, the true count
-    assert sum(c["committed_tokens"] for c in decodes) \
-        == (NEW - 1) * len(ROWS)
-    assert sum(c["drafted"] for c in decodes) \
-        == sum(len(a) for a in accepted)
-    assert sum(c["accepted"] for c in decodes) \
-        == sum(sum(a) for a in accepted)
-    assert all(o["tokens_real"] == 2 * c["drafted"] for o, c in zip(
-        steps.opened[1:], decodes))
+        assert NEW // 2 < loop["forwards"] <= NEW - 1
+    # what the observer learns: one draft a live row and step, the true count
+    assert loop["committed_tokens"] == (NEW - 1) * len(ROWS)
+    assert loop["drafted"] == sum(len(a) for a in accepted)
+    assert loop["accepted"] == sum(sum(a) for a in accepted)
+    assert loop["load"].shape == (3 * loop["forwards"], 4)
+    assert steps.opened[1]["tokens_real"] == 2 * len(ROWS)
+
+
+def drafting(toy, drafter):
+    sign = DRAFTERS[drafter]
+    return generator(toy, None if sign is None else Oracle(toy[2], sign))
+
+
+@pytest.mark.parametrize("drafter, case", [
+    (d, c) for d in sorted(DRAFTERS) for c in per_step_loop.CASES
+    if d == "its_own_module" or c != "a_padding_row"])
+def test_the_loop_gives_what_the_hosts_loop_gave(toy, drafter, case):
+    """The decode loop on the device against a program a step
+    (``tests/per_step_loop.py``), two positions a row: both latent caches
+    carried through the loop, a row's count of committed tokens and
+    ``finished`` on the device as the host mirrored them."""
+    seen = per_step_loop.check_case(case, drafting(toy, drafter), texts(),
+                                    NEW, atol=ATOL)
+    if seen["done"] is not None:
+        steps = seen["steps"]
+        assert seen["done"]["drafted"] >= len(steps)
+        assert seen["done"]["load"].shape == (3 * len(steps), 4)
+        if drafter == "always_agrees" and case == "whole_budget":
+            assert len(steps) == NEW // 2
+        if drafter == "never_agrees" and case == "whole_budget":
+            assert len(steps) == NEW - 1 and seen["done"]["accepted"] == 0
+
+
+@pytest.mark.parametrize("ends", ["budget_on_a_steps_first_token",
+                                  "eos_a_steps_first_token",
+                                  "eos_a_steps_second_token"])
+def test_a_row_ends_inside_a_pair_on_the_device_as_on_the_host(
+        toy, one_at_a_time, ends):
+    """Every draft accepted: an even budget ends on a step's first token
+    with the second one chosen and dropped; an end-of-sequence id as a
+    step's first token drops its accepted second, and one as its second
+    is kept and ends the row."""
+    gen = drafting(toy, "always_agrees")
+    base = one_at_a_time[0].token_ids
+    at = {"budget_on_a_steps_first_token": None,
+          "eos_a_steps_first_token": 1, "eos_a_steps_second_token": 2}[ends]
+    gen.eos_token_ids = set() if at is None else {base[at]}
+    seen = per_step_loop.assert_the_loop_gives_what_the_hosts_loop_gave(
+        gen, texts(), NEW - (at is None), atol=ATOL)
+    gen.eos_token_ids = set()
+    row = seen["out"][0]
+    if at is None:
+        assert row.token_ids == base[:NEW - 1]
+        # the last step's second token was accepted and not committed
+        assert seen["done"]["committed_tokens"] \
+            < seen["done"]["drafted"] + seen["done"]["accepted"]
+    else:
+        assert row.finished and row.token_ids == base[:base.index(base[at])]
 
 
 def test_a_rejected_column_left_counted_changes_what_is_served(
@@ -701,3 +741,65 @@ def test_guard_classify_goes_through_the_batcher(engine):
     assert 0 <= accepted <= drafted and drafted >= 7 * 3
     # a row's 6 tokens: the prefill's and the steps', one or two each
     assert tokens == 7 * 6
+
+
+def test_the_loop_is_one_program_of_a_generation_through_the_engine(
+        engine, seen):
+    """``engine.guard_classify`` behind the batcher: ONE ``gen.decode``
+    step, marker and program a generation, whose ``forwards`` is the steps
+    the device ran and whose drafts and tokens are the sums a program a
+    step gave for the same prompt; ``engine.gen.done`` totals them."""
+    from semantic_router_tpu.models.generate import build_guard_prompt
+
+    rs = engine._runtime_stats
+    flavours = ("gen.prefill", "gen.decode", "gen.denoise", "gen.commit")
+
+    def counts():
+        by_flavour = {(c, v): getattr(rs, c).get(task="guard", flavour=v)
+                      for c in ("gen_forwards", "gen_programs")
+                      for v in flavours}
+        return dict(by_flavour, **{c: getattr(rs, c).get(task="guard")
+                                   for c in ("gen_drafts", "gen_tokens",
+                                             "gen_drafts_accepted")})
+
+    text = words(prompts(12, (9,))[0])
+    before = counts()
+    engine.guard_classify("guard", text)
+    after = counts()
+    moved = {k: after[k] - before[k] for k in after}
+    # the same prompt, a program a step
+    gen = engine._tasks["guard"].generator
+    enc = gen.tokenizer.encode(build_guard_prompt(text))
+    assert len(enc) <= 64
+    ref = per_step_loop.Steps()
+    per_step_loop.per_step_twin(gen).generate(
+        [], gen.gen_length, encodings=[enc], bucket=64, padded_rows=1,
+        observer=ref)
+    want = ref.decodes()
+    assert 3 <= len(want) <= 5  # 6 tokens: the prefill's, then 1 or 2 a step
+
+    steps = [f for n, f in seen if n == "engine.step"]
+    assert [s["flavour"] for s in steps] == ["gen.prefill", "gen.decode"]
+    assert steps[1]["tokens_real"] == 2 and "block" not in steps[1]
+    marks = [f for n, f in seen if n == "engine.gen.forward"]
+    assert [m["flavour"] for m in marks] == ["gen.prefill", "gen.decode"]
+    loop = marks[1]
+    assert loop["forwards"] == len(want)
+    assert loop["layers"] == 3 * len(want)  # two expert layers, the module
+    for fact in ("drafted", "accepted", "committed_tokens"):
+        assert loop[fact] == sum(w[fact] for w in want), fact
+    assert loop["committed_tokens"] == 5 and loop["drafted"] == len(want)
+    assert loop["pairs"] == sum(int(w["load"][:, 1].sum()) for w in want)
+    (done,) = [f for n, f in seen if n == "engine.gen.done"]
+    assert done["forwards"] == 1 + len(want) and done["tokens"] == 6
+    assert [f["after"] for n, f in seen if n == "engine.gen.turn"] == \
+        ["gen.prefill", "gen.decode"]
+    for counter, decode in (("gen_forwards", len(want)),
+                            ("gen_programs", 1)):
+        assert {v: moved[counter, v] for v in flavours} == {
+            "gen.prefill": 1, "gen.decode": decode, "gen.denoise": 0,
+            "gen.commit": 0}, counter
+    assert moved["gen_drafts"] == loop["drafted"]
+    assert moved["gen_drafts_accepted"] == loop["accepted"]
+    assert moved["gen_tokens"] == 6
+

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import per_step_loop
 from chipbench import cells
 from semantic_router_tpu.models import lfm2_moe as M
 from semantic_router_tpu.models import sdar_moe
@@ -504,18 +505,53 @@ def test_the_loop_serves_the_hybrid_decoder_with_its_trajectory(toy):
                 e["lse"], jax.nn.logsumexp(jnp.asarray(z)), atol=ATOL)
 
 
-def test_a_step_reads_back_a_small_report_not_the_vocabulary(toy):
+def test_the_loop_reads_back_small_reports_not_the_vocabulary(toy):
     gen = generator(toy)
     gen.generate([words(prompts(10, (5,))[0])], max_new_tokens=3)
-    (step,) = gen._step_cache.values()
+    (key,) = gen._loop_cache
+    assert key == (1, 1, 64, 2)  # rows, positions a step, cache, steps
     cfg = gen.config
     cache = jax.eval_shape(
         lambda: M.prefill(cfg, gen.params, jnp.zeros((1, 32), jnp.int32),
                           jnp.ones(1, jnp.int32), 64)[0])
-    out = jax.eval_shape(step, gen.params, cache, jnp.zeros(1, jnp.int32),
-                         jnp.zeros(1, jnp.int32), jnp.asarray(0))
+    out = jax.eval_shape(
+        gen._loop_cache[key], gen.params, cache, jnp.zeros(1, jnp.int32),
+        jnp.zeros(1, jnp.int32), jnp.asarray(0), jnp.zeros(1, bool),
+        jnp.zeros(0, jnp.int32), jnp.asarray(3))
     small = jax.tree_util.tree_leaves(out[1:])
     assert max(int(np.prod(a.shape)) for a in small) < cfg.vocab_size
+    reports, aux = out[1]  # an entry a step
+    assert reports.shape == (2, 1, 2 + 2 * 4)
+    assert aux["experts"].shape == (2, 4, 1, 2)
+    assert aux["load"].shape == (2, 4, 4)
+
+
+@pytest.fixture(scope="module")
+def looped(toy):
+    """The toy with its head untied, the embedding's rows rolled by one:
+    the seeded, tied weights name the token they read, and a row that
+    repeats one token cannot end at a step of its own."""
+    _, _, cfg, params = toy
+    cfg = dataclasses.replace(cfg, tie_word_embeddings=False)
+    params = dict(params, lm_head=jnp.roll(params["embed"], 1, axis=0))
+    return GreedyGenerator(cfg, params, WordTokenizer(),
+                           model=M.CachedModel(cfg), gen_length=7,
+                           top_logits=4)
+
+
+@pytest.mark.parametrize("case", per_step_loop.CASES)
+def test_the_loop_gives_what_the_hosts_loop_gave(toy, looped, case):
+    """The decode loop on the device against a program a step
+    (``tests/per_step_loop.py``) over the hybrid cache: K and V columns
+    and every conv layer's state carried through the loop."""
+    texts = [words(r) for r in prompts(31, (7, 12, 9))]
+    seen = per_step_loop.check_case(case, looped, texts, 7)
+    for res in seen["out"]:
+        assert all(e["experts"].shape == (4, 1, 2)
+                   for e in res.trajectory[1:])
+    if seen["done"] is not None:  # four expert layers a step, stacked
+        assert seen["done"]["load"].shape == (4 * len(seen["steps"]), 4)
+        assert seen["done"]["keys"] is None
 
 
 # -- through the engine and the batcher ----------------------------------------------
@@ -544,7 +580,7 @@ def engine(tmp_path):
     eng.shutdown()
 
 
-def test_guard_classify_goes_through_the_batcher_a_step_a_forward(
+def test_guard_classify_goes_through_the_batcher_the_loop_a_step(
         engine, seen):
     rs = engine._runtime_stats
     before = {v: rs.gen_forwards.get(task="guard", flavour=v)
@@ -553,20 +589,21 @@ def test_guard_classify_goes_through_the_batcher_a_step_a_forward(
     verdict = engine.guard_classify("guard", words(prompts(12, (9,))[0]))
     assert verdict.safety == "Controversial"  # seeded weights say nothing
     steps = [f for n, f in seen if n == "engine.step"]
-    assert [s["flavour"] for s in steps] == ["gen.prefill"] \
-        + ["gen.decode"] * 5
+    assert [s["flavour"] for s in steps] == ["gen.prefill", "gen.decode"]
     n_prompt = steps[0]["tokens_real"]
     assert n_prompt > 9 and steps[0]["group"] == "gen:guard"
     assert steps[0]["bucket"] * steps[0]["padded_rows"] == 64
-    assert [s["block"] for s in steps[1:]] == [0, 1, 2, 3, 4]
-    assert all(s["tokens_real"] == 1 for s in steps[1:])
+    assert steps[1]["tokens_real"] == 1  # the live rows' of a step
     marks = [f for n, f in seen if n == "engine.gen.forward"]
     assert [m["flavour"] for m in marks] == [s["flavour"] for s in steps]
     assert marks[0]["layers"] == 4 and marks[0]["pairs"] == 4 * n_prompt * 2
     assert marks[0]["rows_per_group"] == 1 \
         and "rows_per_group" not in marks[1]
     assert rs.gen_rows_per_group.get(task="guard") == 1
-    assert marks[1]["pairs"] == 4 * 2 and marks[1]["experts_touched"] == 8
+    # five steps' sums: four expert layers, one row, two experts a token
+    assert marks[1]["forwards"] == 5 and marks[1]["layers"] == 5 * 4
+    assert marks[1]["pairs"] == 5 * 4 * 2
+    assert marks[1]["experts_touched"] == 5 * 8
     assert {v: rs.gen_forwards.get(task="guard", flavour=v) - before[v]
             for v in before} == {"gen.prefill": 1, "gen.decode": 5}
     assert rs.gen_tokens.get(task="guard") - tokens0 == 6
@@ -582,11 +619,12 @@ def test_guard_classify_goes_through_the_batcher_a_step_a_forward(
     assert fill[0]["tokens_padded"] == 64
 
 
-def test_a_token_at_a_time_generation_is_a_forward_a_program(engine, seen):
+def test_a_token_at_a_time_generation_is_two_programs(engine, seen):
     """The observer, the marker and the counters are shared with the block
-    generator, whose program is a block of forwards; this loop's steps,
-    markers and counters are what they were: a forward a step, and the new
-    ``forwards`` fact and programs counter say one."""
+    generator, whose program is a block of forwards; this loop's program
+    is a generation's decode steps: ONE ``gen.decode`` step, marker and
+    program, whose ``forwards`` fact and forwards counter say how many
+    steps the device ran."""
     rs = engine._runtime_stats
     flavours = ("gen.prefill", "gen.decode", "gen.denoise", "gen.commit")
 
@@ -602,22 +640,20 @@ def test_a_token_at_a_time_generation_is_a_forward_a_program(engine, seen):
     mark_facts = {"group", "flavour", "forwards", "layers", "pairs",
                   "experts_touched", "load_milli"}
     steps = [f for n, f in seen if n == "engine.step"]
-    assert [s["flavour"] for s in steps] == ["gen.prefill"] \
-        + ["gen.decode"] * 5
-    assert set(steps[0]) == step_facts
-    assert all(set(s) == step_facts | {"block"} for s in steps[1:])
+    assert [s["flavour"] for s in steps] == ["gen.prefill", "gen.decode"]
+    assert all(set(s) == step_facts for s in steps)
     marks = [f for n, f in seen if n == "engine.gen.forward"]
     assert [m["flavour"] for m in marks] == [s["flavour"] for s in steps]
     assert set(marks[0]) == mark_facts | {"rows_per_group"}
-    assert all(set(m) == mark_facts for m in marks[1:])
-    assert all(m["forwards"] == 1 and m["layers"] == 4 for m in marks)
+    assert set(marks[1]) == mark_facts
+    assert [(m["forwards"], m["layers"]) for m in marks] == [(1, 4), (5, 20)]
     assert [f["after"] for n, f in seen if n == "engine.gen.turn"] == \
         [s["flavour"] for s in steps]
     (done,) = [f for n, f in seen if n == "engine.gen.done"]
-    assert done["forwards"] == 6
-    for counter in ("gen_forwards", "gen_programs"):
+    assert done["forwards"] == 6 and done["tokens"] == 6
+    for counter, decode in (("gen_forwards", 5), ("gen_programs", 1)):
         assert {v: after[counter, v] - before[counter, v]
-                for v in flavours} == {"gen.prefill": 1, "gen.decode": 5,
+                for v in flavours} == {"gen.prefill": 1, "gen.decode": decode,
                                        "gen.denoise": 0, "gen.commit": 0}
 
 
@@ -641,13 +677,13 @@ def test_warmup_compiles_both_programs_of_every_row_count(engine):
             for r in engine.warmup_report()] == [
         ("gen:guard", 64, 1, ""), ("gen:guard", 64, 3, "")]
     gen = engine._tasks["guard"].generator
-    keys = (sorted(gen._prefill_cache), sorted(gen._step_cache))
+    keys = (sorted(gen._prefill_cache), sorted(gen._loop_cache))
     assert keys == ([(1, 64, 128), (4, 64, 128)],
-                    [(1, 1, 128), (4, 1, 128)])
+                    [(1, 1, 128, 5), (4, 1, 128, 5)])
     engine.guard_classify("guard", words(prompts(14, (9,))[0]))
     engine.generate("guard", [words(p) for p in prompts(15, (6, 7, 8))],
                     max_new_tokens=gen.gen_length)
-    assert (sorted(gen._prefill_cache), sorted(gen._step_cache)) == keys
+    assert (sorted(gen._prefill_cache), sorted(gen._loop_cache)) == keys
     assert all(f._cache_size() == 1 for f in
                list(gen._prefill_cache.values())
-               + list(gen._step_cache.values()))
+               + list(gen._loop_cache.values()))

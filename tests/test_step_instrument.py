@@ -429,8 +429,7 @@ RUNNERS = {
         _generative_engine(),
         lambda e: e.generate("guard", ["a prompt", "another"],
                              max_new_tokens=3),
-        "gen:guard", [("gen.prefill", 2, 2), ("gen.decode", 2, 2),
-                      ("gen.decode", 2, 2)]),
+        "gen:guard", [("gen.prefill", 2, 2), ("gen.decode", 2, 2)]),
 }
 
 
@@ -585,9 +584,9 @@ def _block_loop():
 
 
 LOOPS = {"greedy": _greedy_loop, "blockdiff": _block_loop}
-# the host's steps: a forward a program in the one loop, a BLOCK's forwards
-# a program in the other (a prefill and three blocks)
-STEPS = {"greedy": 4, "blockdiff": 4}
+# the host's steps: the prefill and the LOOP of decode steps in the one, a
+# BLOCK's forwards a program in the other (a prefill and three blocks)
+STEPS = {"greedy": 2, "blockdiff": 4}
 PROMPTS = ["a prompt", "another"]
 
 
@@ -614,6 +613,11 @@ def _named(rows, name):
 def _gen_counters(eng):
     rs = eng._runtime_stats
     return {"forwards": sum(rs.gen_forwards._values.values()),
+            "programs": sum(rs.gen_programs._values.values()),
+            "by_flavour": {f: (rs.gen_programs.get(task="guard", flavour=f),
+                               rs.gen_forwards.get(task="guard", flavour=f))
+                           for f in ("gen.prefill", "gen.decode",
+                                     "gen.denoise", "gen.commit")},
             "blocks": rs.gen_blocks.get(task="guard"),
             "tokens": rs.gen_tokens.get(task="guard"),
             "generations": rs.gen_generations.get(task="guard"),
@@ -679,6 +683,14 @@ def test_gen_done_carries_the_counters_deltas_and_a_tiling(loop, tmp_path):
         assert facts[count] == after[count] - before[count], count
     assert facts["forwards"] == sum(forwards.values())
     assert after["generations"] - before["generations"] == 1
+    # one program a turn of the host, and every forward under its flavour:
+    # the decode loop's steps all under gen.decode, over ONE program
+    assert after["programs"] - before["programs"] == n_steps
+    for flavour, n in forwards.items():
+        programs, ran = (a - b for a, b in zip(
+            after["by_flavour"][flavour], before["by_flavour"][flavour]))
+        assert ran == n, flavour
+        assert programs == (n if flavour == "gen.commit" else 1), flavour
     # the three parts tile the whole, on the host clock ...
     parts = facts["steps_us"] + facts["turns_us"] + facts["finish_us"]
     assert abs(parts - facts["generation_us"]) < 1000
@@ -711,9 +723,9 @@ def _break_a_later_forward(eng):
         real = gen.programs
         gen.programs = lambda *a: (real(*a)[0], boom, boom)
         return lambda: setattr(gen, "programs", real)
-    real = gen._step_fn
-    gen._step_fn = lambda key: boom
-    return lambda: setattr(gen, "_step_fn", real)
+    real = gen._loop_fn
+    gen._loop_fn = lambda key: boom
+    return lambda: setattr(gen, "_loop_fn", real)
 
 
 def test_a_raising_forward_ends_its_turn_and_writes_no_done(loop, tmp_path):
@@ -772,9 +784,9 @@ def _spy_programs(eng, calls):
             spied(n, f) for n, f in zip(("prefill", "denoise", "commit"),
                                         real(*a)))
     else:
-        pre, step = gen._prefill_fn, gen._step_fn
+        pre, step = gen._prefill_fn, gen._loop_fn
         gen._prefill_fn = lambda key: spied("prefill", pre(key))
-        gen._step_fn = lambda key: spied("decode", step(key))
+        gen._loop_fn = lambda key: spied("decode", step(key))
 
 
 def test_a_session_never_changes_a_generations_programs(loop, tmp_path):

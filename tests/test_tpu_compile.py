@@ -206,6 +206,61 @@ def cell_model(name):
         return json.load(f)
 
 
+def loop_of_a_generation(gen, params, cache, state, rows, M, shape,
+                         steps=31):
+    """The generator's decode loop compiled for the described chip at the
+    cell's 32 tokens: ``(compiled, eval_shape's outs)``."""
+    vec, scalar = shape((rows,), jnp.int32), shape((), jnp.int32)
+    loop = gen._loop_fn((rows, 1 + gen.drafts, M, steps))
+    args = (params, cache, state, vec, scalar, shape((rows,), jnp.bool_),
+            shape((1,), jnp.int32), scalar)
+    return loop.lower(*args).compile(), jax.eval_shape(loop, *args)
+
+
+def assert_the_loop_writes_its_cache_in_place(compiled, cache, reports):
+    """Every leaf of the donated ``cache`` is aliased from argument to
+    result; the decode steps are ONE ``while`` (the one that carries the
+    ``reports`` buffer, a step's body once), and its body holds no copy of
+    a cache-shaped array: a step inside the loop writes the cache where
+    it lies, as a step that was a program did.  What the body may hold is
+    memory-space assignment's work — the compiler keeps a carried leaf
+    that fits (a layer's latents, 76 MB) in fast memory across the steps
+    and moves it with ``copy-start`` / ``slice-start`` pairs that have
+    ``S(1)`` on one side: asynchronous, beside the step's compute, and no
+    second home in HBM."""
+    import re
+
+    text = compiled.as_text()
+    leaves = jax.tree_util.tree_leaves(cache)
+    aliases = re.search(r"input_output_alias=\{(.*?may-alias\)) \}", text)
+    assert aliases.group(1).count("-alias)") == len(leaves)
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves)
+    loops = [line for line in text.splitlines()
+             if " while(" in line and reports in line.split(" while(")[0]]
+    assert len(loops) == 1, len(loops)
+    body = re.search(r"body=%?([\w.\-]+)", loops[0]).group(1)
+    start = text.index("\n%" + body + " (")
+    lines = text[start:text.index("\n}\n", start)].splitlines()
+    assert len(lines) > 100  # a whole step
+    names = {"bfloat16": "bf16", "float32": "f32", "int32": "s32"}
+    for leaf in leaves:
+        if int(np.prod(leaf.shape)) * leaf.dtype.itemsize < 2**20:
+            continue  # lengths, a conv state of 64 KB: fast memory's own
+        held = "%s[%s]" % (names[leaf.dtype.name],
+                           ",".join(str(d) for d in leaf.shape))
+        copies = [line for line in lines if re.search(
+            r"= \(?" + re.escape(held) + r"[^=]* copy\(", line)]
+        assert copies == [], copies[:2]
+        moved = [line for line in lines if re.search(
+            r"= \(" + re.escape(held) + r"[^=]* copy-start\(", line)]
+        for line in moved:  # between memory spaces, not within HBM
+            sides = re.findall(re.escape(held) + r"\{[^}]*\}",
+                               line.split(" copy-start(")[0])[:2]
+            assert sum("S(1)" in side for side in sides) == 1, line[:300]
+    return text
+
+
 # padded batches 1..max_batch_size at the short buckets; the batches that
 # fit one chip's HBM at the long ones (32 x 32768 does not: see below)
 FLASH_SHAPES = [(b, s) for s in (128, 512) for b in (1, 2, 4, 8, 16, 32)] \
@@ -628,12 +683,12 @@ class TestHybridGuardCompilesForV5e:
 
     def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch):
         """The generator's prefill (8 rows mapped inside it, two a group)
-        and decode programs over two
+        and the LOOP of its decode steps over two
         layers that hold every kind of part (attention with the dense MLP,
         then a convolution with experts): the prefill's temporaries are
         one group's and under what ``rows_per_group`` reckoned for it,
-        the decode step writes the donated hybrid cache in place and
-        returns a small report."""
+        every step of the loop writes the donated hybrid cache in place
+        and leaves a small report in the loop's buffers."""
         from semantic_router_tpu.models import lfm2_moe
         from semantic_router_tpu.models.generate import GreedyGenerator
 
@@ -665,15 +720,25 @@ class TestHybridGuardCompilesForV5e:
         assert aux["experts"].shape == (1, rows, S, 4)
         cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
                                        cache)
-        step = gen._step_fn((rows, 1, M)).lower(
-            params, cache, shape((rows,), jnp.int32),
-            shape((rows,), jnp.int32), shape((), jnp.int32)).compile()
-        mem = step.memory_analysis()
         sizes = lfm2_moe.CachedModel.cache_bytes(cache)
         assert sizes == {"kv": 2 * rows * 8 * M * 64 * 2,
                          "conv": rows * 2 * H * 2}
-        assert mem.alias_size_in_bytes >= sizes["kv"] + sizes["conv"]
-        assert mem.temp_size_in_bytes < 0.1 * 2**30
+        # K and V with the columns last: the layout the loop keeps
+        assert cache["kv"][0][0].shape == (rows, 8, 64, M)
+        loop, (_, (reports, aux), ran) = loop_of_a_generation(
+            gen, params, cache, shape((rows,), jnp.int32), rows, M, shape)
+        assert reports.shape == (31, rows, 2 + 2 * gen.top_logits)
+        assert aux["experts"].shape == (31, 1, rows, 4)
+        assert aux["load"].shape == (31, 1, 4) and ran.shape == ()
+        text = assert_the_loop_writes_its_cache_in_place(
+            loop, cache, "f32[31,%d,%d]" % (rows, 2 + 2 * gen.top_logits))
+        # ONE decode body a row count: an expert layer's two grouped matmuls
+        assert text.count("tpu_custom_call") == 2
+        # a step's temporaries (3 MB as a program of its own) and a
+        # weight the compiler keeps in fast memory over the steps; with
+        # the columns second to last the loop padded each to 128 and
+        # copied the cache in and out: 0.27 GiB
+        assert loop.memory_analysis().temp_size_in_bytes < 0.1 * 2**30
 
 
 class TestSparseLatentGuardCompilesForV5e:
@@ -740,12 +805,12 @@ class TestSparseLatentGuardCompilesForV5e:
 
     def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch):
         """The generator's prefill (8 rows mapped inside it, one a group:
-        a row's activations are 84 MB) and decode programs over two
-        layers that hold every kind of part (a full layer with the dense
-        MLP, a sliding layer with experts): the prefill's temporaries are
-        one row's and under what ``rows_per_group`` reckoned for it,
-        the decode step writes the donated latent cache in place and
-        returns a small report."""
+        a row's activations are 84 MB) and the LOOP of its decode steps
+        over two layers that hold every kind of part (a full layer with
+        the dense MLP, a sliding layer with experts): the prefill's
+        temporaries are one row's and under what ``rows_per_group``
+        reckoned for it, every step of the loop writes the donated latent
+        cache in place and leaves a small report in the loop's buffers."""
         from semantic_router_tpu.models import dots3_note
         from semantic_router_tpu.models.generate import GreedyGenerator
 
@@ -780,21 +845,23 @@ class TestSparseLatentGuardCompilesForV5e:
                                          S // 8)
         cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
                                        cache)
-        step = gen._step_fn((rows, 1, M)).lower(
-            params, cache, shape((rows,), jnp.int32),
-            shape((rows,), jnp.int32), shape((), jnp.int32)).compile()
-        mem = step.memory_analysis()
         sizes = dots3_note.CachedModel.cache_bytes(cache)
         assert sizes == {"latent": rows * M * (512 + 64) * 2,
                          "index": rows * M * 128 * 2,
                          "window": rows * 513 * (1024 + 64) * 2}
-        assert mem.alias_size_in_bytes >= sum(sizes.values())
-        assert mem.temp_size_in_bytes < 0.2 * 2**30
-        _, _, _, report, aux = jax.eval_shape(
-            gen._step_fn((rows, 1, M)), params, cache,
-            shape((rows,), jnp.int32), shape((rows,), jnp.int32),
-            shape((), jnp.int32))
-        assert aux["selected"].shape == (1, rows, M // 8)
+        loop, (_, (reports, aux), ran) = loop_of_a_generation(
+            gen, params, cache, shape((rows,), jnp.int32), rows, M, shape)
+        assert reports.shape == (31, rows, 2 + 2 * gen.top_logits)
+        # a step's selected bits wait in the loop's buffer: 0.26 MB a layer
+        assert aux["selected"].shape == (31, 1, rows, M // 8)
+        assert aux["keys"].shape == (31, rows, 2) and ran.shape == ()
+        text = assert_the_loop_writes_its_cache_in_place(
+            loop, cache, "f32[31,%d,%d]" % (rows, 2 + 2 * gen.top_logits))
+        assert text.count("tpu_custom_call") == 2  # ONE decode body
+        # a step's temporaries (0.12 GiB as a program of its own) and one
+        # home in HBM for the layer's latents (76 MB), which live in fast
+        # memory while the loop runs
+        assert loop.memory_analysis().temp_size_in_bytes < 0.3 * 2**30
 
 
 class TestSelfDraftingGuardCompilesForV5e:
@@ -806,10 +873,11 @@ class TestSelfDraftingGuardCompilesForV5e:
 
     def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch):
         """The generator's prefill (the layers, the choice, then the
-        module over the prompt) and its step (two positions a row through
-        the layers, the choice, the module, the advance: ONE program that
-        writes both donated latent caches in place and returns a small
-        report)."""
+        module over the prompt) and the LOOP of its steps (two positions
+        a row through the layers, the choice, the module, the advance, the
+        rows' counts against their budget: ONE program whose every step
+        writes both donated latent caches in place and leaves a small
+        report in the loop's buffers)."""
         from semantic_router_tpu.models import joyai_llm_flash
         from semantic_router_tpu.models.generate import GreedyGenerator
 
@@ -842,20 +910,23 @@ class TestSelfDraftingGuardCompilesForV5e:
         sizes = joyai_llm_flash.CachedModel.cache_bytes(cache)
         assert sizes == {"latent": 2 * rows * M * (512 + 64) * 2,
                          "draft": rows * M * (512 + 64) * 2}
-        verify = gen._verify_fn((rows, 2, M))
-        step_args = (params, cache, (vec, vec), vec, scalar)
-        step = verify.lower(*step_args).compile()
-        mem = step.memory_analysis()
-        assert mem.alias_size_in_bytes >= sum(sizes.values())
-        assert mem.temp_size_in_bytes < 0.2 * 2**30
-        assert step.as_text().count("tpu_custom_call") >= 4
-        _, state, at, (chosen, accepted, drafted), aux = jax.eval_shape(
-            verify, *step_args)
-        assert chosen.shape == (rows, 2, 2 + 2 * gen.top_logits)
-        assert accepted.shape == at.shape == (rows,)
-        assert drafted.shape == (rows, 2 + 2 * gen.top_logits)
-        assert aux["experts"].shape == (2, rows, 2, 8)
-        assert aux["load"].shape == (2, 4)
+        loop, (_, ((chosen, accepted, drafted), aux), ran) = \
+            loop_of_a_generation(gen, params, cache, (vec, vec), rows, M,
+                                 shape)
+        assert chosen.shape == (31, rows, 2, 2 + 2 * gen.top_logits)
+        assert accepted.shape == (31, rows) and ran.shape == ()
+        assert drafted.shape == (31, rows, 2 + 2 * gen.top_logits)
+        assert aux["experts"].shape == (31, 2, rows, 2, 8)
+        assert aux["load"].shape == (31, 2, 4)
+        text = assert_the_loop_writes_its_cache_in_place(
+            loop, cache, "f32[31,%d,2,%d]" % (rows, 2 + 2 * gen.top_logits))
+        # ONE body of two positions a row: the layer's and the module's
+        # expert layers, two grouped matmuls each
+        assert text.count("tpu_custom_call") == 4
+        # a step's temporaries (45 MB as a program of its own) and the
+        # transposes of q_b and kv_b, 27 MB a block, which the compiler
+        # makes once before the loop and not once a step
+        assert loop.memory_analysis().temp_size_in_bytes < 0.2 * 2**30
 
 
 class TestLongPromptGuardsPrefillFitsAtTheRulesGroup:
