@@ -1,0 +1,8 @@
+"""Mean length of the traced ``gen.decode`` steps (a generation's loop of
+decode steps, one program) of the SHORT bucket, ms."""
+
+from chipbench.layer_metrics import _ar_spans, _mix_spans
+
+
+def read(run):
+    return _mix_spans.step_mean_ms(run, _ar_spans.DECODE, "short")

@@ -193,6 +193,10 @@ class DynamicBatcher:
         # released ride the NEXT step together instead of one firing alone
         # and the rest queueing behind it for a second step
         self._patient = patient or (lambda key: False)
+        # facts of a group's ``engine.queue_wait`` events beyond its name
+        # (the engine says a generative group's ``bucket``)
+        self.wait_facts: Callable[[Hashable], Dict[str, int]] = \
+            lambda key: {}
         self._released: Dict[Hashable, float] = {}
         self.name = name
         self.max_batch_size = max(1, max_batch_size)
@@ -263,6 +267,7 @@ class DynamicBatcher:
             now = time.perf_counter()
             group = ":".join(map(str, key)) if isinstance(key, tuple) \
                 else str(key)
+            facts = self.wait_facts(key)
             for item in batch:
                 # exemplar: the waiting request's trace id, so a slow
                 # queue-wait bucket links straight to the trace that
@@ -271,7 +276,7 @@ class DynamicBatcher:
                 s.batcher_queue_wait.observe(now - item.enqueue_t,
                                              exemplar=tid,
                                              batcher=self.name)
-                queue_wait(tid or "", group, now - item.enqueue_t)
+                queue_wait(tid or "", group, now - item.enqueue_t, **facts)
             s.batcher_fill_ratio.observe(len(batch) / self.max_batch_size,
                                          batcher=self.name)
         except Exception:

@@ -316,8 +316,10 @@ class _GenerationObserver:
         what this step FINISHED (a block's step its block, whichever step
         writes its K and V later; a decode loop one token a live row and
         step).  ``cache_bytes``: a prefill's cache by kind of state
-        (``{"kv", "conv"}``, or a latent cache's ``{"latent", "index",
-        "window"}``).  ``keys [rows, 2]``: of a model with a learned
+        (``{"kv", "conv"}``, a latent cache's ``{"latent", "index",
+        "window"}``, or ``{"full", "window"}`` of whole K/V beside rings;
+        the step's marker carries each beside the generation's bucket).
+        ``keys [rows, 2]``: of a model with a learned
         selection, the keys its queries selected and those visible to
         them, summed on the device over the full layers.
         ``rows_per_group``: of a prefill whose rows are mapped inside the
@@ -338,7 +340,8 @@ class _GenerationObserver:
             batchtrace.gen_forward(
                 step.group, step.variant, load, keys, rows_per_group,
                 forwards, None if drafted is None else
-                (drafted, accepted, committed_tokens))
+                (drafted, accepted, committed_tokens), bucket=self.bucket,
+                cache_bytes=cache_bytes)
         try:
             self.engine._runtime_stats.record_generation(
                 self.task, step.variant, forwards=forwards,
@@ -508,6 +511,9 @@ class InferenceEngine:
             starvation_steps=self._packing["starvation_steps"],
             patient=lambda key: isinstance(key, tuple) and key[0] == GEN_KEY,
         )
+        # a generative group's key: (GEN_KEY, task, prompt bucket, ...)
+        self.batcher.wait_facts = lambda key: {"bucket": int(key[2])} \
+            if isinstance(key, tuple) and key[0] == GEN_KEY else {}
         # the online shape auto-tuner exists per engine (cheap state);
         # its POLLING THREAD is bootstrap's to start (apply_packing_knobs
         # honors engine.packing.autotune) — bare test engines stay

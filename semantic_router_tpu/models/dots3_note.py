@@ -40,8 +40,9 @@ the latent space, scores and the weighted sum are taken against the cached
 latents, and the value half comes last.  The same mathematics — and the
 same code as ``models/joyai_llm_flash.py``'s: ``models/latent_attention.py``
 has the projections, the cache's row and both forms; what is this family's
-alone (the rescale and ``rotate_half`` through ``Geometry``, the indexer,
-the window's ring, the gate) is here.
+alone (the rescale and ``rotate_half`` through ``Geometry``, the indexer)
+is here; the gate a head and the window's ring are ``models/gated_window.py``'s,
+which ``models/laguna.py`` runs too.
 
 The cache a row carries: per full layer ``latent [rows, M, r_kv + rope]``
 (``c_kv ; k^r``, every token) and ``index [rows, M, index_head_dim]``
@@ -76,7 +77,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.flash_attention import flash_attention
-from ..ops.rope import apply_rotary
+from ..ops.rope import apply_rotary_front
+from .gated_window import gate_out, ring_of, ring_seen, ring_slot
 from .latent_attention import (
     Geometry,
     absorbed,
@@ -324,10 +326,10 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: Dots3NoteConfig
 # -- what prefill and decode share -----------------------------------------------
 
 
-def _rotate_front(x, cos, sin, n: int):
-    """RoPE on the first ``n`` dims of ``x``'s last axis."""
-    front, _ = apply_rotary(x[..., :n], x[..., :n], cos, sin)
-    return jnp.concatenate([front, x[..., n:]], -1)
+def _rotate_front(x, cos, sin):
+    """RoPE on the first dims of ``x``'s last axis: as many as ``cos``
+    and ``sin`` are wide."""
+    return apply_rotary_front(x, x, cos, sin)[0]
 
 
 def _index_key(cfg, p, h, cos, sin):
@@ -339,8 +341,7 @@ def _index_key(cfg, p, h, cos, sin):
     k = (k - mu) * jax.lax.rsqrt(var + INDEX_NORM_EPS) \
         * p["index_k_norm"].astype(jnp.float32) \
         + p["index_k_bias"].astype(jnp.float32)
-    return _rotate_front(k.astype(cfg.dtype), cos, sin,
-                         cfg.qk_rope_head_dim)
+    return _rotate_front(k.astype(cfg.dtype), cos, sin)
 
 
 def _index_weights(cfg, p, h):
@@ -445,14 +446,10 @@ def _head(cfg, params, x):
 
 
 def _gate_out(cfg, g: Geometry, p, h, out):
-    """``h [B, S, H]``, ``out [B, heads, S, v]`` -> ``W_o [g_i * o_i]``."""
-    with jax.named_scope("gate_out"):
-        gate = jax.nn.sigmoid(jnp.dot(h, p["gate_proj"],
-                                      preferred_element_type=jnp.float32))
-        out = (out.astype(jnp.float32)
-               * jnp.moveaxis(gate, -1, 1)[..., None]).astype(cfg.dtype)
-        return jnp.einsum("bhsv,hvo->bso", out,
-                          p["o_proj"].reshape(g.heads, g.v, -1))
+    """``h [B, S, H]``, ``out [B, heads, S, v]`` -> ``W_o [g_i * o_i]``
+    (``gated_window.gate_out``, under the name the benchmark's tests of
+    this family reach it by)."""
+    return gate_out(p, h, out, cfg.dtype)
 
 
 # -- prefill ---------------------------------------------------------------------
@@ -527,7 +524,7 @@ def _prefill_rows(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
                         q_i = _rotate_front(
                             (c_q @ p["index_q"]).reshape(
                                 B, S, cfg.index_n_heads, cfg.index_head_dim),
-                            cos[:, :, None], sin[:, :, None], g.rope)
+                            cos[:, :, None], sin[:, :, None])
                         k_i = _index_key(cfg, p, h, cos, sin)
                         chosen = _select_prefill(
                             cfg, q_i, k_i, _index_weights(cfg, p, h), valid)
@@ -559,15 +556,7 @@ def _prefill_rows(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
                             window=2 * (W - 1), scale=g.scale)
                     x = x + _gate_out(cfg, g, p, h, out)
                     with jax.named_scope("kv"):
-                        # slot j holds the latest position p <= last with
-                        # p mod W == j, if there is one
-                        slot = jnp.arange(W, dtype=jnp.int32)[None, :]
-                        src = last[:, None] - (last[:, None] - slot) % W
-                        held = (src >= 0) & (lengths > 0)[:, None]
-                        ring = jnp.take_along_axis(
-                            lat, jnp.clip(src, 0, S - 1)[:, :, None], axis=1)
-                        window.append(ring * held[:, :, None]
-                                      .astype(ring.dtype))
+                        window.append(ring_of(lat, lengths, W))
             x, top_e, load = _feed_forward_as_one_row(cfg, i, p, x, valid)
             if top_e is not None:
                 experts.append(_expert_ids(cfg, top_e))
@@ -723,7 +712,7 @@ def decode(cfg: Dots3NoteConfig, params, cache, tokens, positions):
                         q_i = _rotate_front(
                             (c_q @ p["index_q"]).reshape(
                                 B, cfg.index_n_heads, cfg.index_head_dim),
-                            cos, sin, g.rope)
+                            cos, sin)
                         idx = put(idx, _index_key(cfg, p, h, cos, sin),
                                   positions)
                         with jax.named_scope("scores"):
@@ -751,10 +740,10 @@ def decode(cfg: Dots3NoteConfig, params, cache, tokens, positions):
                     _, q = queries(g, p, h, cos, sin)
                     with jax.named_scope("kv"):
                         ring = put(ring, latents(g, p, h, cos, sin),
-                                   positions % W)
+                                   ring_slot(positions, W))
                     with jax.named_scope("core"):
-                        seen = jnp.arange(W)[None, :] <= pos
-                        out = absorbed(g, p, q, ring, seen[:, None])
+                        out = absorbed(g, p, q, ring,
+                                       ring_seen(positions, W)[:, None])
                     x = x + _gate_out(cfg, g, p, h, out)
                     window.append(ring)
             x, top_e, load = _feed_forward(cfg, i, p, x, live[:, None])

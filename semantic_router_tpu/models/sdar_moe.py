@@ -302,15 +302,26 @@ def routed_experts(p, x, valid, top_e, top_w, held: Tuple[int, int], dtype):
     return y.astype(dtype), load
 
 
+def softmax_route(x, router, k: int, norm: bool):
+    """The softmax choice of every decoder that has one (this one and
+    ``models/laguna.py``): ``x [T, H]`` -> ``(top_e [T, k], weights [T, k]
+    float32)``.  ``p = softmax(x W)`` in float32 over ALL experts, the ``k``
+    largest are chosen, their weights ``p`` there, over their sum if
+    ``norm``."""
+    logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, k)
+    if norm:
+        top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    return top_e, top_p
+
+
 def moe(cfg: SdarMoeConfig, p, x, valid):
     """``x [T, H]`` through the softmax router and ``routed_experts``.
     Returns ``(y [T, H], top_e [T, k], load [4])``."""
     with jax.named_scope("router"):
-        logits = jnp.dot(x, p["router"], preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_e = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-        if cfg.norm_topk_prob:
-            top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+        top_e, top_p = softmax_route(x, p["router"], cfg.num_experts_per_tok,
+                                     cfg.norm_topk_prob)
     y, load = routed_experts(p, x, valid, top_e, top_p, cfg.held, cfg.dtype)
     return y, top_e, load
 

@@ -303,17 +303,22 @@ def start_step(items, *, group: str, bucket: int, max_batch: int,
     return BatchStep(name, traced, attrs, detailed=detailed, facts=facts)
 
 
-def queue_wait(trace_id: str, group: str, wait_s: float) -> None:
+def queue_wait(trace_id: str, group: str, wait_s: float,
+               **facts: int) -> None:
     """``engine.queue_wait``: one item left the batcher's queue now,
     after ``wait_s``.  The interval is in the past, where an annotation
-    cannot start: the event marks its END and carries its length."""
+    cannot start: the event marks its END and carries its length.
+    ``facts``: what the engine says of the item's group beyond its name (a
+    generative item's prompt ``bucket``: one task's short and long prompts
+    wait in one queue)."""
     with trace_span(QUEUE_WAIT_ANNOTATION, trace_id=trace_id, group=group,
-                    wait_us=int(wait_s * 1e6)):
+                    wait_us=int(wait_s * 1e6), **facts):
         pass
 
 
 def gen_forward(group: str, flavour: str, load, keys=None,
-                rows_per_group=None, forwards: int = 1, drafts=None) -> None:
+                rows_per_group=None, forwards: int = 1, drafts=None,
+                bucket: Optional[int] = None, cache_bytes=None) -> None:
     """``engine.gen.forward``: a step of a generation ended now, and
     this is what only its readback knew: the ``forwards`` the device ran
     in it (a block generator's step is a block's loop) and ``load
@@ -329,10 +334,18 @@ def gen_forward(group: str, flavour: str, load, keys=None,
     fact of that name: the rows one grouped matmul served.  ``drafts =
     (drafted, accepted, committed_tokens)`` of a step of a model that drafts
     for itself adds those three: the drafts verified (one a live row), those
-    that were right, and the tokens the step committed (one or two a row)."""
+    that were right, and the tokens the step committed (one or two a row).
+    ``bucket``: the generation's prompt bucket (two buckets of one task
+    alternate in one window).  ``cache_bytes`` of a prefill, by kind of
+    state (the model's ``cache_bytes``), adds ``cache_bytes_<kind>`` for
+    each: a whole K/V cache's ``full`` beside a ring's ``window``."""
     facts = {} if keys is None else {
         "keys_selected": int(keys[:, 0].sum(dtype="int64")),
         "keys_visible": int(keys[:, 1].sum(dtype="int64"))}
+    if bucket is not None:
+        facts["bucket"] = int(bucket)
+    for kind, size in (cache_bytes or {}).items():
+        facts[f"cache_bytes_{kind}"] = int(size)
     if rows_per_group is not None:
         facts["rows_per_group"] = int(rows_per_group)
     if drafts is not None:

@@ -116,6 +116,21 @@ def apply_rotary(q: jnp.ndarray, k: jnp.ndarray, cos: jnp.ndarray,
     return q_out.astype(orig_dtype), k_out.astype(orig_dtype)
 
 
+def apply_rotary_front(q: jnp.ndarray, k: jnp.ndarray, cos: jnp.ndarray,
+                       sin: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """RoPE over the FIRST ``cos.shape[-1]`` dims of q's and k's last axis
+    (a ``partial_rotary_factor`` below 1, or a head whose leading dims alone
+    rotate): ``rotate_half`` pairs dim ``d`` with ``d + n / 2`` inside those
+    ``n`` dims, the dims from ``n`` on pass through as they are.  Tables as
+    wide as the head are ``apply_rotary``."""
+    n = cos.shape[-1]
+    if n == q.shape[-1]:
+        return apply_rotary(q, k, cos, sin)
+    q_front, k_front = apply_rotary(q[..., :n], k[..., :n], cos, sin)
+    return (jnp.concatenate([q_front, q[..., n:]], -1),
+            jnp.concatenate([k_front, k[..., n:]], -1))
+
+
 @lru_cache(maxsize=256)
 def _cached_spec(head_dim: int, base: float,
                  yarn_key: Optional[Tuple[Tuple[str, object], ...]]

@@ -174,6 +174,16 @@ GENERATORS = {
         "(``num_nextn_predict_layers`` 0) decodes a token at a time "
         "(``generation: {gen_length}``; ``experts_held``).",
         _serve_cached("joyai_llm_flash", "JoyaiLlmFlashConfig")),
+    "laguna": (
+        "grouped-query attention in two geometries by layer type (full "
+        "layers over a whole K/V cache, sliding layers of another head "
+        "count over a ring of ``sliding_window`` positions; per-type RoPE, "
+        "YaRN and a partial rotary width among them), a sigmoid gate a "
+        "head, softmax expert routing beside a gated shared expert, the "
+        "same loop (``generation: {gen_length}``; ``experts_held``, "
+        "``vocab_held``).",
+        _serve_cached("laguna", "LagunaConfig",
+                      ("experts_held", "vocab_held"))),
 }
 GENERATIVE_MODEL_TYPES = tuple(GENERATORS)
 

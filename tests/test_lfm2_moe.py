@@ -638,13 +638,16 @@ def test_a_token_at_a_time_generation_is_two_programs(engine, seen):
     step_facts = {"group", "flavour", "bucket", "rows", "padded_rows",
                   "tokens_real"}
     mark_facts = {"group", "flavour", "forwards", "layers", "pairs",
-                  "experts_touched", "load_milli"}
+                  "experts_touched", "load_milli", "bucket"}
     steps = [f for n, f in seen if n == "engine.step"]
     assert [s["flavour"] for s in steps] == ["gen.prefill", "gen.decode"]
     assert all(set(s) == step_facts for s in steps)
     marks = [f for n, f in seen if n == "engine.gen.forward"]
     assert [m["flavour"] for m in marks] == [s["flavour"] for s in steps]
-    assert set(marks[0]) == mark_facts | {"rows_per_group"}
+    # a prefill's also says its cache by kind of state
+    assert set(marks[0]) == mark_facts | {
+        "rows_per_group", "cache_bytes_kv", "cache_bytes_conv"}
+    assert {m["bucket"] for m in marks} == {steps[0]["bucket"]}
     assert set(marks[1]) == mark_facts
     assert [(m["forwards"], m["layers"]) for m in marks] == [(1, 4), (5, 20)]
     assert [f["after"] for n, f in seen if n == "engine.gen.turn"] == \

@@ -179,6 +179,33 @@ def joyai_param_shapes(cfg, shape):
                     "block": block(n)}}
 
 
+def laguna_param_shapes(cfg, shape):
+    """The ``laguna`` parameter tree of ``cfg`` as shapes."""
+    H, I, W = cfg.hidden_size, cfg.moe_intermediate_size, \
+        cfg.intermediate_size
+    Is, D, nkv = (cfg.shared_expert_intermediate_size, cfg.head_dim,
+                  cfg.num_key_value_heads)
+    E, held, V = cfg.num_experts, cfg.held[1], cfg.vocab[1]
+    layers = []
+    for i, nh in enumerate(cfg.num_attention_heads_per_layer):
+        p = {"norm1": shape((H,)), "norm2": shape((H,)),
+             "q_norm": shape((D,)), "k_norm": shape((D,)),
+             "q_proj": shape((H, nh * D)), "k_proj": shape((H, nkv * D)),
+             "v_proj": shape((H, nkv * D)), "o_proj": shape((nh * D, H)),
+             "gate_proj": shape((H, nh))}
+        if cfg.is_sparse(i):
+            p.update(router=shape((H, E)), gate_up=shape((held, H, 2 * I)),
+                     down=shape((held, I, H)),
+                     shared={"gate_up": shape((H, 2 * Is)),
+                             "down": shape((Is, H))},
+                     shared_gate=shape((H, 1)))
+        else:
+            p.update(gate_up=shape((H, 2 * W)), down=shape((W, H)))
+        layers.append(p)
+    return {"embed": shape((V, H)), "norm": shape((H,)),
+            "lm_head": shape((V, H)), "layers": layers}
+
+
 def on_the_described_chip(monkeypatch):
     """Steer the programs' platform reads to the described v5e (the test's
     backend is the CPU): the megablox kernel, the flash kernel compiled
@@ -968,3 +995,154 @@ class TestLongPromptGuardsPrefillFitsAtTheRulesGroup:
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             < 15.0 * 2**30
         assert mem.temp_size_in_bytes < group * module._row_bytes(cfg, 8192)
+
+
+class TestWindowAndFullGuardCompilesForV5e:
+    """The laguna guard at the published widths (hidden 3072; full layers
+    of 48 query heads and sliding layers of 72 over 8 k/v heads of 128, a
+    window of 512; 128 of 256 experts of width 1024 held beside a gated
+    shared one; 50,176 vocabulary rows), buckets 512 and 8192 x 8 rows."""
+
+    @pytest.mark.parametrize("kind", ["full", "window"])
+    def test_prefill_attention_cores(self, one_chip, kind):
+        """One row's heads of 128 over 8192 columns, bfloat16, at the
+        blocks ``blocks_for`` picks from the shape (measured at D 64):
+        causal and whole at 1024 x 1024, causal under the window of 512
+        keys at 256 x 512 — both within the kernel's scoped memory at D
+        128, so the rule needs no head width."""
+        from semantic_router_tpu.ops.flash_attention import (
+            blocks_for,
+            flash_attention_pallas,
+        )
+
+        heads, window = (48, 0) if kind == "full" else (72, 2 * 511)
+        assert blocks_for(8192, window) == \
+            ((1024, 1024) if kind == "full" else (256, 512))
+        qkv = ((1, heads, 8192, 128), jnp.bfloat16)
+        compiled = compile_for(
+            one_chip,
+            functools.partial(flash_attention_pallas, causal=True,
+                              window=window, interpret=False),
+            qkv, qkv, qkv, ((1, 8192), jnp.int32))
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        assert f"bf16[{heads},8192,128]" in text
+
+    @pytest.mark.parametrize("tokens", [8, 8192])
+    def test_expert_layer_beside_the_gated_shared_expert(
+            self, one_chip, monkeypatch, tokens):
+        """``routed_experts`` at THIS model's matrices ([3072, 2048] and
+        [1024, 3072], by the rule's tiles) with 128 of 256 experts held,
+        behind the softmax router of 256 outputs and its 10 a token, plus
+        the shared expert under its sigmoid."""
+        from semantic_router_tpu.models import laguna, sdar_moe
+
+        monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
+        cfg = laguna.LagunaConfig(experts_held=(0, 128))
+        H, I, E = (cfg.hidden_size, cfg.moe_intermediate_size,
+                   cfg.num_experts)
+        bf = jnp.bfloat16
+
+        def layer(router, gate_up, down, s_gate_up, s_down, s_gate, x,
+                  valid):
+            p = {"router": router, "gate_up": gate_up, "down": down,
+                 "shared": {"gate_up": s_gate_up, "down": s_down},
+                 "shared_gate": s_gate}
+            return laguna.moe(cfg, p, x, valid)
+
+        compiled = compile_for(
+            one_chip, layer, ((H, E), bf), ((128, H, 2 * I), bf),
+            ((128, I, H), bf), ((H, 2 * I), bf), ((I, H), bf), ((H, 1), bf),
+            ((tokens, H), bf), ((tokens,), jnp.bool_))
+        assert compiled.as_text().count("tpu_custom_call") >= 2
+
+    @pytest.mark.parametrize("S, M, group", [(8192, 8256, 1), (512, 576, 8)],
+                             ids=["bucket_8192", "bucket_512"])
+    def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch, S,
+                                              M, group):
+        """The generator's prefill (8 rows mapped inside it: one a group at
+        the long bucket, all together at the short one) and the LOOP of its
+        decode steps over two layers that hold every kind of part (a full
+        layer with the dense MLP, a sliding layer with experts): every step
+        of the loop writes BOTH kinds of donated cache in place — the whole
+        K and V at the token's column, the ring at its slot — and leaves a
+        small report in the loop's buffers."""
+        from semantic_router_tpu.models import laguna
+        from semantic_router_tpu.models.generate import GreedyGenerator
+
+        on_the_described_chip(monkeypatch)
+        full, sliding = laguna.LAYER_TYPES
+        cfg = laguna.LagunaConfig.from_hf(
+            dict(cell_model("laguna-s21-guard"), num_hidden_layers=2,
+                 layer_types=[full, sliding],
+                 mlp_layer_types=["dense", "sparse"],
+                 num_attention_heads_per_layer=[48, 72],
+                 gating_types=["per_head"] * 2, num_experts=256),
+            experts_held=(0, 128), vocab_held=(0, 50176))
+        rows = 8
+
+        def shape(dims, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+        params = laguna_param_shapes(cfg, shape)
+        gen = GreedyGenerator(cfg, None, None,
+                              model=laguna.CachedModel(cfg))
+        args = (params, shape((rows, S), jnp.int32),
+                shape((rows,), jnp.int32), shape((), jnp.int32))
+        prefill = gen._prefill_fn((rows, S, M))
+        compiled = prefill.lower(*args).compile()
+        # both cores and an expert layer's two grouped matmuls
+        assert compiled.as_text().count("tpu_custom_call") >= 4
+        assert gen.model.rows_per_group(params, rows, S, M) == group
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < group * laguna._row_bytes(cfg, S) < 3.0 * 2**30
+        cache, _, report, aux = jax.eval_shape(prefill, *args)
+        assert report.shape == (rows, 2 + 2 * gen.top_logits)
+        assert aux["experts"].shape == (1, rows, S, 10)
+        cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
+                                       cache)
+        sizes = laguna.CachedModel.cache_bytes(cache)
+        assert sizes == {"full": 2 * rows * 8 * M * 128 * 2,
+                         "window": 2 * rows * 8 * 512 * 128 * 2}
+        loop, (_, (reports, aux), ran) = loop_of_a_generation(
+            gen, params, cache, shape((rows,), jnp.int32), rows, M, shape)
+        assert reports.shape == (31, rows, 2 + 2 * gen.top_logits)
+        assert aux["experts"].shape == (31, 1, rows, 10)
+        assert aux["load"].shape == (31, 1, 4) and ran.shape == ()
+        text = assert_the_loop_writes_its_cache_in_place(
+            loop, cache, "f32[31,%d,%d]" % (rows, 2 + 2 * gen.top_logits))
+        assert text.count("tpu_custom_call") == 2  # ONE decode body
+        assert loop.memory_analysis().temp_size_in_bytes < 0.3 * 2**30
+
+    def test_the_cells_prefill_fits_beside_its_weights(self, one_chip,
+                                                       monkeypatch):
+        """The 8 x 8192 prefill at the cell's widths and depth
+        (``chipbench/configs/laguna-s21-guard/model.json``: five layers,
+        128 experts and 50,176 vocabulary rows held), one row a group: its
+        arguments are the cell's 11.1 GB of weights, and weights,
+        temporaries and the cache it returns leave room on a v5e."""
+        from semantic_router_tpu.models import laguna
+
+        on_the_described_chip(monkeypatch)
+        model = cell_model("laguna-s21-guard")
+        cfg = laguna.LagunaConfig.from_hf(
+            dict(model, num_experts=model["published"]["num_experts"],
+                 vocab_size=model["published"]["vocab_size"]),
+            experts_held=tuple(model["held"]["experts"]),
+            vocab_held=tuple(model["held"]["vocab"]))
+
+        def shape(dims, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+        params = laguna_param_shapes(cfg, shape)
+        assert laguna.prefill_group(cfg, params, 8, 8192, 8256) == 1
+        assert laguna.prefill_group(cfg, params, 8, 512, 576) == 8
+        compiled = jax.jit(
+            lambda p, i, n: laguna.prefill(cfg, p, i, n, 8256)).lower(
+                params, shape((8, 8192), jnp.int32),
+                shape((8,), jnp.int32)).compile()
+        mem = compiled.memory_analysis()
+        assert 11.0e9 < mem.argument_size_in_bytes < 11.3e9
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            + mem.output_size_in_bytes < 14.5 * 2**30
+        assert mem.temp_size_in_bytes < laguna._row_bytes(cfg, 8192)
