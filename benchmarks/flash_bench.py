@@ -13,6 +13,12 @@ paper/sections/evaluation.tex:83-121):
      ``ops/flash_attention.py``'s block rule (PERF.md section 6).
   4. end-to-end classifier sweep: mmBERT-32K-geometry ModernBERT b=1 at
      512..32768 tok vs the MI300X FP16 numbers (evaluation.tex:50-57).
+  5. ``--lengths``, alone: ms per call by the row's REAL length in a
+     bucket of 8192, with the kernel handed the length and without, at
+     the long-prompt guards' calls (causal / windowed / selected, D 64 and
+     128), and one shape's time by the precision of its two products'
+     operands (PERF.md section 6, PR 43) -> chiprun_out/
+     flash_bench_lengths.json.
 
 Results stream into --out (default chiprun_out/flash_bench.json, which the
 chip tool brings back) after every section so an interrupted run still
@@ -266,6 +272,143 @@ def print_block_table(rows):
         print("SWEEP", *key, " ".join(cells))
 
 
+def _time_chain(call, q, *operands):
+    """ms of ``call(q, *operands)``: a ``fori_loop`` of n dependent calls
+    (each one's output is the next one's q) inside one program, over n, so
+    no host dispatch is in it; the best of two."""
+    import jax
+    import jax.numpy as jnp
+
+    fn = jax.jit(lambda n, q, *ops: jax.lax.fori_loop(
+        0, n, lambda _, x: call(x, *ops), q))
+
+    def run(n):
+        t0 = time.perf_counter()
+        fn(jnp.int32(n), q, *operands).block_until_ready()
+        return time.perf_counter() - t0
+
+    run(1)
+    n = int(min(200, max(3, 0.25 / (run(2) / 2))))
+    return round(min(run(n), run(n)) / n * 1e3, 4)
+
+
+LENGTHS_BUCKET = 8192
+LENGTHS_REAL = (2048, 3072, 4096, 5120, 6144, 7168, 8192)
+# (kind, head size, heads): one row of the guards' prefill calls, bfloat16
+# — lfm2's causal call is two rows of 32 heads of 64, laguna's 48 heads of
+# 128 whole and 72 under its window of 512 keys, dots3's selected call 128
+# heads (q/k 192 there; the output chains into q here, so 128)
+LENGTHS_CALLS = (("causal", 64, 64), ("causal", 128, 48),
+                 ("window", 64, 32), ("window", 128, 72),
+                 ("select", 64, 32), ("select", 128, 128))
+
+
+def run_lengths_sweep(report, out_path):
+    """Device ms per kernel call by the row's real length: the kernel
+    handed ``lengths`` against the same call without (which does the
+    bucket's work whatever the mask says), beside the share of tiles
+    ``tiles_for`` says are left."""
+    import jax
+    import jax.numpy as jnp
+
+    from semantic_router_tpu.ops.flash_attention import (
+        flash_attention_pallas,
+        tiles_for,
+    )
+
+    S = LENGTHS_BUCKET
+    rows = []
+    for kind, D, H in LENGTHS_CALLS:
+        window = 2 * 511 if kind == "window" else 0
+        keys = jax.random.split(jax.random.PRNGKey(D + H), 4)
+        q, k, v = (jax.random.normal(key, (1, H, S, D), jnp.bfloat16)
+                   for key in keys[:3])
+        kw = dict(causal=True, window=window)
+        if kind == "select":  # every third key and the token itself
+            kw["select"] = (jax.random.bernoulli(keys[3], 0.33, (1, S, S))
+                            | jnp.eye(S, dtype=bool)[None]).astype(jnp.int8)
+
+        def call(q, k, v, lengths, ragged, kw=kw):
+            mask = (jnp.arange(S)[None, :] < lengths[:, None]).astype(
+                jnp.int32)
+            return flash_attention_pallas(
+                q, k, v, mask, lengths=lengths if ragged else None, **kw)
+
+        for real in LENGTHS_REAL:
+            n = jnp.asarray([real], jnp.int32)
+            visited, grid = tiles_for(S, window, True, [real])
+            row = {"kind": kind, "head_dim": D, "heads": H, "real": real,
+                   "tiles_share": round(visited / grid, 4)}
+            for name, ragged in (("ms_lengths", True), ("ms_plain", False)):
+                if not ragged and real != S:
+                    continue  # the plain call's work is the bucket's
+                row[name] = _time_chain(
+                    lambda x, k, v, n, r=ragged: call(x, k, v, n, r),
+                    q, k, v, n)
+            sys.stderr.write(f"lengths sweep {row}\n")
+            rows.append(row)
+            report["lengths_sweep"] = {"bucket": S, "dtype": "bfloat16",
+                                       "rows": rows}
+            _flush(report, out_path)
+
+
+def run_operand_precision(report, out_path):
+    """One shape (causal, one row of 48 heads of 128 over 8192 columns),
+    three ways: bfloat16 arrays as the guards hand them (the kernel widens
+    them and multiplies float32 operands); the same with the operands of
+    both products rounded to bfloat16 first (float32 accumulation; the
+    kernel as it is, its two product calls wrapped while it is traced);
+    float32 arrays.  The ratio of the first two is what bfloat16 operands
+    would win."""
+    import unittest.mock as mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from semantic_router_tpu.ops.flash_attention import flash_attention_pallas
+
+    S, H, D = LENGTHS_BUCKET, 48, 128
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    q, k, v = (jax.random.normal(key, (1, H, S, D), jnp.bfloat16)
+               for key in keys)
+
+    def call(x, k, v):
+        return flash_attention_pallas(x, k, v, causal=True)
+
+    def rounded(product):
+        def wrapped(a, b, *args, **kwargs):
+            return product(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+                           *args, **kwargs)
+        return wrapped
+
+    out = {"shape": [1, H, S, D], "causal": True,
+           "ms_bf16_arrays": _time_chain(call, q, k, v)}
+    with mock.patch.object(jax.lax, "dot_general",
+                           rounded(jax.lax.dot_general)), \
+            mock.patch.object(jnp, "dot", rounded(jnp.dot)):
+        out["ms_bf16_operands"] = _time_chain(call, q, k, v)
+    out["ms_f32_arrays"] = _time_chain(
+        call, *(t.astype(jnp.float32) for t in (q, k, v)))
+    out["bf16_operands_over_as_served"] = round(
+        out["ms_bf16_operands"] / out["ms_bf16_arrays"], 4)
+    sys.stderr.write(f"operand precision {out}\n")
+    report["operand_precision"] = out
+    _flush(report, out_path)
+
+
+def print_lengths_table(rows):
+    """One stdout line per call: ms with the lengths by real length, the
+    plain call's ms last."""
+    for key in sorted({(r["kind"], r["head_dim"], r["heads"])
+                       for r in rows}):
+        mine = [r for r in rows
+                if (r["kind"], r["head_dim"], r["heads"]) == key]
+        cells = [f"{r['real']}={r['ms_lengths']}({r['tiles_share']})"
+                 for r in mine]
+        print("LENGTHS", *key, " ".join(cells),
+              f"plain={mine[-1].get('ms_plain')}")
+
+
 def run_classifier_sweep(report, out_path, seqs,
                          impls=("flash", "dense")):
     """End-to-end mmBERT-32K-geometry classify latency, b=1, comparing
@@ -341,11 +484,18 @@ def main() -> int:
     ap.add_argument("--cls-seqs", default="512,1024,2048,4096,8192,16384,32768")
     ap.add_argument("--skip", default="",
                     help="comma list: numerics,kernel,blocks,classifier")
+    ap.add_argument("--lengths", action="store_true",
+                    help="only the sweep by a row's real length and the "
+                         "operand-precision timing (section 5 above), "
+                         "into chiprun_out/flash_bench_lengths.json "
+                         "unless --out says otherwise")
     ap.add_argument("--deadline", type=float, default=0.0,
                     help="seconds; on expiry the process flushes partial "
                          "results and os._exit(3)s itself")
     args = ap.parse_args()
     skip = set(args.skip.split(",")) if args.skip else set()
+    if args.lengths and args.out == ap.get_default("out"):
+        args.out = "chiprun_out/flash_bench_lengths.json"
 
     if args.deadline > 0:
         import threading
@@ -370,6 +520,12 @@ def main() -> int:
     _flush(report, args.out)
     sys.stderr.write(f"flash_bench: platform={platform}\n")
 
+    if args.lengths:
+        run_lengths_sweep(report, args.out)
+        run_operand_precision(report, args.out)
+        print(json.dumps(report["operand_precision"]))
+        print_lengths_table(report["lengths_sweep"]["rows"])
+        return 0
     seqs = [int(s) for s in args.seqs.split(",")]
     cls_seqs = [int(s) for s in args.cls_seqs.split(",")]
     if "numerics" not in skip:

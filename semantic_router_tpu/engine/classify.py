@@ -308,7 +308,7 @@ class _GenerationObserver:
     def done(self, load=None, forwards: int = 1, committed_blocks: int = 0,
              committed_tokens: int = 0, cache_bytes=None, keys=None,
              rows_per_group=None, drafted: Optional[int] = None,
-             accepted: int = 0) -> None:
+             accepted: int = 0, attn_tiles=None) -> None:
         """``load [layers, 4]`` of an expert model
         (models.sdar_moe.routed_experts), of a step of several
         ``forwards`` theirs stacked (``[forwards x layers, 4]``); a dense
@@ -328,7 +328,10 @@ class _GenerationObserver:
         itself, the drafts it verified (one a live row and step) and those
         that were right; its ``committed_tokens`` is then the true count,
         one or two a row and step.  A loop's ``load`` and ``keys`` are its
-        steps' stacked."""
+        steps' stacked.  ``attn_tiles = (visited, grid)``: of a prefill
+        whose flash calls are handed the rows' lengths, the tiles they
+        folded and those the bucket's grid folds without
+        (``ops.flash_attention.tiles_for``, over layers and heads)."""
         from ..observability import batchtrace
 
         step = self.step
@@ -341,14 +344,14 @@ class _GenerationObserver:
                 step.group, step.variant, load, keys, rows_per_group,
                 forwards, None if drafted is None else
                 (drafted, accepted, committed_tokens), bucket=self.bucket,
-                cache_bytes=cache_bytes)
+                cache_bytes=cache_bytes, attn_tiles=attn_tiles)
         try:
             self.engine._runtime_stats.record_generation(
                 self.task, step.variant, forwards=forwards,
                 committed_blocks=committed_blocks,
                 committed_tokens=committed_tokens, cache_bytes=cache_bytes,
                 rows_per_group=rows_per_group, drafted=drafted or 0,
-                accepted=accepted)
+                accepted=accepted, attn_tiles=attn_tiles)
         except Exception:
             pass  # observability never fails a generation
 

@@ -76,7 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import causal_tiles, flash_attention
 from ..ops.rope import apply_rotary_front
 from .gated_window import gate_out, ring_of, ring_seen, ring_slot
 from .latent_attention import (
@@ -540,7 +540,8 @@ def _prefill_rows(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
                     with jax.named_scope("core"):
                         out = flash_attention(
                             q, k, v, key_padding_mask=mask, causal=True,
-                            scale=g.scale, select=chosen.astype(jnp.int8))
+                            scale=g.scale, select=chosen.astype(jnp.int8),
+                            lengths=lengths)
                     x = x + _gate_out(cfg, g, p, h, out)
                     latent.append(jnp.pad(lat, pad_to))
                     index.append(jnp.pad(k_i, pad_to))
@@ -553,7 +554,8 @@ def _prefill_rows(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
                     with jax.named_scope("core"):
                         out = flash_attention(
                             q, k, v, key_padding_mask=mask, causal=True,
-                            window=2 * (W - 1), scale=g.scale)
+                            window=2 * (W - 1), scale=g.scale,
+                            lengths=lengths)
                     x = x + _gate_out(cfg, g, p, h, out)
                     with jax.named_scope("kv"):
                         window.append(ring_of(lat, lengths, W))
@@ -776,6 +778,16 @@ class CachedModel:
         """How many rows of such a prefill go through the layers
         together."""
         return prefill_group(self.config, params, rows, bucket, cache_len)
+
+    def attn_tiles(self, lengths, bucket: int):
+        """``(visited, grid)`` of such a prefill's flash calls, which are
+        handed the rows' lengths (``flash_attention.tiles_for``), over its
+        layers of both geometries and their heads."""
+        cfg = self.config
+        return causal_tiles(bucket, lengths, [
+            (cfg.geometry(kind).heads, 0 if kind == "full_attention"
+             else 2 * (cfg.sliding_window_size - 1))
+            for kind in cfg.layer_types])
 
     @staticmethod
     def cache_bytes(cache) -> Dict[str, int]:

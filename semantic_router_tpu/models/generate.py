@@ -384,8 +384,10 @@ class Qwen3Cached:
     an expert model — a dense one gives neither; ``cache_bytes(cache)``
     the cache's bytes by kind of state; ``rows_per_group(params, rows,
     bucket, cache_len)`` the rows of such a prefill that go through the
-    layers together (None: the rows are not mapped).  ``models.lfm2_moe.CachedModel``
-    is the other implementation.
+    layers together (None: the rows are not mapped); ``attn_tiles(lengths,
+    bucket)`` the flash kernel's tiles ``(visited, grid)`` of such a
+    prefill (None: the kernel is not handed the lengths).
+    ``models.lfm2_moe.CachedModel`` is the other implementation.
 
     This cache: K and V ``[B, kv, M, D]`` a layer, prompt tokens in
     columns ``0..S`` (a padding column is masked forever), decode step
@@ -433,6 +435,14 @@ class Qwen3Cached:
     def rows_per_group(params, rows: int, bucket: int, cache_len: int):
         """A model that maps a prefill's rows inside the program says how
         many go through its layers together; this one maps none."""
+        return None
+
+    @staticmethod
+    def attn_tiles(lengths, bucket: int):
+        """A model whose prefill hands its rows' lengths to the flash
+        kernel says what the kernel then folds and what the bucket's grid
+        would (``flash_attention.tiles_for``, over layers and heads); this
+        one's attention is plain ``jnp``."""
         return None
 
     @staticmethod
@@ -707,7 +717,8 @@ class GreedyGenerator:
                  cache_bytes=self.model.cache_bytes(cache),
                  keys=aux.get("keys"),
                  rows_per_group=self.model.rows_per_group(
-                     self.params, B, S, M))
+                     self.params, B, S, M),
+                 attn_tiles=self.model.attn_tiles(lengths, S))
         if not finished.all():
             self._decode(obs, key, (cache, state, positions, task_arr),
                          drafted, lengths, (eos, budget), commit, trajectory,

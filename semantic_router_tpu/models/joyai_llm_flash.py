@@ -462,6 +462,12 @@ class CachedModel:
         return None
 
     @staticmethod
+    def attn_tiles(lengths, bucket: int):
+        """A chat bucket is one block a row: its flash call is handed no
+        lengths."""
+        return None
+
+    @staticmethod
     def cache_bytes(cache) -> Dict[str, int]:
         """The cache's bytes by kind of state."""
         return {k: tree_bytes(cache[k]) for k in ("latent", "draft")

@@ -58,7 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import causal_tiles, flash_attention
 from ..ops.rope import RopeSpec, apply_rotary_front
 from .dots3_note import _head, _on_device, _rows
 from .gated_window import gate_out, ring_of, ring_seen, ring_slot
@@ -408,7 +408,8 @@ def _prefill_rows(cfg: LagunaConfig, params, ids, lengths, cache_len: int):
                     out = flash_attention(
                         jnp.moveaxis(q, 2, 1), jnp.repeat(kc, rep, axis=1),
                         jnp.repeat(vc, rep, axis=1), key_padding_mask=mask,
-                        causal=True, window=0 if whole else 2 * (W - 1))
+                        causal=True, window=0 if whole else 2 * (W - 1),
+                        lengths=lengths)
                 x = x + gate_out(p, h, out, cfg.dtype)
                 if whole:
                     full.append((jnp.pad(kc, pad), jnp.pad(vc, pad)))
@@ -562,6 +563,16 @@ class CachedModel:
         """How many rows of such a prefill go through the layers
         together."""
         return prefill_group(self.config, params, rows, bucket, cache_len)
+
+    def attn_tiles(self, lengths, bucket: int):
+        """``(visited, grid)`` of such a prefill's flash calls, which are
+        handed the rows' lengths (``flash_attention.tiles_for``), over its
+        layers of both kinds and their heads."""
+        cfg = self.config
+        return causal_tiles(bucket, lengths, [
+            (cfg.heads(kind), 0 if kind == "full_attention"
+             else 2 * (cfg.sliding_window - 1))
+            for kind in cfg.layer_types])
 
     @staticmethod
     def cache_bytes(cache) -> Dict[str, int]:

@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import causal_tiles, flash_attention
 from .qwen3 import torch_dtype_of
 from .sdar_moe import (
     NEG_INF,
@@ -356,7 +356,7 @@ def _prefill_rows(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int):
                         jnp.moveaxis(q, 2, 1), jnp.repeat(kc, rep, axis=1),
                         jnp.repeat(vc, rep, axis=1),
                         key_padding_mask=valid.astype(jnp.int32),
-                        causal=True)
+                        causal=True, lengths=lengths)
                     out = jnp.moveaxis(out, 1, 2).reshape(B, S, -1)
                     x = x + out.astype(cfg.dtype) @ p["o_proj"]
                     pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - S))
@@ -595,6 +595,15 @@ class CachedModel:
         """How many rows of such a prefill go through the layers
         together."""
         return prefill_group(self.config, params, rows, bucket, cache_len)
+
+    def attn_tiles(self, lengths, bucket: int):
+        """``(visited, grid)`` of such a prefill's flash calls, which are
+        handed the rows' lengths (``flash_attention.tiles_for``), over its
+        attention layers and their heads."""
+        cfg = self.config
+        return causal_tiles(bucket, lengths, [
+            (cfg.num_attention_heads, 0) for kind in cfg.layer_types
+            if kind != "conv"])
 
     @staticmethod
     def cache_bytes(cache) -> Dict[str, int]:

@@ -318,7 +318,8 @@ def queue_wait(trace_id: str, group: str, wait_s: float,
 
 def gen_forward(group: str, flavour: str, load, keys=None,
                 rows_per_group=None, forwards: int = 1, drafts=None,
-                bucket: Optional[int] = None, cache_bytes=None) -> None:
+                bucket: Optional[int] = None, cache_bytes=None,
+                attn_tiles=None) -> None:
     """``engine.gen.forward``: a step of a generation ended now, and
     this is what only its readback knew: the ``forwards`` the device ran
     in it (a block generator's step is a block's loop) and ``load
@@ -338,7 +339,11 @@ def gen_forward(group: str, flavour: str, load, keys=None,
     ``bucket``: the generation's prompt bucket (two buckets of one task
     alternate in one window).  ``cache_bytes`` of a prefill, by kind of
     state (the model's ``cache_bytes``), adds ``cache_bytes_<kind>`` for
-    each: a whole K/V cache's ``full`` beside a ring's ``window``."""
+    each: a whole K/V cache's ``full`` beside a ring's ``window``.
+    ``attn_tiles = (visited, grid)`` of a prefill whose flash calls are
+    handed the rows' lengths adds ``attn_tiles_visited`` and
+    ``attn_tiles_grid``: the tiles folded and those the bucket's grid
+    folds without the lengths, over layers and heads."""
     facts = {} if keys is None else {
         "keys_selected": int(keys[:, 0].sum(dtype="int64")),
         "keys_visible": int(keys[:, 1].sum(dtype="int64"))}
@@ -348,6 +353,9 @@ def gen_forward(group: str, flavour: str, load, keys=None,
         facts[f"cache_bytes_{kind}"] = int(size)
     if rows_per_group is not None:
         facts["rows_per_group"] = int(rows_per_group)
+    if attn_tiles is not None:
+        facts.update(zip(("attn_tiles_visited", "attn_tiles_grid"),
+                         map(int, attn_tiles)))
     if drafts is not None:
         facts.update(zip(("drafted", "accepted", "committed_tokens"),
                          map(int, drafts)))

@@ -207,6 +207,16 @@ class RuntimeStats:
             "Drafted tokens that were the model's own choice: the step "
             "committed a second token for that row; over "
             "llm_runtime_gen_draft_tokens_total it is the acceptance rate")
+        self.gen_attn_tiles_visited = registry.counter(
+            "llm_runtime_gen_attn_tiles_visited_total",
+            "(Query block, key block) pairs the flash kernel folded in "
+            "prefills that hand it their rows' real lengths, over layers "
+            "and heads")
+        self.gen_attn_tiles_grid = registry.counter(
+            "llm_runtime_gen_attn_tiles_grid_total",
+            "The pairs the padded bucket's grid folds without the lengths "
+            "in those same prefills; visited over grid is the share of "
+            "the kernel's work that padding did not take")
         self.gen_seconds = registry.counter(
             "llm_runtime_gen_seconds_total",
             "Host-clock seconds of finished generations by phase: forward "
@@ -277,7 +287,8 @@ class RuntimeStats:
                           committed_blocks: int = 0,
                           committed_tokens: int = 0,
                           cache_bytes=None, rows_per_group=None,
-                          drafted: int = 0, accepted: int = 0) -> None:
+                          drafted: int = 0, accepted: int = 0,
+                          attn_tiles=None) -> None:
         """One step of a generation (the engine's generative runner: a
         prefill, a token-at-a-time generator's loop of decode steps, or a
         block generator's block): one llm_runtime_gen_programs_total and
@@ -296,7 +307,10 @@ class RuntimeStats:
         llm_runtime_gen_prefill_rows_per_group; ``drafted`` / ``accepted``
         (a self-drafting model's step: its ``committed_tokens`` is one or
         two a row) llm_runtime_gen_draft_tokens_total and
-        llm_runtime_gen_draft_accepted_total."""
+        llm_runtime_gen_draft_accepted_total; ``attn_tiles = (visited,
+        grid)`` (a prefill's whose flash calls get its rows' lengths)
+        llm_runtime_gen_attn_tiles_visited_total and
+        llm_runtime_gen_attn_tiles_grid_total."""
         if not self.enabled:
             return
         self.gen_programs.inc(task=task, flavour=flavour)
@@ -317,6 +331,9 @@ class RuntimeStats:
             self.gen_drafts.inc(drafted, task=task)
         if accepted:
             self.gen_drafts_accepted.inc(accepted, task=task)
+        if attn_tiles is not None:
+            self.gen_attn_tiles_visited.inc(attn_tiles[0], task=task)
+            self.gen_attn_tiles_grid.inc(attn_tiles[1], task=task)
 
     def record_generation_done(self, task: str,
                                seconds: Dict[str, float]) -> None:

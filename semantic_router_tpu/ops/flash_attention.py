@@ -17,7 +17,13 @@ with the K axis innermost.  K and V stream through VMEM one
 K+V would not fit v5e's 16 MiB scoped VMEM); the online-softmax state
 (m/l/acc, fp32) lives in VMEM scratch across the K steps of one query
 block.  Padding arrives as a per-(B) additive key bias [B, 1, Sp],
-indexed by bh // H.
+indexed by bh // H.  A caller that knows its rows are RIGHT-padded hands
+their real ``lengths [B]`` too (their ``_row_ends``, by row and head, are
+a scalar-prefetch operand that the index maps and the kernel read at
+``bh``): the grid stays the bucket's, but a query block past its row's
+end and a K block past it are never folded nor copied in — ``tiles_for``
+counts what that leaves.  Without ``lengths`` the call is the program it
+was before they existed.
 
 Blocks: ``blocks_for`` picks (block_q, block_k) from the call's padded
 sequence length and its window — constants of this module, each from a
@@ -36,6 +42,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -82,34 +89,101 @@ def blocks_for(seq_len: int, window: int = 0) -> tuple:
     return fit(want_q), fit(want_k)
 
 
-def _kv_start(qi, *, block_q: int, block_k: int, window: int):
+def _kv_start(qi, *, block_q: int, block_k: int, window: int, xp=jnp):
     """First K block a query block attends to (0 unless windowed)."""
     if window > 0:
-        return jnp.maximum(qi * block_q - window // 2, 0) // block_k
+        return xp.maximum(qi * block_q - window // 2, 0) // block_k
     return 0
 
 
+def _row_ends(lengths, *, block_q: int, block_k: int, xp=jnp):
+    """Of right-padded rows of ``lengths`` real tokens: ``(the last query
+    block that holds one, one past the last K block that does)``."""
+    return (xp.maximum(lengths - 1, 0) // block_q,
+            (lengths + block_k - 1) // block_k)
+
+
 def _kv_stop(qi, *, block_q: int, block_k: int, n_kb: int, window: int,
-             causal: bool):
+             causal: bool, ends=None, xp=jnp):
     """One past the last K block a query block attends to.  (Block-causal
     calls need no rule of their own: ``block_q`` is a multiple of
     ``causal_block``, so a query block's last position ends its group.)
-    A causal call's band ends at the diagonal, windowed or not."""
+    A causal call's band ends at the diagonal, windowed or not.
+    ``ends``: the ``_row_ends`` of the query block's row — no K block past
+    its real tokens, and none at all for a query block past them."""
     last_q = qi * block_q + block_q - 1
     if causal:
-        return last_q // block_k + 1
-    if window > 0:
-        return jnp.minimum((last_q + window // 2) // block_k + 1, n_kb)
-    return n_kb
+        stop = last_q // block_k + 1
+    elif window > 0:
+        stop = xp.minimum((last_q + window // 2) // block_k + 1, n_kb)
+    else:
+        stop = n_kb
+    if ends is None:
+        return stop
+    q_last, kv_end = ends  # (an empty row's kv_end is 0)
+    return xp.where(qi <= q_last, xp.minimum(stop, kv_end), 0)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, scale: float,
-                  block_q: int, block_k: int, n_kb: int, window: int,
-                  causal: bool, causal_block: int, selected: bool):
+def _kv_steps(n_kb: int, *, block_q: int, block_k: int, window: int,
+              causal: bool) -> int:
+    """K steps per query block (the grid's innermost extent): every K
+    block when global/causal, only the blocks one window can straddle
+    when windowed."""
+    if window <= 0:
+        return n_kb
+    return min(n_kb, (block_q - 1 + (1 if causal else 2) * (window // 2))
+               // block_k + 2)
+
+
+def tiles_for(seq_len: int, window: int, causal: bool, lengths) -> tuple:
+    """``(visited, grid)`` of a call at the rule's blocks over right-padded
+    rows of ``seq_len`` positions: the (query block, K block) pairs a head
+    folds when the call is handed the rows' real ``lengths``, summed over
+    the rows, and those it folds without them (what the bucket's grid
+    makes of rows taken as full).  On the host, by the kernel's own
+    ``_kv_start`` / ``_kv_stop``."""
+    block_q, block_k = blocks_for(seq_len, window)
+    padded = -(-seq_len // math.lcm(block_q, block_k)) \
+        * math.lcm(block_q, block_k)
+    geom = dict(block_q=block_q, block_k=block_k, window=window, xp=np)
+    n_kb = padded // block_k
+    steps = _kv_steps(n_kb, block_q=block_q, block_k=block_k, window=window,
+                      causal=causal)
+    qi = np.arange(padded // block_q)[None, :]
+
+    def folds(lengths) -> int:
+        stop = _kv_stop(qi, n_kb=n_kb, causal=causal, **geom,
+                        ends=_row_ends(lengths, block_q=block_q,
+                                       block_k=block_k, xp=np))
+        return int(np.clip(stop - _kv_start(qi, **geom), 0, steps).sum())
+
+    lengths = np.asarray(lengths, np.int64).reshape(-1, 1)
+    return folds(lengths), lengths.size * folds(np.int64(padded))
+
+
+def causal_tiles(seq_len: int, lengths, layers) -> tuple:
+    """``tiles_for`` summed over a prefill's causal attention layers, each
+    ``(heads, window)``: the tiles of a head times the layer's heads."""
+    tiles = [[heads * t for t in tiles_for(seq_len, window, True, lengths)]
+             for heads, window in layers]
+    return tuple(map(sum, zip(*tiles)))
+
+
+def _flash_kernel(*refs, scale: float, block_q: int, block_k: int,
+                  n_kb: int, window: int, causal: bool, causal_block: int,
+                  selected: bool, ragged: bool = False):
     """One (bh, q-block, kv-step) program: fold one K/V block into the
     query block's online-softmax state.  ``selected``: a
     ``(block_q, block_k)`` block of the per-query selection comes before
-    the output (0 = this query does not see this key)."""
+    the output (0 = this query does not see this key).  ``ragged``: the
+    call was handed its rows' lengths, whose ``_row_ends [2, BH]`` then
+    come first (scalar prefetch); a query block past its row's end folds
+    nothing and writes zeros."""
+    ends = None
+    if ragged:
+        ends = (refs[0][0, pl.program_id(0)], refs[0][1, pl.program_id(0)])
+        refs = refs[1:]
+    q_ref, k_ref, v_ref, bias_ref, *rest = refs
     sel_ref = rest[0] if selected else None
     o_ref, m_ref, l_ref, acc_ref = rest[-4:]
     qi = pl.program_id(1)
@@ -117,7 +191,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, scale: float,
     kb = _kv_start(qi, block_q=block_q, block_k=block_k,
                    window=window) + j
     stop = _kv_stop(qi, block_q=block_q, block_k=block_k, n_kb=n_kb,
-                    window=window, causal=causal)
+                    window=window, causal=causal, ends=ends)
 
     @pl.when(j == 0)
     def _init():
@@ -173,7 +247,8 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            block_k: Optional[int] = None,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
-                           select: Optional[jnp.ndarray] = None
+                           select: Optional[jnp.ndarray] = None,
+                           lengths: Optional[jnp.ndarray] = None
                            ) -> jnp.ndarray:
     """q/k: [B, H, S, D], v: [B, H, S, Dv] (the output's head size; a
     latent-attention layer's differs from D); key_padding_mask: [B, S]
@@ -186,6 +261,11 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     on top of every other rule.
     ``causal_block``: with ``causal``, key j is visible to query i iff
     ``j // causal_block <= i // causal_block`` (1 = plain causal).
+    ``lengths``: [B] int32, the real tokens of each RIGHT-padded row (what
+    ``key_padding_mask``, which still goes, says position by position): a
+    query block past a row's end is not folded and comes back as zeros, a
+    K block past it is never visited; every real position's output is bit
+    for bit the call's without them.
     ``block_q`` / ``block_k``: None = the shape's own (``blocks_for``).
     ``interpret``: None = the Pallas interpreter on a CPU platform (so the
     same call site runs in tests), the compiled kernel everywhere else."""
@@ -229,54 +309,79 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     bias = bias[:, None, :]
 
     n_kb = Sp // block_k
-    # K steps per query block: every K block when global/causal, only the
-    # blocks one window can straddle when windowed
-    n_kv = n_kb if window <= 0 else min(
-        n_kb, (block_q - 1 + (1 if causal else 2) * (window // 2))
-        // block_k + 2)
     geom = dict(block_q=block_q, block_k=block_k, window=window)
-
-    def kv_block(qi, j):
-        # steps past the query block's last K block re-name that block:
-        # Pallas skips the copy when the block index does not change
-        last = _kv_stop(qi, n_kb=n_kb, causal=causal, **geom) - 1
-        return jnp.minimum(_kv_start(qi, **geom) + j, last)
-
+    n_kv = _kv_steps(n_kb, causal=causal, **geom)
     kernel = functools.partial(
         _flash_kernel, scale=scale, n_kb=n_kb, causal=causal,
-        causal_block=causal_block, selected=select is not None, **geom)
+        causal_block=causal_block, selected=select is not None,
+        ragged=lengths is not None, **geom)
+
+    # index maps: (bh, qi, j), then the prefetched row ends when the call
+    # has them
+    def q_block(bh, qi, *ends):
+        # a query block past its row's end re-names the last real one
+        # (the OUTPUT's block is always its own: it is written, as zeros)
+        return jnp.minimum(qi, ends[0][0, bh]) if ends else qi
+
+    def kv_block(bh, qi, j, *ends):
+        # steps past the query block's last K block re-name that block:
+        # Pallas skips the copy when the block index does not change.
+        # Every step of a query block past its row's end re-names the
+        # last K block of the last real one: nothing is copied in for it
+        row = (ends[0][0, bh], ends[0][1, bh]) if ends else None
+        real = jnp.minimum(qi, row[0]) if ends else qi
+        last = _kv_stop(real, n_kb=n_kb, causal=causal, ends=row,
+                        **geom) - 1
+        at = jnp.minimum(_kv_start(real, **geom) + j, last)
+        if not ends:
+            return at
+        return jnp.maximum(jnp.where(qi == real, at, last), 0)
 
     operands = [qf, kf, vf, bias]
     in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda bh, qi, j: (bh, qi, 0)),
+        pl.BlockSpec((1, block_q, D),
+                     lambda bh, qi, j, *ends: (bh, q_block(bh, qi, *ends),
+                                               0)),
         pl.BlockSpec((1, block_k, D),
-                     lambda bh, qi, j: (bh, kv_block(qi, j), 0)),
+                     lambda bh, qi, j, *ends: (
+                         bh, kv_block(bh, qi, j, *ends), 0)),
         pl.BlockSpec((1, block_k, Dv),
-                     lambda bh, qi, j: (bh, kv_block(qi, j), 0)),
+                     lambda bh, qi, j, *ends: (
+                         bh, kv_block(bh, qi, j, *ends), 0)),
         pl.BlockSpec((1, 1, block_k),
-                     lambda bh, qi, j: (bh // H, 0, kv_block(qi, j))),
+                     lambda bh, qi, j, *ends: (
+                         bh // H, 0, kv_block(bh, qi, j, *ends))),
     ]
     if select is not None:
         operands.append(select.astype(jnp.int8))
         in_specs.append(pl.BlockSpec(
             (1, block_q, block_k),
-            lambda bh, qi, j: (bh // H, qi, kv_block(qi, j))))
-
-    out = pl.pallas_call(
-        kernel,
+            lambda bh, qi, j, *ends: (bh // H, q_block(bh, qi, *ends),
+                                      kv_block(bh, qi, j, *ends))))
+    grid = dict(
         grid=(BH, Sp // block_q, n_kv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, Dv),
-                               lambda bh, qi, j: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Sp, Dv), q.dtype),
+                               lambda bh, qi, j, *ends: (bh, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, Dv), jnp.float32),
-        ],
+        ])
+    if lengths is not None:
+        # per (row, head), so that no map divides by H at every grid step
+        operands.insert(0, jnp.repeat(jnp.stack(_row_ends(
+            lengths.astype(jnp.int32), block_q=block_q, block_k=block_k)),
+            H, axis=1))
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **grid))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((BH, Sp, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        **grid,
     )(*operands)
     return out.reshape(B, H, Sp, Dv)[:, :, :S, :]
 
@@ -293,13 +398,14 @@ def flash_attention_sharded(q: jnp.ndarray, k: jnp.ndarray,
                             v: jnp.ndarray, key_padding_mask: jnp.ndarray,
                             mesh, batch_axis: str = "dp",
                             head_axis: Optional[str] = "tp",
+                            lengths: Optional[jnp.ndarray] = None,
                             **kernel_kw) -> jnp.ndarray:
     """The kernel under a serving mesh.  GSPMD cannot partition a Mosaic
     kernel ("wrap the call in a shard_map"), so each (batch, head) shard
     runs it on its own rows and heads — attention needs no collective
     across either axis.  B must divide by mesh[batch_axis]; heads shard
     over ``head_axis`` only when it divides H (else every tensor rank
-    computes all heads)."""
+    computes all heads); ``lengths [B]`` shard with the rows."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -308,11 +414,16 @@ def flash_attention_sharded(q: jnp.ndarray, k: jnp.ndarray,
                            and q.shape[1] % mesh.shape[head_axis] == 0) \
         else None
     qspec = P(batch_axis, h_axis, None, None)
+    operands, specs = [key_padding_mask], [P(batch_axis, None)]
+    if lengths is not None:
+        operands.append(lengths)
+        specs.append(P(batch_axis))
     fn = shard_map(
-        lambda q, k, v, m: flash_attention_pallas(q, k, v, m, **kernel_kw),
-        mesh=mesh, in_specs=(qspec, qspec, qspec, P(batch_axis, None)),
+        lambda q, k, v, m, *lens: flash_attention_pallas(
+            q, k, v, m, lengths=lens[0] if lens else None, **kernel_kw),
+        mesh=mesh, in_specs=(qspec, qspec, qspec, *specs),
         out_specs=qspec, check_vma=False)
-    return fn(q, k, v, key_padding_mask)
+    return fn(q, k, v, *operands)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -322,17 +433,19 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     scale: Optional[float] = None, mesh=None,
                     batch_axis: str = "dp",
                     head_axis: Optional[str] = "tp",
-                    select: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                    select: Optional[jnp.ndarray] = None,
+                    lengths: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Dispatch: the Pallas kernel on a TPU platform (per shard when the
     model serves under ``mesh``); the chunked JAX path on CPU.  ``select``
     (``flash_attention_pallas`` says what it is) goes with ``causal`` and
-    no mesh."""
+    no mesh.  ``lengths`` (there too) bound the kernel's work; the CPU
+    paths need none, the mask says the same."""
     if select is not None and (mesh is not None or not causal):
         raise ValueError("a per-query selection is served for causal "
                          "calls without a mesh")
     if _platform_of(q) == "tpu":
         kw = dict(window=window, causal=causal, causal_block=causal_block,
-                  scale=scale)
+                  scale=scale, lengths=lengths)
         if mesh is None:
             if select is not None:
                 kw["select"] = select

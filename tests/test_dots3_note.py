@@ -500,6 +500,63 @@ def test_the_loop_serves_the_decoder_with_its_trajectory(toy):
         assert traj[0]["selected_at"].shape == (M.SELECT_SAMPLE,)
 
 
+def test_two_rows_a_group_end_at_their_own_lengths_in_the_kernel(
+        toy, monkeypatch):
+    """Both cores hand the rows' lengths to the flash kernel (here the
+    kernel itself, interpreted, at blocks of 128 so that a bucket of 384 is
+    three): two rows of one group — the kernel finds a row's length, and
+    the selection's block, at ``bh // H`` — each stop at their own end and
+    still give the reference's logits; what the kernel folded and what the
+    bucket's grid folds without the lengths reach the observer and the
+    marker."""
+    import contextlib
+    import unittest.mock as mock
+
+    from semantic_router_tpu.observability import batchtrace
+    from semantic_router_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    monkeypatch.setattr(fa, "GLOBAL_BLOCKS", (128, 128))
+    monkeypatch.setattr(fa, "WINDOW_BLOCKS", (128, 128))
+    hf, state, cfg, _ = toy
+    rows = prompts(31, (130, 300))
+    seen = per_step_loop.Steps()
+    with mock.patch.object(fa, "flash_attention_pallas",
+                           wraps=fa.flash_attention_pallas) as kernel:
+        out = generator(toy).generate([words(r) for r in rows], 2,
+                                      bucket=384, observer=seen)
+    calls = [c.kwargs for c in kernel.call_args_list]
+    assert all(c["lengths"].shape == (2,) for c in calls)
+    assert [c["window"] for c in calls] == [0, 0, 8, 8, 0]
+    assert ["select" in c for c in calls] == [True, True, False, False,
+                                              True]
+    for row, res in zip(rows, out):
+        e = res.trajectory[0]
+        z = reference(hf, state, row, [len(row) - 1])["logits"][0]
+        assert int(z.argmax()) == e["token"] \
+            or z.max() - z[e["token"]] < 1e-3
+        np.testing.assert_allclose(e["top_logits"], z[e["top_ids"]],
+                                   atol=ATOL)
+    # whole and causal as lfm2's; under the window of 5 a query block of
+    # 128 folds its own K block and, past the first, the one before
+    assert fa.tiles_for(384, 0, True, [130, 300]) == (9, 12)
+    assert fa.tiles_for(384, 8, True, [130, 300]) == (3 + 5, 2 * 5)
+    prefill, loop = seen.closed
+    tiles = (3 * 4 * 9 + 2 * 2 * 8, 3 * 4 * 12 + 2 * 2 * 10)
+    assert prefill["attn_tiles"] == tiles and "attn_tiles" not in loop
+    facts = {}
+
+    def span(name, **kw):
+        facts.update(kw)
+        return contextlib.nullcontext()
+
+    with mock.patch.object(batchtrace, "trace_span", span):
+        batchtrace.gen_forward("gen:t", "gen.prefill", prefill["load"],
+                               prefill["keys"],
+                               attn_tiles=prefill["attn_tiles"])
+    assert (facts["attn_tiles_visited"], facts["attn_tiles_grid"]) == tiles
+
+
 def test_a_program_reports_its_keys_to_the_observer(toy):
     """``keys [rows, 2]`` reaches ``done`` with the prefill, and with the
     loop its steps' stacked (``[steps x rows, 2]``); the marker sums them

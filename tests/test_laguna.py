@@ -614,6 +614,57 @@ def test_the_loop_serves_the_decoder_with_its_trajectory(toy):
         assert "selected" not in traj[0]
 
 
+def test_two_rows_a_group_end_at_their_own_lengths_in_the_kernel(
+        toy, monkeypatch):
+    """Full and sliding layers hand the rows' lengths to the flash kernel
+    (here the kernel itself, interpreted, at blocks of 128 so that a bucket
+    of 384 is three): two rows of one group — the kernel finds a row's
+    length at ``bh // H``, at 4 heads a row in a full layer and 6 in a
+    sliding one — each stop at their own end and still give the
+    reference's logits; what the kernel folded and what the bucket's grid
+    folds without the lengths reach the observer and the marker."""
+    from semantic_router_tpu.observability import batchtrace
+    from semantic_router_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    monkeypatch.setattr(fa, "GLOBAL_BLOCKS", (128, 128))
+    monkeypatch.setattr(fa, "WINDOW_BLOCKS", (128, 128))
+    hf, state, cfg, _ = toy
+    rows = prompts(31, (130, 300))
+    seen = per_step_loop.Steps()
+    with mock.patch.object(fa, "flash_attention_pallas",
+                           wraps=fa.flash_attention_pallas) as kernel:
+        out = generator(toy).generate([words(r) for r in rows], 2,
+                                      bucket=384, observer=seen)
+    calls = [c.kwargs for c in kernel.call_args_list]
+    assert all(c["lengths"].shape == (2,) for c in calls)
+    assert [c["window"] for c in calls] == [0, 14, 14, 14, 0]
+    for row, res in zip(rows, out):
+        e = res.trajectory[0]
+        z = reference(hf, state, row, [len(row) - 1])["logits"][0]
+        assert int(z.argmax()) == e["token"] \
+            or z.max() - z[e["token"]] < 1e-3
+        np.testing.assert_allclose(e["top_logits"], z[e["top_ids"]],
+                                   atol=ATOL)
+    assert fa.tiles_for(384, 0, True, [130, 300]) == (9, 12)
+    assert fa.tiles_for(384, 14, True, [130, 300]) == (3 + 5, 2 * 5)
+    heads = [cfg.heads(kind) for kind in cfg.layer_types]
+    assert heads == [4, 6, 6, 6, 4]
+    prefill, loop = seen.closed
+    tiles = (2 * 4 * 9 + 3 * 6 * 8, 2 * 4 * 12 + 3 * 6 * 10)
+    assert prefill["attn_tiles"] == tiles and "attn_tiles" not in loop
+    facts = {}
+
+    def span(name, **kw):
+        facts.update(kw)
+        return contextlib.nullcontext()
+
+    with mock.patch.object(batchtrace, "trace_span", span):
+        batchtrace.gen_forward("gen:t", "gen.prefill", prefill["load"],
+                               attn_tiles=prefill["attn_tiles"])
+    assert (facts["attn_tiles_visited"], facts["attn_tiles_grid"]) == tiles
+
+
 def test_a_prefill_reports_both_kinds_of_cache_to_the_observer(toy):
     """``cache_bytes`` by kind reaches ``done`` with the prefill; the
     marker carries each kind and the generation's ``bucket``

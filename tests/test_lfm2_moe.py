@@ -13,6 +13,7 @@ chip."""
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import json
@@ -505,6 +506,56 @@ def test_the_loop_serves_the_hybrid_decoder_with_its_trajectory(toy):
                 e["lse"], jax.nn.logsumexp(jnp.asarray(z)), atol=ATOL)
 
 
+def test_two_rows_a_group_end_at_their_own_lengths_in_the_kernel(
+        toy, monkeypatch):
+    """A prefill hands its rows' lengths to the flash kernel (here the
+    kernel itself, interpreted, at blocks of 128 so that a bucket of 384 is
+    three): two rows of one group — the kernel finds a row's length at
+    ``bh // H`` — each stop at their own end and still give the
+    reference's logits; what the kernel folded and what the bucket's grid
+    folds without the lengths reach the observer and the marker."""
+    import unittest.mock as mock
+
+    from semantic_router_tpu.observability import batchtrace
+    from semantic_router_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    monkeypatch.setattr(fa, "GLOBAL_BLOCKS", (128, 128))
+    model, state, cfg, _ = toy
+    rows = prompts(31, (130, 300))
+    seen = per_step_loop.Steps()
+    with mock.patch.object(fa, "flash_attention_pallas",
+                           wraps=fa.flash_attention_pallas) as kernel:
+        out = generator(toy).generate([words(r) for r in rows], 2,
+                                      bucket=384, observer=seen)
+    assert kernel.call_count == 2  # the attention layers, both rows in each
+    assert all(c.kwargs["lengths"].shape == (2,)
+               for c in kernel.call_args_list)
+    for row, res in zip(rows, out):
+        e = res.trajectory[0]
+        z = ref.forward(model, state, row, [len(row) - 1])["logits"][0]
+        assert e["token"] == z.argmax()
+        np.testing.assert_allclose(e["top_logits"], z[e["top_ids"]],
+                                   atol=ATOL)
+    # 130 tokens: 2 query blocks of 128, 1 + 2 folds; 300: 1 + 2 + 3; the
+    # grid's 2 x 6; two attention layers of 4 heads
+    assert fa.tiles_for(384, 0, True, [130, 300]) == (9, 12)
+    prefill, loop = seen.closed
+    assert prefill["attn_tiles"] == (8 * 9, 8 * 12)
+    assert "attn_tiles" not in loop
+    facts = {}
+
+    def span(name, **kw):
+        facts.update(kw)
+        return contextlib.nullcontext()
+
+    with mock.patch.object(batchtrace, "trace_span", span):
+        batchtrace.gen_forward("gen:t", "gen.prefill", prefill["load"],
+                               attn_tiles=prefill["attn_tiles"])
+    assert (facts["attn_tiles_visited"], facts["attn_tiles_grid"]) \
+        == (72, 96)
+
+
 def test_the_loop_reads_back_small_reports_not_the_vocabulary(toy):
     gen = generator(toy)
     gen.generate([words(prompts(10, (5,))[0])], max_new_tokens=3)
@@ -646,7 +697,12 @@ def test_a_token_at_a_time_generation_is_two_programs(engine, seen):
     assert [m["flavour"] for m in marks] == [s["flavour"] for s in steps]
     # a prefill's also says its cache by kind of state
     assert set(marks[0]) == mark_facts | {
-        "rows_per_group", "cache_bytes_kv", "cache_bytes_conv"}
+        "rows_per_group", "cache_bytes_kv", "cache_bytes_conv",
+        "attn_tiles_visited", "attn_tiles_grid"}
+    # one row of 7 tokens in a bucket of one block: 2 layers x 4 heads
+    assert marks[0]["attn_tiles_visited"] == marks[0]["attn_tiles_grid"] == 8
+    assert rs.gen_attn_tiles_grid.get(task="guard") \
+        == rs.gen_attn_tiles_visited.get(task="guard") > 0
     assert {m["bucket"] for m in marks} == {steps[0]["bucket"]}
     assert set(marks[1]) == mark_facts
     assert [(m["forwards"], m["layers"]) for m in marks] == [(1, 4), (5, 20)]

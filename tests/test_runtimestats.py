@@ -72,6 +72,30 @@ class TestProgramRegistry:
         assert "llm_runtime_program_compiles_total" in text
         assert "llm_runtime_step_rows_total" in text
 
+    def test_a_prefills_attention_tiles_are_counted(self):
+        """``attn_tiles = (visited, grid)`` of a prefill whose flash calls
+        get its rows' lengths: two counters beside the forwards'; a
+        program that hands none (a decode loop, a chat guard's prefill)
+        adds to neither."""
+        reg = MetricsRegistry()
+        rs = RuntimeStats(reg)
+        rs.record_generation("t", "gen.prefill", attn_tiles=(21, 36))
+        rs.record_generation("t", "gen.prefill", attn_tiles=(0, 36))
+        rs.record_generation("t", "gen.decode", forwards=31)
+        rs.record_generation("u", "gen.prefill")
+        assert rs.gen_attn_tiles_visited.get(task="t") == 21
+        assert rs.gen_attn_tiles_grid.get(task="t") == 72
+        assert rs.gen_attn_tiles_grid.get(task="u") == 0
+        assert rs.gen_forwards.get(task="t", flavour="gen.prefill") == 2
+        text = reg.expose()
+        assert 'llm_runtime_gen_attn_tiles_visited_total{task="t"} 21' \
+            in text
+        assert 'llm_runtime_gen_attn_tiles_grid_total{task="t"} 72' in text
+        disabled = RuntimeStats(MetricsRegistry())
+        disabled.enabled = False
+        disabled.record_generation("t", "gen.prefill", attn_tiles=(1, 2))
+        assert disabled.gen_attn_tiles_grid.get(task="t") == 0
+
 
 class TestProcessGauges:
     def test_rss_and_threads(self):

@@ -667,18 +667,19 @@ class TestHybridGuardCompilesForV5e:
     vocabulary 65536), bucket 8192."""
 
     def test_causal_prefill_attention(self, one_chip):
-        """Head size 64, bfloat16, one row's 32 heads over 8192 columns
-        under the plain causal mask: what a mapped prefill row runs."""
+        """Head size 64, bfloat16, a group's two rows of 32 heads over
+        8192 columns under the plain causal mask, the rows' lengths a
+        scalar-prefetch operand: what a mapped prefill group runs."""
         from semantic_router_tpu.ops.flash_attention import (
             flash_attention_pallas,
         )
 
-        qkv = ((1, 32, 8192, 64), jnp.bfloat16)
+        qkv = ((2, 32, 8192, 64), jnp.bfloat16)
         compiled = compile_for(
             one_chip,
-            functools.partial(flash_attention_pallas, causal=True,
-                              interpret=False),
-            qkv, qkv, qkv, ((1, 8192), jnp.int32))
+            lambda q, k, v, m, n: flash_attention_pallas(
+                q, k, v, m, causal=True, interpret=False, lengths=n),
+            qkv, qkv, qkv, ((2, 8192), jnp.int32), ((2,), jnp.int32))
         assert "tpu_custom_call" in compiled.as_text()
 
     @pytest.mark.parametrize("tokens", [8, 8192])
@@ -779,7 +780,8 @@ class TestSparseLatentGuardCompilesForV5e:
     def test_prefill_attention_cores(self, one_chip, kind):
         """One row's heads over 8192 columns, bfloat16, v's head size
         apart from q/k's: causal under the int8 selection ``[S, S]``, and
-        causal with the window on the Pallas path."""
+        causal with the window on the Pallas path; the row's length a
+        scalar-prefetch operand, as the prefill hands it."""
         from semantic_router_tpu.ops.flash_attention import (
             flash_attention_pallas,
         )
@@ -787,19 +789,21 @@ class TestSparseLatentGuardCompilesForV5e:
         heads, d = (128, 192) if kind == "full" else (64, 256)
         qk, v = ((1, heads, 8192, d), jnp.bfloat16), \
             ((1, heads, 8192, 128), jnp.bfloat16)
-        mask = ((1, 8192), jnp.int32)
+        mask, lengths = ((1, 8192), jnp.int32), ((1,), jnp.int32)
         if kind == "full":
             compiled = compile_for(
                 one_chip,
-                lambda q, k, v, m, s: flash_attention_pallas(
-                    q, k, v, m, causal=True, select=s, interpret=False),
-                qk, qk, v, mask, ((1, 8192, 8192), jnp.int8))
+                lambda q, k, v, m, n, s: flash_attention_pallas(
+                    q, k, v, m, causal=True, select=s, interpret=False,
+                    lengths=n),
+                qk, qk, v, mask, lengths, ((1, 8192, 8192), jnp.int8))
         else:
             compiled = compile_for(
                 one_chip,
-                functools.partial(flash_attention_pallas, causal=True,
-                                  window=2 * 512, interpret=False),
-                qk, qk, v, mask)
+                lambda q, k, v, m, n: flash_attention_pallas(
+                    q, k, v, m, causal=True, window=2 * 512,
+                    interpret=False, lengths=n),
+                qk, qk, v, mask, lengths)
         text = compiled.as_text()
         assert "tpu_custom_call" in text
         assert f"bf16[{heads},8192,128]" in text  # the output is v's size
@@ -1009,7 +1013,8 @@ class TestWindowAndFullGuardCompilesForV5e:
         blocks ``blocks_for`` picks from the shape (measured at D 64):
         causal and whole at 1024 x 1024, causal under the window of 512
         keys at 256 x 512 — both within the kernel's scoped memory at D
-        128, so the rule needs no head width."""
+        128, so the rule needs no head width; the row's length a
+        scalar-prefetch operand, as the prefill hands it."""
         from semantic_router_tpu.ops.flash_attention import (
             blocks_for,
             flash_attention_pallas,
@@ -1021,9 +1026,10 @@ class TestWindowAndFullGuardCompilesForV5e:
         qkv = ((1, heads, 8192, 128), jnp.bfloat16)
         compiled = compile_for(
             one_chip,
-            functools.partial(flash_attention_pallas, causal=True,
-                              window=window, interpret=False),
-            qkv, qkv, qkv, ((1, 8192), jnp.int32))
+            lambda q, k, v, m, n: flash_attention_pallas(
+                q, k, v, m, causal=True, window=window, interpret=False,
+                lengths=n),
+            qkv, qkv, qkv, ((1, 8192), jnp.int32), ((1,), jnp.int32))
         text = compiled.as_text()
         assert "tpu_custom_call" in text
         assert f"bf16[{heads},8192,128]" in text
