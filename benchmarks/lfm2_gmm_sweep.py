@@ -2,7 +2,7 @@
 matrices (64 experts, ``[2048, 3072]`` gate+up and ``[1536, 2048]`` down,
 bfloat16), at the two regimes the cell runs: a prefill row (8192 tokens
 top-4 = 32768 sorted pairs of which about 17,600 are real) and a decode
-forward of 8 rows (32 pairs).  The table behind ``models/sdar_moe.py``
+forward of 8 rows (32 pairs).  The table behind ``models/experts.py``
 ``_megablox``'s tiling for these widths (PERF.md section 6, PR 32);
 ``benchmarks/moe_gmm_bench.py`` is the same for the sdar_moe widths.
 

@@ -6,7 +6,7 @@
 whole prefill's ms, the ``moe/gmm`` scope's device ms a row-layer (one
 traced call, read as the benchmark's ``ar_moe_gmm_roofline`` reads it),
 the compiler's temporaries and the allocator's peak: the table behind
-``lfm2_moe.rows_per_group`` (PERF.md section 6, PR 37;
+``mapped_prefill.rows_per_group`` (PERF.md section 6, PR 37;
 ``benchmarks/results/lfm2_prefill_groups.json``).  A group that does not
 fit the device is recorded as such.  Then the two ways to bound a prefill
 in tokens that PR 32 chose between: rows mapped INSIDE one program
@@ -221,8 +221,8 @@ def main() -> int:
     results = {"rows": B, "lengths": lengths.tolist(),
                "parameters": n_params, "device_kind": dev.device_kind,
                "bytes_limit": (dev.memory_stats() or {}).get("bytes_limit"),
-               "rule_rows_per_group": M.prefill_group(cfg, params, B, S,
-                                                      cache_len),
+               "rule_rows_per_group": M.CachedModel(cfg).rows_per_group(
+                   params, B, S, cache_len),
                "groups": []}
     print(f"{dev.device_kind}: bytes_limit {results['bytes_limit']}, the "
           f"rule gives {results['rule_rows_per_group']} rows a group",

@@ -8,7 +8,7 @@ matrices are read for a handful of rows, memory-bound) and a prefill of
 16 x 512 tokens of which a third is real (about 22,000 pairs, compute-
 bound).  Two implementations: XLA's own ``jax.lax.ragged_dot`` and the
 Pallas ``megablox`` kernel at a few tilings.  The table behind
-``models/sdar_moe.py`` ``_grouped_matmul``'s choice (PERF.md section 6, PR 28).
+``models/experts.py`` ``_grouped_matmul``'s choice (PERF.md section 6, PR 28).
 
     python benchmarks/moe_gmm_bench.py [--out chiprun_out/moe_gmm_bench.json]
 
@@ -37,6 +37,7 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
+    from semantic_router_tpu.models import experts
     from semantic_router_tpu.models import sdar_moe as M
 
     dev = jax.devices()[0]
@@ -87,7 +88,7 @@ def main() -> int:
                                     preferred_element_type=lhs.dtype,
                                     tiling=t)
             fn = jax.jit(lambda p, x, v: M.moe(cfg, p, x, v))
-            real_gmm, M._grouped_matmul = M._grouped_matmul, gmm
+            real_gmm, experts._grouped_matmul = experts._grouped_matmul, gmm
             try:
                 t0 = time.perf_counter()
                 y, _, load = jax.block_until_ready(fn(p, x, valid))
@@ -113,7 +114,7 @@ def main() -> int:
                 row = {"shape": shape_name, "impl": impl, "tiling": tiling,
                        "error": f"{type(exc).__name__}: {exc}"[:300]}
             finally:
-                M._grouped_matmul = real_gmm
+                experts._grouped_matmul = real_gmm
             rows.append(row)
             print(json.dumps(row), flush=True)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)

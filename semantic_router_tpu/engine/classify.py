@@ -310,7 +310,7 @@ class _GenerationObserver:
              rows_per_group=None, drafted: Optional[int] = None,
              accepted: int = 0, attn_tiles=None) -> None:
         """``load [layers, 4]`` of an expert model
-        (models.sdar_moe.routed_experts), of a step of several
+        (models.experts.routed_experts), of a step of several
         ``forwards`` theirs stacked (``[forwards x layers, 4]``); a dense
         generator gives none.  ``committed_blocks`` / ``committed_tokens``:
         what this step FINISHED (a block's step its block, whichever step

@@ -22,7 +22,7 @@ head.
   heads^-0.5 * dim^-0.5``, RoPE on the first ``qk_rope_head_dim`` dims), and
   ``S_t = {s <= t : I(t, s) >= the index_topk-th largest of I(t, 0..t)}``.
 - ``FF``: a dense SwiGLU in the first ``first_k_dense_replace`` layers, else
-  ``sdar_moe.routed_experts`` behind ``lfm2_moe.sigmoid_route`` (sigmoid
+  ``experts.routed_experts`` behind ``experts.sigmoid_route`` (sigmoid
   scores, the choice by score + bias, the weights the unbiased scores over
   their sum) plus the shared expert, which every chip computes whole.
 
@@ -58,7 +58,7 @@ vocabulary is a smaller vocabulary: id 0 is row ``first``).
 Precision: parameters and cache in ``cfg.dtype``; norms, RoPE, softmax, the
 indexer's scores, the gates, the router and the head's logits in float32.
 A prefill maps its rows INSIDE the program, a group at a time
-(``lfm2_moe.map_row_groups``).
+(``models/mapped_prefill.py``).
 
 Scopes: ``embed_tokens``; ``layers_<i>/attn_full`` (``q``, ``kv``,
 ``indexer/scores``, ``indexer/select``, ``core``, ``gate_out``);
@@ -70,14 +70,32 @@ Scopes: ``embed_tokens``; ``layers_<i>/attn_full`` (``q``, ``kv``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.flash_attention import causal_tiles, flash_attention
+from ..ops.flash_attention import flash_attention
 from ..ops.rope import apply_rotary_front
+from .cached_model import CachedDecoder
+from .checkpoints import (
+    checkpoint_reader,
+    on_device,
+    swiglu_matrices,
+    tensor_rows,
+    torch_dtype_of,
+)
+from .decoder_parts import head, rms_norm
+from .experts import (
+    expert_ids,
+    feed_forward,
+    routed_experts,
+    sigmoid_route,
+    sum_loads,
+    swiglu,
+)
 from .gated_window import gate_out, ring_of, ring_seen, ring_slot
 from .latent_attention import (
     Geometry,
@@ -88,16 +106,7 @@ from .latent_attention import (
     queries,
     tables,
 )
-from .lfm2_moe import (
-    _sum_loads,
-    _swiglu,
-    map_row_groups,
-    rows_per_group,
-    sigmoid_route,
-    tree_bytes,
-)
-from .qwen3 import torch_dtype_of
-from .sdar_moe import checkpoint_reader, rms_norm, routed_experts
+from .mapped_prefill import prefill_group, prefill_in_groups
 
 LAYER_TYPES = ("full_attention", "sliding_attention")
 INDEX_NORM_EPS = 1e-6   # the indexer's LayerNorm
@@ -230,20 +239,6 @@ def params_from_checkpoint(path: str, cfg: Dots3NoteConfig) -> Dict[str, Any]:
         return params_from_state(get, cfg)
 
 
-def _rows(get, name: str, first: int, count: int) -> np.ndarray:
-    """Rows ``[first, first + count)`` of tensor ``name``: through
-    ``get.rows`` where the reader can slice a file (only those rows are
-    read), else off the whole tensor."""
-    if hasattr(get, "rows"):
-        return get.rows(name, first, count)
-    return np.asarray(get(name))[first:first + count]
-
-
-def _on_device(cfg, a: np.ndarray, transpose: bool = False) -> jnp.ndarray:
-    x = jnp.asarray(a).astype(cfg.dtype)
-    return jnp.swapaxes(x, -1, -2) if transpose else x
-
-
 def layer_params(get: Callable[[str], np.ndarray], cfg, i: int
                  ) -> Dict[str, Any]:
     """Layer ``i`` under DeepSeek-V3's tensor names — the two norms, the
@@ -251,16 +246,7 @@ def layer_params(get: Callable[[str], np.ndarray], cfg, i: int
     its selection bias (float32), the experts HELD (``cfg.held``: an
     expert is a tensor of its own, so only those are read) and the shared
     expert — as this family's and ``models/joyai_llm_flash.py``'s tree."""
-    def dev(a, transpose: bool = False):
-        return _on_device(cfg, a, transpose)
-
-    def pair(prefix: str) -> Dict[str, Any]:
-        """A SwiGLU's three matrices as ``gate_up`` and ``down``."""
-        return {"gate_up": jnp.concatenate(
-                    [dev(get(prefix + "gate_proj.weight"), True),
-                     dev(get(prefix + "up_proj.weight"), True)], -1),
-                "down": dev(get(prefix + "down_proj.weight"), True)}
-
+    dev = functools.partial(on_device, cfg)
     p = f"model.layers.{i}."
     a = p + "self_attn."
     layer = {"norm1": dev(get(p + "input_layernorm.weight")),
@@ -274,20 +260,14 @@ def layer_params(get: Callable[[str], np.ndarray], cfg, i: int
              "o_proj": dev(get(a + "o_proj.weight"), True)}
     f = p + "mlp."
     if not cfg.is_sparse(i):
-        layer.update(pair(f))
+        layer.update(swiglu_matrices(get, cfg, f))
         return layer
-    first, count = cfg.held
-    experts = {k: np.stack([get(f"{f}experts.{e}.{k}_proj.weight")
-                            for e in range(first, first + count)])
-               for k in ("gate", "up", "down")}
     layer.update(
         router=dev(get(f + "gate.weight"), True),
         expert_bias=jnp.asarray(np.asarray(
             get(f + "gate.e_score_correction_bias"), np.float32)),
-        gate_up=jnp.concatenate([dev(experts["gate"], True),
-                                 dev(experts["up"], True)], -1),
-        down=dev(experts["down"], True),
-        shared=pair(f + "shared_experts."))
+        **swiglu_matrices(get, cfg, f + "experts.", experts=cfg.held),
+        shared=swiglu_matrices(get, cfg, f + "shared_experts."))
     return layer
 
 
@@ -297,10 +277,7 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: Dots3NoteConfig
     module's tree, in ``cfg.dtype`` on the default device; the router's
     selection bias stays float32.  Only the experts held and the
     vocabulary rows held are read."""
-
-    def dev(a, transpose: bool = False):
-        return _on_device(cfg, a, transpose)
-
+    dev = functools.partial(on_device, cfg)
     layers = []
     for i, kind in enumerate(cfg.layer_types):
         a = f"model.layers.{i}.self_attn."
@@ -316,11 +293,12 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: Dots3NoteConfig
                 index_w=dev(get(x + "weights_proj.weight"), True))
         layers.append(layer)
     v_first, v_count = cfg.vocab
-    return {"embed": dev(_rows(get, "model.embed_tokens.weight", v_first,
-                               v_count)),
+    return {"embed": dev(tensor_rows(get, "model.embed_tokens.weight",
+                                     v_first, v_count)),
             "layers": layers,
             "norm": dev(get("model.norm.weight")),
-            "lm_head": dev(_rows(get, "lm_head.weight", v_first, v_count))}
+            "lm_head": dev(tensor_rows(get, "lm_head.weight", v_first,
+                                       v_count))}
 
 
 # -- what prefill and decode share -----------------------------------------------
@@ -393,56 +371,8 @@ def moe(cfg: Dots3NoteConfig, p, x, valid):
         top_e, w = route(cfg, p, x)
     y, load = routed_experts(p, x, valid, top_e, w, cfg.held, cfg.dtype)
     with jax.named_scope("shared"):
-        y = y + _swiglu(cfg, p["shared"], x)
+        y = y + swiglu(cfg, p["shared"], x)
     return y, top_e, load
-
-
-def _ffn(cfg, i, p, h, valid):
-    """What the second half of layer ``i`` adds to the residual stream,
-    from its normed input ``h [B, S, H]``, and the experts' ``(top_e [B *
-    S, k], load)``; a dense layer reports no experts."""
-    B, S, H = h.shape
-    if not cfg.is_sparse(i):
-        with jax.named_scope("mlp"):
-            return _swiglu(cfg, p, h), None, None
-    with jax.named_scope("moe"):
-        y, top_e, load = moe(cfg, p, h.reshape(B * S, H), valid.reshape(-1))
-    return y.reshape(B, S, H), top_e, load
-
-
-def _feed_forward(cfg, i, p, x, valid):
-    """The second half of layer ``i`` on ``x [B, S, H]``; ``top_e`` comes
-    back ``[B, S, k]``."""
-    B, S, _ = x.shape
-    y, top_e, load = _ffn(
-        cfg, i, p, rms_norm(x, p["norm2"], cfg.rms_norm_eps, cfg.dtype), valid)
-    x = x + y
-    return x, None if top_e is None else top_e.reshape(B, S, -1), load
-
-
-def _feed_forward_as_one_row(cfg, i, p, x, valid):
-    """``_feed_forward`` of a prefill's group ``x [G, S, H]`` with the
-    group's tokens as ONE row of ``G * S`` from the norm on
-    (``lfm2_moe._feed_forward_as_one_row`` says why)."""
-    G, S, H = x.shape
-    h = rms_norm(x, p["norm2"], cfg.rms_norm_eps, cfg.dtype)
-    y, top_e, load = _ffn(cfg, i, p, h.reshape(1, G * S, H),
-                          valid.reshape(1, G * S))
-    return (x.reshape(1, G * S, H) + y).reshape(G, S, H), \
-        None if top_e is None else top_e.reshape(G, S, -1), load
-
-
-def _expert_ids(cfg, top_e):
-    return top_e.astype(jnp.uint8 if cfg.n_routed_experts <= 256
-                        else jnp.int32)
-
-
-def _head(cfg, params, x):
-    """``x [B, H]`` -> logits ``[B, V held]`` float32."""
-    with jax.named_scope("lm_head"):
-        h = rms_norm(x, params["norm"], cfg.rms_norm_eps, cfg.dtype)
-        return jnp.einsum("bh,vh->bv", h, params["lm_head"],
-                          preferred_element_type=jnp.float32)
 
 
 def _gate_out(cfg, g: Geometry, p, h, out):
@@ -559,12 +489,13 @@ def _prefill_rows(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
                     x = x + _gate_out(cfg, g, p, h, out)
                     with jax.named_scope("kv"):
                         window.append(ring_of(lat, lengths, W))
-            x, top_e, load = _feed_forward_as_one_row(cfg, i, p, x, valid)
+            x, top_e, load = feed_forward(cfg, cfg.is_sparse(i), p, x, valid,
+                                          moe, as_one_row=True)
             if top_e is not None:
-                experts.append(_expert_ids(cfg, top_e))
+                experts.append(expert_ids(top_e, cfg.n_routed_experts))
                 loads.append(load)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    return (latent, index, window, _head(cfg, params, x_last),
+    return (latent, index, window, head(cfg, params, x_last),
             jnp.stack(experts), jnp.stack(loads), keys,
             jnp.stack(selected), sample_at)
 
@@ -606,20 +537,12 @@ def _prefill_groups(cfg: Dots3NoteConfig, params, ids, lengths,
     if group == 1:
         return _prefill_a_row_at_a_time(cfg, params, ids, lengths, cache_len)
 
-    def rows(ids, lengths):
-        (latent, index, window, logits, experts, load, keys, selected,
-         at) = _prefill_rows(cfg, params, ids, lengths, cache_len)
-        return (latent, index, window, logits, jnp.moveaxis(experts, 1, 0),
-                keys, jnp.moveaxis(selected, 1, 0), at), load
-
-    (latent, index, window, logits, experts, keys, selected,
-     at), loads = map_row_groups(rows, group, ids, lengths)
-    cache = {"latent": latent, "index": index, "window": window,
-             "lengths": lengths.astype(jnp.int32)}
-    return cache, logits, {
-        "experts": jnp.moveaxis(experts, 0, 1), "load": _sum_loads(loads),
-        "keys": keys, "selected": jnp.moveaxis(selected, 0, 1),
-        "selected_at": at}
+    return prefill_in_groups(
+        lambda ids, lengths: _prefill_rows(cfg, params, ids, lengths,
+                                           cache_len),
+        ("latent", "index", "window", "logits", "experts", "load", "keys",
+         "selected", "selected_at"), ("experts", "selected"), group, ids,
+        lengths)
 
 
 def _prefill_a_row_at_a_time(cfg: Dots3NoteConfig, params, ids, lengths,
@@ -646,20 +569,9 @@ def _prefill_a_row_at_a_time(cfg: Dots3NoteConfig, params, ids, lengths,
              "window": [a[:, 0] for a in window],
              "lengths": lengths.astype(jnp.int32)}
     return cache, logits, {
-        "experts": jnp.moveaxis(experts, 0, 1), "load": _sum_loads(loads),
+        "experts": jnp.moveaxis(experts, 0, 1), "load": sum_loads(loads),
         "keys": keys, "selected": jnp.moveaxis(selected, 0, 1),
         "selected_at": at}
-
-
-def prefill_group(cfg: Dots3NoteConfig, params, rows: int, S: int,
-                  cache_len: int) -> int:
-    """The rows a group of a prefill of ``rows`` x ``S``
-    (``lfm2_moe.rows_per_group`` at this model's sizes: at the guard's
-    widths a row's attention arrays leave room for no second one)."""
-    return rows_per_group(
-        rows, _row_bytes(cfg, S),
-        tree_bytes(params) + _cache_bytes(cfg, rows, cache_len),
-        S * cfg.hidden_size * jnp.dtype(cfg.dtype).itemsize)
 
 
 def prefill(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
@@ -667,12 +579,14 @@ def prefill(cfg: Dots3NoteConfig, params, ids, lengths, cache_len: int):
     row) -> ``(cache, logits [B, V] float32 at each row's last token, aux)``
     with ``aux = {"experts" [expert layers, B, S, k], "load" [expert
     layers, 4], "keys" [B, 2], "selected" [full layers, B, n, S / 8],
-    "selected_at" [B, n]}``.  ``prefill_group`` rows at a time inside the
-    program (``lfm2_moe.map_row_groups``), so a bucket's temporaries are
-    those of ONE group whatever the batch."""
+    "selected_at" [B, n]}``.  ``mapped_prefill.prefill_group`` rows at a
+    time inside the program, so a bucket's temporaries are those of ONE
+    group whatever the batch (at the guard's widths a row's attention
+    arrays leave room for no second one)."""
     return _prefill_groups(
         cfg, params, ids, lengths, cache_len,
-        prefill_group(cfg, params, *ids.shape, cache_len))
+        prefill_group(cfg, params, *ids.shape, cache_len, _row_bytes,
+                      _cache_bytes))
 
 
 # -- decode: one token a row against the latent cache ----------------------------
@@ -748,49 +662,29 @@ def decode(cfg: Dots3NoteConfig, params, cache, tokens, positions):
                                        ring_seen(positions, W)[:, None])
                     x = x + _gate_out(cfg, g, p, h, out)
                     window.append(ring)
-            x, top_e, load = _feed_forward(cfg, i, p, x, live[:, None])
+            x, top_e, load = feed_forward(cfg, cfg.is_sparse(i), p, x,
+                                          live[:, None], moe)
             if top_e is not None:
-                experts.append(_expert_ids(cfg, top_e[:, 0]))
+                experts.append(expert_ids(top_e[:, 0], cfg.n_routed_experts))
                 loads.append(load)
     cache = {"latent": latent, "index": index, "window": window,
              "lengths": cache["lengths"]}
-    return cache, _head(cfg, params, x[:, 0]), {
+    return cache, head(cfg, params, x[:, 0]), {
         "experts": jnp.stack(experts), "load": jnp.stack(loads),
         "keys": keys, "selected": jnp.stack(selected)}
 
 
-class CachedModel:
+class CachedModel(CachedDecoder):
     """This decoder behind the interface ``models.generate.GreedyGenerator``
-    decodes through (``generate.Qwen3Cached`` says what it is); no
-    adapters here, ``task_index`` is accepted and unused."""
+    decodes through; its prefill's flash calls are its layers' of both
+    geometries, all their heads."""
 
     def __init__(self, config: Dots3NoteConfig) -> None:
-        self.config = config
-
-    def prefill(self, params, ids, lengths, cache_len: int, task_index):
-        return prefill(self.config, params, ids, lengths, cache_len)
-
-    def decode(self, params, cache, tokens, positions, task_index):
-        return decode(self.config, params, cache, tokens, positions)
-
-    def rows_per_group(self, params, rows: int, bucket: int,
-                       cache_len: int) -> int:
-        """How many rows of such a prefill go through the layers
-        together."""
-        return prefill_group(self.config, params, rows, bucket, cache_len)
-
-    def attn_tiles(self, lengths, bucket: int):
-        """``(visited, grid)`` of such a prefill's flash calls, which are
-        handed the rows' lengths (``flash_attention.tiles_for``), over its
-        layers of both geometries and their heads."""
-        cfg = self.config
-        return causal_tiles(bucket, lengths, [
-            (cfg.geometry(kind).heads, 0 if kind == "full_attention"
-             else 2 * (cfg.sliding_window_size - 1))
-            for kind in cfg.layer_types])
-
-    @staticmethod
-    def cache_bytes(cache) -> Dict[str, int]:
-        """The cache's bytes by kind of state."""
-        return {k: tree_bytes(cache[k])
-                for k in ("latent", "index", "window")}
+        super().__init__(
+            config, prefill, decode,
+            cache_kinds=("latent", "index", "window"),
+            group_sizes=(_row_bytes, _cache_bytes),
+            attn_layers=[
+                (config.geometry(kind).heads, 0 if kind == "full_attention"
+                 else 2 * (config.sliding_window_size - 1))
+                for kind in config.layer_types])

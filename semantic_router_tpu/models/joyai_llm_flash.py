@@ -16,7 +16,7 @@ logits for ``t_{i+1}``.
   prefill expands K and V a head under the flash kernel; a step is the
   absorbed form against the latent cache, at one or TWO query positions a
   row, the second seeing the first.
-- ``FF``: ``dots3_note``'s (the same ``sigmoid_route`` and
+- ``FF``: ``dots3_note``'s ``moe`` (the same ``sigmoid_route`` and
   ``routed_experts``): the top 8 of ``sigmoid + b``, weights the unbiased
   sigmoids over their sum times ``routed_scaling_factor``, plus the shared
   expert, which every chip computes whole.
@@ -67,13 +67,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.flash_attention import flash_attention
-from .dots3_note import (  # a layer's tensors and its expert half
-    _expert_ids,
-    _feed_forward,
-    _feed_forward_as_one_row,
-    _on_device,
-    layer_params,
-)
+# a layer's tensors and its expert half are dots3_note's: ``layer_params`` and
+# ``moe``, read there at call time — the ONE import of a model file by
+# another (the benchmark's tests inject this family's router fault through
+# ``dots3_note.route``; ROADMAP D6 names the debt)
+from . import dots3_note
+from .cached_model import CachedDecoder
+from .checkpoints import checkpoint_reader, on_device, torch_dtype_of
+from .decoder_parts import rms_norm
+from .experts import expert_ids, feed_forward
 from .latent_attention import (
     Geometry,
     absorbed,
@@ -84,9 +86,6 @@ from .latent_attention import (
     queries,
     tables,
 )
-from .lfm2_moe import tree_bytes
-from .qwen3 import torch_dtype_of
-from .sdar_moe import checkpoint_reader, rms_norm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,11 +190,12 @@ def params_from_state(get: Callable[[str], np.ndarray],
     module's tensors lie under ``model.layers.<num_hidden_layers>.``; its
     embedding and head are the main model's and are not read twice."""
     def dev(name: str, transpose: bool = False):
-        return _on_device(cfg, get(name), transpose)
+        return on_device(cfg, get(name), transpose)
 
     n = cfg.num_hidden_layers
     params = {"embed": dev("model.embed_tokens.weight"),
-              "layers": [layer_params(get, cfg, i) for i in range(n)],
+              "layers": [dots3_note.layer_params(get, cfg, i)
+                         for i in range(n)],
               "norm": dev("model.norm.weight"),
               "lm_head": dev("lm_head.weight")}
     if cfg.num_nextn_predict_layers:
@@ -204,7 +204,7 @@ def params_from_state(get: Callable[[str], np.ndarray],
                          "hnorm": dev(m + "hnorm.weight"),
                          "eh_proj": dev(m + "eh_proj.weight", True),
                          "norm": dev(m + "shared_head.norm.weight"),
-                         "block": layer_params(get, cfg, n)}
+                         "block": dots3_note.layer_params(get, cfg, n)}
     return params
 
 
@@ -275,7 +275,8 @@ def _prompt_layer(cfg, i, p, x, positions, valid):
     cos, sin = tables(g, positions, x.shape[1])
     h = rms_norm(x, p["norm1"], cfg.rms_norm_eps, cfg.dtype)
     out, lat = _attend_prompt(g, p, h, cos, sin, valid.astype(jnp.int32))
-    x, top_e, load = _feed_forward_as_one_row(cfg, i, p, x + out, valid)
+    x, top_e, load = feed_forward(cfg, cfg.is_sparse(i), p, x + out, valid,
+                                  dots3_note.moe, as_one_row=True)
     return x, lat, top_e, load
 
 
@@ -299,7 +300,7 @@ def prefill(cfg: JoyaiLlmFlashConfig, params, ids, lengths, cache_len: int):
                                                 valid)
         latent.append(jnp.pad(lat, pad_to))
         if top_e is not None:
-            experts.append(_expert_ids(cfg, top_e))
+            experts.append(expert_ids(top_e, cfg.n_routed_experts))
             loads.append(load)
     with jax.named_scope("lm_head"):
         hidden = _hidden(cfg, params["norm"], x)
@@ -340,7 +341,8 @@ def first_draft(cfg: JoyaiLlmFlashConfig, params, cache, ids, lengths,
             x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
             logits = _logits(params, _hidden(cfg, m["norm"], x_last))
     cache["draft"] = jnp.pad(lat, ((0, 0), (0, M - S), (0, 0)))
-    return cache, logits, _join(aux, _expert_ids(cfg, top_e), load)
+    return cache, logits, _join(
+        aux, expert_ids(top_e, cfg.n_routed_experts), load)
 
 
 # -- a step: one or two positions a row against the latent cache -------------------
@@ -363,7 +365,8 @@ def _cached_layer(cfg, i, p, x, lat, positions, valid):
     seen = _seen(pos, M)
     h = rms_norm(x, p["norm1"], cfg.rms_norm_eps, cfg.dtype)
     out, lat = _attend_cached(g, p, h, cos, sin, lat, positions, seen)
-    x, top_e, load = _feed_forward(cfg, i, p, x + out, valid)
+    x, top_e, load = feed_forward(cfg, cfg.is_sparse(i), p, x + out, valid,
+                                  dots3_note.moe)
     return x, lat, top_e, load
 
 
@@ -386,7 +389,7 @@ def verify(cfg: JoyaiLlmFlashConfig, params, cache, tokens, positions):
                                                     positions, valid)
             latent.append(lat)
             if top_e is not None:
-                experts.append(_expert_ids(cfg, top_e))
+                experts.append(expert_ids(top_e, cfg.n_routed_experts))
                 loads.append(load)
         with jax.named_scope("lm_head"):
             hidden = _hidden(cfg, params["norm"], x)
@@ -417,7 +420,7 @@ def draft(cfg: JoyaiLlmFlashConfig, params, cache, hidden, chosen, positions,
             x_last = jnp.where(accepted[:, None], x[:, 1], x[:, 0])
             logits = _logits(params, _hidden(cfg, m["norm"], x_last))
     return dict(cache, draft=lat), logits, \
-        _join(aux, _expert_ids(cfg, top_e), load)
+        _join(aux, expert_ids(top_e, cfg.n_routed_experts), load)
 
 
 def decode(cfg: JoyaiLlmFlashConfig, params, cache, tokens, positions):
@@ -428,47 +431,16 @@ def decode(cfg: JoyaiLlmFlashConfig, params, cache, tokens, positions):
     return cache, logits[:, 0], dict(aux, experts=aux["experts"][:, :, 0])
 
 
-class CachedModel:
+class CachedModel(CachedDecoder):
     """This decoder behind the interface ``models.generate.GreedyGenerator``
-    decodes through (``generate.Qwen3Cached`` says what it is); no
-    adapters here, ``task_index`` is accepted and unused.  ``drafts``: the
-    checkpoint has an MTP module, and the generator then steps through
-    ``first_draft`` / ``verify`` / ``draft`` and not ``decode``."""
+    decodes through.  A chat bucket's rows go through the layers together,
+    one block a row under a flash call that is handed no lengths.
+    ``drafts``: the checkpoint has an MTP module, and the generator then
+    steps through ``first_draft`` / ``verify`` / ``draft`` and not
+    ``decode``."""
 
     def __init__(self, config: JoyaiLlmFlashConfig) -> None:
-        self.config = config
-        self.drafts = bool(config.num_nextn_predict_layers)
-
-    def prefill(self, params, ids, lengths, cache_len: int, task_index):
-        return prefill(self.config, params, ids, lengths, cache_len)
-
-    def decode(self, params, cache, tokens, positions, task_index):
-        return decode(self.config, params, cache, tokens, positions)
-
-    def first_draft(self, params, cache, ids, lengths, tokens, aux):
-        return first_draft(self.config, params, cache, ids, lengths, tokens,
-                           aux)
-
-    def verify(self, params, cache, tokens, positions, task_index):
-        return verify(self.config, params, cache, tokens, positions)
-
-    def draft(self, params, cache, hidden, chosen, positions, accepted, aux):
-        return draft(self.config, params, cache, hidden, chosen, positions,
-                     accepted, aux)
-
-    @staticmethod
-    def rows_per_group(params, rows: int, bucket: int, cache_len: int):
-        """A chat bucket's rows go through the layers together."""
-        return None
-
-    @staticmethod
-    def attn_tiles(lengths, bucket: int):
-        """A chat bucket is one block a row: its flash call is handed no
-        lengths."""
-        return None
-
-    @staticmethod
-    def cache_bytes(cache) -> Dict[str, int]:
-        """The cache's bytes by kind of state."""
-        return {k: tree_bytes(cache[k]) for k in ("latent", "draft")
-                if k in cache}
+        super().__init__(
+            config, prefill, decode, cache_kinds=("latent", "draft"),
+            drafter=(first_draft, verify, draft)
+            if config.num_nextn_predict_layers else None)

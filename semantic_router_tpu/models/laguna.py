@@ -21,8 +21,8 @@ lists what is assumed of the published model): ``x <- x + Attn_t(RMSNorm(x))``,
   ``sliding_window`` keys); one sigmoid gate a head from the layer's normed
   input on the head's output; ``W_o``.
 - ``FF_i`` by ``mlp_layer_types[i]``: ``dense`` a SwiGLU of
-  ``intermediate_size``; ``sparse`` ``sdar_moe.routed_experts`` behind
-  ``sdar_moe.softmax_route`` (softmax over all experts, the top k, over
+  ``intermediate_size``; ``sparse`` ``experts.routed_experts`` behind
+  ``experts.softmax_route`` (softmax over all experts, the top k, over
   their sum, times ``moe_routed_scaling_factor``, on the experts' outputs)
   plus ``sigmoid(w_s . h) * SwiGLU_shared(h)``, which every chip computes
   whole.
@@ -39,7 +39,7 @@ A chip's share: ``experts_held`` / ``vocab_held = (first, count)`` as in
 
 Precision: parameters and cache in ``cfg.dtype``; norms, RoPE, softmax, the
 gates, the router and the head's logits in float32.  A prefill maps its
-rows INSIDE the program, a group at a time (``lfm2_moe.map_row_groups``);
+rows INSIDE the program, a group at a time (``models/mapped_prefill.py``);
 its cores are the flash kernel, k and v repeated to the layer's query heads
 as ``lfm2_moe`` repeats them.
 
@@ -52,31 +52,33 @@ Scopes: ``embed_tokens``; ``layers_<i>/attn_full`` and
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.flash_attention import causal_tiles, flash_attention
+from ..ops.flash_attention import flash_attention
 from ..ops.rope import RopeSpec, apply_rotary_front
-from .dots3_note import _head, _on_device, _rows
-from .gated_window import gate_out, ring_of, ring_seen, ring_slot
-from .lfm2_moe import (
-    _sum_loads,
-    _swiglu,
-    map_row_groups,
-    rows_per_group,
-    tree_bytes,
-)
-from .qwen3 import torch_dtype_of
-from .sdar_moe import (
-    NEG_INF,
+from .cached_model import CachedDecoder
+from .checkpoints import (
     checkpoint_reader,
-    rms_norm,
+    on_device,
+    swiglu_matrices,
+    tensor_rows,
+    torch_dtype_of,
+)
+from .decoder_parts import NEG_INF, head, rms_norm
+from .experts import (
+    expert_ids,
+    feed_forward,
     routed_experts,
     softmax_route,
+    swiglu,
 )
+from .gated_window import gate_out, ring_of, ring_seen, ring_slot
+from .mapped_prefill import prefill_group, prefill_in_groups
 
 LAYER_TYPES = ("full_attention", "sliding_attention")
 MLP_TYPES = ("dense", "sparse")
@@ -245,17 +247,7 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: LagunaConfig
     ``self_attn.g_proj`` the gate: ``chipbench/reference/laguna.py`` lists
     them) as this module's tree, in ``cfg.dtype`` on the default device.
     Only the experts held and the vocabulary rows held are read."""
-    def dev(a, transpose: bool = False):
-        return _on_device(cfg, a, transpose)
-
-    def pair(prefix: str) -> Dict[str, Any]:
-        """A SwiGLU's three matrices as ``gate_up`` and ``down``."""
-        return {"gate_up": jnp.concatenate(
-                    [dev(get(prefix + "gate_proj.weight"), True),
-                     dev(get(prefix + "up_proj.weight"), True)], -1),
-                "down": dev(get(prefix + "down_proj.weight"), True)}
-
-    first, count = cfg.held
+    dev = functools.partial(on_device, cfg)
     layers = []
     for i in range(cfg.num_hidden_layers):
         p = f"model.layers.{i}."
@@ -268,28 +260,23 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: LagunaConfig
         for k in ("q", "k", "v", "o"):
             layer[k + "_proj"] = dev(get(f"{a}{k}_proj.weight"), True)
         f = p + "mlp."
-        if not cfg.is_sparse(i):
-            layer.update(pair(f))
-            layers.append(layer)
-            continue
-        experts = {k: np.stack([get(f"{f}experts.{e}.{k}_proj.weight")
-                                for e in range(first, first + count)])
-                   for k in ("gate", "up", "down")}
-        layer.update(
-            router=dev(get(f + "gate.weight"), True),
-            gate_up=jnp.concatenate([dev(experts["gate"], True),
-                                     dev(experts["up"], True)], -1),
-            down=dev(experts["down"], True),
-            shared=pair(f + "shared_expert."),
-            shared_gate=dev(get(f + "shared_expert_gate.weight"), True))
-        del experts
+        if cfg.is_sparse(i):
+            layer.update(
+                router=dev(get(f + "gate.weight"), True),
+                **swiglu_matrices(get, cfg, f + "experts.",
+                                  experts=cfg.held),
+                shared=swiglu_matrices(get, cfg, f + "shared_expert."),
+                shared_gate=dev(get(f + "shared_expert_gate.weight"), True))
+        else:
+            layer.update(swiglu_matrices(get, cfg, f))
         layers.append(layer)
     v_first, v_count = cfg.vocab
-    return {"embed": dev(_rows(get, "model.embed_tokens.weight", v_first,
-                               v_count)),
+    return {"embed": dev(tensor_rows(get, "model.embed_tokens.weight",
+                                     v_first, v_count)),
             "layers": layers,
             "norm": dev(get("model.norm.weight")),
-            "lm_head": dev(_rows(get, "lm_head.weight", v_first, v_count))}
+            "lm_head": dev(tensor_rows(get, "lm_head.weight", v_first,
+                                       v_count))}
 
 
 # -- what prefill and decode share -----------------------------------------------
@@ -325,7 +312,7 @@ def shared_expert(cfg: LagunaConfig, p, x):
     """``sigmoid(w_s . x) * SwiGLU_shared(x)`` of ``x [T, H]``."""
     gate = jax.nn.sigmoid(jnp.dot(x, p["shared_gate"],
                                   preferred_element_type=jnp.float32))
-    return (gate * _swiglu(cfg, p["shared"], x).astype(jnp.float32)
+    return (gate * swiglu(cfg, p["shared"], x).astype(jnp.float32)
             ).astype(cfg.dtype)
 
 
@@ -338,40 +325,6 @@ def moe(cfg: LagunaConfig, p, x, valid):
     with jax.named_scope("shared"):
         y = y + shared_expert(cfg, p, x)
     return y, top_e, load
-
-
-def _ffn(cfg, i, p, h, valid):
-    """What the second half of layer ``i`` adds to the residual stream,
-    from its normed input ``h [B, S, H]``, and the experts' ``(top_e [B *
-    S, k], load)``; a dense layer reports no experts."""
-    B, S, H = h.shape
-    if not cfg.is_sparse(i):
-        with jax.named_scope("mlp"):
-            return _swiglu(cfg, p, h), None, None
-    with jax.named_scope("moe"):
-        y, top_e, load = moe(cfg, p, h.reshape(B * S, H), valid.reshape(-1))
-    return y.reshape(B, S, H), top_e, load
-
-
-def _feed_forward(cfg, i, p, x, valid, as_one_row: bool = False):
-    """The second half of layer ``i`` on ``x [B, S, H]``; ``top_e`` comes
-    back ``[B, S, k]``.  ``as_one_row`` (a prefill's group): the group's
-    tokens as ONE row of ``B * S`` from the norm on
-    (``lfm2_moe._feed_forward_as_one_row`` says why)."""
-    B, S, H = x.shape
-    h = rms_norm(x, p["norm2"], cfg.rms_norm_eps, cfg.dtype)
-    if as_one_row:
-        y, top_e, load = _ffn(cfg, i, p, h.reshape(1, B * S, H),
-                              valid.reshape(1, B * S))
-        x = (x.reshape(1, B * S, H) + y).reshape(B, S, H)
-    else:
-        y, top_e, load = _ffn(cfg, i, p, h, valid)
-        x = x + y
-    return x, None if top_e is None else top_e.reshape(B, S, -1), load
-
-
-def _expert_ids(cfg, top_e):
-    return top_e.astype(jnp.uint8 if cfg.num_experts <= 256 else jnp.int32)
 
 
 def _scope_of(kind: str) -> str:
@@ -417,13 +370,13 @@ def _prefill_rows(cfg: LagunaConfig, params, ids, lengths, cache_len: int):
                     window.append(tuple(
                         jnp.moveaxis(ring_of(t, lengths, W), 1, 2)
                         for t in (k, v)))
-            x, top_e, load = _feed_forward(cfg, i, p, x, valid,
-                                           as_one_row=True)
+            x, top_e, load = feed_forward(cfg, cfg.is_sparse(i), p, x, valid,
+                                          moe, as_one_row=True)
             if top_e is not None:
-                experts.append(_expert_ids(cfg, top_e))
+                experts.append(expert_ids(top_e, cfg.num_experts))
                 loads.append(load)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    return (full, window, _head(cfg, params, x_last), jnp.stack(experts),
+    return (full, window, head(cfg, params, x_last), jnp.stack(experts),
             jnp.stack(loads))
 
 
@@ -454,39 +407,25 @@ def _cache_bytes(cfg: LagunaConfig, rows: int, cache_len: int) -> int:
 def _prefill_groups(cfg: LagunaConfig, params, ids, lengths, cache_len: int,
                     group: int):
     """``prefill`` at ``group`` rows a call of ``_prefill_rows``."""
-    def rows(ids, lengths):
-        full, window, logits, experts, load = _prefill_rows(
-            cfg, params, ids, lengths, cache_len)
-        return (full, window, logits, jnp.moveaxis(experts, 1, 0)), load
-
-    (full, window, logits, experts), loads = map_row_groups(
-        rows, group, ids, lengths)
-    cache = {"full": full, "window": window,
-             "lengths": lengths.astype(jnp.int32)}
-    return cache, logits, {"experts": jnp.moveaxis(experts, 0, 1),
-                           "load": _sum_loads(loads)}
-
-
-def prefill_group(cfg: LagunaConfig, params, rows: int, S: int,
-                  cache_len: int) -> int:
-    """The rows a group of a prefill of ``rows`` x ``S``
-    (``lfm2_moe.rows_per_group`` at this model's sizes: one at the long
-    bucket, every row at the short one)."""
-    return rows_per_group(
-        rows, _row_bytes(cfg, S),
-        tree_bytes(params) + _cache_bytes(cfg, rows, cache_len),
-        S * cfg.hidden_size * jnp.dtype(cfg.dtype).itemsize)
+    return prefill_in_groups(
+        lambda ids, lengths: _prefill_rows(cfg, params, ids, lengths,
+                                           cache_len),
+        ("full", "window", "logits", "experts", "load"), ("experts",), group,
+        ids, lengths)
 
 
 def prefill(cfg: LagunaConfig, params, ids, lengths, cache_len: int):
     """``ids [B, S]`` right-padded prompts of ``lengths [B]`` (0 = a padding
     row) -> ``(cache, logits [B, V] float32 at each row's last token, aux)``
     with ``aux = {"experts" [sparse layers, B, S, k], "load" [sparse
-    layers, 4]}``.  ``prefill_group`` rows at a time inside the program, so
-    a bucket's temporaries are those of ONE group whatever the batch."""
+    layers, 4]}``.  ``mapped_prefill.prefill_group`` rows at a time inside
+    the program, so a bucket's temporaries are those of ONE group whatever
+    the batch (on a v5e one at the long bucket, every row at the short
+    one)."""
     return _prefill_groups(
         cfg, params, ids, lengths, cache_len,
-        prefill_group(cfg, params, *ids.shape, cache_len))
+        prefill_group(cfg, params, *ids.shape, cache_len, _row_bytes,
+                      _cache_bytes))
 
 
 # -- decode: one token a row against both kinds of cache -------------------------
@@ -535,47 +474,27 @@ def decode(cfg: LagunaConfig, params, cache, tokens, positions):
                         v_cache, preferred_element_type=jnp.float32)
                 (full if whole else window).append((k_cache, v_cache))
                 x = x + gate_out(p, h, out.reshape(B, nh, 1, D), cfg.dtype)
-            x, top_e, load = _feed_forward(cfg, i, p, x, live[:, None])
+            x, top_e, load = feed_forward(cfg, cfg.is_sparse(i), p, x,
+                                          live[:, None], moe)
             if top_e is not None:
-                experts.append(_expert_ids(cfg, top_e[:, 0]))
+                experts.append(expert_ids(top_e[:, 0], cfg.num_experts))
                 loads.append(load)
     cache = {"full": full, "window": window, "lengths": cache["lengths"]}
-    return cache, _head(cfg, params, x[:, 0]), {
+    return cache, head(cfg, params, x[:, 0]), {
         "experts": jnp.stack(experts), "load": jnp.stack(loads)}
 
 
-class CachedModel:
+class CachedModel(CachedDecoder):
     """This decoder behind the interface ``models.generate.GreedyGenerator``
-    decodes through (``generate.Qwen3Cached`` says what it is); no
-    adapters here, ``task_index`` is accepted and unused."""
+    decodes through; its cache by kind of state is the full layers' whole K
+    and V and the sliding layers' rings, its prefill's flash calls its
+    layers' of both kinds, all their heads."""
 
     def __init__(self, config: LagunaConfig) -> None:
-        self.config = config
-
-    def prefill(self, params, ids, lengths, cache_len: int, task_index):
-        return prefill(self.config, params, ids, lengths, cache_len)
-
-    def decode(self, params, cache, tokens, positions, task_index):
-        return decode(self.config, params, cache, tokens, positions)
-
-    def rows_per_group(self, params, rows: int, bucket: int,
-                       cache_len: int) -> int:
-        """How many rows of such a prefill go through the layers
-        together."""
-        return prefill_group(self.config, params, rows, bucket, cache_len)
-
-    def attn_tiles(self, lengths, bucket: int):
-        """``(visited, grid)`` of such a prefill's flash calls, which are
-        handed the rows' lengths (``flash_attention.tiles_for``), over its
-        layers of both kinds and their heads."""
-        cfg = self.config
-        return causal_tiles(bucket, lengths, [
-            (cfg.heads(kind), 0 if kind == "full_attention"
-             else 2 * (cfg.sliding_window - 1))
-            for kind in cfg.layer_types])
-
-    @staticmethod
-    def cache_bytes(cache) -> Dict[str, int]:
-        """The cache's bytes by kind of state: the full layers' whole K and
-        V, the sliding layers' rings."""
-        return {k: tree_bytes(cache[k]) for k in ("full", "window")}
+        super().__init__(
+            config, prefill, decode, cache_kinds=("full", "window"),
+            group_sizes=(_row_bytes, _cache_bytes),
+            attn_layers=[
+                (config.heads(kind), 0 if kind == "full_attention"
+                 else 2 * (config.sliding_window - 1))
+                for kind in config.layer_types])

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.rope import RopeSpec, apply_rotary
-from .sdar_moe import NEG_INF
+from .decoder_parts import NEG_INF
 
 
 @dataclasses.dataclass(frozen=True)

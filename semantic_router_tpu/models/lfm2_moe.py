@@ -11,7 +11,7 @@ RMSNorm (``embedding_norm``), a head tied to the embedding.
   ``full_attention``: causal GQA with per-head RMSNorm on q and k, RoPE,
   no bias.
 - ``FF``: a dense SwiGLU in the first ``num_dense_layers`` layers, else the
-  sparse experts of ``sdar_moe.routed_experts`` behind the SIGMOID router:
+  sparse experts of ``experts.routed_experts`` behind the SIGMOID router:
   ``s = sigmoid(W_g h)``, the top k of ``s + b`` (``use_expert_bias``) are
   chosen, the weights are the UNBIASED ``s`` there, ``/ (sum + 1e-6)``
   (``norm_topk_prob``), ``* routed_scaling_factor``.
@@ -32,10 +32,10 @@ softmax, the gate products of the convolution, the router's logits and
 sigmoid and the head's logits in float32 (the published code computes the
 router's logits in the model's dtype: more precise here, never less).
 
-A prefill is bounded in tokens: rows are mapped INSIDE the program
-(``map_row_groups``) a GROUP at a time, so the layers' temporaries exist
-for one group and an expert layer's grouped matmuls read each touched
-expert once a group (``rows_per_group`` has the rule); decoding runs all
+A prefill is bounded in tokens: rows are mapped INSIDE the program a GROUP
+at a time (``models/mapped_prefill.py``: ``rows_per_group`` has the rule),
+so the layers' temporaries exist for one group and an expert layer's
+grouped matmuls read each touched expert once a group; decoding runs all
 rows together.
 
 Scopes: ``embed_tokens``, ``layers_<i>/conv`` (``in_proj``, ``conv1d``,
@@ -46,23 +46,32 @@ Scopes: ``embed_tokens``, ``layers_<i>/conv`` (``in_proj``, ``conv1d``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.flash_attention import causal_tiles, flash_attention
-from .qwen3 import torch_dtype_of
-from .sdar_moe import (
-    NEG_INF,
+from ..ops.flash_attention import flash_attention
+from .cached_model import CachedDecoder
+from .checkpoints import (
     checkpoint_reader,
-    qkv,
-    rms_norm,
-    routed_experts,
+    on_device,
+    swiglu_matrices,
+    torch_dtype_of,
 )
+from .decoder_parts import NEG_INF, qkv, rms_norm
+from .experts import (
+    expert_ids,
+    feed_forward,
+    routed_experts,
+    sigmoid_route,
+)
+from .mapped_prefill import prefill_group, prefill_in_groups
 
 LAYER_TYPES = ("conv", "full_attention")
+_SWIGLU = ("w1", "w3", "w2")  # the published names of gate, up, down
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,7 +105,7 @@ class Lfm2MoeConfig:
         return self.experts_held or (0, self.num_experts)
 
     @property
-    def rms_norm_eps(self) -> float:  # the name sdar_moe.qkv reads
+    def rms_norm_eps(self) -> float:  # the name the shared parts read
         return self.norm_eps
 
     @classmethod
@@ -143,12 +152,7 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: Lfm2MoeConfig
     """The published tensor names (``get(name)`` loads one) as this
     module's tree, in ``cfg.dtype`` on the default device; the router's
     selection bias stays float32.  Only the experts held are read."""
-
-    def dev(a: np.ndarray, transpose: bool = False) -> jnp.ndarray:
-        x = jnp.asarray(a).astype(cfg.dtype)
-        return jnp.swapaxes(x, -1, -2) if transpose else x
-
-    first, count = cfg.held
+    dev = functools.partial(on_device, cfg)
     layers = []
     for i, kind in enumerate(cfg.layer_types):
         p = f"model.layers.{i}."
@@ -171,24 +175,15 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: Lfm2MoeConfig
                 k_norm=dev(get(a + "k_layernorm.weight")))
         f = p + "feed_forward."
         if cfg.is_sparse(i):
-            experts = {k: np.stack([get(f"{f}experts.{e}.{k}.weight")
-                                    for e in range(first, first + count)])
-                       for k in ("w1", "w3", "w2")}
             layer.update(
                 router=dev(get(f + "gate.weight"), True),
-                gate_up=jnp.concatenate([dev(experts["w1"], True),
-                                         dev(experts["w3"], True)], -1),
-                down=dev(experts["w2"], True))
+                **swiglu_matrices(get, cfg, f + "experts.", _SWIGLU,
+                                  experts=cfg.held))
             if cfg.use_expert_bias:
                 layer["expert_bias"] = jnp.asarray(
                     np.asarray(get(f + "expert_bias"), np.float32))
-            del experts
         else:
-            layer.update(
-                gate_up=jnp.concatenate([dev(get(f + "w1.weight"), True),
-                                         dev(get(f + "w3.weight"), True)],
-                                        -1),
-                down=dev(get(f + "w2.weight"), True))
+            layer.update(swiglu_matrices(get, cfg, f, _SWIGLU))
         layers.append(layer)
     params = {"embed": dev(get("model.embed_tokens.weight")),
               "layers": layers,
@@ -199,24 +194,6 @@ def params_from_state(get: Callable[[str], np.ndarray], cfg: Lfm2MoeConfig
 
 
 # -- layers ----------------------------------------------------------------------
-
-
-def sigmoid_route(x, router, bias, k: int, norm: bool, scaling: float,
-                  eps: float):
-    """The sigmoid-and-bias choice of every decoder that has one (this one
-    and ``models/dots3_note.py``): ``x [T, H]`` -> ``(top_e [T, k], weights
-    [T, k] float32)``.  ``s = sigmoid(x W)`` in float32; the ``k`` largest
-    of ``s + bias`` are chosen (``bias`` None: of ``s``); the weights are
-    the UNBIASED ``s`` there, over ``their sum + eps`` if ``norm``, times
-    ``scaling``.  The bias moves the choice and never the weights."""
-    logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
-    s = jax.nn.sigmoid(logits)
-    pick = s if bias is None else s + bias
-    _, top_e = jax.lax.top_k(pick, k)
-    w = jnp.take_along_axis(s, top_e, -1)
-    if norm:
-        w = w / (jnp.sum(w, -1, keepdims=True) + eps)
-    return top_e, w * scaling
 
 
 def route(cfg: Lfm2MoeConfig, p, x):
@@ -236,55 +213,6 @@ def moe(cfg: Lfm2MoeConfig, p, x, valid):
     return y, top_e, load
 
 
-def _swiglu(cfg, p, x):
-    gu = x @ p["gate_up"]
-    I = p["down"].shape[0]
-    h = jax.nn.silu(gu[..., :I].astype(jnp.float32)) \
-        * gu[..., I:].astype(jnp.float32)
-    return h.astype(cfg.dtype) @ p["down"]
-
-
-def _ffn(cfg, i, p, h, valid):
-    """What the second half of layer ``i`` adds to the residual stream,
-    from its normed input ``h [B, S, H]``, and the experts' ``(top_e [B *
-    S, k], load)``; a dense layer reports no experts."""
-    B, S, H = h.shape
-    if not cfg.is_sparse(i):
-        with jax.named_scope("mlp"):
-            return _swiglu(cfg, p, h), None, None
-    with jax.named_scope("moe"):
-        y, top_e, load = moe(cfg, p, h.reshape(B * S, H), valid.reshape(-1))
-    return y.reshape(B, S, H), top_e, load
-
-
-def _feed_forward(cfg, i, p, x, valid):
-    """The second half of layer ``i`` on ``x [B, S, H]``; ``top_e`` comes
-    back ``[B, S, k]``."""
-    B, S, _ = x.shape
-    y, top_e, load = _ffn(
-        cfg, i, p, rms_norm(x, p["norm2"], cfg.norm_eps, cfg.dtype), valid)
-    x = x + y
-    return x, None if top_e is None else top_e.reshape(B, S, -1), load
-
-
-def _feed_forward_as_one_row(cfg, i, p, x, valid):
-    """``_feed_forward`` of a prefill's group ``x [G, S, H]``: the norm in
-    the rows' shape (it rides the epilogue of the matmul before it), then
-    the group's tokens as ONE row of ``G * S`` through the layer and the
-    residual sum — a token's feed-forward does not know its row.  With
-    the sum in the rows' shape the compiler cuts the expert layer's
-    combine at the reshape between them and writes the float32 copies of
-    all ``k`` gathered slices (1.07 GB a layer at four rows; 54 ms of a
-    757 ms prefill on a v5e, PERF.md section 6, PR 37).  One row is its
-    own shape: nothing is reshaped."""
-    G, S, H = x.shape
-    h = rms_norm(x, p["norm2"], cfg.norm_eps, cfg.dtype)
-    y, top_e, load = _ffn(cfg, i, p, h.reshape(1, G * S, H),
-                          valid.reshape(1, G * S))
-    return (x.reshape(1, G * S, H) + y).reshape(G, S, H), \
-        None if top_e is None else top_e.reshape(G, S, -1), load
-
-
 def _gates(cfg, p, h):
     """``h [..., H]`` -> the conv operator's ``z = B * u`` (in the model's
     dtype: what the state holds) and its output gate ``C`` (float32)."""
@@ -298,10 +226,6 @@ def _taps(p, z_window):
     w = p["conv_w"].astype(jnp.float32)
     return sum(w[k] * z_window[k].astype(jnp.float32)
                for k in range(w.shape[0]))
-
-
-def _expert_ids(cfg, top_e):
-    return top_e.astype(jnp.uint8 if cfg.num_experts <= 256 else jnp.int32)
 
 
 def _head(cfg, params, x):
@@ -362,97 +286,14 @@ def _prefill_rows(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int):
                     pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - S))
                     kv.append(tuple(jnp.pad(jnp.swapaxes(t, 2, 3), pad)
                                     for t in (kc, vc)))
-            x, top_e, load = _feed_forward_as_one_row(cfg, i, p, x, valid)
+            x, top_e, load = feed_forward(cfg, cfg.is_sparse(i), p, x, valid,
+                                          moe, as_one_row=True)
             if top_e is not None:
-                experts.append(_expert_ids(cfg, top_e))
+                experts.append(expert_ids(top_e, cfg.num_experts))
                 loads.append(load)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     return (kv, conv, _head(cfg, params, x_last), jnp.stack(experts),
             jnp.stack(loads))
-
-
-def _sum_loads(loads):
-    """Per-group ``load [groups, layers, 4]`` of a mapped prefill as one
-    ``[layers, 4]``: every group's grouped matmul reads its own touched
-    experts once, so pairs and experts touched add up over the groups; the
-    busiest is the busiest of any group, the ratio the groups' mean."""
-    return jnp.stack([loads[..., 0].max(0), loads[..., 1].sum(0),
-                      loads[..., 2].sum(0), loads[..., 3].mean(0)], -1)
-
-
-def device_bytes() -> Optional[int]:
-    """What the device a program is traced for may allocate; None where
-    the backend reports no limit (the CPU)."""
-    stats = jax.local_devices()[0].memory_stats() or {}
-    return stats.get("bytes_limit")
-
-
-def tree_bytes(tree) -> int:
-    """The bytes of a tree's arrays (or of their shapes, under a trace)."""
-    return sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
-               for a in jax.tree_util.tree_leaves(tree))
-
-
-SPARE_BYTES = 1_500_000_000
-# half a v5e core's own memory (VMEM, 128 MiB): what a group's normed
-# activations may take for the compiler to keep them there
-GATHER_SOURCE_BYTES = 64 * 2**20
-
-
-def rows_per_group(rows: int, row_bytes: int, resident_bytes: int,
-                   source_bytes: int) -> int:
-    """How many of a prefill's ``rows`` run through the layers together
-    (``map_row_groups``).  More rows a group feed each touched expert's
-    matrices more pairs a read.  Two things bound a group: its
-    temporaries, ``row_bytes`` a row beside ``resident_bytes`` of weights
-    and cache on a device of ``device_bytes()``, must leave ``SPARE_BYTES``
-    free (the allocator's fragments, the decode program's temporaries);
-    and its normed activations ``[G * S, H]``, ``source_bytes`` a row —
-    which the expert layer's first gather reads k times a token — must
-    stay within ``GATHER_SOURCE_BYTES``, where the compiler keeps them in
-    the core's own memory and the gather costs 13 ns a row, not 33.  The
-    rule: the largest divisor of ``rows`` within both; 1 where not even
-    one row is; where the backend reports no limit (the CPU), every row.
-
-    Set from ``benchmarks/results/lfm2_prefill_groups.json`` (one v5e, the
-    lfm2_moe guard's 8 x 8192 prefill, PERF.md section 6, PR 37), rows a
-    group -> whole prefill ms / grouped matmuls ms a row-layer / the
-    gathers' scope ms / the compiler's temporaries GB: 1 -> 752 / 4.05 /
-    83 / 0.75; 2 -> 642 / 3.27 / 34 / 1.32 (activations 67 MB, in the
-    core's memory); 4 -> 685 / 2.77 / 91 / 2.60 (134 MB: not); 8 -> 670 /
-    2.51 / 91 / 5.13 (leaves 1.1 GB of a 16.9 GB device beside 10.6).  So
-    the guard's cell runs 2 rows a group, the dots3_note cell (a row's
-    temporaries 5.8 GB reckoned, 4.2 by the compiler; activations 84 MB a
-    row) 1."""
-    limit = device_bytes()
-    if limit is None:
-        return rows
-    fit = min((limit - resident_bytes - SPARE_BYTES) // row_bytes,
-              GATHER_SOURCE_BYTES // source_bytes)
-    return max(g for g in range(1, rows + 1)
-               if rows % g == 0 and g <= max(fit, 1))
-
-
-def map_row_groups(rows_fn, group: int, ids, lengths):
-    """``rows_fn(ids [G, S], lengths [G]) -> (per_row, per_group)`` over
-    the batch ``ids [B, S]``, ``lengths [B]``, ``group`` rows a call, one
-    call at a time INSIDE the program (``jax.lax.map``: the temporaries
-    are one group's whatever the batch).  Every leaf of ``per_row`` has
-    the group's rows on its leading axis and comes back ``[B, ...]`` in
-    the batch's order; ``per_group`` comes back stacked ``[B / group,
-    ...]``.  ``group`` divides ``B``.  Not ``jax.lax.map(batch_size=)``:
-    that ``vmap``s a one-row body, and a ``vmap`` of the grouped matmul is
-    one grouped matmul a row under one more grid axis, each reading every
-    expert; here a group's rows are ONE call's tokens."""
-    B = ids.shape[0]
-
-    def split(a):
-        return a.reshape((B // group, group) + a.shape[1:])
-
-    per_row, per_group = jax.lax.map(lambda g: rows_fn(*g),
-                                     (split(ids), split(lengths)))
-    return jax.tree_util.tree_map(
-        lambda a: a.reshape((B,) + a.shape[2:]), per_row), per_group
 
 
 def _row_bytes(cfg: Lfm2MoeConfig, S: int) -> int:
@@ -480,38 +321,25 @@ def _cache_bytes(cfg: Lfm2MoeConfig, rows: int, cache_len: int) -> int:
 def _prefill_groups(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int,
                     group: int):
     """``prefill`` at ``group`` rows a call of ``_prefill_rows``."""
-    def rows(ids, lengths):
-        kv, conv, logits, experts, load = _prefill_rows(
-            cfg, params, ids, lengths, cache_len)
-        return (kv, conv, logits, jnp.moveaxis(experts, 1, 0)), load
-
-    (kv, conv, logits, experts), loads = map_row_groups(
-        rows, group, ids, lengths)
-    cache = {"kv": kv, "conv": conv, "lengths": lengths.astype(jnp.int32)}
-    return cache, logits, {"experts": jnp.moveaxis(experts, 0, 1),
-                           "load": _sum_loads(loads)}
-
-
-def prefill_group(cfg: Lfm2MoeConfig, params, rows: int, S: int,
-                  cache_len: int) -> int:
-    """The rows a group of a prefill of ``rows`` x ``S``
-    (``rows_per_group`` at this model's sizes)."""
-    return rows_per_group(
-        rows, _row_bytes(cfg, S),
-        tree_bytes(params) + _cache_bytes(cfg, rows, cache_len),
-        S * cfg.hidden_size * jnp.dtype(cfg.dtype).itemsize)
+    return prefill_in_groups(
+        lambda ids, lengths: _prefill_rows(cfg, params, ids, lengths,
+                                           cache_len),
+        ("kv", "conv", "logits", "experts", "load"), ("experts",), group,
+        ids, lengths)
 
 
 def prefill(cfg: Lfm2MoeConfig, params, ids, lengths, cache_len: int):
     """``ids [B, S]`` right-padded prompts of ``lengths [B]`` (0 = a padding
     row) -> ``(cache, logits [B, V] float32 at each row's last token, aux)``
     with ``aux = {"experts" [expert layers, B, S, k], "load" [expert
-    layers, 4]}``.  ``prefill_group`` rows at a time inside the program, so
-    a bucket's temporaries are those of ONE group whatever the batch, and
-    an expert layer's grouped matmuls serve a group's tokens together."""
+    layers, 4]}``.  ``mapped_prefill.prefill_group`` rows at a time inside
+    the program, so a bucket's temporaries are those of ONE group whatever
+    the batch, and an expert layer's grouped matmuls serve a group's tokens
+    together."""
     return _prefill_groups(
         cfg, params, ids, lengths, cache_len,
-        prefill_group(cfg, params, *ids.shape, cache_len))
+        prefill_group(cfg, params, *ids.shape, cache_len, _row_bytes,
+                      _cache_bytes))
 
 
 # -- decode: one token a row against the hybrid cache ----------------------------
@@ -567,46 +395,24 @@ def decode(cfg: Lfm2MoeConfig, params, cache, tokens, positions):
                         v_cache, preferred_element_type=jnp.float32)
                     x = x + (out.reshape(B, nh * D).astype(cfg.dtype)
                              @ p["o_proj"])[:, None]
-            x, top_e, load = _feed_forward(cfg, i, p, x, live[:, None])
+            x, top_e, load = feed_forward(cfg, cfg.is_sparse(i), p, x,
+                                          live[:, None], moe)
             if top_e is not None:
-                experts.append(_expert_ids(cfg, top_e[:, 0]))
+                experts.append(expert_ids(top_e[:, 0], cfg.num_experts))
                 loads.append(load)
     cache = {"kv": kv, "conv": conv, "lengths": cache["lengths"]}
     return cache, _head(cfg, params, x[:, 0]), {
         "experts": jnp.stack(experts), "load": jnp.stack(loads)}
 
 
-class CachedModel:
+class CachedModel(CachedDecoder):
     """This decoder behind the interface ``models.generate.GreedyGenerator``
-    decodes through (``generate.Qwen3Cached`` says what it is); no
-    adapters here, ``task_index`` is accepted and unused."""
+    decodes through; its prefill's flash calls are its attention layers',
+    all their heads over the whole prompt."""
 
     def __init__(self, config: Lfm2MoeConfig) -> None:
-        self.config = config
-
-    def prefill(self, params, ids, lengths, cache_len: int, task_index):
-        return prefill(self.config, params, ids, lengths, cache_len)
-
-    def decode(self, params, cache, tokens, positions, task_index):
-        return decode(self.config, params, cache, tokens, positions)
-
-    def rows_per_group(self, params, rows: int, bucket: int,
-                       cache_len: int) -> int:
-        """How many rows of such a prefill go through the layers
-        together."""
-        return prefill_group(self.config, params, rows, bucket, cache_len)
-
-    def attn_tiles(self, lengths, bucket: int):
-        """``(visited, grid)`` of such a prefill's flash calls, which are
-        handed the rows' lengths (``flash_attention.tiles_for``), over its
-        attention layers and their heads."""
-        cfg = self.config
-        return causal_tiles(bucket, lengths, [
-            (cfg.num_attention_heads, 0) for kind in cfg.layer_types
-            if kind != "conv"])
-
-    @staticmethod
-    def cache_bytes(cache) -> Dict[str, int]:
-        """The cache's bytes by kind of state."""
-        return {"kv": tree_bytes(cache["kv"]),
-                "conv": tree_bytes(cache["conv"])}
+        super().__init__(
+            config, prefill, decode, cache_kinds=("kv", "conv"),
+            group_sizes=(_row_bytes, _cache_bytes),
+            attn_layers=[(config.num_attention_heads, 0)
+                         for kind in config.layer_types if kind != "conv"])

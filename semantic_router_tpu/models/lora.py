@@ -107,7 +107,7 @@ class ModernBertLoRAHeadClassifier(nn.Module):
     @nn.compact
     def __call__(self, input_ids: jnp.ndarray,
                  attention_mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-        from .modernbert import _act
+        from .modernbert import activation
 
         cfg = self.config
         if attention_mask is None:
@@ -122,7 +122,7 @@ class ModernBertLoRAHeadClassifier(nn.Module):
         B = self.param("lora_B", nn.initializers.zeros,
                        (self.lora.rank, cfg.hidden_size))
         h = h + self.lora.scale * ((pooled @ A) @ B)
-        h = _act(cfg.classifier_activation)(h)
+        h = activation(cfg.classifier_activation)(h)
         h = nn.LayerNorm(epsilon=cfg.norm_eps, use_bias=cfg.norm_bias,
                          name="head_norm", dtype=cfg.dtype)(h)
         return nn.Dense(self.num_labels, use_bias=True, name="classifier",

@@ -31,13 +31,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import NEG_INF, chunked_sdpa, sdpa
 from ..ops.rope import RopeSpec, apply_rotary
-
-
-def torch_dtype_of(name) -> Any:
-    """A checkpoint's ``torch_dtype`` (``"bfloat16"``, ``torch.bfloat16``)
-    as the dtype a model computes in; anything unknown is float32."""
-    return {"bfloat16": jnp.bfloat16, "float16": jnp.float16}.get(
-        str(name).replace("torch.", ""), jnp.float32)
+from .checkpoints import torch_dtype_of
 
 
 @dataclasses.dataclass(frozen=True)

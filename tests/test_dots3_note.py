@@ -25,8 +25,10 @@ import pytest
 import per_step_loop
 from chipbench import cells
 from test_lfm2_moe import ROW_GROUPS, touched_by_group
+from semantic_router_tpu.models import checkpoints
 from semantic_router_tpu.models import dots3_note as M
-from semantic_router_tpu.models import lfm2_moe, sdar_moe
+from semantic_router_tpu.models import experts as expert_layer
+from semantic_router_tpu.models import lfm2_moe
 from semantic_router_tpu.models.generate import GreedyGenerator
 from semantic_router_tpu.utils.tokenization import Encoding
 
@@ -240,7 +242,7 @@ def test_the_ring_holds_the_latest_window(toy, served):
     short, _, _ = M.prefill(cfg, params, *padded([row[:3]], 48), 64)
     ring = np.asarray(short["window"][0])[0]
     assert (np.abs(ring[:3]).sum(-1) > 0).all() and (ring[3:] == 0).all()
-    assert M.CachedModel.cache_bytes(cache) == {
+    assert M.CachedModel(cfg).cache_bytes(cache) == {
         "latent": 3 * 64 * (16 + 8) * 4, "index": 3 * 64 * 16 * 4,
         "window": 2 * 5 * (32 + 8) * 4}
 
@@ -337,7 +339,8 @@ def test_the_shares_add_up_to_the_uncut_layer(impl, monkeypatch):
     """The routed parts of the shares of all four chips, plus the shared
     expert counted ONCE, are the uncut reference layer."""
     if impl == "megablox":
-        monkeypatch.setattr(sdar_moe, "_grouped_matmul", sdar_moe._megablox)
+        monkeypatch.setattr(expert_layer, "_grouped_matmul",
+                            expert_layer._megablox)
     hf, state, cfg, params = variant(experts=(0, 16))
     x = jnp.asarray(np.random.default_rng(2).standard_normal((11, 64)),
                     jnp.float32)
@@ -345,12 +348,12 @@ def test_the_shares_add_up_to_the_uncut_layer(impl, monkeypatch):
     w = ref.layer_weights(hf, state, 1, "highest", (0, 16))["ff"]
     want, _, _ = ref.moe(hf, w, x, (0, 16))
     p = params["layers"][1]
-    total = M._swiglu(cfg, p["shared"], x)
+    total = expert_layer.swiglu(cfg, p["shared"], x)
     for first in range(0, 16, 4):
         part = dict(p, gate_up=p["gate_up"][first:first + 4],
                     down=p["down"][first:first + 4])
         top_e, top_w = M.route(cfg, p, x)
-        y, load = sdar_moe.routed_experts(part, x, valid, top_e, top_w,
+        y, load = expert_layer.routed_experts(part, x, valid, top_e, top_w,
                                           (first, 4), cfg.dtype)
         total = total + y
         routed, _, _ = ref.moe(hf, {**w, **{
@@ -386,7 +389,7 @@ def test_params_hold_only_what_is_held(tmp_path):
     cfg = M.Dots3NoteConfig.from_hf(hf, experts_held=EXPERTS,
                                     vocab_held=VOCAB)
     asked, sliced = [], []
-    with sdar_moe.checkpoint_reader(dirs["jailbreak"]) as get:
+    with checkpoints.checkpoint_reader(dirs["jailbreak"]) as get:
         def spy(name):
             asked.append(name)
             return get(name)

@@ -31,7 +31,8 @@ from chipbench import cells
 from test_dots3_note import WordTokenizer, prompts, words
 from semantic_router_tpu.models import generate as G
 from semantic_router_tpu.models import joyai_llm_flash as M
-from semantic_router_tpu.models import dots3_note, sdar_moe
+from semantic_router_tpu.models import checkpoints, decoder_parts, dots3_note
+from semantic_router_tpu.models import experts as expert_layer
 from semantic_router_tpu.models.generate import GreedyGenerator
 
 MODEL = {
@@ -208,7 +209,7 @@ def blind_second(pos, Mx):
 
 def mtp_input_without(which: str):
     def fn(cfg, m, embed_next, hidden):
-        norm = lambda x, w: sdar_moe.rms_norm(  # noqa: E731
+        norm = lambda x, w: decoder_parts.rms_norm(  # noqa: E731
             x, w, cfg.rms_norm_eps, cfg.dtype)
         return jnp.concatenate(
             [embed_next if which == "enorm" else norm(embed_next, m["enorm"]),
@@ -533,7 +534,8 @@ def test_the_two_chips_shares_add_up_to_the_uncut_layer(impl, monkeypatch):
     """The routed parts of both chips' shares, plus the shared expert
     counted ONCE, are the uncut reference layer (the 2.5 among them)."""
     if impl == "megablox":
-        monkeypatch.setattr(sdar_moe, "_grouped_matmul", sdar_moe._megablox)
+        monkeypatch.setattr(expert_layer, "_grouped_matmul",
+                            expert_layer._megablox)
     hf, state, cfg, params = variant(experts=(0, 16))
     x = jnp.asarray(np.random.default_rng(2).standard_normal((11, 64)),
                     jnp.float32)
@@ -541,13 +543,13 @@ def test_the_two_chips_shares_add_up_to_the_uncut_layer(impl, monkeypatch):
     w = ref.layer_weights(hf, state, 1, "highest", (0, 16))["ff"]
     want, _, _ = ref.moe(hf, w, x, (0, 16))
     p = params["layers"][1]
-    total = dots3_note._swiglu(cfg, p["shared"], x)
+    total = expert_layer.swiglu(cfg, p["shared"], x)
     top_e, top_w = dots3_note.route(cfg, p, x)
     np.testing.assert_allclose(np.asarray(top_w).sum(-1), 2.5, rtol=1e-6)
     for first in (0, 8):
         part = dict(p, gate_up=p["gate_up"][first:first + 8],
                     down=p["down"][first:first + 8])
-        y, _ = sdar_moe.routed_experts(part, x, valid, top_e, top_w,
+        y, _ = expert_layer.routed_experts(part, x, valid, top_e, top_w,
                                        (first, 8), cfg.dtype)
         total = total + y
         routed, _, _ = ref.moe(hf, {**w, **{
@@ -580,7 +582,7 @@ def test_params_hold_only_what_is_held_and_the_head_once(tmp_path):
     assert hf["n_routed_experts"] == 16 and hf["num_hidden_layers"] == 3
     cfg = M.JoyaiLlmFlashConfig.from_hf(hf, experts_held=EXPERTS)
     asked = []
-    with sdar_moe.checkpoint_reader(dirs["jailbreak"]) as get:
+    with checkpoints.checkpoint_reader(dirs["jailbreak"]) as get:
         def spy(name):
             asked.append(name)
             return get(name)

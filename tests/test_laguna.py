@@ -30,7 +30,8 @@ import pytest
 import per_step_loop
 from chipbench import cells
 from test_lfm2_moe import ROW_GROUPS, touched_by_group
-from semantic_router_tpu.models import gated_window, sdar_moe
+from semantic_router_tpu.models import checkpoints, gated_window
+from semantic_router_tpu.models import experts as expert_layer
 from semantic_router_tpu.models import laguna as M
 from semantic_router_tpu.models.generate import GreedyGenerator
 from semantic_router_tpu.ops import rope as rope_ops
@@ -196,10 +197,10 @@ def test_the_ring_holds_the_latest_window(toy):
     ring = np.asarray(short["window"][0][0])[0]  # [kv, W, D]
     assert (np.abs(ring[:, :3]).sum(-1) > 0).all() \
         and (ring[:, 3:] == 0).all()
-    assert M.CachedModel.cache_bytes(cache) == {
+    assert M.CachedModel(cfg).cache_bytes(cache) == {
         "full": 2 * 2 * 2 * 64 * 16 * 4, "window": 3 * 2 * 2 * 8 * 16 * 4}
     assert M._cache_bytes(cfg, 1, 64) == sum(
-        M.CachedModel.cache_bytes(cache).values())
+        M.CachedModel(cfg).cache_bytes(cache).values())
 
 
 def test_the_ring_is_dots3s_ring():
@@ -321,7 +322,7 @@ def test_the_router_is_the_one_sdar_calls(toy):
     x = jnp.asarray(np.random.default_rng(1).standard_normal((9, 64)),
                     jnp.float32)
     top_e, w = M.route(cfg, p, x)
-    other = sdar_moe.softmax_route(x, p["router"], 3, True)
+    other = expert_layer.softmax_route(x, p["router"], 3, True)
     assert (np.asarray(top_e) == np.asarray(other[0])).all()
     np.testing.assert_allclose(np.asarray(w), 2.5 * np.asarray(other[1]),
                                rtol=1e-6)
@@ -333,7 +334,8 @@ def test_the_shares_add_up_to_the_uncut_layer(impl, monkeypatch):
     """The routed parts of the shares of all four chips, plus the gated
     shared expert counted ONCE, are the uncut reference layer."""
     if impl == "megablox":
-        monkeypatch.setattr(sdar_moe, "_grouped_matmul", sdar_moe._megablox)
+        monkeypatch.setattr(expert_layer, "_grouped_matmul",
+                            expert_layer._megablox)
     hf, state, cfg, params = variant(experts=(0, 16))
     x = jnp.asarray(np.random.default_rng(2).standard_normal((11, 64)),
                     jnp.float32)
@@ -346,7 +348,7 @@ def test_the_shares_add_up_to_the_uncut_layer(impl, monkeypatch):
         part = dict(p, gate_up=p["gate_up"][first:first + 4],
                     down=p["down"][first:first + 4])
         top_e, top_w = M.route(cfg, p, x)
-        y, _ = sdar_moe.routed_experts(part, x, valid, top_e, top_w,
+        y, _ = expert_layer.routed_experts(part, x, valid, top_e, top_w,
                                        (first, 4), cfg.dtype)
         total = total + y
         routed, _, _ = ref.moe(hf, {**w, **{
@@ -406,7 +408,7 @@ def test_params_hold_only_what_is_held(tmp_path):
     assert hf["num_experts"] == 16 and hf["vocab_size"] == 512
     cfg = M.LagunaConfig.from_hf(hf, experts_held=EXPERTS, vocab_held=VOCAB)
     asked, sliced = [], []
-    with sdar_moe.checkpoint_reader(dirs["jailbreak"]) as get:
+    with checkpoints.checkpoint_reader(dirs["jailbreak"]) as get:
         def spy(name):
             asked.append(name)
             return get(name)

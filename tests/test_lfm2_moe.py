@@ -26,8 +26,9 @@ import pytest
 
 import per_step_loop
 from chipbench import cells
+from semantic_router_tpu.models import experts as expert_layer
 from semantic_router_tpu.models import lfm2_moe as M
-from semantic_router_tpu.models import sdar_moe
+from semantic_router_tpu.models import mapped_prefill, sdar_moe
 from semantic_router_tpu.models.generate import GreedyGenerator
 from semantic_router_tpu.utils.tokenization import Encoding
 
@@ -176,7 +177,7 @@ def test_prefill_then_decode_equal_the_full_forward(toy):
                 == np.sort(want["top_e"][:, :n], -1)).all()
     # two kinds of state side by side: 2 attention layers of K and V,
     # 3 conv layers of 2 vectors a row
-    assert M.CachedModel.cache_bytes(cache) == {
+    assert M.CachedModel(cfg).cache_bytes(cache) == {
         "kv": 2 * 2 * 4 * 2 * 23 * 16 * 4, "conv": 3 * 4 * 2 * 64 * 4}
 
 
@@ -279,30 +280,38 @@ def test_a_prefill_in_groups_equals_its_rows_one_at_a_time(toy, rows, group,
         touched_by_group(experts, lens, group))
 
 
-@pytest.mark.parametrize("name, rows, limit, want", [
+@pytest.mark.parametrize("name, rows, bucket, limit, want", [
     # the lfm2_moe cell: 10.36 GB of weights, 0.84 GB a row reckoned, 34 MB
     # of activations a row: memory would hold 5 rows, the core's own 2
-    ("lfm2-24b-a2b-guard", 8, V5E_BYTES, 2),
-    ("lfm2-24b-a2b-guard", 4, V5E_BYTES, 2),
-    ("lfm2-24b-a2b-guard", 2, V5E_BYTES, 2),
-    ("lfm2-24b-a2b-guard", 1, V5E_BYTES, 1),
+    ("lfm2-24b-a2b-guard", 8, 8192, V5E_BYTES, 2),
+    ("lfm2-24b-a2b-guard", 4, 8192, V5E_BYTES, 2),
+    ("lfm2-24b-a2b-guard", 2, 8192, V5E_BYTES, 2),
+    ("lfm2-24b-a2b-guard", 1, 8192, V5E_BYTES, 1),
     # a device that holds the weights and not one more row
-    ("lfm2-24b-a2b-guard", 8, 12e9, 1),
+    ("lfm2-24b-a2b-guard", 8, 8192, 12e9, 1),
     # the dots3_note cell: a row's attention arrays leave no room, and
     # its activations are 84 MB a row
-    ("dots3-note-guard", 8, V5E_BYTES, 1),
-    ("dots3-note-guard", 2, V5E_BYTES, 1),
-    ("dots3-note-guard", 1, V5E_BYTES, 1),
-    ("dots3-note-guard", 8, 4 * V5E_BYTES, 1),
+    ("dots3-note-guard", 8, 8192, V5E_BYTES, 1),
+    ("dots3-note-guard", 2, 8192, V5E_BYTES, 1),
+    ("dots3-note-guard", 1, 8192, V5E_BYTES, 1),
+    ("dots3-note-guard", 8, 8192, 4 * V5E_BYTES, 1),
+    # the laguna cell's two buckets: 11.14 GB of weights and 2.6 GB a long
+    # row reckoned leave room for one; a short row's activations are 3 MB
+    ("laguna-s21-guard", 8, 8192, V5E_BYTES, 1),
+    ("laguna-s21-guard", 8, 512, V5E_BYTES, 8),
+    ("laguna-s21-guard", 3, 512, V5E_BYTES, 3),
     # no limit known (the CPU): every row
-    ("lfm2-24b-a2b-guard", 3, None, 3),
-    ("dots3-note-guard", 8, None, 8),
+    ("lfm2-24b-a2b-guard", 3, 8192, None, 3),
+    ("dots3-note-guard", 8, 8192, None, 8),
+    ("laguna-s21-guard", 8, 8192, None, 8),
 ])
 def test_the_rows_a_group_follow_from_shapes_and_memory(monkeypatch, name,
-                                                        rows, limit, want):
-    """``rows_per_group``'s table at the cells' published widths (bucket
-    8192, cache 8256) on a v5e's memory."""
-    from semantic_router_tpu.models import dots3_note
+                                                        rows, bucket, limit,
+                                                        want):
+    """``mapped_prefill.rows_per_group``'s table at the cells' published
+    widths (cache = the bucket and 64) on a v5e's memory, each model's own
+    ``_row_bytes`` and ``_cache_bytes`` beside its cell's weights."""
+    from semantic_router_tpu.models import dots3_note, laguna
 
     with open(os.path.join(os.path.dirname(cells.__file__), "configs", name,
                            "model.json")) as f:
@@ -310,15 +319,19 @@ def test_the_rows_a_group_follow_from_shapes_and_memory(monkeypatch, name,
     if name.startswith("lfm2"):
         module, weights = M, 10.356e9
         cfg = M.Lfm2MoeConfig.from_hf(hf)
-    else:
+    elif name.startswith("dots3"):
         module, weights = dots3_note, 10.022e9
         cfg = dots3_note.Dots3NoteConfig.from_hf(
             hf, experts_held=(0, 32), vocab_held=(0, 19008))
-    monkeypatch.setattr(M, "device_bytes", lambda: limit)
-    assert M.rows_per_group(
-        rows, module._row_bytes(cfg, 8192),
-        weights + module._cache_bytes(cfg, rows, 8256),
-        8192 * cfg.hidden_size * 2) == want
+    else:
+        module, weights = laguna, 11.144e9
+        cfg = laguna.LagunaConfig.from_hf(
+            hf, experts_held=(0, 128), vocab_held=(0, 50176))
+    monkeypatch.setattr(mapped_prefill, "device_bytes", lambda: limit)
+    assert mapped_prefill.rows_per_group(
+        rows, module._row_bytes(cfg, bucket),
+        weights + module._cache_bytes(cfg, rows, bucket + 64),
+        bucket * cfg.hidden_size * 2) == want
 
 
 # -- the router --------------------------------------------------------------------
@@ -380,7 +393,8 @@ def test_the_shares_add_up_to_the_uncut_layer(toy, router, impl, monkeypatch):
     router) and computes its own; the four partial results add up to the
     uncut layer's, in the program and in the reference alike."""
     if impl == "megablox":
-        monkeypatch.setattr(sdar_moe, "_grouped_matmul", sdar_moe._megablox)
+        monkeypatch.setattr(expert_layer, "_grouped_matmul",
+                            expert_layer._megablox)
     x = jnp.asarray(np.random.default_rng(2).standard_normal((128, 64)),
                     jnp.float32)
     valid = jnp.ones(128, bool)

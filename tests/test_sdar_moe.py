@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from chipbench import cells
+from semantic_router_tpu.models import experts as expert_layer
 from semantic_router_tpu.models import sdar_moe as M
 from semantic_router_tpu.models.generate import (
     BlockDiffusionGenerator,
@@ -138,7 +139,8 @@ def test_the_shares_add_up_to_the_uncut_layer(toy, impl, monkeypatch):
     grouped matmuls: the CPU's own and the chip's kernel, interpreted."""
     state, cfg, params = toy
     if impl == "megablox":
-        monkeypatch.setattr(M, "_grouped_matmul", M._megablox)
+        monkeypatch.setattr(expert_layer, "_grouped_matmul",
+                            expert_layer._megablox)
     p = params["layers"][1]
     x = jnp.asarray(np.random.default_rng(2).standard_normal((128, 64)),
                     jnp.float32)
@@ -183,10 +185,10 @@ def _routed_experts_before(p, x, valid, top_e, top_w, held, dtype):
     group_sizes = jnp.bincount(group, length=count + 1)[:count] \
         .astype(jnp.int32)
     xs = jnp.take(x, order // k, axis=0)
-    gu = M._grouped_matmul(xs, p["gate_up"], group_sizes)
+    gu = expert_layer._grouped_matmul(xs, p["gate_up"], group_sizes)
     h = (jax.nn.silu(gu[:, :I].astype(jnp.float32))
          * gu[:, I:].astype(jnp.float32)).astype(dtype)
-    ys = M._grouped_matmul(h, p["down"], group_sizes)
+    ys = expert_layer._grouped_matmul(h, p["down"], group_sizes)
     w = jnp.where(here, top_w.reshape(-1), 0.0)
     ys = jnp.where(jnp.take(here, order)[:, None], ys.astype(jnp.float32),
                    0.0)
@@ -225,7 +227,7 @@ def test_the_combine_is_the_arithmetic_it_was(k, tokens, held, real, dtype):
     program the CPU's compiler may contract a multiply and an add of
     EITHER form into one rounding.)"""
     args = _a_routed_layer(k, tokens, held, real, jnp.dtype(dtype))
-    y, load = M.routed_experts(*args)
+    y, load = expert_layer.routed_experts(*args)
     want = _routed_experts_before(*args)
     assert y.dtype == want.dtype and y.shape == (tokens, 64)
     assert np.array_equal(np.asarray(y, np.float32),
@@ -235,7 +237,8 @@ def test_the_combine_is_the_arithmetic_it_was(k, tokens, held, real, dtype):
     assert tuple(np.asarray(load)[:3]) == (counts.max(), counts.sum(),
                                            (counts > 0).sum())
     # compiled as one program: to one unit in bfloat16's last place
-    jitted, _ = jax.jit(M.routed_experts, static_argnums=(5, 6))(*args)
+    jitted, _ = jax.jit(expert_layer.routed_experts,
+                        static_argnums=(5, 6))(*args)
     np.testing.assert_allclose(np.asarray(jitted, np.float32),
                                np.asarray(want, np.float32), rtol=2 ** -7,
                                atol=1e-6)
@@ -247,8 +250,8 @@ def test_rows_the_grouped_matmul_never_wrote_cannot_reach_y(
     """The chip's kernel leaves the rows past the held groups unwritten:
     with NaN standing there, ``y`` is what it was."""
     args = _a_routed_layer(4, tokens, held, real, jnp.bfloat16)
-    want, _ = M.routed_experts(*args)
-    grouped = M._grouped_matmul
+    want, _ = expert_layer.routed_experts(*args)
+    grouped = expert_layer._grouped_matmul
     unwritten = []
 
     def leaves_the_rest_unwritten(lhs, rhs, group_sizes):
@@ -257,8 +260,9 @@ def test_rows_the_grouped_matmul_never_wrote_cannot_reach_y(
         return jnp.where(rows < group_sizes.sum(),
                          grouped(lhs, rhs, group_sizes), jnp.nan)
 
-    monkeypatch.setattr(M, "_grouped_matmul", leaves_the_rest_unwritten)
-    y, _ = M.routed_experts(*args)
+    monkeypatch.setattr(expert_layer, "_grouped_matmul",
+                        leaves_the_rest_unwritten)
+    y, _ = expert_layer.routed_experts(*args)
     assert unwritten[0] > 0 and unwritten[0] == unwritten[1]
     assert np.isfinite(np.asarray(y, np.float32)).all()
     assert np.array_equal(np.asarray(y, np.float32),

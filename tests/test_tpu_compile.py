@@ -210,15 +210,15 @@ def on_the_described_chip(monkeypatch):
     """Steer the programs' platform reads to the described v5e (the test's
     backend is the CPU): the megablox kernel, the flash kernel compiled
     and not interpreted, and the device's memory for ``rows_per_group``."""
-    from semantic_router_tpu.models import lfm2_moe, sdar_moe
+    from semantic_router_tpu.models import experts, mapped_prefill
     from semantic_router_tpu.ops import flash_attention as fa
 
-    monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
+    monkeypatch.setattr(experts, "_on_cpu", lambda: False)
     monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
     monkeypatch.setattr(
         fa, "flash_attention_pallas",
         functools.partial(fa.flash_attention_pallas, interpret=False))
-    monkeypatch.setattr(lfm2_moe, "device_bytes", lambda: V5E_BYTES)
+    monkeypatch.setattr(mapped_prefill, "device_bytes", lambda: V5E_BYTES)
 
 
 def cell_model(name):
@@ -534,9 +534,10 @@ class TestBlockDiffusionGuardCompilesForV5e:
         """The megablox kernel under ``moe``'s tiling rule: a block forward
         of 1 and of 16 rows, of one block and of two (the forward that
         commits the block before), a prefill of 1 and of 16 rows."""
+        from semantic_router_tpu.models import experts
         from semantic_router_tpu.models import sdar_moe as M
 
-        monkeypatch.setattr(M, "_on_cpu", lambda: False)
+        monkeypatch.setattr(experts, "_on_cpu", lambda: False)
         cfg = M.SdarMoeConfig(num_hidden_layers=1)
         H, I, E = cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts
         bf = jnp.bfloat16
@@ -558,12 +559,13 @@ class TestBlockDiffusionGuardCompilesForV5e:
         """The generator's two block programs at the cell's shapes (bucket
         512, blocks of 4, one layer of the published widths) with their
         arguments as shapes on the described chip, and the cache's."""
+        from semantic_router_tpu.models import experts
         from semantic_router_tpu.models import sdar_moe as M
         from semantic_router_tpu.models.generate import (
             BlockDiffusionGenerator,
         )
 
-        monkeypatch.setattr(M, "_on_cpu", lambda: False)
+        monkeypatch.setattr(experts, "_on_cpu", lambda: False)
         cfg = M.SdarMoeConfig(num_hidden_layers=1)
         H, I, E, V = (cfg.hidden_size, cfg.moe_intermediate_size,
                       cfg.num_experts, cfg.vocab_size)
@@ -687,9 +689,9 @@ class TestHybridGuardCompilesForV5e:
         """The shared expert layer behind the sigmoid router, at THIS
         model's matrices ([2048, 3072] and [1536, 2048], whole-K tiles): a
         decode forward of 8 rows (32 pairs), a prefill row (32 k pairs)."""
-        from semantic_router_tpu.models import lfm2_moe, sdar_moe
+        from semantic_router_tpu.models import experts, lfm2_moe
 
-        monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
+        monkeypatch.setattr(experts, "_on_cpu", lambda: False)
         cfg = lfm2_moe.Lfm2MoeConfig(layer_types=("conv",),
                                      num_hidden_layers=1, num_dense_layers=0)
         H, I, E = cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts
@@ -748,7 +750,7 @@ class TestHybridGuardCompilesForV5e:
         assert aux["experts"].shape == (1, rows, S, 4)
         cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
                                        cache)
-        sizes = lfm2_moe.CachedModel.cache_bytes(cache)
+        sizes = lfm2_moe.CachedModel(cfg).cache_bytes(cache)
         assert sizes == {"kv": 2 * rows * 8 * M * 64 * 2,
                          "conv": rows * 2 * H * 2}
         # K and V with the columns last: the layout the loop keeps
@@ -814,9 +816,9 @@ class TestSparseLatentGuardCompilesForV5e:
         """``routed_experts`` at THIS model's matrices ([5120, 3072] by the
         rule's tiles, [1536, 5120]) with 32 of 256 experts held, behind
         the sigmoid router of 256 outputs, plus the shared expert."""
-        from semantic_router_tpu.models import dots3_note, sdar_moe
+        from semantic_router_tpu.models import experts, dots3_note
 
-        monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
+        monkeypatch.setattr(experts, "_on_cpu", lambda: False)
         cfg = dots3_note.Dots3NoteConfig(experts_held=(0, 32))
         H, I, E = (cfg.hidden_size, cfg.moe_intermediate_size,
                    cfg.n_routed_experts)
@@ -876,7 +878,7 @@ class TestSparseLatentGuardCompilesForV5e:
                                          S // 8)
         cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
                                        cache)
-        sizes = dots3_note.CachedModel.cache_bytes(cache)
+        sizes = dots3_note.CachedModel(cfg).cache_bytes(cache)
         assert sizes == {"latent": rows * M * (512 + 64) * 2,
                          "index": rows * M * 128 * 2,
                          "window": rows * 513 * (1024 + 64) * 2}
@@ -938,7 +940,7 @@ class TestSelfDraftingGuardCompilesForV5e:
         assert aux["experts"].shape == (2, rows, S, 8)
         cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
                                        cache)
-        sizes = joyai_llm_flash.CachedModel.cache_bytes(cache)
+        sizes = joyai_llm_flash.CachedModel(cfg).cache_bytes(cache)
         assert sizes == {"latent": 2 * rows * M * (512 + 64) * 2,
                          "draft": rows * M * (512 + 64) * 2}
         loop, (_, ((chosen, accepted, drafted), aux), ran) = \
@@ -963,7 +965,7 @@ class TestSelfDraftingGuardCompilesForV5e:
 class TestLongPromptGuardsPrefillFitsAtTheRulesGroup:
     """The 8 x 8192 prefill of both long-prompt guards at their cells'
     widths and depths (``chipbench/configs/*/model.json``), at the rows a
-    group ``lfm2_moe.rows_per_group`` gives on a v5e's memory."""
+    group ``mapped_prefill.rows_per_group`` gives on a v5e's memory."""
 
     @pytest.mark.parametrize("name, group", [("lfm2-24b-a2b-guard", 2),
                                              ("dots3-note-guard", 1)])
@@ -1041,9 +1043,9 @@ class TestWindowAndFullGuardCompilesForV5e:
         [1024, 3072], by the rule's tiles) with 128 of 256 experts held,
         behind the softmax router of 256 outputs and its 10 a token, plus
         the shared expert under its sigmoid."""
-        from semantic_router_tpu.models import laguna, sdar_moe
+        from semantic_router_tpu.models import experts, laguna
 
-        monkeypatch.setattr(sdar_moe, "_on_cpu", lambda: False)
+        monkeypatch.setattr(experts, "_on_cpu", lambda: False)
         cfg = laguna.LagunaConfig(experts_held=(0, 128))
         H, I, E = (cfg.hidden_size, cfg.moe_intermediate_size,
                    cfg.num_experts)
@@ -1107,7 +1109,7 @@ class TestWindowAndFullGuardCompilesForV5e:
         assert aux["experts"].shape == (1, rows, S, 10)
         cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
                                        cache)
-        sizes = laguna.CachedModel.cache_bytes(cache)
+        sizes = laguna.CachedModel(cfg).cache_bytes(cache)
         assert sizes == {"full": 2 * rows * 8 * M * 128 * 2,
                          "window": 2 * rows * 8 * 512 * 128 * 2}
         loop, (_, (reports, aux), ran) = loop_of_a_generation(
@@ -1141,8 +1143,9 @@ class TestWindowAndFullGuardCompilesForV5e:
             return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
         params = laguna_param_shapes(cfg, shape)
-        assert laguna.prefill_group(cfg, params, 8, 8192, 8256) == 1
-        assert laguna.prefill_group(cfg, params, 8, 512, 576) == 8
+        model = laguna.CachedModel(cfg)
+        assert model.rows_per_group(params, 8, 8192, 8256) == 1
+        assert model.rows_per_group(params, 8, 512, 576) == 8
         compiled = jax.jit(
             lambda p, i, n: laguna.prefill(cfg, p, i, n, 8256)).lower(
                 params, shape((8, 8192), jnp.int32),
