@@ -2,8 +2,9 @@
 ``models.generate.GreedyGenerator`` decodes through
 (``generate.Qwen3Cached`` says what it is): the ONE class of every
 functional decoder (``lfm2_moe``, ``dots3_note``, ``joyai_llm_flash``,
-``laguna``).  A model file binds its own functions to it under the name
-``CachedModel``; no adapters here, ``task_index`` is accepted and unused.
+``laguna``, ``olmo_hybrid``).  A model file binds its own functions to it
+under the name ``CachedModel``; no adapters here, ``task_index`` is
+accepted and unused.
 """
 
 from __future__ import annotations

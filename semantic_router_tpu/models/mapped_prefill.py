@@ -1,12 +1,13 @@
 """A prefill bounded in tokens: the rows of a long-prompt decoder's prefill
-(``lfm2_moe``, ``dots3_note``, ``laguna``) are mapped INSIDE the program a
-GROUP at a time, so the layers' temporaries exist for one group whatever
-the batch and an expert layer's grouped matmuls read each touched expert
-once a group.  Here: how many rows a group holds (``rows_per_group`` has
-the rule and the table behind it, ``prefill_group`` reckons it at a model's
-sizes), the map (``map_row_groups``) and the driver around a model's own
-``_prefill_rows`` (``prefill_in_groups``).  A model keeps what IS the
-model: its ``_prefill_rows``, its ``_row_bytes`` and ``_cache_bytes``.
+(``lfm2_moe``, ``dots3_note``, ``laguna``, ``olmo_hybrid``) are mapped
+INSIDE the program a GROUP at a time, so the layers' temporaries exist for
+one group whatever the batch and an expert layer's grouped matmuls read
+each touched expert once a group.  Here: how many rows a group holds
+(``rows_per_group`` has the rule and the table behind it, ``prefill_group``
+reckons it at a model's sizes), the map (``map_row_groups``) and the driver
+around a model's own ``_prefill_rows`` (``prefill_in_groups``).  A model
+keeps what IS the model: its ``_prefill_rows``, its ``_row_bytes`` and
+``_cache_bytes``.
 """
 
 from __future__ import annotations

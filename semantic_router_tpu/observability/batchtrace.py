@@ -327,7 +327,8 @@ def gen_forward(group: str, flavour: str, load, keys=None,
     expert's routed pairs, the pairs computed, the experts that got any,
     the busiest's pairs over the mean; the facts are ``layers``, ``pairs``
     and ``experts_touched`` (sums over the forwards' layers) and
-    ``load_milli`` (the ratio's mean over them, in thousandths).
+    ``load_milli`` (the ratio's mean over them, in thousandths; a dense
+    decoder that reports ``load [0, 4]`` reads 0 in all four).
     ``keys [rows, 2]`` of a model with a learned selection of keys adds
     ``keys_selected`` and ``keys_visible``: what the forward's queries
     selected and what they could see, over its rows and full layers.
@@ -363,7 +364,8 @@ def gen_forward(group: str, flavour: str, load, keys=None,
                     forwards=int(forwards), layers=len(load),
                     pairs=int(load[:, 1].sum()),
                     experts_touched=int(load[:, 2].sum()),
-                    load_milli=int(load[:, 3].mean() * 1000), **facts):
+                    load_milli=int(load[:, 3].mean() * 1000)
+                    if len(load) else 0, **facts):
         pass
 
 

@@ -184,6 +184,16 @@ GENERATORS = {
         "``vocab_held``).",
         _serve_cached("laguna", "LagunaConfig",
                       ("experts_held", "vocab_held"))),
+    "olmo_hybrid": (
+        "a dense decoder of gated-delta-rule linear-attention layers (a "
+        "float32 matrix state a head, moved by a chunked scan in a prefill "
+        "and a token at a time after it, behind three short causal "
+        "convolutions) and full-attention layers in the checkpoint's "
+        "``layer_types`` order, the norm on each sub-layer's output, over "
+        "one cache of K/V, states and conv windows, the same loop "
+        "(``generation: {gen_length}``; held whole: a chip has no share "
+        "of a layer).",
+        _serve_cached("olmo_hybrid", "OlmoHybridConfig", shares=())),
 }
 GENERATIVE_MODEL_TYPES = tuple(GENERATORS)
 

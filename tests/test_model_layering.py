@@ -16,7 +16,7 @@ FILES = sorted(os.path.basename(f)[:-3]
                for f in glob.glob(os.path.join(MODELS, "*.py")))
 # the rows of ``runtime.bootstrap.GENERATORS``: one file a served type
 SERVED = {"sdar_moe", "lfm2_moe", "dots3_note", "joyai_llm_flash", "laguna",
-          "qwen3"}
+          "qwen3", "olmo_hybrid"}
 # what the generative decoders share; each owns one decision
 PARTS = {"experts", "mapped_prefill", "checkpoints", "decoder_parts",
          "cached_model", "latent_attention", "gated_window"}

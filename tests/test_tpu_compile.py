@@ -1155,3 +1155,146 @@ class TestWindowAndFullGuardCompilesForV5e:
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             + mem.output_size_in_bytes < 14.5 * 2**30
         assert mem.temp_size_in_bytes < laguna._row_bytes(cfg, 8192)
+
+
+def olmo_param_shapes(cfg, shape):
+    """The ``olmo_hybrid`` parameter tree of ``cfg`` as shapes."""
+    H, W, V = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    n, dv = cfg.linear_num_key_heads, cfg.linear_value_head_dim
+    layers = []
+    for kind in cfg.layer_types:
+        p = {"attn_norm": shape((H,)), "ffn_norm": shape((H,)),
+             "gate_up": shape((H, 2 * W)), "down": shape((W, H))}
+        if kind == "full_attention":
+            p.update(q_proj=shape((H, H)), k_proj=shape((H, H)),
+                     v_proj=shape((H, H)), o_proj=shape((H, H)),
+                     q_norm=shape((H,)), k_norm=shape((H,)))
+        else:
+            p.update(qkv=shape((H, cfg.conv_width)),
+                     conv_w=shape((cfg.linear_conv_kernel_dim,
+                                   cfg.conv_width)),
+                     ab=shape((H, 2 * n)), gate=shape((H, n * dv)),
+                     A_log=shape((n,), jnp.float32),
+                     dt_bias=shape((n,), jnp.float32),
+                     o_norm=shape((dv,)), o_proj=shape((n * dv, H)))
+        layers.append(p)
+    return {"embed": shape((V, H)), "norm": shape((H,)),
+            "lm_head": shape((V, H)), "layers": layers}
+
+
+class TestLinearAttentionGuardCompilesForV5e:
+    """The ``olmo_hybrid`` guard at its published widths (30 heads of 96 x
+    192 over a float32 state, hidden 3840, SwiGLU 11008, vocabulary
+    100352): the chunked gated delta rule at the served shape, the two
+    programs of a generation over one period of layers, and the cell's
+    whole 12-layer prefill beside its weights."""
+
+    def test_the_chunked_scan_at_the_served_shape(self, one_chip,
+                                                  monkeypatch):
+        """One row of 8192 tokens with its real length: 96 and 192 go
+        through Mosaic as the arrays' own last dims (no padding to the lane
+        tile in HBM), the sequential pass is ONE kernel, and what the op
+        holds for every chunk at once is under a gigabyte."""
+        from semantic_router_tpu.ops import gated_delta_rule as gdr
+
+        on_the_described_chip(monkeypatch)
+        B, H, S, dk, dv = 1, 30, 8192, 96, 192
+        compiled = compile_for(
+            one_chip,
+            lambda q, k, v, g, b, n: gdr.chunk_gated_delta_rule(
+                q, k, v, g, b, lengths=n),
+            ((B, H, S, dk), jnp.bfloat16), ((B, H, S, dk), jnp.bfloat16),
+            ((B, H, S, dv), jnp.bfloat16), ((B, H, S), jnp.float32),
+            ((B, H, S), jnp.float32), ((B,), jnp.int32))
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1
+        # the kernel's operands as the op hands them over: a chunk a block
+        assert "f32[30,128,64,96]" in text and "f32[30,128,64,192]" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * 2**30
+
+    def test_the_two_programs_of_a_generation(self, one_chip, monkeypatch):
+        """The generator's prefill (8 rows mapped inside it, one a group)
+        and the LOOP of its decode steps over one period (three linear
+        layers, one full): the prefill's temporaries are one row's and
+        under what ``_row_bytes`` reckoned, every step of the loop writes
+        all three kinds of donated cache in place — K and V at the token's
+        row, the float32 states, the conv windows — and leaves a small
+        report in the loop's buffers."""
+        from semantic_router_tpu.models import olmo_hybrid
+        from semantic_router_tpu.models.generate import GreedyGenerator
+
+        on_the_described_chip(monkeypatch)
+        cfg = olmo_hybrid.OlmoHybridConfig.from_hf(dict(
+            cell_model("olmo-hybrid-7b-guard"), num_hidden_layers=4,
+            layer_types=["linear_attention"] * 3 + ["full_attention"]))
+        rows, S, M = 8, 8192, 8256
+
+        def shape(dims, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+        params = olmo_param_shapes(cfg, shape)
+        gen = GreedyGenerator(cfg, None, None,
+                              model=olmo_hybrid.CachedModel(cfg))
+        args = (params, shape((rows, S), jnp.int32),
+                shape((rows,), jnp.int32), shape((), jnp.int32))
+        prefill = gen._prefill_fn((rows, S, M))
+        compiled = prefill.lower(*args).compile()
+        # three scans and one causal flash call
+        assert compiled.as_text().count("tpu_custom_call") == 4
+        assert gen.model.rows_per_group(params, rows, S, M) == 1
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 1.1 * olmo_hybrid._row_bytes(cfg, S) < 1.7 * 2**30
+        cache, _, report, aux = jax.eval_shape(prefill, *args)
+        assert report.shape == (rows, 2 + 2 * gen.top_logits)
+        assert aux["load"].shape == (0, 4)
+        cache = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
+                                       cache)
+        sizes = olmo_hybrid.CachedModel(cfg).cache_bytes(cache)
+        assert sizes == {"full": 2 * rows * 30 * M * 128 * 2,
+                         "state": 3 * rows * 30 * 96 * 192 * 4,
+                         "conv": 3 * rows * 3 * 11520 * 2}
+        assert sum(sizes.values()) == olmo_hybrid._cache_bytes(cfg, rows, M)
+        assert cache["state"][0].dtype == jnp.float32
+        loop, (_, (reports, aux), ran) = loop_of_a_generation(
+            gen, params, cache, shape((rows,), jnp.int32), rows, M, shape)
+        assert reports.shape == (31, rows, 2 + 2 * gen.top_logits)
+        assert aux["load"].shape == (31, 0, 4) and ran.shape == ()
+        text = assert_the_loop_writes_its_cache_in_place(
+            loop, cache, "f32[31,%d,%d]" % (rows, 2 + 2 * gen.top_logits))
+        assert "tpu_custom_call" not in text  # a step is plain jnp
+        assert loop.memory_analysis().temp_size_in_bytes < 0.3 * 2**30
+
+    def test_the_cells_prefill_fits_beside_its_weights(self, one_chip,
+                                                       monkeypatch):
+        """The 8 x 8192 prefill at the cell's widths and depth
+        (``chipbench/configs/olmo-hybrid-7b-guard/model.json``: 12
+        layers), one row a group: its arguments are the cell's 6.54 GB of
+        weights, and weights, temporaries and the 3.2 GB of cache it
+        returns stay far under the 15.0e9 the configuration's rule allows.
+        Without the barrier after each layer the scheduler cut every
+        layer's conv window from its ``[S, 11520]`` input at the program's
+        end and held them all (at 16 layers 4.0 GB of temporaries, not
+        2.0)."""
+        from semantic_router_tpu.models import olmo_hybrid
+
+        on_the_described_chip(monkeypatch)
+        cfg = olmo_hybrid.OlmoHybridConfig.from_hf(
+            cell_model("olmo-hybrid-7b-guard"))
+
+        def shape(dims, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+        params = olmo_param_shapes(cfg, shape)
+        for rows in (1, 2, 4, 8):
+            assert olmo_hybrid.CachedModel(cfg).rows_per_group(
+                params, rows, 8192, 8256) == 1
+        compiled = jax.jit(
+            lambda p, i, n: olmo_hybrid.prefill(cfg, p, i, n, 8256)).lower(
+                params, shape((8, 8192), jnp.int32),
+                shape((8,), jnp.int32)).compile()
+        mem = compiled.memory_analysis()
+        assert 6.53e9 < mem.argument_size_in_bytes < 6.55e9
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            + mem.output_size_in_bytes < 12.0e9
+        assert mem.temp_size_in_bytes < 1.4 * olmo_hybrid._row_bytes(
+            cfg, 8192)
